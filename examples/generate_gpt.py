@@ -279,6 +279,8 @@ def slo_demo(state, cfg, args):
 
 
 def main():
+    from hetu_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=150)
     ap.add_argument("--hidden", type=int, default=64)

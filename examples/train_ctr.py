@@ -50,6 +50,8 @@ def parse_args():
 def main():
     args = parse_args()
     import hetu_tpu as ht
+    from hetu_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from hetu_tpu import optim
     from hetu_tpu.models.ctr import DCN, DeepFM, WDL, ctr_loss
 

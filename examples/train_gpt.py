@@ -11,7 +11,11 @@ Run (8 simulated devices):
   python examples/train_gpt.py --dp 2 --tp 4 --steps 20 --hidden 128 \
       --layers 2 --seq-len 64
 
-On a real TPU slice just drop the env overrides.
+On a TPU host ONE process drives every chip it needs (a second process
+cannot have them): ``python examples/train_gpt.py --dp 2 --tp 2 --bf16``
+on four chips, no flags for one.  Kernels are chosen by platform, and
+the compile cache goes where ``JAX_COMPILATION_CACHE_DIR`` says, else
+``<checkout>/.jax_cache``.
 """
 from __future__ import annotations
 
@@ -19,13 +23,15 @@ import argparse
 import json
 import os
 import sys
+import time
+import types
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import numpy as np  # noqa: E402
 
 
-def parse_args():
+def parse_args(argv=None):
     p = argparse.ArgumentParser(description="GPT/LLaMA pretraining")
     # model (reference train_hetu.py:479-588 surface)
     p.add_argument("--model", choices=["gpt", "llama"], default="gpt")
@@ -78,20 +84,24 @@ def parse_args():
                    help="trace the run (per-step feed/executable/commit "
                         "phase spans) and write a Perfetto-loadable "
                         "chrome trace JSON here")
-    return p.parse_args()
+    return p.parse_args(argv)
 
 
-def main():
-    args = parse_args()
+def main(argv=None):
+    """Train; returns what ran (graph, model, loader, per-step losses
+    and wall seconds) so a caller — ``chip_smoke.py`` — can check it."""
+    args = parse_args(argv)
     import jax
     import hetu_tpu as ht
     from jax.sharding import PartitionSpec as P
     from hetu_tpu import optim
     from hetu_tpu.data import Dataloader, GPTSeqDataset
     from hetu_tpu.models import GPTConfig, GPTLMHeadModel, llama_config
-    from hetu_tpu.utils import StepProfiler, get_logger
+    from hetu_tpu.utils import get_logger
+    from hetu_tpu.utils.compile_cache import enable_compile_cache
 
     log = get_logger("train_gpt")
+    enable_compile_cache()
     n_dev = len(jax.devices())
     dp, tp, pp, zero = args.dp, args.tp, args.pp, args.zero
     mk = llama_config if args.model == "llama" else GPTConfig
@@ -134,6 +144,11 @@ def main():
                               jax.devices()[:dp * tp])
     else:
         mesh = None
+    # one device holds the whole [B*S, V] logits (3.3 GB in bf16 at the
+    # default widths and batch 32 — with its backward it does not fit a
+    # 16 GB chip), so there the LM head and the loss run fused in chunks;
+    # on a mesh the vocab-parallel loss shards the logits over dp x tp
+    cfg.fused_lm_ce = mesh is None
     micro = args.micro_batch or max(1, args.global_batch // dp)
     num_micro = max(1, args.global_batch // (micro * dp))
 
@@ -141,11 +156,16 @@ def main():
     if args.data:
         tokens = np.load(args.data)
     else:
+        # seeded synthetic text: Zipf-distributed ids, the unigram
+        # statistics of real text, so the first few steps already lower
+        # the loss — uniform ids would leave nothing to learn
         rng = np.random.RandomState(0)
-        tokens = rng.randint(0, args.vocab_size,
-                             args.global_batch * args.seq_len * 64)
+        tokens = (rng.zipf(1.2, args.global_batch * args.seq_len * 64)
+                  - 1) % args.vocab_size
     ds = GPTSeqDataset(tokens, seq_len=args.seq_len)
     loader = Dataloader(ds, batch_size=args.global_batch, shuffle=True)
+    print("loader:", "native C++ prefetch core" if loader._lib is not None
+          else "python (the native core could not be built)")
 
     batch_shape = (args.global_batch, args.seq_len)
     with ht.graph("define_and_run", create_new=True, mesh=mesh) as g:
@@ -170,34 +190,38 @@ def main():
             load_model(model, args.load)
             log.info("resumed from %s", args.load)
 
-        sp_prof = StepProfiler(warmup=2)
         tracer = None
         if args.trace_out:
             from hetu_tpu import obs
             tracer = obs.SpanTracer()
             obs.install_tracer(tracer)   # graph.run phases pick it up
         step = 0
+        losses, step_seconds = [], []
         while step < args.steps:
             for batch in loader:
                 if step >= args.steps:
                     break
-                if isinstance(batch, tuple):   # python-fallback loader
+                if isinstance(batch, tuple):   # python loader: (x, y)
                     x, y = batch
-                else:                          # native matrix layout
+                else:                          # native loader: one matrix
                     x, y = batch[:, :args.seq_len], batch[:, args.seq_len:]
-                with sp_prof:
-                    # pp>1: micro-batching happens inside pipeline_spmd
-                    out = g.run(loss, [loss, train_op], {ids: x, labels: y},
-                                num_micro_batches=1 if pp > 1 else num_micro)
+                t0 = time.perf_counter()
+                # pp>1: micro-batching happens inside pipeline_spmd
+                out = g.run(loss, [loss, train_op], {ids: x, labels: y},
+                            num_micro_batches=1 if pp > 1 else num_micro)
+                # dispatch is asynchronous: the step has taken its time
+                # only once the loss is on the host
+                losses.append(float(np.asarray(out[0])))
+                step_seconds.append(time.perf_counter() - t0)
                 step += 1
                 if step % args.log_every == 0 or step == args.steps:
-                    st = sp_prof.stats()
+                    # the first two steps compile (on a mesh, twice)
+                    mean = float(np.mean(step_seconds[2:])) \
+                        if step > 2 else 0.0
                     tput = (args.global_batch * args.seq_len
-                            / st["mean"]) if st["mean"] else 0.0
-                    print(f"step {step:5d} | loss "
-                          f"{float(np.asarray(out[0])):.4f} | "
-                          f"{st['mean'] * 1e3:.1f} ms/step | "
-                          f"{tput_fmt(tput)}")
+                            / mean) if mean else 0.0
+                    print(f"step {step:5d} | loss {losses[-1]:.4f} | "
+                          f"{mean * 1e3:.1f} ms/step | {tput_fmt(tput)}")
         if tracer is not None:
             from hetu_tpu import obs
             obs.install_tracer(None)
@@ -211,6 +235,9 @@ def main():
             os.makedirs(d, exist_ok=True)
             save_model(model, args.save)
             log.info("saved to %s", args.save)
+    return types.SimpleNamespace(args=args, graph=g, model=model,
+                                 loader=loader, losses=losses,
+                                 step_seconds=step_seconds)
 
 
 def tput_fmt(tokens_per_s: float) -> str:

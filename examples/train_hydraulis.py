@@ -57,6 +57,8 @@ def main():
     args = parse_args()
     import jax
     import hetu_tpu as ht
+    from hetu_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from jax.sharding import PartitionSpec as P
     from hetu_tpu import optim
     from hetu_tpu.data.bucket import (Bucket, get_sorted_batch_and_len)

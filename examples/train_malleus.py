@@ -60,6 +60,8 @@ def main():
     import hetu_tpu as ht
     from jax.sharding import PartitionSpec as P
     from hetu_tpu import optim
+    from hetu_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from hetu_tpu.elastic import Straggler, StragglerWorkload, StrategyModel
     from hetu_tpu.elastic.trainer import Trainer
     from hetu_tpu.models import GPTLMHeadModel, llama_config

@@ -68,6 +68,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 
 from ..planner.cost_model import (ChipSpec, ClusterSpec, collective_time)
+from .jaxpr_walk import source_line
 
 #: elementwise arithmetic: 1 FLOP per output element (XLA counts int
 #: ops too, and select/compare chains count per op)
@@ -392,21 +393,6 @@ def _is_narrow_float(aval) -> bool:
         return False
 
 
-def _source_of(eqn) -> str:
-    si = getattr(eqn, "source_info", None)
-    if si is None:
-        return ""
-    try:
-        from jax._src import source_info_util as siu
-        fr = siu.user_frame(si)
-        if fr is not None:
-            import os
-            return f"{os.path.basename(fr.file_name)}:{fr.start_line}"
-    except Exception:
-        pass
-    return ""
-
-
 def dot_general_flops(eqn) -> float:
     """``2 · |out| · |contracting dims|`` from the dimension numbers —
     the exact count XLA's cost analysis reports for a dot."""
@@ -680,7 +666,7 @@ def cost_walk(jaxpr, scale: float = 1.0, upcast: bool = False,
                       + sum(_aval_bytes(ov.aval, upcast)
                             for ov in eqn.outvars
                             if hasattr(ov, "aval"))) * scale
-            src = _source_of(eqn)
+            src = source_line(eqn)
             out.flops += flops
             out.transcendentals += trans
             out.bytes += nb
@@ -720,7 +706,7 @@ def cost_walk(jaxpr, scale: float = 1.0, upcast: bool = False,
                 joined = union(joined, comp_of_var[ri])
         joined = find(joined)
         if flops or trans:
-            comp_src.setdefault(joined, _source_of(eqn))
+            comp_src.setdefault(joined, source_line(eqn))
         for iv in eqn.invars:
             if not hasattr(iv, "count"):
                 continue

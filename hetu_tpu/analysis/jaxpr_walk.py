@@ -27,6 +27,7 @@ jaxpr inventory (``rules.implicit-reshard``).
 """
 from __future__ import annotations
 
+import os
 from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
@@ -95,22 +96,22 @@ def _name_stack_of(eqn) -> str:
         return ""
 
 
-def _source_of(eqn) -> Tuple[str, str]:
-    """(scope, file:line) from eqn provenance."""
-    scope = _name_stack_of(eqn)
-    src = ""
+def source_line(eqn) -> str:
+    """``file:line`` of the user frame that emitted ``eqn`` ("" when
+    the eqn carries no source info or no user frame)."""
     si = getattr(eqn, "source_info", None)
     if si is None:
-        return scope, src
-    try:
-        from jax._src import source_info_util as siu
-        fr = siu.user_frame(si)
-        if fr is not None:
-            import os
-            src = f"{os.path.basename(fr.file_name)}:{fr.start_line}"
-    except Exception:
-        pass
-    return scope, src
+        return ""
+    from jax._src import source_info_util as siu
+    fr = siu.user_frame(si.traceback)
+    if fr is None:
+        return ""
+    return f"{os.path.basename(fr.file_name)}:{fr.start_line}"
+
+
+def _source_of(eqn) -> Tuple[str, str]:
+    """(scope, file:line) from eqn provenance."""
+    return _name_stack_of(eqn), source_line(eqn)
 
 
 def iter_eqns(jaxpr, _trip: int = 1, _axis_sizes: Optional[Dict[str, int]]

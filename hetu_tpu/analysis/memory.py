@@ -56,6 +56,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .jaxpr_walk import source_line
+
 #: primitives whose outputs always materialize as real buffers (XLA
 #: cannot fuse them away): contractions, data movement, collectives,
 #: control-flow containers, reductions.  Everything else is assumed
@@ -227,21 +229,6 @@ def _aval_bytes(aval, upcast: bool) -> int:
         return 0
 
 
-def _source_of(eqn) -> str:
-    si = getattr(eqn, "source_info", None)
-    if si is None:
-        return ""
-    try:
-        from jax._src import source_info_util as siu
-        fr = siu.user_frame(si)
-        if fr is not None:
-            import os
-            return f"{os.path.basename(fr.file_name)}:{fr.start_line}"
-    except Exception:
-        pass
-    return ""
-
-
 @dataclasses.dataclass
 class _LivePeak:
     """Result of one (sub-)jaxpr liveness walk."""
@@ -331,7 +318,7 @@ def liveness_walk(jaxpr, scale: float = 1.0, upcast: bool = False,
                 out_b += b
                 if b:
                     if src is None:
-                        src = _source_of(eqn)
+                        src = source_line(eqn)
                     live_desc[id(ov)] = (
                         b, pname,
                         src or str(getattr(ov.aval, "shape", "")))
@@ -393,9 +380,9 @@ def _kv_page_shapes(serving) -> set:
 
     Read from the pool's live arrays (``page_array_shapes``), not its
     constructor attrs: the MLA latent layout stores a compressed
-    ``[.., 1, latent_dim]`` stream (k) next to a rope/scale sidecar (v)
-    whose shapes differ from ``(num_pages, page_size, kv_heads,
-    head_dim)`` — and from each other."""
+    ``[.., 1, page_size, latent_dim]`` stream (k) next to a rope/scale
+    sidecar (v) whose shapes differ from ``(num_pages, kv_heads,
+    page_size, head_dim)`` — and from each other."""
     shapes = set()
     pool = (serving or {}).get("pool")
     if pool is not None:
@@ -404,8 +391,8 @@ def _kv_page_shapes(serving) -> set:
             for s in (*k_shapes, *v_shapes):
                 shapes.add(tuple(int(d) for d in s))
         except AttributeError:      # foreign pool object: attr fallback
-            shapes.add((int(pool.num_pages), int(pool.page_size),
-                        int(pool.kv_heads), int(pool.head_dim)))
+            shapes.add((int(pool.num_pages), int(pool.kv_heads),
+                        int(pool.page_size), int(pool.head_dim)))
     return shapes
 
 

@@ -1,14 +1,21 @@
-"""Build + load the native planner core (ctypes, cached .so).
+"""Build + load the native cores (ctypes, cached .so).
 
 The reference ships its solver as a pybind11 extension
 (``tools/Galvatron/csrc/dp_core.cpp``); here we compile a plain C-ABI
-shared library with g++ at first use (cached by source mtime) and bind it
-with ctypes — no pybind11 needed.  All callers must tolerate ``None``
-(compiler missing) and fall back to the pure-Python implementations.
+shared library with g++ at first use and bind it with ctypes — no
+pybind11 needed.  The ``.so`` is keyed on a hash of its sources (in the
+file name), so a binary built from other sources — a stale one copied
+along with the tree, whatever its mtime — is never picked up.
+
+Callers that merely *prefer* the native path get ``None`` when it cannot
+be built and take their pure-Python implementation; callers that were
+*asked* for it pass ``required=True`` and get :class:`NativeBuildError`
+carrying g++'s stderr.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -16,44 +23,62 @@ from typing import Optional
 
 _SRC_DIR = os.path.dirname(os.path.abspath(__file__))
 _BUILD_DIR = os.path.join(_SRC_DIR, "_build")
+_CXX = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread"]
 _LOCK = threading.Lock()
 _CACHE: dict = {}
 
 
-def _compile(name: str, sources) -> Optional[str]:
-    os.makedirs(_BUILD_DIR, exist_ok=True)
-    so_path = os.path.join(_BUILD_DIR, f"lib{name}.so")
+class NativeBuildError(RuntimeError):
+    """A native core could not be compiled or loaded."""
+
+
+def _compile(name: str, sources) -> str:
     srcs = [os.path.join(_SRC_DIR, s) for s in sources]
-    if os.path.exists(so_path) and all(
-            os.path.getmtime(so_path) >= os.path.getmtime(s) for s in srcs):
+    h = hashlib.sha256(" ".join(_CXX).encode())
+    for src in srcs:
+        with open(src, "rb") as f:
+            h.update(f.read())
+    so_path = os.path.join(_BUILD_DIR, f"lib{name}-{h.hexdigest()[:16]}.so")
+    if os.path.exists(so_path):
         return so_path
-    cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
-           "-o", so_path, *srcs]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-    except (OSError, subprocess.SubprocessError):
-        return None
+    os.makedirs(_BUILD_DIR, exist_ok=True)
+    tmp = f"{so_path}.{os.getpid()}.tmp"    # concurrent builders never
+    try:                                     # expose a half-written .so
+        proc = subprocess.run([*_CXX, "-o", tmp, *srcs],
+                              capture_output=True, text=True, timeout=120)
+    except (OSError, subprocess.TimeoutExpired) as e:
+        raise NativeBuildError(f"g++ did not run for lib{name}: {e}") from e
+    if proc.returncode != 0:
+        raise NativeBuildError(
+            f"g++ failed for lib{name} (rc={proc.returncode}):\n"
+            f"{proc.stderr}")
+    os.replace(tmp, so_path)
     return so_path
 
 
-def load_native(name: str, sources) -> Optional[ctypes.CDLL]:
-    """Compile-if-stale and dlopen ``lib<name>.so``; None on any failure."""
+def load_native(name: str, sources,
+                required: bool = False) -> Optional[ctypes.CDLL]:
+    """Build-if-missing and dlopen ``lib<name>-<source hash>.so``.  On
+    failure: ``None``, or :class:`NativeBuildError` when ``required``."""
     with _LOCK:
-        if name in _CACHE:
-            return _CACHE[name]
-        lib = None
-        so = _compile(name, sources)
-        if so is not None:
+        if name not in _CACHE:
             try:
-                lib = ctypes.CDLL(so)
-            except OSError:
-                lib = None
-        _CACHE[name] = lib
-        return lib
+                _CACHE[name] = ctypes.CDLL(_compile(name, sources))
+            except NativeBuildError as e:
+                _CACHE[name] = e
+            except OSError as e:
+                _CACHE[name] = NativeBuildError(
+                    f"cannot load lib{name}: {e}")
+        lib = _CACHE[name]
+    if isinstance(lib, NativeBuildError):
+        if required:
+            raise lib
+        return None
+    return lib
 
 
-def load_dataloader_core() -> Optional[ctypes.CDLL]:
-    lib = load_native("hetu_dataloader", ["dataloader.cc"])
+def load_dataloader_core(required: bool = False) -> Optional[ctypes.CDLL]:
+    lib = load_native("hetu_dataloader", ["dataloader.cc"], required)
     if lib is not None and not getattr(lib, "_hetu_sigs_set", False):
         lib.hetu_loader_create.restype = ctypes.c_void_p
         lib.hetu_loader_create.argtypes = [

@@ -46,16 +46,17 @@ class Dataloader:
         self._handle = None
         self._handle_key = None
         if use_native is not False:
-            lib = load_dataloader_core()  # probe before materializing
+            # probe before materializing; asked for (True), a build
+            # failure raises with g++'s stderr instead of degrading
+            lib = load_dataloader_core(required=use_native is True)
             if lib is not None:
                 mat = self._native_matrix(dataset)
                 if mat is not None:
                     self._native_mat = mat
                     self._lib = lib
         if use_native is True and self._lib is None:
-            raise RuntimeError("native dataloader requested but "
-                               "unavailable (need a contiguous 2-D array "
-                               "dataset and a working g++)")
+            raise RuntimeError("native dataloader requested but the "
+                               "dataset is not one contiguous 2-D array")
 
     @staticmethod
     def _native_matrix(dataset) -> Optional[np.ndarray]:

@@ -17,12 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+from .pallas import on_tpu
 
 
 def sdpa_reference(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -67,18 +62,16 @@ def sdpa(q, k, v, causal: bool = True, softmax_scale: Optional[float] = None,
          bias: Optional[jax.Array] = None,
          segment_ids: Optional[jax.Array] = None,
          use_flash: Optional[bool] = None) -> jax.Array:
-    """Dispatching attention entry point."""
+    """Dispatching attention entry point: the Pallas flash kernel on
+    platform ``tpu``, the jnp reference elsewhere and whenever a
+    ``bias`` is given (flash takes none).  A kernel error propagates."""
     if use_flash is None:
-        use_flash = _on_tpu()
-    if use_flash:
-        try:
-            from .pallas.flash_attention import flash_attention
-            if bias is None:
-                return flash_attention(q, k, v, causal=causal,
-                                       softmax_scale=softmax_scale,
-                                       segment_ids=segment_ids)
-        except Exception:
-            pass
+        use_flash = on_tpu()
+    if use_flash and bias is None:
+        from .pallas.flash_attention import flash_attention
+        return flash_attention(q, k, v, causal=causal,
+                               softmax_scale=softmax_scale,
+                               segment_ids=segment_ids)
     return sdpa_reference(q, k, v, causal=causal,
                           softmax_scale=softmax_scale, bias=bias,
                           segment_ids=segment_ids)

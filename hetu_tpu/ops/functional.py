@@ -614,23 +614,46 @@ def attention(q, k, v, causal=True, softmax_scale=None, use_flash=None,
     """Scaled-dot-product attention on [batch, seq, heads, head_dim]
     (reference ops/Attention.cc wrapping flash-attn2).
 
-    On TPU, dispatches to the Pallas flash-attention kernel when available;
-    the jnp fallback is used on CPU/simulation (XLA still fuses well).
+    On platform ``tpu`` this is the Pallas flash-attention kernel (an
+    error in it propagates); elsewhere it is the jnp reference.
     ``segment_ids`` ([b, s] int, -1 pad) gives packed/varlen masking —
     the reference's cu_seqlens path (ops/Attention.h:286).
-    """
-    from .attention import sdpa  # local import to avoid cycle
-    if segment_ids is None:
-        def _impl(q, k, v, causal=True, softmax_scale=None):
-            return sdpa(q, k, v, causal=causal, softmax_scale=softmax_scale,
-                        use_flash=use_flash)
-        return _op("attention", _impl, [q, k, v],
-                   {"causal": causal, "softmax_scale": softmax_scale})
 
-    def _impl(q, k, v, segs, causal=True, softmax_scale=None):
-        return sdpa(q, k, v, causal=causal, softmax_scale=softmax_scale,
-                    use_flash=use_flash, segment_ids=segs)
-    return _op("attention", _impl, [q, k, v, segment_ids],
+    A Mosaic call is opaque to the SPMD partitioner ("cannot be
+    automatically partitioned"), so under a multi-device graph mesh the
+    kernel runs inside a ``shard_map`` placed on the batch/head sharding
+    ``q`` was annotated with — what the context-parallel paths do for
+    the same kernel.  The reference needs no such help from GSPMD.
+    """
+    from jax.sharding import PartitionSpec as P
+    from .attention import sdpa  # local import to avoid cycle
+    from .pallas import on_tpu
+    g = _graph_of(q, k, v)
+    q_tensor = q
+
+    def _impl(q, k, v, segs=None, causal=True, softmax_scale=None):
+        def attn(q, k, v, segs=None):
+            return sdpa(q, k, v, causal=causal, softmax_scale=softmax_scale,
+                        use_flash=use_flash, segment_ids=segs)
+        mesh = g.mesh
+        flash = on_tpu() if use_flash is None else use_flash
+        if not flash or mesh is None or mesh.size == 1:
+            return attn(q, k, v, segs)
+        spec = tuple(g._pspec_for(q_tensor) or ())
+        b_ax, s_ax, h_ax, d_ax = spec + (None,) * (4 - len(spec))
+        if s_ax is not None or d_ax is not None:
+            raise ValueError(
+                f"attention needs seq and head_dim unsharded, got q "
+                f"pspec {spec}; sequence sharding is parallel_attention")
+        from ..parallel.comm import shard_map
+        spec = P(b_ax, None, h_ax, None)
+        if segs is None:
+            return shard_map(attn, mesh, (spec, spec, spec), spec)(q, k, v)
+        return shard_map(attn, mesh, (spec, spec, spec, P(b_ax, None)),
+                         spec)(q, k, v, segs)
+
+    inputs = [q, k, v] if segment_ids is None else [q, k, v, segment_ids]
+    return _op("attention", _impl, inputs,
                {"causal": causal, "softmax_scale": softmax_scale})
 
 

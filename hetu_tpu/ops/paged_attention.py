@@ -2,7 +2,7 @@
 
 Serving keeps KV state in a preallocated page pool
 (``hetu_tpu/serving/kv_pool.py``): per layer, ``k_pages``/``v_pages``
-of shape ``[num_pages, page_size, kv_heads, head_dim]``, with each
+of shape ``[num_pages, kv_heads, page_size, head_dim]``, with each
 request owning a list of pages through an int32 page table.  Decode
 attention then reads *ragged* per-request histories through the page
 table instead of a padded dense ``[B, max_len, ...]`` cache — the
@@ -13,8 +13,8 @@ Two implementations, numerically interchangeable:
 
 - ``paged_attention_reference`` — gather pages via the page table into a
   contiguous ``[B, maxp*ps, kvh, hd]`` view and run masked dense
-  attention.  This is the CPU/simulation path and the oracle the kernel
-  is tested against.
+  attention.  This is the path off TPU and the oracle the kernel is
+  tested against.
 - ``paged_attention_pallas`` — Pallas TPU kernel.  The page table and
   sequence lengths ride in as **scalar-prefetch** operands
   (``PrefetchScalarGridSpec``), so the kernel's k/v BlockSpec index maps
@@ -24,11 +24,15 @@ Two implementations, numerically interchangeable:
   last partial page).  Runs in interpret mode off-TPU so the whole path
   is testable on the simulated mesh.
 
-Layout notes (DESIGN.md §8): ``head_dim`` fills the 128-lane tile;
-``page_size`` is the sublane dim of the per-(page, kv-head) ``[ps, hd]``
-tile and must be a multiple of 8 (f32 sublanes) — multiples of 128
-additionally make one page exactly one MXU-shaped block.  The GQA group
-dim is padded to 8 sublanes for the q/out tiles.
+Layout notes (DESIGN.md §8): the last two page dims are ``(page_size,
+head_dim)``, so the per-(page, kv-head) ``[ps, hd]`` tile a grid step
+DMAs is a whole trailing block — the only k/v block Mosaic accepts when
+there is more than one KV head (a block of 1 on a ``kv_heads`` axis in
+second-to-last position is refused).  ``head_dim`` fills the 128-lane
+tile; ``page_size`` is the sublane dim and must be a multiple of 8 (f32
+sublanes) — multiples of 128 additionally make one page exactly one
+MXU-shaped block.  The GQA group dim is padded to 8 sublanes for the
+q/out tiles.
 """
 from __future__ import annotations
 
@@ -41,21 +45,51 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .pallas import on_tpu
+
 LANES = 128
 SUBLANES = 8
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+def gather_pages(pages: jax.Array, page_tables: jax.Array) -> jax.Array:
+    """Pages ``[P, h, ps, w]`` read through ``page_tables [..., maxp]``
+    into position order: ``[..., maxp*ps, h, w]`` (the dense view every
+    reference path attends over)."""
+    g = jnp.swapaxes(pages[page_tables], -3, -2)   # [..., maxp, ps, h, w]
+    return g.reshape(*page_tables.shape[:-1], -1, *g.shape[-2:])
+
+
+def _tiled_bytes(shape, dtype) -> int:
+    """VMEM bytes of one block: the last dim padded to 128 lanes, the
+    second-to-last to the dtype's sublane tile (8 rows of 32 bits)."""
+    item = jnp.dtype(dtype).itemsize
+    sub = SUBLANES * max(1, 4 // item)
+    *lead, rows, cols = shape
+    n = item * (-(-rows // sub) * sub) * (-(-cols // LANES) * LANES)
+    for d in lead:
+        n *= d
+    return n
+
+
+def vmem_params(blocks, scratch):
+    """Mosaic compiler params for a kernel whose q/out blocks span the
+    whole token axis: ``blocks`` (each double-buffered by the pipeline)
+    and ``scratch`` are ``(shape, dtype)`` pairs.  Below Mosaic's 16 MiB
+    scoped-VMEM default nothing is asked for (None); above it the limit
+    is raised to the estimate plus a quarter for the compiler's own
+    temporaries — a 256-token chunk at d_c 512 needs 17 MiB."""
+    need = 2 * sum(_tiled_bytes(s, d) for s, d in blocks) \
+        + sum(_tiled_bytes(s, d) for s, d in scratch)
+    need += need // 4
+    if need <= 16 << 20:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=need)
 
 
 def _check_shapes(q, k_pages, v_pages, page_tables, seq_lens):
     b, nh, hd = q.shape
-    p_, ps, kvh, hd2 = k_pages.shape
+    p_, kvh, ps, hd2 = k_pages.shape
     if v_pages.shape != k_pages.shape:
         raise ValueError(f"k_pages {k_pages.shape} != v_pages "
                          f"{v_pages.shape}")
@@ -81,7 +115,7 @@ def paged_attention_reference(q: jax.Array, k_pages: jax.Array,
                               softmax_scale: Optional[float] = None
                               ) -> jax.Array:
     """q [B, nh, hd] (one decode token per request), pages
-    [P, ps, kvh, hd], page_tables [B, maxp] int32, seq_lens [B] int32
+    [P, kvh, ps, hd], page_tables [B, maxp] int32, seq_lens [B] int32
     (tokens valid, *including* the one just written) -> out [B, nh, hd].
     """
     b, nh, hd, ps, kvh = _check_shapes(q, k_pages, v_pages, page_tables,
@@ -91,9 +125,8 @@ def paged_attention_reference(q: jax.Array, k_pages: jax.Array,
     # named scope: the static analyzer (hetu_tpu/analysis) attributes
     # eqns to this op through the jaxpr name stack
     with jax.named_scope("paged_attention"):
-        # [B, maxp, ps, kvh, hd] -> [B, maxp*ps, kvh, hd]
-        k = k_pages[page_tables].reshape(b, maxp * ps, kvh, hd)
-        v = v_pages[page_tables].reshape(b, maxp * ps, kvh, hd)
+        k = gather_pages(k_pages, page_tables)  # [B, maxp*ps, kvh, hd]
+        v = gather_pages(v_pages, page_tables)
         g = nh // kvh
         qg = q.reshape(b, kvh, g, hd).astype(jnp.float32)
         s = jnp.einsum("bhgd,bshd->bhgs", qg,
@@ -128,8 +161,8 @@ def _paged_kernel(sl_ref, pt_ref,            # scalar prefetch
     @pl.when(p * ps < seqlen)
     def _page():
         q = q_ref[0, 0].astype(jnp.float32)            # [gp, hd]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)      # [ps, hd]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)            # [ps, hd]
+        v = v_ref[0, 0].astype(jnp.float32)
         s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
         cols = p * ps + lax.broadcasted_iota(jnp.int32, (gp, ps), 1)
@@ -169,7 +202,7 @@ def paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
     maxp = page_tables.shape[1]
     scale = softmax_scale if softmax_scale is not None else hd ** -0.5
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
     g = nh // kvh
     gp = max(SUBLANES, ((g + SUBLANES - 1) // SUBLANES) * SUBLANES)
     qg = q.reshape(b, kvh, g, hd)
@@ -186,11 +219,11 @@ def paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
         in_specs=[
             pl.BlockSpec((1, 1, gp, hd),
                          lambda bi, h, p, sl_r, pt_r: (bi, h, 0, 0)),
-            pl.BlockSpec((1, ps, 1, hd),
-                         lambda bi, h, p, sl_r, pt_r: (pt_r[bi, p], 0, h,
+            pl.BlockSpec((1, 1, ps, hd),
+                         lambda bi, h, p, sl_r, pt_r: (pt_r[bi, p], h, 0,
                                                        0)),
-            pl.BlockSpec((1, ps, 1, hd),
-                         lambda bi, h, p, sl_r, pt_r: (pt_r[bi, p], 0, h,
+            pl.BlockSpec((1, 1, ps, hd),
+                         lambda bi, h, p, sl_r, pt_r: (pt_r[bi, p], h, 0,
                                                        0)),
         ],
         out_specs=pl.BlockSpec(
@@ -207,6 +240,7 @@ def paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, kvh, gp, hd), q.dtype),
             interpret=interpret,
+            name="paged_attention",
         )(sl, pt, qg, k_pages, v_pages)
     return out[:, :, :g, :].reshape(b, nh, hd)
 
@@ -216,16 +250,14 @@ def paged_attention_decode(q: jax.Array, k_pages: jax.Array,
                            seq_lens: jax.Array,
                            softmax_scale: Optional[float] = None,
                            use_kernel: Optional[bool] = None) -> jax.Array:
-    """Dispatching entry point: Pallas kernel on TPU, gather-dense
-    reference elsewhere (mirrors ``ops.sdpa``'s dispatch discipline)."""
+    """Dispatching entry point: Pallas kernel on platform ``tpu``,
+    gather-dense reference elsewhere (``ops.sdpa``'s dispatch rule: the
+    platform chooses, a kernel error propagates)."""
     if use_kernel is None:
-        use_kernel = _on_tpu()
+        use_kernel = on_tpu()
     if use_kernel:
-        try:
-            return paged_attention_pallas(q, k_pages, v_pages, page_tables,
-                                          seq_lens,
-                                          softmax_scale=softmax_scale)
-        except Exception:
-            pass
+        return paged_attention_pallas(q, k_pages, v_pages, page_tables,
+                                      seq_lens,
+                                      softmax_scale=softmax_scale)
     return paged_attention_reference(q, k_pages, v_pages, page_tables,
                                      seq_lens, softmax_scale=softmax_scale)

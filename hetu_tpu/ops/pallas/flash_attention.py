@@ -41,6 +41,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import on_tpu
+
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
@@ -58,10 +60,6 @@ def _empty_rows(m):
 # every API boundary (ring correction, backward, tests).
 LOG2E = 1.4426950408889634
 LN2 = 0.6931471805599453
-
-
-def _interpret() -> bool:
-    return jax.devices()[0].platform != "tpu"
 
 
 LANES = 128      # last-dim tile width
@@ -114,11 +112,8 @@ def _seg_operands(segment_ids, b, h, sq, sk, bq, bk):
 
 def _dim_semantics(*sem):
     """Mosaic dimension semantics (parallel dims may split across
-    TensorCores); None on toolchains without CompilerParams."""
-    try:
-        return pltpu.CompilerParams(dimension_semantics=sem)
-    except (AttributeError, TypeError):
-        return None
+    TensorCores)."""
+    return pltpu.CompilerParams(dimension_semantics=sem)
 
 
 def _nosegs_kernel(kernel, *refs, **kw):
@@ -311,7 +306,8 @@ def _flash_fwd(q, k, v, scale, causal, segment_ids, causal_offset=0):
             pltpu.VMEM((bq, LANES), jnp.float32),
         ],
         compiler_params=_dim_semantics("parallel", "parallel", "arbitrary"),
-        interpret=_interpret(),
+        interpret=not on_tpu(),
+        name="flash_fwd",
     )(*seg_args, qr, kr, vr)
     out = out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
     lse = lse[:, :, 0].reshape(b, h, sq)
@@ -451,7 +447,8 @@ def _flash_bwd_fused(scale, causal, segment_ids, res, do, causal_offset):
             pltpu.VMEM((bq, LANES), jnp.float32),
         ],
         compiler_params=_dim_semantics("parallel", "arbitrary", "arbitrary"),
-        interpret=_interpret(),
+        interpret=not on_tpu(),
+        name="flash_bwd_fused",
     )(*seg_args, qr, kr, vr, dor, outr, lser)
     dq = dq.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
     dk = dk.reshape(b, h, sk, d).transpose(0, 2, 1, 3)
@@ -601,7 +598,8 @@ def _flash_bwd_split(scale, causal, segment_ids, res, do, causal_offset):
         out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         compiler_params=_dim_semantics("parallel", "parallel", "arbitrary"),
-        interpret=_interpret(),
+        interpret=not on_tpu(),
+        name="flash_bwd_dq",
     )(*seg_args, qr, kr, vr, dor, lser, delta)
 
     dkv_kernel = functools.partial(
@@ -638,7 +636,8 @@ def _flash_bwd_split(scale, causal, segment_ids, res, do, causal_offset):
             pltpu.VMEM((bk, d), jnp.float32),
         ],
         compiler_params=_dim_semantics("parallel", "parallel", "arbitrary"),
-        interpret=_interpret(),
+        interpret=not on_tpu(),
+        name="flash_bwd_dkv",
     )(*seg_args, qr, kr, vr, dor, lser, delta)
 
     dq = dq.reshape(b, h, sq, d).transpose(0, 2, 1, 3)

@@ -35,12 +35,14 @@ Two implementations with the same contract:
   attention over a static ``max_q``-wide query window (CPU oracle).
 - ``ragged_paged_attention_pallas`` — Pallas TPU kernel, grid
   ``(kvh, S, maxp)`` with pages innermost.  The k/v BlockSpec index
-  maps read the prefetched page table (one physical-page DMA per grid
-  step), ``pl.when`` skips pages past ``ctx_lens`` and whole padding
-  rows, and the online-softmax state is carried in VMEM scratch.  The
-  query window is loaded with a dynamic ``pl.ds`` slice at ``cu_q[i]``
-  and the output window is committed read-modify-write so ragged row
-  boundaries never clobber a neighbour.  Runs in interpret mode off-TPU.
+  maps read the prefetched page table (one ``[ps, hd]`` page-of-one-head
+  DMA per grid step — pages are ``[P, kvh, ps, hd]`` so that tile is a
+  whole trailing block), ``pl.when`` skips pages past ``ctx_lens`` and
+  whole padding rows, and the online-softmax state is carried in VMEM
+  scratch.  The query window is loaded with a dynamic ``pl.ds`` slice at
+  ``cu_q[i]`` and the output window is committed read-modify-write so
+  ragged row boundaries never clobber a neighbour.  Runs in interpret
+  mode off-TPU.
 
 ``max_q`` (the static query-window bound) is the scheduler's prefill
 chunk size: every row owns at most ``max_q`` query tokens.  Inputs are
@@ -54,17 +56,20 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .paged_attention import (DEFAULT_MASK_VALUE, LANES, SUBLANES, _on_tpu)
+from .paged_attention import (DEFAULT_MASK_VALUE, LANES, SUBLANES,
+                              gather_pages, vmem_params)
+from .pallas import on_tpu
 
 
 def _check_ragged_shapes(q, k_pages, v_pages, q_lens, cu_q, page_tables,
                          ctx_lens, max_q):
     t, nh, hd = q.shape
-    p_, ps, kvh, hd2 = k_pages.shape
+    p_, kvh, ps, hd2 = k_pages.shape
     if v_pages.shape != k_pages.shape:
         raise ValueError(f"k_pages {k_pages.shape} != v_pages "
                          f"{v_pages.shape}")
@@ -115,8 +120,8 @@ def ragged_paged_attention_reference(q: jax.Array, k_pages: jax.Array,
             start, qlen, ctx = cu_q[i], q_lens[i], ctx_lens[i]
             qi = lax.dynamic_slice(qp, (start, 0, 0), (max_q, nh, hd))
             qg = qi.reshape(max_q, kvh, g, hd).astype(jnp.float32)
-            k = k_pages[page_tables[i]].reshape(kk, kvh, hd)
-            v = v_pages[page_tables[i]].reshape(kk, kvh, hd)
+            k = gather_pages(k_pages, page_tables[i])     # [kk, kvh, hd]
+            v = gather_pages(v_pages, page_tables[i])
             sc = jnp.einsum("qhgd,khd->qhgk", qg,
                             k.astype(jnp.float32)) * scale
             qpos = (ctx - qlen) + jnp.arange(max_q)       # absolute pos
@@ -162,8 +167,8 @@ def _ragged_kernel(ql_ref, cu_ref, pt_ref, cl_ref,    # scalar prefetch
     def _page():
         q = q_ref[pl.ds(start, max_q), 0].astype(jnp.float32)
         q2 = q.reshape(mqg, q.shape[-1])               # [max_q*gp, hd]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)      # [ps, hd]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)            # [ps, hd]
+        v = v_ref[0, 0].astype(jnp.float32)
         s = lax.dot_general(q2, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
         row_q = lax.broadcasted_iota(jnp.int32, (mqg, ps), 0) // gp
@@ -208,15 +213,15 @@ def ragged_paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
     Grid is ``(kvh, S, maxp)`` with pages innermost (sequential on TPU);
     the query/output windows live in a full-token-axis VMEM block while
     k/v index maps read the prefetched page table so each grid step DMAs
-    exactly one physical page — pages past ``ctx_lens[i]`` and whole
-    padding rows are skipped with ``pl.when``.
+    exactly one head of one physical page — pages past ``ctx_lens[i]``
+    and whole padding rows are skipped with ``pl.when``.
     """
     t, nh, hd, ps, kvh, s = _check_ragged_shapes(
         q, k_pages, v_pages, q_lens, cu_q, page_tables, ctx_lens, max_q)
     maxp = page_tables.shape[1]
     scale = softmax_scale if softmax_scale is not None else hd ** -0.5
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
     g = nh // kvh
     gp = max(SUBLANES, ((g + SUBLANES - 1) // SUBLANES) * SUBLANES)
     t_pad = t + max_q                       # window slide never OOB
@@ -230,11 +235,11 @@ def ragged_paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
         in_specs=[
             pl.BlockSpec((t_pad, 1, gp, hd),
                          lambda h, i, p, ql, cu, pt, cl: (0, h, 0, 0)),
-            pl.BlockSpec((1, ps, 1, hd),
-                         lambda h, i, p, ql, cu, pt, cl: (pt[i, p], 0, h,
+            pl.BlockSpec((1, 1, ps, hd),
+                         lambda h, i, p, ql, cu, pt, cl: (pt[i, p], h, 0,
                                                           0)),
-            pl.BlockSpec((1, ps, 1, hd),
-                         lambda h, i, p, ql, cu, pt, cl: (pt[i, p], 0, h,
+            pl.BlockSpec((1, 1, ps, hd),
+                         lambda h, i, p, ql, cu, pt, cl: (pt[i, p], h, 0,
                                                           0)),
         ],
         out_specs=pl.BlockSpec(
@@ -251,7 +256,13 @@ def ragged_paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((t_pad, kvh, gp, hd), q.dtype),
+            compiler_params=vmem_params(
+                blocks=[((t_pad, gp, hd), q.dtype)] * 2
+                + [((ps, hd), k_pages.dtype)] * 2,
+                scratch=[((max_q * gp, LANES), jnp.float32)] * 2
+                + [((max_q * gp, hd), jnp.float32)]),
             interpret=interpret,
+            name="ragged_paged_attention",
         )(q_lens.astype(jnp.int32), cu_q.astype(jnp.int32),
           page_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
           qg, k_pages, v_pages)
@@ -264,18 +275,15 @@ def ragged_paged_attention(q: jax.Array, k_pages: jax.Array,
                            ctx_lens: jax.Array, *, max_q: int,
                            softmax_scale: Optional[float] = None,
                            use_kernel: Optional[bool] = None) -> jax.Array:
-    """Dispatching entry point: Pallas kernel on TPU, gather-dense
-    reference elsewhere (``ops.sdpa`` / ``paged_attention_decode``
-    dispatch discipline)."""
+    """Dispatching entry point: Pallas kernel on platform ``tpu``,
+    gather-dense reference elsewhere (``ops.sdpa``'s dispatch rule: the
+    platform chooses, a kernel error propagates)."""
     if use_kernel is None:
-        use_kernel = _on_tpu()
+        use_kernel = on_tpu()
     if use_kernel:
-        try:
-            return ragged_paged_attention_pallas(
-                q, k_pages, v_pages, q_lens, cu_q, page_tables, ctx_lens,
-                max_q=max_q, softmax_scale=softmax_scale)
-        except Exception:
-            pass
+        return ragged_paged_attention_pallas(
+            q, k_pages, v_pages, q_lens, cu_q, page_tables, ctx_lens,
+            max_q=max_q, softmax_scale=softmax_scale)
     return ragged_paged_attention_reference(
         q, k_pages, v_pages, q_lens, cu_q, page_tables, ctx_lens,
         max_q=max_q, softmax_scale=softmax_scale)
@@ -286,9 +294,9 @@ def ragged_paged_attention(q: jax.Array, k_pages: jax.Array,
 # ---------------------------------------------------------------------------
 #
 # The latent variants run attention directly against ONE compressed KV
-# stream per layer: ``c_pages [P, ps, 1, d_c]`` (or int8/packed-nf4
+# stream per layer: ``c_pages [P, 1, ps, d_c]`` (or int8/packed-nf4
 # codes plus a per-token absmax sidecar) and an optional decoupled-rope
-# key stream ``r_pages [P, ps, 1, d_r]``.  The query side arrives
+# key stream ``r_pages [P, 1, ps, d_r]``.  The query side arrives
 # ALREADY weight-absorbed — ``q [*, nh, d_c + d_r]`` is
 # ``concat(q_nope @ k_up, rope(q_rope))`` per head — so scores are MQA
 # dot products in latent space and the attention output STAYS latent
@@ -308,7 +316,7 @@ def _dequant_latent(codes, scales, quant, latent_dim):
 
 def _check_latent_shapes(q, c_pages, r_pages, quant, latent_dim):
     nh, dq = q.shape[-2], q.shape[-1]
-    p_, ps, one, wc = c_pages.shape
+    p_, one, ps, wc = c_pages.shape
     if one != 1:
         raise ValueError(f"latent c_pages carry ONE shared stream, got "
                          f"{c_pages.shape}")
@@ -321,7 +329,7 @@ def _check_latent_shapes(q, c_pages, r_pages, quant, latent_dim):
         raise ValueError(f"c_pages width {wc} != latent_dim {d_c}")
     d_r = 0
     if r_pages is not None and r_pages.shape[-1] > 0:
-        if r_pages.shape[:2] != (p_, ps) or r_pages.shape[2] != 1:
+        if r_pages.shape[:3] != (p_, 1, ps):
             raise ValueError(f"r_pages {r_pages.shape} incompatible with "
                              f"c_pages {c_pages.shape}")
         d_r = r_pages.shape[-1]
@@ -417,14 +425,33 @@ def latent_ragged_paged_attention_reference(
     return out[:t]
 
 
+def _nibble_order(d_c: int) -> np.ndarray:
+    """Latent column order the 4-bit kernel works in: packed byte ``j``
+    holds element ``2j`` in its high nibble and ``2j+1`` in its low one,
+    and the kernel dequantizes the two nibble planes side by side
+    (``[evens | odds]``) — Mosaic has no lane interleave to restore the
+    original order, so q's latent columns go in permuted and the latent
+    output comes back permuted."""
+    return np.concatenate([np.arange(0, d_c, 2), np.arange(1, d_c, 2)])
+
+
+def _decode4(idx, code):
+    """Codebook lookup as a select chain (Mosaic has no vector gather
+    from a table)."""
+    c = jnp.zeros(idx.shape, jnp.float32)
+    for k, val in enumerate(code):
+        c = jnp.where(idx == k, val, c)
+    return c
+
+
 def _make_latent_kernel(scale: float, ps: int, maxp: int, max_q: int,
                         gp: int, d_c: int, quant: Optional[str],
-                        has_rope: bool, has_scales: bool,
-                        has_code: bool = False):
+                        has_rope: bool, has_scales: bool, code=None):
     """Latent twin of :func:`_ragged_kernel`: grid ``(S, maxp)`` (one
     shared KV stream, so no kv-head grid dim), q/out blocks span the
     padded token axis, c/r/scale blocks are one physical page each via
-    the prefetched page table; online softmax in VMEM scratch."""
+    the prefetched page table; online softmax in VMEM scratch.  ``code``
+    is the 4-bit codebook as Python floats (packed pages only)."""
 
     def kernel(ql_ref, cu_ref, pt_ref, cl_ref, q_ref, c_ref, *rest):
         n = 0
@@ -432,8 +459,6 @@ def _make_latent_kernel(scale: float, ps: int, maxp: int, max_q: int,
         n += int(has_rope)
         s_ref = rest[n] if has_scales else None
         n += int(has_scales)
-        code_ref = rest[n] if has_code else None
-        n += int(has_code)
         o_ref, m_scr, l_scr, acc_scr = rest[n:n + 4]
         i = pl.program_id(0)
         p = pl.program_id(1)
@@ -452,22 +477,22 @@ def _make_latent_kernel(scale: float, ps: int, maxp: int, max_q: int,
         def _page():
             q = q_ref[pl.ds(start, max_q)].astype(jnp.float32)
             q2 = q.reshape(mqg, q.shape[-1])           # [mqg, d_c+d_r]
-            raw = c_ref[0, :, 0, :]                    # [ps, w]
+            raw = c_ref[0, 0]                          # [ps, w]
             if quant is None:
                 c = raw.astype(jnp.float32)
             else:
-                sc = s_ref[0, :, 0, :].astype(jnp.float32)     # [ps, 1]
+                sc = s_ref[0, 0].astype(jnp.float32)           # [ps, 1]
                 sc = jnp.where(sc > 0, sc, 1.0)
                 if quant == "int8":
                     c = raw.astype(jnp.float32) / 127.0 * sc
-                else:                                  # packed 4-bit
-                    hi = (raw >> 4).astype(jnp.int32)
-                    lo = (raw & 0xF).astype(jnp.int32)
-                    idx = jnp.stack([hi, lo], axis=-1).reshape(ps, d_c)
-                    c = code_ref[...][idx] * sc
+                else:                  # packed 4-bit, _nibble_order
+                    raw = raw.astype(jnp.int32)
+                    c = jnp.concatenate([_decode4(raw >> 4, code),
+                                         _decode4(raw & 0xF, code)],
+                                        -1) * sc
             if has_rope:
                 k = jnp.concatenate(
-                    [c, r_ref[0, :, 0, :].astype(jnp.float32)], -1)
+                    [c, r_ref[0, 0].astype(jnp.float32)], -1)
             else:
                 k = c
             s = lax.dot_general(q2, k, (((1,), (1,)), ((), ())),
@@ -515,39 +540,39 @@ def latent_ragged_paged_attention_pallas(
     s_rows = q_lens.shape[0]
     maxp = page_tables.shape[1]
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
     gp = max(SUBLANES, ((nh + SUBLANES - 1) // SUBLANES) * SUBLANES)
     t_pad = t + max_q
-    qg = jnp.pad(q, ((0, max_q), (0, gp - nh), (0, 0)))
     has_rope, has_scales = d_r > 0, scale_pages is not None
     if quant is not None and not has_scales:
         raise ValueError("quantized latent pages need scale_pages")
-    has_code = quant in ("nf4", "fp4")
+    code = order = None
+    if quant in ("nf4", "fp4"):
+        from .quantization import _CODES
+        code = tuple(float(c) for c in _CODES[quant])
+        order = _nibble_order(d_c)
+        q = jnp.concatenate([q[..., :d_c][..., order], q[..., d_c:]], -1)
+    qg = jnp.pad(q, ((0, max_q), (0, gp - nh), (0, 0)))
     kernel = _make_latent_kernel(float(softmax_scale), ps, maxp,
                                  int(max_q), gp, d_c, quant, has_rope,
-                                 has_scales, has_code)
+                                 has_scales, code)
     in_specs = [
         pl.BlockSpec((t_pad, gp, d_c + d_r),
                      lambda i, p, ql, cu, pt, cl: (0, 0, 0)),
-        pl.BlockSpec((1, ps, 1, c_pages.shape[-1]),
+        pl.BlockSpec((1, 1, ps, c_pages.shape[-1]),
                      lambda i, p, ql, cu, pt, cl: (pt[i, p], 0, 0, 0)),
     ]
     operands = [qg, c_pages]
     if has_rope:
         in_specs.append(pl.BlockSpec(
-            (1, ps, 1, d_r),
+            (1, 1, ps, d_r),
             lambda i, p, ql, cu, pt, cl: (pt[i, p], 0, 0, 0)))
         operands.append(r_pages)
     if has_scales:
         in_specs.append(pl.BlockSpec(
-            (1, ps, 1, 1),
+            (1, 1, ps, 1),
             lambda i, p, ql, cu, pt, cl: (pt[i, p], 0, 0, 0)))
         operands.append(scale_pages)
-    if has_code:
-        from .quantization import _CODES
-        in_specs.append(pl.BlockSpec(
-            (16,), lambda i, p, ql, cu, pt, cl: (0,)))
-        operands.append(jnp.asarray(_CODES[quant]))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(s_rows, maxp),
@@ -565,11 +590,19 @@ def latent_ragged_paged_attention_pallas(
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((t_pad, gp, d_c), jnp.float32),
+            compiler_params=vmem_params(
+                blocks=[((t_pad, gp, d_c + d_r), q.dtype),
+                        ((t_pad, gp, d_c), jnp.float32)]
+                + [(o.shape[2:], o.dtype) for o in operands[1:]],
+                scratch=[((max_q * gp, LANES), jnp.float32)] * 2
+                + [((max_q * gp, d_c), jnp.float32)]),
             interpret=interpret,
+            name="latent_ragged_paged_attention",
         )(q_lens.astype(jnp.int32), cu_q.astype(jnp.int32),
           page_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
           *operands)
-    return out[:t, :nh, :]
+    out = out[:t, :nh, :]
+    return out if order is None else out[..., np.argsort(order)]
 
 
 def latent_ragged_paged_attention(
@@ -579,19 +612,16 @@ def latent_ragged_paged_attention(
         scale_pages: Optional[jax.Array] = None,
         quant: Optional[str] = None, latent_dim: Optional[int] = None,
         use_kernel: Optional[bool] = None) -> jax.Array:
-    """Dispatching entry point for the latent path (kernel on TPU,
-    gather-dense oracle elsewhere)."""
+    """Dispatching entry point for the latent path (kernel on platform
+    ``tpu``, gather-dense oracle elsewhere; a kernel error propagates)."""
     if use_kernel is None:
-        use_kernel = _on_tpu()
+        use_kernel = on_tpu()
     if use_kernel:
-        try:
-            return latent_ragged_paged_attention_pallas(
-                q, c_pages, r_pages, q_lens, cu_q, page_tables, ctx_lens,
-                max_q=max_q, softmax_scale=softmax_scale,
-                scale_pages=scale_pages, quant=quant,
-                latent_dim=latent_dim)
-        except Exception:
-            pass
+        return latent_ragged_paged_attention_pallas(
+            q, c_pages, r_pages, q_lens, cu_q, page_tables, ctx_lens,
+            max_q=max_q, softmax_scale=softmax_scale,
+            scale_pages=scale_pages, quant=quant,
+            latent_dim=latent_dim)
     return latent_ragged_paged_attention_reference(
         q, c_pages, r_pages, q_lens, cu_q, page_tables, ctx_lens,
         max_q=max_q, softmax_scale=softmax_scale, scale_pages=scale_pages,

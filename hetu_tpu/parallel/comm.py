@@ -40,33 +40,14 @@ from jax import lax
 
 def shard_map(f, mesh, in_specs, out_specs, check_rep: bool = False,
               axis_names=None):
-    """Version-stable shard_map wrapper.
-
-    jax>=0.8 exposes ``jax.shard_map`` (check_rep renamed to check_vma,
-    partial-manual via ``axis_names``); older jax has
-    ``jax.experimental.shard_map.shard_map`` (check_rep, partial-manual
-    via the complementary ``auto`` set).  ``axis_names``, when given,
-    restricts manual mode to those mesh axes.
-    """
-    if hasattr(jax, "shard_map"):
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_rep, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    if axis_names is not None and \
-            frozenset(axis_names) != frozenset(mesh.axis_names):
-        # old-jax auto= lowering is broken: even trivial partial-manual
-        # programs die in XLA with `Check failed: IsManualSubgroup()`
-        # (spmd_partitioner.cc:512 on jaxlib 0.4.36).  Raise cleanly
-        # instead of letting the compile abort the process.
-        raise NotImplementedError(
-            "partial-manual shard_map (axis_names a proper subset of the "
-            "mesh axes) requires jax>=0.8; this jax's auto= lowering "
-            "hits an XLA IsManualSubgroup check failure")
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_rep)
+    """``jax.shard_map`` under the argument names this package grew up
+    with: ``check_rep`` is jax's ``check_vma``; ``axis_names``, when
+    given, restricts manual mode to those mesh axes (partial-manual)."""
+    kw = {}
+    if axis_names is not None:
+        kw["axis_names"] = set(axis_names)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_rep, **kw)
 
 
 def _operand_bytes(x) -> int:

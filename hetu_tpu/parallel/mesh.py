@@ -52,14 +52,15 @@ def create_mesh(shape: Dict[str, int],
     sizes = tuple(int(shape[n]) for n in names)
     n = int(np.prod(sizes)) if sizes else 1
     if devices is None:
-        try:
-            # Topology-aware assignment on real TPU slices.
+        devices = jax.devices()
+        if devices[0].platform == "tpu" and len(devices) == n:
+            # topology-aware assignment over a whole TPU slice; virtual
+            # CPU devices and sub-slices take enumeration order below
             from jax.experimental import mesh_utils
             dev_array = mesh_utils.create_device_mesh(
-                sizes, allow_split_physical_axes=allow_split_physical_axes)
+                sizes, devices,
+                allow_split_physical_axes=allow_split_physical_axes)
             return Mesh(dev_array, names)
-        except Exception:
-            devices = jax.devices()[:n]
     if len(devices) < n:
         raise ValueError(f"need {n} devices for mesh {shape}, got {len(devices)}")
     dev_array = np.asarray(devices[:n]).reshape(sizes)

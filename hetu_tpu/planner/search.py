@@ -137,14 +137,11 @@ def hand_plan_times(cfg, global_batch: int, seq: int, n_chips: int,
     sweep (its best possible showing), so beating it means beating the
     layout, not a strawman.  Infeasible layouts (don't fit HBM, don't
     divide the chip grid) are omitted from the result."""
-    import jax
-    from .cost_model import (CHIPS, ChipSpec, calibrate_layer_time)
-    from .profile_hardware import _kind_key
+    from .cost_model import calibrate_layer_time
+    from .profile_hardware import local_chip
 
     if cluster is None:
-        kind = getattr(jax.devices()[0], "device_kind", "")
-        cluster = ClusterSpec(chip=CHIPS.get(_kind_key(kind), ChipSpec()),
-                              num_chips=n_chips)
+        cluster = ClusterSpec(chip=local_chip(), num_chips=n_chips)
     dtype_bytes = 2 if "bf16" in str(cfg.dtype) or "bfloat16" in \
         str(cfg.dtype) else 4
     if time_calibration == "auto":
@@ -214,16 +211,13 @@ def plan_for_gpt(cfg, global_batch: int, seq: int, n_chips: int,
     :class:`~hetu_tpu.planner.cost_model.TimeCalibration` to reuse a
     measurement, or ``None`` for the uncalibrated closed form.
     """
-    import jax
-    from .cost_model import (CHIPS, ChipSpec, calibrate_layer_memory,
-                             calibrate_layer_time)
-    from .profile_hardware import _kind_key
+    from .cost_model import calibrate_layer_memory, calibrate_layer_time
+    from .profile_hardware import local_chip
 
     if calibration is not None:
         chip = calibration.to_chip_spec()
     else:
-        kind = getattr(jax.devices()[0], "device_kind", "")
-        chip = CHIPS.get(_kind_key(kind), ChipSpec())
+        chip = local_chip()
     cluster = ClusterSpec(chip=chip, num_chips=max(1, n_chips // num_slices),
                           num_slices=num_slices)
     if calibration is not None and getattr(calibration, "collectives",

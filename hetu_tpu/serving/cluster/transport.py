@@ -88,7 +88,7 @@ class LocalPageTransport(PageTransport):
     def extract(self, src_pool: PagedKVPool,
                 src_pages: Sequence[int]) -> Dict[str, Any]:
         """Pull ``src_pages`` off the source pool into host staging
-        buffers (one ``[n, page, kvh, hd]`` array per layer per k/v).
+        buffers (one ``[n, kvh, page, hd]`` array per layer per k/v).
         ``np.asarray`` forces the device values — the staging copy is
         taken NOW, so the source engine may free/retire the pages the
         moment this returns."""

@@ -22,7 +22,7 @@ over a fixed-shape **ragged token batch** (DESIGN.md §12):
   scatter-writes each token's k/v into its page at ``(token_page,
   token_off)`` (padding tokens land in the trash page), and attends
   raggedly: the Pallas kernel on TPU, or — off TPU — a split dense
-  fallback whose decode half IS ``paged_attention_reference`` (the
+  reference whose decode half IS ``paged_attention_reference`` (the
   bit-for-bit-proven v1 decode math) and whose chunk half is the same
   gather+masked-dense attention with a causal in-row mask;
 - sampling is ON DEVICE for every mode: greedy argmax (bit-for-bit the
@@ -44,7 +44,7 @@ from jax import lax
 from ..models.generate import (_act, _lm_head, _moe_mlp, _norm_apply,
                                _Params, _rotary_tables)
 from ..models.gpt import GPTConfig
-from ..ops.paged_attention import paged_attention_reference
+from ..ops.paged_attention import gather_pages, paged_attention_reference
 from ..ops.quantization import quantize_rows
 from ..ops.ragged_paged_attention import (_dequant_latent,
                                           latent_paged_attention_reference,
@@ -113,7 +113,7 @@ def _split_ragged_attention(cfg: GPTConfig, q, kp, vp, q_lens,
     hd, nh, kvh = c.head_dim, c.num_heads, c.kv_heads
     g = nh // kvh
     maxp = page_tables.shape[1]
-    ps = kp.shape[1]
+    ps = kp.shape[2]
     scale = hd ** -0.5
     # decode slots: [S] one-token rows (v1 math, bitwise-proven)
     outs = [paged_attention_reference(
@@ -148,8 +148,8 @@ def _split_ragged_attention(cfg: GPTConfig, q, kp, vp, q_lens,
         def attn(qc, pt_row, ctx, qlen):
             width = npages * ps
             qg = qc.reshape(width_q, kvh, g, hd).astype(jnp.float32)
-            k = kp[pt_row[:npages]].reshape(width, kvh, hd)
-            v = vp[pt_row[:npages]].reshape(width, kvh, hd)
+            k = gather_pages(kp, pt_row[:npages])      # [width, kvh, hd]
+            v = gather_pages(vp, pt_row[:npages])
             s = jnp.einsum("qhgd,khd->qhgk", qg,
                            k.astype(jnp.float32)) * scale
             qpos = (ctx - qlen) + jnp.arange(width_q)
@@ -195,7 +195,7 @@ def _split_latent_ragged_attention(cfg: GPTConfig, q_cat, cp, rp, q_lens,
     hd, nh = c.head_dim, c.num_heads
     d_c, d_r = c.kv_latent_dim, c.rope_dim
     maxp = page_tables.shape[1]
-    ps = cp.shape[1]
+    ps = cp.shape[2]
     scale = (hd + d_r) ** -0.5
     outs = [latent_paged_attention_reference(
         q_cat[:max_seqs], cp, rp, page_tables[:max_seqs],
@@ -407,17 +407,17 @@ def build_unified_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
                 with jax.named_scope("kv_page_scatter"):
                     if page_quant:
                         codes, am = quantize_rows(c_kv, page_quant)
-                        kp = k_pages[i].at[token_page, token_off].set(
-                            codes[:, None, :])
-                        vp = v_pages[i].at[token_page, token_off].set(
-                            am[:, None, :])
+                        kp = k_pages[i].at[token_page, 0, token_off].set(
+                            codes)
+                        vp = v_pages[i].at[token_page, 0, token_off].set(
+                            am)
                     else:
-                        kp = k_pages[i].at[token_page, token_off].set(
-                            c_kv[:, None, :].astype(cdt))
+                        kp = k_pages[i].at[token_page, 0, token_off].set(
+                            c_kv.astype(cdt))
                         if d_r:
                             vp = v_pages[i].at[
-                                token_page, token_off].set(
-                                k_rope[:, None, :].astype(cdt))
+                                token_page, 0, token_off].set(
+                                k_rope.astype(cdt))
                         else:
                             vp = v_pages[i]        # width-0 rope stream
                 rp = None if (page_quant or not d_r) else vp
@@ -456,10 +456,16 @@ def build_unified_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
                     q = _rope_tok(q, cos[token_pos], sin[token_pos])
                     k = _rope_tok(k, cos[token_pos], sin[token_pos])
                 with jax.named_scope("kv_page_scatter"):
-                    kp = k_pages[i].at[token_page, token_off].set(
-                        k.astype(cdt))
-                    vp = v_pages[i].at[token_page, token_off].set(
-                        v.astype(cdt))
+                    # one [hd] row per (page, head, offset) index: the
+                    # written window is the pages' minor dim, so at a
+                    # head_dim that fills the 128 lanes XLA scatters in
+                    # place — a [kvh, hd] window per token makes it
+                    # re-lay the whole pool out and back (as does a
+                    # 64-wide head either way: CHANGES.md, PR 21)
+                    at = (token_page[:, None], jnp.arange(nkv)[None, :],
+                          token_off[:, None])
+                    kp = k_pages[i].at[at].set(k.astype(cdt))
+                    vp = v_pages[i].at[at].set(v.astype(cdt))
                 if use_kernel:
                     attn = ragged_paged_attention_pallas(
                         q, kp, vp, q_lens, cu_q, page_tables, ctx_lens,

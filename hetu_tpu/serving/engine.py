@@ -77,6 +77,7 @@ import numpy as np
 from ..models.generate import _Params
 from ..models.gpt import GPTConfig
 from ..obs.tracer import get_tracer
+from ..ops.pallas import on_tpu
 from ..utils.metrics import make_instrument, render_prometheus
 from .decode import build_unified_step_fn
 from .kv_pool import TRASH_PAGE, PagedKVPool, protocol_seq
@@ -96,7 +97,7 @@ class Engine:
                  num_pages: int = 64, page_size: int = 64,
                  max_batch: int = 8, max_model_len: Optional[int] = None,
                  chunk_size: Optional[int] = 64, prefill_rows: int = 1,
-                 mesh=None, use_kernel: bool = False,
+                 mesh=None, use_kernel: Optional[bool] = None,
                  metrics: bool = True,
                  latency_buckets: Optional[Sequence[float]] = None,
                  time_fn: Optional[Callable[[], float]] = None,
@@ -170,7 +171,10 @@ class Engine:
                                    chunk=chunk,
                                    prefill_rows=prefill_rows,
                                    prefix_cache=self.prefix_cache)
-        self.use_kernel = bool(use_kernel)
+        # kernel or gather-dense reference: chosen from the platform,
+        # exactly as the ops' own dispatchers choose (ops/pallas)
+        self.use_kernel = on_tpu() if use_kernel is None \
+            else bool(use_kernel)
         self.queue = RequestQueue()
         self.running: List[Request] = []
         self.finished: Dict[int, Request] = {}

@@ -14,10 +14,12 @@ jitted prefill/decode programs can scatter-write unconditionally with
 static shapes — writes land in the trash page, reads past ``seq_len``
 are masked by the attention op.  It is never allocated.
 
-Sharding: pages are ``[num_pages, page_size, kv_heads, head_dim]`` —
-the same ``kv_heads`` axis the training stack splits across ``tp``
+Layout: pages are ``[num_pages, kv_heads, page_size, head_dim]`` — the
+trailing ``(page_size, head_dim)`` tile is what the Pallas kernels DMA
+per grid step (``ops/paged_attention.py`` layout notes).  ``kv_heads``
+is the same axis the training stack splits across ``tp``
 (nn/parallel.py column-parallel QKV), so a pool built with a mesh
-shards pages ``P(None, None, 'tp', None)`` and the decode executable's
+shards pages ``P(None, 'tp', None, None)`` and the decode executable's
 per-shard pages line up with the per-shard QKV projections.
 
 Pages live in one of THREE states (``serving/prefix_cache.py`` adds
@@ -57,7 +59,7 @@ _QUANT_CODES = {None: 0, "int8": 1, "nf4": 2}
 
 
 def page_shape_bytes(shape: Sequence[int], dtype) -> int:
-    """Bytes ONE page of a per-layer page array ``[P, ps, h, w]``
+    """Bytes ONE page of a per-layer page array ``[P, h, ps, w]``
     occupies (i.e. everything but the leading page axis).  The single
     source of truth for KV page sizing: ``PagedKVPool.page_bytes``,
     ``PageTransport`` handoff pricing, engine metrics, and the
@@ -76,14 +78,14 @@ class PagedKVPool:
     Two layouts share every allocator/bookkeeping path:
 
     - **full-head** (default): k and v pages are both
-      ``[P, ps, kv_heads, head_dim]``.
+      ``[P, kv_heads, ps, head_dim]``.
     - **latent** (MLA, ``latent_dim`` set): k_pages hold ONE compressed
-      stream ``[P, ps, 1, latent_dim]`` and v_pages carry the decoupled
-      rotated key ``[P, ps, 1, rope_dim]`` (width 0 for learned
+      stream ``[P, 1, ps, latent_dim]`` and v_pages carry the decoupled
+      rotated key ``[P, 1, ps, rope_dim]`` (width 0 for learned
       positions).  With ``quant`` set (int8/nf4, learned-position MLA
       only), k_pages store codes (int8, or packed uint8 at
       ``latent_dim // 2``) and v_pages become the per-token fp32 absmax
-      sidecar ``[P, ps, 1, 1]``.
+      sidecar ``[P, 1, ps, 1]``.
 
     Page-table math, the allocator, CoW refcounts, and the prefix cache
     never look inside a page, so they compose with any layout; only
@@ -122,20 +124,20 @@ class PagedKVPool:
         self.quant = quant
         if latent_dim is not None:
             if quant == "int8":
-                k_shape = (num_pages, page_size, 1, self.latent_dim)
+                k_shape = (num_pages, 1, page_size, self.latent_dim)
                 k_dtype = jnp.dtype(jnp.int8)
             elif quant == "nf4":
-                k_shape = (num_pages, page_size, 1, self.latent_dim // 2)
+                k_shape = (num_pages, 1, page_size, self.latent_dim // 2)
                 k_dtype = jnp.dtype(jnp.uint8)
             else:
-                k_shape = (num_pages, page_size, 1, self.latent_dim)
+                k_shape = (num_pages, 1, page_size, self.latent_dim)
                 k_dtype = self.dtype
             # rope stream, or the per-token absmax sidecar when quantized
             v_w = 1 if quant else self.rope_dim
-            v_shape = (num_pages, page_size, 1, v_w)
+            v_shape = (num_pages, 1, page_size, v_w)
             v_dtype = jnp.dtype(jnp.float32) if quant else self.dtype
         else:
-            k_shape = v_shape = (num_pages, page_size, kv_heads, head_dim)
+            k_shape = v_shape = (num_pages, kv_heads, page_size, head_dim)
             k_dtype = v_dtype = self.dtype
         self.sharding = None
         if mesh is not None and kv_axis in getattr(mesh, "axis_names", ()):
@@ -144,7 +146,7 @@ class PagedKVPool:
             # the latent stream has no head axis to split — replicate
             if latent_dim is None and kv_heads % tp == 0:
                 self.sharding = NamedSharding(
-                    mesh, P(None, None, kv_axis, None))
+                    mesh, P(None, kv_axis, None, None))
 
         def make(shape, dt):
             z = jnp.zeros(shape, dt)

@@ -1,0 +1,34 @@
+"""JAX's persistent compilation cache, placed from outside the program.
+
+Entry points (``chip_smoke.py``, ``bench.py``, ``examples/*.py``) call
+:func:`enable_compile_cache` before their first compile.  Where
+``JAX_COMPILATION_CACHE_DIR`` is set JAX already keeps its cache there
+and nothing is set in code.  Otherwise the cache lives in
+``<checkout>/.jax_cache`` (git-ignored), derived from this file's
+location: the directory is part of every cache key, so it must be the
+same path on every run — never a temp dir, a pid or a timestamp.
+"""
+from __future__ import annotations
+
+import os
+
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def enable_compile_cache() -> str:
+    """Returns the cache directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def cache_entries(path: str) -> int:
+    """Executables the cache directory holds (0 when it does not exist)."""
+    if not os.path.isdir(path):
+        return 0
+    return sum(1 for name in os.listdir(path) if name.endswith("-cache"))

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 mesh = Mesh(np.array(jax.devices()[:8]), ("dp",))
 
@@ -41,7 +41,7 @@ def f(x, y):
 
 
 sm = shard_map(f, mesh=mesh, in_specs=(P(), P()),
-               out_specs=(P(), P(), P(None), P(), P()), check_rep=False)
+               out_specs=(P(), P(), P(None), P(), P()), check_vma=False)
 cj = jax.make_jaxpr(sm)(np.ones((64,), np.float32),
                         np.ones((4,), np.float32))
 (smeqn,) = [e for e in cj.jaxpr.eqns if e.primitive.name == "shard_map"]

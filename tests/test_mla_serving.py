@@ -501,14 +501,14 @@ def test_latent_kernel_matches_reference(quant, d_r):
     cu[1:] = np.cumsum(q_lens)
     t = int(cu[-1])
     q = jnp.asarray(rng.randn(t, nh, d_c + d_r), jnp.float32)
-    lat = rng.randn(num_pages, ps, 1, d_c).astype(np.float32)
+    lat = rng.randn(num_pages, 1, ps, d_c).astype(np.float32)
     scale_pages = None
     if quant:
         codes, absmax = quantize_rows(jnp.asarray(lat), quant)
         c_pages, scale_pages = codes, absmax
     else:
         c_pages = jnp.asarray(lat)
-    r_pages = jnp.asarray(rng.randn(num_pages, ps, 1, d_r),
+    r_pages = jnp.asarray(rng.randn(num_pages, 1, ps, d_r),
                           jnp.float32) if d_r else None
     perm = rng.permutation(np.arange(1, num_pages))
     pt = np.zeros((s, maxp), np.int32)
@@ -559,4 +559,4 @@ def test_kv_byte_gauges_and_analysis_shapes(mla):
     q8 = PagedKVPool(num_layers=1, num_pages=4, page_size=4,
                      kv_heads=2, head_dim=4, latent_dim=8, quant="int8")
     shapes = _kv_page_shapes({"pool": q8})
-    assert (4, 4, 1, 8) in shapes and (4, 4, 1, 1) in shapes
+    assert (4, 1, 4, 8) in shapes and (4, 1, 4, 1) in shapes

@@ -115,7 +115,7 @@ def test_pool_tp_sharding_spec(devices8):
                        kv_heads=4, head_dim=8, mesh=mesh)
     assert pool.sharding is not None
     spec = pool.sharding.spec
-    assert tuple(spec) == (None, None, "tp", None)
+    assert tuple(spec) == (None, "tp", None, None)
     assert pool.k_pages[0].sharding == pool.sharding
 
 
@@ -126,10 +126,10 @@ def test_pool_tp_sharding_spec(devices8):
 def _scatter_dense_to_pages(k_dense, page_table, ps, num_pages):
     """[B, S, kvh, hd] dense -> pages, via each request's page table."""
     b, s, kvh, hd = k_dense.shape
-    pages = np.zeros((num_pages, ps, kvh, hd), k_dense.dtype)
+    pages = np.zeros((num_pages, kvh, ps, hd), k_dense.dtype)
     for bi in range(b):
         for t in range(s):
-            pages[page_table[bi, t // ps], t % ps] = k_dense[bi, t]
+            pages[page_table[bi, t // ps], :, t % ps] = k_dense[bi, t]
     return pages
 
 
@@ -177,8 +177,8 @@ def test_paged_attention_pallas_matches_reference():
     rng = np.random.RandomState(1)
     B, nh, kvh, hd, ps, num_pages, maxp = 2, 4, 2, 32, 8, 10, 4
     q = jnp.asarray(rng.randn(B, nh, hd), jnp.float32)
-    kp = jnp.asarray(rng.randn(num_pages, ps, kvh, hd), jnp.float32)
-    vp = jnp.asarray(rng.randn(num_pages, ps, kvh, hd), jnp.float32)
+    kp = jnp.asarray(rng.randn(num_pages, kvh, ps, hd), jnp.float32)
+    vp = jnp.asarray(rng.randn(num_pages, kvh, ps, hd), jnp.float32)
     pt = jnp.asarray([[3, 1, 8, 0], [5, 0, 0, 0]], jnp.int32)
     sl = jnp.asarray([19, 8], jnp.int32)
     ref = paged_attention_reference(q, kp, vp, pt, sl)
@@ -189,7 +189,7 @@ def test_paged_attention_pallas_matches_reference():
 
 def test_paged_attention_rejects_bad_shapes():
     q = jnp.zeros((2, 4, 16))
-    kp = jnp.zeros((4, 8, 2, 16))
+    kp = jnp.zeros((4, 2, 8, 16))
     pt = jnp.zeros((2, 2), jnp.int32)
     sl = jnp.zeros((2,), jnp.int32)
     with pytest.raises(ValueError, match="head_dim"):

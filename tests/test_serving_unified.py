@@ -90,8 +90,8 @@ def test_ragged_kernel_matches_reference(q_lens, ctx_lens, maxp, ps):
     cu[1:] = np.cumsum(q_lens)
     t = max(int(cu[-1]), 1)
     q = jnp.asarray(rng.randn(t, nh, hd), jnp.float32)
-    kp = jnp.asarray(rng.randn(num_pages, ps, kvh, hd), jnp.float32)
-    vp = jnp.asarray(rng.randn(num_pages, ps, kvh, hd), jnp.float32)
+    kp = jnp.asarray(rng.randn(num_pages, kvh, ps, hd), jnp.float32)
+    vp = jnp.asarray(rng.randn(num_pages, kvh, ps, hd), jnp.float32)
     # non-contiguous per-row page ids; padding slots -> trash page 0
     perm = rng.permutation(np.arange(1, num_pages))
     pt = np.zeros((s, maxp), np.int32)
@@ -125,16 +125,16 @@ def test_ragged_reference_matches_per_token_oracle():
     pt = np.asarray([[3, 6, 0], [2, 0, 0], [8, 0, 0]], np.int32)
     t = 7
     q = rng.randn(t, nh, hd).astype(np.float32)
-    kp = rng.randn(num_pages, ps, kvh, hd).astype(np.float32)
-    vp = rng.randn(num_pages, ps, kvh, hd).astype(np.float32)
+    kp = rng.randn(num_pages, kvh, ps, hd).astype(np.float32)
+    vp = rng.randn(num_pages, kvh, ps, hd).astype(np.float32)
     got = np.asarray(ragged_paged_attention_reference(
         jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
         jnp.asarray(q_lens), jnp.asarray(cu), jnp.asarray(pt),
         jnp.asarray(ctx_lens), max_q=max_q))
     g = nh // kvh
     for i in range(3):
-        k = kp[pt[i]].reshape(-1, kvh, hd)
-        v = vp[pt[i]].reshape(-1, kvh, hd)
+        k = kp[pt[i]].swapaxes(1, 2).reshape(-1, kvh, hd)
+        v = vp[pt[i]].swapaxes(1, 2).reshape(-1, kvh, hd)
         for j in range(int(q_lens[i])):
             pos = int(ctx_lens[i]) - int(q_lens[i]) + j
             kk = np.repeat(k[:pos + 1], g, axis=1)
