@@ -36,6 +36,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..core.dtype import canonicalize_dtype
+from ..obs.phases import current_phase, phase
 from ..obs.tracer import get_tracer
 from .tensor import SymbolicDim, Tensor, concrete_shape
 
@@ -144,6 +145,16 @@ def clear_executables(prefix: str = "") -> None:
     clear_prediction_cache(prefix)
 
 
+def _abstract_of(a) -> jax.ShapeDtypeStruct:
+    """The abstract spec of one executable argument."""
+    if not hasattr(a, "aval"):
+        return jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype)
+    sharding = getattr(a, "sharding", None)
+    if not isinstance(sharding, NamedSharding):
+        sharding = None            # uncommitted / single-device: free
+    return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+
+
 def _select_tree(flag, new, old):
     """Per-leaf ``jnp.where(flag, new, old)`` over matching pytrees —
     the on-device skip primitive the AMP scaler (overflow) and the
@@ -223,6 +234,11 @@ class Graph:
                 name: str = "", num_outputs: int = 1) -> Union[Tensor, List[Tensor]]:
         attrs = dict(attrs or {})
         in_tensors = [self.as_tensor(x) for x in inputs]
+        ph = current_phase()
+        if ph is not None:
+            # the model phase the node was built under (obs/phases.py):
+            # entered again around impl when the plan is traced
+            attrs["_phase"] = ph
         node = OpNode(op_type, impl, in_tensors, attrs, name)
         # shape/dtype inference via abstract evaluation (replaces the
         # reference's per-op DoInferMeta, operator.h:423).  Unbound symbolic
@@ -408,7 +424,14 @@ class Graph:
                 args = [env[t.id] for t in node.inputs]
                 attrs = {k: v for k, v in node.attrs.items()
                          if not k.startswith("_")}
-                out = node.impl(*args, **attrs)
+                ph = node.attrs.get("_phase")
+                if ph is None:
+                    out = node.impl(*args, **attrs)
+                else:
+                    # metadata only: the phase reaches the HLO op_name
+                    # of the node's instructions, forward and backward
+                    with jax.named_scope(ph):
+                        out = node.impl(*args, **attrs)
                 flat = jax.tree_util.tree_leaves(out)
                 for t, v in zip(node.outputs, flat):
                     spec = self._pspec_for(t)
@@ -1229,8 +1252,10 @@ class DefineAndRunGraph(Graph):
                 # identity on a clean step)
                 code = jnp.reshape(feeds_mb[sentry_tid], (-1,))[0]
                 acc_grads = sentry.inject_grads(acc_grads, code)
-            new_vars, new_opt = opt._apply_updates(
-                var_state, opt_core, acc_grads, update_node.attrs["xs"])
+            with phase("optimizer"):
+                new_vars, new_opt = opt._apply_updates(
+                    var_state, opt_core, acc_grads,
+                    update_node.attrs["xs"])
             if scaler is not None:
                 # skip the update (params AND optimizer state) on overflow,
                 # then grow/backoff the scale (reference update_scale op)
@@ -1632,10 +1657,6 @@ class DefineAndRunGraph(Graph):
         if run_level == RunLevel.TOPO:
             return self._topo_from([f for f in fetches if isinstance(f, Tensor)])
 
-        if self._shape_buckets is not None:
-            feed_dict = self._bucket_feeds(feed_dict)
-        self._bind_symbolic_dims(feed_dict)
-
         # find update node among fetches (optimizer.minimize output);
         # remember its positions so returned values align with fetches
         update_node = None
@@ -1650,6 +1671,38 @@ class DefineAndRunGraph(Graph):
                 real_fetches.append(f)
         if run_level in (RunLevel.COMPUTE_ONLY, RunLevel.ALLOC):
             update_node = None
+
+        # trace plane (hetu_tpu/obs): per-step phase spans on the
+        # "train" track — plan lookup, feed marshalling, state
+        # assembly, the executable call, state commit — nested under
+        # one step span.  NULL tracer: all guards read False and
+        # nothing below allocates.  The try/finally closes the step
+        # span even when the body raises (ending the outermost span
+        # pops-and-discards any open children), so a caught-and-retried
+        # failing step never corrupts the per-thread nesting stack.
+        tr = get_tracer()
+        step_sp = tr.begin(
+            "train_step" if update_node is not None else "forward",
+            track="train", run_level=run_level.value,
+            strategy=self.cur_strategy_id) if tr.enabled else None
+        try:
+            return self._run_step(tr, run_level, update_node, real_fetches,
+                                  update_positions, feed_dict,
+                                  num_micro_batches)
+        finally:
+            if step_sp is not None:
+                tr.end(step_sp)
+
+    def _run_step(self, tr, run_level, update_node, real_fetches,
+                  update_positions, feed_dict, num_micro_batches):
+        """:meth:`run` under its step span: find (or build) the plan,
+        then run it."""
+        # bucketed feeds, symbolic dims, variables, the plan key and its
+        # pool lookup — and, on a plan's first run, the trace + compile
+        plan_sp = tr.begin("plan", track="train") if tr.enabled else None
+        if self._shape_buckets is not None:
+            feed_dict = self._bucket_feeds(feed_dict)
+        self._bind_symbolic_dims(feed_dict)
 
         # materialize variables (ALLOC)
         for t in self._var_tensors.values():
@@ -1670,35 +1723,17 @@ class DefineAndRunGraph(Graph):
         self._grad_comm_active, self._grad_comm_fallback = gc_state
         self._last_plan = jit_step  # for cost_analysis()
         self._last_plan_key = key
-
-        # trace plane (hetu_tpu/obs): per-step phase spans on the
-        # "train" track — feed marshalling, the executable call, state
-        # commit — nested under one step span.  NULL tracer: all guards
-        # read False and nothing below allocates.  The try/finally
-        # closes the step span even when the body raises (ending the
-        # outermost span pops-and-discards any open children), so a
-        # caught-and-retried failing step never corrupts the
-        # per-thread nesting stack.
-        tr = get_tracer()
-        step_sp = tr.begin(
-            "train_step" if update_node is not None else "forward",
-            track="train", run_level=run_level.value,
-            strategy=self.cur_strategy_id) if tr.enabled else None
-        try:
-            return self._run_plan(tr, key, jit_step, gc_state, flat_mode,
-                                  update_node, real_fetches,
-                                  update_positions, feed_dict,
-                                  num_micro_batches)
-        finally:
-            if step_sp is not None:
-                tr.end(step_sp)
+        if plan_sp is not None:
+            tr.end(plan_sp)
+        return self._run_plan(tr, key, jit_step, gc_state, flat_mode,
+                              update_node, real_fetches, update_positions,
+                              feed_dict, num_micro_batches)
 
     def _run_plan(self, tr, key, jit_step, gc_state, flat_mode,
                   update_node, real_fetches, update_positions, feed_dict,
                   num_micro_batches):
         """The per-run tail of :meth:`run`: feed marshalling, state
-        assembly, registration, the executable call, and state commit —
-        split out so the step span wraps it in one try/finally."""
+        assembly, registration, the executable call, and state commit."""
         feed_sp = tr.begin("feed", track="train") if tr.enabled else None
         feeds = {}
         for t, v in feed_dict.items():
@@ -1721,6 +1756,10 @@ class DefineAndRunGraph(Graph):
         if feed_sp is not None:
             tr.end(feed_sp, n_feeds=len(feed_dict),
                    micro_batches=num_micro_batches)
+        # state dicts, flat optimizer state, plan registration: host
+        # work between the feeds and the call
+        asm_sp = tr.begin("assemble", track="train") if tr.enabled \
+            else None
 
         # ZeRO-3 flat leaves per-param working copies stale between
         # update steps (the flat master is authoritative); any OTHER
@@ -1763,25 +1802,27 @@ class DefineAndRunGraph(Graph):
         grad_accum = dict(self._grad_accum)
 
         if key not in self._abstract_pool:
-            # arg specs for cost_analysis(); shapes are invariant per plan
-            # key, so this traversal runs once per compiled plan
+            # arg specs for cost_analysis() and the registered handle;
+            # shapes are invariant per plan key, so this traversal runs
+            # once per compiled plan.  Mesh-placed arrays keep their
+            # sharding: the handle then compiles the program that RAN
+            # (obs.device_phases joins its instruction names against
+            # the device trace), not a replicated-input variant of it
             self._abstract_pool[key] = jax.tree_util.tree_map(
-                lambda a: jax.ShapeDtypeStruct(
-                    np.shape(a), np.asarray(a).dtype)
-                if not hasattr(a, "aval") else
-                jax.ShapeDtypeStruct(a.shape, a.dtype),
-                (var_state, opt_state, grad_accum, feeds_mb))
+                _abstract_of, (var_state, opt_state, grad_accum, feeds_mb))
         self._register_plan_for_analysis(key, jit_step, gc_state,
                                          update_node, real_fetches,
                                          num_micro_batches,
                                          flat_mode=flat_mode)
         exec_sp = None
         if tr.enabled:
+            tr.end(asm_sp)
             # the span reconciliation joins on: exec= is the registered
-            # plan name; grad-comm/optimizer work happens INSIDE the
-            # executable, attributed here via the plan's comm meta (the
-            # per-bucket comm_tag plane names each collective in the
-            # lowered program itself)
+            # plan name (obs.reconcile looks the static predictions up
+            # by it at report time); grad-comm/optimizer work happens
+            # INSIDE the executable, attributed here via the plan's comm
+            # meta (the per-bucket comm_tag plane names each collective
+            # in the lowered program itself)
             plan_name = self._plan_names.get(key, self.name)
             attrs: Dict[str, Any] = {"exec": plan_name,
                                      "micro_batches": num_micro_batches}
@@ -1793,15 +1834,15 @@ class DefineAndRunGraph(Graph):
                     if gc_state[0] else "gspmd"
                 attrs["zero"] = int(getattr(opt_tr, "zero", 0))
                 attrs["flat_state"] = bool(flat_mode)
-            from ..obs.reconcile import predicted_span_attrs
-            attrs.update(predicted_span_attrs(plan_name))
             exec_sp = tr.begin("executable", track="train", **attrs)
         fetch_vals, new_vars, new_opt, new_accum = jit_step(
             var_state, opt_state, grad_accum, feeds_mb)
         if exec_sp is not None:
-            # the jit call returns async futures: only block for an
-            # honest wall time when the step is actually being traced
-            jax.block_until_ready(fetch_vals)
+            # the span times the CALL (dispatch up to the return of the
+            # async futures), not the device: a traced run issues the
+            # same host schedule as an untraced one, and the profiler's
+            # device trace (obs.device_phases) gives the executable's
+            # time
             tr.end(exec_sp)
 
         commit_sp = tr.begin("commit", track="train") if tr.enabled \

@@ -25,6 +25,7 @@ from ..nn import (ColumnParallelLinear, Dropout, Module, ModuleList,
                   ParallelLayerNorm, ParallelRMSNorm, RowParallelLinear,
                   VocabParallelEmbedding, vocab_parallel_cross_entropy)
 from ..nn.parallel import sharded
+from ..obs.phases import phase
 from jax.sharding import PartitionSpec as P
 
 
@@ -307,7 +308,20 @@ class ParallelAttentionBlock(Module):
 
     def forward(self, x, seq_len: int, segment_ids=None):
         c = self.config
-        qkv = self.qkv(x)  # [b, s, (nh + 2*nkv) * hd], tp-sharded on last dim
+        with phase("attn_proj"):
+            # [b, s, (nh + 2*nkv) * hd], tp-sharded on last dim
+            qkv = self.qkv(x)
+        with phase("attn_core"):
+            attn = self._core(qkv, seq_len, segment_ids)
+        with phase("attn_proj"):
+            out = self.out(attn)
+            if self.dropout is not None:
+                out = self.dropout(out)
+        return out
+
+    def _core(self, qkv, seq_len: int, segment_ids):
+        """Head split, positions and the attention itself."""
+        c = self.config
         b_spec = P(c.dp_axis, c.cp_axis, c.tp_axis, None)
         q_size = c.num_heads * c.head_dim
         kv_size = c.kv_heads * c.head_dim
@@ -338,11 +352,7 @@ class ParallelAttentionBlock(Module):
                                  segment_ids=segment_ids)
         attn = sharded(attn, b_spec)
         attn = attn.reshape((-1, seq_len, q_size))
-        attn = sharded(attn, P(c.dp_axis, c.cp_axis, c.tp_axis))
-        out = self.out(attn)
-        if self.dropout is not None:
-            out = self.dropout(out)
-        return out
+        return sharded(attn, P(c.dp_axis, c.cp_axis, c.tp_axis))
 
 
 class ParallelMLP(Module):
@@ -422,8 +432,15 @@ class GPTBlock(Module):
             else ParallelMLP(config, layer_idx)
 
     def forward(self, x, seq_len: int, segment_ids=None):
-        x = x + self.attn(self.ln_1(x), seq_len, segment_ids=segment_ids)
-        x = x + self.mlp(self.ln_2(x))
+        with phase("norm"):
+            h = self.ln_1(x)
+        a = self.attn(h, seq_len, segment_ids=segment_ids)
+        with phase("attn_proj"):       # the residual rides the out-proj
+            x = x + a
+        with phase("norm"):
+            h = self.ln_2(x)
+        with phase("mlp"):
+            x = x + self.mlp(h)
         return x
 
 
@@ -455,15 +472,17 @@ class GPTModel(Module):
             seq_len = input_ids.shape[-1]
             if hasattr(seq_len, "get"):
                 seq_len = seq_len.get()
-        x = self.wte(input_ids)
-        if c.position == "learned":
-            pos = ops.getitem(self.wpe, slice(0, seq_len))
-            x = x + pos
-        if self.drop is not None:
-            x = self.drop(x)
+        with phase("embed"):
+            x = self.wte(input_ids)
+            if c.position == "learned":
+                pos = ops.getitem(self.wpe, slice(0, seq_len))
+                x = x + pos
+            if self.drop is not None:
+                x = self.drop(x)
         for block in self.h:
             x = block(x, seq_len, segment_ids=segment_ids)
-        return self.ln_f(x)
+        with phase("norm"):
+            return self.ln_f(x)
 
 
 class GPTLMHeadModel(Module):
@@ -487,11 +506,13 @@ class GPTLMHeadModel(Module):
                segment_ids=None):
         c = self.config
         x = self.transformer(input_ids, seq_len, segment_ids=segment_ids)
-        if self.lm_head is None:
-            logits = ops.matmul(x, self.transformer.wte.weight, trans_b=True)
-            logits = sharded(logits, P(c.dp_axis, c.cp_axis, c.tp_axis))
-        else:
-            logits = self.lm_head(x)
+        with phase("lm_head_ce"):
+            if self.lm_head is None:
+                logits = ops.matmul(x, self.transformer.wte.weight,
+                                    trans_b=True)
+                logits = sharded(logits, P(c.dp_axis, c.cp_axis, c.tp_axis))
+            else:
+                logits = self.lm_head(x)
         return logits
 
     def forward(self, input_ids, labels=None,
@@ -505,14 +526,16 @@ class GPTLMHeadModel(Module):
                                  segment_ids=segment_ids)
             w = self.lm_head.weight if self.lm_head is not None \
                 else self.transformer.wte.weight
-            return ops.fused_lm_cross_entropy(x, w, labels,
-                                              ignore_index=-100)
+            with phase("lm_head_ce"):
+                return ops.fused_lm_cross_entropy(x, w, labels,
+                                                  ignore_index=-100)
         logits = self.logits(input_ids, seq_len, segment_ids=segment_ids)
         if labels is None:
             return logits
-        loss = vocab_parallel_cross_entropy(
-            logits, labels, dp_axis=c.dp_axis, tp_axis=c.tp_axis,
-            seq_axis=c.cp_axis, ignore_index=-100)
+        with phase("lm_head_ce"):
+            loss = vocab_parallel_cross_entropy(
+                logits, labels, dp_axis=c.dp_axis, tp_axis=c.tp_axis,
+                seq_axis=c.cp_axis, ignore_index=-100)
         if c.num_experts > 0 and c.moe_aux_coef:
             for block in self.transformer.h:
                 if isinstance(block.mlp, MoEMLP) and \

@@ -11,26 +11,37 @@ The observability counterpart of ``hetu_tpu/analysis`` (DESIGN.md §15):
   readable with ``utils.metrics.load_jsonl``;
 * :mod:`.reconcile` — joins observed per-executable wall time and
   device memory peaks against the analysis plane's static wire-byte and
-  peak-HBM predictions.
+  peak-HBM predictions (looked up at report time by the spans' ``exec=``);
+* :mod:`.phases` — the model-phase vocabulary (``phase("mlp")`` scopes
+  that reach HLO ``op_name`` metadata) and ``device_phases(exec)``, the
+  ``{HLO instruction: phase}`` map that names a device trace's events.
+
+Real-time spans are mirrored into the ``jax.profiler`` trace
+(``hetu:<name>`` annotations), so host phases and device operations
+share the profiler's clock.
 
 Instrumented out of the box: ``serving.Engine`` (full per-request
 lifecycle: queue wait, admission + page accounting, prefix-cache
 hit/evict, prefill chunks, decode tokens, preemption, finish, plus the
 scheduler's per-step packing decision), ``DefineAndRunGraph.run``
-(per-step feed / executable / commit phases with grad-comm
-attribution), ``switch_strategy`` and the MPMD pipeline task loop.
+(per-step feed / assemble / executable / commit phases with grad-comm
+attribution; ``executable`` times the call, not the device),
+``switch_strategy`` and the MPMD pipeline task loop.
 """
 from .export import (chrome_trace, events_to_jsonl, request_timelines,
                      timeline_summary, validate_chrome_trace,
                      write_chrome_trace, write_jsonl)
+from .phases import PHASES, device_phases, phase
 from .reconcile import (ReconcileReport, ReconcileRow, predicted_stats,
                         reconcile)
-from .tracer import (NOOP_SPAN, NULL_TRACER, PrefixedTracer, Span,
-                     SpanTracer, get_tracer, install_tracer, trace)
+from .tracer import (NOOP_SPAN, NULL_TRACER, PROFILER_PREFIX,
+                     PrefixedTracer, Span, SpanTracer, get_tracer,
+                     install_tracer, trace)
 
 __all__ = [
     "Span", "SpanTracer", "PrefixedTracer", "NULL_TRACER", "NOOP_SPAN",
-    "get_tracer", "install_tracer", "trace",
+    "PROFILER_PREFIX", "get_tracer", "install_tracer", "trace",
+    "PHASES", "phase", "device_phases",
     "chrome_trace", "write_chrome_trace", "events_to_jsonl", "write_jsonl",
     "validate_chrome_trace", "request_timelines", "timeline_summary",
     "ReconcileReport", "ReconcileRow", "predicted_stats", "reconcile",

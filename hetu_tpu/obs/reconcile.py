@@ -27,17 +27,11 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Dict, List, Optional, Sequence
 
-__all__ = ["predicted_stats", "predicted_span_attrs", "reconcile",
+__all__ = ["predicted_stats", "reconcile",
            "ReconcileRow", "ReconcileReport", "clear_prediction_cache"]
 
-# the stats a traced executable span carries, span-attr name -> the
-# predicted_stats key it projects (ONE mapping for every emission site)
-_SPAN_ATTR_KEYS = (("predicted_wire_bytes", "wire_bytes"),
-                   ("predicted_peak_hbm_bytes", "peak_hbm_bytes"),
-                   ("predicted_step_time_s", "step_time_s"))
-
-# predictions require tracing+lowering the executable — cached per
-# registered name so the engine hot loop pays once per process; the
+# predictions require tracing+lowering the executable — done at report
+# time only (no emission site calls this), cached per registered name; the
 # entry remembers WHICH handle it priced, so a re-registered name
 # (new engine, new graph plan) recomputes instead of serving stale
 # numbers
@@ -80,16 +74,6 @@ def predicted_stats(name_or_handle) -> Dict[str, Optional[int]]:
                  "cmp_peak_bytes": None}
     _PRED_CACHE[handle.name] = (handle, stats)
     return stats
-
-
-def predicted_span_attrs(name_or_handle) -> Dict[str, Any]:
-    """:func:`predicted_stats` projected into the span-attribute
-    namespace (``predicted_*`` keys, None fields dropped) — the single
-    mapping both the serving engine and the train loop attach to their
-    executable spans."""
-    p = predicted_stats(name_or_handle)
-    return {attr: p[key] for attr, key in _SPAN_ATTR_KEYS
-            if p.get(key) is not None}
 
 
 @dataclasses.dataclass

@@ -28,6 +28,16 @@ Clocks: spans stamped through :meth:`SpanTracer.now` (``time.monotonic``
 unless a ``time_fn`` is injected).  Components with their own clock
 (e.g. ``serving.Engine(time_fn=...)``) pass explicit ``ts`` values so
 one consistent timeline survives synthetic test clocks.
+
+Profiler mirror: every real-time span (:meth:`SpanTracer.begin` ...
+:meth:`SpanTracer.end`, whatever ``ts`` labels it) also enters a
+``jax.profiler.TraceAnnotation("hetu:" + name)`` on the calling thread,
+the scalar attributes given to ``begin`` as the annotation's stats.
+While a ``jax.profiler`` session runs, the program's spans therefore sit
+in the profiler's own trace beside the device operations, on the
+profiler's clock; with no session an annotation is a flag check.  Retroactive
+(``complete``) and point (``instant``) events have no "now" to bracket
+and are not mirrored.
 """
 from __future__ import annotations
 
@@ -38,7 +48,28 @@ from contextlib import contextmanager
 from typing import Any, Callable, Dict, List, Optional
 
 __all__ = ["Span", "SpanTracer", "PrefixedTracer", "NULL_TRACER",
-           "NOOP_SPAN", "get_tracer", "install_tracer", "trace"]
+           "NOOP_SPAN", "PROFILER_PREFIX", "get_tracer", "install_tracer",
+           "trace"]
+
+# name prefix of the spans mirrored into the jax profiler's trace
+PROFILER_PREFIX = "hetu:"
+
+_SCALARS = (bool, int, float, str)
+_annotation_cls = None
+
+
+def _profiler_annotation(name: str, attrs: Dict[str, Any]):
+    """An entered ``TraceAnnotation`` for a span that starts now (jax is
+    imported on the first traced span, never by the no-op path)."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        from jax.profiler import TraceAnnotation
+        _annotation_cls = TraceAnnotation
+    ann = _annotation_cls(
+        PROFILER_PREFIX + name,
+        **{k: v for k, v in attrs.items() if isinstance(v, _SCALARS)})
+    ann.__enter__()
+    return ann
 
 
 class Span:
@@ -46,7 +77,7 @@ class Span:
     phase letters: "X" complete span, "i" instant."""
 
     __slots__ = ("name", "track", "ts", "dur", "ph", "attrs", "parent",
-                 "_tracer")
+                 "_tracer", "_ann")
 
     def __init__(self, name: str, track: str, ts: float,
                  attrs: Dict[str, Any], parent: Optional[str] = None,
@@ -59,6 +90,7 @@ class Span:
         self.attrs = attrs
         self.parent = parent                    # parent span NAME (nesting)
         self._tracer = tracer
+        self._ann = None                        # open profiler annotation
 
     def set(self, **attrs: Any) -> "Span":
         self.attrs.update(attrs)
@@ -102,6 +134,12 @@ class _NoopSpan:
 
 
 NOOP_SPAN = _NoopSpan()
+
+
+def _close_annotation(span: Span) -> None:
+    ann, span._ann = span._ann, None
+    if ann is not None:
+        ann.__exit__(None, None, None)
 
 
 class SpanTracer:
@@ -151,6 +189,7 @@ class SpanTracer:
                   parent=parent.name if parent is not None else None,
                   tracer=self)
         st.append(sp)
+        sp._ann = _profiler_annotation(name, attrs)
         return sp
 
     def end(self, span: Span, ts: Optional[float] = None,
@@ -164,8 +203,15 @@ class SpanTracer:
             return                    # NOOP_SPAN / disabled / re-ended
         st = self._stack()
         if span in st:
-            while st and st.pop() is not span:
-                pass
+            # annotations are a per-thread stack in the profiler too:
+            # close the discarded children's first, innermost first
+            while st:
+                top = st.pop()
+                _close_annotation(top)
+                if top is span:
+                    break
+        else:
+            _close_annotation(span)
         end_ts = self.now() if ts is None else ts
         span.dur = max(0.0, end_ts - span.ts)
         if attrs:

@@ -40,6 +40,7 @@ import numpy as np
 
 from ..graph.graph import DefineAndRunGraph, Graph, OpNode, get_default_graph
 from ..graph.tensor import Tensor
+from ..obs.phases import phase
 
 
 class Optimizer:
@@ -573,36 +574,41 @@ class Optimizer:
             transport=self.grad_comm or "fp32")
         assert tuple(rs_layout.chunks) == tuple(lay.chunks), \
             "flat-state layout drifted from the reduce-scatter geometry"
-        sq_norm = None
-        if self.max_grad_norm is not None or want_sq_norm:
-            # global sum of squares over the scattered chunks: local
-            # partial sums + one psum (padding lanes contribute exact
-            # zeros) — pre-clip, shared by clip and sentry
-            sq = sum(jnp.sum(jnp.square(c)) for c in chunks)
-            sq_norm = jax.lax.psum(sq, axis)
-        if self.max_grad_norm is not None:
-            norm = jnp.sqrt(sq_norm)
-            scale = jnp.minimum(1.0, self.max_grad_norm / (norm + 1e-6))
-            chunks = [c * scale for c in chunks]
-        step = fstate["step"] + 1
-        lr = self._lr_at(step)
-        slots = self._flat_slots()
-        new_master: list = []
-        new_slots: Dict[str, list] = {s: [] for s in slots}
-        for bi, g in enumerate(chunks):
-            p = fstate["flat_master"][bi]
-            cur = {s: fstate[f"flat_{s}"][bi] for s in slots}
-            p_new, cur_new = self._flat_update(p, cur, g, step, lr,
-                                               bucket=bi, axis=axis,
-                                               fstate=fstate)
-            new_master.append(p_new)
+        # the local-chunk update (clip, slots, master) is the step's
+        # ``optimizer`` phase; the collectives on either side carry
+        # their own comm_tag (grad_comm / param_comm / param_gather)
+        with phase("optimizer"):
+            sq_norm = None
+            if self.max_grad_norm is not None or want_sq_norm:
+                # global sum of squares over the scattered chunks: local
+                # partial sums + one psum (padding lanes contribute exact
+                # zeros) — pre-clip, shared by clip and sentry
+                sq = sum(jnp.sum(jnp.square(c)) for c in chunks)
+                sq_norm = jax.lax.psum(sq, axis)
+            if self.max_grad_norm is not None:
+                norm = jnp.sqrt(sq_norm)
+                scale = jnp.minimum(
+                    1.0, self.max_grad_norm / (norm + 1e-6))
+                chunks = [c * scale for c in chunks]
+            step = fstate["step"] + 1
+            lr = self._lr_at(step)
+            slots = self._flat_slots()
+            new_master: list = []
+            new_slots: Dict[str, list] = {s: [] for s in slots}
+            for bi, g in enumerate(chunks):
+                p = fstate["flat_master"][bi]
+                cur = {s: fstate[f"flat_{s}"][bi] for s in slots}
+                p_new, cur_new = self._flat_update(p, cur, g, step, lr,
+                                                   bucket=bi, axis=axis,
+                                                   fstate=fstate)
+                new_master.append(p_new)
+                for s in slots:
+                    new_slots[s].append(cur_new[s])
+            out: Dict[str, Any] = {"flat_master": new_master}
             for s in slots:
-                new_slots[s].append(cur_new[s])
-        out: Dict[str, Any] = {"flat_master": new_master}
-        for s in slots:
-            out[f"flat_{s}"] = new_slots[s]
-        for k, v in self._flat_extra_update(fstate).items():
-            out[k] = v
+                out[f"flat_{s}"] = new_slots[s]
+            for k, v in self._flat_extra_update(fstate).items():
+                out[k] = v
         if self.zero >= 3:
             # ZeRO-3: nothing but the 1/dp master chunks survives the
             # step — the next step's forward re-gathers just-in-time

@@ -44,6 +44,7 @@ from jax import lax
 from ..models.generate import (_act, _lm_head, _moe_mlp, _norm_apply,
                                _Params, _rotary_tables)
 from ..models.gpt import GPTConfig
+from ..obs.phases import phase
 from ..ops.paged_attention import gather_pages, paged_attention_reference
 from ..ops.quantization import quantize_rows
 from ..ops.ragged_paged_attention import (_dequant_latent,
@@ -362,13 +363,17 @@ def build_unified_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
                  q_lens, cu_q, page_tables, ctx_lens, temps, top_ps,
                  top_ks, seeds, spec_lens, k_pages, v_pages):
         p = _params_view(c, params)
-        x = p("wte.weight")[tokens].astype(cdt)            # [T, H]
-        if c.position == "learned":
-            x = x + p("wpe")[token_pos].astype(x.dtype)
+        # model phases (obs/phases.py): names on the HLO op_name
+        # metadata only, the compiled program is what it was
+        with phase("embed"):
+            x = p("wte.weight")[tokens].astype(cdt)        # [T, H]
+            if c.position == "learned":
+                x = x + p("wpe")[token_pos].astype(x.dtype)
         new_k, new_v = [], []
         for i in range(c.num_layers):
-            h = _norm_apply(c, p.layer(i, "ln_1.weight"),
-                            p.layer(i, "ln_1.bias"), x)
+            with phase("norm"):
+                h = _norm_apply(c, p.layer(i, "ln_1.weight"),
+                                p.layer(i, "ln_1.bias"), x)
 
             if c.is_mla:
                 d_c, d_r = c.kv_latent_dim, c.rope_dim
@@ -383,28 +388,30 @@ def build_unified_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
                     kb = p.layer(i, "attn.kv_a.bias")
                     return out + kb if kb is not None else out
 
-                qh = region_map(q_proj, h, q_lens).reshape(
-                    t_tokens, nh, hd + d_r)
-                kv = region_map(kv_proj, h, q_lens)    # [T, d_c + d_r]
-                c_kv = kv[..., :d_c]
-                k_up = p.layer(i, "attn.k_up.weight")  # [nh, hd, d_c]
-                v_up = p.layer(i, "attn.v_up.weight")
-                # FlashMLA-ETAP absorption: fold W_UK into q so scores
-                # are MQA dot products against the latent stream
-                q_abs = jnp.einsum("thd,hdc->thc",
-                                   qh[..., :hd].astype(jnp.float32),
-                                   k_up.astype(jnp.float32))
-                if d_r:
-                    q_rope = _rope_tok(qh[..., hd:], cos[token_pos],
-                                       sin[token_pos])
-                    k_rope = _rope_tok(kv[..., d_c:][:, None, :],
-                                       cos[token_pos],
-                                       sin[token_pos])[:, 0]
-                    q_cat = jnp.concatenate(
-                        [q_abs, q_rope.astype(jnp.float32)], -1)
-                else:
-                    q_cat = q_abs
-                with jax.named_scope("kv_page_scatter"):
+                with phase("attn_proj"):
+                    qh = region_map(q_proj, h, q_lens).reshape(
+                        t_tokens, nh, hd + d_r)
+                    kv = region_map(kv_proj, h, q_lens)  # [T, d_c + d_r]
+                with phase("attn_core"):
+                    c_kv = kv[..., :d_c]
+                    k_up = p.layer(i, "attn.k_up.weight")  # [nh, hd, d_c]
+                    v_up = p.layer(i, "attn.v_up.weight")
+                    # FlashMLA-ETAP absorption: fold W_UK into q so scores
+                    # are MQA dot products against the latent stream
+                    q_abs = jnp.einsum("thd,hdc->thc",
+                                       qh[..., :hd].astype(jnp.float32),
+                                       k_up.astype(jnp.float32))
+                    if d_r:
+                        q_rope = _rope_tok(qh[..., hd:], cos[token_pos],
+                                           sin[token_pos])
+                        k_rope = _rope_tok(kv[..., d_c:][:, None, :],
+                                           cos[token_pos],
+                                           sin[token_pos])[:, 0]
+                        q_cat = jnp.concatenate(
+                            [q_abs, q_rope.astype(jnp.float32)], -1)
+                    else:
+                        q_cat = q_abs
+                with phase("kv_scatter"):
                     if page_quant:
                         codes, am = quantize_rows(c_kv, page_quant)
                         kp = k_pages[i].at[token_page, 0, token_off].set(
@@ -420,42 +427,45 @@ def build_unified_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
                                 k_rope.astype(cdt))
                         else:
                             vp = v_pages[i]        # width-0 rope stream
-                rp = None if (page_quant or not d_r) else vp
-                sp = vp if page_quant else None
-                if use_kernel:
-                    o_lat = latent_ragged_paged_attention_pallas(
-                        q_cat, kp, rp, q_lens, cu_q, page_tables,
-                        ctx_lens, max_q=max(chunk, spec_k + 1),
-                        softmax_scale=(hd + d_r) ** -0.5,
-                        scale_pages=sp, quant=page_quant,
-                        latent_dim=d_c)
-                else:
-                    o_lat = _split_latent_ragged_attention(
-                        c, q_cat, kp, rp, q_lens, page_tables, ctx_lens,
-                        max_seqs, prefill_rows, chunk, spec_k=spec_k,
-                        scale_pages=sp, quant=page_quant)
-                # the W_UV fold: one up-projection per QUERY token —
-                # cached tokens are never decompressed
-                attn = jnp.einsum("thc,hdc->thd", o_lat,
-                                  v_up.astype(jnp.float32))
-                attn = attn.reshape(t_tokens, nh * hd).astype(x.dtype)
+                with phase("attn_core"):
+                    rp = None if (page_quant or not d_r) else vp
+                    sp = vp if page_quant else None
+                    if use_kernel:
+                        o_lat = latent_ragged_paged_attention_pallas(
+                            q_cat, kp, rp, q_lens, cu_q, page_tables,
+                            ctx_lens, max_q=max(chunk, spec_k + 1),
+                            softmax_scale=(hd + d_r) ** -0.5,
+                            scale_pages=sp, quant=page_quant,
+                            latent_dim=d_c)
+                    else:
+                        o_lat = _split_latent_ragged_attention(
+                            c, q_cat, kp, rp, q_lens, page_tables, ctx_lens,
+                            max_seqs, prefill_rows, chunk, spec_k=spec_k,
+                            scale_pages=sp, quant=page_quant)
+                    # the W_UV fold: one up-projection per QUERY token —
+                    # cached tokens are never decompressed
+                    attn = jnp.einsum("thc,hdc->thd", o_lat,
+                                      v_up.astype(jnp.float32))
+                    attn = attn.reshape(t_tokens, nh * hd).astype(x.dtype)
             else:
                 def qkv_proj(hh, i=i):
                     out = hh @ p.layer(i, "attn.qkv.weight").T
                     qb = p.layer(i, "attn.qkv.bias")
                     return out + qb if qb is not None else out
 
-                qkv = region_map(qkv_proj, h, q_lens)
+                with phase("attn_proj"):
+                    qkv = region_map(qkv_proj, h, q_lens)
                 q_size, kv_size = nh * hd, nkv * hd
-                q = qkv[..., :q_size].reshape(t_tokens, nh, hd)
-                k = qkv[..., q_size:q_size + kv_size].reshape(
-                    t_tokens, nkv, hd)
-                v = qkv[..., q_size + kv_size:].reshape(t_tokens, nkv,
-                                                        hd)
-                if c.position == "rotary":
-                    q = _rope_tok(q, cos[token_pos], sin[token_pos])
-                    k = _rope_tok(k, cos[token_pos], sin[token_pos])
-                with jax.named_scope("kv_page_scatter"):
+                with phase("attn_core"):
+                    q = qkv[..., :q_size].reshape(t_tokens, nh, hd)
+                    k = qkv[..., q_size:q_size + kv_size].reshape(
+                        t_tokens, nkv, hd)
+                    v = qkv[..., q_size + kv_size:].reshape(t_tokens, nkv,
+                                                            hd)
+                    if c.position == "rotary":
+                        q = _rope_tok(q, cos[token_pos], sin[token_pos])
+                        k = _rope_tok(k, cos[token_pos], sin[token_pos])
+                with phase("kv_scatter"):
                     # one [hd] row per (page, head, offset) index: the
                     # written window is the pages' minor dim, so at a
                     # head_dim that fills the 128 lanes XLA scatters in
@@ -466,24 +476,27 @@ def build_unified_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
                           token_off[:, None])
                     kp = k_pages[i].at[at].set(k.astype(cdt))
                     vp = v_pages[i].at[at].set(v.astype(cdt))
-                if use_kernel:
-                    attn = ragged_paged_attention_pallas(
-                        q, kp, vp, q_lens, cu_q, page_tables, ctx_lens,
-                        max_q=max(chunk, spec_k + 1))
-                else:
-                    attn = _split_ragged_attention(
-                        c, q, kp, vp, q_lens, page_tables, ctx_lens,
-                        max_seqs, prefill_rows, chunk, spec_k=spec_k)
-                attn = attn.reshape(t_tokens, nh * hd).astype(x.dtype)
+                with phase("attn_core"):
+                    if use_kernel:
+                        attn = ragged_paged_attention_pallas(
+                            q, kp, vp, q_lens, cu_q, page_tables, ctx_lens,
+                            max_q=max(chunk, spec_k + 1))
+                    else:
+                        attn = _split_ragged_attention(
+                            c, q, kp, vp, q_lens, page_tables, ctx_lens,
+                            max_seqs, prefill_rows, chunk, spec_k=spec_k)
+                    attn = attn.reshape(t_tokens, nh * hd).astype(x.dtype)
 
             def out_proj(aa, i=i):
                 out = aa @ p.layer(i, "attn.out.weight").T
                 ob = p.layer(i, "attn.out.bias")
                 return out + ob if ob is not None else out
 
-            x = x + region_map(out_proj, attn, q_lens)
-            h = _norm_apply(c, p.layer(i, "ln_2.weight"),
-                            p.layer(i, "ln_2.bias"), x)
+            with phase("attn_proj"):
+                x = x + region_map(out_proj, attn, q_lens)
+            with phase("norm"):
+                h = _norm_apply(c, p.layer(i, "ln_2.weight"),
+                                p.layer(i, "ln_2.bias"), x)
             if c.is_moe_layer(i):
                 # decode slots: [T', 1, H] -> s=1 dense per-token mix
                 # (v1 decode path); chunk slots: [1, C, H] -> dispatched
@@ -505,19 +518,23 @@ def build_unified_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
                     db = p.layer(i, "mlp.down.bias")
                     return hh + db if db is not None else hh
 
-            x = x + region_map(mlp, h, q_lens, f_chunk=mlp_chunk)
+            with phase("mlp"):
+                x = x + region_map(mlp, h, q_lens, f_chunk=mlp_chunk)
             new_k.append(kp)
             new_v.append(vp)
-        x = _norm_apply(c, p("ln_f.weight"), p("ln_f.bias"), x)
+        with phase("norm"):
+            x = _norm_apply(c, p("ln_f.weight"), p("ln_f.bias"), x)
         # per-row last TRUE query token -> [rows, V] fp32 logits
         last = jnp.clip(cu_q[:n_rows] + jnp.maximum(q_lens, 1) - 1, 0,
                         t_tokens - 1)
-        logits = _lm_head(p, x[last])
+        with phase("lm_head_ce"):
+            logits = _lm_head(p, x[last])
         # batched sampler: the sort-based sampled path runs under ONE
         # any(temps > 0) branch — all-greedy steps (the temp-0 bitwise
         # contract's case) never pay a vocab argsort per row
-        next_tokens = sample_rows(logits, temps, top_ps, top_ks,
-                                  seeds, ctx_lens)
+        with phase("sample"):
+            next_tokens = sample_rows(logits, temps, top_ps, top_ks,
+                                      seeds, ctx_lens)
         if spec_k == 0:
             return next_tokens, tuple(new_k), tuple(new_v)
         # -- verify head (dedicated verify slots only: decode slots and
@@ -531,12 +548,14 @@ def build_unified_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
         starts = cu_q[v0:n_rows]                 # [R = verify_rows]
         widx = jnp.clip(starts[:, None] + jnp.arange(spec_k)[None, :],
                         0, t_tokens - 1)                   # [R, K]
-        vlogits = _lm_head(p, x[widx.reshape(-1)]).reshape(
-            verify_rows, spec_k, -1)
+        with phase("lm_head_ce"):
+            vlogits = _lm_head(p, x[widx.reshape(-1)]).reshape(
+                verify_rows, spec_k, -1)
         draft_next = tokens[jnp.clip(widx + 1, 0, t_tokens - 1)]
-        acc_v, alt_v = speculative_verify_head(
-            vlogits, draft_next, spec_lens[v0:], temps[v0:],
-            top_ps[v0:], top_ks[v0:], seeds[v0:], ctx_lens[v0:])
+        with phase("sample"):
+            acc_v, alt_v = speculative_verify_head(
+                vlogits, draft_next, spec_lens[v0:], temps[v0:],
+                top_ps[v0:], top_ks[v0:], seeds[v0:], ctx_lens[v0:])
         # bonus token: first-rejection alternative, or — on full
         # acceptance — the last-position per-row sample (whose sampling
         # index ctx_lens[r] is exactly the emitted token's index)

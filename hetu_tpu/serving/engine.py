@@ -59,10 +59,19 @@ every request gets a complete lifecycle timeline on its own track —
 [submit, finish] gaplessly across preemptions, ``admit`` (page
 accounting), ``prefix_cache_hit``, per-chunk ``prefill_chunk`` spans
 with their token-budget slice, per-token instants, ``preempt`` and
-``finish`` — plus the scheduler's ``pack`` decision per step and a
-``unified_step`` span per executable call carrying the analysis plane's
-predicted wire bytes / peak HBM for reconciliation.  The default tracer
-is the shared no-op: every emission site guards on ``tracer.enabled``.
+``finish`` — plus the scheduler's ``pack`` decision per step, a
+``unified_step`` span per executable call (``exec=`` names the registered
+executable; ``obs.reconcile()`` joins the analysis plane's predictions
+on it) and, around the whole of ``step()``, an ``engine_step`` span tiled
+by its host phases ``step.admit`` (admission, prefix-cache match, draft
+staging), ``step.pages`` (decode pages, preemption), ``step.pack``
+(packing decision + host arrays), ``step.tap`` (the analysis tap's
+copy), ``step.h2d`` (host-to-device copies), ``step.dispatch`` (the
+compiled call up to its return), ``step.fetch`` (the host waits for the
+device here) and ``step.commit`` (pages, counters, per-row commit,
+stream callbacks, gauges).  These real-time spans are mirrored into the
+jax profiler's trace (``hetu:`` prefix).  The default tracer is the
+shared no-op: every emission site guards on ``tracer.enabled``.
 """
 from __future__ import annotations
 
@@ -114,7 +123,10 @@ class Engine:
         # shared no-op — every emission site below guards on
         # ``tr.enabled`` so disabled tracing stays out of the hot loop
         self._tracer = tracer
-        self._pred_attrs: Optional[Dict[str, Any]] = None
+        # traced steps only: the open ``step.*`` child of the
+        # ``engine_step`` span and the attributes that span ends with
+        self._phase_sp = None
+        self._step_attrs: Dict[str, Any] = {}
         # ring buffer of recent packed-step layouts (rows + page tables),
         # consumed by the trash-page-write lint (hetu_tpu/analysis)
         self.tap: Optional[deque] = deque(maxlen=128) if analysis_tap \
@@ -465,11 +477,48 @@ class Engine:
         number of tokens emitted."""
         now = self._now()
         tr = self.tracer
+        if not tr.enabled:
+            return self._step(tr, now)
+        # host phases on the profiler's clock (obs/tracer.py mirrors
+        # begin/end into jax.profiler): one ``engine_step`` parent whose
+        # ``step.*`` children tile it, so a device idle gap can be put
+        # down to the phase the host was in.  The finally closes the
+        # parent even when the body raises (ending it discards any open
+        # child), so a failing step never corrupts the nesting stack.
+        step_sp = tr.begin("engine_step", track="engine", ts=now)
+        try:
+            return self._step(tr, now)
+        finally:
+            t = self._now()
+            if self._phase_sp is not None:
+                tr.end(self._phase_sp, ts=t)
+                self._phase_sp = None
+            tr.end(step_sp, ts=t, **self._step_attrs)
+
+    def _enter_phase(self, tr, name: str,
+                     t: Optional[float] = None) -> float:
+        """Close the open ``step.*`` child of ``engine_step`` and open
+        ``name`` at the same instant ``t`` (now unless given), which is
+        returned.  Traced steps only."""
+        if t is None:
+            t = self._now()
+        if self._phase_sp is not None:
+            tr.end(self._phase_sp, ts=t)
+        self._phase_sp = tr.begin(name, track="engine", ts=t)
+        return t
+
+    def _step(self, tr, now: float) -> int:
+        traced = tr.enabled
+        if traced:
+            self._step_attrs = {"rows": 0, "tokens": 0}
+            self._enter_phase(tr, "step.admit", now)
         for req in self.scheduler.admit(self.queue, self.running, now):
             self._start(req)
         live = [r for r in self.running if r.state == RUNNING]
         if self.spec is not None:
             self._stage_spec(live)
+        if traced:
+            self._enter_phase(tr, "step.pages")
         kept, evicted = self.scheduler.ensure_decode_pages(live)
         for req in evicted:
             self.running.remove(req)
@@ -500,14 +549,23 @@ class Engine:
                            n_preemptions=req.n_preemptions,
                            pos_lost=len(req.tokens))
             req.trace_t0 = t
+        if traced:
+            self._enter_phase(tr, "step.pack")
         rows = self.scheduler.pack(kept)
-        if tr.enabled and rows:
-            tr.instant("pack", track="scheduler", ts=self._now(),
-                       running=len(self.running),
-                       queue_depth=len(self.queue),
-                       free_pages=self.pool.free_pages,
-                       **self.scheduler.slot_mix(rows))
+        if traced:
+            # queue_depth counts every queued request, future arrivals
+            # included (as the pack instant always has); queue_due only
+            # those admission left waiting
+            load = {"running": len(self.running),
+                    "queue_depth": len(self.queue),
+                    "free_pages": self.pool.free_pages}
+            self._step_attrs.update(load, queue_due=self.queue.due(now))
+            if rows:
+                tr.instant("pack", track="scheduler", ts=self._now(),
+                           **load, **self.scheduler.slot_mix(rows))
         produced = self._run_unified(rows) if rows else 0
+        if traced and not rows:
+            self._enter_phase(tr, "step.commit")
         if self.debug:
             self.pool.check_invariants()
             if self.prefix_cache is not None:
@@ -783,6 +841,10 @@ class Engine:
         (tokens, token_pos, token_page, token_off, q_lens, page_tables,
          ctx_lens, temps, top_ps, top_ks, seeds,
          spec_lens) = self._pack_arrays(rows)
+        tr = self.tracer
+        traced = tr.enabled
+        if traced:
+            self._enter_phase(tr, "step.tap")
         if self.tap is not None:
             self.tap.append({
                 "kind": "unified",
@@ -802,7 +864,7 @@ class Engine:
                 # cached = read-only, whatever the sharer count)
                 "refcounts": {int(pg): self.pool.refcount(pg)
                               for pg in self.pool._cached}})
-        t0 = self._now()
+        t0 = self._enter_phase(tr, "step.h2d") if traced else self._now()
         args = (self.params, jnp.asarray(tokens), jnp.asarray(token_pos),
                 jnp.asarray(token_page), jnp.asarray(token_off),
                 jnp.asarray(q_lens), jnp.asarray(self._cu_q),
@@ -810,30 +872,38 @@ class Engine:
                 jnp.asarray(temps), jnp.asarray(top_ps),
                 jnp.asarray(top_ks), jnp.asarray(seeds))
         if self.spec is not None:
-            next_tokens, accepted, new_k, new_v = \
-                self._compiled["unified"](*args,
-                                          jnp.asarray(spec_lens),
-                                          self.pool.k_pages,
-                                          self.pool.v_pages)
+            args += (jnp.asarray(spec_lens),)
+        if traced:
+            self._enter_phase(tr, "step.dispatch")
+        # the call returns once the executable is enqueued; the host
+        # waits for the device in the fetch below
+        out = self._compiled["unified"](*args, self.pool.k_pages,
+                                        self.pool.v_pages)
+        if traced:
+            self._enter_phase(tr, "step.fetch")
+        if self.spec is not None:
+            next_tokens, accepted, new_k, new_v = out
             accs = np.asarray(accepted)         # [rows] int32
         else:
-            next_tokens, new_k, new_v = self._compiled["unified"](
-                *args, self.pool.k_pages, self.pool.v_pages)
+            next_tokens, new_k, new_v = out
             accs = None
-        self.pool.set_pages(new_k, new_v)
         toks = np.asarray(next_tokens)          # [rows] int32, ever
-        dt = self._now() - t0
+        t1 = self._now()
+        dt = t1 - t0
+        if traced:
+            self._enter_phase(tr, "step.commit", t1)
+        self.pool.set_pages(new_k, new_v)
         self._calls += 1
         self.counters["step_calls"].inc()
-        tr = self.tracer
-        if tr.enabled:
+        if traced:
             # the span every reconciliation row hangs off: exec= names
-            # the registered ExecutableHandle, and the static
-            # predictions ride along as attributes
+            # the registered ExecutableHandle (obs.reconcile looks the
+            # static predictions up by it at report time)
+            n_tokens = int(sum(q for _, q, _ in rows))
+            self._step_attrs.update(rows=len(rows), tokens=n_tokens)
             tr.complete("unified_step", t0, dt, track="engine",
                         exec=f"{self.name}/unified", rows=len(rows),
-                        tokens=int(sum(q for _, q, _ in rows)),
-                        **self._predicted_attrs())
+                        tokens=n_tokens)
         # classify by SLOT, not q_len: a chunk_size=1 prefill chunk is
         # still a prefill chunk, and a verify row is neither
         n_decode = sum(1 for _, _, row in rows if row < s)
@@ -1055,18 +1125,6 @@ class Engine:
         clear_executables(f"{self.name}/")
 
     # -- observability -------------------------------------------------------
-
-    def _predicted_attrs(self) -> Dict[str, Any]:
-        """Static analysis-plane predictions for the unified executable,
-        attached to every traced ``unified_step`` span so the trace
-        alone suffices for reconciliation.  Computed once (tracing the
-        registered handle) on the first TRACED step; failures degrade to
-        no attrs rather than breaking serving."""
-        if self._pred_attrs is None:
-            from ..obs.reconcile import predicted_span_attrs
-            self._pred_attrs = predicted_span_attrs(
-                f"{self.name}/unified")
-        return self._pred_attrs
 
     def metrics_text(self) -> str:
         """Prometheus text exposition of every engine instrument

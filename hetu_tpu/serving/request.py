@@ -139,6 +139,13 @@ class RequestQueue:
         for heap in self._heaps.values():
             heap.clear()
 
+    def due(self, now: float) -> int:
+        """Queued requests whose ``arrival_time`` has passed: the ones
+        admission left waiting.  O(queue) — for the trace plane, not
+        the untraced loop."""
+        return sum(1 for heap in self._heaps.values()
+                   for arrival, _, _ in heap if arrival <= now)
+
     def depth_by_class(self) -> dict:
         """Queue depth per class — an autoscaler/router signal."""
         return {c: len(h) for c, h in self._heaps.items()}

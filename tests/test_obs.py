@@ -313,11 +313,14 @@ def test_chrome_trace_schema_from_serving_run(tiny_state):
         assert "queued" in kinds and "running" in kinds \
             and "admit" in kinds and "prefill_chunk" in kinds
         assert sum(1 for k in kinds if k == "token") == 4
-    # unified_step spans carry the reconciliation join key + predictions
+    # unified_step spans carry the reconciliation join key; the
+    # predictions are looked up by it at report time, not emitted
     un = [e for e in events if e.name == "unified_step"]
     assert un and all(e.attrs["exec"] == "serving/unified" for e in un)
-    assert all(e.attrs.get("predicted_peak_hbm_bytes", 0) > 0
-               for e in un)
+    assert not any(k.startswith("predicted_") for e in un for k in e.attrs)
+    row = next(r for r in reconcile(events).rows
+               if r.executable == "serving/unified")
+    assert row.calls == len(un) and row.predicted_peak_hbm_bytes > 0
     assert timeline_summary(events)          # renders without error
 
 
@@ -470,3 +473,256 @@ def test_reconcile_joins_two_executable_families(tiny_state):
     assert len(d["rows"]) == rep.families
     assert d["rows"][0]["predicted_step_s"] is not None
     json.dumps(d)                            # BENCH_OBS-serializable
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 25: host phases on the profiler's clock, device time by phase
+# ---------------------------------------------------------------------------
+
+
+def _profiler_hetu_spans(trace_dir):
+    """(start_ns, end_ns, name, stats) of the ``hetu:`` annotations in
+    the one .xplane.pb under ``trace_dir``, in start order."""
+    import glob
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(str(trace_dir / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(obs.PROFILER_PREFIX):
+                    out.append((ev.start_ns, ev.start_ns + ev.duration_ns,
+                                ev.name[len(obs.PROFILER_PREFIX):],
+                                dict(ev.stats)))
+    return sorted(out, key=lambda e: (e[0], -e[1]))
+
+
+def test_profiler_mirror_nests_spans_as_tracer_events(tmp_path):
+    """Under a live jax.profiler session the real-time spans land in
+    the profiler's trace, nested and ordered as the tracer has them."""
+    import jax
+    import jax.numpy as jnp
+    with trace() as tr:
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with tr.span("outer", track="work", rows=3, label="x",
+                         skipped=[1, 2]):
+                with tr.span("first"):
+                    jnp.ones(8).sum().block_until_ready()
+                tr.instant("point")              # not mirrored
+                tr.complete("retro", tr.now() - 1.0, 0.5)   # not mirrored
+                with tr.span("second", ts=123.0):    # labelled, mirrored
+                    pass
+        finally:
+            jax.profiler.stop_trace()
+    got = _profiler_hetu_spans(tmp_path)
+    assert [g[2] for g in got] == ["outer", "first", "second"]
+    by = {g[2]: g for g in got}
+    # nesting: both children inside the parent, in the tracer's order
+    assert by["outer"][0] <= by["first"][0] <= by["first"][1] \
+        <= by["second"][0] <= by["second"][1] <= by["outer"][1]
+    spans = [e for e in tr.events() if e.ph == "X" and e.name != "retro"]
+    assert sorted(e.name for e in spans) == sorted(g[2] for g in got)
+    assert [e.parent for e in spans if e.name != "outer"] == ["outer"] * 2
+    # scalar attributes ride along as stats, containers do not
+    assert by["outer"][3] == {"rows": 3, "label": "x"}
+    # the mirror's duration is the span's own, on another clock
+    first = next(e for e in spans if e.name == "first")
+    assert (by["first"][1] - by["first"][0]) / 1e9 == \
+        pytest.approx(first.dur, rel=0.5, abs=2e-3)
+    assert tr.open_count() == 0
+
+
+STEP_PHASES = ("step.admit", "step.pages", "step.pack", "step.tap",
+               "step.h2d", "step.dispatch", "step.fetch", "step.commit")
+
+
+def test_engine_step_phases_tile_engine_step(tiny_state):
+    """Every traced step that runs the executable emits each ``step.*``
+    phase once, and the children tile ``engine_step`` gaplessly."""
+    import itertools
+    state, cfg = tiny_state
+    ticks = itertools.count()
+    now = lambda: float(next(ticks))                     # noqa: E731
+    tracer = SpanTracer(time_fn=now)
+    eng = Engine(state, cfg, tracer=tracer, time_fn=now, num_pages=16,
+                 page_size=8, max_batch=2, name="obs_phases")
+    eng.add_request([7, 3, 9, 1, 5], 3, arrival_time=0.0)
+    eng.add_request([2, 4], 2, arrival_time=0.0)
+    steps = 0
+    while eng.has_work and steps < 50:
+        eng.step()
+        steps += 1
+    assert not eng.has_work and tracer.open_count() == 0
+    events = tracer.events()
+    parents = [e for e in events if e.name == "engine_step"]
+    assert len(parents) == steps
+    assert all(e.track == "engine" for e in parents)
+    unified = [e for e in events if e.name == "unified_step"]
+    assert len(unified) == eng.executable_calls == steps
+    for par, un in zip(parents, unified):
+        kids = sorted((e for e in events if e.name.startswith("step.")
+                       and par.ts <= e.ts and e.end_ts <= par.end_ts),
+                      key=lambda e: e.ts)
+        assert tuple(e.name for e in kids) == STEP_PHASES
+        assert all(e.parent == "engine_step" and e.track == "engine"
+                   for e in kids)
+        assert kids[0].ts == par.ts and kids[-1].end_ts == par.end_ts
+        assert all(a.end_ts == b.ts for a, b in zip(kids, kids[1:]))
+        # the old span keeps its bounds: copy-in through the fetch
+        assert un.ts == kids[4].ts and un.end_ts == kids[6].end_ts
+        assert par.attrs["rows"] == un.attrs["rows"] >= 1
+        assert par.attrs["tokens"] == un.attrs["tokens"]
+        assert {"queue_depth", "queue_due", "running",
+                "free_pages"} <= set(par.attrs)
+        assert 0 <= par.attrs["queue_due"] <= par.attrs["queue_depth"]
+    # names the benchmark reads are still there, none shadowed
+    names = {e.name for e in events}
+    assert {"admit", "pack", "queued", "running", "token"} <= names
+    # an idle step (nothing to run) still tiles: admit, pages, pack, commit
+    eng.step()
+    idle = [e for e in tracer.events() if e.ts >= parents[-1].end_ts
+            and e.name.startswith("step.")]
+    assert [e.name for e in idle] == ["step.admit", "step.pages",
+                                      "step.pack", "step.commit"]
+    # untraced: the same engine goes silent and keeps no span open
+    eng.set_tracer(None)
+    n = len(tracer.events())
+    eng.add_request([1, 2, 3], 2, arrival_time=0.0)
+    eng.run()
+    assert len(tracer.events()) == n and eng._phase_sp is None
+
+
+def _tiny_train_graph(cfg, prefix):
+    from hetu_tpu import optim
+    g_ctx = ht.graph("define_and_run", create_new=True, prefix=prefix)
+    g = g_ctx.__enter__()
+    try:
+        ids = ht.placeholder("int32", (2, 8), name="ids")
+        lbl = ht.placeholder("int32", (2, 8), name="lbl")
+        loss = GPTLMHeadModel(cfg)(ids, lbl)
+        train_op = optim.AdamOptimizer(lr=1e-3).minimize(loss)
+    finally:
+        g_ctx.__exit__(None, None, None)
+    data = np.random.RandomState(0).randint(0, 61, (2, 8)).astype(np.int32)
+    return g, loss, train_op, {ids: data, lbl: data}
+
+
+def test_traced_train_step_never_blocks(tiny_state, monkeypatch):
+    """A traced ``g.run`` issues the untraced host schedule: no
+    ``block_until_ready``, and its phases in order under the step."""
+    import jax
+    _, cfg = tiny_state
+    ht.set_seed(0)
+    g, loss, train_op, feed = _tiny_train_graph(cfg, "obs_noblock")
+    calls = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: (calls.append(1), real(x))[1])
+    with trace() as tr:
+        out = g.run(loss, [loss, train_op], feed)
+    assert calls == []
+    assert np.isfinite(float(out[0]))
+    names = [e.name for e in sorted(tr.events(), key=lambda e: e.ts)
+             if e.parent == "train_step"]
+    assert names == ["plan", "feed", "assemble", "executable", "commit"]
+    ex = next(e for e in tr.events() if e.name == "executable")
+    assert ex.attrs["exec"].startswith("obs_noblock")
+    assert not any(k.startswith("predicted_") for k in ex.attrs)
+
+
+def test_device_phases_toy_function():
+    """Two scopes on a jitted function: forward and backward
+    instructions map to their phase, the rest to ``unmapped``."""
+    import jax
+    import jax.numpy as jnp
+    from hetu_tpu.graph.graph import clear_executables, register_executable
+    from hetu_tpu.obs.phases import UNMAPPED, hlo_phase_map, phase
+
+    def f(w1, w2, x):
+        def loss(w1, w2):
+            with phase("attn_proj"):
+                h = jnp.tanh(x @ w1)
+            with phase("mlp"):
+                h = jnp.sin(h @ w2)
+            return jnp.sum(h * h)
+        return jax.value_and_grad(loss, argnums=(0, 1))(w1, w2)
+
+    sds = jax.ShapeDtypeStruct((16, 16), np.float32)
+    register_executable("obs_toy/f", jax.jit(f), (sds, sds, sds))
+    try:
+        m = obs.device_phases("obs_toy/f")
+    finally:
+        clear_executables("obs_toy/")
+    phases = set(m.values())
+    assert {"attn_proj", "mlp"} <= phases <= {"attn_proj", "mlp", UNMAPPED}
+    with pytest.raises(KeyError):
+        obs.device_phases("obs_toy/never_registered")
+    with pytest.raises(ValueError):
+        with phase("not_a_phase"):
+            pass
+    # the text rules, on a hand-made module: path word, backward wrapper,
+    # alias, fusion by its root / its relabelling user / its members,
+    # Mosaic kernel by name, unknown
+    hlo = '''HloModule m
+%fused_computation.1 (p0: f32[4]) -> f32[4] {
+  %p0 = f32[4]{0} parameter(0)
+  %mul.3 = f32[4]{0} multiply(%p0, %p0), metadata={op_name="jit(s)/norm/mul"}
+  ROOT %add.4 = f32[4]{0} add(%mul.3, %p0), metadata={op_name="jit(s)/norm/add"}
+}
+%fused_computation.2 (p1: f32[4]) -> f32[4] {
+  %p1 = f32[4]{0} parameter(0)
+  %neg.12 = f32[4]{0} negate(%p1), metadata={op_name="jit(s)/attn_core/neg"}
+  ROOT %scatter.13 = f32[4]{0} scatter(%p1, %neg.12, %neg.12), to_apply=%r
+}
+ENTRY %main.9 (a: f32[4]) -> f32[4] {
+  %a = f32[4]{0} parameter(0)
+  %dot.1 = f32[4]{0} dot(%a, %a), metadata={op_name="jit(s)/transpose(jvp(mlp))/dot_general"}
+  %ag.2 = f32[4]{0} all-gather(%a), metadata={op_name="jit(s)/param_comm/bucket0/all_gather"}
+  %fusion.5 = f32[4]{0} fusion(%a), kind=kLoop, calls=%fused_computation.1
+  %fusion.10 = f32[4]{0} fusion(%a), kind=kCustom, calls=%fused_computation.2
+  %bitcast.11 = f32[2,2]{1,0} bitcast(%fusion.10), metadata={op_name="jit(s)/kv_scatter/scatter"}
+  %flash_fwd.7 = f32[4]{0} custom-call(%a), custom_call_target="tpu_custom_call"
+  %copy.8 = f32[4]{0} copy(%a), metadata={op_name="jit(s)/reshape"}
+  ROOT %opt.6 = f32[4]{0} add(%a, %a), metadata={op_name="jit(s)/optimizer/grad_comm/add"}
+}
+'''
+    got = hlo_phase_map(hlo)
+    assert got["dot.1"] == "mlp" and got["ag.2"] == "param_gather"
+    assert got["mul.3"] == got["add.4"] == got["fusion.5"] == "norm"
+    assert got["flash_fwd.7"] == "flash_fwd"
+    # a rewritten scatter lost its metadata: the fusion goes by the
+    # bitcast that consumes it, not by the operands fused into it
+    assert got["scatter.13"] == UNMAPPED and got["neg.12"] == "attn_core"
+    assert got["fusion.10"] == got["bitcast.11"] == "kv_scatter"
+    assert got["opt.6"] == "optimizer"           # the FIRST word wins
+    assert got["copy.8"] == got["a"] == UNMAPPED
+    assert got.get("fusion.999", UNMAPPED) == UNMAPPED
+
+
+def test_device_phases_tiny_train_plan(tiny_state):
+    """The registered train plan: forward and backward instructions of
+    the model's phases and the optimizer all carry their names."""
+    _, cfg = tiny_state
+    ht.set_seed(0)
+    g, loss, train_op, feed = _tiny_train_graph(cfg, "obs_devph")
+    with trace() as tr:
+        g.run(loss, [loss, train_op], feed)
+    name = next(e.attrs["exec"] for e in tr.events()
+                if e.name == "executable")
+    m = obs.device_phases(name)
+    by_phase = {}
+    for inst, ph in m.items():
+        by_phase.setdefault(ph, []).append(inst)
+    assert {"embed", "norm", "attn_proj", "attn_core", "mlp",
+            "lm_head_ce", "optimizer"} <= set(by_phase)
+    from hetu_tpu.graph.graph import get_executable
+    text = get_executable(name).compiled_text()
+    # backward instructions inherit the phase through transpose(jvp(.))
+    assert "transpose(jvp(mlp))" in text and "jvp(attn_proj)" in text
+    # most of the program is named: the tiny plan's own bookkeeping
+    # (parameters, tuples, copies) is what stays unmapped
+    dots = [i for i in m if i.startswith(("dot", "fusion"))]
+    named = [i for i in dots if m[i] != "unmapped"]
+    assert len(named) >= 0.9 * len(dots) > 0
