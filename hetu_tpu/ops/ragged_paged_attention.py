@@ -39,15 +39,27 @@ Two implementations with the same contract:
   DMA per grid step — pages are ``[P, kvh, ps, hd]`` so that tile is a
   whole trailing block), ``pl.when`` skips pages past ``ctx_lens`` and
   whole padding rows, and the online-softmax state is carried in VMEM
-  scratch.  The query window is loaded with a dynamic ``pl.ds`` slice at
-  ``cu_q[i]`` and the output window is committed read-modify-write so
-  ragged row boundaries never clobber a neighbour.  Runs in interpret
+  scratch.  A kv head's queries lie flat on the sublanes, ``gp`` tile
+  rows a token (``gp = g`` where the group size divides the packed
+  tile, else ``g`` rounded up to it — an MHA model has ONE row a token,
+  not eight).  A grid step computes one query tile: the tile-aligned
+  window over row ``i``'s ``max_q * gp`` rows at ``cu_q[i] * gp``,
+  loaded with a dynamic ``pl.ds`` slice.  q and k meet on the MXU in
+  their own dtype; statistics, ``p``, the PV product and the
+  accumulator are float32.  The output window is committed
+  read-modify-write so ragged row boundaries never clobber a
+  neighbour; tokens that no row owns read as 0.  Runs in interpret
   mode off-TPU.
 
-``max_q`` (the static query-window bound) is the scheduler's prefill
-chunk size: every row owns at most ``max_q`` query tokens.  Inputs are
-padded by ``max_q`` rows internally so the window slide never reads out
-of bounds.
+``max_q`` is the static bound on a row's query tokens IN THIS CALL, and
+it sizes the tile every running grid step computes — so a caller whose
+rows fall into classes of different width issues one call per class.
+The serving step (``serving/decode.py``) does: its decode slots run at
+``max_q = 1``, its prefill chunk slots at the scheduler's chunk size,
+its verify slots at ``spec_k + 1``; one ``max_q = chunk`` call over
+all rows made every decode row pay a ``chunk``-token matmul for one
+token.  Inputs are padded by one window internally so the window slide
+never reads out of bounds.
 """
 from __future__ import annotations
 
@@ -148,14 +160,36 @@ def _ragged_kernel(ql_ref, cu_ref, pt_ref, cl_ref,    # scalar prefetch
                    q_ref, k_ref, v_ref,               # inputs
                    o_ref,                             # output
                    m_scr, l_scr, acc_scr,             # scratch
-                   *, scale: float, ps: int, maxp: int, max_q: int,
-                   gp: int):
+                   *, scale: float, ps: int, maxp: int, gp: int,
+                   sub: int):
+    """One (kv head, row, page) grid step over a ``win``-row query tile.
+
+    The head's queries lie flat on the sublanes, ``gp`` tile rows per
+    token (``q_ref [1, rows, hd]``): row ``i``'s first tile row is
+    ``cu_q[i] * gp`` and the tile is the ``sub``-aligned window that
+    covers its ``max_q * gp`` rows.  A tile row that is not one of the
+    row's ``q_lens[i]`` query tokens (a neighbour's token inside the
+    aligned window, the tail of a short chunk) is computed and dropped
+    at the commit."""
     i = pl.program_id(1)
     p = pl.program_id(2)
     qlen = ql_ref[i]
-    start = cu_ref[i]
     ctx = cl_ref[i]
-    mqg = max_q * gp
+    win = acc_scr.shape[0]
+    first = cu_ref[i] * gp
+    base = pl.multiple_of((first // sub) * sub, sub)
+    # tile row r holds in-row query (r - (first - base)) // gp; `bias`
+    # keeps the dividend non-negative (a gp under sub divides it)
+    bias = sub if gp < sub else 0
+    lead = bias - (first - base)
+
+    def query_index(shape):
+        r = lax.broadcasted_iota(jnp.int32, shape, 0)
+        return (r + lead) // gp - bias // gp
+
+    @pl.when(jnp.logical_and(i == 0, p == 0))
+    def _clear():                       # tokens no row owns read as 0
+        o_ref[...] = jnp.zeros_like(o_ref)
 
     @pl.when(p == 0)
     def _init():
@@ -165,56 +199,66 @@ def _ragged_kernel(ql_ref, cu_ref, pt_ref, cl_ref,    # scalar prefetch
 
     @pl.when(jnp.logical_and(qlen > 0, p * ps < ctx))
     def _page():
-        q = q_ref[pl.ds(start, max_q), 0].astype(jnp.float32)
-        q2 = q.reshape(mqg, q.shape[-1])               # [max_q*gp, hd]
-        k = k_ref[0, 0].astype(jnp.float32)            # [ps, hd]
+        # q and k meet on the MXU in their own dtype (bf16 products are
+        # exact in the float32 accumulator); statistics, p and the PV
+        # product stay float32
+        dt = jnp.promote_types(q_ref.dtype, k_ref.dtype)
+        q = q_ref[0, pl.ds(base, win), :].astype(dt)   # [win, hd]
+        k = k_ref[0, 0].astype(dt)                     # [ps, hd]
         v = v_ref[0, 0].astype(jnp.float32)
-        s = lax.dot_general(q2, k, (((1,), (1,)), ((), ())),
+        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-        row_q = lax.broadcasted_iota(jnp.int32, (mqg, ps), 0) // gp
-        cols = p * ps + lax.broadcasted_iota(jnp.int32, (mqg, ps), 1)
-        qpos = (ctx - qlen) + row_q                    # absolute position
+        cols = p * ps + lax.broadcasted_iota(jnp.int32, (win, ps), 1)
+        qpos = (ctx - qlen) + query_index((win, ps))   # absolute position
         s = jnp.where(cols <= qpos, s, DEFAULT_MASK_VALUE)
-        m_prev = m_scr[:, 0]                           # [mqg]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        m_prev = m_scr[:, :1]                          # [win, 1]
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_cur)
-        pexp = jnp.exp(s - m_cur[:, None])             # [mqg, ps]
-        l_cur = l_scr[:, 0] * alpha + jnp.sum(pexp, axis=1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + lax.dot_general(
+        pexp = jnp.exp(s - m_cur)                      # [win, ps]
+        l_cur = l_scr[:, :1] * alpha + jnp.sum(pexp, axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + lax.dot_general(
             pexp, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_scr[...] = jnp.broadcast_to(m_cur[:, None], m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_cur[:, None], l_scr.shape)
+        m_scr[...] = jnp.broadcast_to(m_cur, m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(l_cur, l_scr.shape)
 
-    @pl.when(p == maxp - 1)
+    @pl.when(jnp.logical_and(qlen > 0, p == maxp - 1))
     def _finalize():
-        l = l_scr[:, 0]
-        l = jnp.where(l == 0.0, 1.0, l)                # empty rows -> 0
-        o = (acc_scr[...] / l[:, None]).reshape(max_q, gp,
-                                                acc_scr.shape[-1])
-        # ragged row boundaries are not block-aligned: commit the window
-        # read-modify-write so the padded tail of this row's window never
-        # clobbers the next row's (already- or not-yet-written) tokens
-        prev = o_ref[pl.ds(start, max_q), 0]
-        rowv = lax.broadcasted_iota(jnp.int32, (max_q, 1, 1), 0) < qlen
-        o_ref[pl.ds(start, max_q), 0] = jnp.where(
-            rowv, o.astype(o_ref.dtype), prev)
+        l = l_scr[:, :1]
+        o = acc_scr[...] / jnp.where(l == 0.0, 1.0, l)  # empty rows -> 0
+        # ragged row boundaries are not tile-aligned: commit the window
+        # read-modify-write so the rows of the tile that are not this
+        # row's queries never clobber a neighbour's tokens
+        j = query_index((win, 1))
+        mine = jnp.logical_and(j >= 0, j < qlen)
+        prev = o_ref[0, pl.ds(base, win), :]
+        o_ref[0, pl.ds(base, win), :] = jnp.where(
+            mine, o.astype(o_ref.dtype), prev)
 
 
+# jitted: the serving step calls this once per layer and region with the
+# same shapes, and an inner jit is traced and lowered to Mosaic ONCE per
+# distinct call, not once per layer (the lowering is seconds of every
+# process's set-up)
+@functools.partial(jax.jit, static_argnames=("max_q", "softmax_scale",
+                                             "interpret", "name"))
 def ragged_paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
                                   v_pages: jax.Array, q_lens: jax.Array,
                                   cu_q: jax.Array, page_tables: jax.Array,
                                   ctx_lens: jax.Array, *, max_q: int,
                                   softmax_scale: Optional[float] = None,
-                                  interpret: Optional[bool] = None
+                                  interpret: Optional[bool] = None,
+                                  name: str = "ragged_paged_attention"
                                   ) -> jax.Array:
     """Pallas ragged paged attention (same contract as the reference).
 
     Grid is ``(kvh, S, maxp)`` with pages innermost (sequential on TPU);
-    the query/output windows live in a full-token-axis VMEM block while
-    k/v index maps read the prefetched page table so each grid step DMAs
-    exactly one head of one physical page — pages past ``ctx_lens[i]``
-    and whole padding rows are skipped with ``pl.when``.
+    the query/output windows live in a whole-token-axis VMEM block of
+    the grid step's kv head while k/v index maps read the prefetched
+    page table so each grid step DMAs exactly one head of one physical
+    page — pages past ``ctx_lens[i]`` and whole padding rows are skipped
+    with ``pl.when``.  ``name`` is the Mosaic call's name on the device
+    trace (the serving step names one call per region).
     """
     t, nh, hd, ps, kvh, s = _check_ragged_shapes(
         q, k_pages, v_pages, q_lens, cu_q, page_tables, ctx_lens, max_q)
@@ -223,18 +267,23 @@ def ragged_paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
     if interpret is None:
         interpret = not on_tpu()
     g = nh // kvh
-    gp = max(SUBLANES, ((g + SUBLANES - 1) // SUBLANES) * SUBLANES)
-    t_pad = t + max_q                       # window slide never OOB
-    qg = q.reshape(t, kvh, g, hd)
-    qg = jnp.pad(qg, ((0, max_q), (0, 0), (0, gp - g), (0, 0)))
+    # rows of one packed tile of q's dtype: windows start on its multiples
+    sub = SUBLANES * max(1, 4 // q.dtype.itemsize)
+    gp = g if sub % g == 0 else -(-g // sub) * sub
+    win = -(-(max_q * gp + max(sub - gp, 0)) // sub) * sub
+    rows = -(-(t * gp) // sub) * sub + win  # window slide never OOB
+    qg = jnp.pad(q.reshape(t, kvh, g, hd),
+                 ((0, 0), (0, 0), (0, gp - g), (0, 0)))
+    qg = qg.transpose(1, 0, 2, 3).reshape(kvh, t * gp, hd)
+    qg = jnp.pad(qg, ((0, 0), (0, rows - t * gp), (0, 0)))
     kernel = functools.partial(_ragged_kernel, scale=float(scale), ps=ps,
-                               maxp=maxp, max_q=int(max_q), gp=gp)
+                               maxp=maxp, gp=gp, sub=sub)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(kvh, s, maxp),
         in_specs=[
-            pl.BlockSpec((t_pad, 1, gp, hd),
-                         lambda h, i, p, ql, cu, pt, cl: (0, h, 0, 0)),
+            pl.BlockSpec((1, rows, hd),
+                         lambda h, i, p, ql, cu, pt, cl: (h, 0, 0)),
             pl.BlockSpec((1, 1, ps, hd),
                          lambda h, i, p, ql, cu, pt, cl: (pt[i, p], h, 0,
                                                           0)),
@@ -243,30 +292,30 @@ def ragged_paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
                                                           0)),
         ],
         out_specs=pl.BlockSpec(
-            (t_pad, 1, gp, hd),
-            lambda h, i, p, ql, cu, pt, cl: (0, h, 0, 0)),
+            (1, rows, hd), lambda h, i, p, ql, cu, pt, cl: (h, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((max_q * gp, LANES), jnp.float32),
-            pltpu.VMEM((max_q * gp, LANES), jnp.float32),
-            pltpu.VMEM((max_q * gp, hd), jnp.float32),
+            pltpu.VMEM((win, LANES), jnp.float32),
+            pltpu.VMEM((win, LANES), jnp.float32),
+            pltpu.VMEM((win, hd), jnp.float32),
         ],
     )
-    with jax.named_scope("ragged_paged_attention"):
+    with jax.named_scope(name):
         out = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((t_pad, kvh, gp, hd), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((kvh, rows, hd), q.dtype),
             compiler_params=vmem_params(
-                blocks=[((t_pad, gp, hd), q.dtype)] * 2
+                blocks=[((rows, hd), q.dtype)] * 2
                 + [((ps, hd), k_pages.dtype)] * 2,
-                scratch=[((max_q * gp, LANES), jnp.float32)] * 2
-                + [((max_q * gp, hd), jnp.float32)]),
+                scratch=[((win, LANES), jnp.float32)] * 2
+                + [((win, hd), jnp.float32)]),
             interpret=interpret,
-            name="ragged_paged_attention",
+            name=name,
         )(q_lens.astype(jnp.int32), cu_q.astype(jnp.int32),
           page_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
           qg, k_pages, v_pages)
-    return out[:t, :, :g, :].reshape(t, nh, hd)
+    out = out[:, :t * gp].reshape(kvh, t, gp, hd)[:, :, :g]
+    return out.transpose(1, 0, 2, 3).reshape(t, nh, hd)
 
 
 def ragged_paged_attention(q: jax.Array, k_pages: jax.Array,
@@ -525,15 +574,19 @@ def _make_latent_kernel(scale: float, ps: int, maxp: int, max_q: int,
     return kernel
 
 
+@functools.partial(jax.jit, static_argnames=(
+    "max_q", "softmax_scale", "quant", "latent_dim", "interpret", "name"))
 def latent_ragged_paged_attention_pallas(
         q: jax.Array, c_pages: jax.Array, r_pages: Optional[jax.Array],
         q_lens: jax.Array, cu_q: jax.Array, page_tables: jax.Array,
         ctx_lens: jax.Array, *, max_q: int, softmax_scale: float,
         scale_pages: Optional[jax.Array] = None,
         quant: Optional[str] = None, latent_dim: Optional[int] = None,
-        interpret: Optional[bool] = None) -> jax.Array:
+        interpret: Optional[bool] = None,
+        name: str = "latent_ragged_paged_attention") -> jax.Array:
     """Pallas latent ragged paged attention (same contract as
-    :func:`latent_ragged_paged_attention_reference`)."""
+    :func:`latent_ragged_paged_attention_reference`; ``name`` is the
+    Mosaic call's name on the device trace)."""
     nh, ps, d_c, d_r = _check_latent_shapes(q, c_pages, r_pages, quant,
                                             latent_dim)
     t = q.shape[0]
@@ -585,7 +638,7 @@ def latent_ragged_paged_attention_pallas(
             pltpu.VMEM((max_q * gp, d_c), jnp.float32),
         ],
     )
-    with jax.named_scope("latent_ragged_paged_attention"):
+    with jax.named_scope(name):
         out = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
@@ -597,7 +650,7 @@ def latent_ragged_paged_attention_pallas(
                 scratch=[((max_q * gp, LANES), jnp.float32)] * 2
                 + [((max_q * gp, d_c), jnp.float32)]),
             interpret=interpret,
-            name="latent_ragged_paged_attention",
+            name=name,
         )(q_lens.astype(jnp.int32), cu_q.astype(jnp.int32),
           page_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
           *operands)

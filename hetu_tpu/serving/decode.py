@@ -21,10 +21,12 @@ over a fixed-shape **ragged token batch** (DESIGN.md §12):
   MXU-shaped matmul for mixed prefill+decode, the core RPA win),
   scatter-writes each token's k/v into its page at ``(token_page,
   token_off)`` (padding tokens land in the trash page), and attends
-  raggedly: the Pallas kernel on TPU, or — off TPU — a split dense
-  reference whose decode half IS ``paged_attention_reference`` (the
-  bit-for-bit-proven v1 decode math) and whose chunk half is the same
-  gather+masked-dense attention with a causal in-row mask;
+  raggedly, one call per REGION of the layout (decode slots, chunk
+  slots, verify slots): the Pallas kernel on TPU, its query window the
+  region's own width, or — off TPU — a split dense reference whose
+  decode half IS ``paged_attention_reference`` (the bit-for-bit-proven
+  v1 decode math) and whose chunk half is the same gather+masked-dense
+  attention with a causal in-row mask;
 - sampling is ON DEVICE for every mode: greedy argmax (bit-for-bit the
   ``jnp.argmax`` solo ``generate()`` runs), or temperature / top-k /
   top-p (nucleus) from a per-row params vector, keyed by
@@ -252,6 +254,37 @@ def _split_latent_ragged_attention(cfg: GPTConfig, q_cat, cp, rp, q_lens,
     return jnp.concatenate(outs, axis=0)
 
 
+def _attend_by_region(kernel, name: str, q, q_lens, cu_q, page_tables,
+                      ctx_lens, max_seqs: int, prefill_rows: int,
+                      chunk: int, spec_k: int = 0):
+    """On-TPU ragged attention over the structured serving layout: one
+    ``kernel`` call per REGION of the static token axis, so a grid step
+    computes a query window of the region's own width — one token for
+    the ``max_seqs`` decode slots, ``chunk`` for the prefill chunk
+    slots, ``spec_k + 1`` for the verify slots — and not the widest
+    region's for every row.  ``kernel`` is a ragged Pallas op with its
+    pages bound (``functools.partial``); each call gets, by keyword, its
+    slice of the token and row axes with ``cu_q`` rebased to the slice,
+    and the outputs are concatenated on the token axis.  On the device
+    trace the calls are ``<name>_decode`` / ``_chunk`` / ``_verify``."""
+    slots = _chunk_slots(max_seqs, prefill_rows, chunk, spec_k)
+    regions = [("decode", 0, 0, max_seqs, 1)]
+    for tag, part in (("chunk", slots[:prefill_rows]),
+                      ("verify", slots[prefill_rows:])):
+        if part:
+            row, tok, width = part[0]
+            regions.append((tag, row, tok, len(part), width))
+    outs = []
+    for tag, row, tok, n, width in regions:
+        rows, toks = slice(row, row + n), slice(tok, tok + n * width)
+        outs.append(kernel(
+            q=q[toks], q_lens=q_lens[rows],
+            cu_q=cu_q[row: row + n + 1] - tok,
+            page_tables=page_tables[rows], ctx_lens=ctx_lens[rows],
+            max_q=width, name=f"{name}_{tag}"))
+    return jnp.concatenate(outs, axis=0)
+
+
 # the on-device per-row sampler lives next to the verify head in
 # ops/ragged_paged_attention.py (ONE implementation: the speculative
 # accept rule is "the draft matches this sampler's keyed choice", which
@@ -288,6 +321,12 @@ def build_unified_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
     the end of its accumulated sequence (``pos + q_len == len(tokens)``
     — i.e. the final prefill chunk or a decode step).  ALL shapes are
     fixed: the engine compiles this exactly once.
+
+    Attention is issued per region of that layout, not once over all
+    rows (:func:`_attend_by_region` with the kernel,
+    ``_split_ragged_attention`` without): a decode slot's grid steps
+    compute a one-token query tile, a chunk slot's a ``chunk``-token
+    one.  The regions are the layout itself — nothing selects them.
 
     ``spec_k > 0`` (speculative serving, DESIGN.md §20) grows BOTH the
     layout and the signature.  The token axis gains ``max_seqs``
@@ -431,12 +470,16 @@ def build_unified_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
                     rp = None if (page_quant or not d_r) else vp
                     sp = vp if page_quant else None
                     if use_kernel:
-                        o_lat = latent_ragged_paged_attention_pallas(
-                            q_cat, kp, rp, q_lens, cu_q, page_tables,
-                            ctx_lens, max_q=max(chunk, spec_k + 1),
-                            softmax_scale=(hd + d_r) ** -0.5,
-                            scale_pages=sp, quant=page_quant,
-                            latent_dim=d_c)
+                        o_lat = _attend_by_region(
+                            functools.partial(
+                                latent_ragged_paged_attention_pallas,
+                                c_pages=kp, r_pages=rp,
+                                softmax_scale=(hd + d_r) ** -0.5,
+                                scale_pages=sp, quant=page_quant,
+                                latent_dim=d_c),
+                            "latent_ragged_paged_attention", q_cat,
+                            q_lens, cu_q, page_tables, ctx_lens,
+                            max_seqs, prefill_rows, chunk, spec_k)
                     else:
                         o_lat = _split_latent_ragged_attention(
                             c, q_cat, kp, rp, q_lens, page_tables, ctx_lens,
@@ -478,9 +521,13 @@ def build_unified_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
                     vp = v_pages[i].at[at].set(v.astype(cdt))
                 with phase("attn_core"):
                     if use_kernel:
-                        attn = ragged_paged_attention_pallas(
-                            q, kp, vp, q_lens, cu_q, page_tables, ctx_lens,
-                            max_q=max(chunk, spec_k + 1))
+                        attn = _attend_by_region(
+                            functools.partial(
+                                ragged_paged_attention_pallas,
+                                k_pages=kp, v_pages=vp),
+                            "ragged_paged_attention", q, q_lens, cu_q,
+                            page_tables, ctx_lens, max_seqs,
+                            prefill_rows, chunk, spec_k)
                     else:
                         attn = _split_ragged_attention(
                             c, q, kp, vp, q_lens, page_tables, ctx_lens,

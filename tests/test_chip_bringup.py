@@ -9,6 +9,7 @@
   engine's platform rule, the hash-keyed native build, and the flash
   kernel's placement under a dp x tp mesh.
 """
+import functools
 import importlib
 import json
 import os
@@ -53,9 +54,20 @@ def _kernel_cases():
             q, k, v, causal=True).astype(F32).sum(), argnums=(0, 1, 2))(
                 q, k, v)
 
-    def ragged(q, kp, vp, *d):
+    def ragged(q, kp, vp, *d, max_q=CHUNK):
         return rpa.ragged_paged_attention_pallas(
-            q, kp, vp, *d, max_q=CHUNK, interpret=False)
+            q, kp, vp, *d, max_q=max_q, interpret=False)
+
+    def region(rows, max_q):
+        # one region of the serving step at the benchmark's widths
+        # (Cerebras-GPT-590M: 12 kv heads x 128, 64-token pages, 32
+        # pages a row)
+        t, maxp = rows * max_q, 32
+        return (functools.partial(ragged, max_q=max_q), (
+            _sds((t, 12, 128), BF16),
+            *(_sds((PAGES, 12, PAGE, 128), BF16),) * 2,
+            _sds((rows,), I32), _sds((rows + 1,), I32),
+            _sds((rows, maxp), I32), _sds((rows,), I32)))
 
     def latent(quant):
         def run(q, cp, side, *d):
@@ -73,6 +85,8 @@ def _kernel_cases():
         "flash_split": (flash_grads, qkv(8192)),
         "ragged_12kv_x64": (ragged, (_sds((T, 12, 64), BF16), *pages,
                                      *_desc())),
+        "ragged_decode_region": region(32, 1),
+        "ragged_chunk_region": region(1, CHUNK),
         "latent_512_64": (latent(None), (
             _sds((T, 16, 576), F32), _sds((PAGES, 1, PAGE, 512), BF16),
             _sds((PAGES, 1, PAGE, 64), BF16), *_desc())),
@@ -88,7 +102,10 @@ def _kernel_cases():
 # the Mosaic compile is seconds per kernel and tier-1 has none to spare:
 # it takes the three that broke there — the k/v block, scoped VMEM at a
 # 256-token chunk of d_c 512, the 4-bit unpack; flash compiled as it was
-AOT_CASES = ("ragged_12kv_x64", "latent_512_64", "latent_nf4")
+# — and the serving step's one-token window, whose 16-row bf16 tile is
+# the layout most likely to be refused
+AOT_CASES = ("ragged_12kv_x64", "ragged_decode_region", "latent_512_64",
+             "latent_nf4")
 
 
 @pytest.fixture

@@ -533,6 +533,25 @@ def test_latent_kernel_matches_reference(quant, d_r):
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("fixture", ["mla", "mla_rot"])
+def test_latent_kernel_backed_step_end_to_end(fixture, request):
+    """The latent call site goes through the same per-region helper as
+    the full-head one: the whole executable with the latent kernel
+    (interpret mode) serves the dense-fallback executable's greedy
+    tokens, decode rows, chunked prompts and a late arrival included."""
+    lstate, lcfg = request.getfixturevalue(fixture)[-2:]
+    prompts = [[5, 17, 2, 9, 33, 12, 8, 1, 4, 6, 7], [3, 2, 1]]
+    outs = {}
+    for uk in (False, True):
+        eng = _make_engine(lstate, lcfg, num_pages=9, page_size=8,
+                           max_batch=2, chunk_size=8, use_kernel=uk)
+        reqs = [eng.add_request(p, 5, arrival_time=float(2 * i))
+                for i, p in enumerate(prompts)]
+        _drain(eng)
+        outs[uk] = [r.out_tokens for r in reqs]
+    assert outs[False] == outs[True]
+
+
 # ---------------------------------------------------------------------------
 # observability
 # ---------------------------------------------------------------------------

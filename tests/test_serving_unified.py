@@ -19,6 +19,7 @@ Covers the tentpole contracts the v1 bucketed engine could not offer:
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 import hetu_tpu as ht
@@ -26,7 +27,8 @@ from hetu_tpu.models import GPTConfig, GPTLMHeadModel
 from hetu_tpu.models.generate import generate
 from hetu_tpu.ops.ragged_paged_attention import (
     ragged_paged_attention_pallas, ragged_paged_attention_reference)
-from hetu_tpu.serving import Engine
+from hetu_tpu.serving import Engine, SpecConfig
+from hetu_tpu.serving.decode import _attend_by_region
 
 CFG_KW = dict(vocab_size=97, hidden_size=32, num_layers=2, num_heads=4,
               max_seq_len=64, sp=False, dropout=0.0)
@@ -146,6 +148,123 @@ def test_ragged_reference_matches_per_token_oracle():
             want = np.einsum("hl,lhd->hd", p, vv)
             np.testing.assert_allclose(got[int(cu[i]) + j], want,
                                        rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# the step's attention, one kernel call per region of the static layout
+# ---------------------------------------------------------------------------
+
+# 4 decode slots, 2 chunk slots of 8 tokens, spec_k = 2 -> 4 verify slots
+# of 3 tokens: live and idle decode rows, a partial chunk, an idle chunk
+# slot, full / partial / idle verify rows
+REGION_LAYOUT = dict(max_seqs=4, prefill_rows=2, chunk=8, spec_k=2)
+REGION_Q_LENS = [1, 0, 1, 1, 5, 0, 3, 0, 2, 0]
+REGION_CTX = [13, 0, 1, 24, 21, 0, 9, 0, 17, 0]
+
+
+def _pallas_calls(jaxpr):
+    """(name, [scratch shape, ...]) of every pallas_call under a jaxpr."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append((eqn.params["name"], [
+                a.shape for a in eqn.params["grid_mapping"].scratch_avals]))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _pallas_calls(sub)
+    return found
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 1e-2)])
+@pytest.mark.parametrize("ps", [8, 16])
+@pytest.mark.parametrize("g", [1, 4])
+def test_region_split_attention_matches_reference(g, ps, dtype, tol):
+    """The serving step's kernel path — one call per region, each with
+    the region's own query window — against the dense reference run
+    once over the whole layout at the widest window.  Every token is
+    compared: tokens no row owns read as 0 on both sides."""
+    lay = REGION_LAYOUT
+    rng = np.random.RandomState(5)
+    kvh, hd, maxp = 2, 32, 4
+    nh = kvh * g
+    vk = lay["spec_k"] + 1
+    s, r, ck = lay["max_seqs"], lay["prefill_rows"], lay["chunk"]
+    cu = np.concatenate([np.arange(s), s + ck * np.arange(r),
+                         s + r * ck + vk * np.arange(s + 1)])
+    t = int(cu[-1])
+    q_lens = np.asarray(REGION_Q_LENS, np.int32)
+    ctx = np.asarray(REGION_CTX, np.int32)
+    assert len(cu) == len(q_lens) + 1 and maxp * ps >= ctx.max()
+    num_pages = 1 + sum(-(-int(c) // ps) for c in ctx)
+    pt = np.zeros((len(q_lens), maxp), np.int32)   # idle -> trash page 0
+    perm, k = rng.permutation(np.arange(1, num_pages)), 0
+    for i, c in enumerate(ctx):
+        need = -(-int(c) // ps)
+        pt[i, :need] = perm[k:k + need]
+        k += need
+    q = jnp.asarray(rng.randn(t, nh, hd), dtype)
+    kp = jnp.asarray(rng.randn(num_pages, kvh, ps, hd), dtype)
+    vp = jnp.asarray(rng.randn(num_pages, kvh, ps, hd), dtype)
+    desc = tuple(jnp.asarray(a.astype(np.int32))
+                 for a in (q_lens, cu, pt, ctx))
+    names = []
+
+    def kernel(name, **kw):
+        names.append(name)
+        return ragged_paged_attention_pallas(
+            k_pages=kp, v_pages=vp, name=name, interpret=True, **kw)
+
+    got = _attend_by_region(kernel, "ragged_paged_attention", q, *desc,
+                            **lay)
+    ref = ragged_paged_attention_reference(q, kp, vp, *desc,
+                                           max_q=max(ck, vk))
+    assert names == ["ragged_paged_attention_decode",
+                     "ragged_paged_attention_chunk",
+                     "ragged_paged_attention_verify"]
+    assert got.dtype == q.dtype and got.shape == (t, nh, hd)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(ref, np.float32),
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("kind", ["full_head", "spec", "latent"])
+def test_step_lowers_one_kernel_call_per_region(kind):
+    """What the benchmark's readers rest on, read off the lowered step:
+    every ragged call is named ``<op>_<region>``, and a region's query
+    tile is sized by its own window — one sublane tile for the one-token
+    decode slots (not ``chunk`` tokens x 8 padded group rows), ``chunk``
+    tokens (+ the alignment slack) for the chunk slots.  The latent
+    (MLA) call site goes through the same helper: its decode window is
+    one token x the padded head group."""
+    from hetu_tpu.graph.graph import get_executable
+    from hetu_tpu.models.gpt import mla_state_from
+    cfg = GPTConfig(position="learned", norm="layernorm",
+                    activation="gelu", vocab_size=97, hidden_size=32,
+                    num_layers=2, num_heads=4, max_seq_len=32, sp=False,
+                    dropout=0.0)
+    state = _build_state(cfg, seed=4)
+    chunk, k, sub = 16, 2, 8         # float32 pools: 8-row tiles
+    op, spec = "ragged_paged_attention", None
+    if kind == "spec":
+        spec = SpecConfig(dict(state), cfg, k=k)
+    elif kind == "latent":
+        state, cfg = mla_state_from(state, cfg, kv_latent_dim=16)
+        op = "latent_" + op
+    _make_engine(state, cfg, num_pages=9, page_size=8, max_batch=2,
+                 chunk_size=chunk, use_kernel=True, spec=spec,
+                 name="regions")
+    calls = _pallas_calls(get_executable("regions/unified").jaxpr.jaxpr)
+    want = ["decode", "chunk"] + (["verify"] if spec else [])
+    assert [n for n, _ in calls] == [f"{op}_{w}" for w in want] \
+        * cfg.num_layers
+    tiles = {n.rsplit("_", 1)[1]: scr[2][0] for n, scr in calls}
+    if kind == "latent":             # 4 heads padded to 8 rows a token
+        assert tiles == {"decode": 1 * sub, "chunk": chunk * sub}
+        return
+    assert tiles["decode"] == sub    # g = 1: one row a token
+    assert chunk <= tiles["chunk"] < chunk + 2 * sub
+    if spec:
+        assert k + 1 <= tiles["verify"] < k + 1 + 2 * sub
 
 
 def test_kernel_backed_unified_step_end_to_end():
