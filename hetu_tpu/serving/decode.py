@@ -19,8 +19,11 @@ over a fixed-shape **ragged token batch** (DESIGN.md §12):
   :mod:`~hetu_tpu.ops.ragged_paged_attention` kernel prefetches;
 - every layer runs the projections/MLP over the WHOLE token axis (one
   MXU-shaped matmul for mixed prefill+decode, the core RPA win),
-  scatter-writes each token's k/v into its page at ``(token_page,
-  token_off)`` (padding tokens land in the trash page), and attends
+  writes the LIVE tokens' k/v into their pages at ``(token_page,
+  token_off)`` — on TPU as page-runs, one Pallas call a layer for K and
+  V (:mod:`~hetu_tpu.ops.paged_kv_write`; padding slots and idle rows
+  write nothing), off TPU as the plain scatter over every slot (padding
+  lands in the trash page) — and attends
   raggedly, one call per REGION of the layout (decode slots, chunk
   slots, verify slots): the Pallas kernel on TPU, its query window the
   region's own width, or — off TPU — a split dense reference whose
@@ -48,6 +51,8 @@ from ..models.generate import (_act, _lm_head, _moe_mlp, _norm_apply,
 from ..models.gpt import GPTConfig
 from ..obs.phases import phase
 from ..ops.paged_attention import gather_pages, paged_attention_reference
+from ..ops.paged_kv_write import (kv_write_plan, paged_kv_write,
+                                  paged_kv_write_reference, write_tile)
 from ..ops.quantization import quantize_rows
 from ..ops.ragged_paged_attention import (_dequant_latent,
                                           latent_paged_attention_reference,
@@ -254,6 +259,22 @@ def _split_latent_ragged_attention(cfg: GPTConfig, q_cat, cp, rp, q_lens,
     return jnp.concatenate(outs, axis=0)
 
 
+def _regions(max_seqs: int, prefill_rows: int, chunk: int, spec_k: int):
+    """The static token axis as regions of equal-width rows: a list of
+    ``(tag, first_row, first_token, rows, width)`` — the decode slots,
+    the chunk slots and (spec mode) the verify slots.  Attention and the
+    KV write are both issued per region; nothing selects one at run
+    time."""
+    slots = _chunk_slots(max_seqs, prefill_rows, chunk, spec_k)
+    regions = [("decode", 0, 0, max_seqs, 1)]
+    for tag, part in (("chunk", slots[:prefill_rows]),
+                      ("verify", slots[prefill_rows:])):
+        if part:
+            row, tok, width = part[0]
+            regions.append((tag, row, tok, len(part), width))
+    return regions
+
+
 def _attend_by_region(kernel, name: str, q, q_lens, cu_q, page_tables,
                       ctx_lens, max_seqs: int, prefill_rows: int,
                       chunk: int, spec_k: int = 0):
@@ -267,15 +288,9 @@ def _attend_by_region(kernel, name: str, q, q_lens, cu_q, page_tables,
     slice of the token and row axes with ``cu_q`` rebased to the slice,
     and the outputs are concatenated on the token axis.  On the device
     trace the calls are ``<name>_decode`` / ``_chunk`` / ``_verify``."""
-    slots = _chunk_slots(max_seqs, prefill_rows, chunk, spec_k)
-    regions = [("decode", 0, 0, max_seqs, 1)]
-    for tag, part in (("chunk", slots[:prefill_rows]),
-                      ("verify", slots[prefill_rows:])):
-        if part:
-            row, tok, width = part[0]
-            regions.append((tag, row, tok, len(part), width))
     outs = []
-    for tag, row, tok, n, width in regions:
+    for tag, row, tok, n, width in _regions(max_seqs, prefill_rows, chunk,
+                                            spec_k):
         rows, toks = slice(row, row + n), slice(tok, tok + n * width)
         outs.append(kernel(
             q=q[toks], q_lens=q_lens[rows],
@@ -306,8 +321,10 @@ def build_unified_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
 
     fn(params,
        tokens [T] i32, token_pos [T] i32,
-       token_page [T] i32, token_off [T] i32,   # KV write plan (trash
-                                                # page for padding)
+       token_page [T] i32, token_off [T] i32,   # KV write plan: where
+                                                # each token's k/v goes
+                                                # (trash page, offset 0
+                                                # for padding)
        q_lens [rows] i32, cu_q [rows+1] i32,
        page_tables [rows, max_pages] i32, ctx_lens [rows] i32,
        temps [rows] f32, top_ps [rows] f32,
@@ -327,6 +344,12 @@ def build_unified_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
     ``_split_ragged_attention`` without): a decode slot's grid steps
     compute a one-token query tile, a chunk slot's a ``chunk``-token
     one.  The regions are the layout itself — nothing selects them.
+    The KV write follows the same regions: with the kernel, a row's
+    tokens (consecutive positions of one sequence) are written as the
+    page-runs they fall into, cut into the pool's packed tiles
+    (``kv_write_plan`` once a step, ``paged_kv_write`` once a layer),
+    and a slot that holds no token writes nothing; without it, the
+    plain scatter writes every slot and padding lands in the trash page.
 
     ``spec_k > 0`` (speculative serving, DESIGN.md §20) grows BOTH the
     layout and the signature.  The token axis gains ``max_seqs``
@@ -365,6 +388,9 @@ def build_unified_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
     cos, sin = (_rotary_tables(c, max_len) if c.position == "rotary"
                 else (None, None))
     hd, nh, nkv = c.head_dim, c.num_heads, c.kv_heads
+    write_regions = tuple(
+        (row, n, width) for _, row, _, n, width
+        in _regions(max_seqs, prefill_rows, chunk, spec_k))
 
     def region_map(f, h, q_lens, f_chunk=None):
         """Apply a row-wise map ``f`` per region: unconditionally over
@@ -394,7 +420,8 @@ def build_unified_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
         return jnp.concatenate(parts, axis=0)
 
     # pages are donated (the pool replaces them wholesale every call, so
-    # XLA scatters in place); seeds is donated so the [rows] int32
+    # the KV write updates them in place); seeds is donated so the [rows]
+    # int32
     # next-token output can alias it instead of tripping donation-miss
     # (spec mode additionally donates spec_lens to back the [rows]
     # accepted output)
@@ -408,6 +435,24 @@ def build_unified_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
             x = p("wte.weight")[tokens].astype(cdt)        # [T, H]
             if c.position == "learned":
                 x = x + p("wpe")[token_pos].astype(x.dtype)
+        if use_kernel:
+            # the write's page-run pieces, once for every layer
+            tile = write_tile((k_pages[0], v_pages[0]))
+            with phase("kv_scatter"):
+                plan = kv_write_plan(token_page, token_off, q_lens, cu_q,
+                                     regions=write_regions,
+                                     page_size=page_size, tile=tile)
+
+        def write_kv(pools, news):
+            """This step's new rows into a layer's pools: the live
+            tokens' page-runs with the kernel, every slot of the token
+            axis (padding to the trash page) with the reference."""
+            with phase("kv_scatter"):
+                if use_kernel:
+                    return paged_kv_write(pools, news, plan, tile=tile)
+                return paged_kv_write_reference(pools, news, token_page,
+                                                token_off)
+
         new_k, new_v = [], []
         for i in range(c.num_layers):
             with phase("norm"):
@@ -450,22 +495,20 @@ def build_unified_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
                             [q_abs, q_rope.astype(jnp.float32)], -1)
                     else:
                         q_cat = q_abs
-                with phase("kv_scatter"):
-                    if page_quant:
-                        codes, am = quantize_rows(c_kv, page_quant)
-                        kp = k_pages[i].at[token_page, 0, token_off].set(
-                            codes)
-                        vp = v_pages[i].at[token_page, 0, token_off].set(
-                            am)
-                    else:
-                        kp = k_pages[i].at[token_page, 0, token_off].set(
-                            c_kv.astype(cdt))
-                        if d_r:
-                            vp = v_pages[i].at[
-                                token_page, 0, token_off].set(
-                                k_rope.astype(cdt))
-                        else:
-                            vp = v_pages[i]        # width-0 rope stream
+                if page_quant:
+                    kp, vp = write_kv(
+                        (k_pages[i], v_pages[i]),
+                        [x[:, None] for x in quantize_rows(c_kv,
+                                                           page_quant)])
+                elif d_r:
+                    kp, vp = write_kv(
+                        (k_pages[i], v_pages[i]),
+                        (c_kv.astype(cdt)[:, None],
+                         k_rope.astype(cdt)[:, None]))
+                else:
+                    kp, = write_kv((k_pages[i],),
+                                   (c_kv.astype(cdt)[:, None],))
+                    vp = v_pages[i]                # width-0 rope stream
                 with phase("attn_core"):
                     rp = None if (page_quant or not d_r) else vp
                     sp = vp if page_quant else None
@@ -508,17 +551,8 @@ def build_unified_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
                     if c.position == "rotary":
                         q = _rope_tok(q, cos[token_pos], sin[token_pos])
                         k = _rope_tok(k, cos[token_pos], sin[token_pos])
-                with phase("kv_scatter"):
-                    # one [hd] row per (page, head, offset) index: the
-                    # written window is the pages' minor dim, so at a
-                    # head_dim that fills the 128 lanes XLA scatters in
-                    # place — a [kvh, hd] window per token makes it
-                    # re-lay the whole pool out and back (as does a
-                    # 64-wide head either way: CHANGES.md, PR 21)
-                    at = (token_page[:, None], jnp.arange(nkv)[None, :],
-                          token_off[:, None])
-                    kp = k_pages[i].at[at].set(k.astype(cdt))
-                    vp = v_pages[i].at[at].set(v.astype(cdt))
+                kp, vp = write_kv((k_pages[i], v_pages[i]),
+                                  (k.astype(cdt), v.astype(cdt)))
                 with phase("attn_core"):
                     if use_kernel:
                         attn = _attend_by_region(

@@ -42,7 +42,8 @@ preemption.  Cache-hit and cache-cold runs are bit-for-bit identical
 at temperature 0: the kernel reads identical page contents either way.
 
 Observability (utils/metrics.py instruments): counters
-``tokens_generated``/``prefill_tokens``/``requests_completed``/
+``tokens_generated``/``prefill_tokens``/``kv_tokens_written``/
+``requests_completed``/
 ``preemptions``/``decode_steps``/``prefill_chunks``/``step_calls``/
 ``prefix_cache_hits``/``prefix_cache_misses``/
 ``prefix_cache_tokens_saved``/``prefix_cache_evictions``,
@@ -62,8 +63,10 @@ with their token-budget slice, per-token instants, ``preempt`` and
 ``finish`` — plus the scheduler's ``pack`` decision per step, a
 ``unified_step`` span per executable call (``exec=`` names the registered
 executable; ``obs.reconcile()`` joins the analysis plane's predictions
-on it) and, around the whole of ``step()``, an ``engine_step`` span tiled
-by its host phases ``step.admit`` (admission, prefix-cache match, draft
+on it; ``kv_tokens=`` / ``kv_runs=`` are the tokens its KV write moves
+and the page-runs they fall into) and, around the whole of ``step()``,
+an ``engine_step`` span tiled by its host phases
+``step.admit`` (admission, prefix-cache match, draft
 staging), ``step.pages`` (decode pages, preemption), ``step.pack``
 (packing decision + host arrays), ``step.tap`` (the analysis tap's
 copy), ``step.h2d`` (host-to-device copies), ``step.dispatch`` (the
@@ -201,6 +204,7 @@ class Engine:
         m = metrics
         self.counters = {k: make_instrument("counter", k, m) for k in
                          ("tokens_generated", "prefill_tokens",
+                          "kv_tokens_written",
                           "requests_completed", "preemptions",
                           "decode_steps", "prefill_chunks",
                           "step_calls",
@@ -841,6 +845,7 @@ class Engine:
         (tokens, token_pos, token_page, token_off, q_lens, page_tables,
          ctx_lens, temps, top_ps, top_ks, seeds,
          spec_lens) = self._pack_arrays(rows)
+        kv_tokens = sum(q for _, q, _ in rows)   # every fed token's KV
         tr = self.tracer
         traced = tr.enabled
         if traced:
@@ -895,15 +900,21 @@ class Engine:
         self.pool.set_pages(new_k, new_v)
         self._calls += 1
         self.counters["step_calls"].inc()
+        self.counters["kv_tokens_written"].inc(kv_tokens)
         if traced:
             # the span every reconciliation row hangs off: exec= names
             # the registered ExecutableHandle (obs.reconcile looks the
             # static predictions up by it at report time)
-            n_tokens = int(sum(q for _, q, _ in rows))
-            self._step_attrs.update(rows=len(rows), tokens=n_tokens)
+            self._step_attrs.update(rows=len(rows), tokens=kv_tokens)
+            # the KV write moves a row's tokens in one run per page its
+            # positions touch (req.pos is still the step's first)
+            ps = self.pool.page_size
+            kv_runs = sum((req.pos + q - 1) // ps - req.pos // ps + 1
+                          for req, q, _ in rows)
             tr.complete("unified_step", t0, dt, track="engine",
                         exec=f"{self.name}/unified", rows=len(rows),
-                        tokens=n_tokens)
+                        tokens=kv_tokens, kv_tokens=kv_tokens,
+                        kv_runs=kv_runs)
         # classify by SLOT, not q_len: a chunk_size=1 prefill chunk is
         # still a prefill chunk, and a verify row is neither
         n_decode = sum(1 for _, _, row in rows if row < s)

@@ -10,9 +10,12 @@ Attention storage layout, PAPERS.md arxiv 2604.15464).
 
 Page 0 is a reserved **trash page**: every padded page-table slot (the
 tail of a request's table, dummy batch slots) points at it, so the
-jitted prefill/decode programs can scatter-write unconditionally with
-static shapes — writes land in the trash page, reads past ``seq_len``
-are masked by the attention op.  It is never allocated.
+jitted step reads through static-shape page tables unconditionally —
+reads past ``seq_len`` are masked by the attention op.  It is never
+allocated.  The write plan aims padding token slots at it too: the
+plain scatter of the CPU path (``ops.paged_kv_write_reference``) writes
+them there; the TPU path's page-run write (``ops/paged_kv_write.py``)
+writes only tokens that exist and leaves the page alone.
 
 Layout: pages are ``[num_pages, kv_heads, page_size, head_dim]`` — the
 trailing ``(page_size, head_dim)`` tile is what the Pallas kernels DMA

@@ -30,6 +30,7 @@ import hetu_tpu as ht  # noqa: E402
 from hetu_tpu import ops  # noqa: E402
 
 rpa = importlib.import_module("hetu_tpu.ops.ragged_paged_attention")
+kvw = importlib.import_module("hetu_tpu.ops.paged_kv_write")
 fa = importlib.import_module("hetu_tpu.ops.pallas.flash_attention")
 
 I32, BF16, F32 = jnp.int32, jnp.bfloat16, jnp.float32
@@ -77,6 +78,25 @@ def _kernel_cases():
                 quant=quant, latent_dim=512, interpret=False)
         return run
 
+    def kv_write(*widths):
+        # a layer's KV write at the serving step's token axis (32 decode
+        # slots + one 256-token chunk): (heads, row width, dtype) a pool
+        t = 32 + CHUNK
+
+        def run(tp, to, ql, cu, *arrays):
+            pools, news = arrays[:len(widths)], arrays[len(widths):]
+            tile = kvw.write_tile(pools)
+            plan = kvw.kv_write_plan(
+                tp, to, ql, cu, regions=((0, 32, 1), (32, 1, CHUNK)),
+                page_size=PAGE, tile=tile)
+            return kvw.paged_kv_write(pools, news, plan, tile=tile,
+                                      interpret=False)
+        return (run, (
+            _sds((t,), I32), _sds((t,), I32), _sds((33,), I32),
+            _sds((34,), I32),
+            *(_sds((PAGES, h, PAGE, w), dt) for h, w, dt in widths),
+            *(_sds((t, h, w), dt) for h, w, dt in widths)))
+
     qkv = lambda s: (_sds((1, s, 12, 64), BF16),) * 3   # noqa: E731
     pages = (_sds((PAGES, 12, PAGE, 64), BF16),) * 2
     return {
@@ -96,6 +116,11 @@ def _kernel_cases():
         "latent_nf4": (latent("nf4"), (
             _sds((T, 16, 512), F32), _sds((PAGES, 1, PAGE, 256), jnp.uint8),
             _sds((PAGES, 1, PAGE, 1), F32), *_desc())),
+        # the benchmark's pool (16-row bf16 tiles), and the two whose
+        # rows do not fill the lanes (written a page at a time)
+        "kv_write_12kv_x128": kv_write((12, 128, BF16), (12, 128, BF16)),
+        "kv_write_latent_512_64": kv_write((1, 512, BF16), (1, 64, BF16)),
+        "kv_write_int8_sidecar": kv_write((1, 512, jnp.int8), (1, 1, F32)),
     }
 
 
@@ -103,9 +128,10 @@ def _kernel_cases():
 # it takes the three that broke there — the k/v block, scoped VMEM at a
 # 256-token chunk of d_c 512, the 4-bit unpack; flash compiled as it was
 # — and the serving step's one-token window, whose 16-row bf16 tile is
-# the layout most likely to be refused
+# the layout most likely to be refused; of the KV write, the benchmark's
+# pool and the one written in whole one-lane pages
 AOT_CASES = ("ragged_12kv_x64", "ragged_decode_region", "latent_512_64",
-             "latent_nf4")
+             "latent_nf4", "kv_write_12kv_x128", "kv_write_int8_sidecar")
 
 
 @pytest.fixture

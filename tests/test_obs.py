@@ -574,12 +574,21 @@ def test_engine_step_phases_tile_engine_step(tiny_state):
         assert un.ts == kids[4].ts and un.end_ts == kids[6].end_ts
         assert par.attrs["rows"] == un.attrs["rows"] >= 1
         assert par.attrs["tokens"] == un.attrs["tokens"]
+        # the KV write's counters: every fed token is written, in at
+        # least one page-run a row and no more runs than tokens
+        assert un.attrs["kv_tokens"] == un.attrs["tokens"]
+        assert un.attrs["rows"] <= un.attrs["kv_runs"] \
+            <= un.attrs["kv_tokens"]
         assert {"queue_depth", "queue_due", "running",
                 "free_pages"} <= set(par.attrs)
         assert 0 <= par.attrs["queue_due"] <= par.attrs["queue_depth"]
     # names the benchmark reads are still there, none shadowed
     names = {e.name for e in events}
     assert {"admit", "pack", "queued", "running", "token"} <= names
+    assert eng.counters["kv_tokens_written"].value == sum(
+        e.attrs["kv_tokens"] for e in unified) == 5 + 2 + (3 - 1) + (2 - 1)
+    # the 5-token prompt fills positions 0..4 of an 8-token page: one run
+    assert unified[0].attrs["kv_runs"] == unified[0].attrs["rows"]
     # an idle step (nothing to run) still tiles: admit, pages, pack, commit
     eng.step()
     idle = [e for e in tracer.events() if e.ts >= parents[-1].end_ts

@@ -255,8 +255,12 @@ def test_step_lowers_one_kernel_call_per_region(kind):
                  name="regions")
     calls = _pallas_calls(get_executable("regions/unified").jaxpr.jaxpr)
     want = ["decode", "chunk"] + (["verify"] if spec else [])
-    assert [n for n, _ in calls] == [f"{op}_{w}" for w in want] \
-        * cfg.num_layers
+    # each layer: ONE KV write (K and V together), whose name the
+    # readers of ``ragged_paged_attention`` must not match, then the
+    # region calls
+    assert [n for n, _ in calls] == (
+        ["paged_kv_write"] + [f"{op}_{w}" for w in want]) * cfg.num_layers
+    calls = [c for c in calls if c[0] != "paged_kv_write"]
     tiles = {n.rsplit("_", 1)[1]: scr[2][0] for n, scr in calls}
     if kind == "latent":             # 4 heads padded to 8 rows a token
         assert tiles == {"decode": 1 * sub, "chunk": chunk * sub}
@@ -285,6 +289,42 @@ def test_kernel_backed_unified_step_end_to_end():
         _drain(eng)
         outs[uk] = [r.out_tokens for r in reqs]
     assert outs[False] == outs[True]
+
+
+def test_kernel_backed_step_under_prefix_cache_and_preemption():
+    """The page-run KV write where its runs are least regular: a cached
+    header (suffixes start mid-page behind shared pages), 4-token
+    chunks, late arrivals and a pool small enough to force cache
+    eviction AND recompute preemption.  Greedy outputs of the kernel
+    step (KV write and attention in interpret mode) equal the
+    ``use_kernel=False`` step's token for token, and both count the
+    same written tokens."""
+    cfg = GPTConfig(position="rotary", norm="rmsnorm",
+                    activation="swiglu", **CFG_KW)
+    state = _build_state(cfg, seed=13)
+    rng = np.random.RandomState(8)
+    header = [int(t) for t in rng.randint(1, 90, size=8)]
+    prompts = [header + [int(t) for t in rng.randint(1, 90, size=n)]
+               for n in (9, 2, 13, 5)]
+    outs, written = {}, {}
+    for uk in (False, True):
+        eng = _make_engine(state, cfg, num_pages=7, page_size=8,
+                           max_batch=3, chunk_size=4, use_kernel=uk)
+        eng.add_request(header + prompts[0][8:10], 2, arrival_time=0.0)
+        _drain(eng)
+        reqs = [eng.add_request(pr, 8,
+                                arrival_time=eng._test_clock[0] + i)
+                for i, pr in enumerate(prompts)]
+        _drain(eng)
+        m = eng.metrics_summary()
+        assert m["preemptions"] >= 1 and m["prefix_cache_hits"] >= 1
+        assert m["prefix_cache_evictions"] >= 1
+        assert eng.pool.used_pages == 0 and eng.compile_count == 1
+        outs[uk] = [r.out_tokens for r in reqs]
+        written[uk] = m["kv_tokens_written"]
+    assert outs[True] == outs[False]
+    assert all(len(o) == 8 for o in outs[True])
+    assert written[True] == written[False] > sum(map(len, prompts))
 
 
 # ---------------------------------------------------------------------------
