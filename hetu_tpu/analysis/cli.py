@@ -72,24 +72,30 @@ emits; then:
 * ``--hbm-budget``: device HBM budget in GiB for the ``oom-risk`` rule
   (default: the rule's v5p budget).
 
-The memory gate (on by default, with ``--tolerance``): per-executable
-predicted peak bytes are pinned in the baseline and may not grow; and
-every compiled executable's prediction must stay within ±10% of XLA's
-own ``compiled.memory_analysis()`` totals — a drifting memory model is
-itself a gate failure, so the planner numbers stay honest.
+The gate collects two kinds of problem and keeps them apart.
 
-The step-time gate works the same way: per-executable predicted FLOPs
-/ HBM bytes / step time are pinned in the baseline and may not grow
-beyond the tolerance, and every compiled executable's comparable FLOP
-and bytes-accessed totals must stay within ±10% (absolute floors for
-toy-scale programs) of XLA's own ``compiled.cost_analysis()`` — the
-same numbers ``planner.cost_model.calibrate_layer_time`` feeds the DP
-solver, so the planner search runs on cross-checked physics.
+*Structural* problems decide the exit code: collective counts by kind,
+GSPMD-inserted collectives, unexplained edges, lint findings, grad-comm
+emission, protocol and schedule coverage, the baseline-pinned predicted
+peak bytes / FLOPs / HBM bytes / step time (may not grow beyond
+``--tolerance``), and a static pass or an XLA cross-check that produced
+no report at all.
+
+*Predictor accuracy* does not: every compiled executable's static peak
+is compared with XLA's own ``compiled.memory_analysis()`` total, and its
+comparable FLOP and bytes-accessed totals with
+``compiled.cost_analysis()``, at ±10% (absolute floors for toy-scale
+programs).  What falls outside the band is printed under its own
+heading and carried in the JSON payload (``predictor_drift``); the
+tier-1 test ``tests/test_analysis.py::
+test_static_predictors_within_band_of_xla`` holds that list to empty
+(``xfail(strict=True)`` while the predictors are off).  These are the
+numbers ``planner.cost_model.calibrate_layer_time`` feeds the DP solver.
 
 Exit codes (stable, documented for CI): **0** clean, **1** findings or
-baseline regressions, **2** baseline missing (run ``--update-baseline``
-to create it — the missing-baseline check runs *before* the expensive
-build, so a misconfigured CI path fails fast).
+structural / baseline regressions, **2** baseline missing (run
+``--update-baseline`` to create it — the missing-baseline check runs
+*before* the expensive build, so a misconfigured CI path fails fast).
 
 The model shapes are deliberately frozen: the baseline pins exact
 collective counts, so any change to the lowering path (a new implicit
@@ -99,6 +105,7 @@ tests still pass numerically.
 from __future__ import annotations
 
 import argparse
+import json
 import os
 import sys
 
@@ -567,7 +574,8 @@ def run_gate(baseline_path: str = BASELINE_DEFAULT,
              schedule: bool = False,
              hbm_budget_gib: float = None, out=sys.stdout) -> int:
     """Build, analyze, gate.  Returns the process exit code
-    (0 clean / 1 findings / 2 baseline missing)."""
+    (0 clean / 1 findings or structural regressions / 2 baseline
+    missing); predictor drift against XLA is reported, not gated."""
     from . import (AnalysisReport, analyze_handle, get_executable,
                    load_baseline, save_baseline, verify_grad_comm)
 
@@ -603,7 +611,10 @@ def run_gate(baseline_path: str = BASELINE_DEFAULT,
 
     names = build_gate_executables()
     report = AnalysisReport()
-    problems = []
+    # ``problems`` are structural and decide the exit code; ``drift`` is
+    # predictor accuracy against XLA, reported only (module docstring) —
+    # an inaccurate predictor must not switch the sharding gate off
+    problems, drift = [], []
     for name in names:
         handle = get_executable(name)
         rep = report.add(analyze_handle(handle, compile=compile,
@@ -615,11 +626,11 @@ def run_gate(baseline_path: str = BASELINE_DEFAULT,
             except AssertionError as e:
                 problems.append(f"{name}: grad-comm emission drifted "
                                 f"from the DS prediction: {e}")
-        # XLA cross-check: the static model must stay within ±10% of
-        # compiled.memory_analysis() (abs floor for tiny programs) —
-        # a drifting memory model fails the gate even when the baseline
-        # peak is unchanged, and LOSING the cross-check (memory pass or
-        # memory_analysis gone) fails it too
+        # XLA cross-check: the static model is compared with
+        # compiled.memory_analysis() at ±10% (abs floor for tiny
+        # programs).  Being outside the band is predictor drift;
+        # LOSING the cross-check (memory pass or memory_analysis gone)
+        # is structural and fails the gate
         if compile:
             mem = rep.meta.get("memory")
             if mem is None:
@@ -629,14 +640,14 @@ def run_gate(baseline_path: str = BASELINE_DEFAULT,
                 problems.append(f"{name}: compiled.memory_analysis() "
                                 f"unavailable — XLA cross-check lost")
             elif not mem.xla_within(rel=0.1):
-                problems.append(
+                drift.append(
                     f"{name}: static peak {mem.cmp_peak_bytes} B drifted "
                     f"{mem.xla_delta():+.1%} from XLA's "
                     f"{mem.xla_total} B (±10% cross-check)")
             # step-time cross-check, same stance: FLOP and
-            # bytes-accessed totals within ±10% of cost_analysis()
-            # (absolute floors for toy-scale programs), and LOSING the
-            # accounting is itself a gate failure
+            # bytes-accessed totals against cost_analysis() at ±10%
+            # (absolute floors for toy-scale programs) are drift, and
+            # LOSING the accounting is itself a gate failure
             co = rep.meta.get("cost")
             if co is None:
                 problems.append(f"{name}: static cost pass produced "
@@ -646,7 +657,7 @@ def run_gate(baseline_path: str = BASELINE_DEFAULT,
                                 f"unavailable — XLA cross-check lost")
             elif not co.xla_within(rel=0.1):
                 fd, bd = co.xla_flops_delta(), co.xla_bytes_delta()
-                problems.append(
+                drift.append(
                     f"{name}: static cost drifted from XLA's "
                     f"cost_analysis (flops "
                     f"{fd:+.1%}, bytes "
@@ -654,7 +665,9 @@ def run_gate(baseline_path: str = BASELINE_DEFAULT,
                     if fd is not None and bd is not None else
                     f"{name}: static cost cross-check unavailable")
     if as_json:
-        print(report.to_json(records=True), file=out)
+        payload = report.to_dict(records=True)
+        payload["predictor_drift"] = drift
+        print(json.dumps(payload, indent=1, sort_keys=True), file=out)
     else:
         print(report.summary(), file=out)
         if memory:
@@ -667,6 +680,11 @@ def run_gate(baseline_path: str = BASELINE_DEFAULT,
             schedule_section(report, out=out)
     if explain:
         explain_report(report, out=out, memory=memory, cost=cost)
+    if drift:
+        print("\nSTATIC PREDICTORS OUTSIDE ±10% OF XLA "
+              "(reported, not gated):", file=out)
+        for d in drift:
+            print(f"  ~ {d}", file=out)
     if update:
         save_baseline(baseline_path, report)
         print(f"baseline written to {baseline_path}", file=out)

@@ -864,7 +864,7 @@ def schedule_summary(ctx) -> Dict[str, Any]:
 
 
 # ---------------------------------------------------------------------------
-# strategy grid + seeded-bug corpus (bench.py schedule_lint / tier-1)
+# strategy grid + seeded-bug corpus (the tier-1 schedule gate)
 # ---------------------------------------------------------------------------
 
 
@@ -918,7 +918,7 @@ def _clone(schedules: Dict[int, List[CommOp]]) -> Dict[int, List[CommOp]]:
 def seeded_bug_corpus() -> List[Dict[str, Any]]:
     """>= 6 injected cross-rank divergences, one per rule.  Each entry's
     mutated schedules must be flagged by EXACTLY its rule (asserted by
-    the vacuity meta-test and ``bench.py schedule_lint``)."""
+    the vacuity meta-test and ``test_schedule_gate_grid_and_corpus``)."""
     base = extract_schedules(_reference_spec())
     corpus: List[Dict[str, Any]] = []
 

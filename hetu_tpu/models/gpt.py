@@ -192,7 +192,7 @@ def mla_state_from(state, cfg: GPTConfig, kv_latent_dim: int,
 
     The factorization is the truncated SVD of the stacked per-head
     ``[W_k; W_v]`` — EXACT (up to fp rounding) whenever that stack has
-    rank <= d_c, which is how the bench accuracy gate builds its
+    rank <= d_c, which is how tests/test_mla_serving.py builds its
     equivalence witness.  Learned-position configs convert losslessly;
     rotary sources are approximate by construction (full-head rope
     content cannot live in a position-free latent — the decoupled rope

@@ -13,8 +13,8 @@ Cost model, same pattern as ``utils.metrics.NULL_INSTRUMENT``: the
 module-global default tracer is a shared no-op (``NULL_TRACER``), every
 emission site in the engine/train hot loops guards on ``tracer.enabled``
 and every no-op method swallows its arguments — disabled tracing costs
-a couple of attribute reads per *step* (asserted < 2% on the serving
-microbench, BENCH_OBS.json).  A real :class:`SpanTracer` can also be
+a couple of attribute reads per *step* (what tracing ON costs a chip
+run is in ``PERF.md``, PR 25).  A real :class:`SpanTracer` can also be
 switched off in place (``tracer.enabled = False``) without losing its
 buffer.
 

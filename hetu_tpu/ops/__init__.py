@@ -1,6 +1,5 @@
 from .attention import sdpa, sdpa_reference
-from .paged_attention import (paged_attention_decode,
-                              paged_attention_reference)
+from .paged_attention import paged_attention_reference
 from .ragged_paged_attention import (ragged_paged_attention,
                                      ragged_paged_attention_reference)
 from .functional import *  # noqa: F401,F403

@@ -28,7 +28,7 @@ rows that cover it, the body rotates those onto the tile's rows and
 keeps the old rows outside ``[lo, hi)``, and the pipeline DMAs the tile
 back.  The pools are aliased to the outputs, so only the touched tiles
 move: nothing copies a pool.  The steps after the last live piece keep
-its block indices and do nothing — no DMA, ~0.1 us each.
+its block indices and do nothing — no DMA (their cost: PERF.md, PR 29).
 
 Every live token's row lands bit-equal to the scatter's.  The trash
 page is no longer written (the scatter left padding junk there); a step

@@ -1,9 +1,9 @@
 """Ragged paged attention: mixed prefill chunks + decode in ONE kernel.
 
-The serving engine's v1 split (``ops/paged_attention.py`` decode kernel
-+ a dense bucketed prefill) pays a compile-grid tax: every prompt-length
-bucket and every decode-batch bucket is its own executable, and each
-admitted request runs its own prefill call.  This op collapses the two
+The serving engine's v1 split (a one-token paged decode kernel, since
+removed, + a dense bucketed prefill) paid a compile-grid tax: every
+prompt-length bucket and every decode-batch bucket was its own
+executable, and each admitted request ran its own prefill call.  This op collapses the two
 phases into one program over a **ragged batch** — the Ragged Paged
 Attention recipe (PAPERS.md, arxiv 2604.15464):
 

@@ -173,8 +173,8 @@ class MPMDPipelineRuntime:
         # executable_graph.cc:1738-1761 _all_micro_batches_memory_info)
         from ..utils.profiler import MemoryProfiler
         self.memory_profiler = MemoryProfiler()
-        # per-(P, counts) jitted rng-table builders: fold_in costs ~5ms
-        # of host dispatch per eager call, so the whole table is built in
+        # per-(P, counts) jitted rng-table builders: every eager fold_in
+        # is a host dispatch of its own, so the whole table is built in
         # ONE jit call per step instead of 2 fold_ins per task
         self._fold_cache: Dict[Tuple, Any] = {}
         # executed-order p2p tap: one ("send"|"recv", "F"|"B", pipe,

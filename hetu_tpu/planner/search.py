@@ -189,7 +189,7 @@ def plan_for_gpt(cfg, global_batch: int, seq: int, n_chips: int,
     spec when given, and return the searched plan — the reference's
     ``get_hybrid_parallel_configs_api`` entry point
     (``tools/Galvatron/galvatron/core/hybrid_parallel_config.py:13``),
-    consumed by ``bench.py`` and ``examples/train_gpt.py --auto-parallel``.
+    consumed by ``examples/train_gpt.py --auto-parallel``.
 
     The search covers (pp, dp, tp, zero, recompute) jointly with the
     micro-batch size (``micro_batch_options`` defaults to the powers of
@@ -314,7 +314,7 @@ def verify_plan_schedule(plan: PlanResult):
 
 
 def plan_summary(plan: PlanResult) -> Dict:
-    """Flat JSON-able description of a plan (bench `extra` reporting)."""
+    """Flat JSON-able description of a plan (what ``--auto-parallel`` prints)."""
     from collections import Counter
     sts = Counter(str(s) for s in plan.layer_strategies)
     first = plan.layer_strategies[0]

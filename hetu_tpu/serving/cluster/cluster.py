@@ -315,8 +315,8 @@ class EngineCluster:
                           # SLO traffic plane (DESIGN.md §22): per-class
                           # sheds, the inversion detector (a shed or
                           # placement that favored a lower class —
-                          # always 0 by construction, asserted in the
-                          # bench), autoscaler actions
+                          # always 0 by construction, asserted in
+                          # tests/test_slo.py), autoscaler actions
                           *(f"shed_{c}" for c in SLO_CLASSES),
                           "class_inversions", "scale_ups",
                           "scale_downs",
@@ -426,7 +426,7 @@ class EngineCluster:
         # sits in the backlog equally sheddable means the shed policy
         # inverted the SLO order — by construction (shed_candidate /
         # expired_head scan lowest-class-first) this never fires, and
-        # the slo bench asserts the counter stays 0
+        # tests/test_slo.py asserts the counter stays 0
         for _arr, _rid, q in self._backlog:
             if q.rank <= creq.rank:
                 continue

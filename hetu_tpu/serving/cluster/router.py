@@ -18,8 +18,8 @@ chained page hashes):
 3. **Backpressure** — replicas at ``max_queue_depth`` (queued + running
    requests) are not candidates; when every live replica is saturated
    the request stays in the cluster backlog and the router re-tries
-   next step.  A ``"random"`` policy (seeded) exists as the bench
-   baseline prefix-aware routing must beat.
+   next step.  A ``"random"`` policy (seeded) exists as the
+   baseline prefix-aware routing must beat (tests/test_cluster.py).
 
 Every placement emits a tracer instant on the ``router`` track carrying
 the decision *and its reason* (matched pages per replica, outstanding

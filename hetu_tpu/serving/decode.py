@@ -4,9 +4,9 @@ v1 (PR 2) compiled a GRID of programs — one bucketed prefill executable
 per power-of-two prompt length, one decode executable per power-of-two
 batch size — and ran every admitted request's prefill as its own call.
 That bounded compiles logarithmically but still paid
-O(prefill buckets x batch buckets) compiles and serialized prefills,
-which is exactly where the v1 bench lost (15.5 tok/s paged vs 25.6
-dense, TTFT p90 6.3 s, BENCH_SERVING.json v1).
+O(prefill buckets x batch buckets) compiles and serialized prefills:
+every admitted request's prompt ran as a call of its own, ahead of
+the decode batch.
 
 ``build_unified_step_fn`` replaces the whole grid with ONE executable
 over a fixed-shape **ragged token batch** (DESIGN.md §12):

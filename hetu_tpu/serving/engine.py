@@ -468,7 +468,7 @@ class Engine:
     def set_tracer(self, tracer) -> None:
         """Swap the engine's tracer live (None reverts to following the
         ambient global) — lets a service toggle tracing on a running
-        engine, and the obs microbench A/B the same warm executable."""
+        engine, and a traced and an untraced run share one executable."""
         self._tracer = tracer
 
     @property

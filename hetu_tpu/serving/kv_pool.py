@@ -18,8 +18,8 @@ them there; the TPU path's page-run write (``ops/paged_kv_write.py``)
 writes only tokens that exist and leaves the page alone.
 
 Layout: pages are ``[num_pages, kv_heads, page_size, head_dim]`` — the
-trailing ``(page_size, head_dim)`` tile is what the Pallas kernels DMA
-per grid step (``ops/paged_attention.py`` layout notes).  ``kv_heads``
+trailing ``(page_size, head_dim)`` tile is what the ragged kernel DMAs
+per grid step (``ops/ragged_paged_attention.py``).  ``kv_heads``
 is the same axis the training stack splits across ``tp``
 (nn/parallel.py column-parallel QKV), so a pool built with a mesh
 shards pages ``P(None, 'tp', None, None)`` and the decode executable's

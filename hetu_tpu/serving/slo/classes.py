@@ -17,12 +17,12 @@ is preempted, never what any surviving request computes.  Temperature-0
 outputs therefore stay bit-for-bit identical to an unmanaged run for
 every request that completes in both (the position-keyed sampler makes
 token values a function of the request's own history alone; asserted
-in ``tests/test_slo.py`` and gated in ``bench.py slo_bench``).
+in ``tests/test_slo.py``).
 
 Per-class latency targets feed the autoscaler
 (:class:`~hetu_tpu.serving.slo.autoscaler.Autoscaler` scales up when
-interactive TTFT crosses its target) and the bench acceptance
-booleans; they are defaults, overridable per cluster.
+interactive TTFT crosses its target); they are defaults, overridable
+per cluster.
 """
 from __future__ import annotations
 

@@ -216,7 +216,7 @@ class HostTier:
         """The priced edge claim, shaped like the disaggregation wire's
         (``LocalPageTransport._price``) with tag ``host_offload`` —
         one vocabulary, one ``collective_time`` implementation, so the
-        bench's hit-vs-recompute comparison and the lint both read the
+        hit-vs-recompute comparison and the lint both read the
         planner's own numbers."""
         from ...planner.cost_model import collective_time
         src, dst = (("device_pool", "host_tier")

@@ -1,6 +1,6 @@
 """JAX's persistent compilation cache, placed from outside the program.
 
-Entry points (``chip_smoke.py``, ``bench.py``, ``examples/*.py``) call
+Entry points (``chip_smoke.py``, ``benchmark/``, ``examples/*.py``) call
 :func:`enable_compile_cache` before their first compile.  Where
 ``JAX_COMPILATION_CACHE_DIR`` is set JAX already keeps its cache there
 and nothing is set in code.  Otherwise the cache lives in

@@ -210,7 +210,7 @@ class Histogram:
         linearly interpolated between ranks (numpy's default).  The old
         nearest-index rounding biased small-window tails — p90 of
         [1..10] snapped to a sample instead of 9.1 — which made
-        BENCH_SERVING TTFT/TBT tails jumpy run-to-run."""
+        TTFT/TBT tails over a few dozen requests jumpy run-to-run."""
         return percentile_of(sorted(self._obs), p)
 
     def summary(self) -> Dict[str, float]:

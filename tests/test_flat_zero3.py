@@ -203,12 +203,27 @@ class TestZero3Adafactor:
                                     {"clipping_threshold": None}])
     def test_flat_matches_optax_reference(self, devices8, kw):
         """The flat reimplementation follows optax.adafactor's exact
-        chain; z2 and z3 stay bitwise to each other."""
+        chain; z2 and z3 apply the same update bitwise: every loss an
+        update has touched, the flat fp32 master (every parameter) and
+        the factored stats.  The loss of step 0 is taken BEFORE any
+        update, by two different XLA programs whose forward reductions
+        nothing holds to one order (it differs by a float32 ulp under
+        jaxlib 0.9.0), so it is held to rtol only."""
         ref, _, _, _ = self._run(devices8, 0, False, **kw)
-        l2, _, _, _ = self._run(devices8, 2, True, **kw)
-        l3, _, _, _ = self._run(devices8, 3, True, **kw)
-        assert l2 == l3, kw
+        l2, g2, o2, w2 = self._run(devices8, 2, True, **kw)
+        l3, g3, o3, w3 = self._run(devices8, 3, True, **kw)
+        assert l2[1:] == l3[1:], kw
+        np.testing.assert_allclose(l2, l3, rtol=1e-6, atol=0)
+        for slot in ("flat_master", "flat_v", "fac_row", "fac_col"):
+            for a, b in zip(jax.tree_util.tree_leaves(o2._state[slot]),
+                            jax.tree_util.tree_leaves(o3._state[slot])):
+                np.testing.assert_array_equal(np.asarray(a),
+                                              np.asarray(b), slot)
+        np.testing.assert_array_equal(
+            np.asarray(g2.get_tensor_value(w2)),
+            np.asarray(g3.get_tensor_value(w3)))
         np.testing.assert_allclose(l2, ref, rtol=2e-4, atol=1e-6)
+        np.testing.assert_allclose(l3, ref, rtol=2e-4, atol=1e-6)
 
     def test_factored_lanes_keep_zero_v(self, devices8):
         """Factored matrices ride the replicated row/col EMAs; their

@@ -472,7 +472,7 @@ def test_reconcile_joins_two_executable_families(tiny_state):
     d = rep.to_dict()
     assert len(d["rows"]) == rep.families
     assert d["rows"][0]["predicted_step_s"] is not None
-    json.dumps(d)                            # BENCH_OBS-serializable
+    json.dumps(d)                            # JSON-serializable
 
 
 # ---------------------------------------------------------------------------

@@ -62,7 +62,7 @@ class TestPipeline:
 
     def test_gpt2_blocks_pipeline(self, devices8):
         """GPT-2-style blocks (gelu/layernorm/learned positions, biases)
-        pipeline too — the flagship bench config is no longer barred from
+        pipeline too — the GPT-2 architecture is not barred from
         pp (reference places the same blocks across stages regardless of
         architecture, examples/gpt/train_hetu.py:256)."""
         from hetu_tpu.models.gpt import GPTConfig

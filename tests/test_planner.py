@@ -267,7 +267,7 @@ class TestSearchEngine:
 
     def test_plan_for_gpt_closes_the_loop(self):
         """plan_for_gpt: GPTConfig -> layer chain -> searched plan with a
-        micro-batch sweep (the bench.py / train_gpt --auto-parallel entry,
+        micro-batch sweep (the train_gpt --auto-parallel entry,
         reference hybrid_parallel_config.py:13)."""
         from hetu_tpu.models.gpt import GPTConfig
         from hetu_tpu.planner import plan_for_gpt, plan_summary

@@ -387,7 +387,7 @@ class TestExplorer:
 
     @pytest.mark.slow
     def test_default_config_exhausts(self):
-        # the full default bound (BENCH_PROTOCOL.json's headline run):
+        # the full default bound:
         # ~365k distinct states, tens of trillions of interleavings
         res = explore(stop_at_first=False)
         assert res.ok, [v.message for v in res.violations]
@@ -452,8 +452,8 @@ def test_protocol_gate_explorer_and_fuzz():
     exhausts a two-replica config with ZERO violations on the clean
     model, and a seeded ~300-event chaos fuzz trace replays through
     the lifecycle machines with strict terminal conservation.  The
-    full default-config exhaustion lives in bench.py protocol_lint
-    (BENCH_PROTOCOL.json)."""
+    full default-config exhaustion is the `slow`
+    TestExplorer::test_default_config_exhausts."""
     res = explore(SMALL, stop_at_first=False)
     assert res.ok, [f"{v.rule}: {v.message}" for v in res.violations]
     assert res.interleavings > 10_000

@@ -312,7 +312,7 @@ class TestGateWiring:
     def test_schedule_gate_grid_and_corpus(self):
         """The tier-1 schedule gate: the full strategy grid verifies
         hang-free and every corpus divergence is caught by exactly its
-        rule (the bench.py schedule_lint sweep, inline)."""
+        rule."""
         dirty = []
         for label, spec in GRID:
             if verify_schedules(extract_schedules(spec)):

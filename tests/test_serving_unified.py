@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import hetu_tpu as ht
 from hetu_tpu.models import GPTConfig, GPTLMHeadModel
 from hetu_tpu.models.generate import generate
+from hetu_tpu.ops.paged_attention import paged_attention_reference
 from hetu_tpu.ops.ragged_paged_attention import (
     ragged_paged_attention_pallas, ragged_paged_attention_reference)
 from hetu_tpu.serving import Engine, SpecConfig
@@ -71,22 +72,25 @@ def _drain(eng, check=True):
 # ---------------------------------------------------------------------------
 
 RAGGED_CASES = [
-    # (q_lens, ctx_lens, maxp, ps)   — mixed chunks + decodes + padding
-    ([1, 5, 0, 6], [13, 10, 0, 6], 3, 8),
-    ([1, 1, 1, 1], [9, 3, 17, 1], 3, 8),      # all-decode
-    ([8, 8], [8, 24], 4, 8),                  # all-chunk, partial pages
-    ([3, 0, 0, 7], [20, 0, 0, 7], 4, 8),      # sparse rows
+    # (q_lens, ctx_lens, maxp, ps, max_q) — mixed chunks + decodes + padding
+    ([1, 5, 0, 6], [13, 10, 0, 6], 3, 8, 8),
+    ([1, 1, 1, 1], [9, 3, 17, 1], 3, 8, 8),   # all-decode
+    ([1, 1, 1, 1], [9, 3, 17, 1], 3, 8, 1),   # the decode region's window
+    ([8, 8], [8, 24], 4, 8, 8),               # all-chunk, partial pages
+    ([3, 0, 0, 7], [20, 0, 0, 7], 4, 8, 8),   # sparse rows
 ]
 
 
-@pytest.mark.parametrize("q_lens,ctx_lens,maxp,ps", RAGGED_CASES)
-def test_ragged_kernel_matches_reference(q_lens, ctx_lens, maxp, ps):
+@pytest.mark.parametrize("q_lens,ctx_lens,maxp,ps,max_q", RAGGED_CASES)
+def test_ragged_kernel_matches_reference(q_lens, ctx_lens, maxp, ps,
+                                         max_q):
     """Pallas ragged kernel (interpret mode on CPU) against the
     gather-dense reference across ragged shapes: decode rows, prefill
-    chunks, padding rows, partial last pages, GQA group padding."""
+    chunks, padding rows, partial last pages, GQA group padding.  At
+    ``max_q = 1`` every row is one decode token, and the kernel is also
+    held to the decode oracle ``paged_attention_reference``."""
     rng = np.random.RandomState(0)
     nh, kvh, hd, num_pages = 4, 2, 32, 12
-    max_q = 8
     s = len(q_lens)
     cu = np.zeros(s + 1, np.int32)
     cu[1:] = np.cumsum(q_lens)
@@ -114,6 +118,11 @@ def test_ragged_kernel_matches_reference(q_lens, ctx_lens, maxp, ps):
     np.testing.assert_allclose(np.asarray(got)[mask],
                                np.asarray(ref)[mask],
                                rtol=2e-5, atol=2e-5)
+    if max_q == 1:
+        assert mask.all()       # one token a row: q is [B, nh, hd]
+        dec = paged_attention_reference(q, kp, vp, args[2], args[3])
+        np.testing.assert_allclose(np.asarray(got), np.asarray(dec),
+                                   rtol=2e-5, atol=2e-5)
 
 
 def test_ragged_reference_matches_per_token_oracle():
