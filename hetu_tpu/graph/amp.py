@@ -28,7 +28,7 @@ from ..core.dtype import canonicalize_dtype
 _LOW_PRECISION_OPS = frozenset({
     "matmul", "batch_matmul", "linear", "einsum", "conv2d",
     "fused_lm_cross_entropy",
-    "attention", "parallel_attention", "flash_attention",
+    "attention", "attention_qkv", "parallel_attention", "flash_attention",
 })
 # Ops whose floating inputs are cast UP to fp32 (numerically sensitive).
 _FULL_PRECISION_OPS = frozenset({

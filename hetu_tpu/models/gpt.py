@@ -325,6 +325,17 @@ class ParallelAttentionBlock(Module):
         b_spec = P(c.dp_axis, c.cp_axis, c.tp_axis, None)
         q_size = c.num_heads * c.head_dim
         kv_size = c.kv_heads * c.head_dim
+        mesh = qkv.graph.mesh
+        tp = mesh.shape.get(c.tp_axis, 1) if mesh is not None else 1
+        if (c.position != "rotary" and c.kv_heads == c.num_heads
+                and not c.cp_axis and tp == 1):
+            # nothing stands between the projection and the attention:
+            # the kernel takes q, k and v out of the fused tensor by block
+            # index and no slice is cut (under tp > 1 the fused axis is
+            # sharded across q | k | v, so there the slices stay)
+            attn = ops.attention_qkv(qkv, c.num_heads, causal=True,
+                                     segment_ids=segment_ids)
+            return sharded(attn, P(c.dp_axis, c.cp_axis, c.tp_axis))
         q = ops.getitem(qkv, (Ellipsis, slice(0, q_size)))
         k = ops.getitem(qkv, (Ellipsis, slice(q_size, q_size + kv_size)))
         v = ops.getitem(qkv, (Ellipsis, slice(q_size + kv_size, None)))

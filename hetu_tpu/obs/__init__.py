@@ -16,6 +16,10 @@ The observability counterpart of ``hetu_tpu/analysis`` (DESIGN.md §15):
   that reach HLO ``op_name`` metadata) and ``device_phases(exec)``, the
   ``{HLO instruction: phase}`` map that names a device trace's events.
 
+* :mod:`.counters` — ``count(name, **labels)`` / ``counts(name)``: what
+  the program chose while an executable was traced (``flash_calls`` by
+  ``layout``), which the compiled program can no longer tell.
+
 Real-time spans are mirrored into the ``jax.profiler`` trace
 (``hetu:<name>`` annotations), so host phases and device operations
 share the profiler's clock.
@@ -28,6 +32,7 @@ scheduler's per-step packing decision), ``DefineAndRunGraph.run``
 attribution; ``executable`` times the call, not the device),
 ``switch_strategy`` and the MPMD pipeline task loop.
 """
+from .counters import count, counts, reset_counts
 from .export import (chrome_trace, events_to_jsonl, request_timelines,
                      timeline_summary, validate_chrome_trace,
                      write_chrome_trace, write_jsonl)
@@ -42,6 +47,7 @@ __all__ = [
     "Span", "SpanTracer", "PrefixedTracer", "NULL_TRACER", "NOOP_SPAN",
     "PROFILER_PREFIX", "get_tracer", "install_tracer", "trace",
     "PHASES", "phase", "device_phases",
+    "count", "counts", "reset_counts",
     "chrome_trace", "write_chrome_trace", "events_to_jsonl", "write_jsonl",
     "validate_chrome_trace", "request_timelines", "timeline_summary",
     "ReconcileReport", "ReconcileRow", "predicted_stats", "reconcile",

@@ -5,7 +5,13 @@ CUDA, varlen via cu_seqlens at ``impl/kernel/FlashAttention.cu:48-56``).
 On TPU the flash kernel is Pallas (``hetu_tpu/ops/pallas/flash_attention.py``);
 on CPU/simulation we use the jnp path (XLA fuses it adequately for tests).
 
-Layout convention follows the reference: [batch, seq, num_heads, head_dim].
+Layout convention follows the reference: [batch, seq, num_heads, head_dim],
+and the flash kernels read it in place: with ``head_dim % 128 == 0`` a
+head's block is a lane block of the [b, s, h*d] view, so nothing is
+transposed round the call (a narrower head still goes head-major; the
+shape decides, ``pallas/flash_attention.py``).  :func:`sdpa_qkv` is
+self-attention straight off a fused projection's [b, s, 3*h*d]: the same
+kernels with three block index maps on the one array.
 """
 from __future__ import annotations
 
@@ -75,3 +81,25 @@ def sdpa(q, k, v, causal: bool = True, softmax_scale: Optional[float] = None,
     return sdpa_reference(q, k, v, causal=causal,
                           softmax_scale=softmax_scale, bias=bias,
                           segment_ids=segment_ids)
+
+
+def sdpa_qkv(qkv, num_heads: int, causal: bool = True,
+             softmax_scale: Optional[float] = None,
+             segment_ids: Optional[jax.Array] = None,
+             use_flash: Optional[bool] = None) -> jax.Array:
+    """Self-attention on a fused projection: ``qkv`` [b, s, 3*h*d] (q | k
+    | v on the last axis, ``num_heads`` heads each) -> [b, s, h*d].
+    Dispatches as :func:`sdpa` does."""
+    if use_flash is None:
+        use_flash = on_tpu()
+    if use_flash:
+        from .pallas.flash_attention import flash_attention_qkv
+        return flash_attention_qkv(qkv, num_heads, causal=causal,
+                                   softmax_scale=softmax_scale,
+                                   segment_ids=segment_ids)
+    b, s, w = qkv.shape
+    q, k, v = (x.reshape(b, s, num_heads, -1)
+               for x in jnp.split(qkv, 3, axis=-1))
+    return sdpa_reference(q, k, v, causal=causal,
+                          softmax_scale=softmax_scale,
+                          segment_ids=segment_ids).reshape(b, s, w // 3)

@@ -657,6 +657,64 @@ def attention(q, k, v, causal=True, softmax_scale=None, use_flash=None,
                {"causal": causal, "softmax_scale": softmax_scale})
 
 
+def attention_qkv(qkv, num_heads: int, causal=True, softmax_scale=None,
+                  use_flash=None, segment_ids=None):
+    """Self-attention straight off the fused projection: ``qkv``
+    [batch, seq, 3 * heads * head_dim] (q | k | v on the last axis) ->
+    [batch, seq, heads * head_dim].  What :func:`attention` computes for
+    the three slices, without cutting them out: on platform ``tpu`` the
+    flash kernels take q, k and v from the one array by block index
+    (``ops.pallas.flash_attention.flash_attention_qkv``).
+
+    Under a multi-device mesh the kernel runs in a ``shard_map`` over the
+    batch axis ``qkv`` was annotated with, as in :func:`attention`.  Where
+    the mesh shards the fused axis (tensor parallelism cuts it across
+    q | k | v) the slices are cut after all and the heads sharded over
+    that axis.
+    """
+    from jax.sharding import PartitionSpec as P
+    from .attention import sdpa, sdpa_qkv  # local import to avoid cycle
+    from .pallas import on_tpu
+    g = _graph_of(qkv)
+    qkv_tensor = qkv
+
+    def _impl(qkv, segs=None, causal=True, softmax_scale=None):
+        kw = dict(causal=causal, softmax_scale=softmax_scale,
+                  use_flash=use_flash)
+        mesh = g.mesh
+        flash = on_tpu() if use_flash is None else use_flash
+        if not flash or mesh is None or mesh.size == 1:
+            return sdpa_qkv(qkv, num_heads, segment_ids=segs, **kw)
+        spec = tuple(g._pspec_for(qkv_tensor) or ())
+        b_ax, s_ax, f_ax = spec + (None,) * (3 - len(spec))
+        if s_ax is not None:
+            raise ValueError(
+                f"attention_qkv needs seq unsharded, got qkv pspec {spec}; "
+                f"sequence sharding is parallel_attention")
+        from ..parallel.comm import shard_map
+        seg_spec = () if segs is None else (P(b_ax, None),)
+        seg_arg = () if segs is None else (segs,)
+        if f_ax is None or math.prod(
+                mesh.shape[a] for a in (f_ax if isinstance(f_ax, tuple)
+                                        else (f_ax,))) == 1:
+            spec = P(b_ax, None, None)
+            return shard_map(
+                lambda x, s=None: sdpa_qkv(x, num_heads, segment_ids=s, **kw),
+                mesh, (spec,) + seg_spec, spec)(qkv, *seg_arg)
+        b, s, w = qkv.shape
+        spec = P(b_ax, None, f_ax, None)
+        out = shard_map(
+            lambda q, k, v, s=None: sdpa(q, k, v, segment_ids=s, **kw),
+            mesh, (spec,) * 3 + seg_spec, spec)(
+                *(x.reshape(b, s, num_heads, -1)
+                  for x in jnp.split(qkv, 3, axis=-1)), *seg_arg)
+        return out.reshape(b, s, w // 3)
+
+    inputs = [qkv] if segment_ids is None else [qkv, segment_ids]
+    return _op("attention_qkv", _impl, inputs,
+               {"causal": causal, "softmax_scale": softmax_scale})
+
+
 def parallel_attention(q, k, v, causal=True, softmax_scale=None,
                        cp_axis: str = "cp", batch_axis: str = "dp",
                        head_axis: str = "tp", segment_ids=None,

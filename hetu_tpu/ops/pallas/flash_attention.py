@@ -11,16 +11,25 @@ correction needs (reference ``AttnCommRing::ExecCorr``,
 ``ops/ParallelAttention.h:361``) and what the backward recompute uses.
 
 Backward is a single fused kernel (dq, dk, dv in one grid pass): grid
-(bh, q, kv) with kv innermost; dq accumulates in a per-q-block VMEM
+(b, h, q, kv) with kv innermost; dq accumulates in a per-q-block VMEM
 scratch, dk/dv accumulate in full-sequence VMEM scratch written out once
-per bh, and delta = rowsum(do*o) is computed in-kernel at kv==0 — so the
+per head, and delta = rowsum(do*o) is computed in-kernel at kv==0 — so the
 score matrix is materialized once per (q, kv) block pair instead of twice
 (the split dq / dkv formulation).  Sequences whose dk/dv scratch would
 exceed the VMEM budget fall back to the split two-kernel path.
 
-Layout: [batch, seq, heads, head_dim] (reference convention).  Internally
-[b*h, s, d].  Causal masking is block-skipped (fully-masked kv blocks are
-not computed).  ``segment_ids`` gives packed/varlen semantics (the
+Layout: [batch, seq, heads, head_dim] (reference convention), and the
+kernels read it as it is.  With ``head_dim % 128 == 0`` a head's rows in
+the [b, s, h*d] view (a reshape that moves nothing) are whole lane tiles,
+so the (1, rows, d) block at block index (b, i, h) IS that head's block:
+no operand and no result is transposed (``_Layout``, "native").  The
+same index maps take q, k and v out of the fused projection's
+[b, s, 3*h*d] (``flash_attention_qkv``).  A narrower head (64) cannot be
+a lane block of a wider array and is transposed to [b*h, s, d] round the
+call ("head_major").  One grid, one set of kernel bodies; the path is
+chosen by the shape alone and counted in ``obs.counts("flash_calls")``.
+Causal masking is block-skipped (fully-masked kv blocks are not
+computed).  ``segment_ids`` gives packed/varlen semantics (the
 cu_seqlens path of the reference, ``ops/Attention.h:286``).  Narrow
 (8-lane) layouts are used for the lse / delta / q-segment operands — not
 full 128-lane broadcasts.
@@ -42,6 +51,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import on_tpu
+from ...obs.counters import count
 
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -54,10 +64,10 @@ def _empty_rows(m):
     return m <= DEFAULT_MASK_VALUE * 0.5
 
 # Scores are computed as base-2 logits: the softmax scale AND log2(e) are
-# folded into the q operand (one [s, d] multiply outside the kernel
-# instead of a [s, s] multiply per block inside), and exp/log become
-# exp2/log2 — the VPU-native transcendentals.  LSE stays natural-log at
-# every API boundary (ring correction, backward, tests).
+# folded into the q block (one [bq, d] multiply per q block, rounded to
+# q's dtype, instead of a [bq, bk] multiply per block pair), and exp/log
+# become exp2/log2 — the VPU-native transcendentals.  LSE stays
+# natural-log at every API boundary (ring correction, backward, tests).
 LOG2E = 1.4426950408889634
 LN2 = 0.6931471805599453
 
@@ -70,18 +80,95 @@ SUBLANES = 8     # second-to-last tile width (f32/int32)
 _FUSED_DKV_VMEM_BYTES = 4 * 1024 * 1024
 
 
-def _padded_segs(segment_ids, b, h, sq, sk):
-    """Broadcast segment ids into TPU-tileable layouts: q side
-    [bh, sq, SUBLANES] (narrow lanes), kv side [bh, SUBLANES, sk].
+def _row(axis):
+    """``axis`` of a block spec as a function of the grid's block indices."""
+    if callable(axis):
+        return axis
+    return (lambda g: 0) if axis is None else (lambda g: g[axis])
+
+
+def _kv_row(causal, offset, bq, bk):
+    """Row block of the kv-side operands on a grid (.., q, kv).  Under the
+    causal block skip the steps past a q block's last visible kv block
+    compute nothing: they keep that block's index, so the pipeline
+    fetches nothing for them either (a strided native-layout block costs
+    more to fetch than a contiguous one, and nothing hides it there)."""
+    if not causal:
+        return 1
+    return lambda g: jnp.minimum(g[1], (g[0] * bq + bq - 1 + offset) // bk)
+
+
+def _q_row(causal, offset, bq, bk, num_q):
+    """The same for the q-side operands on the dkv kernel's (.., kv, q)
+    grid: steps before a kv block's first visible q block keep its index."""
+    if not causal:
+        return 1
+    return lambda g: jnp.clip((g[0] * bk - offset) // bq, g[1], num_q - 1)
+
+
+class _Layout:
+    """Where the kernels find a head's [rows, d] block.  Every call has
+    the grid (b, h, *g) — ``g`` the q and kv block indices, in the
+    kernel's order — and the index maps below are all that tells the two
+    layouts apart (module docstring)."""
+
+    def __init__(self, b: int, h: int, d: int):
+        self.b, self.h, self.d = b, h, d
+        self.native = d % LANES == 0
+        count("flash_calls",
+              layout="native" if self.native else "head_major")
+
+    def view(self, x):
+        """[b, s, h, d] as a kernel operand."""
+        b, s, h, d = x.shape
+        if self.native:
+            return x.reshape(b, s, h * d)
+        return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+
+    def unview(self, y):
+        """A kernel result as [b, s, h, d]."""
+        if self.native:
+            return y.reshape(self.b, -1, self.h, self.d)
+        return y.reshape(self.b, self.h, -1, self.d).transpose(0, 2, 1, 3)
+
+    def shape(self, s: int):
+        if self.native:
+            return (self.b, s, self.h * self.d)
+        return (self.b * self.h, s, self.d)
+
+    def spec(self, rows: int, axis, head0: int = 0):
+        """A head's (1, rows, d) block at row block ``g[axis]`` (``None``:
+        the whole sequence, block 0; a callable: ``axis(g)``).  ``head0``
+        is the head's offset in an array that holds more than these h
+        heads (the fused qkv)."""
+        h, d = self.h, self.d
+        row = _row(axis)
+        if self.native:
+            return pl.BlockSpec(
+                (1, rows, d), lambda b, hh, *g: (b, row(g), head0 + hh))
+        return pl.BlockSpec(
+            (1, rows, d), lambda b, hh, *g: (b * h + hh, row(g), 0))
+
+    def narrow(self, rows: int, axis):
+        """The lse / delta operands, [b*h, s, SUBLANES] in both layouts."""
+        h, row = self.h, _row(axis)
+        return pl.BlockSpec((1, rows, SUBLANES),
+                            lambda b, hh, *g: (b * h + hh, row(g), 0))
+
+
+def _seg_operands(segment_ids, sq, sk, bq, bk, q_axis=0, kv_axis=1):
+    """(in_specs, operands) for the segment-id streams — empty when
+    segments are unused, so the common no-packing case pays zero extra
+    HBM traffic for them.  TPU-tileable layouts, one per batch row (the
+    heads share it): q side [b, sq, SUBLANES] (narrow lanes), kv side
+    [b, SUBLANES, sk].
 
     ``segment_ids`` is either a [b, sq] array (shared q/kv — requires
     sq == sk) or a tuple ``(q_ids [b, sq], kv_ids [b, sk])`` — the ring
     attention case where the visiting KV block carries its own ids.
     """
     if segment_ids is None:
-        q_segs = jnp.zeros((b * h, sq, SUBLANES), jnp.int32)
-        kv_segs = jnp.zeros((b * h, SUBLANES, sk), jnp.int32)
-        return q_segs, kv_segs
+        return [], []
     if isinstance(segment_ids, (tuple, list)):
         q_ids, kv_ids = segment_ids
     else:
@@ -89,25 +176,20 @@ def _padded_segs(segment_ids, b, h, sq, sk):
             raise NotImplementedError(
                 "segment_ids with sq != sk needs a (q_ids, kv_ids) tuple")
         q_ids = kv_ids = segment_ids
-    flat_q = jnp.repeat(q_ids[:, None, :], h, axis=1).reshape(b * h, sq)
-    q_segs = jnp.broadcast_to(flat_q[:, :, None], (b * h, sq, SUBLANES))
-    flat_kv = jnp.repeat(kv_ids[:, None, :], h, axis=1).reshape(b * h, sk)
-    kv_segs = jnp.broadcast_to(flat_kv[:, None, :], (b * h, SUBLANES, sk))
-    return q_segs, kv_segs
-
-
-def _seg_operands(segment_ids, b, h, sq, sk, bq, bk):
-    """(in_specs, operands) for the segment-id streams — empty when
-    segments are unused, so the common no-packing case pays zero extra
-    HBM traffic for them."""
-    if segment_ids is None:
-        return [], []
-    q_segs, kv_segs = _padded_segs(segment_ids, b, h, sq, sk)
+    b = q_ids.shape[0]
+    q_segs = jnp.broadcast_to(q_ids[:, :, None], (b, sq, SUBLANES))
+    kv_segs = jnp.broadcast_to(kv_ids[:, None, :], (b, SUBLANES, sk))
+    q_row, kv_row = _row(q_axis), _row(kv_axis)
     specs = [
-        pl.BlockSpec((1, bq, SUBLANES), lambda bh, i, j: (bh, i, 0)),
-        pl.BlockSpec((1, SUBLANES, bk), lambda bh, i, j: (bh, 0, j)),
+        pl.BlockSpec((1, bq, SUBLANES), lambda b, hh, *g: (b, q_row(g), 0)),
+        pl.BlockSpec((1, SUBLANES, bk), lambda b, hh, *g: (b, 0, kv_row(g))),
     ]
     return specs, [q_segs, kv_segs]
+
+
+def _scaled(q_ref, qscale):
+    """The q block times softmax_scale * LOG2E, rounded to q's dtype."""
+    return (q_ref[0].astype(jnp.float32) * qscale).astype(q_ref.dtype)
 
 
 def _dim_semantics(*sem):
@@ -172,16 +254,16 @@ def _block_sizes(s: int, d: int, dtype, role: str = "fwd"
 
 def _fwd_kernel(q_seg_ref, kv_seg_ref, q_ref, k_ref, v_ref,  # inputs
                 o_ref, lse_ref,                              # outputs
-                acc_ref, m_ref, l_ref,                       # scratch
-                *, causal: bool, offset: int, bq: int,
+                qs_ref, acc_ref, m_ref, l_ref,               # scratch
+                *, qscale: float, causal: bool, offset: int, bq: int,
                 bk: int, num_kv: int, use_segs: bool):
-    # q arrives pre-scaled by softmax_scale * LOG2E: scores are base-2
-    # logits and all exps are exp2 (see module constant note).
-    kv_idx = pl.program_id(2)
-    q_idx = pl.program_id(1)
+    # the q block is scaled by softmax_scale * LOG2E once, at its first
+    # kv block (qs_ref): scores are base-2 logits and all exps are exp2
+    # (see module constant note).
+    q_idx = pl.program_id(2)
+    kv_idx = pl.program_id(3)
 
-    def _scores():
-        q = q_ref[0]                       # [bq, d]
+    def _scores(q):
         k = k_ref[0]                       # [bk, d]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
@@ -199,7 +281,7 @@ def _fwd_kernel(q_seg_ref, kv_seg_ref, q_ref, k_ref, v_ref,  # inputs
         # single-kv-block fast path (the whole kv sequence is one block,
         # and the block is never fully skipped): no online-softmax carry,
         # no scratch traffic, outputs written directly
-        s = _scores()
+        s = _scores(_scaled(q_ref, qscale))
         m = jnp.max(s, axis=1)
         p = jnp.exp2(s - m[:, None])
         l = jnp.sum(p, axis=1)             # >= 1: exp2(0) at the max
@@ -220,6 +302,7 @@ def _fwd_kernel(q_seg_ref, kv_seg_ref, q_ref, k_ref, v_ref,  # inputs
 
     @pl.when(kv_idx == 0)
     def _init():
+        qs_ref[:] = _scaled(q_ref, qscale)
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[:] = jnp.zeros_like(l_ref)
@@ -233,7 +316,7 @@ def _fwd_kernel(q_seg_ref, kv_seg_ref, q_ref, k_ref, v_ref,  # inputs
 
     @pl.when(run)
     def _compute():
-        s = _scores()
+        s = _scores(qs_ref[:])
         m_prev = m_ref[:, 0]               # [bq]
         m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
         p = jnp.exp2(s - m_cur[:, None])
@@ -261,57 +344,63 @@ def _fwd_kernel(q_seg_ref, kv_seg_ref, q_ref, k_ref, v_ref,  # inputs
         lse_ref[0] = jnp.broadcast_to(lse[:, None], lse_ref.shape[1:])
 
 
-def _flash_fwd(q, k, v, scale, causal, segment_ids, causal_offset=0):
-    b, sq, h, d = q.shape
-    sk = k.shape[1]
-    # fold softmax scale + log2(e) into q (one [s, d] multiply; scores
-    # come out of the kernel's matmul as base-2 logits)
-    qr = (q * (scale * LOG2E)).astype(q.dtype) \
-        .transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    kr = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-    vr = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
+def _fwd_call(lay, q, k, v, heads0, sq, sk, scale, causal, segment_ids,
+              causal_offset):
+    """``q``, ``k``, ``v`` as ``lay`` views them: three arrays, or the one
+    fused qkv array three times with the head offsets ``heads0``.
+    Returns (out in the same layout, lse [b, h, sq])."""
+    b, h, d = lay.b, lay.h, lay.d
     bq, _ = _block_sizes(sq, d, q.dtype)
     _, bk = _block_sizes(sk, d, q.dtype)
     num_q, num_kv = sq // bq, sk // bk
 
     use_segs = segment_ids is not None
-    seg_specs, seg_args = _seg_operands(segment_ids, b, h, sq, sk, bq, bk)
+    kv = _kv_row(causal, causal_offset, bq, bk)
+    seg_specs, seg_args = _seg_operands(segment_ids, sq, sk, bq, bk,
+                                        kv_axis=kv)
 
     kernel = functools.partial(
-        _fwd_kernel, causal=causal, offset=causal_offset,
-        bq=bq, bk=bk, num_kv=num_kv, use_segs=use_segs)
+        _fwd_kernel, qscale=scale * LOG2E, causal=causal,
+        offset=causal_offset, bq=bq, bk=bk, num_kv=num_kv, use_segs=use_segs)
     if not use_segs:
         kernel = functools.partial(_nosegs_kernel, kernel)
 
     out, lse = pl.pallas_call(
         kernel,
-        grid=(b * h, num_q, num_kv),
+        grid=(b, h, num_q, num_kv),
         in_specs=[
             *seg_specs,
-            pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, j, 0)),
+            lay.spec(bq, 0, heads0[0]),
+            lay.spec(bk, kv, heads0[1]),
+            lay.spec(bk, kv, heads0[2]),
         ],
-        out_specs=[
-            pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, bq, SUBLANES), lambda bh, i, j: (bh, i, 0)),
-        ],
+        out_specs=[lay.spec(bq, 0), lay.narrow(bq, 0)],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
+            jax.ShapeDtypeStruct(lay.shape(sq), q.dtype),
             jax.ShapeDtypeStruct((b * h, sq, SUBLANES), jnp.float32),
         ],
         scratch_shapes=[
+            pltpu.VMEM((bq, d), q.dtype),
             pltpu.VMEM((bq, d), jnp.float32),
             pltpu.VMEM((bq, LANES), jnp.float32),
             pltpu.VMEM((bq, LANES), jnp.float32),
         ],
-        compiler_params=_dim_semantics("parallel", "parallel", "arbitrary"),
+        compiler_params=_dim_semantics("parallel", "parallel", "parallel",
+                                       "arbitrary"),
         interpret=not on_tpu(),
         name="flash_fwd",
-    )(*seg_args, qr, kr, vr)
-    out = out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
-    lse = lse[:, :, 0].reshape(b, h, sq)
-    return out, lse
+    )(*seg_args, q, k, v)
+    return out, lse[:, :, 0].reshape(b, h, sq)
+
+
+def _flash_fwd(q, k, v, scale, causal, segment_ids, causal_offset=0):
+    """(out [b, sq, h, d], lse [b, h, sq]) of [b, s, h, d] operands."""
+    b, sq, h, d = q.shape
+    lay = _Layout(b, h, d)
+    out, lse = _fwd_call(lay, lay.view(q), lay.view(k), lay.view(v),
+                         (0, 0, 0), sq, k.shape[1], scale, causal,
+                         segment_ids, causal_offset)
+    return lay.unview(out), lse
 
 
 # ---------------------------------------------------------------------------
@@ -321,15 +410,16 @@ def _flash_fwd(q, k, v, scale, causal, segment_ids, causal_offset=0):
 def _bwd_fused_kernel(q_seg_ref, kv_seg_ref, q_ref, k_ref, v_ref, do_ref,
                       o_ref, lse_ref,
                       dq_ref, dk_ref, dv_ref,
-                      dq_acc, dk_acc, dv_acc, delta_scr,
+                      qs_ref, dq_acc, dk_acc, dv_acc, delta_scr,
                       *, scale, causal, offset, bq, bk, num_q, num_kv,
                       use_segs):
-    # q and lse arrive pre-scaled by LOG2E (q also by softmax_scale), so
-    # p = exp2(s2 - lse2) with no per-element scale multiplies; the
-    # deferred scales land on the [*, d] accumulators at finalize:
-    # dq *= scale, dk /= LOG2E (dk was accumulated against the scaled q).
-    q_idx = pl.program_id(1)
-    kv_idx = pl.program_id(2)
+    # the q block is scaled by softmax_scale * LOG2E at its first kv block
+    # (qs_ref) and lse arrives pre-scaled by LOG2E, so p = exp2(s2 - lse2)
+    # with no per-element scale multiplies; the deferred scales land on
+    # the [*, d] accumulators at finalize: dq *= scale, dk /= LOG2E (dk
+    # was accumulated against the scaled q).
+    q_idx = pl.program_id(2)
+    kv_idx = pl.program_id(3)
 
     @pl.when(jnp.logical_and(q_idx == 0, kv_idx == 0))
     def _init_kv():
@@ -338,6 +428,7 @@ def _bwd_fused_kernel(q_seg_ref, kv_seg_ref, q_ref, k_ref, v_ref, do_ref,
 
     @pl.when(kv_idx == 0)
     def _init_q():
+        qs_ref[:] = _scaled(q_ref, scale * LOG2E)
         dq_acc[:] = jnp.zeros_like(dq_acc)
         do = do_ref[0].astype(jnp.float32)
         o = o_ref[0].astype(jnp.float32)
@@ -351,7 +442,7 @@ def _bwd_fused_kernel(q_seg_ref, kv_seg_ref, q_ref, k_ref, v_ref, do_ref,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0]
+        q = qs_ref[:]
         k = k_ref[0]
         v = v_ref[0]
         do = do_ref[0]
@@ -394,66 +485,59 @@ def _bwd_fused_kernel(q_seg_ref, kv_seg_ref, q_ref, k_ref, v_ref, do_ref,
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _flash_bwd_fused(scale, causal, segment_ids, res, do, causal_offset):
-    q, k, v, out, lse = res
-    b, sq, h, d = q.shape
-    sk = k.shape[1]
-    qr = (q * (scale * LOG2E)).astype(q.dtype) \
-        .transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    kr = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-    vr = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-    dor = do.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    outr = out.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    lser = jnp.broadcast_to((lse * LOG2E).reshape(b * h, sq)[:, :, None],
+def _narrow(x, b, h, sq):
+    """[b, h, sq] float32 as the kernels' [b*h, sq, SUBLANES] operand."""
+    return jnp.broadcast_to(x.reshape(b * h, sq)[:, :, None],
                             (b * h, sq, SUBLANES))
+
+
+def _bwd_fused_call(lay, q, k, v, heads0, do, out, lse, sq, sk, scale,
+                    causal, segment_ids, causal_offset):
+    b, h, d = lay.b, lay.h, lay.d
     bq, _ = _block_sizes(sq, d, q.dtype, role="bwd")
     _, bk = _block_sizes(sk, d, q.dtype, role="bwd")
     num_q, num_kv = sq // bq, sk // bk
 
     use_segs = segment_ids is not None
-    seg_specs, seg_args = _seg_operands(segment_ids, b, h, sq, sk, bq, bk)
+    kv = _kv_row(causal, causal_offset, bq, bk)
+    seg_specs, seg_args = _seg_operands(segment_ids, sq, sk, bq, bk,
+                                        kv_axis=kv)
 
     kernel = functools.partial(
         _bwd_fused_kernel, scale=scale, causal=causal, offset=causal_offset,
         bq=bq, bk=bk, num_q=num_q, num_kv=num_kv, use_segs=use_segs)
     if not use_segs:
         kernel = functools.partial(_nosegs_kernel, kernel)
-    dq, dk, dv = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
-        grid=(b * h, num_q, num_kv),
+        grid=(b, h, num_q, num_kv),
         in_specs=[
             *seg_specs,
-            pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, j, 0)),
-            pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, bq, SUBLANES), lambda bh, i, j: (bh, i, 0)),
+            lay.spec(bq, 0, heads0[0]),
+            lay.spec(bk, kv, heads0[1]),
+            lay.spec(bk, kv, heads0[2]),
+            lay.spec(bq, 0),
+            lay.spec(bq, 0),
+            lay.narrow(bq, 0),
         ],
-        out_specs=[
-            pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, sk, d), lambda bh, i, j: (bh, 0, 0)),
-            pl.BlockSpec((1, sk, d), lambda bh, i, j: (bh, 0, 0)),
-        ],
+        out_specs=[lay.spec(bq, 0), lay.spec(sk, None), lay.spec(sk, None)],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, sk, d), v.dtype),
+            jax.ShapeDtypeStruct(lay.shape(sq), q.dtype),
+            jax.ShapeDtypeStruct(lay.shape(sk), k.dtype),
+            jax.ShapeDtypeStruct(lay.shape(sk), v.dtype),
         ],
         scratch_shapes=[
+            pltpu.VMEM((bq, d), q.dtype),
             pltpu.VMEM((bq, d), jnp.float32),
             pltpu.VMEM((sk, d), jnp.float32),
             pltpu.VMEM((sk, d), jnp.float32),
             pltpu.VMEM((bq, LANES), jnp.float32),
         ],
-        compiler_params=_dim_semantics("parallel", "arbitrary", "arbitrary"),
+        compiler_params=_dim_semantics("parallel", "parallel", "arbitrary",
+                                       "arbitrary"),
         interpret=not on_tpu(),
         name="flash_bwd_fused",
-    )(*seg_args, qr, kr, vr, dor, outr, lser)
-    dq = dq.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
-    dk = dk.reshape(b, h, sk, d).transpose(0, 2, 1, 3)
-    dv = dv.reshape(b, h, sk, d).transpose(0, 2, 1, 3)
-    return dq, dk, dv
+    )(*seg_args, q, k, v, do, out, _narrow(lse * LOG2E, b, h, sq))
 
 
 # ---------------------------------------------------------------------------
@@ -461,13 +545,14 @@ def _flash_bwd_fused(scale, causal, segment_ids, res, do, causal_offset):
 # ---------------------------------------------------------------------------
 
 def _bwd_dq_kernel(q_seg_ref, kv_seg_ref, q_ref, k_ref, v_ref, do_ref,
-                   lse_ref, delta_ref, dq_ref, dq_acc,
+                   lse_ref, delta_ref, dq_ref, qs_ref, dq_acc,
                    *, scale, causal, offset, bq, bk, num_kv, use_segs):
-    kv_idx = pl.program_id(2)
-    q_idx = pl.program_id(1)
+    q_idx = pl.program_id(2)
+    kv_idx = pl.program_id(3)
 
     @pl.when(kv_idx == 0)
     def _init():
+        qs_ref[:] = _scaled(q_ref, scale * LOG2E)
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
     run = True
@@ -476,7 +561,7 @@ def _bwd_dq_kernel(q_seg_ref, kv_seg_ref, q_ref, k_ref, v_ref, do_ref,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0]
+        q = qs_ref[:]
         k = k_ref[0]
         v = v_ref[0]
         do = do_ref[0]
@@ -507,8 +592,8 @@ def _bwd_dq_kernel(q_seg_ref, kv_seg_ref, q_ref, k_ref, v_ref, do_ref,
 def _bwd_dkv_kernel(q_seg_ref, kv_seg_ref, q_ref, k_ref, v_ref, do_ref,
                     lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc,
                     *, scale, causal, offset, bq, bk, num_q, use_segs):
-    q_idx = pl.program_id(2)
-    kv_idx = pl.program_id(1)
+    kv_idx = pl.program_id(2)
+    q_idx = pl.program_id(3)
 
     @pl.when(q_idx == 0)
     def _init():
@@ -522,7 +607,8 @@ def _bwd_dkv_kernel(q_seg_ref, kv_seg_ref, q_ref, k_ref, v_ref, do_ref,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0]
+        # a new q block every step here: scaled where it is used
+        q = _scaled(q_ref, scale * LOG2E)
         k = k_ref[0]
         v = v_ref[0]
         do = do_ref[0]
@@ -554,28 +640,25 @@ def _bwd_dkv_kernel(q_seg_ref, kv_seg_ref, q_ref, k_ref, v_ref, do_ref,
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _flash_bwd_split(scale, causal, segment_ids, res, do, causal_offset):
-    q, k, v, out, lse = res
-    b, sq, h, d = q.shape
-    sk = k.shape[1]
-    qr = (q * (scale * LOG2E)).astype(q.dtype) \
-        .transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    kr = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-    vr = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-    dor = do.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    outr = out.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    lser = (lse * LOG2E).reshape(b * h, sq)
-    # delta = rowsum(do * o)  [bh, sq] -> narrow-lane [bh, sq, SUBLANES]
-    delta = jnp.sum(dor.astype(jnp.float32) * outr.astype(jnp.float32),
-                    axis=-1)
-    delta = jnp.broadcast_to(delta[:, :, None], (b * h, sq, SUBLANES))
-    lser = jnp.broadcast_to(lser[:, :, None], (b * h, sq, SUBLANES))
+def _bwd_split_call(lay, q, k, v, heads0, do, out, lse, sq, sk, scale,
+                    causal, segment_ids, causal_offset):
+    b, h, d = lay.b, lay.h, lay.d
+    # delta = rowsum(do * o): [b, sq, h] out of either layout's [.., d]
+    # rows, then head-major like lse (a tensor d times smaller than do)
+    delta = jnp.sum((do.astype(jnp.float32) * out.astype(jnp.float32))
+                    .reshape(do.shape[:-1] + (-1, d)), axis=-1)
+    if lay.native:
+        delta = delta.transpose(0, 2, 1)
+    delta = _narrow(delta, b, h, sq)
+    lser = _narrow(lse * LOG2E, b, h, sq)
     bq, _ = _block_sizes(sq, d, q.dtype, role="bwd")
     _, bk = _block_sizes(sk, d, q.dtype, role="bwd")
     num_q, num_kv = sq // bq, sk // bk
 
     use_segs = segment_ids is not None
-    seg_specs, seg_args = _seg_operands(segment_ids, b, h, sq, sk, bq, bk)
+    kv = _kv_row(causal, causal_offset, bq, bk)
+    seg_specs, seg_args = _seg_operands(segment_ids, sq, sk, bq, bk,
+                                        kv_axis=kv)
 
     dq_kernel = functools.partial(
         _bwd_dq_kernel, scale=scale, causal=causal, offset=causal_offset,
@@ -584,80 +667,86 @@ def _flash_bwd_split(scale, causal, segment_ids, res, do, causal_offset):
         dq_kernel = functools.partial(_nosegs_kernel, dq_kernel)
     dq = pl.pallas_call(
         dq_kernel,
-        grid=(b * h, num_q, num_kv),
+        grid=(b, h, num_q, num_kv),
         in_specs=[
             *seg_specs,
-            pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, j, 0)),
-            pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, bq, SUBLANES), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, bq, SUBLANES), lambda bh, i, j: (bh, i, 0)),
+            lay.spec(bq, 0, heads0[0]),
+            lay.spec(bk, kv, heads0[1]),
+            lay.spec(bk, kv, heads0[2]),
+            lay.spec(bq, 0),
+            lay.narrow(bq, 0),
+            lay.narrow(bq, 0),
         ],
-        out_specs=pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_dim_semantics("parallel", "parallel", "arbitrary"),
+        out_specs=lay.spec(bq, 0),
+        out_shape=jax.ShapeDtypeStruct(lay.shape(sq), q.dtype),
+        scratch_shapes=[pltpu.VMEM((bq, d), q.dtype),
+                        pltpu.VMEM((bq, d), jnp.float32)],
+        compiler_params=_dim_semantics("parallel", "parallel", "parallel",
+                                       "arbitrary"),
         interpret=not on_tpu(),
         name="flash_bwd_dq",
-    )(*seg_args, qr, kr, vr, dor, lser, delta)
+    )(*seg_args, q, k, v, do, lser, delta)
 
     dkv_kernel = functools.partial(
         _bwd_dkv_kernel, scale=scale, causal=causal, offset=causal_offset,
         bq=bq, bk=bk, num_q=num_q, use_segs=use_segs)
     if not use_segs:
         dkv_kernel = functools.partial(_nosegs_kernel, dkv_kernel)
-    dkv_seg_specs = [] if not use_segs else [
-        pl.BlockSpec((1, bq, SUBLANES), lambda bh, j, i: (bh, i, 0)),
-        pl.BlockSpec((1, SUBLANES, bk), lambda bh, j, i: (bh, 0, j)),
-    ]
+    # grid (b, h, kv, q): the kv block index is g[0], the q block's g[1]
+    qr = _q_row(causal, causal_offset, bq, bk, num_q)
+    dkv_seg_specs, _ = _seg_operands(segment_ids, sq, sk, bq, bk,
+                                     q_axis=qr, kv_axis=0)
     dk, dv = pl.pallas_call(
         dkv_kernel,
-        grid=(b * h, num_kv, num_q),
+        grid=(b, h, num_kv, num_q),
         in_specs=[
             *dkv_seg_specs,
-            pl.BlockSpec((1, bq, d), lambda bh, j, i: (bh, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, j, i: (bh, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, j, i: (bh, j, 0)),
-            pl.BlockSpec((1, bq, d), lambda bh, j, i: (bh, i, 0)),
-            pl.BlockSpec((1, bq, SUBLANES), lambda bh, j, i: (bh, i, 0)),
-            pl.BlockSpec((1, bq, SUBLANES), lambda bh, j, i: (bh, i, 0)),
+            lay.spec(bq, qr, heads0[0]),
+            lay.spec(bk, 0, heads0[1]),
+            lay.spec(bk, 0, heads0[2]),
+            lay.spec(bq, qr),
+            lay.narrow(bq, qr),
+            lay.narrow(bq, qr),
         ],
-        out_specs=[
-            pl.BlockSpec((1, bk, d), lambda bh, j, i: (bh, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, j, i: (bh, j, 0)),
-        ],
+        out_specs=[lay.spec(bk, 0), lay.spec(bk, 0)],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, sk, d), v.dtype),
+            jax.ShapeDtypeStruct(lay.shape(sk), k.dtype),
+            jax.ShapeDtypeStruct(lay.shape(sk), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
         ],
-        compiler_params=_dim_semantics("parallel", "parallel", "arbitrary"),
+        compiler_params=_dim_semantics("parallel", "parallel", "parallel",
+                                       "arbitrary"),
         interpret=not on_tpu(),
         name="flash_bwd_dkv",
-    )(*seg_args, qr, kr, vr, dor, lser, delta)
-
-    dq = dq.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
-    dk = dk.reshape(b, h, sk, d).transpose(0, 2, 1, 3)
-    dv = dv.reshape(b, h, sk, d).transpose(0, 2, 1, 3)
+    )(*seg_args, q, k, v, do, lser, delta)
     return dq, dk, dv
 
 
+def _bwd_call(lay, q, k, v, heads0, do, out, lse, sq, sk, *args):
+    """(dq, dk, dv) in ``lay``'s layout; operands as ``_fwd_call`` takes
+    them, ``do`` and ``out`` in the layout ``_fwd_call`` returned."""
+    # fused pins two full-sk fp32 scratch planes PLUS the full-sk dk/dv
+    # output blocks (constant-index out_specs) in VMEM per head
+    dkv_bytes = 2 * sk * lay.d * (4 + jnp.dtype(k.dtype).itemsize)
+    call = _bwd_fused_call if dkv_bytes <= _FUSED_DKV_VMEM_BYTES \
+        else _bwd_split_call
+    return call(lay, q, k, v, heads0, do, out, lse, sq, sk, *args)
+
+
 def _flash_bwd(scale, causal, segment_ids, res, g, causal_offset=0):
+    """(dq, dk, dv) of [b, s, h, d] operands; ``res`` = (q, k, v, out,
+    lse) as ``_flash_fwd`` took and returned them."""
     do = g[0] if isinstance(g, (tuple, list)) else g
     q, k, v, out, lse = res
-    sk, d = k.shape[1], k.shape[3]
-    # fused pins two full-sk fp32 scratch planes PLUS the full-sk dk/dv
-    # output blocks (constant-index out_specs) in VMEM per bh iteration
-    dkv_bytes = 2 * sk * d * (4 + jnp.dtype(k.dtype).itemsize)
-    if dkv_bytes <= _FUSED_DKV_VMEM_BYTES:
-        return _flash_bwd_fused(scale, causal, segment_ids,
-                                (q, k, v, out, lse), do, causal_offset)
-    return _flash_bwd_split(scale, causal, segment_ids,
-                            (q, k, v, out, lse), do, causal_offset)
+    b, sq, h, d = q.shape
+    lay = _Layout(b, h, d)
+    grads = _bwd_call(lay, lay.view(q), lay.view(k), lay.view(v), (0, 0, 0),
+                      lay.view(do), lay.view(out), lse, sq, k.shape[1],
+                      scale, causal, segment_ids, causal_offset)
+    return tuple(lay.unview(x) for x in grads)
 
 
 # ---------------------------------------------------------------------------
@@ -709,3 +798,69 @@ def flash_attention_with_lse(q, k, v, causal: bool = True,
     scale = softmax_scale if softmax_scale is not None \
         else 1.0 / math.sqrt(q.shape[-1])
     return _flash_fwd(q, k, v, scale, causal, segment_ids)
+
+
+# ---------------------------------------------------------------------------
+# self-attention off the fused projection: q | k | v on one array's lanes
+# ---------------------------------------------------------------------------
+
+def _qkv_fwd(qkv, segs, h, scale, causal):
+    b, s, w = qkv.shape
+    return _fwd_call(_Layout(b, h, w // (3 * h)), qkv, qkv, qkv,
+                     (0, h, 2 * h), s, s, scale, causal, segs, 0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5))
+def _flash_qkv(qkv, segment_ids, h, scale, causal, use_segs):
+    return _qkv_fwd(qkv, segment_ids if use_segs else None, h, scale,
+                    causal)[0]
+
+
+def _flash_qkv_fwd_rule(qkv, segment_ids, h, scale, causal, use_segs):
+    out, lse = _qkv_fwd(qkv, segment_ids if use_segs else None, h, scale,
+                        causal)
+    return out, (qkv, segment_ids, out, lse)
+
+
+def _flash_qkv_bwd_rule(h, scale, causal, use_segs, res, do):
+    qkv, segment_ids, out, lse = res
+    b, s, w = qkv.shape
+    lay = _Layout(b, h, w // (3 * h))
+    grads = _bwd_call(lay, qkv, qkv, qkv, (0, h, 2 * h), do, out, lse, s, s,
+                      scale, causal, segment_ids if use_segs else None, 0)
+    # dq | dk | dv as a sum of three pads (what a slice's transpose is):
+    # XLA fuses that into the operands of the projection's dW and dx
+    # matmuls, where a concatenate is materialized by three
+    # dynamic-update-slices over the whole [b, s, 3*h*d]
+    zero = jnp.zeros((), do.dtype)
+    dqkv = sum(lax.pad(g, zero, [(0, 0, 0), (0, 0, 0),
+                                 (i * h * lay.d, (2 - i) * h * lay.d, 0)])
+               for i, g in enumerate(grads))
+    dsegs = np.zeros(segment_ids.shape, jax.dtypes.float0)
+    return dqkv, dsegs
+
+
+_flash_qkv.defvjp(_flash_qkv_fwd_rule, _flash_qkv_bwd_rule)
+
+
+def flash_attention_qkv(qkv, num_heads: int, causal: bool = True,
+                        softmax_scale: Optional[float] = None,
+                        segment_ids: Optional[jax.Array] = None):
+    """Self-attention on the fused projection's [b, s, 3*h*d] (q | k | v,
+    each h heads of d) -> [b, s, h*d]; differentiable.  With
+    ``d % 128 == 0`` the three operands are block index maps on the one
+    array and nothing is sliced out; a narrower head goes through
+    :func:`flash_attention`."""
+    b, s, w = qkv.shape
+    d = w // (3 * num_heads)
+    if d % LANES:
+        q, k, v = (x.reshape(b, s, num_heads, d)
+                   for x in jnp.split(qkv, 3, axis=-1))
+        return flash_attention(q, k, v, causal, softmax_scale,
+                               segment_ids).reshape(b, s, num_heads * d)
+    scale = softmax_scale if softmax_scale is not None \
+        else 1.0 / math.sqrt(d)
+    use_segs = segment_ids is not None
+    if segment_ids is None:
+        segment_ids = jnp.zeros((b, s), jnp.int32)
+    return _flash_qkv(qkv, segment_ids, num_heads, scale, causal, use_segs)

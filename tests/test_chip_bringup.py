@@ -97,12 +97,22 @@ def _kernel_cases():
             *(_sds((PAGES, h, PAGE, w), dt) for h, w, dt in widths),
             *(_sds((t, h, w), dt) for h, w, dt in widths)))
 
-    qkv = lambda s: (_sds((1, s, 12, 64), BF16),) * 3   # noqa: E731
+    def flash_qkv_grad(x):
+        return jax.grad(lambda x: fa.flash_attention_qkv(
+            x, 12, causal=True).astype(F32).sum())(x)
+
+    qkv = lambda s, b=1, d=64: (_sds((b, s, 12, d), BF16),) * 3  # noqa: E731
     pages = (_sds((PAGES, 12, PAGE, 64), BF16),) * 2
     return {
         # 1024: fused single-kernel backward; 8192: split dq / dkv
         "flash_fused": (flash_grads, qkv(1024)),
         "flash_split": (flash_grads, qkv(8192)),
+        # head_dim 128 read out of [b, s, h*d] as it is, at the train
+        # cell's shape (Cerebras-GPT-590M, micro-batch 4): three arrays,
+        # the split backward, and q | k | v on the fused projection's lanes
+        "flash_native": (flash_grads, qkv(2048, 4, 128)),
+        "flash_native_split": (flash_grads, qkv(4096, 1, 128)),
+        "flash_qkv": (flash_qkv_grad, (_sds((4, 2048, 3 * 12 * 128), BF16),)),
         "ragged_12kv_x64": (ragged, (_sds((T, 12, 64), BF16), *pages,
                                      *_desc())),
         "ragged_decode_region": region(32, 1),
@@ -126,12 +136,14 @@ def _kernel_cases():
 
 # the Mosaic compile is seconds per kernel and tier-1 has none to spare:
 # it takes the three that broke there — the k/v block, scoped VMEM at a
-# 256-token chunk of d_c 512, the 4-bit unpack; flash compiled as it was
-# — and the serving step's one-token window, whose 16-row bf16 tile is
-# the layout most likely to be refused; of the KV write, the benchmark's
-# pool and the one written in whole one-lane pages
+# 256-token chunk of d_c 512, the 4-bit unpack; head-major flash compiled
+# as it was — and the serving step's one-token window, whose 16-row bf16
+# tile is the layout most likely to be refused; of the KV write, the
+# benchmark's pool and the one written in whole one-lane pages; of flash,
+# the train cell's call: three lane blocks of the fused [b, s, 3*h*d]
 AOT_CASES = ("ragged_12kv_x64", "ragged_decode_region", "latent_512_64",
-             "latent_nf4", "kv_write_12kv_x128", "kv_write_int8_sidecar")
+             "latent_nf4", "kv_write_12kv_x128", "kv_write_int8_sidecar",
+             "flash_qkv")
 
 
 @pytest.fixture
@@ -325,6 +337,33 @@ def test_native_build_is_keyed_on_its_sources(monkeypatch, tmp_path):
     assert build.load_native("bad", [str(bad)]) is None        # preferred
     with pytest.raises(build.NativeBuildError, match="error"):  # asked for
         build.load_native("bad", [str(bad)], required=True)
+
+
+@pytest.mark.parametrize("shape", [{"dp": 4, "tp": 1}, {"dp": 2, "tp": 2}],
+                         ids=["dp4", "dp2tp2"])
+def test_fused_qkv_flash_is_placed_by_shard_map_under_a_mesh(devices8, shape):
+    """``attention_qkv`` under a mesh: whole fused rows per batch shard
+    where tp is 1 (the four-chip train cell); where tp cuts the fused
+    axis across q | k | v, sliced after all and the heads sharded."""
+    from hetu_tpu.nn.parallel import sharded
+    mesh = ht.create_mesh(shape, devices8[:4])
+    spec = P("dp", None, "tp")
+    x = np.random.RandomState(0).randn(4, 64, 3 * 2 * 128).astype(np.float32)
+    outs = {}
+    for flash in (False, True):
+        with ht.graph("define_and_run", create_new=True, mesh=mesh) as g:
+            ph = ht.parallel_placeholder("float32", x.shape, pspec=spec,
+                                         name="qkv")
+            o = ops.attention_qkv(sharded(ph, spec), 2, causal=True,
+                                  use_flash=flash)
+            loss = ops.reduce_sum(ops.mul(o, o))
+            (gx,) = g.make_gradients(loss, [ph])
+            outs[flash] = [np.asarray(r) for r in g.run(
+                loss, [o, gx], {ph: x})]
+            jaxpr = str(g.analysis_handles()[-1].jaxpr)
+            assert ("shard_map" in jaxpr) == flash
+    for a, b in zip(outs[True], outs[False]):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
 
 
 def test_flash_is_placed_by_shard_map_under_a_mesh(devices8):

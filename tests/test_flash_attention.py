@@ -2,16 +2,25 @@
 
 Oracle: the jnp reference SDPA (itself validated against torch in
 test_ops.py::TestAttention).  Covers fwd/bwd, causal/full, packed
-segment-ids (varlen), LSE output, GQA-shaped inputs, odd block sizes.
+segment-ids (varlen), LSE output, GQA-shaped inputs, odd block sizes —
+in both layouts the kernels read: head_dim 128 out of [b, s, h*d] as it
+is ("native"), head_dim 64 transposed to [b*h, s, d] ("head_major").
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from hetu_tpu import obs
 from hetu_tpu.ops.attention import sdpa_reference
+from hetu_tpu.ops.pallas import flash_attention as fa
 from hetu_tpu.ops.pallas.flash_attention import (flash_attention,
+                                                flash_attention_qkv,
                                                 flash_attention_with_lse)
+
+# head_dim -> the layout the kernels take it in
+LAYOUTS = {128: "native", 64: "head_major"}
+head_dims = pytest.mark.parametrize("d", list(LAYOUTS))
 
 
 def _mk(b=2, s=128, h=2, d=64, seed=0, dtype=jnp.float32):
@@ -21,9 +30,10 @@ def _mk(b=2, s=128, h=2, d=64, seed=0, dtype=jnp.float32):
 
 
 class TestFlashForward:
+    @head_dims
     @pytest.mark.parametrize("causal", [True, False])
-    def test_matches_reference(self, causal):
-        q, k, v = _mk()
+    def test_matches_reference(self, causal, d):
+        q, k, v = _mk(d=d)
         out = flash_attention(q, k, v, causal=causal)
         ref = sdpa_reference(q, k, v, causal=causal)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -37,8 +47,9 @@ class TestFlashForward:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=1e-4, atol=1e-4)
 
-    def test_segment_ids_packing(self):
-        q, k, v = _mk()
+    @head_dims
+    def test_segment_ids_packing(self, d):
+        q, k, v = _mk(d=d)
         b, s = q.shape[0], q.shape[1]
         segs = jnp.asarray(np.repeat(np.arange(4), s // 4)[None].repeat(b, 0))
         out = flash_attention(q, k, v, causal=True, segment_ids=segs)
@@ -46,14 +57,15 @@ class TestFlashForward:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=1e-4, atol=1e-4)
 
-    def test_fully_masked_rows_empty_contract(self):
+    @head_dims
+    def test_fully_masked_rows_empty_contract(self, d):
         """Rows that see no valid kv position (ring varlen padding, -1 seg
         ids everywhere) must emit out=0, lse=-inf — the contract
         ring_attention's _merge/backward guards rely on — in BOTH the
         single-kv-block fast path and the multi-block accumulate path."""
         for s in (128, 384):  # 128 -> single-kv fast path; 384 -> 3 blocks
             # of 128 through the accumulate/_finalize path
-            q, k, v = _mk(s=s)
+            q, k, v = _mk(s=s, d=d)
             b = q.shape[0]
             # first half of each batch row is a real doc, second half pad
             seg = np.zeros((b, s), np.int32)
@@ -74,8 +86,9 @@ class TestFlashForward:
             np.testing.assert_allclose(out[:, : s // 2], np.asarray(ref),
                                        rtol=1e-4, atol=1e-4)
 
-    def test_lse(self):
-        q, k, v = _mk()
+    @head_dims
+    def test_lse(self, d):
+        q, k, v = _mk(d=d)
         out, lse = flash_attention_with_lse(q, k, v, causal=True)
         assert lse.shape == (2, 2, 128)
         # oracle LSE from dense logits
@@ -87,6 +100,130 @@ class TestFlashForward:
         ref_lse = jax.nn.logsumexp(logits, axis=-1)
         np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse),
                                    rtol=1e-4, atol=1e-4)
+
+
+def _sq(x):
+    return jnp.sum(x.astype(jnp.float32) ** 2)
+
+
+def _assert_grads(got, want, tol=1e-3):
+    for name, a, b in zip("qkv", got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=tol, atol=tol, err_msg=f"d{name}")
+
+
+class TestBothLayouts:
+    """Forward and jax.grad in the layout each head_dim takes, at sizes
+    tier-1 can afford (the slow classes below keep the larger ones)."""
+
+    @head_dims
+    @pytest.mark.parametrize("causal", [True, False])
+    @pytest.mark.parametrize("packed", [False, True])
+    def test_grads_match_reference(self, d, causal, packed):
+        q, k, v = _mk(b=1, s=256, d=d)      # fused backward, 2 x 2 blocks
+        segs = jnp.asarray(np.repeat(np.arange(2), 128)[None]) \
+            if packed else None
+        obs.reset_counts()
+        got = jax.grad(lambda *a: _sq(flash_attention(
+            *a, causal=causal, segment_ids=segs)), argnums=(0, 1, 2))(q, k, v)
+        want = jax.grad(lambda *a: _sq(sdpa_reference(
+            *a, causal=causal, segment_ids=segs)), argnums=(0, 1, 2))(q, k, v)
+        _assert_grads(got, want)
+        assert [dict(key)["layout"] for key in obs.counts("flash_calls")] \
+            == [LAYOUTS[d]]
+
+    @head_dims
+    def test_split_backward(self, d, monkeypatch):
+        monkeypatch.setattr(fa, "_FUSED_DKV_VMEM_BYTES", 0)  # force split
+        q, k, v = _mk(b=1, s=256, d=d)
+        segs = jnp.asarray(np.repeat(np.arange(2), 128)[None])
+        got = jax.grad(lambda *a: _sq(flash_attention(
+            *a, causal=True, segment_ids=segs)), argnums=(0, 1, 2))(q, k, v)
+        want = jax.grad(lambda *a: _sq(sdpa_reference(
+            *a, causal=True, segment_ids=segs)), argnums=(0, 1, 2))(q, k, v)
+        _assert_grads(got, want)
+
+    @head_dims
+    @pytest.mark.parametrize("split", [False, True])
+    def test_causal_offset_as_ring_attention_passes_it(self, d, split,
+                                                       monkeypatch):
+        """The SYM tail half: q rows sh.. against the whole kv, the
+        diagonal shifted by sh (``parallel/ring_attention.py``)."""
+        if split:
+            monkeypatch.setattr(fa, "_FUSED_DKV_VMEM_BYTES", 0)
+        q, k, v = _mk(b=1, s=256, d=d)
+        sh = 128
+        scale = 1.0 / np.sqrt(d)
+        qt = q[:, sh:]
+        out, lse = fa._flash_fwd(qt, k, v, scale, True, None,
+                                 causal_offset=sh)
+        # the reference's causal mask is ki <= qi + (sk - sq): that shift
+        ref, vjp = jax.vjp(lambda *a: sdpa_reference(*a, causal=True),
+                           qt, k, v)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=1e-4, atol=1e-4)
+        do = jnp.asarray(np.random.RandomState(1).randn(*out.shape),
+                         jnp.float32)
+        got = fa._flash_bwd(scale, True, None, (qt, k, v, out, lse), do,
+                            causal_offset=sh)
+        _assert_grads(got, vjp(do))
+
+    @head_dims
+    @pytest.mark.parametrize("packed", [False, True])
+    def test_fused_qkv_matches_reference(self, d, packed):
+        """q | k | v on one array's last axis: block index maps at
+        head_dim 128, slices and the head-major path at 64."""
+        q, k, v = _mk(b=1, s=256, d=d)
+        b, s, h, _ = q.shape
+        segs = jnp.asarray(np.repeat(np.arange(2), 128)[None]) \
+            if packed else None
+        qkv = jnp.concatenate([x.reshape(b, s, h * d) for x in (q, k, v)],
+                              axis=-1)
+
+        def ref(x):
+            return sdpa_reference(*(t.reshape(b, s, h, d) for t in
+                                    jnp.split(x, 3, axis=-1)),
+                                  segment_ids=segs).reshape(b, s, h * d)
+
+        obs.reset_counts()
+        np.testing.assert_allclose(
+            np.asarray(flash_attention_qkv(qkv, h, segment_ids=segs)),
+            np.asarray(ref(qkv)), rtol=1e-4, atol=1e-4)
+        got = jax.grad(lambda x: _sq(flash_attention_qkv(
+            x, h, segment_ids=segs)))(qkv)
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(jax.grad(lambda x: _sq(ref(x)))(qkv)),
+            rtol=1e-3, atol=1e-3)
+        assert [dict(key)["layout"] for key in obs.counts("flash_calls")] \
+            == [LAYOUTS[d]]
+
+    def test_native_layout_transposes_nothing(self):
+        """At head_dim 128 no operand and no result changes layout round
+        the kernels, forward or backward; at 64 they do."""
+        def transposes(d):
+            q, k, v = _mk(b=1, s=128, d=d)
+            jaxpr = jax.make_jaxpr(jax.grad(
+                lambda *a: _sq(flash_attention(*a)), argnums=(0, 1, 2)))(
+                    q, k, v)
+            return sum(e.primitive.name == "transpose"
+                       for e in jaxpr.jaxpr.eqns)
+
+        obs.reset_counts()
+        assert transposes(128) == 0
+        assert obs.counts("flash_calls") == {(("layout", "native"),): 2}
+        assert transposes(64) >= 12
+
+    def test_fused_qkv_cuts_no_slice(self):
+        qkv = jnp.zeros((1, 128, 3 * 2 * 128), jnp.float32)
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda x: _sq(flash_attention_qkv(x, 2))))(qkv)
+        # (the log-sum-exp's narrow [b*h, s, 8] is sliced; nothing as
+        # wide as a head is)
+        moved = [e.primitive.name for e in jaxpr.jaxpr.eqns
+                 if e.primitive.name in ("slice", "split", "transpose",
+                                         "concatenate")
+                 and any(v.aval.shape[-1] >= 128 for v in e.invars)]
+        assert moved == []
 
 
 @pytest.mark.slow
