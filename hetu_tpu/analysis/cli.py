@@ -433,6 +433,42 @@ def build_gate_executables():
     assert all(r["predicted_s"] > 0 for r in heng.host_tier.records), \
         "host-tier move lost its alpha-beta pricing"
     names.append("gate_serving@slo/unified")
+
+    # -- hybrid stack: one mixer per layer (attention, latent MoE over a
+    # share of the experts, mamba2), K/V pages for the attention layer
+    # and a state slot per sequence for the mamba2 layers carried through
+    # the SAME one executable; a pool squeezed into a preemption so the
+    # trace holds a slot dropped and taken again ----------------------
+    from hetu_tpu.models.hybrid import hybrid_config, init_state
+    ycfg = hybrid_config(dict(
+        hybrid_override_pattern="*EMEM", num_hidden_layers=5,
+        hidden_size=32, num_attention_heads=4, head_dim=8,
+        num_key_value_heads=2, vocab_size=128, max_position_embeddings=64,
+        mlp_hidden_act="relu2", layer_norm_epsilon=1e-5,
+        tie_word_embeddings=False, mamba_num_heads=4, mamba_head_dim=8,
+        n_groups=2, ssm_state_size=8, conv_kernel=4, chunk_size=4,
+        n_routed_experts=4, moe_router_outputs=16, expert_offset=4,
+        num_experts_per_tok=6, routed_scaling_factor=5,
+        moe_intermediate_size=16, moe_latent_size=16,
+        moe_shared_expert_intermediate_size=32, n_shared_experts=1,
+        dtype="float32"))
+    yclock = [0.0]
+    yeng = Engine(init_state(ycfg, 1), ycfg, num_pages=6, page_size=8,
+                  max_batch=4, chunk_size=4, name="gate_serving@hybrid",
+                  time_fn=lambda: yclock[0], prefix_cache=False)
+    yeng.add_request(list(range(1, 14)), max_new_tokens=12)
+    yeng.add_request(list(range(20, 33)), max_new_tokens=12)
+    yeng.add_request([7, 8, 9], max_new_tokens=4)
+    while yeng.has_work:
+        yeng.step()
+        yclock[0] += 1.0
+    yeng.pool.check_invariants(force=True)
+    assert yeng.compile_count == 1, \
+        "the hybrid stack retraced the unified executable"
+    assert yeng.state_store.in_use == 0, "a state slot leaked"
+    assert yeng.counters["preemptions"].value >= 1, \
+        "hybrid gate trace never preempted — no slot was dropped"
+    names.append("gate_serving@hybrid/unified")
     return names + [f"gate_serving@r{i}/unified" for i in range(2)]
 
 

@@ -32,14 +32,22 @@ from jax import lax
 from .gpt import GPTConfig
 
 
+def norm_eps(cfg: GPTConfig) -> float:
+    """``cfg.norm_eps``, or the norm's own default."""
+    if cfg.norm_eps is not None:
+        return float(cfg.norm_eps)
+    return 1e-6 if cfg.norm == "rmsnorm" else 1e-5
+
+
 def _norm_apply(cfg: GPTConfig, w, b, x):
     xf = x.astype(jnp.float32)
+    eps = norm_eps(cfg)
     if cfg.norm == "rmsnorm":
-        xf = xf * lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + 1e-6)
+        xf = xf * lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
         return (xf * w).astype(x.dtype)
     m = jnp.mean(xf, -1, keepdims=True)
     v = jnp.var(xf, -1, keepdims=True)
-    out = (xf - m) * lax.rsqrt(v + 1e-5) * w + (b if b is not None else 0.0)
+    out = (xf - m) * lax.rsqrt(v + eps) * w + (b if b is not None else 0.0)
     return out.astype(x.dtype)
 
 

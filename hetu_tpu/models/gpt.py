@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
-from typing import Optional
+from typing import Optional, Tuple
 
 import numpy as np
 
@@ -29,6 +29,9 @@ from ..obs.phases import phase
 from jax.sharding import PartitionSpec as P
 
 
+MIXERS = ("mamba2", "attention", "moe")
+
+
 @dataclass
 class GPTConfig:
     vocab_size: int = 50257
@@ -38,9 +41,9 @@ class GPTConfig:
     num_kv_heads: Optional[int] = None      # GQA; None -> = num_heads
     ffn_hidden_size: Optional[int] = None   # None -> 4h (gelu) or 8h/3 (swiglu)
     max_seq_len: int = 1024
-    activation: str = "gelu"                # gelu (GPT) | swiglu (LLaMA)
+    activation: str = "gelu"      # gelu (GPT) | swiglu (LLaMA) | relu2
     norm: str = "layernorm"                 # layernorm (GPT) | rmsnorm (LLaMA)
-    position: str = "learned"               # learned (GPT) | rotary (LLaMA)
+    position: str = "learned"     # learned (GPT) | rotary (LLaMA) | none
     dropout: float = 0.0
     sp: bool = True                         # Megatron sequence parallel
     tie_embeddings: bool = False
@@ -69,8 +72,59 @@ class GPTConfig:
     # None -> head_dim); learned-position configs carry no rope stream.
     kv_latent_dim: Optional[int] = None
     kv_rope_dim: Optional[int] = None
+    # Hybrid stacks (serving path; models/hybrid.py owns the parameter
+    # names): ``layer_pattern`` gives ONE mixer per layer behind one
+    # pre-norm and one residual — "mamba2" | "attention" | "moe".  None
+    # is today's block (attention + MLP in every layer).  The K/V pool
+    # then holds the attention layers only and a state-slot store the
+    # mamba2 layers (serving/kv_pool.py).
+    layer_pattern: Optional[Tuple[str, ...]] = None
+    norm_eps: Optional[float] = None        # None -> the norm's own default
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_n_groups: int = 1
+    mamba_state_dim: int = 0                # ssm_state_size N
+    mamba_conv_kernel: int = 4
+    mamba_chunk_size: int = 128             # SSD chunk of the matmul form
+    # expert layer of a hybrid stack: ``num_experts`` is the ROUTER's
+    # width (all experts of the deployment), of which this program holds
+    # ``experts_held`` starting at ``expert_offset`` (default: all) and
+    # computes only their part of the result (expert parallelism without
+    # the exchange).  "sigmoid_bias": sigmoid scores, top-k of score +
+    # selection bias, chosen scores renormalised, x moe_router_scale.
+    moe_router: str = "softmax"             # softmax | sigmoid_bias
+    moe_router_scale: float = 1.0
+    moe_ffn_size: Optional[int] = None      # one routed expert's width
+    moe_latent_dim: Optional[int] = None    # experts work in this width
+    moe_shared_ffn_size: int = 0            # shared expert (0: none)
+    experts_held: Optional[int] = None
+    expert_offset: int = 0
 
     def __post_init__(self):
+        if self.layer_pattern is not None:
+            self.layer_pattern = tuple(self.layer_pattern)
+            bad = set(self.layer_pattern) - set(MIXERS)
+            if bad or len(self.layer_pattern) != self.num_layers:
+                raise ValueError(
+                    f"layer_pattern must name one of {MIXERS} for each of "
+                    f"the {self.num_layers} layers, got {self.layer_pattern}")
+            if "moe" in self.layer_pattern:
+                held, off = self.held_experts, self.expert_offset
+                if not (self.num_experts > 0 and held >= 1 and off >= 0
+                        and off + held <= self.num_experts):
+                    raise ValueError(
+                        f"experts held [{off}, {off + held}) must lie in "
+                        f"the router's {self.num_experts}")
+        if self.moe_router not in ("softmax", "sigmoid_bias"):
+            raise ValueError(f"unknown moe_router {self.moe_router!r}")
+        if self.layer_pattern is None and (
+                self.moe_router != "softmax" or self.moe_latent_dim
+                or self.moe_shared_ffn_size or self.experts_held is not None):
+            raise ValueError(
+                "moe_router / moe_latent_dim / moe_shared_ffn_size / "
+                "experts_held describe the expert layer of a layer_pattern "
+                "stack; the plain block's MoE is softmax top-k over all its "
+                "experts")
         assert self.hidden_size % self.num_heads == 0, \
             f"hidden {self.hidden_size} not divisible by heads {self.num_heads}"
         kv = self.num_kv_heads or self.num_heads
@@ -93,6 +147,34 @@ class GPTConfig:
     @property
     def kv_heads(self) -> int:
         return self.num_kv_heads or self.num_heads
+
+    @property
+    def is_hybrid(self) -> bool:
+        return self.layer_pattern is not None
+
+    def layers_of(self, mixer: str) -> Tuple[int, ...]:
+        """Layer indices running ``mixer``; a plain stack has attention
+        (and nothing else from the pattern's vocabulary) in every layer."""
+        if self.layer_pattern is None:
+            return tuple(range(self.num_layers)) if mixer == "attention" \
+                else ()
+        return tuple(i for i, m in enumerate(self.layer_pattern)
+                     if m == mixer)
+
+    @property
+    def held_experts(self) -> int:
+        return self.num_experts if self.experts_held is None \
+            else int(self.experts_held)
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Channels the causal conv runs over: x | B | C."""
+        return self.mamba_inner + \
+            2 * self.mamba_n_groups * self.mamba_state_dim
 
     @property
     def is_mla(self) -> bool:

@@ -1,0 +1,346 @@
+"""Hybrid stacks: one mixer per layer — Mamba-2 / attention / latent MoE.
+
+A ``GPTConfig`` with a ``layer_pattern`` runs ``x = x + mixer_l(RMSNorm_l
+(x))`` for every layer, a final norm and an untied head.  This module is
+the one place that knows the stack's PARAMETER NAMES and the mixers'
+arithmetic; the serving step (``serving/decode.py``) lays them over the
+ragged token axis and owns the state (K/V pages for the attention layers,
+state slots for the mamba2 layers).  The plain float32 reference is
+``models/hybrid_reference.py``.
+
+Tensors (a projection ``W`` is ``[out, in]``, used as ``x @ W.T``)::
+
+    wte.weight [V, H]   lm_head.weight [V, H]   ln_f.weight [H]
+    h{i}.norm.weight [H]                                  every layer
+    h{i}.mamba.in_proj.weight  [2*inner + 2*G*N + heads, H]   z | xBC | dt
+    h{i}.mamba.conv.weight [K, inner + 2*G*N]   .conv.bias [inner + 2*G*N]
+    h{i}.mamba.dt_bias / .A_log / .D [heads]    (float32)
+    h{i}.mamba.norm.weight [inner]    h{i}.mamba.out_proj.weight [H, inner]
+    h{i}.attn.qkv.weight [(nh + 2*kv)*hd, H]    h{i}.attn.out.weight [H, nh*hd]
+    h{i}.moe.router.weight [E_all, H]   .router.bias [E_all]  (float32)
+    h{i}.moe.latent_down.weight [L, H]  h{i}.moe.latent_up.weight [H, L]
+    h{i}.moe.experts.w1 [E_held, L, F]  h{i}.moe.experts.w2 [E_held, F, L]
+    h{i}.moe.shared.up.weight [Fs, H]   h{i}.moe.shared.down.weight [H, Fs]
+
+Without ``moe_latent_dim`` the experts work on the hidden itself (``L =
+H``, no latent projections).
+"""
+from __future__ import annotations
+
+import math
+from typing import Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ..obs.phases import phase
+from ..ops.ssd import causal_conv, ssd_chunk_scan, ssd_decode_step
+from .generate import norm_eps
+from .gpt import GPTConfig
+
+F32 = jnp.float32
+MIXER_OF = {"M": "mamba2", "*": "attention", "E": "moe"}
+# float32 whatever the model's dtype: the recurrence's own parameters and
+# the router (its scores decide a top-k)
+_F32_PARAMS = ("mamba.dt_bias", "mamba.A_log", "mamba.D",
+               "moe.router.weight", "moe.router.bias")
+
+
+def hybrid_config(pub: dict, **overrides) -> GPTConfig:
+    """The one translation from the published ``config.json`` keys of a
+    ``nemotron_h``-type model (as cut: ``n_routed_experts`` = experts held
+    here, ``moe_router_outputs`` = the router's width) to ``GPTConfig``."""
+    pattern = tuple(MIXER_OF[ch] for ch in pub["hybrid_override_pattern"])
+    if len(pattern) != pub["num_hidden_layers"]:
+        raise ValueError("hybrid_override_pattern and num_hidden_layers "
+                         "disagree")
+    if pub["hidden_size"] != pub["num_attention_heads"] * pub["head_dim"]:
+        raise ValueError("head_dim other than hidden / heads is not built")
+    if pub.get("n_group", 1) != 1 or pub.get("topk_group", 1) != 1:
+        raise ValueError("group-limited routing is not built")
+    kw = dict(
+        vocab_size=pub["vocab_size"], hidden_size=pub["hidden_size"],
+        num_layers=len(pattern), num_heads=pub["num_attention_heads"],
+        num_kv_heads=pub["num_key_value_heads"],
+        max_seq_len=pub["max_position_embeddings"],
+        activation=pub["mlp_hidden_act"], norm="rmsnorm", position="none",
+        norm_eps=float(pub["layer_norm_epsilon"]),
+        tie_embeddings=bool(pub["tie_word_embeddings"]), sp=False,
+        dtype=pub.get("dtype", "bfloat16"), layer_pattern=pattern,
+        mamba_num_heads=pub["mamba_num_heads"],
+        mamba_head_dim=pub["mamba_head_dim"],
+        mamba_n_groups=pub["n_groups"],
+        mamba_state_dim=pub["ssm_state_size"],
+        mamba_conv_kernel=pub["conv_kernel"],
+        mamba_chunk_size=pub["chunk_size"],
+        num_experts=pub.get("moe_router_outputs", pub["n_routed_experts"]),
+        experts_held=pub["n_routed_experts"],
+        expert_offset=pub.get("expert_offset", 0),
+        moe_top_k=pub["num_experts_per_tok"], moe_router="sigmoid_bias",
+        moe_router_scale=float(pub["routed_scaling_factor"]),
+        moe_ffn_size=pub["moe_intermediate_size"],
+        moe_latent_dim=pub.get("moe_latent_size"),
+        moe_shared_ffn_size=pub["moe_shared_expert_intermediate_size"]
+        * pub["n_shared_experts"])
+    kw.update(overrides)
+    return GPTConfig(**kw)
+
+
+def param_shapes(cfg: GPTConfig) -> Dict[str, Tuple[int, ...]]:
+    """Every tensor of a hybrid stack under its name."""
+    c = cfg
+    hd = c.hidden_size
+    out = {"wte.weight": (c.vocab_size, hd),
+           "lm_head.weight": (c.vocab_size, hd), "ln_f.weight": (hd,)}
+    inner, cd, mh = c.mamba_inner, c.mamba_conv_dim, c.mamba_num_heads
+    lat = c.moe_latent_dim or hd
+    for i, mixer in enumerate(c.layer_pattern):
+        p = f"h{i}."
+        out[p + "norm.weight"] = (hd,)
+        if mixer == "mamba2":
+            out[p + "mamba.in_proj.weight"] = (inner + cd + mh, hd)
+            out[p + "mamba.conv.weight"] = (c.mamba_conv_kernel, cd)
+            out[p + "mamba.conv.bias"] = (cd,)
+            for n in ("dt_bias", "A_log", "D"):
+                out[p + "mamba." + n] = (mh,)
+            out[p + "mamba.norm.weight"] = (inner,)
+            out[p + "mamba.out_proj.weight"] = (hd, inner)
+        elif mixer == "attention":
+            q, kv = c.num_heads * c.head_dim, c.kv_heads * c.head_dim
+            out[p + "attn.qkv.weight"] = (q + 2 * kv, hd)
+            out[p + "attn.out.weight"] = (hd, q)
+        else:
+            out[p + "moe.router.weight"] = (c.num_experts, hd)
+            out[p + "moe.router.bias"] = (c.num_experts,)
+            if c.moe_latent_dim:
+                out[p + "moe.latent_down.weight"] = (lat, hd)
+                out[p + "moe.latent_up.weight"] = (hd, lat)
+            out[p + "moe.experts.w1"] = (c.held_experts, lat, c.moe_ffn_size)
+            out[p + "moe.experts.w2"] = (c.held_experts, c.moe_ffn_size, lat)
+            if c.moe_shared_ffn_size:
+                out[p + "moe.shared.up.weight"] = (c.moe_shared_ffn_size, hd)
+                out[p + "moe.shared.down.weight"] = (hd,
+                                                     c.moe_shared_ffn_size)
+    return out
+
+
+def param_dtype(cfg: GPTConfig, name: str):
+    if name.endswith(_F32_PARAMS):
+        return F32
+    return jnp.bfloat16 if cfg.dtype == "bfloat16" else F32
+
+
+def init_state(cfg: GPTConfig, seed: int, time_step=(0.001, 0.1, 1e-4),
+               router_bias_std: float = 0.0) -> Dict[str, jax.Array]:
+    """Seeded random weights, made on the device, one jitted call per
+    layer so that no more than one layer's temporaries are live: matrices
+    normal(0, init_std), the projections back into the residual stream
+    (``out_proj``, ``attn.out``, ``latent_up``, ``shared.down``) scaled by
+    ``1 / sqrt(num_layers)`` (the published ``rescale_prenorm_residual``:
+    one residual branch a layer); norms 1, conv bias 0; ``dt`` log-uniform
+    in ``[time_step_min, time_step_max]`` floored at ``time_step_floor``
+    and stored as ``dt_bias = dt + log(-expm1(-dt))`` (inverse softplus),
+    ``A`` uniform 1..16 as ``A_log``, ``D`` 1 — the published
+    initialiser.  The hardware bit generator (``rbg``) keeps the
+    temporaries at the tensors' own size."""
+    shapes = param_shapes(cfg)
+    groups: Dict[str, Dict[str, Tuple[int, ...]]] = {}   # layer -> tails
+    for name, shape in shapes.items():
+        head, _, tail = name.partition(".")
+        if not (head[0] == "h" and head[1:].isdigit()):
+            head, tail = "", name
+        groups.setdefault(head, {})[tail] = shape
+    std, down = cfg.init_std, cfg.init_std / math.sqrt(cfg.num_layers)
+    t_min, t_max, t_floor = time_step
+
+    def draw(key, tail, shape):
+        dt = param_dtype(cfg, tail)
+        if tail.endswith("norm.weight") or tail in ("ln_f.weight", "mamba.D"):
+            return jnp.ones(shape, dt)
+        if tail == "mamba.conv.bias":
+            return jnp.zeros(shape, dt)
+        if tail == "moe.router.bias":
+            return router_bias_std * jax.random.normal(key, shape, dt)
+        if tail == "mamba.A_log":
+            return jnp.log(jax.random.uniform(key, shape, dt, 1.0, 16.0))
+        if tail == "mamba.dt_bias":
+            step = jnp.exp(jax.random.uniform(
+                key, shape, dt, math.log(t_min), math.log(t_max)))
+            step = jnp.maximum(step, t_floor)
+            return step + jnp.log(-jnp.expm1(-step))
+        s = down if tail in ("mamba.out_proj.weight", "attn.out.weight",
+                             "moe.latent_up.weight",
+                             "moe.shared.down.weight") else std
+        if tail == "mamba.conv.weight":             # fan-in K
+            s = 1.0 / math.sqrt(shape[0])
+        return s * jax.random.normal(key, shape, dt)
+
+    build = {}                      # one compile per distinct layer shape
+    root = jax.random.key(int(seed) % (2 ** 31), impl="rbg")
+    state = {}
+    for key, (head, tails) in zip(jax.random.split(root, len(groups)),
+                                  sorted(groups.items())):
+        sig = tuple(sorted(tails.items()))
+        if sig not in build:
+            build[sig] = jax.jit(lambda k, sig=sig: {
+                t: draw(kk, t, shape) for kk, (t, shape)
+                in zip(jax.random.split(k, len(sig)), sig)})
+        for tail, v in build[sig](key).items():
+            state[f"{head}.{tail}" if head else tail] = v
+    return state
+
+
+# -- the mixers over plain arrays ---------------------------------------------
+
+def act_fn(cfg: GPTConfig):
+    if cfg.activation == "relu2":
+        return lambda h: jnp.square(jax.nn.relu(h))
+    return {"relu": jax.nn.relu, "gelu": jax.nn.gelu,
+            "silu": jax.nn.silu}[cfg.activation]
+
+
+class MambaWeights:
+    """One mamba2 layer's tensors, looked up once."""
+
+    def __init__(self, params: dict, i: int):
+        g = lambda n: params[f"h{i}.mamba.{n}"]  # noqa: E731
+        self.in_proj, self.out_proj = g("in_proj.weight"), g("out_proj.weight")
+        self.conv_w, self.conv_b = g("conv.weight"), g("conv.bias")
+        self.dt_bias, self.a_log, self.d = g("dt_bias"), g("A_log"), g("D")
+        self.norm = g("norm.weight")
+
+
+def _split_zxd(cfg: GPTConfig, zxd):
+    inner, cd = cfg.mamba_inner, cfg.mamba_conv_dim
+    return zxd[..., :inner], zxd[..., inner:inner + cd], zxd[..., inner + cd:]
+
+
+def _ssm_inputs(cfg: GPTConfig, w: MambaWeights, conv_out, dt_raw):
+    """silu on the conv's output, the split into x / B / C, softplus."""
+    c = cfg
+    n = conv_out.shape[0]
+    gn = c.mamba_n_groups * c.mamba_state_dim
+    xbc = jax.nn.silu(conv_out)
+    x = xbc[:, :c.mamba_inner].reshape(n, c.mamba_num_heads, c.mamba_head_dim)
+    b = xbc[:, c.mamba_inner:c.mamba_inner + gn].reshape(
+        n, c.mamba_n_groups, c.mamba_state_dim)
+    cc = xbc[:, c.mamba_inner + gn:].reshape(
+        n, c.mamba_n_groups, c.mamba_state_dim)
+    dt = jax.nn.softplus(dt_raw.astype(F32) + w.dt_bias.astype(F32))
+    return x, b, cc, dt, -jnp.exp(w.a_log.astype(F32))
+
+
+def mamba_rows(cfg: GPTConfig, w: MambaWeights, zxd, conv_state, ssm_state,
+               live, fresh):
+    """One token for each state slot: ``zxd`` [S, in_proj width] in SLOT
+    order, ``conv_state`` [S, K-1, conv_dim], ``ssm_state`` [S, H, P, N]
+    float32 — the whole store, updated where ``live`` and left as it is
+    elsewhere; a ``fresh`` slot (its sequence's first token) starts from
+    zeros.  Returns ``(y [S, inner] float32 before the gate, new conv
+    state, new ssm state)``."""
+    _, xbc, dt_raw = _split_zxd(cfg, zxd)
+    with phase("ssm_conv"):
+        tail = jnp.where(fresh[:, None, None], 0, conv_state)
+        full = jnp.concatenate([tail, xbc[:, None].astype(tail.dtype)], 1)
+        conv = jnp.einsum("skc,kc->sc", full.astype(F32),
+                          w.conv_w.astype(F32)) + w.conv_b.astype(F32)
+        new_conv = jnp.where(live[:, None, None], full[:, 1:], conv_state)
+    with phase("ssm_scan"):
+        x, b, c, dt, a = _ssm_inputs(cfg, w, conv, dt_raw)
+        s0 = jnp.where(fresh[:, None, None, None], 0.0, ssm_state)
+        y, new = ssd_decode_step(x, dt, a, b, c, w.d, s0)
+        new_ssm = jnp.where(live[:, None, None, None], new, ssm_state)
+    return y.reshape(y.shape[0], cfg.mamba_inner), new_conv, new_ssm
+
+
+def mamba_chunk(cfg: GPTConfig, w: MambaWeights, zxd, tail, state, length,
+                fresh):
+    """A run of ``length`` (<= C) consecutive tokens of ONE sequence:
+    ``zxd`` [C, in_proj width], ``tail`` [K-1, conv_dim] and ``state``
+    [H, P, N] float32 as the sequence left them (ignored when ``fresh``).
+    Returns ``(y [C, inner] float32 before the gate, new tail, new
+    state)``."""
+    _, xbc, dt_raw = _split_zxd(cfg, zxd)
+    n = zxd.shape[0]
+    with phase("ssm_conv"):
+        tail0 = jnp.where(fresh, 0, tail)
+        conv, new_tail = causal_conv(xbc, w.conv_w, w.conv_b, tail0, length)
+    with phase("ssm_scan"):
+        x, b, c, dt, a = _ssm_inputs(cfg, w, conv, dt_raw)
+        q = min(cfg.mamba_chunk_size, n)
+        pad = -n % q
+        if pad:
+            x, b, c, dt = (jnp.pad(v, ((0, pad),) + ((0, 0),) * (v.ndim - 1))
+                           for v in (x, b, c, dt))
+        y, new_state = ssd_chunk_scan(
+            x, dt, a, b, c, w.d, jnp.where(fresh, 0.0, state), q, length)
+    return y[:n].reshape(n, cfg.mamba_inner), new_tail, new_state
+
+
+def mamba_gate_norm(cfg: GPTConfig, w: MambaWeights, y, z, dtype):
+    """``GroupRMSNorm_G(y * silu(z)) * w``: the gate comes before the
+    norm, and the norm runs over each of the G groups of ``inner / G``."""
+    n, g = y.shape[0], cfg.mamba_n_groups
+    v = (y * jax.nn.silu(z.astype(F32))).reshape(n, g, -1)
+    v = v * lax.rsqrt(jnp.mean(v * v, -1, keepdims=True) + norm_eps(cfg))
+    return (v.reshape(n, -1) * w.norm.astype(F32)).astype(dtype)
+
+
+def moe_route(cfg: GPTConfig, w_router, bias, u):
+    """Scores over ALL routed experts in float32 (the matmul too: a bf16
+    pass would reorder near-ties).  ``sigmoid_bias``: top-k of sigmoid
+    score + bias, the chosen scores renormalised and scaled; ``softmax``:
+    top-k of the softmax, its values the weights (the plain block's
+    rule).  Returns ``(idx [T, k] int32, weights [T, k] float32)``."""
+    logits = jnp.dot(u.astype(F32), w_router.astype(F32).T,
+                     precision=lax.Precision.HIGHEST)
+    if cfg.moe_router == "softmax":
+        w, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.moe_top_k)
+        return idx, w * cfg.moe_router_scale
+    s = jax.nn.sigmoid(logits)
+    _, idx = lax.top_k(s + bias.astype(F32), cfg.moe_top_k)
+    chosen = jnp.take_along_axis(s, idx, -1)
+    w = chosen / (chosen.sum(-1, keepdims=True) + 1e-20) * cfg.moe_router_scale
+    return idx, w
+
+
+def held_weights(cfg: GPTConfig, idx, w):
+    """Combine weights over the experts HELD here, ``[T, held]`` float32:
+    an assignment to an expert another chip holds is left out."""
+    local = idx - cfg.expert_offset
+    ok = (local >= 0) & (local < cfg.held_experts)
+    rows = jnp.arange(idx.shape[0])[:, None]
+    return jnp.zeros((idx.shape[0], cfg.held_experts), F32).at[
+        rows, jnp.clip(local, 0, cfg.held_experts - 1)].add(
+            jnp.where(ok, w, 0.0))
+
+
+def latent_moe(cfg: GPTConfig, params: dict, i: int, u, live):
+    """The expert layer on ``u`` [n, H] (normed).  Routed part: every
+    held expert on every token, mixed by the combine weights (zero where
+    not chosen) — the same arithmetic whatever the ids choose, so its
+    device time does not follow the routing.  ``live`` [n] marks real
+    tokens for the load count.  Returns ``(out [n, H], tokens per held
+    expert [held] int32)``."""
+    g = lambda n: params.get(f"h{i}.moe.{n}")  # noqa: E731
+    act = act_fn(cfg)
+    with phase("moe_router"):
+        idx, w = moe_route(cfg, g("router.weight"), g("router.bias"), u)
+        wd = held_weights(cfg, idx, w)                       # [n, held]
+        load = jnp.sum((wd > 0) & live[:, None], axis=0, dtype=jnp.int32)
+    down, up = g("latent_down.weight"), g("latent_up.weight")
+    with phase("moe_latent"):
+        lat = u if down is None else u @ down.T
+    with phase("moe_routed"):
+        hid = act(jnp.einsum("tl,elf->tef", lat, g("experts.w1")))
+        hid = hid * wd[..., None].astype(hid.dtype)
+        r = jnp.einsum("tef,efl->tl", hid, g("experts.w2"),
+                       preferred_element_type=F32).astype(u.dtype)
+    with phase("moe_latent"):
+        out = r if up is None else r @ up.T
+    s_up = g("shared.up.weight")
+    if s_up is not None:
+        with phase("moe_shared"):
+            out = out + act(u @ s_up.T) @ g("shared.down.weight").T
+    return out, load

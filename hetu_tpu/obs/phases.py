@@ -29,9 +29,15 @@ from typing import Dict, List, Optional
 __all__ = ["PHASES", "UNMAPPED", "phase", "current_phase",
            "hlo_phase_map", "device_phases"]
 
-# training: embed .. param_gather; serving adds kv_scatter and sample
+# training: embed .. param_gather; serving adds kv_scatter and sample; a
+# hybrid stack's mixers (models/hybrid.py) add the state-space phases
+# (in / out projections, conv, scan + gate + group norm, moves of state
+# between the slot store and a row) and the expert layer's (router, latent
+# down / up, routed experts, shared expert)
 PHASES = ("embed", "norm", "attn_proj", "attn_core", "mlp", "lm_head_ce",
-          "optimizer", "grad_comm", "param_gather", "kv_scatter", "sample")
+          "optimizer", "grad_comm", "param_gather", "kv_scatter", "sample",
+          "ssm_proj", "ssm_conv", "ssm_scan", "state_io",
+          "moe_router", "moe_latent", "moe_routed", "moe_shared")
 UNMAPPED = "unmapped"
 
 # scope names that predate the vocabulary (parallel/comm.py's comm_tag

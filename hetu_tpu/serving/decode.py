@@ -376,6 +376,17 @@ def build_unified_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
     if spec_k < 0:
         raise ValueError(f"spec_k must be >= 0, got {spec_k}")
     c = cfg
+    if c.is_hybrid:
+        # one mixer per layer, recurrent state beside the pages: its own
+        # layer loop and signature (below), the same layout and regions
+        if spec_k or page_quant is not None or c.is_mla:
+            raise ValueError(
+                "a hybrid stack (layer_pattern) is built without "
+                "speculative verify rows, page quantization and latent "
+                "K/V: a rejected draft would have to roll recurrent "
+                "state back")
+        return _build_hybrid_step_fn(c, max_seqs, chunk, prefill_rows,
+                                     max_pages, page_size, use_kernel)
     if page_quant is not None and (not c.is_mla or c.rope_dim):
         raise ValueError("page_quant requires the latent (MLA) layout "
                          "with rope_dim == 0")
@@ -671,3 +682,195 @@ def build_unified_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
                             spec_lens, k_pages, v_pages)
 
     return run
+
+
+def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
+                          prefill_rows: int, max_pages: int, page_size: int,
+                          use_kernel: bool):
+    """The unified step of a hybrid stack (``cfg.layer_pattern``): the
+    same token axis, regions and K/V write, one mixer per layer, and the
+    recurrent state carried beside the pages.
+
+    fn(params, tokens, token_pos, token_page, token_off, q_lens, cu_q,
+       page_tables, ctx_lens, temps, top_ps, top_ks, seeds,
+       state_slots [rows] i32,          # the row's slot in the store
+       k_pages, v_pages,                # attention layers only
+       conv_states, ssm_states)         # mamba2 layers: [slots, ...]
+      -> (next_tokens [rows] i32, moe_load [moe layers, held] i32,
+          new k_pages, v_pages, conv_states, ssm_states)
+
+    The store has ``max_seqs`` slots, one per running sequence.  Decode
+    rows run SLOT-major: the rows' projections are permuted into slot
+    order, the whole store is updated in place (a slot with no live
+    decode row this step keeps its content) and the outputs are permuted
+    back — the state is read and written once, never gathered.  A chunk
+    slot takes its row's state out of the store, carries it through the
+    chunk (conv tail, then the chunked scan from the state it found) and
+    puts it back; an idle chunk slot puts back what it took.  A row
+    whose first token sits at position 0 starts from zeros whatever the
+    slot holds.  ``moe_load`` counts, per expert layer, the live tokens
+    each held expert was chosen by."""
+    from ..models import hybrid as hy
+    c = cfg
+    t_tokens = max_seqs + prefill_rows * chunk
+    n_rows = max_seqs + prefill_rows
+    cdt = jnp.bfloat16 if c.dtype == "bfloat16" else jnp.float32
+    hd, nh, nkv = c.head_dim, c.num_heads, c.kv_heads
+    slots = _chunk_slots(max_seqs, prefill_rows, chunk, 0)
+    write_regions = tuple((row, n, width) for _, row, _, n, width
+                          in _regions(max_seqs, prefill_rows, chunk, 0))
+    attn_of = {i: a for a, i in enumerate(c.layers_of("attention"))}
+    mamba_of = {i: m for m, i in enumerate(c.layers_of("mamba2"))}
+
+    def by_region(f, h, q_lens, live):
+        """``f(tokens [n, ...], live [n]) -> (per-token [n, ...], sums)``
+        over the decode slots and, under ``lax.cond``, each chunk slot;
+        an idle chunk slot gives zeros and pays nothing."""
+        outs = [f(h[:max_seqs], live[:max_seqs])]
+        for row, start, width in slots:
+            sl, lv = h[start: start + width], live[start: start + width]
+            zero = jax.eval_shape(f, sl, lv)
+            outs.append(lax.cond(
+                q_lens[row] > 0, f,
+                lambda s, l, z=zero: jax.tree_util.tree_map(
+                    lambda a: jnp.zeros(a.shape, a.dtype), z), sl, lv))
+        ys, sums = zip(*outs)
+        return jnp.concatenate(ys, axis=0), \
+            jax.tree_util.tree_map(lambda *a: sum(a), *sums)
+
+    def tokenwise(f):
+        return lambda hh, _: (f(hh), ())
+
+    def run(params, tokens, token_pos, token_page, token_off, q_lens, cu_q,
+            page_tables, ctx_lens, temps, top_ps, top_ks, seeds,
+            state_slots, k_pages, v_pages, conv_states, ssm_states):
+        p = _params_view(c, params)
+        with phase("embed"):
+            x = p("wte.weight")[tokens].astype(cdt)
+        # a token is live when its row holds it
+        tok_row = jnp.concatenate(
+            [jnp.arange(max_seqs)] +
+            [jnp.full((w,), r) for r, _, w in slots])
+        tok_idx = jnp.arange(t_tokens) - cu_q[tok_row]
+        live = tok_idx < q_lens[tok_row]
+        fresh_row = (ctx_lens - q_lens) == 0
+        with phase("state_io"):
+            # slot -> the decode row that feeds it this step (-1: none)
+            dec = jnp.where(q_lens[:max_seqs] > 0, state_slots[:max_seqs],
+                            max_seqs)
+            slot_row = jnp.full((max_seqs,), -1, jnp.int32).at[dec].set(
+                jnp.arange(max_seqs, dtype=jnp.int32), mode="drop")
+            slot_live = slot_row >= 0
+            slot_src = jnp.maximum(slot_row, 0)
+            slot_fresh = slot_live & fresh_row[slot_src]
+        if use_kernel and attn_of:
+            tile = write_tile((k_pages[0], v_pages[0]))
+            with phase("kv_scatter"):
+                plan = kv_write_plan(token_page, token_off, q_lens, cu_q,
+                                     regions=write_regions,
+                                     page_size=page_size, tile=tile)
+        new_k, new_v = list(k_pages), list(v_pages)
+        new_conv, new_ssm = list(conv_states), list(ssm_states)
+        loads = []
+        for i, mixer in enumerate(c.layer_pattern):
+            with phase("norm"):
+                h = _norm_apply(c, p.layer(i, "norm.weight"), None, x)
+            if mixer == "attention":
+                a = attn_of[i]
+                with phase("attn_proj"):
+                    qkv, _ = by_region(tokenwise(
+                        lambda hh, i=i: hh @ p.layer(i, "attn.qkv.weight").T),
+                        h, q_lens, live)
+                q_size, kv_size = nh * hd, nkv * hd
+                with phase("attn_core"):
+                    q = qkv[..., :q_size].reshape(t_tokens, nh, hd)
+                    k = qkv[..., q_size:q_size + kv_size].reshape(
+                        t_tokens, nkv, hd)
+                    v = qkv[..., q_size + kv_size:].reshape(t_tokens, nkv, hd)
+                with phase("kv_scatter"):
+                    if use_kernel:
+                        kp, vp = paged_kv_write(
+                            (k_pages[a], v_pages[a]),
+                            (k.astype(cdt), v.astype(cdt)), plan, tile=tile)
+                    else:
+                        kp, vp = paged_kv_write_reference(
+                            (k_pages[a], v_pages[a]),
+                            (k.astype(cdt), v.astype(cdt)), token_page,
+                            token_off)
+                with phase("attn_core"):
+                    if use_kernel:
+                        attn = _attend_by_region(
+                            functools.partial(ragged_paged_attention_pallas,
+                                              k_pages=kp, v_pages=vp),
+                            "ragged_paged_attention", q, q_lens, cu_q,
+                            page_tables, ctx_lens, max_seqs, prefill_rows,
+                            chunk, 0)
+                    else:
+                        attn = _split_ragged_attention(
+                            c, q, kp, vp, q_lens, page_tables, ctx_lens,
+                            max_seqs, prefill_rows, chunk)
+                    attn = attn.reshape(t_tokens, nh * hd).astype(x.dtype)
+                with phase("attn_proj"):
+                    out, _ = by_region(tokenwise(
+                        lambda aa, i=i: aa @ p.layer(i, "attn.out.weight").T),
+                        attn, q_lens, live)
+                new_k[a], new_v[a] = kp, vp
+            elif mixer == "mamba2":
+                m = mamba_of[i]
+                w = hy.MambaWeights(params, i)
+                with phase("ssm_proj"):
+                    zxd, _ = by_region(tokenwise(
+                        lambda hh, w=w: hh @ w.in_proj.T), h, q_lens, live)
+                with phase("state_io"):
+                    zxd_slots = zxd[:max_seqs][slot_src]
+                y_slots, conv_s, ssm_s = hy.mamba_rows(
+                    c, w, zxd_slots, new_conv[m], new_ssm[m], slot_live,
+                    slot_fresh)
+                with phase("state_io"):
+                    ys = [y_slots[state_slots[:max_seqs]]]
+                for row, start, width in slots:
+                    slot, qlen = state_slots[row], q_lens[row]
+                    with phase("state_io"):
+                        tail0, s0 = conv_s[slot], ssm_s[slot]
+                    y_c, tail1, s1 = lax.cond(
+                        qlen > 0,
+                        lambda z, t, s, n, f, w=w: hy.mamba_chunk(
+                            c, w, z, t, s, n, f),
+                        lambda z, t, s, n, f: (
+                            jnp.zeros((width, c.mamba_inner), jnp.float32),
+                            t, s),
+                        zxd[start: start + width], tail0, s0, qlen,
+                        fresh_row[row])
+                    with phase("state_io"):
+                        conv_s = conv_s.at[slot].set(tail1)
+                        ssm_s = ssm_s.at[slot].set(s1)
+                    ys.append(y_c)
+                with phase("ssm_scan"):
+                    y = hy.mamba_gate_norm(
+                        c, w, jnp.concatenate(ys, axis=0),
+                        zxd[..., :c.mamba_inner], x.dtype)
+                with phase("ssm_proj"):
+                    out, _ = by_region(tokenwise(
+                        lambda yy, w=w: yy @ w.out_proj.T), y, q_lens, live)
+                new_conv[m], new_ssm[m] = conv_s, ssm_s
+            else:
+                out, load = by_region(
+                    lambda hh, lv, i=i: hy.latent_moe(c, params, i, hh, lv),
+                    h, q_lens, live)
+                loads.append(load)
+            x = x + out.astype(x.dtype)
+        with phase("norm"):
+            x = _norm_apply(c, p("ln_f.weight"), None, x)
+        last = jnp.clip(cu_q[:n_rows] + jnp.maximum(q_lens, 1) - 1, 0,
+                        t_tokens - 1)
+        with phase("lm_head_ce"):
+            logits = _lm_head(p, x[last])
+        with phase("sample"):
+            next_tokens = sample_rows(logits, temps, top_ps, top_ks, seeds,
+                                      ctx_lens)
+        moe_load = jnp.stack(loads) if loads else \
+            jnp.zeros((0, max(c.held_experts, 1)), jnp.int32)
+        return (next_tokens, moe_load, tuple(new_k), tuple(new_v),
+                tuple(new_conv), tuple(new_ssm))
+
+    return jax.jit(run, donate_argnums=(12, 14, 15, 16, 17))

@@ -92,7 +92,8 @@ from ..obs.tracer import get_tracer
 from ..ops.pallas import on_tpu
 from ..utils.metrics import make_instrument, render_prometheus
 from .decode import build_unified_step_fn
-from .kv_pool import TRASH_PAGE, PagedKVPool, protocol_seq
+from .kv_pool import (TRASH_PAGE, PagedKVPool, StateSlotStore,
+                      protocol_seq)
 from .prefix_cache import PrefixCache
 from .request import FINISHED, RUNNING, Request, RequestQueue
 from .scheduler import Scheduler
@@ -164,13 +165,39 @@ class Engine:
             raise ValueError("page_quant requires an MLA config "
                              "(kv_latent_dim set)")
         self.page_quant = page_quant
-        self.pool = PagedKVPool(cfg.num_layers, num_pages, page_size,
+        # a hybrid stack (cfg.layer_pattern) keeps K/V for its attention
+        # layers only and a recurrent-state slot per running sequence for
+        # its mamba2 layers.  What is not built for recurrent state is
+        # refused here, not run wrong: a cached prefix would need the
+        # state AT the cached boundary, a rejected draft a roll-back
+        self.hybrid = cfg.is_hybrid
+        if self.hybrid and cfg.layers_of("mamba2"):
+            if prefix_cache:
+                raise ValueError(
+                    "prefix_cache=True is not built for a stack with "
+                    "recurrent (mamba2) layers: a cached page prefix "
+                    "carries no state snapshot — pass prefix_cache=False")
+            if spec is not None:
+                raise ValueError(
+                    "speculative decoding is not built for a stack with "
+                    "recurrent (mamba2) layers: a rejected draft cannot "
+                    "be rolled back out of the state")
+        self.pool = PagedKVPool(len(cfg.layers_of("attention")),
+                                num_pages, page_size,
                                 cfg.kv_heads, cfg.head_dim, dtype,
                                 mesh=mesh, debug=debug,
                                 latent_dim=cfg.kv_latent_dim,
                                 rope_dim=cfg.rope_dim if cfg.is_mla
                                 else 0,
                                 quant=page_quant)
+        self.state_store: Optional[StateSlotStore] = None
+        if cfg.layers_of("mamba2"):
+            self.state_store = StateSlotStore(
+                len(cfg.layers_of("mamba2")), int(max_batch),
+                cfg.mamba_conv_kernel, cfg.mamba_conv_dim,
+                cfg.mamba_num_heads, cfg.mamba_head_dim,
+                cfg.mamba_state_dim, conv_dtype=dtype)
+            self.pool.state_slots = self.state_store
         # copy-on-write prefix reuse: finished requests' full pages are
         # indexed by chained token hash; _start attaches the longest
         # cached prefix so prefill skips straight to the cached boundary
@@ -231,7 +258,13 @@ class Engine:
                           "admitted_batch", "preempted_interactive",
                           "preempted_standard", "preempted_batch",
                           "host_evictions", "host_hits",
-                          "host_refetch_bytes")}
+                          "host_refetch_bytes",
+                          # hybrid stacks (zero elsewhere): state slots
+                          # handed out; live (token, expert) assignments
+                          # that fell on the experts held here / made by
+                          # the router over all experts
+                          "state_slot_allocs", "moe_assignments_local",
+                          "moe_assignments_total")}
         self.gauges = {k: make_instrument("gauge", k, m) for k in
                        ("batch_occupancy", "page_utilization",
                         "queue_depth",
@@ -243,7 +276,10 @@ class Engine:
                         # transport / metrics planes can never disagree
                         "kv_bytes_per_token", "kv_bytes_in_use",
                         # live host-tier page count (0 without one)
-                        "host_pages")}
+                        "host_pages",
+                        # hybrid stacks: slots held; the last step's
+                        # busiest held expert over the mean load
+                        "state_slots_in_use", "moe_expert_load_peak")}
         self.gauges["kv_bytes_per_token"].set(
             self.pool.kv_bytes_per_token)
         lb = list(latency_buckets if latency_buckets is not None
@@ -417,6 +453,10 @@ class Engine:
                 f"has {self.pool.num_usable} — it could never run")
         pages = list(pages or ())
         pos = int(pos)
+        if pos and self.state_store is not None:
+            raise ValueError(
+                "a mid-flight hand-off (pos > 0) carries K/V pages but no "
+                "recurrent state: adopt with pos=0 and let it re-prefill")
         if pos > len(prompt) + len(generated):
             raise ValueError(f"pos {pos} past the accumulated tokens")
         if pos and len(pages) < self.pool.pages_for(pos):
@@ -662,6 +702,14 @@ class Engine:
                 req.cached_tokens = req.pos
         need = self.pool.pages_for(len(req.tokens)) - len(req.pages)
         pages = self.pool.alloc(need)
+        if pages is not None and self.state_store is not None:
+            # the recurrent-state slot comes with the pages or not at all
+            req.state_slot = self.state_store.alloc(req.req_id)
+            if req.state_slot is None:
+                self.pool.free(pages)
+                pages = None
+            else:
+                self.counters["state_slot_allocs"].inc()
         tr = self.tracer
         if pages is None:
             if tr.enabled:
@@ -737,6 +785,7 @@ class Engine:
                 self.prefix_cache.release(req)
             if self.spec is not None:
                 self.spec.release(req)
+            self._free_state_slot(req)
             req.pages = []
             req.shared_pages = 0
             req.cached_tokens = 0
@@ -750,6 +799,11 @@ class Engine:
             if self.prefix_cache is not None:
                 self.prefix_cache.check_invariants()
         return [r.req_id for r in victims]
+
+    def _free_state_slot(self, req: Request) -> None:
+        if req.state_slot is not None:
+            self.state_store.free(req.state_slot)
+            req.state_slot = None
 
     def _stage_spec(self, live: List[Request]) -> None:
         """Draft-propose for every decode-ready request that can still
@@ -809,7 +863,10 @@ class Engine:
         top_ks = np.zeros(nr, np.int32)
         seeds = np.zeros(nr, np.int32)
         spec_lens = np.zeros(nr, np.int32)
+        state_slots = np.zeros(nr, np.int32)
         for req, qlen, row in rows:
+            if req.state_slot is not None:
+                state_slots[row] = req.state_slot
             start = int(self._cu_q[row])
             pos = np.arange(req.pos, req.pos + qlen)
             seq = req.tokens if not (row >= vbase and req.spec_drafts) \
@@ -830,7 +887,7 @@ class Engine:
                 spec_lens[row] = len(req.spec_drafts)
         return (tokens, token_pos, token_page, token_off, q_lens,
                 page_tables, ctx_lens, temps, top_ps, top_ks, seeds,
-                spec_lens)
+                spec_lens, state_slots)
 
     def _run_unified(self, rows: List[Tuple[Request, int, int]]) -> int:
         s = self.scheduler.max_batch
@@ -844,7 +901,7 @@ class Engine:
                 req.spec_drafts = []
         (tokens, token_pos, token_page, token_off, q_lens, page_tables,
          ctx_lens, temps, top_ps, top_ks, seeds,
-         spec_lens) = self._pack_arrays(rows)
+         spec_lens, state_slots) = self._pack_arrays(rows)
         kv_tokens = sum(q for _, q, _ in rows)   # every fed token's KV
         tr = self.tracer
         traced = tr.enabled
@@ -878,26 +935,37 @@ class Engine:
                 jnp.asarray(top_ks), jnp.asarray(seeds))
         if self.spec is not None:
             args += (jnp.asarray(spec_lens),)
+        states = ()
+        if self.hybrid:
+            args += (jnp.asarray(state_slots),)
+            st = self.state_store
+            states = (st.conv, st.ssm) if st is not None else ((), ())
         if traced:
             self._enter_phase(tr, "step.dispatch")
         # the call returns once the executable is enqueued; the host
         # waits for the device in the fetch below
         out = self._compiled["unified"](*args, self.pool.k_pages,
-                                        self.pool.v_pages)
+                                        self.pool.v_pages, *states)
         if traced:
             self._enter_phase(tr, "step.fetch")
-        if self.spec is not None:
+        accs = moe_load = None
+        if self.hybrid:
+            next_tokens, moe_load, new_k, new_v, new_conv, new_ssm = out
+        elif self.spec is not None:
             next_tokens, accepted, new_k, new_v = out
             accs = np.asarray(accepted)         # [rows] int32
         else:
             next_tokens, new_k, new_v = out
-            accs = None
         toks = np.asarray(next_tokens)          # [rows] int32, ever
+        if moe_load is not None:                # [moe layers, held] int32
+            moe_load = np.asarray(moe_load)
         t1 = self._now()
         dt = t1 - t0
         if traced:
             self._enter_phase(tr, "step.commit", t1)
         self.pool.set_pages(new_k, new_v)
+        hybrid_attrs = self._commit_hybrid(new_conv, new_ssm, moe_load,
+                                           kv_tokens) if self.hybrid else {}
         self._calls += 1
         self.counters["step_calls"].inc()
         self.counters["kv_tokens_written"].inc(kv_tokens)
@@ -914,7 +982,7 @@ class Engine:
             tr.complete("unified_step", t0, dt, track="engine",
                         exec=f"{self.name}/unified", rows=len(rows),
                         tokens=kv_tokens, kv_tokens=kv_tokens,
-                        kv_runs=kv_runs)
+                        kv_runs=kv_runs, **hybrid_attrs)
         # classify by SLOT, not q_len: a chunk_size=1 prefill chunk is
         # still a prefill chunk, and a verify row is neither
         n_decode = sum(1 for _, _, row in rows if row < s)
@@ -945,6 +1013,32 @@ class Engine:
                 self._observe_token(req, row < s, dt)
                 self._maybe_finish(req)
         return produced
+
+    def _commit_hybrid(self, new_conv, new_ssm, moe_load,
+                       live_tokens: int) -> Dict[str, Any]:
+        """Install the step's recurrent state and account the expert
+        layers' load; returns the ``unified_step`` span's extra
+        attributes (``state_slots``: slots held; ``moe_local``: live
+        assignments on the held experts; ``moe_load_peak``: the busiest
+        held expert over the mean; ``moe_experts_hit``: held experts,
+        summed over the expert layers, that got >= 1 token)."""
+        st = self.state_store
+        attrs: Dict[str, Any] = {}
+        if st is not None:
+            st.set_arrays(new_conv, new_ssm)
+            self.gauges["state_slots_in_use"].set(st.in_use)
+            attrs["state_slots"] = st.in_use
+        if moe_load is not None and moe_load.size:
+            local = int(moe_load.sum())
+            self.counters["moe_assignments_local"].inc(local)
+            self.counters["moe_assignments_total"].inc(
+                live_tokens * self.cfg.moe_top_k * moe_load.shape[0])
+            mean = moe_load.mean()
+            peak = float(moe_load.max() / mean) if mean else 0.0
+            self.gauges["moe_expert_load_peak"].set(peak)
+            attrs.update(moe_local=local, moe_load_peak=peak,
+                         moe_experts_hit=int((moe_load > 0).sum()))
+        return attrs
 
     def _observe_token(self, req: Request, decode_slot: bool,
                        dt: float) -> None:
@@ -1042,6 +1136,7 @@ class Engine:
             self.prefix_cache.on_finish(req)
         else:
             self.pool.free(req.pages)
+        self._free_state_slot(req)
         req.pages = []
         req.state = FINISHED
         req.finish_time = self._now()
@@ -1089,8 +1184,14 @@ class Engine:
         args = (params, i32(t), i32(t), i32(t), i32(t), i32(nr),
                 i32(nr + 1), i32(nr, maxp), i32(nr), f32(nr), f32(nr),
                 i32(nr), i32(nr)) \
-            + ((i32(nr),) if self.spec is not None else ()) \
+            + ((i32(nr),) if self.spec is not None or self.hybrid
+               else ()) \
             + (k_pages, v_pages)
+        if self.hybrid:
+            st = self.state_store
+            args += (tuple(sds(a) for a in st.conv),
+                     tuple(sds(a) for a in st.ssm)) if st is not None \
+                else ((), ())
         meta = {
             "kind": "serving_unified",
             "mesh_axes": {},

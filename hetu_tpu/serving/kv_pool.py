@@ -75,6 +75,90 @@ def page_shape_bytes(shape: Sequence[int], dtype) -> int:
     return n * jnp.dtype(dtype).itemsize
 
 
+class StateSlotStore:
+    """Fixed-size recurrent state beside the K/V pages: one SLOT per
+    running sequence for every state-space layer.
+
+    A page holds a few tokens' K/V and a sequence grows into more of
+    them; a slot holds a sequence's WHOLE recurrent state (the conv's
+    last ``K - 1`` inputs and the ``[heads, head_dim, state]`` float32
+    scan state of each mamba2 layer) and never grows.  The engine owns
+    the store beside the pool and moves a request's slot with its pages:
+    allocated at admission, freed at finish, at preemption (recompute:
+    the state is dropped and the sequence re-prefilled) and at abort.
+    There is no zeroing pass: the unified step starts a row whose first
+    token sits at position 0 from zeros whatever its slot holds, so a
+    slot's old content cannot reach the sequence that takes it next.
+
+    ``conv`` / ``ssm`` are tuples of per-layer arrays ``[slots, K - 1,
+    conv_dim]`` / ``[slots, heads, head_dim, state]``; the jitted step
+    takes them donated and returns them (``set_arrays``)."""
+
+    def __init__(self, num_layers: int, num_slots: int, conv_kernel: int,
+                 conv_dim: int, heads: int, head_dim: int, state_dim: int,
+                 conv_dtype=jnp.float32):
+        if num_slots < 1:
+            raise ValueError(f"num_slots must be >= 1, got {num_slots}")
+        self.num_layers, self.num_slots = int(num_layers), int(num_slots)
+        self.conv: Tuple[jax.Array, ...] = tuple(
+            jnp.zeros((num_slots, conv_kernel - 1, conv_dim), conv_dtype)
+            for _ in range(num_layers))
+        self.ssm: Tuple[jax.Array, ...] = tuple(
+            jnp.zeros((num_slots, heads, head_dim, state_dim), jnp.float32)
+            for _ in range(num_layers))
+        self._free: List[int] = list(range(num_slots - 1, -1, -1))
+        self._owner: Dict[int, int] = {}          # slot -> req_id
+        self.allocs = 0
+        # ``(seq, op, [slot])`` on the pool's own sequence counter
+        self.event_log: List[Tuple[int, str, List[int]]] = []
+
+    @property
+    def in_use(self) -> int:
+        return len(self._owner)
+
+    @property
+    def slot_bytes(self) -> int:
+        """Bytes ONE slot holds across all layers."""
+        return sum(page_shape_bytes(a.shape, a.dtype)
+                   for a in self.conv + self.ssm)
+
+    def alloc(self, req_id: int) -> Optional[int]:
+        """A free slot for ``req_id``, or None when every slot is held."""
+        if not self._free:
+            return None
+        slot = self._free.pop()
+        self._owner[slot] = int(req_id)
+        self.allocs += 1
+        self.event_log.append((protocol_seq(), "slot_alloc", [slot]))
+        return slot
+
+    def free(self, slot: int) -> None:
+        if slot not in self._owner:
+            raise ValueError(f"double free / foreign state slot {slot}")
+        del self._owner[slot]
+        self._free.append(slot)
+        self.event_log.append((protocol_seq(), "slot_free", [slot]))
+
+    def owner(self, slot: int) -> Optional[int]:
+        return self._owner.get(slot)
+
+    def set_arrays(self, conv, ssm) -> None:
+        self.conv, self.ssm = tuple(conv), tuple(ssm)
+
+    def problems(self) -> List[str]:
+        """Free and held slots partition ``range(num_slots)``."""
+        out = []
+        free, held = set(self._free), set(self._owner)
+        if len(free) != len(self._free):
+            out.append("a state slot is on the free list twice")
+        if free & held:
+            out.append(f"state slots both free and held: {sorted(free & held)}")
+        if free | held != set(range(self.num_slots)):
+            out.append("state slots leaked or invented: "
+                       f"{sorted(set(range(self.num_slots)) ^ (free | held))}")
+        return out
+
+
 class PagedKVPool:
     """Free-list page allocator over per-layer k/v page arrays.
 
@@ -184,6 +268,9 @@ class PagedKVPool:
         # itself, and a conditional log would make the protocol lint
         # silently vacuous on production-configured pools.
         self.event_log: List[Tuple[int, str, List[int]]] = []
+        # the recurrent-state slots of a hybrid stack, attached by the
+        # engine that owns both (None: every layer keeps K/V)
+        self.state_slots: Optional[StateSlotStore] = None
 
     # -- allocator -----------------------------------------------------------
 
@@ -340,6 +427,8 @@ class PagedKVPool:
         from ..analysis.protocol import page_partition_problems
         problems = page_partition_problems(
             self.num_pages, self._free, self._allocated, self._cached)
+        if self.state_slots is not None:
+            problems = list(problems) + self.state_slots.problems()
         assert not problems, "; ".join(problems)
 
     # -- accounting ----------------------------------------------------------

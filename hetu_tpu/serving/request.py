@@ -53,6 +53,9 @@ class Request:
     # commits (or when the drafts are dropped: preemption, a step with
     # no free chunk slot, a page squeeze).
     spec_drafts: List[int] = field(default_factory=list)
+    # the recurrent-state slot of a hybrid stack (kv_pool.StateSlotStore),
+    # held from admission to finish / preemption
+    state_slot: Optional[int] = None
     pos: int = 0                 # KV entries committed (next write index)
     state: str = WAITING
     # start of the CURRENT lifecycle segment (queued/running) for the

@@ -271,6 +271,11 @@ class Scheduler:
         self.pool.free(req.pages[req.shared_pages:])
         if self.cache is not None and req.shared_pages:
             self.cache.release(req)
+        if req.state_slot is not None:
+            # the recurrent state goes with the K/V: the resumed request
+            # re-prefills into whatever slot it is given then
+            self.pool.state_slots.free(req.state_slot)
+            req.state_slot = None
         req.pages = []
         req.shared_pages = 0
         req.cached_tokens = 0

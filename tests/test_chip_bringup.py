@@ -59,14 +59,15 @@ def _kernel_cases():
         return rpa.ragged_paged_attention_pallas(
             q, kp, vp, *d, max_q=max_q, interpret=False)
 
-    def region(rows, max_q):
+    def region(rows, max_q, heads=12, kv_heads=12):
         # one region of the serving step at the benchmark's widths
         # (Cerebras-GPT-590M: 12 kv heads x 128, 64-token pages, 32
-        # pages a row)
+        # pages a row; the hybrid configuration: 32 query heads on 2 kv
+        # heads, 64 decode slots)
         t, maxp = rows * max_q, 32
         return (functools.partial(ragged, max_q=max_q), (
-            _sds((t, 12, 128), BF16),
-            *(_sds((PAGES, 12, PAGE, 128), BF16),) * 2,
+            _sds((t, heads, 128), BF16),
+            *(_sds((PAGES, kv_heads, PAGE, 128), BF16),) * 2,
             _sds((rows,), I32), _sds((rows + 1,), I32),
             _sds((rows, maxp), I32), _sds((rows,), I32)))
 
@@ -117,6 +118,8 @@ def _kernel_cases():
                                      *_desc())),
         "ragged_decode_region": region(32, 1),
         "ragged_chunk_region": region(1, CHUNK),
+        "ragged_decode_gqa16": region(64, 1, heads=32, kv_heads=2),
+        "ragged_chunk_gqa16": region(1, CHUNK, heads=32, kv_heads=2),
         "latent_512_64": (latent(None), (
             _sds((T, 16, 576), F32), _sds((PAGES, 1, PAGE, 512), BF16),
             _sds((PAGES, 1, PAGE, 64), BF16), *_desc())),
@@ -141,7 +144,8 @@ def _kernel_cases():
 # tile is the layout most likely to be refused; of the KV write, the
 # benchmark's pool and the one written in whole one-lane pages; of flash,
 # the train cell's call: three lane blocks of the fused [b, s, 3*h*d]
-AOT_CASES = ("ragged_12kv_x64", "ragged_decode_region", "latent_512_64",
+AOT_CASES = ("ragged_12kv_x64", "ragged_decode_region",
+             "ragged_decode_gqa16", "latent_512_64",
              "latent_nf4", "kv_write_12kv_x128", "kv_write_int8_sidecar",
              "flash_qkv")
 

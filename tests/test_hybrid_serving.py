@@ -1,0 +1,421 @@
+"""Hybrid stacks (Mamba-2 / attention / latent MoE, one mixer a layer)
+through the serving engine, against the plain float32 reference
+(``models/hybrid_reference.py``), at tiny widths on the CPU with seeded
+random weights.
+
+Tolerances, each with its reason:
+
+* ``GAP_F32`` 1e-4 — float32 system against the float32 reference, in
+  logit units of the reference (a served greedy token's logit below the
+  reference's best, teacher-forced).  The two differ by reassociation
+  only (chunked matmul scan against a sequential one, the dense expert
+  mix against a per-expert loop, paged against whole-sequence attention):
+  ~1e-6 at these widths; a wrong mask, group, state or share reads 0.1-1.
+* ``TENSOR_F32`` 2e-5 (relative to the tensor's largest entry) — the same
+  pair compared tensor against tensor (scan outputs, final states, the
+  expert layer).
+* ``GAP_BF16`` 0.006 — the bf16 system against the float32 reference.
+  bf16 keeps 8 mantissa bits; at these widths (hidden 64, 5 layers) the
+  served tokens read 0 to 0.0016 below the reference's best over 12
+  requests, so 0.006 leaves ~4x.  The float8 reading of the same
+  comparison (3 mantissa bits, the nearest precision below: the tokens
+  the reference itself picks with every weight matrix and every mixer's
+  input and output rounded to e4m3, scaled per tensor) reads 0-0.045 a
+  sequence, 0.045 over the 8 sequences of
+  ``test_lower_precision_fails_the_tolerance``, which has to fail it.
+"""
+from __future__ import annotations
+
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from hetu_tpu.models import hybrid as hy
+from hetu_tpu.models import hybrid_reference as ref
+from hetu_tpu.ops import ssd
+from hetu_tpu.serving import Engine
+from hetu_tpu.serving.kv_pool import StateSlotStore
+from hetu_tpu.serving.spec import SpecConfig
+
+GAP_F32 = 1e-4
+TENSOR_F32 = 2e-5
+GAP_BF16 = 0.006
+VOCAB = 128
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def published(pattern: str, **kw) -> dict:
+    """A tiny ``nemotron_h`` config under the published keys: 16 routed
+    experts of which 4 are held from offset 4, top-6, latent 32."""
+    d = dict(hybrid_override_pattern=pattern, num_hidden_layers=len(pattern),
+             hidden_size=64, num_attention_heads=4, head_dim=16,
+             num_key_value_heads=2, vocab_size=VOCAB,
+             max_position_embeddings=256, mlp_hidden_act="relu2",
+             layer_norm_epsilon=1e-5, tie_word_embeddings=False,
+             mamba_num_heads=8, mamba_head_dim=8, n_groups=2,
+             ssm_state_size=16, conv_kernel=4, chunk_size=8,
+             n_routed_experts=4, moe_router_outputs=16, expert_offset=4,
+             num_experts_per_tok=6, routed_scaling_factor=5,
+             moe_intermediate_size=24, moe_latent_size=32,
+             moe_shared_expert_intermediate_size=48, n_shared_experts=1,
+             dtype="float32")
+    d.update(kw)
+    return d
+
+
+def build(pattern: str, seed: int = 3, **kw):
+    pub = published(pattern, **kw)
+    cfg = hy.hybrid_config(pub)
+    # a router bias that matters: selection and weights come apart
+    return pub, cfg, hy.init_state(cfg, seed, router_bias_std=0.05)
+
+
+def engine(state, cfg, **kw):
+    kw = {"num_pages": 64, "page_size": 8, "max_batch": 4, "chunk_size": 8,
+          "prefix_cache": False, "debug": True, "use_kernel": False, **kw}
+    return Engine(state, cfg, **kw)
+
+
+def prompts(lens, seed=0):
+    rng = np.random.RandomState(seed)
+    return [rng.randint(0, VOCAB, n).tolist() for n in lens]
+
+
+def worst_gap(pub, state, prompt, out) -> float:
+    spec = ref.spec_from_config(pub)
+    return max(ref.greedy_logit_gaps(state, prompt + out, len(prompt), spec,
+                                     pad_to=64, max_new=16))
+
+
+# -- the mixers' arithmetic against the reference, tensor for tensor ----------
+
+def test_chunked_scan_and_recurrence_agree_with_the_sequential_scan():
+    """``ssd_chunk_scan`` (from a non-zero state, a ragged tail) and
+    ``ssd_decode_step`` against the token-by-token recurrence."""
+    rng = np.random.RandomState(1)
+    t, h, p, g, n, q = 24, 8, 8, 2, 16, 8
+    x = rng.randn(t, h, p).astype(np.float32)
+    dt = np.log1p(np.exp(rng.randn(t, h))).astype(np.float32) * 0.3
+    a = -np.exp(rng.rand(h)).astype(np.float32)
+    b, c = (rng.randn(t, g, n).astype(np.float32) for _ in range(2))
+    d = rng.randn(h).astype(np.float32)
+    s0 = rng.randn(h, p, n).astype(np.float32)
+    length = 19
+    s, ys = s0.copy(), []
+    for i in range(length):
+        bi, ci = (np.repeat(v[i], h // g, axis=0) for v in (b, c))
+        s = s * np.exp(dt[i] * a)[:, None, None] + \
+            (dt[i][:, None] * x[i])[:, :, None] * bi[:, None, :]
+        ys.append(np.einsum("hpn,hn->hp", s, ci) + d[:, None] * x[i])
+    y, s_end = ssd.ssd_chunk_scan(x, dt, a, b, c, d, s0, q, length)
+    scale = np.abs(np.stack(ys)).max()
+    assert np.abs(np.asarray(y)[:length] - np.stack(ys)).max() \
+        <= TENSOR_F32 * scale
+    assert np.abs(np.asarray(s_end) - s).max() <= TENSOR_F32 * np.abs(s).max()
+    # one token for each of 3 rows
+    rows = np.stack([s0, 2 * s0, -s0])
+    y1, new = ssd.ssd_decode_step(x[:3], dt[:3], a, b[:3], c[:3], d, rows)
+    for r in range(3):
+        one_y, one_s = ssd.ssd_chunk_scan(x[r:r + 1], dt[r:r + 1], a,
+                                          b[r:r + 1], c[r:r + 1], d,
+                                          rows[r], 1)
+        assert np.allclose(y1[r], one_y[0], atol=1e-5)
+        assert np.allclose(new[r], one_s, atol=1e-5)
+
+
+def test_causal_conv_carries_its_tail_across_a_ragged_chunk():
+    rng = np.random.RandomState(2)
+    k, ch = 4, 6
+    x = rng.randn(11, ch).astype(np.float32)
+    w, b = rng.randn(k, ch).astype(np.float32), rng.randn(ch).astype(np.float32)
+    pad = np.concatenate([np.zeros((k - 1, ch), np.float32), x])
+    want = sum(pad[j: j + 11] * w[j] for j in range(k)) + b
+    tail = jnp.zeros((k - 1, ch), jnp.float32)
+    # 5 valid tokens of an 8-wide slot, then the other 6
+    y1, tail = ssd.causal_conv(jnp.pad(x[:5], ((0, 3), (0, 0))), w, b, tail, 5)
+    y2, tail = ssd.causal_conv(x[5:], w, b, tail)
+    got = np.concatenate([np.asarray(y1)[:5], np.asarray(y2)])
+    assert np.abs(got - want).max() <= 1e-5
+    assert np.array_equal(np.asarray(tail), x[-(k - 1):])
+
+
+def test_the_four_shares_and_the_shared_expert_once_give_the_uncut_layer():
+    """Expert parallelism without the exchange: each of 4 shares routes
+    over all 16 experts and computes its own 4; their routed parts, with
+    the shared expert counted once, add up to the uncut reference."""
+    pub, cfg, state = build("E", n_routed_experts=16, expert_offset=0)
+    u = jax.random.normal(jax.random.PRNGKey(0), (9, 64), jnp.float32)
+    live = jnp.ones((9,), bool)
+    spec = ref.spec_from_config(pub)
+    with jax.default_matmul_precision("highest"):
+        whole = ref.latent_moe(u, {k[len("h0.moe."):]: v for k, v in
+                                   state.items() if k.startswith("h0.moe.")},
+                               spec)
+        total, loads = 0.0, []
+        for share in range(4):
+            part = dataclasses.replace(cfg, experts_held=4,
+                                       expert_offset=4 * share)
+            params = {k: v for k, v in state.items()
+                      if share == 0 or ".shared." not in k}
+            for n in ("w1", "w2"):
+                params[f"h0.moe.experts.{n}"] = \
+                    state[f"h0.moe.experts.{n}"][4 * share: 4 * share + 4]
+            out, load = hy.latent_moe(part, params, 0, u, live)
+            total = total + out
+            loads.append(int(load.sum()))
+    assert np.abs(np.asarray(total - whole)).max() \
+        <= TENSOR_F32 * float(jnp.abs(whole).max())
+    assert sum(loads) == 9 * 6          # every assignment on exactly one share
+
+
+# -- through the engine --------------------------------------------------------
+
+@pytest.mark.parametrize("pattern", ["M", "*", "E", "*EMEM"])
+def test_prefill_then_decode_agrees_with_the_reference(pattern):
+    """Chunked prefill, then decode through pages and state slots, three
+    requests batched: every served token within ``GAP_F32`` logits of the
+    reference's full forward pass; one executable."""
+    pub, cfg, state = build(pattern)
+    eng = engine(state, cfg)
+    ps = prompts((19, 5, 1))
+    reqs = [eng.add_request(p, 8) for p in ps]
+    eng.run()
+    assert eng.compile_count == 1
+    for r, p in zip(reqs, ps):
+        assert len(r.out_tokens) == 8
+        assert worst_gap(pub, state, p, r.out_tokens) <= GAP_F32
+    st = eng.state_store
+    assert (st is None) == ("M" not in pattern)
+    assert eng.pool.num_layers == pattern.count("*")
+    if st is not None:
+        assert st.in_use == 0 and not st.problems()
+        assert eng.counters["state_slot_allocs"].value == 3
+    if "E" in pattern:
+        c = eng.metrics_summary()
+        assert 0 < c["moe_assignments_local"] < c["moe_assignments_total"]
+
+
+@pytest.mark.parametrize("chunk", [5, 32, None])
+def test_chunk_boundaries_change_neither_tokens_nor_state(chunk):
+    """A 27-token prompt fed as chunks of 5, of 32 (one ragged chunk) and
+    whole: the same tokens as the reference and the same recurrent state
+    after the last token (conv tail and scan state of both mamba2
+    layers), within float32 reassociation."""
+    pub, cfg, state = build("*EMEM")
+    p = prompts((27,), seed=5)[0]
+
+    def serve(chunk_size):
+        eng = engine(state, cfg, chunk_size=chunk_size, max_batch=2)
+        r = eng.add_request(p, 6)
+        eng.run()
+        st = eng.state_store            # slot 0: the freed slot keeps it
+        return r.out_tokens, [np.asarray(a[0]) for a in st.conv + st.ssm]
+
+    out, states = serve(chunk)
+    assert worst_gap(pub, state, p, out) <= GAP_F32
+    out8, states8 = serve(8)
+    assert out == out8
+    for a, b in zip(states, states8):
+        assert np.abs(a - b).max() <= TENSOR_F32 * max(np.abs(b).max(), 1.0)
+
+
+def test_a_reused_slot_starts_from_zeros():
+    """No zeroing pass: a row whose first token sits at position 0 starts
+    from zeros whatever its slot holds.  Slot 0, left dirty by a first
+    request (and then overwritten with large values), serves a second
+    request exactly as a new engine does."""
+    pub, cfg, state = build("*EMEM")
+    a, b = prompts((13, 9), seed=7)
+    eng = engine(state, cfg, max_batch=1)
+    eng.add_request(a, 5)
+    eng.run()
+    st = eng.state_store
+    assert st.in_use == 0 and any(float(jnp.abs(s).max()) > 0 for s in st.ssm)
+    st.set_arrays([c + 100 for c in st.conv], [s + 100 for s in st.ssm])
+    second = eng.add_request(b, 5)
+    eng.run()
+    fresh = engine(state, cfg, max_batch=1)
+    want = fresh.add_request(b, 5)
+    fresh.run()
+    assert second.state_slot is None and second.out_tokens == want.out_tokens
+    assert worst_gap(pub, state, b, second.out_tokens) <= GAP_F32
+
+
+def test_preemption_drops_the_slot_and_resuming_reproduces_the_tokens():
+    """A pool too small for all the requests' decode pages: someone is
+    preempted (recompute: pages and slot go back), resumes in whatever
+    slot is free then, and every request still serves the reference's
+    tokens.  The invariants of pages and slots hold at every step
+    (``debug=True`` checks both)."""
+    pub, cfg, state = build("*EMEM")
+    ps = prompts((14, 15, 13), seed=11)
+    eng = engine(state, cfg, num_pages=8, page_size=8, max_batch=3)
+    reqs = [eng.add_request(p, 12) for p in ps]
+    eng.run()
+    assert eng.counters["preemptions"].value >= 1
+    assert eng.counters["state_slot_allocs"].value >= 4
+    for r, p in zip(reqs, ps):
+        assert worst_gap(pub, state, p, r.out_tokens) <= GAP_F32
+    assert eng.state_store.in_use == 0
+    assert eng.pool.free_pages == eng.pool.num_usable
+    eng.pool.check_invariants(force=True)
+
+
+def test_abort_returns_every_slot():
+    pub, cfg, state = build("MM")
+    eng = engine(state, cfg)
+    for p in prompts((9, 4, 6)):
+        eng.add_request(p, 50)
+    for _ in range(3):
+        eng.step()
+    assert eng.state_store.in_use == 3
+    assert eng.gauges["state_slots_in_use"].value == 3
+    assert len(eng.abort_all()) == 3
+    assert eng.state_store.in_use == 0 and not eng.state_store.problems()
+
+
+def test_slot_store_keeps_its_invariants_under_the_protocol_chaos_trace():
+    """The protocol gate's seeded chaos trace (admissions, preemptions,
+    crashes that requeue, sheds, finishes, on two replicas), replayed on
+    one slot store per replica: a request holds exactly one slot from
+    admit to preempt / requeue / shed / finish, and free and held slots
+    partition the store after every event."""
+    from hetu_tpu.analysis.protocol import fuzz_trace
+    events = fuzz_trace(seed=0, n_events=300)
+    store = StateSlotStore(2, 8, 4, 12, 2, 4, 4)
+    held = {}
+    for e in events:
+        if e.kind == "req.admit" and e.key not in held:
+            held[e.key] = store.alloc(int(e.key.split(":")[1]))
+            assert held[e.key] is not None
+        elif e.kind in ("req.preempt", "req.queued", "req.shed",
+                        "req.finish") and e.key in held:
+            store.free(held.pop(e.key))
+        assert not store.problems()
+        assert store.in_use == len(held)
+        assert {store.owner(s) for s in held.values()} == \
+            {int(k.split(":")[1]) for k in held}
+    assert sum(1 for e in events if e.kind == "req.admit") >= 10
+    with pytest.raises(ValueError, match="double free"):
+        store.free(7 if store.owner(7) is None else 99)
+
+
+def test_engine_chaos_keeps_pages_and_slots_consistent():
+    """Random arrivals on a squeezed pool with an abort in the middle,
+    ``debug=True`` checking the partition of pages AND slots every step."""
+    pub, cfg, state = build("M*")
+    clock = [0.0]
+    eng = engine(state, cfg, num_pages=10, max_batch=3,
+                 time_fn=lambda: clock[0])
+    rng = np.random.RandomState(4)
+    for i in range(14):
+        eng.add_request(rng.randint(0, VOCAB, rng.randint(1, 20)).tolist(),
+                        int(rng.randint(1, 10)),
+                        arrival_time=float(rng.randint(0, 12)))
+    for step in range(400):
+        if not eng.has_work:
+            break
+        eng.step()
+        clock[0] += 1.0
+        assert eng.state_store.in_use == len(eng.running)
+        if step == 9:
+            eng.abort_all()
+    assert not eng.has_work and eng.state_store.in_use == 0
+    assert eng.compile_count == 1
+
+
+# -- what is not built is refused ---------------------------------------------
+
+def test_prefix_cache_with_recurrent_layers_is_refused():
+    pub, cfg, state = build("*EMEM")
+    with pytest.raises(ValueError, match="prefix_cache=True is not built"):
+        Engine(state, cfg, num_pages=16, page_size=8, use_kernel=False)
+    # an attention + expert stack holds no recurrent state: it may cache
+    _, cfg2, state2 = build("*E")
+    eng = Engine(state2, cfg2, num_pages=16, page_size=8, use_kernel=False)
+    assert eng.prefix_cache is not None and eng.state_store is None
+
+
+def test_speculation_with_recurrent_layers_is_refused():
+    pub, cfg, state = build("*EMEM")
+    spec = SpecConfig(draft_state=state, draft_cfg=cfg, k=2)
+    with pytest.raises(ValueError, match="speculative decoding is not built"):
+        Engine(state, cfg, num_pages=16, page_size=8, prefix_cache=False,
+               spec=spec, use_kernel=False)
+    eng = engine(state, cfg)
+    with pytest.raises(ValueError, match="no recurrent state"):
+        eng.adopt_request([1, 2, 3], [4], 4, pages=[1], pos=3)
+
+
+def test_config_refuses_what_it_cannot_mean():
+    with pytest.raises(ValueError, match="layer_pattern must name"):
+        hy.hybrid_config(published("*EM"), layer_pattern=("moe", "conv", "x"))
+    with pytest.raises(ValueError, match="experts held"):
+        hy.hybrid_config(published("E", expert_offset=14))
+    with pytest.raises(ValueError, match="group-limited"):
+        hy.hybrid_config(published("E", n_group=2))
+    from hetu_tpu.models import GPTConfig
+    with pytest.raises(ValueError, match="expert layer of a layer_pattern"):
+        GPTConfig(num_experts=4, moe_router="sigmoid_bias")
+
+
+def test_softmax_router_is_the_plain_blocks_rule():
+    """``moe_router="softmax"`` in a pattern stack routes as
+    ``generate._moe_route`` does: top-k of the softmax, its values the
+    weights, no renormalisation."""
+    from hetu_tpu.models.generate import _moe_route
+    cfg = hy.hybrid_config(published("E"), moe_router="softmax",
+                           moe_router_scale=1.0)
+    u = jax.random.normal(jax.random.PRNGKey(1), (7, 64), jnp.float32)
+    wr = jax.random.normal(jax.random.PRNGKey(2), (16, 64), jnp.float32)
+    idx, w = hy.moe_route(cfg, wr, jnp.zeros(16), u)
+    _, topv, topi = _moe_route(cfg, wr, u[None])
+    assert np.array_equal(np.asarray(idx), np.asarray(topi[0]))
+    assert np.allclose(w, topv[0], atol=1e-6)
+
+
+# -- precision ----------------------------------------------------------------
+
+def test_bf16_serving_stays_inside_its_tolerance():
+    pub, cfg, state = build("*EMEM", dtype="bfloat16")
+    eng = engine(state, cfg)
+    ps = prompts((21, 6))
+    reqs = [eng.add_request(p, 10) for p in ps]
+    eng.run()
+    for r, p in zip(reqs, ps):
+        assert worst_gap(pub, state, p, r.out_tokens) <= GAP_BF16
+
+
+def test_lower_precision_fails_the_tolerance():
+    """The comparison is tight enough to tell a precision below bf16: the
+    tokens the reference itself picks computed as a float8 deployment
+    would lie further than ``GAP_BF16`` below its float32 best, somewhere
+    in 8 sequences."""
+    pub, cfg, state = build("*EMEM", dtype="bfloat16")
+    spec = ref.spec_from_config(pub)
+    worst = 0.0
+    for p in prompts((40,) * 8, seed=9):
+        worst = max(worst, max(ref.lowp_choice_gaps(
+            state, p, 8, spec, pad_to=64, max_new=32)))
+    assert worst > GAP_BF16
+
+
+def test_the_two_copies_of_the_reference_are_one_file():
+    with open(os.path.join(REPO, "benchmark", "reference_hybrid.py")) as f, \
+            open(os.path.join(REPO, "hetu_tpu", "models",
+                              "hybrid_reference.py")) as g:
+        assert f.read() == g.read()
+
+
+def test_a_plain_config_means_what_it_meant():
+    """No pattern: attention in every layer, K/V for every layer, no
+    state store, and the dense step's signature."""
+    from hetu_tpu.models import GPTConfig
+    cfg = GPTConfig(vocab_size=64, hidden_size=32, num_layers=2, num_heads=4)
+    assert not cfg.is_hybrid and cfg.layers_of("attention") == (0, 1)
+    assert cfg.layers_of("mamba2") == () and cfg.layers_of("moe") == ()
+    assert cfg.held_experts == 0
