@@ -35,6 +35,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..obs.phases import phase
+from ..ops.moe_grouped import ACTIVATIONS, grouped_experts
 from ..ops.ssd import causal_conv, ssd_chunk_scan, ssd_decode_step
 from .generate import norm_eps
 from .gpt import GPTConfig
@@ -193,13 +194,6 @@ def init_state(cfg: GPTConfig, seed: int, time_step=(0.001, 0.1, 1e-4),
 
 # -- the mixers over plain arrays ---------------------------------------------
 
-def act_fn(cfg: GPTConfig):
-    if cfg.activation == "relu2":
-        return lambda h: jnp.square(jax.nn.relu(h))
-    return {"relu": jax.nn.relu, "gelu": jax.nn.gelu,
-            "silu": jax.nn.silu}[cfg.activation]
-
-
 class MambaWeights:
     """One mamba2 layer's tensors, looked up once."""
 
@@ -305,42 +299,56 @@ def moe_route(cfg: GPTConfig, w_router, bias, u):
     return idx, w
 
 
-def held_weights(cfg: GPTConfig, idx, w):
-    """Combine weights over the experts HELD here, ``[T, held]`` float32:
-    an assignment to an expert another chip holds is left out."""
-    local = idx - cfg.expert_offset
-    ok = (local >= 0) & (local < cfg.held_experts)
-    rows = jnp.arange(idx.shape[0])[:, None]
-    return jnp.zeros((idx.shape[0], cfg.held_experts), F32).at[
-        rows, jnp.clip(local, 0, cfg.held_experts - 1)].add(
-            jnp.where(ok, w, 0.0))
-
-
-def latent_moe(cfg: GPTConfig, params: dict, i: int, u, live):
-    """The expert layer on ``u`` [n, H] (normed).  Routed part: every
-    held expert on every token, mixed by the combine weights (zero where
-    not chosen) — the same arithmetic whatever the ids choose, so its
-    device time does not follow the routing.  ``live`` [n] marks real
-    tokens for the load count.  Returns ``(out [n, H], tokens per held
-    expert [held] int32)``."""
+def moe_route_down(cfg: GPTConfig, params: dict, i: int, u):
+    """An expert layer's way in, token by token on ``u`` [n, H] (normed):
+    the router's choice and the latent the routed experts work on.
+    Returns ``(idx [n, k] int32, weights [n, k] float32, lat [n, L])``."""
     g = lambda n: params.get(f"h{i}.moe.{n}")  # noqa: E731
-    act = act_fn(cfg)
     with phase("moe_router"):
         idx, w = moe_route(cfg, g("router.weight"), g("router.bias"), u)
-        wd = held_weights(cfg, idx, w)                       # [n, held]
-        load = jnp.sum((wd > 0) & live[:, None], axis=0, dtype=jnp.int32)
-    down, up = g("latent_down.weight"), g("latent_up.weight")
+    down = g("latent_down.weight")
     with phase("moe_latent"):
         lat = u if down is None else u @ down.T
+    return idx, w, lat
+
+
+def moe_routed(cfg: GPTConfig, params: dict, i: int, lat, idx, w, live):
+    """The routed experts over the WHOLE token axis at once: one grouped
+    matmul (``ops/moe_grouped.py``) over the assignments that are
+    ``live`` [n] and fall on the experts held here, sorted by expert —
+    each hit expert's weights are read once, a dead token or an expert
+    nobody chose costs nothing, so the device time follows the routing.
+    Returns ``(r [n, L] in ``lat``'s dtype, live tokens per held expert
+    [held] int32)``."""
     with phase("moe_routed"):
-        hid = act(jnp.einsum("tl,elf->tef", lat, g("experts.w1")))
-        hid = hid * wd[..., None].astype(hid.dtype)
-        r = jnp.einsum("tef,efl->tl", hid, g("experts.w2"),
-                       preferred_element_type=F32).astype(u.dtype)
+        r, load = grouped_experts(
+            lat, idx, w, live, params[f"h{i}.moe.experts.w1"],
+            params[f"h{i}.moe.experts.w2"],
+            expert_offset=cfg.expert_offset, activation=cfg.activation)
+        return r.astype(lat.dtype), load
+
+
+def moe_up_shared(cfg: GPTConfig, params: dict, i: int, u, r):
+    """An expert layer's way out, token by token: the routed part ``r``
+    [n, L] back up to the hidden, plus the shared expert on ``u``."""
+    g = lambda n: params.get(f"h{i}.moe.{n}")  # noqa: E731
+    up = g("latent_up.weight")
     with phase("moe_latent"):
         out = r if up is None else r @ up.T
     s_up = g("shared.up.weight")
     if s_up is not None:
+        act = ACTIVATIONS[cfg.activation]
         with phase("moe_shared"):
             out = out + act(u @ s_up.T) @ g("shared.down.weight").T
-    return out, load
+    return out
+
+
+def latent_moe(cfg: GPTConfig, params: dict, i: int, u, live):
+    """The expert layer on ``u`` [n, H] (normed); ``live`` [n] marks real
+    tokens: a dead one is given no routed expert and counts in no load.
+    Returns ``(out [n, H], tokens per held expert [held] int32)``.  The
+    serving step runs the three parts itself: the middle one once over
+    its whole token axis, the outer two region by region."""
+    idx, w, lat = moe_route_down(cfg, params, i, u)
+    r, load = moe_routed(cfg, params, i, lat, idx, w, live)
+    return moe_up_shared(cfg, params, i, u, r), load

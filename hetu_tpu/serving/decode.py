@@ -722,24 +722,21 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
     attn_of = {i: a for a, i in enumerate(c.layers_of("attention"))}
     mamba_of = {i: m for m, i in enumerate(c.layers_of("mamba2"))}
 
-    def by_region(f, h, q_lens, live):
-        """``f(tokens [n, ...], live [n]) -> (per-token [n, ...], sums)``
-        over the decode slots and, under ``lax.cond``, each chunk slot;
-        an idle chunk slot gives zeros and pays nothing."""
-        outs = [f(h[:max_seqs], live[:max_seqs])]
+    def by_region(f, h, q_lens):
+        """``f(tokens) -> per-token`` over the decode slots and, under
+        ``lax.cond``, each chunk slot; both sides are arrays ``[n, ...]``
+        or trees of them.  An idle chunk slot gives zeros and pays
+        nothing."""
+        tmap = jax.tree_util.tree_map
+        outs = [f(tmap(lambda a: a[:max_seqs], h))]
         for row, start, width in slots:
-            sl, lv = h[start: start + width], live[start: start + width]
-            zero = jax.eval_shape(f, sl, lv)
+            sl = tmap(lambda a: a[start: start + width], h)
+            zero = jax.eval_shape(f, sl)
             outs.append(lax.cond(
                 q_lens[row] > 0, f,
-                lambda s, l, z=zero: jax.tree_util.tree_map(
-                    lambda a: jnp.zeros(a.shape, a.dtype), z), sl, lv))
-        ys, sums = zip(*outs)
-        return jnp.concatenate(ys, axis=0), \
-            jax.tree_util.tree_map(lambda *a: sum(a), *sums)
-
-    def tokenwise(f):
-        return lambda hh, _: (f(hh), ())
+                lambda s, z=zero: tmap(
+                    lambda a: jnp.zeros(a.shape, a.dtype), z), sl))
+        return tmap(lambda *a: jnp.concatenate(a, axis=0), *outs)
 
     def run(params, tokens, token_pos, token_page, token_off, q_lens, cu_q,
             page_tables, ctx_lens, temps, top_ps, top_ks, seeds,
@@ -778,9 +775,9 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
             if mixer == "attention":
                 a = attn_of[i]
                 with phase("attn_proj"):
-                    qkv, _ = by_region(tokenwise(
-                        lambda hh, i=i: hh @ p.layer(i, "attn.qkv.weight").T),
-                        h, q_lens, live)
+                    qkv = by_region(
+                        lambda hh, i=i: hh @ p.layer(i, "attn.qkv.weight").T,
+                        h, q_lens)
                 q_size, kv_size = nh * hd, nkv * hd
                 with phase("attn_core"):
                     q = qkv[..., :q_size].reshape(t_tokens, nh, hd)
@@ -811,16 +808,16 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
                             max_seqs, prefill_rows, chunk)
                     attn = attn.reshape(t_tokens, nh * hd).astype(x.dtype)
                 with phase("attn_proj"):
-                    out, _ = by_region(tokenwise(
-                        lambda aa, i=i: aa @ p.layer(i, "attn.out.weight").T),
-                        attn, q_lens, live)
+                    out = by_region(
+                        lambda aa, i=i: aa @ p.layer(i, "attn.out.weight").T,
+                        attn, q_lens)
                 new_k[a], new_v[a] = kp, vp
             elif mixer == "mamba2":
                 m = mamba_of[i]
                 w = hy.MambaWeights(params, i)
                 with phase("ssm_proj"):
-                    zxd, _ = by_region(tokenwise(
-                        lambda hh, w=w: hh @ w.in_proj.T), h, q_lens, live)
+                    zxd = by_region(
+                        lambda hh, w=w: hh @ w.in_proj.T, h, q_lens)
                 with phase("state_io"):
                     zxd_slots = zxd[:max_seqs][slot_src]
                 y_slots, conv_s, ssm_s = hy.mamba_rows(
@@ -850,13 +847,21 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
                         c, w, jnp.concatenate(ys, axis=0),
                         zxd[..., :c.mamba_inner], x.dtype)
                 with phase("ssm_proj"):
-                    out, _ = by_region(tokenwise(
-                        lambda yy, w=w: yy @ w.out_proj.T), y, q_lens, live)
+                    out = by_region(
+                        lambda yy, w=w: yy @ w.out_proj.T, y, q_lens)
                 new_conv[m], new_ssm[m] = conv_s, ssm_s
             else:
-                out, load = by_region(
-                    lambda hh, lv, i=i: hy.latent_moe(c, params, i, hh, lv),
-                    h, q_lens, live)
+                # router, latent and shared expert region by region; the
+                # routed experts ONCE over the token axis, dead tokens
+                # carrying no assignment: a chunk step reads an expert's
+                # weights once, not once a region
+                idx, wts, lat = by_region(
+                    lambda hh, i=i: hy.moe_route_down(c, params, i, hh),
+                    h, q_lens)
+                r, load = hy.moe_routed(c, params, i, lat, idx, wts, live)
+                out = by_region(
+                    lambda hr, i=i: hy.moe_up_shared(c, params, i, *hr),
+                    (h, r), q_lens)
                 loads.append(load)
             x = x + out.astype(x.dtype)
         with phase("norm"):

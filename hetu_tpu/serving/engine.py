@@ -89,6 +89,7 @@ import numpy as np
 from ..models.generate import _Params
 from ..models.gpt import GPTConfig
 from ..obs.tracer import get_tracer
+from ..ops.moe_grouped import ROW_BLOCK, block_rows
 from ..ops.pallas import on_tpu
 from ..utils.metrics import make_instrument, render_prometheus
 from .decode import build_unified_step_fn
@@ -262,9 +263,11 @@ class Engine:
                           # hybrid stacks (zero elsewhere): state slots
                           # handed out; live (token, expert) assignments
                           # that fell on the experts held here / made by
-                          # the router over all experts
+                          # the router over all experts; rows the grouped
+                          # expert kernel computed for the local ones
+                          # (each expert's group padded to whole blocks)
                           "state_slot_allocs", "moe_assignments_local",
-                          "moe_assignments_total")}
+                          "moe_assignments_total", "moe_block_rows")}
         self.gauges = {k: make_instrument("gauge", k, m) for k in
                        ("batch_occupancy", "page_utilization",
                         "queue_depth",
@@ -1021,7 +1024,8 @@ class Engine:
         attributes (``state_slots``: slots held; ``moe_local``: live
         assignments on the held experts; ``moe_load_peak``: the busiest
         held expert over the mean; ``moe_experts_hit``: held experts,
-        summed over the expert layers, that got >= 1 token)."""
+        summed over the expert layers, that got >= 1 token;
+        ``moe_blocks``: row blocks the grouped expert kernel ran)."""
         st = self.state_store
         attrs: Dict[str, Any] = {}
         if st is not None:
@@ -1036,8 +1040,11 @@ class Engine:
             mean = moe_load.mean()
             peak = float(moe_load.max() / mean) if mean else 0.0
             self.gauges["moe_expert_load_peak"].set(peak)
+            rows = int(block_rows(moe_load))
+            self.counters["moe_block_rows"].inc(rows)
             attrs.update(moe_local=local, moe_load_peak=peak,
-                         moe_experts_hit=int((moe_load > 0).sum()))
+                         moe_experts_hit=int((moe_load > 0).sum()),
+                         moe_blocks=rows // ROW_BLOCK)
         return attrs
 
     def _observe_token(self, req: Request, decode_slot: bool,

@@ -32,6 +32,7 @@ from hetu_tpu import ops  # noqa: E402
 rpa = importlib.import_module("hetu_tpu.ops.ragged_paged_attention")
 kvw = importlib.import_module("hetu_tpu.ops.paged_kv_write")
 fa = importlib.import_module("hetu_tpu.ops.pallas.flash_attention")
+mg = importlib.import_module("hetu_tpu.ops.moe_grouped")
 
 I32, BF16, F32 = jnp.int32, jnp.bfloat16, jnp.float32
 # the serving step's ragged batch at GPT-2 124M widths: 16 decode rows +
@@ -98,6 +99,13 @@ def _kernel_cases():
             *(_sds((PAGES, h, PAGE, w), dt) for h, w, dt in widths),
             *(_sds((t, h, w), dt) for h, w, dt in widths)))
 
+    def grouped_experts(x, idx, w, live, w1, w2):
+        # an expert layer of the hybrid configuration's serving step: 64
+        # decode slots + one 256-token chunk, top-22, 128 experts held
+        # from offset 128, latent 1024, experts 2688 wide
+        return mg.grouped_experts(x, idx, w, live, w1, w2,
+                                  expert_offset=128, interpret=False)
+
     def flash_qkv_grad(x):
         return jax.grad(lambda x: fa.flash_attention_qkv(
             x, 12, causal=True).astype(F32).sum())(x)
@@ -134,6 +142,10 @@ def _kernel_cases():
         "kv_write_12kv_x128": kv_write((12, 128, BF16), (12, 128, BF16)),
         "kv_write_latent_512_64": kv_write((1, 512, BF16), (1, 64, BF16)),
         "kv_write_int8_sidecar": kv_write((1, 512, jnp.int8), (1, 1, F32)),
+        "moe_grouped_experts": (grouped_experts, (
+            _sds((320, 1024), BF16), _sds((320, 22), I32),
+            _sds((320, 22), F32), _sds((320,), jnp.bool_),
+            _sds((128, 1024, 2688), BF16), _sds((128, 2688, 1024), BF16))),
     }
 
 
@@ -143,11 +155,12 @@ def _kernel_cases():
 # as it was — and the serving step's one-token window, whose 16-row bf16
 # tile is the layout most likely to be refused; of the KV write, the
 # benchmark's pool and the one written in whole one-lane pages; of flash,
-# the train cell's call: three lane blocks of the fused [b, s, 3*h*d]
+# the train cell's call: three lane blocks of the fused [b, s, 3*h*d]; the
+# grouped experts: two whole expert matrices double-buffered (33 MB of VMEM)
 AOT_CASES = ("ragged_12kv_x64", "ragged_decode_region",
              "ragged_decode_gqa16", "latent_512_64",
              "latent_nf4", "kv_write_12kv_x128", "kv_write_int8_sidecar",
-             "flash_qkv")
+             "flash_qkv", "moe_grouped_experts")
 
 
 @pytest.fixture

@@ -37,6 +37,7 @@ import pytest
 from hetu_tpu.models import hybrid as hy
 from hetu_tpu.models import hybrid_reference as ref
 from hetu_tpu.ops import ssd
+from hetu_tpu.ops.moe_grouped import ROW_BLOCK, grouped_experts
 from hetu_tpu.serving import Engine
 from hetu_tpu.serving.kv_pool import StateSlotStore
 from hetu_tpu.serving.spec import SpecConfig
@@ -172,6 +173,113 @@ def test_the_four_shares_and_the_shared_expert_once_give_the_uncut_layer():
     assert sum(loads) == 9 * 6          # every assignment on exactly one share
 
 
+def _dense_mix(cfg, params, lat, idx, w, live):
+    """The arithmetic the grouped routed part replaced: every held expert
+    on every token, mixed by combine weights that are zero where an
+    expert was not chosen or the token is dead."""
+    local = idx - cfg.expert_offset
+    ok = (local >= 0) & (local < cfg.held_experts) & live[:, None]
+    rows = jnp.arange(idx.shape[0])[:, None]
+    wd = jnp.zeros((idx.shape[0], cfg.held_experts), jnp.float32).at[
+        rows, jnp.clip(local, 0, cfg.held_experts - 1)].add(
+            jnp.where(ok, w, 0.0))
+    hid = jnp.square(jax.nn.relu(jnp.einsum(
+        "tl,elf->tef", lat, params["h0.moe.experts.w1"])))
+    return jnp.einsum("tef,efl->tl", hid * wd[..., None],
+                      params["h0.moe.experts.w2"]), (wd > 0).sum(0)
+
+
+# decode slots 0..8, one 32-token chunk slot behind them; a case is (live
+# tokens, published keys, router bias by expert); 16 routed experts, top-6
+_DEC, _CHUNK = 8, 32
+_ROUTED_CASES = {
+    # (a) decode rows only, the chunk region dead
+    "decode_rows_chunk_dead": (list(range(5)), {}, {}),
+    # (b) a chunk beside the decode rows
+    "chunk_and_decode_rows": ([0, 2, 3] + list(range(_DEC, _DEC + 27)), {},
+                              {}),
+    # (c) one held expert chosen by every token: many blocks of one expert
+    "one_expert_takes_every_token": (
+        list(range(_DEC + _CHUNK)), {}, {5: 10.0}),
+    # every assignment held here: the static worst case, T * k rows
+    "every_assignment_held": (
+        list(range(_DEC + _CHUNK)),
+        {"n_routed_experts": 16, "expert_offset": 0}, {}),
+    # (d) no assignment on a held expert
+    "nothing_held_here": (list(range(_DEC + _CHUNK)), {},
+                          {4: -10.0, 5: -10.0, 6: -10.0, 7: -10.0}),
+    # (e) the first and the last share
+    "offset_0": ([1, 4] + list(range(_DEC, _DEC + 9)),
+                 {"expert_offset": 0}, {}),
+    "offset_12": ([1, 4] + list(range(_DEC, _DEC + 9)),
+                  {"expert_offset": 12}, {}),
+    # (f) dead tokens that all point at held expert 6
+    "dead_tokens_point_at_a_held_expert": ([0, 1, _DEC, _DEC + 1], {},
+                                           {6: 10.0}),
+}
+
+
+@pytest.mark.parametrize("case", list(_ROUTED_CASES))
+def test_grouped_routed_experts_equal_the_dense_mix_and_the_reference(case):
+    """The routed part as the serving step runs it (``moe_route_down`` ->
+    ``moe_routed`` over the whole token axis, kernel interpreted) against
+    the dense every-expert mix and against the reference's per-expert
+    loop, float32: live rows agree, dead rows are exactly zero, the load
+    counts live tokens only."""
+    live_at, keys, bias = _ROUTED_CASES[case]
+    pub, cfg, state = build("E", **keys)
+    rb = state["h0.moe.router.bias"]
+    for e, b in bias.items():
+        rb = rb.at[e].set(b)
+    state = {**state, "h0.moe.router.bias": rb}
+    n = _DEC + _CHUNK
+    u = jax.random.normal(jax.random.PRNGKey(1), (n, 64), jnp.float32)
+    live = jnp.zeros((n,), bool).at[jnp.asarray(live_at)].set(True)
+    no_shared = {k: v for k, v in state.items() if ".shared." not in k}
+    with jax.default_matmul_precision("highest"):
+        idx, w, lat = hy.moe_route_down(cfg, state, 0, u)
+        r, load = hy.moe_routed(cfg, state, 0, lat, idx, w, live)
+        want, want_load = _dense_mix(cfg, state, lat, idx, w, live)
+        # 8-row blocks: a group of 40 tokens is then five blocks of one
+        # expert, where the step's own block holds it in one
+        fine, fine_load = grouped_experts(
+            lat, idx, w, live, state["h0.moe.experts.w1"],
+            state["h0.moe.experts.w2"], expert_offset=cfg.expert_offset,
+            activation=cfg.activation, block=8)
+        whole = ref.latent_moe(
+            u, {k[len("h0.moe."):]: v for k, v in state.items()
+                if k.startswith("h0.moe.")},
+            ref.spec_from_config(pub), shared=False)
+        got = hy.moe_up_shared(cfg, no_shared, 0, u, r)
+    r, load, dead = np.asarray(r), np.asarray(load), ~np.asarray(live)
+    assert np.array_equal(load, np.asarray(want_load))
+    assert not r[dead].any()
+    scale = max(float(jnp.abs(want).max()), 1e-6)
+    assert np.abs(r - np.asarray(want)).max() <= TENSOR_F32 * scale
+    assert np.abs(np.asarray(fine) - np.asarray(want)).max() \
+        <= TENSOR_F32 * scale
+    assert np.array_equal(np.asarray(fine_load), load)
+    whole, got = np.asarray(whole), np.asarray(got)
+    assert np.abs(got - whole)[~dead].max() \
+        <= TENSOR_F32 * max(np.abs(whole).max(), 1e-6)
+    if case == "nothing_held_here":
+        assert not r.any() and not load.any()
+    if case == "one_expert_takes_every_token":
+        assert load[5 - cfg.expert_offset] == n
+    if case == "every_assignment_held":
+        assert load.sum() == n * cfg.moe_top_k
+    if case == "dead_tokens_point_at_a_held_expert":
+        # the dead tokens chose expert 6 too; only the live ones count,
+        # and the live rows are what they are with no dead token beside
+        assert load[6 - cfg.expert_offset] == len(live_at)
+        at = jnp.asarray(live_at)
+        alone, alone_load = hy.moe_routed(
+            cfg, state, 0, lat[at], idx[at], w[at], live[at])
+        assert np.array_equal(np.asarray(alone_load), load)
+        assert np.abs(np.asarray(alone) - r[np.asarray(live_at)]).max() \
+            <= TENSOR_F32 * scale
+
+
 # -- through the engine --------------------------------------------------------
 
 @pytest.mark.parametrize("pattern", ["M", "*", "E", "*EMEM"])
@@ -197,6 +305,33 @@ def test_prefill_then_decode_agrees_with_the_reference(pattern):
     if "E" in pattern:
         c = eng.metrics_summary()
         assert 0 < c["moe_assignments_local"] < c["moe_assignments_total"]
+
+
+def test_engine_serves_the_same_tokens_and_counts_the_kernels_rows():
+    """The tiny hybrid cell through the grouped routed experts: the
+    tokens are those the dense every-expert mix served (pinned from the
+    parent of PR 34, greedy, float32), the load counters are what they
+    were, and ``moe_block_rows`` counts each hit expert's group padded
+    to whole row blocks."""
+    from hetu_tpu.obs.tracer import SpanTracer
+    pub, cfg, state = build("*EMEM")
+    eng = engine(state, cfg, tracer=SpanTracer())
+    ps = prompts((19, 5, 1, 30))
+    reqs = [eng.add_request(p, 12) for p in ps]
+    eng.run()
+    assert [r.out_tokens for r in reqs] == [
+        [125, 26, 23, 119, 52, 109, 35, 73, 78, 17, 52, 24],
+        [104, 108, 53, 97, 51, 6, 23, 30, 126, 64, 59, 116],
+        [66, 57, 97, 101, 83, 33, 121, 120, 72, 52, 9, 97],
+        [113, 78, 19, 109, 117, 72, 96, 89, 13, 29, 74, 9]]
+    c = eng.metrics_summary()
+    assert c["moe_assignments_local"] == 318
+    assert c["moe_assignments_total"] == 1188
+    assert c["moe_block_rows"] >= c["moe_assignments_local"] > 0
+    assert c["moe_block_rows"] % ROW_BLOCK == 0
+    steps = [e for e in eng.tracer.events() if e.name == "unified_step"]
+    assert steps and sum(e.attrs["moe_blocks"] for e in steps) \
+        * ROW_BLOCK == c["moe_block_rows"]
 
 
 @pytest.mark.parametrize("chunk", [5, 32, None])
