@@ -29,7 +29,7 @@ from ..obs.phases import phase
 from jax.sharding import PartitionSpec as P
 
 
-MIXERS = ("mamba2", "attention", "moe")
+MIXERS = ("mamba2", "attention", "moe", "mla")
 
 
 @dataclass
@@ -72,6 +72,25 @@ class GPTConfig:
     # None -> head_dim); learned-position configs carry no rope stream.
     kv_latent_dim: Optional[int] = None
     kv_rope_dim: Optional[int] = None
+    # A latent attention that is the MODEL'S OWN (the "mla" mixer of a
+    # layer_pattern stack; models/hybrid.py), not a conversion of a
+    # full-head checkpoint: a low-rank q with its norm (``mla_q_rank``),
+    # a norm on the latent, per-head widths that differ (``mla_nope_dim``
+    # for q/k outside the rotary stream, ``kv_rope_dim`` inside it,
+    # ``mla_v_dim`` for v; None -> head_dim), the softmax scale as a
+    # value, and the rotary stream's tables: ``rope_interleave`` rotates
+    # the pairs (2i, 2i+1), ``rope_yarn`` = (factor, original positions,
+    # beta_fast, beta_slow, table factor) stretches the frequencies, and
+    # ``q_pos_scale`` = (beta, period) multiplies q by ``1 + beta *
+    # ln(1 + floor(pos / period))``.
+    mla_q_rank: Optional[int] = None
+    mla_nope_dim: Optional[int] = None
+    mla_v_dim: Optional[int] = None
+    attn_scale: Optional[float] = None
+    rope_theta: float = 10000.0
+    rope_interleave: bool = False
+    rope_yarn: Optional[Tuple[float, int, float, float, float]] = None
+    q_pos_scale: Optional[Tuple[float, int]] = None
     # Hybrid stacks (serving path; models/hybrid.py owns the parameter
     # names): ``layer_pattern`` gives ONE mixer per layer behind one
     # pre-norm and one residual — "mamba2" | "attention" | "moe".  None
@@ -92,7 +111,12 @@ class GPTConfig:
     # computes only their part of the result (expert parallelism without
     # the exchange).  "sigmoid_bias": sigmoid scores, top-k of score +
     # selection bias, chosen scores renormalised, x moe_router_scale.
+    # ``moe_norm_topk`` renormalises a softmax router's chosen values to
+    # sum 1; ``moe_gated`` experts (routed and shared) are ``down(act(
+    # gate u) * up u)``, three matrices, where the plain ones are two.
     moe_router: str = "softmax"             # softmax | sigmoid_bias
+    moe_norm_topk: bool = False
+    moe_gated: bool = False
     moe_router_scale: float = 1.0
     moe_ffn_size: Optional[int] = None      # one routed expert's width
     moe_latent_dim: Optional[int] = None    # experts work in this width
@@ -108,6 +132,12 @@ class GPTConfig:
                 raise ValueError(
                     f"layer_pattern must name one of {MIXERS} for each of "
                     f"the {self.num_layers} layers, got {self.layer_pattern}")
+            if "mla" in self.layer_pattern and (
+                    self.kv_latent_dim is None
+                    or "attention" in self.layer_pattern):
+                raise ValueError(
+                    "an mla layer needs kv_latent_dim, and one page pool "
+                    "holds one layout: not beside plain attention layers")
             if "moe" in self.layer_pattern:
                 held, off = self.held_experts, self.expert_offset
                 if not (self.num_experts > 0 and held >= 1 and off >= 0
@@ -119,12 +149,21 @@ class GPTConfig:
             raise ValueError(f"unknown moe_router {self.moe_router!r}")
         if self.layer_pattern is None and (
                 self.moe_router != "softmax" or self.moe_latent_dim
-                or self.moe_shared_ffn_size or self.experts_held is not None):
+                or self.moe_shared_ffn_size or self.experts_held is not None
+                or self.moe_norm_topk or self.moe_gated):
             raise ValueError(
                 "moe_router / moe_latent_dim / moe_shared_ffn_size / "
-                "experts_held describe the expert layer of a layer_pattern "
-                "stack; the plain block's MoE is softmax top-k over all its "
-                "experts")
+                "experts_held / moe_norm_topk / moe_gated describe the "
+                "expert layer of a layer_pattern stack; the plain block's "
+                "MoE is softmax top-k over all its experts")
+        if "mla" not in (self.layer_pattern or ()) and (
+                self.mla_q_rank or self.mla_nope_dim or self.mla_v_dim
+                or self.attn_scale is not None or self.rope_interleave
+                or self.rope_yarn or self.q_pos_scale):
+            raise ValueError(
+                "mla_q_rank / mla_nope_dim / mla_v_dim / attn_scale / "
+                "rope_interleave / rope_yarn / q_pos_scale describe the mla "
+                "mixer of a layer_pattern stack")
         assert self.hidden_size % self.num_heads == 0, \
             f"hidden {self.hidden_size} not divisible by heads {self.num_heads}"
         kv = self.num_kv_heads or self.num_heads
@@ -151,6 +190,38 @@ class GPTConfig:
     @property
     def is_hybrid(self) -> bool:
         return self.layer_pattern is not None
+
+    @property
+    def nope_dim(self) -> int:
+        """An mla head's q/k width outside the rotary stream."""
+        return self.mla_nope_dim or self.head_dim
+
+    @property
+    def v_dim(self) -> int:
+        """An mla head's v width."""
+        return self.mla_v_dim or self.head_dim
+
+    @property
+    def mla_softmax_scale(self) -> float:
+        return float(self.attn_scale) if self.attn_scale is not None \
+            else (self.nope_dim + self.rope_dim) ** -0.5
+
+    @property
+    def latent_page_dims(self) -> Tuple[int, int]:
+        """``(latent_dim, rope_dim)`` of the page pool's latent layout.
+        The ``mla`` mixer's rotary stream is padded with zero lanes to a
+        multiple of 128: an array whose minor dimension does not fill
+        the lanes gets a device layout that is not the kernels', and XLA
+        re-lays the whole pool out on entry and exit of every step (11 ms
+        of a 72 ms step at a 64-wide stream: PERF.md, PR 35)."""
+        if "mla" in (self.layer_pattern or ()):
+            return self.kv_latent_dim, -(-self.rope_dim // 128) * 128
+        return self.kv_latent_dim, self.rope_dim
+
+    @property
+    def paged_layers(self) -> Tuple[int, ...]:
+        """Layers that keep pages in the K/V pool, in pool order."""
+        return self.layers_of("mla") or self.layers_of("attention")
 
     def layers_of(self, mixer: str) -> Tuple[int, ...]:
         """Layer indices running ``mixer``; a plain stack has attention

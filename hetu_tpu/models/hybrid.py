@@ -1,4 +1,5 @@
-"""Hybrid stacks: one mixer per layer — Mamba-2 / attention / latent MoE.
+"""Hybrid stacks: one mixer per layer — Mamba-2 / attention / latent
+attention (MLA) / MoE.
 
 A ``GPTConfig`` with a ``layer_pattern`` runs ``x = x + mixer_l(RMSNorm_l
 (x))`` for every layer, a final norm and an untied head.  This module is
@@ -17,13 +18,30 @@ Tensors (a projection ``W`` is ``[out, in]``, used as ``x @ W.T``)::
     h{i}.mamba.dt_bias / .A_log / .D [heads]    (float32)
     h{i}.mamba.norm.weight [inner]    h{i}.mamba.out_proj.weight [H, inner]
     h{i}.attn.qkv.weight [(nh + 2*kv)*hd, H]    h{i}.attn.out.weight [H, nh*hd]
+    mla (``L``; nope / rope / v widths n / r / v, latent d_c, q rank R):
+    h{i}.attn.q_a.weight [R, H]   .q_a_norm.weight [R]
+    h{i}.attn.q_b.weight [nh*(n + r), R]    a head's rows: nope | rope
+    h{i}.attn.kv_a.weight [d_c + r, H]      .kv_a_norm.weight [d_c]
+    h{i}.attn.k_up.weight [nh, n, d_c]   .v_up.weight [nh, v, d_c]
+                          (the two halves of W_kvb, as decode absorbs them)
+    h{i}.attn.out.weight [H, nh*v]
     h{i}.moe.router.weight [E_all, H]   .router.bias [E_all]  (float32)
     h{i}.moe.latent_down.weight [L, H]  h{i}.moe.latent_up.weight [H, L]
     h{i}.moe.experts.w1 [E_held, L, F]  h{i}.moe.experts.w2 [E_held, F, L]
     h{i}.moe.shared.up.weight [Fs, H]   h{i}.moe.shared.down.weight [H, Fs]
+    gated experts (``cfg.moe_gated``): w1 is the gate, and beside it
+    h{i}.moe.experts.w3 [E_held, L, F]  h{i}.moe.shared.gate.weight [Fs, H]
 
 Without ``moe_latent_dim`` the experts work on the hidden itself (``L =
-H``, no latent projections).
+H``, no latent projections).  Without a router bias (a softmax router)
+``router.bias`` is absent.
+
+What the serving engine refuses depends on the pattern, not on the
+stack being a pattern: the prefix cache and speculation for a pattern
+with an ``M`` layer (a page prefix carries no recurrent state);
+speculation and page quantisation for every pattern; ``L`` beside ``*``
+(one pool, one layout).  ``mistral4_config`` is the translation for the
+``(L, E) x depth`` stacks of ``model_type: mistral4``.
 """
 from __future__ import annotations
 
@@ -32,6 +50,7 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ..obs.phases import phase
@@ -41,7 +60,7 @@ from .generate import norm_eps
 from .gpt import GPTConfig
 
 F32 = jnp.float32
-MIXER_OF = {"M": "mamba2", "*": "attention", "E": "moe"}
+MIXER_OF = {"M": "mamba2", "*": "attention", "E": "moe", "L": "mla"}
 # float32 whatever the model's dtype: the recurrence's own parameters and
 # the router (its scores decide a top-k)
 _F32_PARAMS = ("mamba.dt_bias", "mamba.A_log", "mamba.D",
@@ -88,6 +107,101 @@ def hybrid_config(pub: dict, **overrides) -> GPTConfig:
     return GPTConfig(**kw)
 
 
+def mistral4_config(pub: dict, **overrides) -> GPTConfig:
+    """The one translation from the published ``config.json`` keys of a
+    ``mistral4``-type model (as cut: ``n_routed_experts`` = experts held
+    here, ``moe_router_outputs`` = the router's width) to ``GPTConfig``:
+    every published layer is an ``L`` layer then an ``E`` layer of the
+    pattern.  What the keys do not carry is the caller's to state
+    (``pub["assumed"]``): the softmax router is this translation's, the
+    softmax scale follows the ``deepseek_v3`` convention ``(n + r) ** -0.5
+    * m * m`` with ``m = 0.1 * mscale_all_dim * ln(factor) + 1``."""
+    if pub.get("n_group", 1) != 1 or pub.get("topk_group", 1) != 1:
+        raise ValueError("group-limited routing (n_group > 1) is not built")
+    if pub.get("first_k_dense_replace", 0):
+        raise ValueError("leading dense layers (first_k_dense_replace > 0) "
+                         "are not built: every layer is an expert layer")
+    rp = pub["rope_parameters"]
+    if rp.get("rope_type", rp.get("type")) != "yarn":
+        raise ValueError("the rotary stream is built with YaRN tables")
+    nope, rope = pub["qk_nope_head_dim"], pub["qk_rope_head_dim"]
+    factor = float(rp["factor"])
+
+    def ms(m):              # the family's get_mscale
+        return 0.1 * float(m) * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+    mscale, mall = rp.get("mscale"), rp.get("mscale_all_dim")
+    m_all = ms(mall) if mall else 1.0
+    table = ms(mscale) / ms(mall) if mscale and mall else ms(1)
+    beta = float(rp.get("llama_4_scaling_beta", 0.0))
+    orig = int(rp["original_max_position_embeddings"])
+    layers = pub["num_hidden_layers"]
+    kw = dict(
+        vocab_size=pub["vocab_size"], hidden_size=pub["hidden_size"],
+        num_layers=2 * layers, num_heads=pub["num_attention_heads"],
+        max_seq_len=pub["max_position_embeddings"],
+        activation=pub["hidden_act"], norm="rmsnorm", position="rotary",
+        norm_eps=float(pub["rms_norm_eps"]),
+        tie_embeddings=bool(pub["tie_word_embeddings"]), sp=False,
+        dtype=pub.get("dtype", "bfloat16"),
+        layer_pattern=("mla", "moe") * layers,
+        kv_latent_dim=pub["kv_lora_rank"], kv_rope_dim=rope,
+        mla_q_rank=pub["q_lora_rank"], mla_nope_dim=nope,
+        mla_v_dim=pub["v_head_dim"],
+        attn_scale=(nope + rope) ** -0.5 * m_all * m_all,
+        rope_theta=float(rp["rope_theta"]),
+        rope_interleave=bool(pub.get("rope_interleave", True)),
+        rope_yarn=(factor, orig, float(rp["beta_fast"]),
+                   float(rp["beta_slow"]), table),
+        q_pos_scale=(beta, orig) if beta else None,
+        num_experts=pub.get("moe_router_outputs", pub["n_routed_experts"]),
+        experts_held=pub["n_routed_experts"],
+        expert_offset=pub.get("expert_offset", 0),
+        moe_top_k=pub["num_experts_per_tok"], moe_router="softmax",
+        moe_norm_topk=bool(pub["norm_topk_prob"]), moe_gated=True,
+        moe_router_scale=float(pub["routed_scaling_factor"]),
+        moe_ffn_size=pub["moe_intermediate_size"],
+        moe_shared_ffn_size=pub["moe_intermediate_size"]
+        * pub["n_shared_experts"])
+    kw.update(overrides)
+    return GPTConfig(**kw)
+
+
+def mla_rotary_tables(cfg: GPTConfig, max_len: int):
+    """``(cos, sin [max_len, r], q_scale [max_len])`` of the rotary
+    stream, float32: YaRN frequencies where ``cfg.rope_yarn`` (each
+    frequency between its own and its ``1 / factor``, by how many turns
+    it makes in the original positions), times the tables' factor; the
+    halves laid ``[angles | angles]`` for the half-split rotation (an
+    interleaved stream is brought into that order first: ``mla_rotate``).
+    ``q_scale`` is the per-position factor on q (1 without
+    ``cfg.q_pos_scale``)."""
+    d = cfg.rope_dim
+    freqs = cfg.rope_theta ** (np.arange(0, d, 2, dtype=np.float64) / d)
+    inv, table = 1.0 / freqs, 1.0
+    if cfg.rope_yarn:
+        factor, orig, fast, slow, table = cfg.rope_yarn
+
+        def turn_dim(turns):
+            return d * math.log(orig / (turns * 2 * math.pi)) / \
+                (2 * math.log(cfg.rope_theta))
+
+        low = max(math.floor(turn_dim(fast)), 0)
+        high = min(math.ceil(turn_dim(slow)), d - 1)
+        ramp = np.clip((np.arange(d // 2) - low) /
+                       ((high - low) or 0.001), 0, 1)
+        inv = inv / factor * ramp + inv * (1 - ramp)
+    pos = np.arange(max_len, dtype=np.float64)
+    ang = np.outer(pos, inv)
+    emb = np.concatenate([ang, ang], -1)
+    scale = np.ones(max_len)
+    if cfg.q_pos_scale:
+        beta, period = cfg.q_pos_scale
+        scale = 1 + beta * np.log1p(np.floor(pos / period))
+    as32 = lambda a: jnp.asarray(a.astype(np.float32))  # noqa: E731
+    return as32(np.cos(emb) * table), as32(np.sin(emb) * table), as32(scale)
+
+
 def param_shapes(cfg: GPTConfig) -> Dict[str, Tuple[int, ...]]:
     """Every tensor of a hybrid stack under its name."""
     c = cfg
@@ -111,15 +225,32 @@ def param_shapes(cfg: GPTConfig) -> Dict[str, Tuple[int, ...]]:
             q, kv = c.num_heads * c.head_dim, c.kv_heads * c.head_dim
             out[p + "attn.qkv.weight"] = (q + 2 * kv, hd)
             out[p + "attn.out.weight"] = (hd, q)
+        elif mixer == "mla":
+            nh, d_c, qk = c.num_heads, c.kv_latent_dim, c.nope_dim + c.rope_dim
+            if c.mla_q_rank:
+                out[p + "attn.q_a.weight"] = (c.mla_q_rank, hd)
+                out[p + "attn.q_a_norm.weight"] = (c.mla_q_rank,)
+            out[p + "attn.q_b.weight"] = (nh * qk, c.mla_q_rank or hd)
+            out[p + "attn.kv_a.weight"] = (d_c + c.rope_dim, hd)
+            out[p + "attn.kv_a_norm.weight"] = (d_c,)
+            out[p + "attn.k_up.weight"] = (nh, c.nope_dim, d_c)
+            out[p + "attn.v_up.weight"] = (nh, c.v_dim, d_c)
+            out[p + "attn.out.weight"] = (hd, nh * c.v_dim)
         else:
             out[p + "moe.router.weight"] = (c.num_experts, hd)
-            out[p + "moe.router.bias"] = (c.num_experts,)
+            if c.moe_router == "sigmoid_bias":
+                out[p + "moe.router.bias"] = (c.num_experts,)
             if c.moe_latent_dim:
                 out[p + "moe.latent_down.weight"] = (lat, hd)
                 out[p + "moe.latent_up.weight"] = (hd, lat)
             out[p + "moe.experts.w1"] = (c.held_experts, lat, c.moe_ffn_size)
             out[p + "moe.experts.w2"] = (c.held_experts, c.moe_ffn_size, lat)
+            if c.moe_gated:
+                out[p + "moe.experts.w3"] = out[p + "moe.experts.w1"]
             if c.moe_shared_ffn_size:
+                if c.moe_gated:
+                    out[p + "moe.shared.gate.weight"] = (
+                        c.moe_shared_ffn_size, hd)
                 out[p + "moe.shared.up.weight"] = (c.moe_shared_ffn_size, hd)
                 out[p + "moe.shared.down.weight"] = (hd,
                                                      c.moe_shared_ffn_size)
@@ -281,16 +412,75 @@ def mamba_gate_norm(cfg: GPTConfig, w: MambaWeights, y, z, dtype):
     return (v.reshape(n, -1) * w.norm.astype(F32)).astype(dtype)
 
 
+def _rms(cfg: GPTConfig, v, w):
+    f = v.astype(F32)
+    f = f * lax.rsqrt(jnp.mean(f * f, -1, keepdims=True) + norm_eps(cfg))
+    return (f * w.astype(F32)).astype(v.dtype)
+
+
+def mla_in(cfg: GPTConfig, params: dict, i: int, u):
+    """An mla layer's way in, token by token on ``u`` [n, H] (normed):
+    the low-rank q behind its norm, the normed latent and the raw rotary
+    key.  Returns ``(q [n, nh, nope + rope], c_kv [n, d_c], k_r [n, r])``."""
+    g = lambda n: params.get(f"h{i}.attn.{n}")  # noqa: E731
+    c_q = u
+    if g("q_a.weight") is not None:
+        c_q = _rms(cfg, u @ g("q_a.weight").T, g("q_a_norm.weight"))
+    q = (c_q @ g("q_b.weight").T).reshape(
+        u.shape[0], cfg.num_heads, cfg.nope_dim + cfg.rope_dim)
+    kv = u @ g("kv_a.weight").T
+    d_c = cfg.kv_latent_dim
+    return q, _rms(cfg, kv[:, :d_c], g("kv_a_norm.weight")), kv[:, d_c:]
+
+
+def mla_rotate(cfg: GPTConfig, x, cos_t, sin_t):
+    """The rotary stream of ``x [T, ..., r]`` at per-token tables ``cos_t,
+    sin_t [T, r]`` (``mla_rotary_tables`` gathered by position).  An
+    interleaved stream (pairs ``(2i, 2i + 1)``) is brought into ``[evens
+    | odds]`` and rotated by halves; it stays in that order — q and the
+    cached key take the same road, and only their product is read."""
+    if cfg.rope_interleave:
+        x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], -1)
+    half = x.shape[-1] // 2
+    rot = jnp.concatenate([-x[..., half:], x[..., :half]], -1)
+    shape = (x.shape[0],) + (1,) * (x.ndim - 2) + (x.shape[-1],)
+    f = x.astype(F32)
+    return (f * cos_t.reshape(shape) +
+            rot.astype(F32) * sin_t.reshape(shape)).astype(x.dtype)
+
+
+def mla_absorb_q(cfg: GPTConfig, params: dict, i: int, q, q_rot):
+    """``W_kvb``'s k-half folded into q: ``q [T, nh, nope + r]`` with its
+    rotary part already rotated as ``q_rot [T, nh, r]`` -> float32 ``[T,
+    nh, d_c + r]``, a query against the cached ``c_kv | k_r`` itself."""
+    k_up = params[f"h{i}.attn.k_up.weight"]
+    q_abs = jnp.einsum("thd,hdc->thc", q[..., :cfg.nope_dim].astype(F32),
+                       k_up.astype(F32))
+    return jnp.concatenate([q_abs, q_rot.astype(F32)], -1)
+
+
+def mla_absorb_out(cfg: GPTConfig, params: dict, i: int, o_lat, dtype):
+    """``W_kvb``'s v-half out of the latent output ``o_lat [T, nh, d_c]``
+    -> ``[T, nh * v]``: one up-projection a QUERY token, cached tokens
+    are never decompressed."""
+    v_up = params[f"h{i}.attn.v_up.weight"]
+    o = jnp.einsum("thc,hdc->thd", o_lat.astype(F32), v_up.astype(F32))
+    return o.reshape(o.shape[0], -1).astype(dtype)
+
+
 def moe_route(cfg: GPTConfig, w_router, bias, u):
     """Scores over ALL routed experts in float32 (the matmul too: a bf16
     pass would reorder near-ties).  ``sigmoid_bias``: top-k of sigmoid
     score + bias, the chosen scores renormalised and scaled; ``softmax``:
     top-k of the softmax, its values the weights (the plain block's
-    rule).  Returns ``(idx [T, k] int32, weights [T, k] float32)``."""
+    rule; ``moe_norm_topk`` renormalises them to sum 1).  Returns ``(idx
+    [T, k] int32, weights [T, k] float32)``."""
     logits = jnp.dot(u.astype(F32), w_router.astype(F32).T,
                      precision=lax.Precision.HIGHEST)
     if cfg.moe_router == "softmax":
         w, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.moe_top_k)
+        if cfg.moe_norm_topk:
+            w = w / (w.sum(-1, keepdims=True) + 1e-20)
         return idx, w * cfg.moe_router_scale
     s = jax.nn.sigmoid(logits)
     _, idx = lax.top_k(s + bias.astype(F32), cfg.moe_top_k)
@@ -324,6 +514,7 @@ def moe_routed(cfg: GPTConfig, params: dict, i: int, lat, idx, w, live):
         r, load = grouped_experts(
             lat, idx, w, live, params[f"h{i}.moe.experts.w1"],
             params[f"h{i}.moe.experts.w2"],
+            params.get(f"h{i}.moe.experts.w3"),
             expert_offset=cfg.expert_offset, activation=cfg.activation)
         return r.astype(lat.dtype), load
 
@@ -337,9 +528,11 @@ def moe_up_shared(cfg: GPTConfig, params: dict, i: int, u, r):
         out = r if up is None else r @ up.T
     s_up = g("shared.up.weight")
     if s_up is not None:
-        act = ACTIVATIONS[cfg.activation]
+        act, gate = ACTIVATIONS[cfg.activation], g("shared.gate.weight")
         with phase("moe_shared"):
-            out = out + act(u @ s_up.T) @ g("shared.down.weight").T
+            hid = act(u @ s_up.T) if gate is None else \
+                act(u @ gate.T) * (u @ s_up.T)
+            out = out + hid @ g("shared.down.weight").T
     return out
 
 

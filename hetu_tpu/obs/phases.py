@@ -33,11 +33,12 @@ __all__ = ["PHASES", "UNMAPPED", "phase", "current_phase",
 # hybrid stack's mixers (models/hybrid.py) add the state-space phases
 # (in / out projections, conv, scan + gate + group norm, moves of state
 # between the slot store and a row) and the expert layer's (router, latent
-# down / up, routed experts, shared expert)
+# down / up, routed experts, shared expert) and the latent attention's
+# two folds of W_kvb into q and out of the latent output (mla_absorb)
 PHASES = ("embed", "norm", "attn_proj", "attn_core", "mlp", "lm_head_ce",
           "optimizer", "grad_comm", "param_gather", "kv_scatter", "sample",
           "ssm_proj", "ssm_conv", "ssm_scan", "state_io",
-          "moe_router", "moe_latent", "moe_routed", "moe_shared")
+          "moe_router", "moe_latent", "moe_routed", "moe_shared", "mla_absorb")
 UNMAPPED = "unmapped"
 
 # scope names that predate the vocabulary (parallel/comm.py's comm_tag

@@ -189,7 +189,7 @@ def _split_latent_ragged_attention(cfg: GPTConfig, q_cat, cp, rp, q_lens,
                                    page_tables, ctx_lens, max_seqs: int,
                                    prefill_rows: int, chunk: int,
                                    spec_k: int = 0, scale_pages=None,
-                                   quant=None):
+                                   quant=None, scale=None, dims=None):
     """Latent (MLA) twin of :func:`_split_ragged_attention`: absorbed
     ``q_cat [T, nh, d_c+d_r]`` against the single latent stream ``cp``
     (+ optional rope stream ``rp`` / absmax sidecar ``scale_pages``),
@@ -198,13 +198,17 @@ def _split_latent_ragged_attention(cfg: GPTConfig, q_cat, cp, rp, q_lens,
     :func:`latent_paged_attention_reference` and chunk/verify slots run
     the same pow2 page-window ``lax.switch`` with ``-inf`` masking, so
     temp-0 latent serving stays bit-for-bit with the solo MLA oracle
-    (``models.generate._mla_attn_step``)."""
+    (``models.generate._mla_attn_step``).  ``scale`` is the softmax
+    scale where the model states one (default: the converted model's
+    ``(head_dim + d_r) ** -0.5``), ``dims`` the pool's ``(d_c, d_r)``
+    where they are not the config's two streams."""
     c = cfg
     hd, nh = c.head_dim, c.num_heads
-    d_c, d_r = c.kv_latent_dim, c.rope_dim
+    d_c, d_r = dims or (c.kv_latent_dim, c.rope_dim)
     maxp = page_tables.shape[1]
     ps = cp.shape[2]
-    scale = (hd + d_r) ** -0.5
+    if scale is None:
+        scale = (hd + d_r) ** -0.5
     outs = [latent_paged_attention_reference(
         q_cat[:max_seqs], cp, rp, page_tables[:max_seqs],
         jnp.maximum(ctx_lens[:max_seqs], 1), softmax_scale=scale,
@@ -379,12 +383,12 @@ def build_unified_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
     if c.is_hybrid:
         # one mixer per layer, recurrent state beside the pages: its own
         # layer loop and signature (below), the same layout and regions
-        if spec_k or page_quant is not None or c.is_mla:
+        if spec_k or page_quant is not None:
             raise ValueError(
                 "a hybrid stack (layer_pattern) is built without "
-                "speculative verify rows, page quantization and latent "
-                "K/V: a rejected draft would have to roll recurrent "
-                "state back")
+                "speculative verify rows and page quantization: a "
+                "rejected draft would have to roll recurrent state back, "
+                "and a rotary stream has no quantized page layout")
         return _build_hybrid_step_fn(c, max_seqs, chunk, prefill_rows,
                                      max_pages, page_size, use_kernel)
     if page_quant is not None and (not c.is_mla or c.rope_dim):
@@ -689,7 +693,13 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
                           use_kernel: bool):
     """The unified step of a hybrid stack (``cfg.layer_pattern``): the
     same token axis, regions and K/V write, one mixer per layer, and the
-    recurrent state carried beside the pages.
+    recurrent state carried beside the pages.  The pages belong to the
+    pattern's attention layers or to its latent-attention (``mla``)
+    layers (``cfg.paged_layers``; one pool holds one layout): the latter
+    write ``c_kv`` and ``k_r`` (zero lanes up to the pool's padded
+    width, ``cfg.latent_page_dims``: q's rotary part is padded alike, so
+    the scores are what they were) and attend them with ``W_kvb``
+    absorbed, decode rows and prefill chunk alike.
 
     fn(params, tokens, token_pos, token_page, token_off, q_lens, cu_q,
        page_tables, ctx_lens, temps, top_ps, top_ks, seeds,
@@ -719,7 +729,11 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
     slots = _chunk_slots(max_seqs, prefill_rows, chunk, 0)
     write_regions = tuple((row, n, width) for _, row, _, n, width
                           in _regions(max_seqs, prefill_rows, chunk, 0))
-    attn_of = {i: a for a, i in enumerate(c.layers_of("attention"))}
+    attn_of = {i: a for a, i in enumerate(c.paged_layers)}
+    if c.layers_of("mla"):
+        cos, sin, q_scale = hy.mla_rotary_tables(c, max_pages * page_size)
+        # the rotary stream's zero lanes, key and query alike
+        rope_pad = ((0, 0), (0, c.latent_page_dims[1] - c.rope_dim))
     mamba_of = {i: m for m, i in enumerate(c.layers_of("mamba2"))}
 
     def by_region(f, h, q_lens):
@@ -769,6 +783,16 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
         new_k, new_v = list(k_pages), list(v_pages)
         new_conv, new_ssm = list(conv_states), list(ssm_states)
         loads = []
+
+        def write_pages(a, news):
+            """This step's new rows into paged layer ``a``'s two pools."""
+            pools = (k_pages[a], v_pages[a])
+            with phase("kv_scatter"):
+                if use_kernel:
+                    return paged_kv_write(pools, news, plan, tile=tile)
+                return paged_kv_write_reference(pools, news, token_page,
+                                                token_off)
+
         for i, mixer in enumerate(c.layer_pattern):
             with phase("norm"):
                 h = _norm_apply(c, p.layer(i, "norm.weight"), None, x)
@@ -784,16 +808,7 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
                     k = qkv[..., q_size:q_size + kv_size].reshape(
                         t_tokens, nkv, hd)
                     v = qkv[..., q_size + kv_size:].reshape(t_tokens, nkv, hd)
-                with phase("kv_scatter"):
-                    if use_kernel:
-                        kp, vp = paged_kv_write(
-                            (k_pages[a], v_pages[a]),
-                            (k.astype(cdt), v.astype(cdt)), plan, tile=tile)
-                    else:
-                        kp, vp = paged_kv_write_reference(
-                            (k_pages[a], v_pages[a]),
-                            (k.astype(cdt), v.astype(cdt)), token_page,
-                            token_off)
+                kp, vp = write_pages(a, (k.astype(cdt), v.astype(cdt)))
                 with phase("attn_core"):
                     if use_kernel:
                         attn = _attend_by_region(
@@ -807,6 +822,50 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
                             c, q, kp, vp, q_lens, page_tables, ctx_lens,
                             max_seqs, prefill_rows, chunk)
                     attn = attn.reshape(t_tokens, nh * hd).astype(x.dtype)
+                with phase("attn_proj"):
+                    out = by_region(
+                        lambda aa, i=i: aa @ p.layer(i, "attn.out.weight").T,
+                        attn, q_lens)
+                new_k[a], new_v[a] = kp, vp
+            elif mixer == "mla":
+                a = attn_of[i]
+                with phase("attn_proj"):
+                    q, c_kv, k_r = by_region(
+                        lambda hh, i=i: hy.mla_in(c, params, i, hh),
+                        h, q_lens)
+                with phase("attn_core"):
+                    cos_t, sin_t = cos[token_pos], sin[token_pos]
+                    if c.q_pos_scale:
+                        q = q * q_scale[token_pos][:, None, None].astype(
+                            q.dtype)
+                    q_rot = hy.mla_rotate(c, q[..., c.nope_dim:], cos_t,
+                                          sin_t)
+                    k_rot = hy.mla_rotate(c, k_r, cos_t, sin_t)
+                with phase("mla_absorb"):
+                    q_cat = hy.mla_absorb_q(c, params, i, q, q_rot)
+                kp, vp = write_pages(a, (
+                    c_kv.astype(cdt)[:, None],
+                    jnp.pad(k_rot.astype(cdt), rope_pad)[:, None]))
+                with phase("attn_core"):
+                    q_cat = jnp.pad(q_cat, ((0, 0),) + rope_pad)
+                    if use_kernel:
+                        o_lat = _attend_by_region(
+                            functools.partial(
+                                latent_ragged_paged_attention_pallas,
+                                c_pages=kp, r_pages=vp,
+                                softmax_scale=c.mla_softmax_scale,
+                                latent_dim=c.kv_latent_dim),
+                            "latent_ragged_paged_attention", q_cat, q_lens,
+                            cu_q, page_tables, ctx_lens, max_seqs,
+                            prefill_rows, chunk, 0)
+                    else:
+                        o_lat = _split_latent_ragged_attention(
+                            c, q_cat, kp, vp, q_lens, page_tables, ctx_lens,
+                            max_seqs, prefill_rows, chunk,
+                            scale=c.mla_softmax_scale,
+                            dims=c.latent_page_dims)
+                with phase("mla_absorb"):
+                    attn = hy.mla_absorb_out(c, params, i, o_lat, x.dtype)
                 with phase("attn_proj"):
                     out = by_region(
                         lambda aa, i=i: aa @ p.layer(i, "attn.out.weight").T,

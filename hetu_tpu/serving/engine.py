@@ -183,13 +183,12 @@ class Engine:
                     "speculative decoding is not built for a stack with "
                     "recurrent (mamba2) layers: a rejected draft cannot "
                     "be rolled back out of the state")
-        self.pool = PagedKVPool(len(cfg.layers_of("attention")),
+        latent_dim, rope_dim = cfg.latent_page_dims
+        self.pool = PagedKVPool(len(cfg.paged_layers),
                                 num_pages, page_size,
                                 cfg.kv_heads, cfg.head_dim, dtype,
                                 mesh=mesh, debug=debug,
-                                latent_dim=cfg.kv_latent_dim,
-                                rope_dim=cfg.rope_dim if cfg.is_mla
-                                else 0,
+                                latent_dim=latent_dim, rope_dim=rope_dim,
                                 quant=page_quant)
         self.state_store: Optional[StateSlotStore] = None
         if cfg.layers_of("mamba2"):
@@ -267,7 +266,12 @@ class Engine:
                           # expert kernel computed for the local ones
                           # (each expert's group padded to whole blocks)
                           "state_slot_allocs", "moe_assignments_local",
-                          "moe_assignments_total", "moe_block_rows")}
+                          "moe_assignments_total", "moe_block_rows",
+                          # latent (mla) layers of a hybrid stack: pages
+                          # the rows attended, counted per row / once
+                          # where several rows read one physical page
+                          "latent_pages_attended",
+                          "latent_pages_attended_distinct")}
         self.gauges = {k: make_instrument("gauge", k, m) for k in
                        ("batch_occupancy", "page_utilization",
                         "queue_depth",
@@ -969,6 +973,8 @@ class Engine:
         self.pool.set_pages(new_k, new_v)
         hybrid_attrs = self._commit_hybrid(new_conv, new_ssm, moe_load,
                                            kv_tokens) if self.hybrid else {}
+        if self.hybrid and self.pool.is_latent:
+            hybrid_attrs.update(self._latent_reads(rows, page_tables))
         self._calls += 1
         self.counters["step_calls"].inc()
         self.counters["kv_tokens_written"].inc(kv_tokens)
@@ -1016,6 +1022,28 @@ class Engine:
                 self._observe_token(req, row < s, dt)
                 self._maybe_finish(req)
         return produced
+
+    def _latent_reads(self, rows, page_tables) -> Dict[str, Any]:
+        """What the step's rows read of the latent page pool, as the
+        ``unified_step`` span's attributes: ``latent_ctx_tokens`` (sum of
+        the live rows' contexts), ``latent_pages`` (pages attended,
+        counted per row), ``latent_pages_distinct`` (distinct physical
+        pages among them: rows on one cached document share its pages)
+        and ``attn_pairs`` ((query, key) pairs inside the causal mask);
+        one layer's, every mla layer reads the same."""
+        ps = self.pool.page_size
+        ctx = [req.pos + q for req, q, _ in rows]
+        held = [page_tables[row, :-(-c // ps)]
+                for (_, _, row), c in zip(rows, ctx)]
+        pages = sum(len(h) for h in held)
+        distinct = len(np.unique(np.concatenate(held))) if held else 0
+        self.counters["latent_pages_attended"].inc(pages)
+        self.counters["latent_pages_attended_distinct"].inc(distinct)
+        return dict(
+            latent_ctx_tokens=sum(ctx), latent_pages=pages,
+            latent_pages_distinct=distinct,
+            attn_pairs=sum(q * c - q * (q - 1) // 2
+                           for (_, q, _), c in zip(rows, ctx)))
 
     def _commit_hybrid(self, new_conv, new_ssm, moe_load,
                        live_tokens: int) -> Dict[str, Any]:

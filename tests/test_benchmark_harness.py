@@ -164,16 +164,18 @@ def test_new_layer_metric_names_a_reader_and_a_cell(name):
     assert set(entry["workloads"]) <= set(moved["workloads"])
     suffix = name.rsplit(".", 1)[1]
     # a suffix names the cells of one traffic family; a metric lists
-    # those of them its reader finds something to read in (the hybrid
+    # those of them its reader finds something to read in (a drawn
     # configuration's own metrics list its cell alone)
+    own = {"nemotron3s-ep4.serve-chat", "mistral4-ep4.serve-longdoc"}
     family = {"chat": {"cgpt590m.serve-chat", "nemotron3s-ep4.serve-chat"},
-              "replay": {"cgpt590m.serve-prefix"},
+              "replay": {"cgpt590m.serve-prefix",
+                         "mistral4-ep4.serve-longdoc"},
               "train": {"cgpt590m.train", "cgpt1.3b.train-dp4z3"}}[suffix]
     assert set(entry["workloads"]) <= family
-    if spec["reader"] not in ("trace_phase_sum", "span_work_roofline") and \
-            not name.startswith("moe_"):
-        assert family - {"nemotron3s-ep4.serve-chat"} \
-            <= set(entry["workloads"])
+    if spec["reader"] not in ("trace_phase_sum", "span_work_roofline",
+                              "span_work_share", "engine_counter_rest") \
+            and not name.startswith("moe_"):
+        assert family - own <= set(entry["workloads"])
     if spec["reader"] == "trace_idle_by_phase":
         assert spec["args"]["per_span"] in ("engine.step", "g.run")
         assert isinstance(spec["args"]["phases"], list)

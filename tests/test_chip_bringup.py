@@ -106,6 +106,27 @@ def _kernel_cases():
         return mg.grouped_experts(x, idx, w, live, w1, w2,
                                   expert_offset=128, interpret=False)
 
+    def grouped_gated(x, idx, w, live, w1, w2, w3):
+        # an expert layer of the latent-attention configuration's step: 32
+        # decode slots + one 256-token chunk, top-4, 32 gated experts of
+        # 4096 x 2048 held from offset 0: walked in four tiles of 512
+        return mg.grouped_experts(x, idx, w, live, w1, w2, w3,
+                                  activation="silu", interpret=False)
+
+    def latent_region(rows, max_q):
+        # one region of that step's latent attention: 32 heads on a 256 |
+        # 64 cache (the rotary stream padded to the 128 lanes), 272 pages
+        # a row (17,408 tokens)
+        def run(q, cp, rp, *d):
+            return rpa.latent_ragged_paged_attention_pallas(
+                q, cp, rp, *d, max_q=max_q, softmax_scale=0.19,
+                latent_dim=256, interpret=False)
+        return (run, (
+            _sds((rows * max_q, 32, 384), F32),
+            _sds((PAGES, 1, PAGE, 256), BF16), _sds((PAGES, 1, PAGE, 128), BF16),
+            _sds((rows,), I32), _sds((rows + 1,), I32),
+            _sds((rows, 272), I32), _sds((rows,), I32)))
+
     def flash_qkv_grad(x):
         return jax.grad(lambda x: fa.flash_attention_qkv(
             x, 12, causal=True).astype(F32).sum())(x)
@@ -142,10 +163,18 @@ def _kernel_cases():
         "kv_write_12kv_x128": kv_write((12, 128, BF16), (12, 128, BF16)),
         "kv_write_latent_512_64": kv_write((1, 512, BF16), (1, 64, BF16)),
         "kv_write_int8_sidecar": kv_write((1, 512, jnp.int8), (1, 1, F32)),
+        "kv_write_latent_256_128": kv_write((1, 256, BF16), (1, 128, BF16)),
+        "latent_256_128_decode_region": latent_region(32, 1),
+        "latent_256_128_chunk_region": latent_region(1, CHUNK),
         "moe_grouped_experts": (grouped_experts, (
             _sds((320, 1024), BF16), _sds((320, 22), I32),
             _sds((320, 22), F32), _sds((320,), jnp.bool_),
             _sds((128, 1024, 2688), BF16), _sds((128, 2688, 1024), BF16))),
+        "moe_grouped_gated_tiled": (grouped_gated, (
+            _sds((288, 4096), BF16), _sds((288, 4), I32),
+            _sds((288, 4), F32), _sds((288,), jnp.bool_),
+            _sds((32, 4096, 2048), BF16), _sds((32, 2048, 4096), BF16),
+            _sds((32, 4096, 2048), BF16))),
     }
 
 
@@ -156,11 +185,15 @@ def _kernel_cases():
 # tile is the layout most likely to be refused; of the KV write, the
 # benchmark's pool and the one written in whole one-lane pages; of flash,
 # the train cell's call: three lane blocks of the fused [b, s, 3*h*d]; the
-# grouped experts: two whole expert matrices double-buffered (33 MB of VMEM)
+# grouped experts: two whole expert matrices double-buffered (33 MB of VMEM),
+# and the gated ones in tiles (three matrices of 4096 x 512, 25 MB); the
+# latent chunk region at 32 heads: q and output blocks over the whole
+# padded token axis in float32 (25 + 17 MB, twice)
 AOT_CASES = ("ragged_12kv_x64", "ragged_decode_region",
              "ragged_decode_gqa16", "latent_512_64",
              "latent_nf4", "kv_write_12kv_x128", "kv_write_int8_sidecar",
-             "flash_qkv", "moe_grouped_experts")
+             "flash_qkv", "moe_grouped_experts", "moe_grouped_gated_tiled",
+             "latent_256_128_chunk_region")
 
 
 @pytest.fixture
