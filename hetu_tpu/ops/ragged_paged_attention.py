@@ -74,7 +74,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .paged_attention import (DEFAULT_MASK_VALUE, LANES, SUBLANES,
-                              gather_pages, vmem_params)
+                              _tiled_bytes, gather_pages, vmem_params)
 from .pallas import on_tpu
 
 
@@ -351,6 +351,19 @@ def ragged_paged_attention(q: jax.Array, k_pages: jax.Array,
 # dot products in latent space and the attention output STAYS latent
 # (``[*, nh, d_c]``); the caller applies the ``v_up`` fold per query
 # token.  No cached token is ever decompressed.
+#
+# A grid step of the latent kernel covers a GROUP of ``K`` consecutive
+# page-table slots of its row (grid ``(S, ceil(maxp / K))``) and runs the
+# online softmax once over the group's ``K * ps`` columns: at one page a
+# step the call was bound by the number of grid steps that run (~0.5 us
+# each whatever they fetch), and a 64-token page fills half the MXU.  ``K``
+# comes from :func:`latent_pages_per_grid_step` — the call's own shapes
+# (``max_q``, heads, ``maxp``, page bytes) against a VMEM budget, so the
+# serving step's decode region (``max_q`` 1) takes 32 pages and its
+# 256-token chunk region 8 — and the engine's ``latent_grid_steps``
+# counter reads the same function.  A stream whose rows fill the lanes
+# stays in HBM and reaches VMEM by the kernel's own double-buffered DMAs,
+# one a page; a narrower one through ``K`` page-table-indexed blocks.
 
 
 def _dequant_latent(codes, scales, quant, latent_dim):
@@ -493,45 +506,176 @@ def _decode4(idx, code):
     return c
 
 
-def _make_latent_kernel(scale: float, ps: int, maxp: int, max_q: int,
-                        gp: int, d_c: int, quant: Optional[str],
-                        has_rope: bool, has_scales: bool, code=None):
-    """Latent twin of :func:`_ragged_kernel`: grid ``(S, maxp)`` (one
-    shared KV stream, so no kv-head grid dim), q/out blocks span the
-    padded token axis, c/r/scale blocks are one physical page each via
-    the prefetched page table; online softmax in VMEM scratch.  ``code``
-    is the 4-bit codebook as Python floats (packed pages only)."""
+# the widest group of page-table slots one step of the latent call walks
+# (Step 0 of PR 36, PERF.md section 6: a 32-row decode call at 263 pages
+# a row takes 4.17 / 0.97 / 0.72 / 0.63 ms at 1 / 8 / 16 / 32 on a v5e),
+# and the VMEM one group's page buffers and float32 temporaries may take
+# (a 256-token chunk of 32 heads fills it at 8 pages: 6.27 -> 1.32 ms)
+LATENT_GROUP_MAX = 32
+LATENT_GROUP_VMEM = 40 << 20
 
-    def kernel(ql_ref, cu_ref, pt_ref, cl_ref, q_ref, c_ref, *rest):
-        n = 0
-        r_ref = rest[n] if has_rope else None
-        n += int(has_rope)
-        s_ref = rest[n] if has_scales else None
-        n += int(has_scales)
-        o_ref, m_scr, l_scr, acc_scr = rest[n:n + 4]
+
+def _latent_head_rows(heads: int) -> int:
+    """Tile rows a query token takes: its heads, to whole sublanes."""
+    return max(SUBLANES, -(-heads // SUBLANES) * SUBLANES)
+
+
+def _latent_group_vmem(pages: int, max_q: int, heads: int, q_width: int,
+                       streams):
+    """``(shape, dtype)`` entries, as :func:`vmem_params` takes them, of
+    what a group of ``pages`` pages holds in VMEM beside the q / out
+    blocks and the softmax state: both buffers of its pages in every
+    stream (``[P, 1, ps, w]`` arrays or their shapes) and the float32
+    temporaries — the upcast pages, the score tile and ``p``."""
+    toks = pages * streams[0].shape[2]
+    tile = (max_q * _latent_head_rows(heads), toks)
+    return [((2 * toks, a.shape[-1]), a.dtype) for a in streams] \
+        + [((toks, q_width), jnp.float32), (tile, jnp.float32),
+           (tile, jnp.float32)]
+
+
+def latent_pages_per_grid_step(max_q: int, heads: int, q_width: int,
+                               maxp: int, streams) -> int:
+    """``K``: how many consecutive page-table slots of its row ONE grid
+    step of the latent call covers — the one rule, read by the kernel
+    wrapper and by ``Engine`` (the ``latent_grid_steps`` counter), from
+    what both can see: the call's ``max_q``, the query heads, the
+    absorbed q's width, the page table's width and the pool's page
+    streams (``[P, 1, ps, w]`` arrays or their shapes).
+
+    A step costs ~0.5 us whatever it fetches (DMA issue and wait, one
+    rescale of the accumulator), and a page of 64 tokens fills half the
+    MXU's width (the score tile's columns) and half its depth (the PV
+    product's contraction), so every call takes the widest group it can
+    hold: the largest power of two, at most ``LATENT_GROUP_MAX`` and
+    ``maxp``, whose page buffers and float32 temporaries
+    (:func:`_latent_group_vmem`) fit ``LATENT_GROUP_VMEM``.  A decode
+    region's tile is ``heads`` rows and takes the cap; a prefill chunk's
+    score tile (``max_q * heads`` rows, thousands) is the budget after a
+    few pages."""
+    def fits(k):
+        return sum(_tiled_bytes(s, d) for s, d in _latent_group_vmem(
+            k, max_q, heads, q_width, streams)) <= LATENT_GROUP_VMEM
+
+    k = 1
+    while 2 * k <= min(LATENT_GROUP_MAX, maxp) and fits(2 * k):
+        k *= 2
+    return k
+
+
+def _make_latent_kernel(scale: float, ps: int, pages: int, groups: int,
+                        s_rows: int, max_q: int, gp: int, d_c: int,
+                        quant: Optional[str], has_rope: bool,
+                        has_scales: bool, by_dma, code=None):
+    """Latent twin of :func:`_ragged_kernel`: grid ``(S, groups)`` (one
+    shared KV stream, so no kv-head grid dim), q/out blocks span the
+    padded token axis.  A grid step covers a GROUP of ``pages``
+    consecutive page-table slots of its row and runs the online softmax
+    ONCE over the group's ``pages * ps`` columns (state in VMEM
+    scratch).  The page streams are c, then r and the scale sidecar
+    where the pool has them; ``by_dma[x]`` says how stream ``x``'s pages
+    reach VMEM:
+
+    - True (rows that fill the 128 lanes): the stream stays in HBM and
+      each page of the group is copied into one of two VMEM buffers by
+      its own DMA driven from the prefetched page table; a running step
+      starts the copies of the NEXT running step (the row's next group,
+      or the next row's first) before it waits for its own, so they are
+      in flight while this group is computed;
+    - False (Mosaic cannot slice an HBM array whose rows are padded to
+      the lanes): ``pages`` page-table-indexed blocks of the stream, one
+      a slot, fetched by the grid's own pipeline.
+
+    ``code`` is the 4-bit codebook as Python floats (packed pages
+    only)."""
+    cols_g = pages * ps
+    mqg = max_q * gp
+    n_dma = sum(by_dma)
+
+    def kernel(ql_ref, cu_ref, pt_ref, cl_ref, q_ref, *rest):
+        srcs = []                    # a stream: its HBM ref or its blocks
+        for dma in by_dma:
+            n = 1 if dma else pages
+            srcs.append(rest[0] if dma else rest[:n])
+            rest = rest[n:]
+        o_ref, m_scr, l_scr, acc_scr = rest[:4]
+        sem, count = rest[4 + n_dma:]
+        buf_of = dict(zip([x for x, dma in enumerate(by_dma) if dma],
+                          rest[4:4 + n_dma]))
+        dmas = [(srcs[x], buf) for x, buf in buf_of.items()]
         i = pl.program_id(0)
-        p = pl.program_id(1)
+        g = pl.program_id(1)
         qlen = ql_ref[i]
         start = cu_ref[i]
         ctx = cl_ref[i]
-        mqg = max_q * gp
 
-        @pl.when(p == 0)
+        def live_groups(row):        # 0 for a padding row
+            return jnp.where(ql_ref[row] > 0,
+                             (cl_ref[row] + cols_g - 1) // cols_g, 0)
+
+        n_g = live_groups(i)
+
+        def copies(row, grp, slot):
+            """One DMA a page a stream of group ``grp`` of ``row`` into
+            buffer ``slot`` (``row`` None: the same shapes from page 0,
+            which is all a wait reads of its descriptor)."""
+            return [pltpu.make_async_copy(
+                src.at[0 if row is None else pt_ref[row, grp * pages + j],
+                       0],
+                dst.at[slot, pl.ds(j * ps, ps)], sem.at[slot])
+                for j in range(pages) for src, dst in dmas]
+
+        @pl.when(jnp.logical_and(i == 0, g == 0))
+        def _first():
+            count[0] = 0             # running steps so far: buffer parity
+
+        @pl.when(g == 0)
         def _init():
             m_scr[...] = jnp.full_like(m_scr, DEFAULT_MASK_VALUE)
             l_scr[...] = jnp.zeros_like(l_scr)
             acc_scr[...] = jnp.zeros_like(acc_scr)
 
-        @pl.when(jnp.logical_and(qlen > 0, p * ps < ctx))
-        def _page():
+        @pl.when(g < n_g)
+        def _group():
+            slot = count[0] % 2
+            if dmas:
+                nxt = jnp.minimum(i + 1, s_rows - 1)
+                next_live = jnp.logical_and(i + 1 < s_rows,
+                                            live_groups(nxt) > 0)
+                prev_live = jnp.logical_and(
+                    i > 0, live_groups(jnp.maximum(i - 1, 0)) > 0)
+                more = g + 1 < n_g
+
+                @pl.when(jnp.logical_and(g == 0,
+                                         jnp.logical_not(prev_live)))
+                def _cold():         # no step before this one fetched it
+                    for cp in copies(i, 0, slot):
+                        cp.start()
+
+                @pl.when(jnp.logical_or(more, next_live))
+                def _prefetch():
+                    for cp in copies(jnp.where(more, i, nxt),
+                                     jnp.where(more, g + 1, 0), 1 - slot):
+                        cp.start()
+
+                for cp in copies(None, 0, slot):
+                    cp.wait()
+                count[0] = count[0] + 1
+
+            def stream(x):           # [cols_g, w], position order
+                if by_dma[x]:
+                    return buf_of[x][slot]
+                return jnp.concatenate([r[0, 0] for r in srcs[x]], 0)
+
             q = q_ref[pl.ds(start, max_q)].astype(jnp.float32)
             q2 = q.reshape(mqg, q.shape[-1])           # [mqg, d_c+d_r]
-            raw = c_ref[0, 0]                          # [ps, w]
+            raw = stream(0)                            # [cols_g, w]
+            rope = stream(1) if has_rope else None
             if quant is None:
                 c = raw.astype(jnp.float32)
             else:
-                sc = s_ref[0, 0].astype(jnp.float32)           # [ps, 1]
-                sc = jnp.where(sc > 0, sc, 1.0)
+                sc = stream(len(by_dma) - 1).astype(jnp.float32)
+                sc = jnp.where(sc > 0, sc, 1.0)                # [.., 1]
                 if quant == "int8":
                     c = raw.astype(jnp.float32) / 127.0 * sc
                 else:                  # packed 4-bit, _nibble_order
@@ -540,15 +684,18 @@ def _make_latent_kernel(scale: float, ps: int, maxp: int, max_q: int,
                                          _decode4(raw & 0xF, code)],
                                         -1) * sc
             if has_rope:
-                k = jnp.concatenate(
-                    [c, r_ref[0, 0].astype(jnp.float32)], -1)
+                k = jnp.concatenate([c, rope.astype(jnp.float32)], -1)
             else:
                 k = c
             s = lax.dot_general(q2, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-            row_q = lax.broadcasted_iota(jnp.int32, (mqg, ps), 0) // gp
-            cols = p * ps + lax.broadcasted_iota(jnp.int32, (mqg, ps), 1)
+            row_q = lax.broadcasted_iota(jnp.int32, (mqg, cols_g),
+                                         0) // gp
+            cols = g * cols_g + lax.broadcasted_iota(
+                jnp.int32, (mqg, cols_g), 1)
             qpos = (ctx - qlen) + row_q
+            # the slots of the row's last group past its context hold
+            # the trash page: masked like the tail of its last page
             s = jnp.where(cols <= qpos, s, DEFAULT_MASK_VALUE)
             m_prev = m_scr[:, 0]
             m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
@@ -561,7 +708,7 @@ def _make_latent_kernel(scale: float, ps: int, maxp: int, max_q: int,
             m_scr[...] = jnp.broadcast_to(m_cur[:, None], m_scr.shape)
             l_scr[...] = jnp.broadcast_to(l_cur[:, None], l_scr.shape)
 
-        @pl.when(p == maxp - 1)
+        @pl.when(g == groups - 1)
         def _finalize():
             l = l_scr[:, 0]
             l = jnp.where(l == 0.0, 1.0, l)
@@ -575,7 +722,8 @@ def _make_latent_kernel(scale: float, ps: int, maxp: int, max_q: int,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "max_q", "softmax_scale", "quant", "latent_dim", "interpret", "name"))
+    "max_q", "softmax_scale", "quant", "latent_dim", "interpret", "name",
+    "pages_per_step"))
 def latent_ragged_paged_attention_pallas(
         q: jax.Array, c_pages: jax.Array, r_pages: Optional[jax.Array],
         q_lens: jax.Array, cu_q: jax.Array, page_tables: jax.Array,
@@ -583,10 +731,15 @@ def latent_ragged_paged_attention_pallas(
         scale_pages: Optional[jax.Array] = None,
         quant: Optional[str] = None, latent_dim: Optional[int] = None,
         interpret: Optional[bool] = None,
-        name: str = "latent_ragged_paged_attention") -> jax.Array:
+        name: str = "latent_ragged_paged_attention",
+        pages_per_step: Optional[int] = None) -> jax.Array:
     """Pallas latent ragged paged attention (same contract as
     :func:`latent_ragged_paged_attention_reference`; ``name`` is the
-    Mosaic call's name on the device trace)."""
+    Mosaic call's name on the device trace).  A grid step covers
+    :func:`latent_pages_per_grid_step` page-table slots of its row;
+    ``pages_per_step`` overrides the rule for the kernel's own tests and
+    timings only (no serving code passes it: the engine's
+    ``latent_grid_steps`` counter reads the rule)."""
     nh, ps, d_c, d_r = _check_latent_shapes(q, c_pages, r_pages, quant,
                                             latent_dim)
     t = q.shape[0]
@@ -594,11 +747,19 @@ def latent_ragged_paged_attention_pallas(
     maxp = page_tables.shape[1]
     if interpret is None:
         interpret = not on_tpu()
-    gp = max(SUBLANES, ((nh + SUBLANES - 1) // SUBLANES) * SUBLANES)
+    gp = _latent_head_rows(nh)
     t_pad = t + max_q
     has_rope, has_scales = d_r > 0, scale_pages is not None
     if quant is not None and not has_scales:
         raise ValueError("quantized latent pages need scale_pages")
+    streams = [c_pages] + ([r_pages] if has_rope else []) \
+        + ([scale_pages] if has_scales else [])
+    kpg = pages_per_step or latent_pages_per_grid_step(
+        max_q, nh, d_c + d_r, maxp, streams)
+    groups = -(-maxp // kpg)
+    # a table the group does not divide ends in the trash page's slots
+    page_tables = jnp.pad(page_tables.astype(jnp.int32),
+                          ((0, 0), (0, groups * kpg - maxp)))
     code = order = None
     if quant in ("nf4", "fp4"):
         from .quantization import _CODES
@@ -606,37 +767,34 @@ def latent_ragged_paged_attention_pallas(
         order = _nibble_order(d_c)
         q = jnp.concatenate([q[..., :d_c][..., order], q[..., d_c:]], -1)
     qg = jnp.pad(q, ((0, max_q), (0, gp - nh), (0, 0)))
-    kernel = _make_latent_kernel(float(softmax_scale), ps, maxp,
-                                 int(max_q), gp, d_c, quant, has_rope,
-                                 has_scales, code)
-    in_specs = [
-        pl.BlockSpec((t_pad, gp, d_c + d_r),
-                     lambda i, p, ql, cu, pt, cl: (0, 0, 0)),
-        pl.BlockSpec((1, 1, ps, c_pages.shape[-1]),
-                     lambda i, p, ql, cu, pt, cl: (pt[i, p], 0, 0, 0)),
-    ]
-    operands = [qg, c_pages]
-    if has_rope:
-        in_specs.append(pl.BlockSpec(
-            (1, 1, ps, d_r),
-            lambda i, p, ql, cu, pt, cl: (pt[i, p], 0, 0, 0)))
-        operands.append(r_pages)
-    if has_scales:
-        in_specs.append(pl.BlockSpec(
-            (1, 1, ps, 1),
-            lambda i, p, ql, cu, pt, cl: (pt[i, p], 0, 0, 0)))
-        operands.append(scale_pages)
+    by_dma = tuple(a.shape[-1] % LANES == 0 for a in streams)
+    kernel = _make_latent_kernel(float(softmax_scale), ps, kpg, groups,
+                                 s_rows, int(max_q), gp, d_c, quant,
+                                 has_rope, has_scales, by_dma, code)
+
+    def slot(j):                     # slot j of the grid step's group
+        return lambda i, g, ql, cu, pt, cl: (pt[i, g * kpg + j], 0, 0, 0)
+
+    in_specs = [pl.BlockSpec((t_pad, gp, d_c + d_r),
+                             lambda i, g, ql, cu, pt, cl: (0, 0, 0))]
+    operands = [qg]
+    for a, dma in zip(streams, by_dma):
+        in_specs += [pl.BlockSpec(memory_space=pl.ANY)] if dma else [
+            pl.BlockSpec((1, 1, ps, a.shape[-1]), slot(j))
+            for j in range(kpg)]
+        operands += [a] * (1 if dma else kpg)
+    state = [((max_q * gp, LANES), jnp.float32)] * 2 \
+        + [((max_q * gp, d_c), jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(s_rows, maxp),
+        grid=(s_rows, groups),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((t_pad, gp, d_c),
-                               lambda i, p, ql, cu, pt, cl: (0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((max_q * gp, LANES), jnp.float32),
-            pltpu.VMEM((max_q * gp, LANES), jnp.float32),
-            pltpu.VMEM((max_q * gp, d_c), jnp.float32),
-        ],
+                               lambda i, g, ql, cu, pt, cl: (0, 0, 0)),
+        scratch_shapes=[pltpu.VMEM(s, d) for s, d in state]
+        + [pltpu.VMEM((2, kpg * ps, a.shape[-1]), a.dtype)
+           for a, dma in zip(streams, by_dma) if dma]
+        + [pltpu.SemaphoreType.DMA((2,)), pltpu.SMEM((1,), jnp.int32)],
     )
     with jax.named_scope(name):
         out = pl.pallas_call(
@@ -645,15 +803,13 @@ def latent_ragged_paged_attention_pallas(
             out_shape=jax.ShapeDtypeStruct((t_pad, gp, d_c), jnp.float32),
             compiler_params=vmem_params(
                 blocks=[((t_pad, gp, d_c + d_r), q.dtype),
-                        ((t_pad, gp, d_c), jnp.float32)]
-                + [(o.shape[2:], o.dtype) for o in operands[1:]],
-                scratch=[((max_q * gp, LANES), jnp.float32)] * 2
-                + [((max_q * gp, d_c), jnp.float32)]),
+                        ((t_pad, gp, d_c), jnp.float32)],
+                scratch=state + _latent_group_vmem(
+                    kpg, max_q, nh, d_c + d_r, streams)),
             interpret=interpret,
             name=name,
-        )(q_lens.astype(jnp.int32), cu_q.astype(jnp.int32),
-          page_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
-          *operands)
+        )(q_lens.astype(jnp.int32), cu_q.astype(jnp.int32), page_tables,
+          ctx_lens.astype(jnp.int32), *operands)
     out = out[:t, :nh, :]
     return out if order is None else out[..., np.argsort(order)]
 

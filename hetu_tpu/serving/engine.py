@@ -91,8 +91,9 @@ from ..models.gpt import GPTConfig
 from ..obs.tracer import get_tracer
 from ..ops.moe_grouped import ROW_BLOCK, block_rows
 from ..ops.pallas import on_tpu
+from ..ops.ragged_paged_attention import latent_pages_per_grid_step
 from ..utils.metrics import make_instrument, render_prometheus
-from .decode import build_unified_step_fn
+from .decode import _regions, build_unified_step_fn
 from .kv_pool import (TRASH_PAGE, PagedKVPool, StateSlotStore,
                       protocol_seq)
 from .prefix_cache import PrefixCache
@@ -271,7 +272,8 @@ class Engine:
                           # the rows attended, counted per row / once
                           # where several rows read one physical page
                           "latent_pages_attended",
-                          "latent_pages_attended_distinct")}
+                          "latent_pages_attended_distinct",
+                          "latent_grid_steps")}
         self.gauges = {k: make_instrument("gauge", k, m) for k in
                        ("batch_occupancy", "page_utilization",
                         "queue_depth",
@@ -363,6 +365,18 @@ class Engine:
                                  base + vk * np.arange(vr + 1,
                                                        dtype=np.int32)])
         self._cu_q = cu                       # [rows + 1], layout-fixed
+        # pages a grid step of the latent call covers, by row slot: each
+        # region's call takes its group from its own width (the kernel
+        # wrapper reads the same rule from the same shapes)
+        self._latent_group = None
+        if self.hybrid and self.pool.is_latent:
+            self._latent_group = np.ones(self.n_rows, np.int64)
+            for _, row, _, n, width in _regions(s, r, ck, self.spec_k):
+                self._latent_group[row: row + n] = \
+                    latent_pages_per_grid_step(
+                        width, cfg.num_heads, sum(cfg.latent_page_dims),
+                        self.max_pages_per_seq,
+                        (self.pool.k_pages[0], self.pool.v_pages[0]))
         self._register_for_analysis()
 
     # -- submission ----------------------------------------------------------
@@ -1028,20 +1042,25 @@ class Engine:
         ``unified_step`` span's attributes: ``latent_ctx_tokens`` (sum of
         the live rows' contexts), ``latent_pages`` (pages attended,
         counted per row), ``latent_pages_distinct`` (distinct physical
-        pages among them: rows on one cached document share its pages)
-        and ``attn_pairs`` ((query, key) pairs inside the causal mask);
-        one layer's, every mla layer reads the same."""
+        pages among them: rows on one cached document share its pages),
+        ``latent_grid_steps`` (grid steps of the latent calls that run:
+        a row's pages over the group its region's call walks a step,
+        rounded up) and ``attn_pairs`` ((query, key) pairs inside the
+        causal mask); one layer's, every mla layer reads the same."""
         ps = self.pool.page_size
         ctx = [req.pos + q for req, q, _ in rows]
         held = [page_tables[row, :-(-c // ps)]
                 for (_, _, row), c in zip(rows, ctx)]
         pages = sum(len(h) for h in held)
         distinct = len(np.unique(np.concatenate(held))) if held else 0
+        steps = sum(-(-len(h) // int(self._latent_group[row]))
+                    for (_, _, row), h in zip(rows, held))
         self.counters["latent_pages_attended"].inc(pages)
         self.counters["latent_pages_attended_distinct"].inc(distinct)
+        self.counters["latent_grid_steps"].inc(steps)
         return dict(
             latent_ctx_tokens=sum(ctx), latent_pages=pages,
-            latent_pages_distinct=distinct,
+            latent_pages_distinct=distinct, latent_grid_steps=steps,
             attn_pairs=sum(q * c - q * (q - 1) // 2
                            for (_, q, _), c in zip(rows, ctx)))
 

@@ -174,7 +174,7 @@ def test_new_layer_metric_names_a_reader_and_a_cell(name):
     assert set(entry["workloads"]) <= family
     if spec["reader"] not in ("trace_phase_sum", "span_work_roofline",
                               "span_work_share", "engine_counter_rest") \
-            and not name.startswith("moe_"):
+            and not name.startswith(("moe_", "latent_")):
         assert family - own <= set(entry["workloads"])
     if spec["reader"] == "trace_idle_by_phase":
         assert spec["args"]["per_span"] in ("engine.step", "g.run")

@@ -325,6 +325,46 @@ def test_rows_on_one_document_share_its_pages_in_the_counters():
     assert m["prefix_cache_tokens_saved"] == 3 * 40
 
 
+def test_the_grid_steps_counter_follows_the_kernels_own_rule():
+    """``latent_grid_steps``: per step and layer, each live row's pages
+    over the group its region's latent call walks a grid step, rounded
+    up — the group from the rule the kernel wrapper reads, at the
+    engine's shapes (decode rows and the chunk row take their own); the
+    ``unified_step`` span carries the step's count."""
+    from hetu_tpu.obs.tracer import SpanTracer
+    from hetu_tpu.ops.ragged_paged_attention import (
+        latent_pages_per_grid_step)
+    pub, cfg, state = build()
+    eng = engine(state, cfg, tracer=SpanTracer(), prefix_cache=False)
+    sch = eng.scheduler
+
+    def group(width):                # the kernel wrapper's own call
+        return latent_pages_per_grid_step(
+            width, cfg.num_heads, sum(cfg.latent_page_dims),
+            eng.max_pages_per_seq,
+            (eng.pool.k_pages[0], eng.pool.v_pages[0]))
+
+    assert group(1) > 1              # else the count is the pages'
+    want, reads = [], eng._latent_reads
+
+    def spy(rows, page_tables):
+        want.append(sum(
+            -(-eng.pool.pages_for(req.pos + q)
+              // group(1 if row < sch.max_batch else sch.chunk))
+            for req, q, row in rows))
+        return reads(rows, page_tables)
+
+    eng._latent_reads = spy
+    for n, out in ((47, 6), (21, 9), (70, 3)):
+        eng.add_request(prompts([n], seed=n)[0], out)
+    eng.run()
+    steps = [e for e in eng.tracer.events() if e.name == "unified_step"]
+    assert len(steps) == len(want) > 9
+    assert [e.attrs["latent_grid_steps"] for e in steps] == want
+    m = eng.metrics_summary()
+    assert m["latent_grid_steps"] == sum(want) < m["latent_pages_attended"]
+
+
 # -- (g) the translation, its refusals, and what stays refused ----------------
 
 @pytest.mark.parametrize("change,word", [

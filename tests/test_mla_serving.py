@@ -31,6 +31,7 @@ Covers the tentpole contracts:
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 import hetu_tpu as ht
@@ -487,15 +488,34 @@ def test_quantized_latent_engine_deterministic(mla):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("quant,d_r", [(None, 4), (None, 0),
-                                       ("int8", 0), ("nf4", 0)])
-def test_latent_kernel_matches_reference(quant, d_r):
+# (max_q, q_lens, ctx_lens, rows sharing physical pages) over 8-token
+# pages and 11 page-table slots: a decode call and a chunk call, each
+# with padding rows (q_lens 0) at the start, in the middle and at the
+# end, a context that ends inside a group, a row holding fewer pages
+# than any group > 1 (one page), and two rows naming the same pages
+_LATENT_CALLS = {
+    "decode": (1, [1, 1, 0, 1, 1, 1, 0], [13, 80, 0, 6, 33, 80, 0],
+               (1, 5)),
+    "chunk": (8, [0, 1, 5, 0, 0, 6, 8, 8], [0, 13, 70, 0, 0, 6, 88, 88],
+              (6, 7)),
+}
+
+
+@pytest.mark.parametrize("group", [None, 1, 2, 4, 8, 16])
+@pytest.mark.parametrize("call", list(_LATENT_CALLS))
+@pytest.mark.parametrize("quant,d_c,d_r", [
+    (None, 16, 4), (None, 16, 0), ("int8", 16, 0), ("nf4", 16, 0),
+    (None, 128, 128), ("int8", 128, 0)])
+def test_latent_kernel_matches_reference(quant, d_c, d_r, call, group):
     """Pallas latent ragged kernel (interpret mode) against the
-    gather-dense latent reference: mixed chunks + decodes + padding
-    rows, rope sidecar and quantized-page variants."""
+    gather-dense latent reference, by the group of page-table slots a
+    grid step covers (None: the rule's own; 11 slots are a multiple of
+    no group but 1), rope sidecar and quantized-page variants.  Streams
+    128 wide reach VMEM by the kernel's own DMAs, narrower ones through
+    page-table-indexed blocks: both ways, and both in one call."""
     rng = np.random.RandomState(0)
-    nh, d_c, num_pages, ps, maxp, max_q = 4, 16, 12, 8, 3, 8
-    q_lens, ctx_lens = [1, 5, 0, 6], [13, 10, 0, 6]
+    nh, num_pages, ps, maxp = 4, 40, 8, 11
+    max_q, q_lens, ctx_lens, (first, twin) = _LATENT_CALLS[call]
     s = len(q_lens)
     cu = np.zeros(s + 1, np.int32)
     cu[1:] = np.cumsum(q_lens)
@@ -510,13 +530,11 @@ def test_latent_kernel_matches_reference(quant, d_r):
         c_pages = jnp.asarray(lat)
     r_pages = jnp.asarray(rng.randn(num_pages, 1, ps, d_r),
                           jnp.float32) if d_r else None
-    perm = rng.permutation(np.arange(1, num_pages))
     pt = np.zeros((s, maxp), np.int32)
-    k = 0
     for i in range(s):
         need = -(-ctx_lens[i] // ps)
-        pt[i, :need] = perm[k:k + need]
-        k += need
+        pt[i, :need] = rng.permutation(np.arange(1, num_pages))[:need]
+    pt[twin] = pt[first]             # one document under two rows
     args = (jnp.asarray(np.asarray(q_lens, np.int32)), jnp.asarray(cu),
             jnp.asarray(pt), jnp.asarray(np.asarray(ctx_lens, np.int32)))
     kw = dict(max_q=max_q, softmax_scale=(d_c + d_r) ** -0.5,
@@ -524,13 +542,31 @@ def test_latent_kernel_matches_reference(quant, d_r):
     ref = latent_ragged_paged_attention_reference(
         q, c_pages, r_pages, *args, **kw)
     got = latent_ragged_paged_attention_pallas(
-        q, c_pages, r_pages, *args, interpret=True, **kw)
+        q, c_pages, r_pages, *args, interpret=True, pages_per_step=group,
+        **kw)
     mask = np.zeros(t, bool)
     for i in range(s):
         mask[int(cu[i]):int(cu[i]) + int(q_lens[i])] = True
     np.testing.assert_allclose(np.asarray(got)[mask],
                                np.asarray(ref)[mask],
                                rtol=2e-5, atol=2e-5)
+
+
+def test_latent_group_rule_follows_the_calls_shapes():
+    """The one rule at the latent cell's shapes (32 heads, 272 slots of
+    64 tokens, a 256 | 128 bf16 pool, q 384 wide): a decode
+    region takes the cap, a 256-token chunk what its score tile leaves
+    of the budget, a short table no more than it has."""
+    from hetu_tpu.ops.ragged_paged_attention import (
+        LATENT_GROUP_MAX, latent_pages_per_grid_step)
+    pool = (jax.ShapeDtypeStruct((8138, 1, 64, 256), jnp.bfloat16),
+            jax.ShapeDtypeStruct((8138, 1, 64, 128), jnp.bfloat16))
+    rule = lambda max_q, maxp=272: latent_pages_per_grid_step(  # noqa: E731
+        max_q, 32, 384, maxp, pool)
+    assert rule(1) == LATENT_GROUP_MAX
+    assert 1 < rule(256) < rule(1)
+    assert rule(4096) == 1
+    assert [rule(1, maxp) for maxp in (1, 2, 3, 5)] == [1, 2, 2, 4]
 
 
 @pytest.mark.parametrize("fixture", ["mla", "mla_rot"])
