@@ -6,7 +6,7 @@ Two formats over the same :class:`~hetu_tpu.obs.tracer.Span` stream:
   (``{"traceEvents": [...]}``) loadable directly in Perfetto
   (https://ui.perfetto.dev) or ``chrome://tracing``.  Each tracer
   *track* becomes a named thread row — serving runs get one track per
-  request (``req N``) plus ``engine``/``scheduler`` rows, training runs
+  request (``req N``) plus an ``engine`` row, training runs
   get per-phase ``train`` / ``pipeN/stageM`` rows.  Timestamps convert
   to microseconds (the format's unit).
 * :func:`write_jsonl` — a flat one-event-per-line journal readable with

@@ -38,9 +38,17 @@ in the profiler's own trace beside the device operations, on the
 profiler's clock; with no session an annotation is a flag check.  Retroactive
 (``complete``) and point (``instant``) events have no "now" to bracket
 and are not mirrored.
+
+Collector: :meth:`SpanTracer.watch_gc` registers one ``gc.callbacks``
+hook that records every collection as a retroactive ``gc`` span on track
+``runtime`` (attrs ``generation``, ``collected``) on this tracer's clock;
+:meth:`SpanTracer.unwatch_gc` takes it off again.  ``trace()`` and
+``serving.Engine.set_tracer`` call them, so the hook lives exactly as
+long as a real tracer is set and with no tracer no callback exists.
 """
 from __future__ import annotations
 
+import gc
 import threading
 import time
 from collections import deque
@@ -157,7 +165,11 @@ class SpanTracer:
         self.capacity = int(capacity)
         self._time = time_fn or time.monotonic
         self._buf: deque = deque(maxlen=self.capacity)
-        self._lock = threading.Lock()
+        # re-entrant: a collection can start between any two bytecodes,
+        # also while this thread holds the lock in _push, and its hook
+        # (watch_gc) pushes a span of its own
+        self._lock = threading.RLock()
+        self._gc_hook: Optional[Callable] = None
         self._tls = threading.local()
         self.dropped = 0
 
@@ -253,6 +265,35 @@ class SpanTracer:
                 self.dropped += 1
             self._buf.append(ev)
 
+    # -- the collector -------------------------------------------------------
+
+    def watch_gc(self) -> bool:
+        """Record every garbage collection from now on as a ``gc`` span
+        (track ``runtime``; ``generation``, ``collected``) through one
+        ``gc.callbacks`` hook.  True if this call registered it: that
+        caller is the one to call :meth:`unwatch_gc`."""
+        if self._gc_hook is not None:
+            return False
+        started: List[float] = []
+
+        def hook(phase: str, info: Dict[str, int]) -> None:
+            if phase == "start":
+                started[:] = [self.now()]
+            elif started:
+                t0 = started.pop()
+                self.complete("gc", t0, self.now() - t0, track="runtime",
+                              generation=info["generation"],
+                              collected=info["collected"])
+
+        self._gc_hook = hook
+        gc.callbacks.append(hook)
+        return True
+
+    def unwatch_gc(self) -> None:
+        hook, self._gc_hook = self._gc_hook, None
+        if hook is not None and hook in gc.callbacks:
+            gc.callbacks.remove(hook)
+
     # -- reading -------------------------------------------------------------
 
     def events(self) -> List[Span]:
@@ -305,6 +346,12 @@ class _NullTracer:
                  track: Optional[str] = None, **attrs: Any) -> None:
         pass
 
+    def watch_gc(self) -> bool:
+        return False
+
+    def unwatch_gc(self) -> None:
+        pass
+
     def events(self) -> List[Span]:
         return []
 
@@ -323,7 +370,7 @@ class PrefixedTracer:
 
     The serving cluster hands each replica engine
     ``PrefixedTracer(shared, "r0/")`` so N engines' identically-named
-    tracks (``engine``, ``scheduler``, ``req 3``) land as distinct
+    tracks (``engine``, ``runtime``, ``req 3``) land as distinct
     ``r0/engine`` / ``r1/engine`` rows in ONE merged Perfetto trace —
     the engines need no cluster awareness and the router's own
     ``router`` track sits alongside.  Purely a pass-through otherwise:
@@ -373,6 +420,12 @@ class PrefixedTracer:
         self.base.complete(name, ts, dur, track=self._track(track),
                            **attrs)
 
+    def watch_gc(self) -> bool:
+        return self.base.watch_gc()
+
+    def unwatch_gc(self) -> None:
+        self.base.unwatch_gc()
+
     def events(self) -> List[Span]:
         return self.base.events()
 
@@ -411,11 +464,14 @@ def install_tracer(tracer) -> Any:
 def trace(capacity: int = 65536,
           time_fn: Optional[Callable[[], float]] = None, tracer=None):
     """``with trace() as tr:`` — install a fresh :class:`SpanTracer`
-    (or the one given) for the dynamic extent, restoring the previous
-    ambient tracer on exit."""
+    (or the one given) for the dynamic extent, the collector's ``gc``
+    spans with it, restoring the previous ambient tracer on exit."""
     tr = tracer if tracer is not None else SpanTracer(capacity, time_fn)
     prev = install_tracer(tr)
+    watching = tr.watch_gc()
     try:
         yield tr
     finally:
+        if watching:
+            tr.unwatch_gc()
         install_tracer(prev)

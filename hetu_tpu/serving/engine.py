@@ -60,12 +60,12 @@ every request gets a complete lifecycle timeline on its own track —
 [submit, finish] gaplessly across preemptions, ``admit`` (page
 accounting), ``prefix_cache_hit``, per-chunk ``prefill_chunk`` spans
 with their token-budget slice, per-token instants, ``preempt`` and
-``finish`` — plus the scheduler's ``pack`` decision per step, a
-``unified_step`` span per executable call (``exec=`` names the registered
-executable; ``obs.reconcile()`` joins the analysis plane's predictions
-on it; ``kv_tokens=`` / ``kv_runs=`` are the tokens its KV write moves
-and the page-runs they fall into) and, around the whole of ``step()``,
-an ``engine_step`` span tiled by its host phases
+``finish`` — plus a ``unified_step`` span per executable call (``exec=``
+names the registered executable; ``obs.reconcile()`` joins the analysis
+plane's predictions on it) and, around the whole of ``step()``,
+an ``engine_step`` span (``step=`` the engine's step index; it ends with
+the queue's load and the scheduler's packing decision, ``slot_mix``)
+tiled by its host phases
 ``step.admit`` (admission, prefix-cache match, draft
 staging), ``step.pages`` (decode pages, preemption), ``step.pack``
 (packing decision + host arrays), ``step.tap`` (the analysis tap's
@@ -73,11 +73,33 @@ copy), ``step.h2d`` (host-to-device copies), ``step.dispatch`` (the
 compiled call up to its return), ``step.fetch`` (the host waits for the
 device here) and ``step.commit`` (pages, counters, per-row commit,
 stream callbacks, gauges).  These real-time spans are mirrored into the
-jax profiler's trace (``hetu:`` prefix).  The default tracer is the
+jax profiler's trace (``hetu:`` prefix).  Two retroactive spans, host
+tracer only, size what the phases hide: ``pack_arrays`` (the packed
+host arrays, inside ``step.pack``; ``rows``, ``page_slots``) and
+``account`` (what the step spends on the engine's own counters and span
+attributes, inside ``step.commit``); with ``unified_step`` they carry
+the same ``step=`` as their ``engine_step``.  While a tracer is set
+through ``set_tracer`` the collector's runs are ``gc`` spans on track
+``runtime`` (``obs/tracer.py``).  The default tracer is the
 shared no-op: every emission site guards on ``tracer.enabled``.
+
+A clock that is always on, tracing or not: ``step()`` reads the clock at
+entry, round the compiled call (``t0``, ``t1``) and at exit, and adds to
+the counters ``host_before_s`` (entry to ``t0``), ``call_s`` (``t0`` to
+``t1``: copies in, the call, the fetch), ``host_after_s`` (``t1`` to
+exit) and ``between_steps_s`` (the previous exit to this entry, counted
+only when requests were running at that exit).  ``slow_step_s`` is the
+part of a step's wall (its ``between`` share and entry to exit) beyond
+``SLOW_STEP_FACTOR`` x the mean wall of the steps before it since the
+reset; ``Engine.slow_steps`` keeps the ``SLOW_STEPS_KEPT`` longest steps
+since the reset (index, seconds since the reset, the four parts, rows,
+tokens, full collections in it), and a step that enters it beyond the
+rule is one warning on logger ``hetu_tpu.serving``.
 """
 from __future__ import annotations
 
+import gc
+import logging
 import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -106,6 +128,14 @@ from .spec import SpecConfig, SpecDecoder
 DEFAULT_LATENCY_BUCKETS = (0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0,
                            10.0)
 
+# the always-on clock's rule for a slow step: a wall beyond this many
+# times the mean wall of the steps before it since the reset; and how
+# many of the longest steps ``Engine.slow_steps`` keeps
+SLOW_STEP_FACTOR = 10.0
+SLOW_STEPS_KEPT = 8
+
+log = logging.getLogger("hetu_tpu.serving")
+
 
 class Engine:
     def __init__(self, state: Dict[str, Any], cfg: GPTConfig,
@@ -129,6 +159,8 @@ class Engine:
         # shared no-op — every emission site below guards on
         # ``tr.enabled`` so disabled tracing stays out of the hot loop
         self._tracer = tracer
+        # the tracer whose collector hook ``set_tracer`` registered
+        self._gc_watched = None
         # traced steps only: the open ``step.*`` child of the
         # ``engine_step`` span and the attributes that span ends with
         self._phase_sp = None
@@ -225,6 +257,7 @@ class Engine:
         self._next_id = 0
         self.steps = 0
         self._calls = 0
+        self._reset_clock()
         # host logits round-trips actually paid: sampling (greedy AND
         # temperature/top-k/top-p) runs on device and moves [rows]
         # int32s per step — this stays 0 on every traffic mix
@@ -273,7 +306,13 @@ class Engine:
                           # where several rows read one physical page
                           "latent_pages_attended",
                           "latent_pages_attended_distinct",
-                          "latent_grid_steps")}
+                          "latent_grid_steps",
+                          # the always-on clock (header): seconds of
+                          # step() before / in / after the compiled call
+                          # and between two steps with requests running;
+                          # the part of slow steps' walls beyond the rule
+                          "host_before_s", "call_s", "host_after_s",
+                          "between_steps_s", "slow_step_s")}
         self.gauges = {k: make_instrument("gauge", k, m) for k in
                        ("batch_occupancy", "page_utilization",
                         "queue_depth",
@@ -529,8 +568,15 @@ class Engine:
     def set_tracer(self, tracer) -> None:
         """Swap the engine's tracer live (None reverts to following the
         ambient global) — lets a service toggle tracing on a running
-        engine, and a traced and an untraced run share one executable."""
+        engine, and a traced and an untraced run share one executable.
+        While a tracer is set here the collector's runs are its ``gc``
+        spans (``SpanTracer.watch_gc``); the hook goes with the tracer."""
+        if self._gc_watched is not None:
+            self._gc_watched.unwatch_gc()
+            self._gc_watched = None
         self._tracer = tracer
+        if tracer is not None and tracer.watch_gc():
+            self._gc_watched = tracer
 
     @property
     def has_work(self) -> bool:
@@ -542,23 +588,88 @@ class Engine:
         number of tokens emitted."""
         now = self._now()
         tr = self.tracer
+        # (t0, t1, rows, tokens) of the step's compiled call, if it makes one
+        self._call: Optional[Tuple[float, float, int, int]] = None
         if not tr.enabled:
-            return self._step(tr, now)
+            produced = self._step(tr, now)
+            self._clock_step(now, self._now())
+            return produced
         # host phases on the profiler's clock (obs/tracer.py mirrors
         # begin/end into jax.profiler): one ``engine_step`` parent whose
         # ``step.*`` children tile it, so a device idle gap can be put
         # down to the phase the host was in.  The finally closes the
         # parent even when the body raises (ending it discards any open
         # child), so a failing step never corrupts the nesting stack.
-        step_sp = tr.begin("engine_step", track="engine", ts=now)
+        step_sp = tr.begin("engine_step", track="engine", ts=now,
+                           step=self.steps)
         try:
-            return self._step(tr, now)
+            produced = self._step(tr, now)
         finally:
             t = self._now()
             if self._phase_sp is not None:
                 tr.end(self._phase_sp, ts=t)
                 self._phase_sp = None
             tr.end(step_sp, ts=t, **self._step_attrs)
+        self._clock_step(now, t)
+        return produced
+
+    def _reset_clock(self) -> None:
+        """The always-on clock's state since the reset (header)."""
+        self._clocked = 0                   # steps booked, and the
+        self._wall_s = 0.0                  # sum of their walls
+        self._reset_at = self._now()
+        # the last step's exit, if requests were running then
+        self._last_exit: Optional[float] = None
+        self._gc_full = gc.get_stats()[2]["collections"]
+        self.slow_steps: List[Dict[str, Any]] = []
+
+    def _clock_step(self, entry: float, exit_: float) -> None:
+        """Book one step's wall under the four parts of the always-on
+        clock, and keep the longest steps (header)."""
+        c = self.counters
+        between = 0.0
+        if self._last_exit is not None:
+            between = entry - self._last_exit
+            c["between_steps_s"].inc(between)
+        # an open-loop caller that sleeps to the next arrival with an
+        # empty engine is not a stall
+        self._last_exit = exit_ if self.running else None
+        t0, t1, rows, tokens = self._call or (exit_, exit_, 0, 0)
+        c["host_before_s"].inc(t0 - entry)
+        c["call_s"].inc(t1 - t0)
+        c["host_after_s"].inc(exit_ - t1)
+        wall = between + (exit_ - entry)
+        # (no rule before a step has taken time: the first step after a
+        # reset, a test's clock that stands still inside a step)
+        mean = self._wall_s / self._clocked if self._wall_s > 0.0 else 0.0
+        over = wall - SLOW_STEP_FACTOR * mean if mean else 0.0
+        self._clocked += 1
+        self._wall_s += wall
+        full, self._gc_full = self._gc_full, \
+            gc.get_stats()[2]["collections"]
+        slow = self.slow_steps
+        if over > 0.0:
+            c["slow_step_s"].inc(over)
+        elif len(slow) == SLOW_STEPS_KEPT and wall <= slow[-1]["wall_s"]:
+            return
+        parts = {"between_steps_s": between, "host_before_s": t0 - entry,
+                 "call_s": t1 - t0, "host_after_s": exit_ - t1}
+        rec = {"step": self.steps - 1, "at_s": entry - self._reset_at,
+               "wall_s": wall, **parts, "rows": rows, "tokens": tokens,
+               "full_collections": self._gc_full - full}
+        slow.append(rec)
+        slow.sort(key=lambda r: -r["wall_s"])
+        del slow[SLOW_STEPS_KEPT:]
+        if over > 0.0 and rec in slow:
+            log.warning(
+                "%s: step %d, %.1f s after the reset, took %.3f s, %.3f s "
+                "beyond %g x the mean step (%.4f s); most of it in %s "
+                "(between_steps %.3f, host_before %.3f, call %.3f, "
+                "host_after %.3f s); rows %d, tokens %d, full "
+                "collections in it %d", self.name, rec["step"],
+                rec["at_s"], wall, over, SLOW_STEP_FACTOR, mean,
+                max(parts, key=parts.get), between, t0 - entry, t1 - t0,
+                exit_ - t1, rows, tokens, rec["full_collections"])
 
     def _enter_phase(self, tr, name: str,
                      t: Optional[float] = None) -> float:
@@ -619,15 +730,13 @@ class Engine:
         rows = self.scheduler.pack(kept)
         if traced:
             # queue_depth counts every queued request, future arrivals
-            # included (as the pack instant always has); queue_due only
-            # those admission left waiting
-            load = {"running": len(self.running),
-                    "queue_depth": len(self.queue),
-                    "free_pages": self.pool.free_pages}
-            self._step_attrs.update(load, queue_due=self.queue.due(now))
+            # included; queue_due only those admission left waiting
+            self._step_attrs.update(
+                running=len(self.running), queue_depth=len(self.queue),
+                free_pages=self.pool.free_pages,
+                queue_due=self.queue.due(now))
             if rows:
-                tr.instant("pack", track="scheduler", ts=self._now(),
-                           **load, **self.scheduler.slot_mix(rows))
+                self._step_attrs.update(self.scheduler.slot_mix(rows))
         produced = self._run_unified(rows) if rows else 0
         if traced and not rows:
             self._enter_phase(tr, "step.commit")
@@ -920,14 +1029,19 @@ class Engine:
                 # this row commits a token the drafts never saw, so
                 # they are stale and dropped before the step
                 req.spec_drafts = []
+        tr = self.tracer
+        traced = tr.enabled
+        tp = self._now() if traced else 0.0
         (tokens, token_pos, token_page, token_off, q_lens, page_tables,
          ctx_lens, temps, top_ps, top_ks, seeds,
          spec_lens, state_slots) = self._pack_arrays(rows)
         kv_tokens = sum(q for _, q, _ in rows)   # every fed token's KV
-        tr = self.tracer
-        traced = tr.enabled
         if traced:
-            self._enter_phase(tr, "step.tap")
+            t = self._now()
+            tr.complete("pack_arrays", tp, t - tp, track="engine",
+                        step=self.steps, rows=len(rows),
+                        page_slots=sum(len(r.pages) for r, _, _ in rows))
+            self._enter_phase(tr, "step.tap", t)
         if self.tap is not None:
             self.tap.append({
                 "kind": "unified",
@@ -982,30 +1096,32 @@ class Engine:
             moe_load = np.asarray(moe_load)
         t1 = self._now()
         dt = t1 - t0
+        self._call = (t0, t1, len(rows), kv_tokens)
         if traced:
             self._enter_phase(tr, "step.commit", t1)
         self.pool.set_pages(new_k, new_v)
-        hybrid_attrs = self._commit_hybrid(new_conv, new_ssm, moe_load,
-                                           kv_tokens) if self.hybrid else {}
+        if self.state_store is not None:
+            self.state_store.set_arrays(new_conv, new_ssm)
+        # what the step spends on the engine's own counters and span
+        # attributes (traced steps put the ``account`` span round it)
+        ta = self._now() if traced else 0.0
+        attrs = self._account_hybrid(moe_load, kv_tokens) \
+            if self.hybrid else {}
         if self.hybrid and self.pool.is_latent:
-            hybrid_attrs.update(self._latent_reads(rows, page_tables))
+            attrs.update(self._latent_reads(rows, page_tables))
         self._calls += 1
         self.counters["step_calls"].inc()
         self.counters["kv_tokens_written"].inc(kv_tokens)
         if traced:
+            self._step_attrs.update(rows=len(rows), tokens=kv_tokens)
+            tr.complete("account", ta, self._now() - ta, track="engine",
+                        step=self.steps)
             # the span every reconciliation row hangs off: exec= names
             # the registered ExecutableHandle (obs.reconcile looks the
             # static predictions up by it at report time)
-            self._step_attrs.update(rows=len(rows), tokens=kv_tokens)
-            # the KV write moves a row's tokens in one run per page its
-            # positions touch (req.pos is still the step's first)
-            ps = self.pool.page_size
-            kv_runs = sum((req.pos + q - 1) // ps - req.pos // ps + 1
-                          for req, q, _ in rows)
             tr.complete("unified_step", t0, dt, track="engine",
-                        exec=f"{self.name}/unified", rows=len(rows),
-                        tokens=kv_tokens, kv_tokens=kv_tokens,
-                        kv_runs=kv_runs, **hybrid_attrs)
+                        exec=f"{self.name}/unified", step=self.steps,
+                        rows=len(rows), tokens=kv_tokens, **attrs)
         # classify by SLOT, not q_len: a chunk_size=1 prefill chunk is
         # still a prefill chunk, and a verify row is neither
         n_decode = sum(1 for _, _, row in rows if row < s)
@@ -1064,10 +1180,9 @@ class Engine:
             attn_pairs=sum(q * c - q * (q - 1) // 2
                            for (_, q, _), c in zip(rows, ctx)))
 
-    def _commit_hybrid(self, new_conv, new_ssm, moe_load,
-                       live_tokens: int) -> Dict[str, Any]:
-        """Install the step's recurrent state and account the expert
-        layers' load; returns the ``unified_step`` span's extra
+    def _account_hybrid(self, moe_load, live_tokens: int) -> Dict[str, Any]:
+        """Account the state slots and the expert layers' load; returns
+        the ``unified_step`` span's extra
         attributes (``state_slots``: slots held; ``moe_local``: live
         assignments on the held experts; ``moe_load_peak``: the busiest
         held expert over the mean; ``moe_experts_hit``: held experts,
@@ -1076,7 +1191,6 @@ class Engine:
         st = self.state_store
         attrs: Dict[str, Any] = {}
         if st is not None:
-            st.set_arrays(new_conv, new_ssm)
             self.gauges["state_slots_in_use"].set(st.in_use)
             attrs["state_slots"] = st.in_use
         if moe_load is not None and moe_load.size:
@@ -1313,6 +1427,7 @@ class Engine:
         deliberately does NOT reset — compiles are lifetime state)."""
         self.steps = 0
         self._calls = 0
+        self._reset_clock()
         for d in (self.counters, self.gauges, self.histograms):
             for k, inst in list(d.items()):
                 if inst.__class__.__name__ == "_NullInstrument":

@@ -189,7 +189,7 @@ class Scheduler:
     def slot_mix(self, rows: List[Tuple[Request, int, int]]
                  ) -> dict:
         """The step's packing decision as a flat dict — the trace
-        plane emits it as the per-step ``pack`` instant event, so a
+        plane ends the step's ``engine_step`` span with it, so a
         Perfetto timeline shows exactly how each executable call's
         token budget was split between decode slots and prefill
         chunks."""

@@ -139,6 +139,119 @@ def test_phase_time_on_a_hand_made_trace():
                     "values": {}}) is None
 
 
+def _ops(start, *ops):
+    """Device operations (start_ns, dur_ns, name, text) from (offset,
+    dur) pairs after ``start``."""
+    return [(start + o, d, "fusion.%d" % i, "") for i, (o, d) in
+            enumerate(ops)]
+
+
+def test_step_edges_on_a_hand_written_event_list():
+    """Two executions with known launch, gaps and tail: the three
+    numbers tile the idle between ``step.dispatch`` start and
+    ``step.fetch`` end, and are the same whether the compiled call
+    returns before or after the execution's first operation."""
+    rd = _reader("trace_step_edges")
+
+    def edges(dispatch_len_a, dispatch_len_b):
+        spans = []
+        for base, dl in ((1000, dispatch_len_a), (11000, dispatch_len_b)):
+            spans += [(base, base + 300, "step.h2d"),
+                      (base + 300, base + 300 + dl, "step.dispatch"),
+                      (base + 300 + dl, base + 5000, "step.fetch")]
+        return {"spans": sorted(spans), "steps": [(900, 5500, 7),
+                                                  (10900, 15500, 8)],
+                "extents": [(1500, 4200), (11700, 14000)],
+                "enqueued": [], "seen_done": []}
+
+    # execution A: first operation 200 after dispatch starts, operations
+    # [1500, 2000) [2100, 3000) [3000, 4200): one gap of 100, tail 1800;
+    # execution B: launch 400, gaps 50 + 150 (the last before the
+    # extent's end), tail 2000
+    events = _ops(1500, (0, 500), (600, 900), (1500, 1200)) + \
+        _ops(11700, (0, 1000), (1050, 500), (200, 300), (1550, 600))
+    events.sort()
+    want = {"launch": (200 + 400) / 2, "dev_gap": (100 + 50 + 150) / 2,
+            "fetch_tail": (1800 + 2000) / 2}
+    for lens in ((100, 100), (700, 900), (100, 900)):
+        got = rd.step_edges(edges(*lens), events, 0, 20000)
+        assert got["steps"] == 2 and got["bracket_ns"] is None \
+            and got["offset_ns"] == 0
+        assert {k: got[k] for k in want} == want
+        assert got["raw"] == (want["launch"], want["dev_gap"],
+                              want["fetch_tail"])
+        # they tile the idle of [dispatch start, fetch end)
+        idle = sum(xplane.total(xplane.idle_gaps(events, a, b))
+                   for a, b in ((1300, 6000), (11300, 16000)))
+        assert sum(want.values()) * 2 == idle
+        assert got["worst"] == (2000, 8)
+    # a step cut by the window's edge is left out; none left: None
+    assert rd.step_edges(edges(100, 100), events, 0, 15000)["steps"] == 1
+    assert rd.step_edges(edges(100, 100), events, 7000, 9000) is None
+    assert rd.step_edges({**edges(100, 100), "extents": []}, events, 0,
+                         20000) is None
+    # the runtime's events bracket the clocks' offset: the device's
+    # times go to the middle, launch grows by it and the tail shrinks
+    e = edges(100, 100)
+    e["enqueued"] = [(1420, 1460), (11500, 11560)]
+    e["seen_done"] = [(4380, 4400), (14300, 14310)]
+    got = rd.step_edges(e, events, 0, 20000)
+    assert got["bracket_ns"] == (-40, 180) and got["offset_ns"] == 70
+    assert (got["launch"], got["dev_gap"], got["fetch_tail"]) == \
+        (want["launch"] + 70, want["dev_gap"], want["fetch_tail"] - 70)
+    assert got["raw"] == (want["launch"], want["dev_gap"],
+                          want["fetch_tail"])
+    # read() hands out the cached table and says nothing without a trace
+    facts = {"trace": {}, "_step_edges": {"launch": 1.5}}
+    assert rd.read({"part": "launch"}, facts) == 1.5
+    assert rd.read({"part": "launch"}, {"trace": None}) is None
+    assert rd.read({"part": "launch"}, {"trace": {},
+                                        "_step_edges": None}) is None
+
+
+def test_step_edges_on_the_recorded_fixture():
+    """Three steps of ``nemotron3s-ep4.serve-chat`` recorded on the chip
+    (PR 37, Step 0): the device plane's clock ran ~1.6 ms early there,
+    so on the trace's own clock the executions start before their
+    ``step.dispatch``; the runtime's events put them back."""
+    rd = _reader("trace_step_edges")
+    edges, events = rd.load_fixture(os.path.join(
+        BENCH, "fixtures", "step_edges_hybrid.json.gz"))
+    assert len(edges["extents"]) == 3 and len(rd.host_steps(
+        edges["spans"])) == 3
+    assert len(events) > 6000 and events == sorted(events)
+    got = rd.step_edges(edges, events, 0, max(e for _, e, _ in
+                                              edges["spans"]))
+    assert got["steps"] == 3
+    lo, hi = got["bracket_ns"]
+    assert 1.4e6 < lo < got["offset_ns"] < hi < 1.8e6 and hi - lo < 0.4e6
+    assert got["raw"][0] == 0.0                  # "started" under h2d
+    assert 0.4e6 < got["launch"] < 1.0e6
+    assert 3e3 < got["dev_gap"] < 2e4
+    assert 0.9e6 < got["fetch_tail"] < 1.6e6
+    # moving the clock moves time between the two ends, not in or out
+    assert got["launch"] + got["fetch_tail"] == pytest.approx(
+        got["raw"][2] - (got["offset_ns"] - got["launch"]), rel=1e-6)
+    # the parent's engine_step carried no step index
+    assert edges["steps"] == [] and got["worst"][1] is None
+
+
+def test_host_span_sum_reader():
+    from hetu_tpu.obs import SpanTracer
+    rd = _reader("host_span_sum")
+    tr = SpanTracer()
+    for ts, dur in ((0.5, 0.25), (1.0, 0.002), (1.5, 0.004), (2.5, 0.5)):
+        tr.complete("gc", ts, dur, track="runtime", generation=0)
+    tr.complete("account", 1.2, 0.1)
+    facts = {"host_spans": tr.events(),
+             "values": {"steps": 4, "host_window": (1.0, 2.0)}}
+    assert rd.read({"name": "gc"}, facts) == pytest.approx(6.0 / 4)
+    assert rd.read({"name": "gc"}, {**facts, "values": {
+        "steps": 4, "host_window": (3.0, 4.0)}}) == 0.0
+    assert rd.read({"name": "pause"}, facts) is None
+    assert rd.read({"name": "gc"}, {**facts, "host_spans": []}) is None
+
+
 def _new_metric_files():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         doc = json.load(f)
@@ -172,8 +285,12 @@ def test_new_layer_metric_names_a_reader_and_a_cell(name):
                          "mistral4-ep4.serve-longdoc"},
               "train": {"cgpt590m.train", "cgpt1.3b.train-dp4z3"}}[suffix]
     assert set(entry["workloads"]) <= family
+    # (trace_step_edges: the dense cells' traced run, 287 s of the
+    # driver's 360, has no room for a third parse of the trace until
+    # ROADMAP B0(iv); it is listed on the two 2 s cells alone)
     if spec["reader"] not in ("trace_phase_sum", "span_work_roofline",
-                              "span_work_share", "engine_counter_rest") \
+                              "span_work_share", "engine_counter_rest",
+                              "trace_step_edges") \
             and not name.startswith(("moe_", "latent_")):
         assert family - own <= set(entry["workloads"])
     if spec["reader"] == "trace_idle_by_phase":

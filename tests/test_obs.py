@@ -304,7 +304,7 @@ def test_chrome_trace_schema_from_serving_run(tiny_state):
              if ev["ph"] == "M" and ev["name"] == "thread_name"]
     for i in range(3):
         assert f"req {i}" in names
-    assert "engine" in names and "scheduler" in names
+    assert "engine" in names
     # every request has a complete lifecycle in the trace
     tls = request_timelines(events)
     for i in range(3):
@@ -405,7 +405,8 @@ def test_adversarial_trace_timelines_gapless(tiny_state):
         assert sum(1 for e in evs if e.name == "token") \
             == req.n_generated
     # scheduler pack decisions stay inside the token budget
-    packs = [e for e in tracer.events() if e.name == "pack"]
+    packs = [e for e in tracer.events() if e.name == "engine_step"
+             and e.attrs["rows"]]
     assert packs
     for p in packs:
         assert p.attrs["tokens"] <= p.attrs["token_budget"]
@@ -574,21 +575,21 @@ def test_engine_step_phases_tile_engine_step(tiny_state):
         assert un.ts == kids[4].ts and un.end_ts == kids[6].end_ts
         assert par.attrs["rows"] == un.attrs["rows"] >= 1
         assert par.attrs["tokens"] == un.attrs["tokens"]
-        # the KV write's counters: every fed token is written, in at
-        # least one page-run a row and no more runs than tokens
-        assert un.attrs["kv_tokens"] == un.attrs["tokens"]
-        assert un.attrs["rows"] <= un.attrs["kv_runs"] \
-            <= un.attrs["kv_tokens"]
+        # every fed token is written, at least one a row; the spans
+        # of one step share its index
+        assert un.attrs["rows"] <= un.attrs["tokens"]
+        assert un.attrs["step"] == par.attrs["step"]
         assert {"queue_depth", "queue_due", "running",
                 "free_pages"} <= set(par.attrs)
         assert 0 <= par.attrs["queue_due"] <= par.attrs["queue_depth"]
     # names the benchmark reads are still there, none shadowed
     names = {e.name for e in events}
-    assert {"admit", "pack", "queued", "running", "token"} <= names
+    assert {"admit", "queued", "running", "token"} <= names
     assert eng.counters["kv_tokens_written"].value == sum(
-        e.attrs["kv_tokens"] for e in unified) == 5 + 2 + (3 - 1) + (2 - 1)
-    # the 5-token prompt fills positions 0..4 of an 8-token page: one run
-    assert unified[0].attrs["kv_runs"] == unified[0].attrs["rows"]
+        e.attrs["tokens"] for e in unified) == 5 + 2 + (3 - 1) + (2 - 1)
+    # the first step packs both prompts: two chunk rows' worth of slots
+    assert parents[0].attrs["tokens"] == unified[0].attrs["tokens"] \
+        <= parents[0].attrs["token_budget"]
     # an idle step (nothing to run) still tiles: admit, pages, pack, commit
     eng.step()
     idle = [e for e in tracer.events() if e.ts >= parents[-1].end_ts
@@ -601,6 +602,180 @@ def test_engine_step_phases_tile_engine_step(tiny_state):
     eng.add_request([1, 2, 3], 2, arrival_time=0.0)
     eng.run()
     assert len(tracer.events()) == n and eng._phase_sp is None
+
+
+class _TickClock:
+    """A clock that moves ``tick`` at every reading, so a step has a
+    wall of its own; ``t`` can be moved by hand."""
+
+    def __init__(self, tick=0.001):
+        self.t, self.tick = 0.0, tick
+
+    def __call__(self):
+        self.t += self.tick
+        return self.t
+
+
+def _stalling(eng, clock, at_call, seconds=0.0, collect=False):
+    """Make the engine's ``at_call``-th compiled call lose ``seconds``
+    of the clock (and run a full collection) before it returns."""
+    import gc
+    fn, calls = eng._compiled["unified"], [0]
+
+    def stalled(*args):
+        calls[0] += 1
+        if calls[0] == at_call:
+            clock.t += seconds
+            if collect:
+                gc.collect()
+        return fn(*args)
+
+    eng._compiled["unified"] = stalled
+
+
+def test_slow_step_is_named_once_and_the_parts_sum(tiny_state, caplog):
+    """The always-on clock, tracing off: one injected 3 s step is the
+    head of ``slow_steps`` with its part, ``slow_step_s`` is what lies
+    beyond the rule, the warning comes once, and the four parts sum to
+    the window the caller spent in and between its steps."""
+    import logging
+    from hetu_tpu.serving.engine import SLOW_STEP_FACTOR, SLOW_STEPS_KEPT
+    state, cfg = tiny_state
+    clock = _TickClock()
+    eng = Engine(state, cfg, time_fn=clock, num_pages=16, page_size=8,
+                 max_batch=2, name="obs_clock")
+    assert eng.tracer is NULL_TRACER
+    eng.add_request([7, 3, 9, 1, 5], 12, arrival_time=0.0)
+    eng.add_request([2, 4], 14, arrival_time=0.0)
+    _stalling(eng, clock, at_call=9, seconds=3.0)
+    walls, first = [], None
+    with caplog.at_level(logging.WARNING, logger="hetu_tpu.serving"):
+        while eng.has_work:
+            entry = clock.t + clock.tick
+            first = entry if first is None else first
+            eng.step()
+            walls.append(clock.t - entry)
+    m = eng.metrics_summary()
+    parts = [m[k] for k in ("host_before_s", "call_s", "host_after_s",
+                            "between_steps_s")]
+    assert all(p > 0 for p in parts)
+    assert sum(parts) == pytest.approx(clock.t - first, rel=1e-9)
+    head = eng.slow_steps[0]
+    assert head["step"] == 8 and head["wall_s"] > 3.0
+    assert max(("between_steps_s", "host_before_s", "call_s",
+                "host_after_s"), key=head.get) == "call_s"
+    assert head["rows"] == 2 and head["tokens"] == 2
+    assert head["full_collections"] == 0
+    assert len(eng.slow_steps) == SLOW_STEPS_KEPT
+    assert [r["wall_s"] for r in eng.slow_steps] == sorted(
+        (r["wall_s"] for r in eng.slow_steps), reverse=True)
+    # beyond 10 x the mean wall of the eight steps before it (a step's
+    # wall here counts the tick between two steps too)
+    mean = (sum(walls[:8]) + 7 * clock.tick) / 8
+    assert m["slow_step_s"] == pytest.approx(
+        head["wall_s"] - SLOW_STEP_FACTOR * mean, rel=1e-9)
+    assert m["slow_step_s"] == pytest.approx(3.0, abs=0.5)
+    warned = [r for r in caplog.records if r.name == "hetu_tpu.serving"]
+    assert len(warned) == 1
+    text = warned[0].getMessage()
+    assert "step 8," in text and "most of it in call_s" in text \
+        and "full collections in it 0" in text
+    # the reset clears the record with the counters
+    eng.reset_metrics()
+    assert eng.slow_steps == [] and \
+        eng.metrics_summary()["slow_step_s"] == 0.0
+
+
+def test_sleeping_caller_with_an_empty_engine_is_no_stall(tiny_state,
+                                                           caplog):
+    """``between_steps_s`` counts the way from one step to the next only
+    while requests were running at the first one's exit."""
+    import logging
+    state, cfg = tiny_state
+    clock = _TickClock()
+    eng = Engine(state, cfg, time_fn=clock, num_pages=16, page_size=8,
+                 max_batch=2, name="obs_sleeper")
+    with caplog.at_level(logging.WARNING, logger="hetu_tpu.serving"):
+        eng.add_request([2, 4, 6], 3, arrival_time=0.0)
+        eng.run()
+        between = eng.metrics_summary()["between_steps_s"]
+        assert between == pytest.approx(2 * clock.tick)   # three steps
+        clock.t += 100.0                  # the caller sleeps, engine empty
+        eng.add_request([1, 3], 2, arrival_time=0.0)
+        eng.run()
+    assert eng.metrics_summary()["between_steps_s"] == \
+        pytest.approx(between + clock.tick)
+    assert eng.metrics_summary()["slow_step_s"] == 0.0
+    assert not caplog.records
+
+
+def test_pack_arrays_account_and_gc_spans(tiny_state):
+    """Traced steps: ``pack_arrays`` and ``account`` carry their step's
+    index and lie inside ``step.pack`` / ``step.commit``; a collection
+    inside a step is one ``gc`` span and a full collection in the slow
+    record; the hook goes when the tracer goes."""
+    import gc
+    state, cfg = tiny_state
+    clock = _TickClock()
+    tracer = SpanTracer(time_fn=clock)
+    eng = Engine(state, cfg, time_fn=clock, num_pages=16, page_size=8,
+                 max_batch=2, name="obs_account")
+    hooks = len(gc.callbacks)
+    eng.set_tracer(tracer)
+    eng.set_tracer(tracer)                       # asked twice, one hook
+    assert len(gc.callbacks) == hooks + 1
+    eng.add_request([7, 3, 9, 1, 5], 3, arrival_time=0.0)
+    eng.add_request([2, 4], 2, arrival_time=0.0)
+    _stalling(eng, clock, at_call=2, collect=True)
+    gc.disable()                     # no collection but the forced one
+    try:
+        eng.run()
+    finally:
+        gc.enable()
+    eng.set_tracer(None)
+    assert len(gc.callbacks) == hooks and eng.tracer is NULL_TRACER
+    events = tracer.events()
+    by = lambda n: [e for e in events if e.name == n]       # noqa: E731
+    parents = by("engine_step")
+    assert [e.attrs["step"] for e in parents] == list(range(eng.steps))
+    for name, phase in (("pack_arrays", "step.pack"),
+                        ("account", "step.commit")):
+        spans, homes = by(name), by(phase)
+        assert len(spans) == len(homes) == len(parents) == eng.steps
+        for sp, home, par in zip(spans, homes, parents):
+            assert sp.attrs["step"] == par.attrs["step"]
+            assert sp.track == "engine" and sp.dur > 0
+            assert home.ts <= sp.ts and sp.end_ts <= home.end_ts
+    # one chunk row a step: the first prompt, then the second beside
+    # the first's decode row; each holds one 8-token page
+    assert [(e.attrs["rows"], e.attrs["page_slots"])
+            for e in by("pack_arrays")[:2]] == [(1, 1), (2, 2)]
+    # the account span starts after the pools are swapped in
+    assert all(a.ts > c.ts for a, c in zip(by("account"),
+                                           by("step.commit")))
+    (coll,) = by("gc")
+    assert coll.track == "runtime" and coll.attrs["generation"] == 2
+    assert coll.attrs["collected"] >= 0
+    disp = by("step.dispatch")[1]
+    assert disp.ts <= coll.ts and coll.end_ts <= disp.end_ts
+    assert [r["full_collections"] for r in sorted(
+        eng.slow_steps, key=lambda r: r["step"])][:3] == [0, 1, 0]
+
+
+def test_trace_context_watches_the_collector():
+    """``obs.trace()`` brings the ``gc`` spans and takes the hook away;
+    with no tracer no callback exists."""
+    import gc
+    hooks = len(gc.callbacks)
+    with trace() as tr:
+        assert len(gc.callbacks) == hooks + 1
+        with trace(tracer=tr):               # nested on one tracer
+            gc.collect()
+        assert len(gc.callbacks) == hooks + 1
+    assert len(gc.callbacks) == hooks
+    assert [e.attrs["generation"] for e in tr.events()
+            if e.name == "gc" and e.attrs["generation"] == 2] == [2]
+    assert NULL_TRACER.watch_gc() is False and len(gc.callbacks) == hooks
 
 
 def _tiny_train_graph(cfg, prefix):
