@@ -44,6 +44,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ..models.generate import (_act, _lm_head, _moe_mlp, _norm_apply,
@@ -279,6 +280,106 @@ def _regions(max_seqs: int, prefill_rows: int, chunk: int, spec_k: int):
     return regions
 
 
+class StepLayout:
+    """The serving step's control data as ONE int32 buffer each way.
+
+    In: every small host array of a step, end to end in one ``[size]``
+    int32 buffer, so the engine makes one host-to-device transfer a step
+    and the step cuts the buffer at static offsets (``unpack``).  The
+    fields, in order (``t = n_tokens``, ``r = n_rows``)::
+
+        tokens [t]  token_pos [t]  token_page [t]  token_off [t]
+        q_lens [r]  page_tables [r, max_pages]  ctx_lens [r]
+        temps [r] f32  top_ps [r] f32  top_ks [r]  seeds [r]
+        spec_lens [r]      (a speculative build, ``spec_k > 0``)
+        state_slots [r]    (a hybrid stack)
+
+    ``temps`` and ``top_ps`` ride as their float32 bit patterns
+    (``.view(np.float32)`` on the host, ``lax.bitcast_convert_type`` in
+    the step).  ``cu_q [r + 1]`` (each row's first token) follows from
+    the regions alone and is a constant of the build, not a field.
+
+    Out: the step's host-bound results as one int32 vector
+    (``join`` in the step, ``split`` on the host): ``next_tokens [r]``,
+    then ``moe_load [expert layers, held experts]`` flattened (a hybrid
+    stack), then ``accepted [r]`` (a speculative build)."""
+
+    F32 = ("temps", "top_ps")
+
+    def __init__(self, cfg: GPTConfig, max_seqs: int, chunk: int,
+                 prefill_rows: int, max_pages: int, spec_k: int = 0):
+        regions = _regions(max_seqs, prefill_rows, chunk, spec_k)
+        _, row, tok, n, width = regions[-1]
+        self.n_rows, self.n_tokens = row + n, tok + n * width
+        self.cu_q = np.concatenate(
+            [tok + width * np.arange(n) for _, _, tok, n, width in regions]
+            + [[self.n_tokens]]).astype(np.int32)
+        t, r = self.n_tokens, self.n_rows
+        shapes = {"tokens": (t,), "token_pos": (t,), "token_page": (t,),
+                  "token_off": (t,), "q_lens": (r,),
+                  "page_tables": (r, max_pages), "ctx_lens": (r,),
+                  "temps": (r,), "top_ps": (r,), "top_ks": (r,),
+                  "seeds": (r,)}
+        if spec_k:
+            shapes["spec_lens"] = (r,)
+        if cfg.is_hybrid:
+            shapes["state_slots"] = (r,)
+        self.fields, self.size = self._offsets(shapes)
+        outs = {"next_tokens": (r,)}
+        if cfg.is_hybrid:
+            outs["moe_load"] = (len(cfg.layers_of("moe")),
+                                max(cfg.held_experts, 1))
+        if spec_k:
+            outs["accepted"] = (r,)
+        self.outs, self.out_size = self._offsets(outs)
+
+    @staticmethod
+    def _offsets(shapes):
+        """name -> (offset, shape) in dict order, and the total size."""
+        table, off = {}, 0
+        for name, shape in shapes.items():
+            table[name] = (off, shape)
+            off += int(np.prod(shape))
+        return table, off
+
+    @staticmethod
+    def _cut(table, vec, as_f32):
+        out = {}
+        for name, (off, shape) in table.items():
+            a = vec[off: off + int(np.prod(shape))].reshape(shape)
+            out[name] = as_f32(a) if name in StepLayout.F32 else a
+        return out
+
+    def views(self, buf):
+        """The fields of a host buffer as NumPy views on it (writes
+        land in ``buf``)."""
+        return self._cut(self.fields, buf, lambda a: a.view(np.float32))
+
+    def unpack(self, packed):
+        """The fields of the device buffer, inside the step: static
+        slices, which XLA fuses into their consumers."""
+        return self._cut(
+            self.fields, packed,
+            lambda a: lax.bitcast_convert_type(a, jnp.float32))
+
+    def join(self, **outs):
+        """The step's host-bound results as the one output vector."""
+        for name, (_, shape) in self.outs.items():
+            if outs[name].shape != shape:
+                raise ValueError(f"{name} is {outs[name].shape}, the "
+                                 f"layout holds {shape}")
+        return jnp.concatenate(
+            [outs[name].astype(jnp.int32).reshape(-1) for name in self.outs])
+
+    def split(self, vec):
+        """The fetched output vector as its named arrays (host)."""
+        return self._cut(self.outs, vec, None)
+
+    def abstract(self):
+        """The packed input's shape, for lowering without running."""
+        return jax.ShapeDtypeStruct((self.size,), jnp.int32)
+
+
 def _attend_by_region(kernel, name: str, q, q_lens, cu_q, page_tables,
                       ctx_lens, max_seqs: int, prefill_rows: int,
                       chunk: int, spec_k: int = 0):
@@ -323,20 +424,23 @@ def build_unified_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
         [0 .. max_seqs)                    decode slots, 1 token each
         [max_seqs .. max_seqs + R*chunk)   R = prefill_rows chunk slots
 
-    fn(params,
-       tokens [T] i32, token_pos [T] i32,
-       token_page [T] i32, token_off [T] i32,   # KV write plan: where
-                                                # each token's k/v goes
-                                                # (trash page, offset 0
-                                                # for padding)
-       q_lens [rows] i32, cu_q [rows+1] i32,
-       page_tables [rows, max_pages] i32, ctx_lens [rows] i32,
-       temps [rows] f32, top_ps [rows] f32,
-       top_ks [rows] i32, seeds [rows] i32,
-       k_pages, v_pages)
-      -> (next_tokens [rows] i32, new k_pages, new v_pages)
+    fn(params, packed [layout.size] i32, k_pages, v_pages)
+      -> (out [layout.out_size] i32, new k_pages, new v_pages)
 
-    where ``rows = max_seqs + prefill_rows`` and ``T = max_seqs +
+    ``packed`` is the step's control data in ONE buffer, the engine's one
+    host-to-device transfer a step, cut here at the static offsets of
+    :class:`StepLayout` (the only home of the format):
+    the flat token arrays ``tokens`` / ``token_pos`` and the KV write
+    plan ``token_page`` / ``token_off`` (where each token's k/v goes;
+    trash page, offset 0 for padding), all ``[T]``; the ragged
+    descriptors ``q_lens [rows]``, ``page_tables [rows, max_pages]``,
+    ``ctx_lens [rows]``; the sampling parameters ``temps``, ``top_ps``
+    (float32, carried by bit pattern), ``top_ks``, ``seeds``, all
+    ``[rows]``.  ``cu_q [rows + 1]`` follows from the layout alone and
+    is a constant of the build.  ``out`` is the one array the engine
+    fetches: ``next_tokens [rows]``, here its whole content.
+
+    ``rows = max_seqs + prefill_rows`` and ``T = max_seqs +
     prefill_rows * chunk``.  Every row gets a next-token sample at its
     LAST query token; the engine commits it only when the row reached
     the end of its accumulated sequence (``pos + q_len == len(tokens)``
@@ -356,15 +460,15 @@ def build_unified_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
     plain scatter writes every slot and padding lands in the trash page.
 
     ``spec_k > 0`` (speculative serving, DESIGN.md §20) grows BOTH the
-    layout and the signature.  The token axis gains ``max_seqs``
+    layout and the two buffers.  The token axis gains ``max_seqs``
     dedicated VERIFY slots of ``spec_k + 1`` tokens each (after the
     prefill chunk slots), so every decode-capable request can verify a
     draft burst every step — structurally a prefill chunk, but priced
     at ``k + 1`` tokens of compute instead of a ``chunk``-wide slot,
     and never competing with prompt prefills for chunk slots.  An
-    extra ``spec_lens [rows] i32`` input after ``seeds`` marks live
+    extra ``spec_lens [rows] i32`` field of ``packed`` marks live
     verify rows (feeding the last committed token plus the drafts),
-    and the outputs gain ``accepted [rows] i32`` — the
+    and ``out`` gains ``accepted [rows] i32`` after the tokens — the
     longest-accepted-prefix length from the on-device verify head
     (:func:`~hetu_tpu.ops.ragged_paged_attention.speculative_verify_head`).
     For rows with ``spec_len == 0`` (every decode slot, every plain
@@ -394,10 +498,9 @@ def build_unified_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
     if page_quant is not None and (not c.is_mla or c.rope_dim):
         raise ValueError("page_quant requires the latent (MLA) layout "
                          "with rope_dim == 0")
+    layout = StepLayout(c, max_seqs, chunk, prefill_rows, max_pages, spec_k)
     verify_rows = max_seqs if spec_k else 0
-    t_tokens = max_seqs + prefill_rows * chunk \
-        + verify_rows * (spec_k + 1)
-    n_rows = max_seqs + prefill_rows + verify_rows
+    t_tokens, n_rows = layout.n_tokens, layout.n_rows
     max_len = max_pages * page_size
     cdt = jnp.bfloat16 if c.dtype == "bfloat16" else jnp.float32
     cos, sin = (_rotary_tables(c, max_len) if c.position == "rotary"
@@ -434,15 +537,15 @@ def build_unified_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
             parts.append(f(h[max_seqs + prefill_rows * chunk:]))
         return jnp.concatenate(parts, axis=0)
 
-    # pages are donated (the pool replaces them wholesale every call, so
-    # the KV write updates them in place); seeds is donated so the [rows]
-    # int32
-    # next-token output can alias it instead of tripping donation-miss
-    # (spec mode additionally donates spec_lens to back the [rows]
-    # accepted output)
-    def run_impl(params, tokens, token_pos, token_page, token_off,
-                 q_lens, cu_q, page_tables, ctx_lens, temps, top_ps,
-                 top_ks, seeds, spec_lens, k_pages, v_pages):
+    # pages are donated: the pool replaces them wholesale every call, so
+    # the KV write updates them in place
+    @functools.partial(jax.jit, donate_argnums=(2, 3))
+    def run(params, packed, k_pages, v_pages):
+        (tokens, token_pos, token_page, token_off, q_lens, page_tables,
+         ctx_lens, temps, top_ps, top_ks, seeds,
+         *spec_lens) = layout.unpack(packed).values()
+        spec_lens = spec_lens[0] if spec_k else None
+        cu_q = jnp.asarray(layout.cu_q)
         p = _params_view(c, params)
         # model phases (obs/phases.py): names on the HLO op_name
         # metadata only, the compiled program is what it was
@@ -632,7 +735,8 @@ def build_unified_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
             next_tokens = sample_rows(logits, temps, top_ps, top_ks,
                                       seeds, ctx_lens)
         if spec_k == 0:
-            return next_tokens, tuple(new_k), tuple(new_v)
+            return (layout.join(next_tokens=next_tokens), tuple(new_k),
+                    tuple(new_v))
         # -- verify head (dedicated verify slots only: decode slots and
         # prefill chunks never stage drafts).  Verify position j of a
         # row starting at cu sits at token cu + j and its logits verify
@@ -664,26 +768,8 @@ def build_unified_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
             [next_tokens[:v0], verify_next])
         accepted = jnp.concatenate(
             [jnp.zeros(v0, jnp.int32), acc_v])
-        return next_tokens, accepted, tuple(new_k), tuple(new_v)
-
-    if spec_k == 0:
-        @functools.partial(jax.jit, donate_argnums=(12, 13, 14))
-        def run(params, tokens, token_pos, token_page, token_off,
-                q_lens, cu_q, page_tables, ctx_lens, temps, top_ps,
-                top_ks, seeds, k_pages, v_pages):
-            return run_impl(params, tokens, token_pos, token_page,
-                            token_off, q_lens, cu_q, page_tables,
-                            ctx_lens, temps, top_ps, top_ks, seeds,
-                            None, k_pages, v_pages)
-    else:
-        @functools.partial(jax.jit, donate_argnums=(12, 13, 14, 15))
-        def run(params, tokens, token_pos, token_page, token_off,
-                q_lens, cu_q, page_tables, ctx_lens, temps, top_ps,
-                top_ks, seeds, spec_lens, k_pages, v_pages):
-            return run_impl(params, tokens, token_pos, token_page,
-                            token_off, q_lens, cu_q, page_tables,
-                            ctx_lens, temps, top_ps, top_ks, seeds,
-                            spec_lens, k_pages, v_pages)
+        return (layout.join(next_tokens=next_tokens, accepted=accepted),
+                tuple(new_k), tuple(new_v))
 
     return run
 
@@ -701,12 +787,13 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
     the scores are what they were) and attend them with ``W_kvb``
     absorbed, decode rows and prefill chunk alike.
 
-    fn(params, tokens, token_pos, token_page, token_off, q_lens, cu_q,
-       page_tables, ctx_lens, temps, top_ps, top_ks, seeds,
-       state_slots [rows] i32,          # the row's slot in the store
+    fn(params, packed [layout.size] i32,   # the dense step's fields and
+                                           # state_slots [rows] i32: the
+                                           # row's slot in the store
        k_pages, v_pages,                # attention layers only
        conv_states, ssm_states)         # mamba2 layers: [slots, ...]
-      -> (next_tokens [rows] i32, moe_load [moe layers, held] i32,
+      -> (out [layout.out_size] i32,    # next_tokens [rows], then
+                                        # moe_load [moe layers, held]
           new k_pages, v_pages, conv_states, ssm_states)
 
     The store has ``max_seqs`` slots, one per running sequence.  Decode
@@ -722,8 +809,8 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
     each held expert was chosen by."""
     from ..models import hybrid as hy
     c = cfg
-    t_tokens = max_seqs + prefill_rows * chunk
-    n_rows = max_seqs + prefill_rows
+    layout = StepLayout(c, max_seqs, chunk, prefill_rows, max_pages)
+    t_tokens, n_rows = layout.n_tokens, layout.n_rows
     cdt = jnp.bfloat16 if c.dtype == "bfloat16" else jnp.float32
     hd, nh, nkv = c.head_dim, c.num_heads, c.kv_heads
     slots = _chunk_slots(max_seqs, prefill_rows, chunk, 0)
@@ -752,9 +839,11 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
                     lambda a: jnp.zeros(a.shape, a.dtype), z), sl))
         return tmap(lambda *a: jnp.concatenate(a, axis=0), *outs)
 
-    def run(params, tokens, token_pos, token_page, token_off, q_lens, cu_q,
-            page_tables, ctx_lens, temps, top_ps, top_ks, seeds,
-            state_slots, k_pages, v_pages, conv_states, ssm_states):
+    def run(params, packed, k_pages, v_pages, conv_states, ssm_states):
+        (tokens, token_pos, token_page, token_off, q_lens, page_tables,
+         ctx_lens, temps, top_ps, top_ks, seeds,
+         state_slots) = layout.unpack(packed).values()
+        cu_q = jnp.asarray(layout.cu_q)
         p = _params_view(c, params)
         with phase("embed"):
             x = p("wte.weight")[tokens].astype(cdt)
@@ -934,7 +1023,7 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
                                       ctx_lens)
         moe_load = jnp.stack(loads) if loads else \
             jnp.zeros((0, max(c.held_experts, 1)), jnp.int32)
-        return (next_tokens, moe_load, tuple(new_k), tuple(new_v),
-                tuple(new_conv), tuple(new_ssm))
+        return (layout.join(next_tokens=next_tokens, moe_load=moe_load),
+                tuple(new_k), tuple(new_v), tuple(new_conv), tuple(new_ssm))
 
-    return jax.jit(run, donate_argnums=(12, 14, 15, 16, 17))
+    return jax.jit(run, donate_argnums=(2, 3, 4, 5))

@@ -12,7 +12,14 @@ leave without waiting for long ones; long prompts prefill in
 
 One executable, compiled once (DESIGN.md §12): there is no prefill
 bucket grid and no per-batch-size decode program — ``compile_count``
-is 1 regardless of traffic, asserted by the CI recompile guard.
+is 1 regardless of traffic, asserted by the CI recompile guard.  Its
+control data crosses the host-device boundary once each way
+(``serving/decode.StepLayout``): ``fn(params, packed, k_pages, v_pages,
+*states)`` takes the step's small host arrays as ONE int32 buffer — one
+transfer a step, counter ``h2d_copies`` — and returns, before the pools
+and states, ONE int32 vector — the tokens and, beside them, the hybrid
+family's expert load / a speculative build's accepted lengths; one
+fetch a step, counter ``d2h_fetches``.
 
 Determinism contract: at temperature 0 every request's output equals a
 solo ``generate()`` run — batching, paging, chunked prefill, admission
@@ -69,9 +76,9 @@ tiled by its host phases
 ``step.admit`` (admission, prefix-cache match, draft
 staging), ``step.pages`` (decode pages, preemption), ``step.pack``
 (packing decision + host arrays), ``step.tap`` (the analysis tap's
-copy), ``step.h2d`` (host-to-device copies), ``step.dispatch`` (the
-compiled call up to its return), ``step.fetch`` (the host waits for the
-device here) and ``step.commit`` (pages, counters, per-row commit,
+copy), ``step.h2d`` (the one host-to-device transfer), ``step.dispatch``
+(the compiled call up to its return), ``step.fetch`` (the host waits for
+the device here) and ``step.commit`` (pages, counters, per-row commit,
 stream callbacks, gauges).  These real-time spans are mirrored into the
 jax profiler's trace (``hetu:`` prefix).  Two retroactive spans, host
 tracer only, size what the phases hide: ``pack_arrays`` (the packed
@@ -115,7 +122,7 @@ from ..ops.moe_grouped import ROW_BLOCK, block_rows
 from ..ops.pallas import on_tpu
 from ..ops.ragged_paged_attention import latent_pages_per_grid_step
 from ..utils.metrics import make_instrument, render_prometheus
-from .decode import _regions, build_unified_step_fn
+from .decode import StepLayout, _regions, build_unified_step_fn
 from .kv_pool import (TRASH_PAGE, PagedKVPool, StateSlotStore,
                       protocol_seq)
 from .prefix_cache import PrefixCache
@@ -269,6 +276,9 @@ class Engine:
                           "requests_completed", "preemptions",
                           "decode_steps", "prefill_chunks",
                           "step_calls",
+                          # transfers of the step's control data: one
+                          # buffer in, one array out, a call
+                          "h2d_copies", "d2h_fetches",
                           # prefix cache: hits/misses count request
                           # starts with/without a cached prefix;
                           # tokens_saved = prefill tokens skipped;
@@ -387,29 +397,20 @@ class Engine:
             # silent draft retrace trips compile_count just like a
             # unified-step retrace would
             self._compiled.update(self.spec.compiled)
-        # static packed-layout constants: decode slots, prefill chunk
-        # slots, then (spec mode) one (k+1)-wide verify slot per
-        # decode-capable request
+        # the static packed layout (decode slots, prefill chunk slots,
+        # then -- spec mode -- one (k+1)-wide verify slot per
+        # decode-capable request) and the step's two buffers: from the
+        # shapes alone, so a shared ``step_fn`` and this engine agree
         s, r, ck = (self.scheduler.max_batch, self.scheduler.prefill_rows,
                     self.scheduler.chunk)
-        vr = s if self.spec is not None else 0
-        vk = self.spec_k + 1
-        self.n_rows = s + r + vr
-        self.n_tokens = s + r * ck + vr * vk
-        cu = np.concatenate([np.arange(s, dtype=np.int32),
-                             s + ck * np.arange(r + 1, dtype=np.int32)])
-        if vr:
-            base = s + r * ck
-            cu = np.concatenate([cu[:-1],
-                                 base + vk * np.arange(vr + 1,
-                                                       dtype=np.int32)])
-        self._cu_q = cu                       # [rows + 1], layout-fixed
+        self.layout = StepLayout(cfg, s, ck, r, self.max_pages_per_seq,
+                                 self.spec_k)
         # pages a grid step of the latent call covers, by row slot: each
         # region's call takes its group from its own width (the kernel
         # wrapper reads the same rule from the same shapes)
         self._latent_group = None
         if self.hybrid and self.pool.is_latent:
-            self._latent_group = np.ones(self.n_rows, np.int64)
+            self._latent_group = np.ones(self.layout.n_rows, np.int64)
             for _, row, _, n, width in _regions(s, r, ck, self.spec_k):
                 self._latent_group[row: row + n] = \
                     latent_pages_per_grid_step(
@@ -972,32 +973,29 @@ class Engine:
     # -- the unified step ----------------------------------------------------
 
     def _pack_arrays(self, rows: List[Tuple[Request, int, int]]):
-        """Host-side marshalling of the packed step: flat token arrays +
-        per-row ragged descriptors + per-row sampling params.  A verify
+        """Host-side marshalling of the packed step into ONE int32
+        buffer (``serving/decode.StepLayout``): flat token arrays +
+        per-row ragged descriptors + per-row sampling params.  Returns
+        the buffer and its fields as views on it.  A verify
         row's fed tokens are the committed tail plus its staged drafts
         (``qlen = 1 + spec_len``), written through the SAME trash-page-
-        safe per-token KV write plan as any prefill chunk."""
-        t, nr = self.n_tokens, self.n_rows
+        safe per-token KV write plan as any prefill chunk.  The buffer
+        is a fresh one every step: the CPU backend may alias a NumPy
+        array it was given, so a reused one would rewrite the step
+        before it."""
+        lay = self.layout
         ps = self.pool.page_size
         vbase = self.scheduler.max_batch + self.scheduler.prefill_rows
-        tokens = np.zeros(t, np.int32)
-        token_pos = np.zeros(t, np.int32)
-        token_page = np.full(t, TRASH_PAGE, np.int32)
-        token_off = np.zeros(t, np.int32)
-        q_lens = np.zeros(nr, np.int32)
-        page_tables = np.full((nr, self.max_pages_per_seq), TRASH_PAGE,
-                              np.int32)
-        ctx_lens = np.zeros(nr, np.int32)
-        temps = np.zeros(nr, np.float32)
-        top_ps = np.zeros(nr, np.float32)
-        top_ks = np.zeros(nr, np.int32)
-        seeds = np.zeros(nr, np.int32)
-        spec_lens = np.zeros(nr, np.int32)
-        state_slots = np.zeros(nr, np.int32)
+        packed = np.zeros(lay.size, np.int32)
+        f = lay.views(packed)
+        f["token_page"].fill(TRASH_PAGE)
+        f["page_tables"].fill(TRASH_PAGE)
+        tokens, token_pos = f["tokens"], f["token_pos"]
+        token_page, token_off = f["token_page"], f["token_off"]
         for req, qlen, row in rows:
             if req.state_slot is not None:
-                state_slots[row] = req.state_slot
-            start = int(self._cu_q[row])
+                f["state_slots"][row] = req.state_slot
+            start = int(lay.cu_q[row])
             pos = np.arange(req.pos, req.pos + qlen)
             seq = req.tokens if not (row >= vbase and req.spec_drafts) \
                 else req.tokens + req.spec_drafts
@@ -1006,18 +1004,16 @@ class Engine:
             pages = np.asarray(req.pages, np.int32)
             token_page[start:start + qlen] = pages[pos // ps]
             token_off[start:start + qlen] = pos % ps
-            q_lens[row] = qlen
-            page_tables[row, :len(req.pages)] = req.pages
-            ctx_lens[row] = req.pos + qlen
-            temps[row] = req.temperature
-            top_ps[row] = req.top_p
-            top_ks[row] = req.top_k
-            seeds[row] = req.seed
+            f["q_lens"][row] = qlen
+            f["page_tables"][row, :len(req.pages)] = req.pages
+            f["ctx_lens"][row] = req.pos + qlen
+            f["temps"][row] = req.temperature
+            f["top_ps"][row] = req.top_p
+            f["top_ks"][row] = req.top_k
+            f["seeds"][row] = req.seed
             if row >= vbase and req.spec_drafts:
-                spec_lens[row] = len(req.spec_drafts)
-        return (tokens, token_pos, token_page, token_off, q_lens,
-                page_tables, ctx_lens, temps, top_ps, top_ks, seeds,
-                spec_lens, state_slots)
+                f["spec_lens"][row] = len(req.spec_drafts)
+        return packed, f
 
     def _run_unified(self, rows: List[Tuple[Request, int, int]]) -> int:
         s = self.scheduler.max_batch
@@ -1032,9 +1028,8 @@ class Engine:
         tr = self.tracer
         traced = tr.enabled
         tp = self._now() if traced else 0.0
-        (tokens, token_pos, token_page, token_off, q_lens, page_tables,
-         ctx_lens, temps, top_ps, top_ks, seeds,
-         spec_lens, state_slots) = self._pack_arrays(rows)
+        packed, fields = self._pack_arrays(rows)
+        page_tables, ctx_lens = fields["page_tables"], fields["ctx_lens"]
         kv_tokens = sum(q for _, q, _ in rows)   # every fed token's KV
         if traced:
             t = self._now()
@@ -1062,38 +1057,29 @@ class Engine:
                 "refcounts": {int(pg): self.pool.refcount(pg)
                               for pg in self.pool._cached}})
         t0 = self._enter_phase(tr, "step.h2d") if traced else self._now()
-        args = (self.params, jnp.asarray(tokens), jnp.asarray(token_pos),
-                jnp.asarray(token_page), jnp.asarray(token_off),
-                jnp.asarray(q_lens), jnp.asarray(self._cu_q),
-                jnp.asarray(page_tables), jnp.asarray(ctx_lens),
-                jnp.asarray(temps), jnp.asarray(top_ps),
-                jnp.asarray(top_ks), jnp.asarray(seeds))
-        if self.spec is not None:
-            args += (jnp.asarray(spec_lens),)
+        # the step's ONE host-to-device transfer; placed as any
+        # uncommitted array, so a sharded pool has it where it wants it
+        packed_dev = jax.device_put(packed)
+        self.counters["h2d_copies"].inc()
         states = ()
         if self.hybrid:
-            args += (jnp.asarray(state_slots),)
             st = self.state_store
             states = (st.conv, st.ssm) if st is not None else ((), ())
         if traced:
             self._enter_phase(tr, "step.dispatch")
         # the call returns once the executable is enqueued; the host
         # waits for the device in the fetch below
-        out = self._compiled["unified"](*args, self.pool.k_pages,
-                                        self.pool.v_pages, *states)
+        out, new_k, new_v, *new_states = self._compiled["unified"](
+            self.params, packed_dev, self.pool.k_pages, self.pool.v_pages,
+            *states)
         if traced:
             self._enter_phase(tr, "step.fetch")
-        accs = moe_load = None
-        if self.hybrid:
-            next_tokens, moe_load, new_k, new_v, new_conv, new_ssm = out
-        elif self.spec is not None:
-            next_tokens, accepted, new_k, new_v = out
-            accs = np.asarray(accepted)         # [rows] int32
-        else:
-            next_tokens, new_k, new_v = out
-        toks = np.asarray(next_tokens)          # [rows] int32, ever
-        if moe_load is not None:                # [moe layers, held] int32
-            moe_load = np.asarray(moe_load)
+        # the ONE device-to-host fetch: [rows] int32 tokens and, beside
+        # them, the expert load (hybrid) / accepted lengths (spec)
+        out = self.layout.split(np.asarray(out))
+        self.counters["d2h_fetches"].inc()
+        toks = out["next_tokens"]
+        accs, moe_load = out.get("accepted"), out.get("moe_load")
         t1 = self._now()
         dt = t1 - t0
         self._call = (t0, t1, len(rows), kv_tokens)
@@ -1101,7 +1087,7 @@ class Engine:
             self._enter_phase(tr, "step.commit", t1)
         self.pool.set_pages(new_k, new_v)
         if self.state_store is not None:
-            self.state_store.set_arrays(new_conv, new_ssm)
+            self.state_store.set_arrays(*new_states)
         # what the step spends on the engine's own counters and span
         # attributes (traced steps put the ``account`` span round it)
         ta = self._now() if traced else 0.0
@@ -1121,7 +1107,8 @@ class Engine:
             # static predictions up by it at report time)
             tr.complete("unified_step", t0, dt, track="engine",
                         exec=f"{self.name}/unified", step=self.steps,
-                        rows=len(rows), tokens=kv_tokens, **attrs)
+                        rows=len(rows), tokens=kv_tokens,
+                        h2d_bytes=packed.nbytes, **attrs)
         # classify by SLOT, not q_len: a chunk_size=1 prefill chunk is
         # still a prefill chunk, and a verify row is neither
         n_decode = sum(1 for _, _, row in rows if row < s)
@@ -1346,15 +1333,7 @@ class Engine:
         # latent layout — build each spec from its own arrays
         k_pages = tuple(sds(p) for p in self.pool.k_pages)
         v_pages = tuple(sds(p) for p in self.pool.v_pages)
-        t, nr, maxp = self.n_tokens, self.n_rows, self.max_pages_per_seq
-        i32 = lambda *s: jax.ShapeDtypeStruct(s, np.int32)  # noqa: E731
-        f32 = lambda *s: jax.ShapeDtypeStruct(s, np.float32)  # noqa: E731
-        args = (params, i32(t), i32(t), i32(t), i32(t), i32(nr),
-                i32(nr + 1), i32(nr, maxp), i32(nr), f32(nr), f32(nr),
-                i32(nr), i32(nr)) \
-            + ((i32(nr),) if self.spec is not None or self.hybrid
-               else ()) \
-            + (k_pages, v_pages)
+        args = (params, self.layout.abstract(), k_pages, v_pages)
         if self.hybrid:
             st = self.state_store
             args += (tuple(sds(a) for a in st.conv),
