@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
-from typing import Optional, Tuple
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -29,7 +29,55 @@ from ..obs.phases import phase
 from jax.sharding import PartitionSpec as P
 
 
-MIXERS = ("mamba2", "attention", "moe", "mla")
+MIXERS = ("mamba2", "attention", "moe", "mla", "dsa", "swa", "mlp")
+# mixers that keep pages in the K/V pool
+PAGED_MIXERS = ("attention", "mla", "dsa", "swa")
+
+
+class LatentGeometry(NamedTuple):
+    """One latent-attention mixer kind's sizes.  A stack may run several
+    kinds side by side (``GPTConfig.mixer_geometry``): the geometry hangs
+    on the mixer kind, not on the stack.  ``window`` counts the keys a
+    query reads, itself included (0: the whole context); an indexer
+    (``index_topk`` > 0) scores every cached position with
+    ``index_heads`` heads of ``index_dim`` (rotary on the first
+    ``index_rope``) and attention reads the ``index_topk`` best;
+    ``q_rescale`` / ``kv_rescale`` multiply the two latents behind their
+    norms; ``gate`` multiplies a head's output by ``sigmoid(u W_g)``."""
+    heads: int
+    q_rank: Optional[int]
+    latent: int
+    nope: int
+    rope: int
+    v: int
+    theta: float
+    scale: float
+    interleave: bool = False
+    q_rescale: float = 1.0
+    kv_rescale: float = 1.0
+    window: int = 0
+    index_heads: int = 0
+    index_dim: int = 0
+    index_rope: int = 0
+    index_topk: int = 0
+    gate: bool = False
+
+    @property
+    def rope_lanes(self) -> int:
+        """The rotary stream's page width: zero lanes up to a multiple
+        of 128 (``GPTConfig.latent_page_dims`` says why)."""
+        return -(-self.rope // 128) * 128
+
+
+class PageLayer(NamedTuple):
+    """One paged layer's page arrays: ``space`` is the page-id space it
+    allocates from ("full": a page a ``page_size`` tokens of context;
+    "window": only the pages a window can reach), then the widths of its
+    latent, rotary and index-key streams (0: no such stream)."""
+    space: str
+    latent: int
+    rope: int
+    index: int
 
 
 @dataclass
@@ -96,8 +144,13 @@ class GPTConfig:
     # pre-norm and one residual — "mamba2" | "attention" | "moe".  None
     # is today's block (attention + MLP in every layer).  The K/V pool
     # then holds the attention layers only and a state-slot store the
-    # mamba2 layers (serving/kv_pool.py).
+    # mamba2 layers (serving/kv_pool.py).  "dsa" (latent attention over
+    # the positions an indexer picks) and "swa" (latent attention over a
+    # window) take their sizes from ``mixer_geometry[kind]``, so one
+    # stack holds latent layers of different geometry; "mlp" is a dense
+    # gated MLP ``ffn_hidden_size`` wide (a leading dense layer).
     layer_pattern: Optional[Tuple[str, ...]] = None
+    mixer_geometry: Optional[Dict[str, LatentGeometry]] = None
     norm_eps: Optional[float] = None        # None -> the norm's own default
     mamba_num_heads: int = 0
     mamba_head_dim: int = 0
@@ -134,10 +187,21 @@ class GPTConfig:
                     f"the {self.num_layers} layers, got {self.layer_pattern}")
             if "mla" in self.layer_pattern and (
                     self.kv_latent_dim is None
-                    or "attention" in self.layer_pattern):
+                    or len(set(self.layer_pattern) & set(PAGED_MIXERS)) > 1):
                 raise ValueError(
                     "an mla layer needs kv_latent_dim, and one page pool "
-                    "holds one layout: not beside plain attention layers")
+                    "holds one layout of it: not beside attention, dsa or "
+                    "swa layers")
+            for kind in ("dsa", "swa"):
+                if kind in self.layer_pattern and (
+                        "attention" in self.layer_pattern
+                        or kind not in (self.mixer_geometry or {})):
+                    raise ValueError(
+                        f"a {kind} layer takes its sizes from "
+                        f"mixer_geometry[{kind!r}], and latent pages do "
+                        f"not stand beside plain attention layers")
+            if "mlp" in self.layer_pattern and not self.ffn_hidden_size:
+                raise ValueError("an mlp layer needs ffn_hidden_size")
             if "moe" in self.layer_pattern:
                 held, off = self.held_experts, self.expert_offset
                 if not (self.num_experts > 0 and held >= 1 and off >= 0
@@ -221,7 +285,41 @@ class GPTConfig:
     @property
     def paged_layers(self) -> Tuple[int, ...]:
         """Layers that keep pages in the K/V pool, in pool order."""
-        return self.layers_of("mla") or self.layers_of("attention")
+        if self.layer_pattern is None:
+            return tuple(range(self.num_layers))
+        return tuple(i for i, m in enumerate(self.layer_pattern)
+                     if m in PAGED_MIXERS)
+
+    def geometry(self, kind: str) -> LatentGeometry:
+        """The sizes of latent mixer ``kind``: the stack's own scalars
+        for "mla", ``mixer_geometry[kind]`` for "dsa" / "swa"."""
+        if kind != "mla":
+            return self.mixer_geometry[kind]
+        return LatentGeometry(
+            heads=self.num_heads, q_rank=self.mla_q_rank,
+            latent=self.kv_latent_dim, nope=self.nope_dim,
+            rope=self.rope_dim, v=self.v_dim, theta=self.rope_theta,
+            scale=self.mla_softmax_scale, interleave=self.rope_interleave)
+
+    @property
+    def page_layers(self) -> Optional[Tuple[PageLayer, ...]]:
+        """Each paged layer's page arrays, in pool order, where the
+        stack's latent layers differ by kind (None: one layout for every
+        paged layer, ``latent_page_dims`` or the full-head one)."""
+        if not (self.layers_of("dsa") or self.layers_of("swa")):
+            return None
+        out = []
+        for i in self.paged_layers:
+            g = self.geometry(self.layer_pattern[i])
+            out.append(PageLayer("window" if g.window else "full", g.latent,
+                                 g.rope_lanes, g.index_dim))
+        return tuple(out)
+
+    @property
+    def window_tokens(self) -> int:
+        """Keys a window layer's query reads (0: no window layer)."""
+        return self.mixer_geometry["swa"].window \
+            if self.layers_of("swa") else 0
 
     def layers_of(self, mixer: str) -> Tuple[int, ...]:
         """Layer indices running ``mixer``; a plain stack has attention

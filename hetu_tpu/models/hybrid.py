@@ -1,5 +1,6 @@
 """Hybrid stacks: one mixer per layer — Mamba-2 / attention / latent
-attention (MLA) / MoE.
+attention (MLA; over an indexer's selection "dsa"; over a window "swa") /
+MoE / dense gated MLP.
 
 A ``GPTConfig`` with a ``layer_pattern`` runs ``x = x + mixer_l(RMSNorm_l
 (x))`` for every layer, a final norm and an untied head.  This module is
@@ -25,6 +26,11 @@ Tensors (a projection ``W`` is ``[out, in]``, used as ``x @ W.T``)::
     h{i}.attn.k_up.weight [nh, n, d_c]   .v_up.weight [nh, v, d_c]
                           (the two halves of W_kvb, as decode absorbs them)
     h{i}.attn.out.weight [H, nh*v]
+    dsa / swa: the mla tensors at ``cfg.geometry(kind)``'s sizes, and
+    h{i}.attn.gate.weight [nh, H]           one scalar a head
+    h{i}.attn.index.q.weight [IH*ID, R]     .index.w.weight [IH, H]  (dsa)
+    h{i}.attn.index.k.weight [ID, H]   .index.k_norm.weight / .bias [ID]
+    h{i}.mlp.gate.weight / .up.weight [F, H]   h{i}.mlp.down.weight [H, F]
     h{i}.moe.router.weight [E_all, H]   .router.bias [E_all]  (float32)
     h{i}.moe.latent_down.weight [L, H]  h{i}.moe.latent_up.weight [H, L]
     h{i}.moe.experts.w1 [E_held, L, F]  h{i}.moe.experts.w2 [E_held, F, L]
@@ -41,10 +47,13 @@ stack being a pattern: the prefix cache and speculation for a pattern
 with an ``M`` layer (a page prefix carries no recurrent state);
 speculation and page quantisation for every pattern; ``L`` beside ``*``
 (one pool, one layout).  ``mistral4_config`` is the translation for the
-``(L, E) x depth`` stacks of ``model_type: mistral4``.
+``(L, E) x depth`` stacks of ``model_type: mistral4``, ``dots3_config``
+for ``model_type: dots3_note`` (indexed and window latent layers of
+different geometry, a leading dense layer).
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict, Tuple
 
@@ -57,14 +66,36 @@ from ..obs.phases import phase
 from ..ops.moe_grouped import ACTIVATIONS, grouped_experts
 from ..ops.ssd import causal_conv, ssd_chunk_scan, ssd_decode_step
 from .generate import norm_eps
-from .gpt import GPTConfig
+from .gpt import GPTConfig, LatentGeometry
 
 F32 = jnp.float32
+LAYER_NORM_EPS = 1e-5           # the indexer's key norm (a LayerNorm)
+_MASKED = -1e30                 # a score outside the selection / window
 MIXER_OF = {"M": "mamba2", "*": "attention", "E": "moe", "L": "mla"}
 # float32 whatever the model's dtype: the recurrence's own parameters and
 # the router (its scores decide a top-k)
 _F32_PARAMS = ("mamba.dt_bias", "mamba.A_log", "mamba.D",
                "moe.router.weight", "moe.router.bias")
+
+
+def _refuse_unbuilt(pub: dict) -> None:
+    """What no translation builds, refused where the keys are read."""
+    if pub.get("n_group", 1) != 1 or pub.get("topk_group", 1) != 1:
+        raise ValueError("group-limited routing (n_group > 1) is not built")
+
+
+def _expert_keys(pub: dict, shared_width: str) -> dict:
+    """The expert layer's keys, alike in every published family the
+    translations below read (as cut: ``n_routed_experts`` = experts held
+    here, ``moe_router_outputs`` = the router's width)."""
+    return dict(
+        num_experts=pub.get("moe_router_outputs", pub["n_routed_experts"]),
+        experts_held=pub["n_routed_experts"],
+        expert_offset=pub.get("expert_offset", 0),
+        moe_top_k=pub["num_experts_per_tok"],
+        moe_router_scale=float(pub["routed_scaling_factor"]),
+        moe_ffn_size=pub["moe_intermediate_size"],
+        moe_shared_ffn_size=pub[shared_width] * pub["n_shared_experts"])
 
 
 def hybrid_config(pub: dict, **overrides) -> GPTConfig:
@@ -77,8 +108,7 @@ def hybrid_config(pub: dict, **overrides) -> GPTConfig:
                          "disagree")
     if pub["hidden_size"] != pub["num_attention_heads"] * pub["head_dim"]:
         raise ValueError("head_dim other than hidden / heads is not built")
-    if pub.get("n_group", 1) != 1 or pub.get("topk_group", 1) != 1:
-        raise ValueError("group-limited routing is not built")
+    _refuse_unbuilt(pub)
     kw = dict(
         vocab_size=pub["vocab_size"], hidden_size=pub["hidden_size"],
         num_layers=len(pattern), num_heads=pub["num_attention_heads"],
@@ -94,15 +124,9 @@ def hybrid_config(pub: dict, **overrides) -> GPTConfig:
         mamba_state_dim=pub["ssm_state_size"],
         mamba_conv_kernel=pub["conv_kernel"],
         mamba_chunk_size=pub["chunk_size"],
-        num_experts=pub.get("moe_router_outputs", pub["n_routed_experts"]),
-        experts_held=pub["n_routed_experts"],
-        expert_offset=pub.get("expert_offset", 0),
-        moe_top_k=pub["num_experts_per_tok"], moe_router="sigmoid_bias",
-        moe_router_scale=float(pub["routed_scaling_factor"]),
-        moe_ffn_size=pub["moe_intermediate_size"],
+        moe_router="sigmoid_bias",
         moe_latent_dim=pub.get("moe_latent_size"),
-        moe_shared_ffn_size=pub["moe_shared_expert_intermediate_size"]
-        * pub["n_shared_experts"])
+        **_expert_keys(pub, "moe_shared_expert_intermediate_size"))
     kw.update(overrides)
     return GPTConfig(**kw)
 
@@ -116,11 +140,11 @@ def mistral4_config(pub: dict, **overrides) -> GPTConfig:
     (``pub["assumed"]``): the softmax router is this translation's, the
     softmax scale follows the ``deepseek_v3`` convention ``(n + r) ** -0.5
     * m * m`` with ``m = 0.1 * mscale_all_dim * ln(factor) + 1``."""
-    if pub.get("n_group", 1) != 1 or pub.get("topk_group", 1) != 1:
-        raise ValueError("group-limited routing (n_group > 1) is not built")
+    _refuse_unbuilt(pub)
     if pub.get("first_k_dense_replace", 0):
         raise ValueError("leading dense layers (first_k_dense_replace > 0) "
-                         "are not built: every layer is an expert layer")
+                         "are built for dots3_note (dots3_config), not for "
+                         "this family: every layer is an expert layer")
     rp = pub["rope_parameters"]
     if rp.get("rope_type", rp.get("type")) != "yarn":
         raise ValueError("the rotary stream is built with YaRN tables")
@@ -154,20 +178,89 @@ def mistral4_config(pub: dict, **overrides) -> GPTConfig:
         rope_yarn=(factor, orig, float(rp["beta_fast"]),
                    float(rp["beta_slow"]), table),
         q_pos_scale=(beta, orig) if beta else None,
-        num_experts=pub.get("moe_router_outputs", pub["n_routed_experts"]),
-        experts_held=pub["n_routed_experts"],
-        expert_offset=pub.get("expert_offset", 0),
-        moe_top_k=pub["num_experts_per_tok"], moe_router="softmax",
+        moe_router="softmax",
         moe_norm_topk=bool(pub["norm_topk_prob"]), moe_gated=True,
-        moe_router_scale=float(pub["routed_scaling_factor"]),
-        moe_ffn_size=pub["moe_intermediate_size"],
-        moe_shared_ffn_size=pub["moe_intermediate_size"]
-        * pub["n_shared_experts"])
+        **_expert_keys(pub, "moe_intermediate_size"))
     kw.update(overrides)
     return GPTConfig(**kw)
 
 
-def mla_rotary_tables(cfg: GPTConfig, max_len: int):
+def dots3_config(pub: dict, **overrides) -> GPTConfig:
+    """The one translation from the published ``config.json`` keys of a
+    ``dots3_note``-type model (as cut: ``n_routed_experts`` = experts held
+    here, ``moe_router_outputs`` = the router's width, ``layer_types`` =
+    the layers kept) to ``GPTConfig``.  A published layer is two pattern
+    entries: its attention — "dsa" for ``full_attention`` (MLA at the
+    plain keys' sizes, an indexer, a head gate), "swa" for
+    ``sliding_attention`` (MLA at the ``swa_*`` sizes over
+    ``sliding_window_size`` keys, a head gate) — then its FFN: "mlp" for
+    the first ``first_k_dense_replace`` layers, "moe" after (sigmoid
+    scores, top-k of score + bias, the chosen renormalised:
+    ``noaux_tc`` with one group).  What the keys name and do not spell is
+    the caller's to state (``pub["assumed"]``): the two latents behind
+    their norms times ``sqrt(hidden / rank)``
+    (``apply_mla_qkv_lora_rescale``), the head gate on the mixer's normed
+    input, the indexer's LayerNorm / rotary split / weight scaling."""
+    _refuse_unbuilt(pub)
+    if pub["scoring_func"] != "sigmoid" or pub["topk_method"] != "noaux_tc":
+        raise ValueError("the router is built for sigmoid scores with a "
+                         "selection bias (noaux_tc)")
+    if pub.get("rope_scaling"):
+        raise ValueError("a scaled rotary stream is not built here")
+    if pub.get("moe_layer_freq", 1) != 1:
+        raise ValueError("moe_layer_freq other than 1 is not built")
+    for key in ("attention_gate_type", "swa_attention_gate_type"):
+        if pub.get(key) != "headwise":
+            raise ValueError(f"{key} other than headwise is not built")
+    kinds = {"full_attention": "dsa", "sliding_attention": "swa"}
+    types = pub["layer_types"]
+    if len(types) != pub["num_hidden_layers"] or set(types) - set(kinds):
+        raise ValueError("layer_types names full_attention or "
+                         "sliding_attention for each of num_hidden_layers")
+    hidden = pub["hidden_size"]
+    rescale = bool(pub.get("apply_mla_qkv_lora_rescale"))
+
+    def geo(pre: str, **kw) -> LatentGeometry:
+        g = lambda k: pub[pre + k]  # noqa: E731
+        q_rank, latent = g("q_lora_rank"), g("kv_lora_rank")
+        nope, rope = g("qk_nope_head_dim"), g("qk_rope_head_dim")
+        return LatentGeometry(
+            heads=g("num_attention_heads"), q_rank=q_rank, latent=latent,
+            nope=nope, rope=rope, v=g("v_head_dim"),
+            theta=float(g("rope_theta")), scale=(nope + rope) ** -0.5,
+            interleave=bool(pub.get("rope_interleave", True)),
+            q_rescale=math.sqrt(hidden / q_rank) if rescale else 1.0,
+            kv_rescale=math.sqrt(hidden / latent) if rescale else 1.0,
+            gate=True, **kw)
+
+    geometry = {
+        "dsa": geo("", index_heads=pub["index_n_heads"],
+                   index_dim=pub["index_head_dim"],
+                   index_rope=pub["qk_rope_head_dim"],
+                   index_topk=pub["index_topk"]),
+        "swa": geo("swa_", window=pub["sliding_window_size"])}
+    dense = pub.get("first_k_dense_replace", 0)
+    pattern = tuple(m for l, t in enumerate(types)
+                    for m in (kinds[t], "mlp" if l < dense else "moe"))
+    kw = dict(
+        vocab_size=pub["vocab_size"], hidden_size=hidden,
+        num_layers=len(pattern), num_heads=pub["num_attention_heads"],
+        ffn_hidden_size=pub["intermediate_size"],
+        max_seq_len=pub["max_position_embeddings"],
+        activation=pub["hidden_act"], norm="rmsnorm", position="rotary",
+        norm_eps=float(pub["rms_norm_eps"]),
+        tie_embeddings=bool(pub["tie_word_embeddings"]), sp=False,
+        dtype=pub.get("dtype", "bfloat16"), layer_pattern=pattern,
+        mixer_geometry=geometry, moe_router="sigmoid_bias",
+        moe_gated=True, **_expert_keys(pub, "moe_intermediate_size"))
+    if not bool(pub["norm_topk_prob"]):
+        raise ValueError("sigmoid routing is built with the chosen scores "
+                         "renormalised (norm_topk_prob)")
+    kw.update(overrides)
+    return GPTConfig(**kw)
+
+
+def mla_rotary_tables(cfg: GPTConfig, max_len: int, geo=None):
     """``(cos, sin [max_len, r], q_scale [max_len])`` of the rotary
     stream, float32: YaRN frequencies where ``cfg.rope_yarn`` (each
     frequency between its own and its ``1 / factor``, by how many turns
@@ -175,11 +268,13 @@ def mla_rotary_tables(cfg: GPTConfig, max_len: int):
     halves laid ``[angles | angles]`` for the half-split rotation (an
     interleaved stream is brought into that order first: ``mla_rotate``).
     ``q_scale`` is the per-position factor on q (1 without
-    ``cfg.q_pos_scale``)."""
-    d = cfg.rope_dim
-    freqs = cfg.rope_theta ** (np.arange(0, d, 2, dtype=np.float64) / d)
+    ``cfg.q_pos_scale``).  ``geo`` (a dsa / swa layer's geometry) gives
+    its own width and base, unscaled."""
+    d, theta = (geo.rope, geo.theta) if geo else (cfg.rope_dim,
+                                                  cfg.rope_theta)
+    freqs = theta ** (np.arange(0, d, 2, dtype=np.float64) / d)
     inv, table = 1.0 / freqs, 1.0
-    if cfg.rope_yarn:
+    if cfg.rope_yarn and geo is None:
         factor, orig, fast, slow, table = cfg.rope_yarn
 
         def turn_dim(turns):
@@ -195,7 +290,7 @@ def mla_rotary_tables(cfg: GPTConfig, max_len: int):
     ang = np.outer(pos, inv)
     emb = np.concatenate([ang, ang], -1)
     scale = np.ones(max_len)
-    if cfg.q_pos_scale:
+    if cfg.q_pos_scale and geo is None:
         beta, period = cfg.q_pos_scale
         scale = 1 + beta * np.log1p(np.floor(pos / period))
     as32 = lambda a: jnp.asarray(a.astype(np.float32))  # noqa: E731
@@ -225,17 +320,31 @@ def param_shapes(cfg: GPTConfig) -> Dict[str, Tuple[int, ...]]:
             q, kv = c.num_heads * c.head_dim, c.kv_heads * c.head_dim
             out[p + "attn.qkv.weight"] = (q + 2 * kv, hd)
             out[p + "attn.out.weight"] = (hd, q)
-        elif mixer == "mla":
-            nh, d_c, qk = c.num_heads, c.kv_latent_dim, c.nope_dim + c.rope_dim
-            if c.mla_q_rank:
-                out[p + "attn.q_a.weight"] = (c.mla_q_rank, hd)
-                out[p + "attn.q_a_norm.weight"] = (c.mla_q_rank,)
-            out[p + "attn.q_b.weight"] = (nh * qk, c.mla_q_rank or hd)
-            out[p + "attn.kv_a.weight"] = (d_c + c.rope_dim, hd)
+        elif mixer in ("mla", "dsa", "swa"):
+            g = c.geometry(mixer)
+            nh, d_c, qk = g.heads, g.latent, g.nope + g.rope
+            if g.q_rank:
+                out[p + "attn.q_a.weight"] = (g.q_rank, hd)
+                out[p + "attn.q_a_norm.weight"] = (g.q_rank,)
+            out[p + "attn.q_b.weight"] = (nh * qk, g.q_rank or hd)
+            out[p + "attn.kv_a.weight"] = (d_c + g.rope, hd)
             out[p + "attn.kv_a_norm.weight"] = (d_c,)
-            out[p + "attn.k_up.weight"] = (nh, c.nope_dim, d_c)
-            out[p + "attn.v_up.weight"] = (nh, c.v_dim, d_c)
-            out[p + "attn.out.weight"] = (hd, nh * c.v_dim)
+            out[p + "attn.k_up.weight"] = (nh, g.nope, d_c)
+            out[p + "attn.v_up.weight"] = (nh, g.v, d_c)
+            out[p + "attn.out.weight"] = (hd, nh * g.v)
+            if g.gate:
+                out[p + "attn.gate.weight"] = (nh, hd)
+            if g.index_topk:
+                out[p + "attn.index.q.weight"] = (
+                    g.index_heads * g.index_dim, g.q_rank or hd)
+                out[p + "attn.index.k.weight"] = (g.index_dim, hd)
+                out[p + "attn.index.k_norm.weight"] = (g.index_dim,)
+                out[p + "attn.index.k_norm.bias"] = (g.index_dim,)
+                out[p + "attn.index.w.weight"] = (g.index_heads, hd)
+        elif mixer == "mlp":
+            for n in ("gate", "up"):
+                out[p + f"mlp.{n}.weight"] = (c.ffn_hidden_size, hd)
+            out[p + "mlp.down.weight"] = (hd, c.ffn_hidden_size)
         else:
             out[p + "moe.router.weight"] = (c.num_experts, hd)
             if c.moe_router == "sigmoid_bias":
@@ -268,7 +377,8 @@ def init_state(cfg: GPTConfig, seed: int, time_step=(0.001, 0.1, 1e-4),
     """Seeded random weights, made on the device, one jitted call per
     layer so that no more than one layer's temporaries are live: matrices
     normal(0, init_std), the projections back into the residual stream
-    (``out_proj``, ``attn.out``, ``latent_up``, ``shared.down``) scaled by
+    (``out_proj``, ``attn.out``, ``latent_up``, ``shared.down``,
+    ``mlp.down``) scaled by
     ``1 / sqrt(num_layers)`` (the published ``rescale_prenorm_residual``:
     one residual branch a layer); norms 1, conv bias 0; ``dt`` log-uniform
     in ``[time_step_min, time_step_max]`` floored at ``time_step_floor``
@@ -290,7 +400,7 @@ def init_state(cfg: GPTConfig, seed: int, time_step=(0.001, 0.1, 1e-4),
         dt = param_dtype(cfg, tail)
         if tail.endswith("norm.weight") or tail in ("ln_f.weight", "mamba.D"):
             return jnp.ones(shape, dt)
-        if tail == "mamba.conv.bias":
+        if tail == "mamba.conv.bias" or tail.endswith("norm.bias"):
             return jnp.zeros(shape, dt)
         if tail == "moe.router.bias":
             return router_bias_std * jax.random.normal(key, shape, dt)
@@ -302,7 +412,7 @@ def init_state(cfg: GPTConfig, seed: int, time_step=(0.001, 0.1, 1e-4),
             step = jnp.maximum(step, t_floor)
             return step + jnp.log(-jnp.expm1(-step))
         s = down if tail in ("mamba.out_proj.weight", "attn.out.weight",
-                             "moe.latent_up.weight",
+                             "moe.latent_up.weight", "mlp.down.weight",
                              "moe.shared.down.weight") else std
         if tail == "mamba.conv.weight":             # fan-in K
             s = 1.0 / math.sqrt(shape[0])
@@ -412,10 +522,14 @@ def mamba_gate_norm(cfg: GPTConfig, w: MambaWeights, y, z, dtype):
     return (v.reshape(n, -1) * w.norm.astype(F32)).astype(dtype)
 
 
-def _rms(cfg: GPTConfig, v, w):
+def _rms_eps(eps: float, v, w):
     f = v.astype(F32)
-    f = f * lax.rsqrt(jnp.mean(f * f, -1, keepdims=True) + norm_eps(cfg))
+    f = f * lax.rsqrt(jnp.mean(f * f, -1, keepdims=True) + eps)
     return (f * w.astype(F32)).astype(v.dtype)
+
+
+def _rms(cfg: GPTConfig, v, w):
+    return _rms_eps(norm_eps(cfg), v, w)
 
 
 def mla_in(cfg: GPTConfig, params: dict, i: int, u):
@@ -433,28 +547,298 @@ def mla_in(cfg: GPTConfig, params: dict, i: int, u):
     return q, _rms(cfg, kv[:, :d_c], g("kv_a_norm.weight")), kv[:, d_c:]
 
 
-def mla_rotate(cfg: GPTConfig, x, cos_t, sin_t):
+def latent_in(cfg: GPTConfig, params: dict, i: int, u,
+              geo: LatentGeometry):
+    """``mla_in`` at a mixer kind's own geometry (a dsa / swa layer): the
+    two latents times their rescale behind their norms.  Returns ``(q
+    [n, nh, nope + rope], c_kv [n, d_c], k_r [n, r], c_q [n, R])``; the
+    indexer reads ``c_q`` too."""
+    g = lambda n: params[f"h{i}.attn.{n}"]  # noqa: E731
+    c_q = _rms(cfg, u @ g("q_a.weight").T, g("q_a_norm.weight"))
+    c_q = c_q * jnp.asarray(geo.q_rescale, c_q.dtype)
+    kv = u @ g("kv_a.weight").T
+    c_kv = _rms(cfg, kv[:, :geo.latent], g("kv_a_norm.weight"))
+    q = (c_q @ g("q_b.weight").T).reshape(
+        u.shape[0], geo.heads, geo.nope + geo.rope)
+    return (q, c_kv * jnp.asarray(geo.kv_rescale, c_kv.dtype),
+            kv[:, geo.latent:], c_q)
+
+
+def head_gate(params: dict, i: int, u):
+    """``sigmoid(u W_g)`` [n, nh] float32: one scalar a head, on the
+    mixer's normed input; it multiplies the head's output before
+    ``W_o``."""
+    return jax.nn.sigmoid(
+        (u @ params[f"h{i}.attn.gate.weight"].T).astype(F32))
+
+
+def index_queries(params: dict, i: int, u, c_q, geo: LatentGeometry):
+    """The indexer's query side of tokens ``u`` [n, H] with their low-rank
+    q ``c_q`` [n, R]: ``(q [n, IH, ID], w [n, IH] float32)``, before the
+    rotation."""
+    g = lambda n: params[f"h{i}.attn.index.{n}"]  # noqa: E731
+    q = (c_q @ g("q.weight").T).reshape(
+        u.shape[0], geo.index_heads, geo.index_dim)
+    return q, (u @ g("w.weight").T).astype(F32) * (
+        geo.index_heads ** -0.5 * geo.index_dim ** -0.5)
+
+
+def index_keys(params: dict, i: int, u):
+    """The indexer's key of tokens ``u`` [n, H]: ``LayerNorm(u W_Ik)``
+    [n, ID] in ``u``'s dtype, before the rotation; what the pool caches
+    (rotated) beside the layer's latent."""
+    g = lambda n: params[f"h{i}.attn.index.{n}"]  # noqa: E731
+    k = (u @ g("k.weight").T).astype(F32)
+    k = (k - k.mean(-1, keepdims=True)) * lax.rsqrt(
+        k.var(-1, keepdims=True) + LAYER_NORM_EPS)
+    return (k * g("k_norm.weight").astype(F32) +
+            g("k_norm.bias").astype(F32)).astype(u.dtype)
+
+
+def rotate_index(x, cos_t, sin_t):
+    """The indexer's rotation of ``x [T, ..., ID]``: the first ``r``
+    lanes (the tables' width) by halves, the rest as they are."""
+    r = cos_t.shape[-1]
+    return jnp.concatenate(
+        [rotate_halves(x[..., :r], cos_t, sin_t), x[..., r:]], -1)
+
+
+def index_positions(cfg: GPTConfig, params: dict, i: int, u, positions):
+    """The positions dsa layer ``i`` selects for the queries at
+    ``positions`` of ONE sequence whose normed layer input is ``u`` [T,
+    H]: the serving step's own indexer arithmetic (``index_queries`` /
+    ``index_keys`` / ``rotate_index`` / ``index_scores`` /
+    ``index_select`` in the model's dtype) on a whole sequence at once,
+    for the check of the selection against the plain reference.
+    Returns ``[len(positions), k]`` int32, an empty place holding ``T``."""
+    geo = cfg.geometry("dsa")
+    cos, sin, _ = mla_rotary_tables(cfg, u.shape[0], geo)
+    layer = {k: v for k, v in params.items() if k.startswith(f"h{i}.attn.")}
+    return _index_positions(
+        layer, u.astype(param_dtype(cfg, "attn.q_a.weight")),
+        jnp.asarray(positions, jnp.int32), cos, sin, i=i, geo=geo,
+        eps=norm_eps(cfg))
+
+
+@functools.partial(jax.jit, static_argnames=("i", "geo", "eps"))
+def _index_positions(params, u, pos, cos, sin, *, i: int, geo, eps: float):
+    g = lambda n: params[f"h{i}.attn.{n}"]  # noqa: E731
+    uq = u[pos]
+    c_q = _rms_eps(eps, uq @ g("q_a.weight").T, g("q_a_norm.weight"))
+    c_q = c_q * jnp.asarray(geo.q_rescale, c_q.dtype)
+    q, w = index_queries(params, i, uq, c_q, geo)
+    q = rotate_index(q, cos[pos], sin[pos])
+    keys = rotate_index(index_keys(params, i, u), cos, sin)
+
+    def one(args):
+        q_b, w_b, pos_b = args
+        sel, valid = index_select(index_scores(q_b, keys, w_b), pos_b,
+                                  geo.index_topk)
+        return jnp.where(valid, sel, u.shape[0])
+
+    return _by_blocks(one, (q, w, pos), 32)
+
+
+def rotate_halves(x, cos_t, sin_t):
+    """``x [T, ..., r]`` laid ``[first halves | second halves]`` turned by
+    the per-token tables ``cos_t, sin_t [T, r]``, in float32."""
+    half = x.shape[-1] // 2
+    rot = jnp.concatenate([-x[..., half:], x[..., :half]], -1)
+    shape = (x.shape[0],) + (1,) * (x.ndim - 2) + (x.shape[-1],)
+    return (x.astype(F32) * cos_t.reshape(shape) +
+            rot.astype(F32) * sin_t.reshape(shape)).astype(x.dtype)
+
+
+def index_scores(q, keys, w):
+    """The indexer's score of every (query, cached position) pair,
+    float32: ``sum_j w[t, j] relu(q[t, j] . k[s])``, the products
+    accumulated in float32.  ``q [n, IH, ID]``, ``w [n, IH]`` float32;
+    ``keys`` is ``[S, ID]`` (one row's context, shared by the ``n``
+    queries) or ``[n, S, ID]`` (a context a query)."""
+    eq = "njd,sd->njs" if keys.ndim == 2 else "njd,nsd->njs"
+    s = jnp.einsum(eq, q, keys, preferred_element_type=F32)
+    return jnp.einsum("njs,nj->ns", jax.nn.relu(s), w)
+
+
+def index_select(scores, qpos, topk: int):
+    """The ``topk`` best positions of each query among ``s <= qpos``,
+    EXACT: the set ``lax.top_k`` of the float32 scores gives (a tie at the
+    k-th score to the lower position), found without a sort.  The k-th
+    largest score is searched bit by bit on the scores' ordered integer
+    image (32 counts over the row); the positions above it, and the first
+    ties, are then read out in position order: a slot of the result finds
+    its 128-position block by the blocks' running counts and its lane by
+    the running count inside that block.  Returns ``(positions [n, k]
+    int32 ascending, valid [n, k])``; a query with fewer than ``k``
+    positions behind it reads them all (a full sort of 33,792 scores for
+    each of a chunk's queries was the longest thing in a chunk step:
+    PERF.md, PR 39)."""
+    n, s = scores.shape
+    k = min(topk, s)
+    lanes = 128
+    seen = jnp.arange(s)[None, :] <= qpos[:, None]
+    bits = lax.bitcast_convert_type(
+        jnp.where(scores == 0, 0.0, scores).astype(F32), jnp.int32)
+    # float order as unsigned order; 0 is kept for what a query cannot see
+    key = jnp.where(bits < 0, ~bits, bits ^ jnp.int32(-2 ** 31))
+    key = jnp.where(seen, lax.bitcast_convert_type(key, jnp.uint32), 0)
+    pad = -s % lanes
+    key = jnp.pad(key, ((0, 0), (0, pad)))
+
+    def refine(b, thr):
+        cand = thr | (jnp.uint32(1) << (31 - b).astype(jnp.uint32))
+        enough = jnp.sum(key >= cand[:, None], -1) >= k
+        return jnp.where(enough, cand, thr)
+
+    thr = lax.fori_loop(0, 32, refine, jnp.zeros((n,), jnp.uint32))[:, None]
+    above, tie = key > thr, (key == thr) & (key > 0)
+    room = k - jnp.sum(above, -1, keepdims=True)
+    member = above | (tie & (jnp.cumsum(tie, -1) <= room))
+    blocks = member.reshape(n, -1, lanes)
+    count = blocks.sum(-1)                                  # [n, nb]
+    end = jnp.cumsum(count, -1)
+    j = jnp.arange(k)
+    blk = jnp.sum(end[:, None, :] <= j[None, :, None], -1)  # [n, k]
+    blk = jnp.minimum(blk, blocks.shape[1] - 1)
+    before = jnp.take_along_axis(end - count, blk, 1)
+    inside = jnp.cumsum(jnp.take_along_axis(
+        blocks, blk[:, :, None], 1).astype(jnp.int32), -1)  # [n, k, lanes]
+    lane = jnp.sum(inside <= (j[None, :] - before)[:, :, None], -1)
+    valid = j[None, :] < end[:, -1:]
+    pos = jnp.where(valid, blk * lanes + lane, 0)
+    return pos.astype(jnp.int32), valid
+
+
+def selected_attention(q_cat, sel, d_c: int, valid, scale: float):
+    """Absorbed attention of each query over ITS OWN gathered positions:
+    ``q_cat [n, nh, w]`` float32 against ``sel [n, k, w]``, the pool's
+    rows ``c_kv | k_r | zero lanes`` in its dtype (``q_cat``'s rotary part
+    is padded alike), ``valid [n, k]``.  The products run in the pool's
+    dtype with float32 accumulation, the softmax in float32.  Returns the
+    latent output ``[n, nh, d_c]`` float32."""
+    dt = sel.dtype
+    s = jnp.einsum("nhw,nkw->nhk", q_cat.astype(dt), sel,
+                   preferred_element_type=F32)
+    s = jnp.where(valid[:, None, :], s * scale, _MASKED)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("nhk,nkc->nhc", p.astype(dt), sel[..., :d_c],
+                      preferred_element_type=F32)
+
+
+def _by_blocks(f, arrays, block: int):
+    """``f`` over blocks of ``block`` leading rows of the arrays of
+    ``arrays`` (one length ``n``), one block live at a time, the results
+    joined: what bounds a step's temporaries by the block and not by the
+    chunk."""
+    n = arrays[0].shape[0]
+    if n <= block:
+        return f(arrays)
+    pad = -n % block
+    cut = lambda a: jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1)  # noqa: E731
+                            ).reshape((-1, block) + a.shape[1:])
+    out = lax.map(f, tuple(cut(a) for a in arrays))
+    return out.reshape((-1,) + out.shape[2:])[:n]
+
+
+def indexed_attention(geo: LatentGeometry, iq, iw, q_cat, qpos, table,
+                      pools, block: int = 32):
+    """A dsa layer's attention for ``n`` query tokens at positions
+    ``qpos`` [n]: the indexer scores every cached position of the
+    query's context, the ``index_topk`` best are gathered out of the
+    pages and the absorbed attention runs over them alone.  ``table`` is
+    ONE row's page table ``[maxp]`` (a chunk: the queries share a
+    context, its index keys are gathered once) or ``[n, maxp]`` (decode
+    rows: a context a query).  ``pools`` = the layer's latent stream
+    (``c_kv | k_r | zero lanes``) and its index-key stream ``[P, 1, ps,
+    w]``; ``iq [n, IH, ID]`` / ``iw [n, IH]`` the rotated index queries
+    and head weights, ``q_cat [n, nh, w]`` the absorbed query, its rotary
+    part padded as the page's.  ``block`` queries are live at a time.
+    Returns the latent output ``[n, nh, d_c]`` float32.  The selection
+    stays on the device: nothing of it leaves the step."""
+    cp = pools[0].reshape(-1, pools[0].shape[-1])
+    ps = pools[0].shape[2]
+    shared = table.ndim == 1
+
+    def keys_of(tab):
+        """A context's index keys, gathered a page at a time (a page's
+        rows lie together: one 16 KB copy, not 64 of 256 B)."""
+        k = pools[1][tab]                        # [.., maxp, 1, ps, ID]
+        return k.reshape(tab.shape[:-1] + (-1, k.shape[-1]))
+
+    if shared:
+        with phase("attn_index"):
+            keys = keys_of(table)                               # [S, ID]
+
+    def one(args):
+        iq_b, iw_b, qc_b, qpos_b, *tab = args
+        tab = table[None] if shared else tab[0]
+        with phase("attn_index"):
+            k = keys if shared else keys_of(tab)
+            pos, valid = index_select(index_scores(iq_b, k, iw_b), qpos_b,
+                                      geo.index_topk)
+        with phase("attn_sparse"):
+            page = jnp.take_along_axis(
+                jnp.broadcast_to(tab, (pos.shape[0], tab.shape[-1])),
+                pos // ps, axis=1)
+            slot = page * ps + pos % ps                         # [n, k]
+            return selected_attention(qc_b, cp[slot], geo.latent, valid,
+                                      geo.scale)
+
+    return _by_blocks(one, (iq, iw, q_cat, qpos) +
+                      (() if shared else (table,)), block)
+
+
+def window_attention(geo: LatentGeometry, q_cat, qpos, table, base, pool):
+    """A swa layer's attention for ``n`` query tokens at positions
+    ``qpos`` [n] over the window-space pages of ``table``, whose first
+    slot holds position ``base``: ONE row's ``[wp]`` with a scalar
+    ``base`` (a chunk) or ``[n, wp]`` with ``base [n]`` (decode rows).
+    ``pool`` is the layer's stream ``c_kv | k_r | zero lanes``, ``q_cat
+    [n, nh, w]`` padded alike.  A query reads the ``geo.window`` positions
+    up to itself.  Returns the latent output ``[n, nh, d_c]`` float32."""
+    shared = table.ndim == 1
+    keys = pool[table].reshape(table.shape[:-1] + (-1, pool.shape[3]))
+    kpos = jnp.asarray(base)[..., None] + jnp.arange(keys.shape[-2])
+    kpos = jnp.broadcast_to(kpos, (qpos.shape[0], keys.shape[-2]))
+    keep = (kpos <= qpos[:, None]) & (kpos > qpos[:, None] - geo.window)
+    dt = keys.dtype
+    s = jnp.einsum("nhw,kw->nhk" if shared else "nhw,nkw->nhk",
+                   q_cat.astype(dt), keys, preferred_element_type=F32)
+    p = jax.nn.softmax(jnp.where(keep[:, None, :], s * geo.scale, _MASKED),
+                       axis=-1)
+    return jnp.einsum("nhk,kc->nhc" if shared else "nhk,nkc->nhc",
+                      p.astype(dt), keys[..., :geo.latent],
+                      preferred_element_type=F32)
+
+
+def gated_mlp(cfg: GPTConfig, params: dict, i: int, u):
+    """A dense gated MLP layer on ``u`` [n, H] (normed)."""
+    g = lambda n: params[f"h{i}.mlp.{n}.weight"]  # noqa: E731
+    act = ACTIVATIONS[cfg.activation]
+    return (act(u @ g("gate").T) * (u @ g("up").T)) @ g("down").T
+
+
+def mla_rotate(cfg: GPTConfig, x, cos_t, sin_t, interleave=None):
     """The rotary stream of ``x [T, ..., r]`` at per-token tables ``cos_t,
     sin_t [T, r]`` (``mla_rotary_tables`` gathered by position).  An
     interleaved stream (pairs ``(2i, 2i + 1)``) is brought into ``[evens
     | odds]`` and rotated by halves; it stays in that order — q and the
     cached key take the same road, and only their product is read."""
-    if cfg.rope_interleave:
+    if cfg.rope_interleave if interleave is None else interleave:
         x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], -1)
-    half = x.shape[-1] // 2
-    rot = jnp.concatenate([-x[..., half:], x[..., :half]], -1)
-    shape = (x.shape[0],) + (1,) * (x.ndim - 2) + (x.shape[-1],)
-    f = x.astype(F32)
-    return (f * cos_t.reshape(shape) +
-            rot.astype(F32) * sin_t.reshape(shape)).astype(x.dtype)
+    return rotate_halves(x, cos_t, sin_t)
 
 
-def mla_absorb_q(cfg: GPTConfig, params: dict, i: int, q, q_rot):
+def mla_absorb_q(cfg: GPTConfig, params: dict, i: int, q, q_rot,
+                 nope=None):
     """``W_kvb``'s k-half folded into q: ``q [T, nh, nope + r]`` with its
     rotary part already rotated as ``q_rot [T, nh, r]`` -> float32 ``[T,
-    nh, d_c + r]``, a query against the cached ``c_kv | k_r`` itself."""
+    nh, d_c + r]``, a query against the cached ``c_kv | k_r`` itself.
+    ``nope`` is the head's width outside the rotary stream where it is
+    not the stack's (a dsa / swa layer)."""
     k_up = params[f"h{i}.attn.k_up.weight"]
-    q_abs = jnp.einsum("thd,hdc->thc", q[..., :cfg.nope_dim].astype(F32),
+    nope = cfg.nope_dim if nope is None else nope
+    q_abs = jnp.einsum("thd,hdc->thc", q[..., :nope].astype(F32),
                        k_up.astype(F32))
     return jnp.concatenate([q_abs, q_rot.astype(F32)], -1)
 

@@ -34,11 +34,18 @@ __all__ = ["PHASES", "UNMAPPED", "phase", "current_phase",
 # (in / out projections, conv, scan + gate + group norm, moves of state
 # between the slot store and a row) and the expert layer's (router, latent
 # down / up, routed experts, shared expert) and the latent attention's
-# two folds of W_kvb into q and out of the latent output (mla_absorb)
+# two folds of W_kvb into q and out of the latent output (mla_absorb);
+# an indexed latent layer adds the indexer (its projections, scores and
+# top-k: attn_index) and the attention over the selection (the gather of
+# the selected positions and the softmax over them: attn_sparse), a
+# window latent layer its attention (attn_window), both the head gate
+# (attn_gate), and a leading dense layer its MLP (mlp_dense)
 PHASES = ("embed", "norm", "attn_proj", "attn_core", "mlp", "lm_head_ce",
           "optimizer", "grad_comm", "param_gather", "kv_scatter", "sample",
           "ssm_proj", "ssm_conv", "ssm_scan", "state_io",
-          "moe_router", "moe_latent", "moe_routed", "moe_shared", "mla_absorb")
+          "moe_router", "moe_latent", "moe_routed", "moe_shared", "mla_absorb",
+          "attn_index", "attn_sparse", "attn_window", "attn_gate",
+          "mlp_dense")
 UNMAPPED = "unmapped"
 
 # scope names that predate the vocabulary (parallel/comm.py's comm_tag

@@ -55,6 +55,7 @@ from ..ops.paged_attention import gather_pages, paged_attention_reference
 from ..ops.paged_kv_write import (kv_write_plan, paged_kv_write,
                                   paged_kv_write_reference, write_tile)
 from ..ops.quantization import quantize_rows
+from .kv_pool import window_table_pages
 from ..ops.ragged_paged_attention import (_dequant_latent,
                                           latent_paged_attention_reference,
                                           latent_ragged_paged_attention_pallas,
@@ -293,6 +294,11 @@ class StepLayout:
         temps [r] f32  top_ps [r] f32  top_ks [r]  seeds [r]
         spec_lens [r]      (a speculative build, ``spec_k > 0``)
         state_slots [r]    (a hybrid stack)
+        win_tables [r, window pages]  win_base [r]  win_token_page [t]
+                           (a stack with window layers: a row's pages in
+                           the window space, the position its table's
+                           first slot holds, and the write plan there;
+                           ``token_off`` serves both spaces)
 
     ``temps`` and ``top_ps`` ride as their float32 bit patterns
     (``.view(np.float32)`` on the host, ``lax.bitcast_convert_type`` in
@@ -307,7 +313,8 @@ class StepLayout:
     F32 = ("temps", "top_ps")
 
     def __init__(self, cfg: GPTConfig, max_seqs: int, chunk: int,
-                 prefill_rows: int, max_pages: int, spec_k: int = 0):
+                 prefill_rows: int, max_pages: int, spec_k: int = 0,
+                 page_size: int = 0):
         regions = _regions(max_seqs, prefill_rows, chunk, spec_k)
         _, row, tok, n, width = regions[-1]
         self.n_rows, self.n_tokens = row + n, tok + n * width
@@ -324,6 +331,10 @@ class StepLayout:
             shapes["spec_lens"] = (r,)
         if cfg.is_hybrid:
             shapes["state_slots"] = (r,)
+        if cfg.is_hybrid and cfg.window_tokens:
+            wp = window_table_pages(cfg.window_tokens, chunk, page_size)
+            shapes.update(win_tables=(r, wp), win_base=(r,),
+                          win_token_page=(t,))
         self.fields, self.size = self._offsets(shapes)
         outs = {"next_tokens": (r,)}
         if cfg.is_hybrid:
@@ -790,11 +801,25 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
     fn(params, packed [layout.size] i32,   # the dense step's fields and
                                            # state_slots [rows] i32: the
                                            # row's slot in the store
-       k_pages, v_pages,                # attention layers only
+       k_pages, v_pages,                # paged layers only
        conv_states, ssm_states)         # mamba2 layers: [slots, ...]
       -> (out [layout.out_size] i32,    # next_tokens [rows], then
                                         # moe_load [moe layers, held]
           new k_pages, v_pages, conv_states, ssm_states)
+
+    Latent layers whose geometry hangs on their kind (``cfg.geometry``;
+    the pool is laid out by layer, ``cfg.page_layers``: ``k_pages[a]`` is
+    layer ``a``'s one stream ``c_kv | k_r``, ``v_pages`` the index keys of
+    the dsa layers): a ``dsa`` layer writes ``c_kv | k_r`` and its
+    indexer's key of every token under the row's full-space page ids,
+    scores each query against every index key of its context,
+    keeps the ``index_topk`` best positions (on the device: the
+    selection feeds the same layer's attention and nothing of it leaves
+    the step) and attends those alone (``hy.indexed_attention``); a
+    ``swa`` layer writes into the WINDOW space's pages (``win_tables``,
+    ``win_token_page``: a row holds only the pages its window reaches)
+    and attends the window (``hy.window_attention``); both multiply a
+    head's output by its gate.  An ``mlp`` layer is a dense gated MLP.
 
     The store has ``max_seqs`` slots, one per running sequence.  Decode
     rows run SLOT-major: the rows' projections are permuted into slot
@@ -809,7 +834,8 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
     each held expert was chosen by."""
     from ..models import hybrid as hy
     c = cfg
-    layout = StepLayout(c, max_seqs, chunk, prefill_rows, max_pages)
+    layout = StepLayout(c, max_seqs, chunk, prefill_rows, max_pages,
+                        page_size=page_size)
     t_tokens, n_rows = layout.n_tokens, layout.n_rows
     cdt = jnp.bfloat16 if c.dtype == "bfloat16" else jnp.float32
     hd, nh, nkv = c.head_dim, c.num_heads, c.kv_heads
@@ -822,6 +848,11 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
         # the rotary stream's zero lanes, key and query alike
         rope_pad = ((0, 0), (0, c.latent_page_dims[1] - c.rope_dim))
     mamba_of = {i: m for m, i in enumerate(c.layers_of("mamba2"))}
+    index_of = {i: n for n, i in enumerate(c.layers_of("dsa"))}
+    # a dsa / swa layer's geometry and its rotary tables, by kind
+    geo_of = {k: c.geometry(k) for k in ("dsa", "swa") if c.layers_of(k)}
+    rot_of = {k: hy.mla_rotary_tables(c, max_pages * page_size, g)[:2]
+              for k, g in geo_of.items()}
 
     def by_region(f, h, q_lens):
         """``f(tokens) -> per-token`` over the decode slots and, under
@@ -840,9 +871,10 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
         return tmap(lambda *a: jnp.concatenate(a, axis=0), *outs)
 
     def run(params, packed, k_pages, v_pages, conv_states, ssm_states):
+        fields = layout.unpack(packed)
         (tokens, token_pos, token_page, token_off, q_lens, page_tables,
          ctx_lens, temps, top_ps, top_ks, seeds,
-         state_slots) = layout.unpack(packed).values()
+         state_slots) = list(fields.values())[:12]
         cu_q = jnp.asarray(layout.cu_q)
         p = _params_view(c, params)
         with phase("embed"):
@@ -864,7 +896,7 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
             slot_src = jnp.maximum(slot_row, 0)
             slot_fresh = slot_live & fresh_row[slot_src]
         if use_kernel and attn_of:
-            tile = write_tile((k_pages[0], v_pages[0]))
+            tile = write_tile((k_pages[0],) + tuple(v_pages[:1]))
             with phase("kv_scatter"):
                 plan = kv_write_plan(token_page, token_off, q_lens, cu_q,
                                      regions=write_regions,
@@ -872,15 +904,53 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
         new_k, new_v = list(k_pages), list(v_pages)
         new_conv, new_ssm = list(conv_states), list(ssm_states)
         loads = []
+        # the write plans by (page-id space, tile): one a step each
+        plans = {("full", tile): plan} if use_kernel and attn_of else {}
 
-        def write_pages(a, news):
-            """This step's new rows into paged layer ``a``'s two pools."""
-            pools = (k_pages[a], v_pages[a])
+        def write_pages(a, news, index=None):
+            """This step's new rows into paged layer ``a``'s pools, in the
+            page-id space the layer allocates from: its two, or (a pool
+            laid out by layer) its one stream and index stream ``index``
+            where it has one."""
+            if c.page_layers is None:
+                pools = (k_pages[a], v_pages[a])
+            else:
+                pools = (k_pages[a],) + (
+                    () if index is None else (v_pages[index],))
+            window = c.page_layers is not None and \
+                c.page_layers[a].space == "window"
+            page = fields["win_token_page"] if window else token_page
             with phase("kv_scatter"):
-                if use_kernel:
-                    return paged_kv_write(pools, news, plan, tile=tile)
-                return paged_kv_write_reference(pools, news, token_page,
-                                                token_off)
+                if not use_kernel:
+                    return paged_kv_write_reference(pools, news, page,
+                                                    token_off)
+                key = ("window" if window else "full", write_tile(pools))
+                if key not in plans:
+                    plans[key] = kv_write_plan(
+                        page, token_off, q_lens, cu_q,
+                        regions=write_regions, page_size=page_size,
+                        tile=key[1])
+                return paged_kv_write(pools, news, plans[key], tile=key[1])
+
+        def latent_by_region(f, *per_token, per_row=()):
+            """``f(per-token slices..., per-row values...)`` -> the latent
+            attention output, over the decode slots (a row a query) and
+            each chunk slot (one row's queries), each under ``lax.cond``:
+            a region with no live row pays nothing (a document's prefill
+            steps hold no decode row)."""
+            regions = [(jnp.any(q_lens[:max_seqs] > 0),
+                        tuple(a[:max_seqs] for a in per_token + per_row))]
+            for row, start, width in slots:
+                regions.append((q_lens[row] > 0, tuple(
+                    a[start: start + width] for a in per_token) + tuple(
+                        a[row] for a in per_row)))
+            outs = []
+            for live_region, args in regions:
+                zero = jax.eval_shape(f, *args)
+                outs.append(lax.cond(
+                    live_region, f,
+                    lambda *_, z=zero: jnp.zeros(z.shape, z.dtype), *args))
+            return jnp.concatenate(outs, axis=0)
 
         for i, mixer in enumerate(c.layer_pattern):
             with phase("norm"):
@@ -960,6 +1030,76 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
                         lambda aa, i=i: aa @ p.layer(i, "attn.out.weight").T,
                         attn, q_lens)
                 new_k[a], new_v[a] = kp, vp
+            elif mixer in ("dsa", "swa"):
+                a, geo = attn_of[i], geo_of[mixer]
+                cos_k, sin_k = rot_of[mixer]
+                with phase("attn_proj"):
+                    q, c_kv, k_r, c_q = by_region(
+                        lambda hh, i=i, geo=geo: hy.latent_in(
+                            c, params, i, hh, geo), h, q_lens)
+                with phase("attn_gate"):
+                    gate = by_region(
+                        lambda hh, i=i: hy.head_gate(params, i, hh),
+                        h, q_lens)
+                with phase("attn_core"):
+                    cos_t, sin_t = cos_k[token_pos], sin_k[token_pos]
+                    q_rot = hy.mla_rotate(c, q[..., geo.nope:], cos_t,
+                                          sin_t, geo.interleave)
+                    k_rot = hy.mla_rotate(c, k_r, cos_t, sin_t,
+                                          geo.interleave)
+                    # the page's row: c_kv | k_r | zero lanes; q's rotary
+                    # part is padded alike, so the scores are what they
+                    # were
+                    lanes = ((0, 0), (0, geo.rope_lanes - geo.rope))
+                    news = (jnp.concatenate(
+                        [c_kv.astype(cdt), jnp.pad(k_rot.astype(cdt),
+                                                   lanes)], -1)[:, None],)
+                with phase("mla_absorb"):
+                    q_cat = jnp.pad(hy.mla_absorb_q(
+                        c, params, i, q, q_rot, geo.nope), ((0, 0),) + lanes)
+                if mixer == "dsa":
+                    with phase("attn_index"):
+                        iq, iw = by_region(
+                            lambda hc, i=i, geo=geo: hy.index_queries(
+                                params, i, *hc, geo), (h, c_q), q_lens)
+                        ik = by_region(
+                            lambda hh, i=i: hy.index_keys(params, i, hh),
+                            h, q_lens)
+                        iq = hy.rotate_index(iq, cos_t, sin_t)
+                        ik = hy.rotate_index(ik, cos_t, sin_t)
+                    kp, xp = write_pages(
+                        a, news + (ik.astype(cdt)[:, None],), index_of[i])
+                    new_v[index_of[i]] = xp
+                    o_lat = latent_by_region(
+                        lambda iq_, iw_, qc, qp, tab, geo=geo, pools=(
+                            kp, xp): hy.indexed_attention(
+                                geo, iq_, iw_, qc, qp, tab, pools),
+                        iq, iw, q_cat, token_pos, per_row=(page_tables,))
+                else:
+                    (kp,) = write_pages(a, news)
+                    with phase("attn_window"):
+                        o_lat = latent_by_region(
+                            lambda qc, qp, tab, base, geo=geo, kp=kp:
+                            hy.window_attention(geo, qc, qp, tab, base, kp),
+                            q_cat, token_pos,
+                            per_row=(fields["win_tables"],
+                                     fields["win_base"]))
+                with phase("mla_absorb"):
+                    attn = hy.mla_absorb_out(c, params, i, o_lat, jnp.float32)
+                with phase("attn_gate"):
+                    attn = (attn.reshape(t_tokens, geo.heads, geo.v) *
+                            gate[:, :, None]).reshape(
+                                t_tokens, -1).astype(x.dtype)
+                with phase("attn_proj"):
+                    out = by_region(
+                        lambda aa, i=i: aa @ p.layer(i, "attn.out.weight").T,
+                        attn, q_lens)
+                new_k[a] = kp
+            elif mixer == "mlp":
+                with phase("mlp_dense"):
+                    out = by_region(
+                        lambda hh, i=i: hy.gated_mlp(c, params, i, hh),
+                        h, q_lens)
             elif mixer == "mamba2":
                 m = mamba_of[i]
                 w = hy.MambaWeights(params, i)

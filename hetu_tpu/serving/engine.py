@@ -124,7 +124,7 @@ from ..ops.ragged_paged_attention import latent_pages_per_grid_step
 from ..utils.metrics import make_instrument, render_prometheus
 from .decode import StepLayout, _regions, build_unified_step_fn
 from .kv_pool import (TRASH_PAGE, PagedKVPool, StateSlotStore,
-                      protocol_seq)
+                      protocol_seq, window_table_pages)
 from .prefix_cache import PrefixCache
 from .request import FINISHED, RUNNING, Request, RequestQueue
 from .scheduler import Scheduler
@@ -158,9 +158,16 @@ class Engine:
                  tracer=None, step_fn: Optional[Callable] = None,
                  spec: Optional[SpecConfig] = None,
                  page_quant: Optional[str] = None,
-                 host_tier=None):
+                 host_tier=None, window_pages: Optional[int] = None,
+                 early_fetch: bool = False):
         self.cfg = cfg
         self.name = name
+        # start the step's one device-to-host copy when the call is
+        # enqueued, not when the host asks for the tokens: the copy then
+        # follows the execution on the device's own queue, and the fetch
+        # waits for one event where it waited for the execution, woke,
+        # asked for the copy and waited again (ROADMAP S3)
+        self.early_fetch = bool(early_fetch)
         # runtime trace plane (hetu_tpu/obs): None follows the ambient
         # tracer (obs.install_tracer / obs.trace), which defaults to the
         # shared no-op — every emission site below guards on
@@ -224,12 +231,36 @@ class Engine:
                     "recurrent (mamba2) layers: a rejected draft cannot "
                     "be rolled back out of the state")
         latent_dim, rope_dim = cfg.latent_page_dims
+        # window layers (cfg.window_tokens keys a query) keep a row's
+        # pages only where its window reaches: a page-id space of their
+        # own, sized for every row's reach and, by default, as much again
+        # for the tails of cached boundaries (DESIGN.md §27)
+        self.window = cfg.window_tokens
+        chunk = max_model_len if chunk_size is None \
+            else min(int(chunk_size), max_model_len)
+        self.window_table_pages = window_table_pages(
+            self.window, chunk, page_size) if self.window else 0
+        if self.window:
+            if spec is not None or host_tier or mesh is not None:
+                raise ValueError(
+                    "window layers are built without speculation, the "
+                    "host tier and a sharded pool: none of them moves a "
+                    "row's window pages with its full ones")
+            need = int(max_batch) * self.window_table_pages + 1
+            window_pages = 2 * need if window_pages is None \
+                else int(window_pages)
+            if window_pages < need:
+                raise ValueError(
+                    f"window_pages {window_pages} cannot hold {max_batch} "
+                    f"rows x {self.window_table_pages} pages of reach")
         self.pool = PagedKVPool(len(cfg.paged_layers),
                                 num_pages, page_size,
                                 cfg.kv_heads, cfg.head_dim, dtype,
                                 mesh=mesh, debug=debug,
                                 latent_dim=latent_dim, rope_dim=rope_dim,
-                                quant=page_quant)
+                                quant=page_quant, layers=cfg.page_layers,
+                                window_pages=window_pages or 0,
+                                window_tokens=self.window)
         self.state_store: Optional[StateSlotStore] = None
         if cfg.layers_of("mamba2"):
             self.state_store = StateSlotStore(
@@ -247,8 +278,6 @@ class Engine:
             self.pool.set_reclaim(self._reclaim_cached_pages)
         # chunk_size=None: whole-prompt chunks (bounded by what a
         # sequence can ever hold) — the "infinite chunk" configuration
-        chunk = self.max_model_len if chunk_size is None \
-            else min(int(chunk_size), self.max_model_len)
         self.scheduler = Scheduler(self.pool, max_batch=max_batch,
                                    chunk=chunk,
                                    prefill_rows=prefill_rows,
@@ -317,6 +346,13 @@ class Engine:
                           "latent_pages_attended",
                           "latent_pages_attended_distinct",
                           "latent_grid_steps",
+                          # indexed (dsa) and window (swa) layers, one
+                          # layer's, summed over the steps: (query,
+                          # position) pairs the indexer scored; positions
+                          # the attention then read; pages in use in the
+                          # window space and in the full one
+                          "index_pairs_scored", "index_positions_selected",
+                          "window_pages_held", "full_pages_held",
                           # the always-on clock (header): seconds of
                           # step() before / in / after the compiled call
                           # and between two steps with requests running;
@@ -404,12 +440,12 @@ class Engine:
         s, r, ck = (self.scheduler.max_batch, self.scheduler.prefill_rows,
                     self.scheduler.chunk)
         self.layout = StepLayout(cfg, s, ck, r, self.max_pages_per_seq,
-                                 self.spec_k)
+                                 self.spec_k, page_size=page_size)
         # pages a grid step of the latent call covers, by row slot: each
         # region's call takes its group from its own width (the kernel
         # wrapper reads the same rule from the same shapes)
         self._latent_group = None
-        if self.hybrid and self.pool.is_latent:
+        if cfg.layers_of("mla"):
             self._latent_group = np.ones(self.layout.n_rows, np.int64)
             for _, row, _, n, width in _regions(s, r, ck, self.spec_k):
                 self._latent_group[row: row + n] = \
@@ -501,6 +537,9 @@ class Engine:
         if len(generated) >= max_new_tokens:
             raise ValueError("request already finished: "
                              f"{len(generated)} >= {max_new_tokens}")
+        if self.window:
+            raise ValueError("adoption is not built for window layers: a "
+                             "handoff carries no window pages")
         total = len(prompt) + int(max_new_tokens)
         if total > self.max_model_len:
             raise ValueError(
@@ -860,6 +899,7 @@ class Engine:
             if looked_up:
                 if self.prefix_cache is not None and req.shared_pages:
                     self.prefix_cache.release(req)
+                self._free_window_pages(req)
                 req.pages = []
                 req.shared_pages = 0
                 req.cached_tokens = 0
@@ -917,6 +957,7 @@ class Engine:
             if self.spec is not None:
                 self.spec.release(req)
             self._free_state_slot(req)
+            self._free_window_pages(req)
             req.pages = []
             req.shared_pages = 0
             req.cached_tokens = 0
@@ -930,6 +971,11 @@ class Engine:
             if self.prefix_cache is not None:
                 self.prefix_cache.check_invariants()
         return [r.req_id for r in victims]
+
+    def _free_window_pages(self, req: Request) -> None:
+        if req.win_pages:
+            self.pool.window.release(req.win_pages)
+            req.win_pages, req.win_first = [], 0
 
     def _free_state_slot(self, req: Request) -> None:
         if req.state_slot is not None:
@@ -990,6 +1036,7 @@ class Engine:
         f = lay.views(packed)
         f["token_page"].fill(TRASH_PAGE)
         f["page_tables"].fill(TRASH_PAGE)
+        # (the window fields start at 0, which is that space's trash page)
         tokens, token_pos = f["tokens"], f["token_pos"]
         token_page, token_off = f["token_page"], f["token_off"]
         for req, qlen, row in rows:
@@ -1001,11 +1048,19 @@ class Engine:
                 else req.tokens + req.spec_drafts
             tokens[start:start + qlen] = seq[req.pos:req.pos + qlen]
             token_pos[start:start + qlen] = pos
-            pages = np.asarray(req.pages, np.int32)
+            pages = self._page_array(req)
             token_page[start:start + qlen] = pages[pos // ps]
             token_off[start:start + qlen] = pos % ps
+            if self.window:
+                # the row's reach in the window layers: its table, the
+                # position of the table's first slot, the write plan
+                wp = np.asarray(req.win_pages, np.int32)
+                f["win_tables"][row, :len(wp)] = wp
+                f["win_base"][row] = req.win_first * ps
+                f["win_token_page"][start:start + qlen] = \
+                    wp[pos // ps - req.win_first]
             f["q_lens"][row] = qlen
-            f["page_tables"][row, :len(req.pages)] = req.pages
+            f["page_tables"][row, :len(pages)] = pages
             f["ctx_lens"][row] = req.pos + qlen
             f["temps"][row] = req.temperature
             f["top_ps"][row] = req.top_p
@@ -1014,6 +1069,25 @@ class Engine:
             if row >= vbase and req.spec_drafts:
                 f["spec_lens"][row] = len(req.spec_drafts)
         return packed, f
+
+    @staticmethod
+    def _page_array(req: Request) -> np.ndarray:
+        """``req.pages`` as int32, kept on the request from step to step:
+        the list only grows in place (``extend``) or is replaced by
+        another, so the same list object is the same table as far as the
+        array goes, and a decode step converts the page it gained, not
+        the hundreds under a long context."""
+        pages = req.pages
+        src, arr = req.page_array or (None, ())
+        if src is not pages or len(arr) > len(pages):
+            arr = np.asarray(pages, np.int32)
+        elif len(arr) < len(pages):
+            arr = np.concatenate(
+                [arr, np.asarray(pages[len(arr):], np.int32)])
+        else:
+            return arr
+        req.page_array = (pages, arr)
+        return arr
 
     def _run_unified(self, rows: List[Tuple[Request, int, int]]) -> int:
         s = self.scheduler.max_batch
@@ -1054,8 +1128,10 @@ class Engine:
                 # cow-page-write lint flags any live row whose write
                 # plan targets a page in this snapshot (membership =
                 # cached = read-only, whatever the sharer count)
-                "refcounts": {int(pg): self.pool.refcount(pg)
-                              for pg in self.pool._cached}})
+                # (``pool.refcount`` of a cached page, without a call a
+                # page: a long document's cache is thousands of them)
+                "refcounts": {pg: 1 + n
+                              for pg, n in self.pool._cached.items()}})
         t0 = self._enter_phase(tr, "step.h2d") if traced else self._now()
         # the step's ONE host-to-device transfer; placed as any
         # uncommitted array, so a sharded pool has it where it wants it
@@ -1072,6 +1148,8 @@ class Engine:
         out, new_k, new_v, *new_states = self._compiled["unified"](
             self.params, packed_dev, self.pool.k_pages, self.pool.v_pages,
             *states)
+        if self.early_fetch:
+            out.copy_to_host_async()
         if traced:
             self._enter_phase(tr, "step.fetch")
         # the ONE device-to-host fetch: [rows] int32 tokens and, beside
@@ -1093,8 +1171,10 @@ class Engine:
         ta = self._now() if traced else 0.0
         attrs = self._account_hybrid(moe_load, kv_tokens) \
             if self.hybrid else {}
-        if self.hybrid and self.pool.is_latent:
+        if self._latent_group is not None:
             attrs.update(self._latent_reads(rows, page_tables))
+        if self.pool.layers is not None:
+            attrs.update(self._index_reads(rows, page_tables, traced))
         self._calls += 1
         self.counters["step_calls"].inc()
         self.counters["kv_tokens_written"].inc(kv_tokens)
@@ -1166,6 +1246,67 @@ class Engine:
             latent_pages_distinct=distinct, latent_grid_steps=steps,
             attn_pairs=sum(q * c - q * (q - 1) // 2
                            for (_, q, _), c in zip(rows, ctx)))
+
+    def _index_reads(self, rows, page_tables,
+                     traced: bool) -> Dict[str, Any]:
+        """What the step's rows ask of the indexed (dsa) and window (swa)
+        layers, one layer's (every layer of a kind reads the same): the
+        counters, and for a traced step the ``unified_step`` span's
+        attributes.  ``index_pairs`` ((query, position) pairs the indexer
+        scores: every position up to the query), ``index_selected``
+        (positions the attention then reads: ``index_topk`` a query, or
+        all it has), ``index_selected_floor`` (DISTINCT positions among
+        them that can be proven from the host: rows whose page tables
+        start with one page share a document and may select the same
+        positions, so a group of them counts its largest selection once),
+        ``index_pages_distinct`` (distinct full-space pages under the
+        rows' contexts: the index keys read), ``window_pages``
+        (window-space pages in the rows' tables),
+        ``window_tokens_distinct`` (token slots of the distinct pages
+        among them: rows that resumed at one boundary share its tail) and
+        ``window_pairs`` ((query, key) pairs inside the windows).  The
+        counters are sums in closed form; what walks the page tables is
+        the span's alone."""
+        geo = self.cfg.mixer_geometry
+        topk = geo["dsa"].index_topk if "dsa" in geo else 0
+
+        def capped(lo: int, hi: int, cap: int) -> int:
+            # sum of min(p, cap) over the positions seen p = lo+1 .. hi
+            mid = min(max(lo, cap), hi)
+            return (lo + 1 + mid) * (mid - lo) // 2 + cap * (hi - mid)
+
+        pairs = selected = 0
+        for req, q, _ in rows:
+            pairs += q * (req.pos + q) - q * (q - 1) // 2
+            if topk:
+                selected += capped(req.pos, req.pos + q, topk)
+        self.counters["index_pairs_scored"].inc(pairs)
+        self.counters["index_positions_selected"].inc(selected)
+        if self.pool.window is not None:
+            self.counters["window_pages_held"].inc(self.pool.window.in_use)
+        self.counters["full_pages_held"].inc(
+            self.pool.num_usable - self.pool.free_pages)
+        if not traced:
+            return {}
+        ps = self.pool.page_size
+        groups: Dict[int, int] = {}
+        held = []
+        if topk:
+            for req, q, row in rows:
+                ctx = req.pos + q
+                first = int(page_tables[row, 0])
+                groups[first] = max(groups.get(first, 0), min(ctx, topk))
+                held.append(page_tables[row, :-(-ctx // ps)])
+        return dict(
+            index_pairs=pairs, index_selected=selected,
+            index_selected_floor=sum(groups.values()),
+            index_pages_distinct=len(np.unique(
+                np.concatenate(held))) if held else 0,
+            window_pages=sum(len(r.win_pages) for r, _, _ in rows),
+            window_tokens_distinct=ps * len(
+                {pg for r, _, _ in rows for pg in r.win_pages}),
+            window_pairs=sum(capped(r.pos, r.pos + q, self.window)
+                             for r, q, _ in rows) if self.window else 0)
 
     def _account_hybrid(self, moe_load, live_tokens: int) -> Dict[str, Any]:
         """Account the state slots and the expert layers' load; returns
@@ -1291,6 +1432,7 @@ class Engine:
             self.prefix_cache.on_finish(req)
         else:
             self.pool.free(req.pages)
+        self._free_window_pages(req)
         self._free_state_slot(req)
         req.pages = []
         req.state = FINISHED

@@ -159,6 +159,104 @@ class StateSlotStore:
         return out
 
 
+class WindowPages:
+    """The page-id space of the window layers: pages counted by
+    reference, so that a row and a cached boundary can hold one page.
+
+    A window layer's query reads the ``window`` positions up to itself,
+    so a row needs that layer's pages only where its next queries' windows
+    reach (``window_table_pages``), whatever its context: the engine drops
+    a row's page once every query still to come starts behind it and
+    allocates ahead of the write cursor.  A cached prefix is resumable
+    only with the window pages of its tail (``PrefixCache``: the entry at
+    the boundary holds a reference on each); a row that resumes there
+    holds references on the same pages until its window has slid past
+    them.  A page returns to the free list when its last holder lets go.
+    Page 0 is the trash page, as in the pool."""
+
+    def __init__(self, num_pages: int, tail_pages: int = 0):
+        if num_pages < 2:
+            raise ValueError(f"window pages must be >= 2, got {num_pages}")
+        self.num_pages = int(num_pages)
+        # pages behind a page boundary that a request resuming there
+        # reads (``window_tail_pages``): what a row keeps and a cached
+        # boundary's tail holds
+        self.tail_pages = int(tail_pages)
+        self._free: List[int] = list(range(num_pages - 1, 0, -1))
+        self._refs: Dict[int, int] = {}
+        # fn(n_short): lets go of cached boundaries' tails (the prefix
+        # cache's sweep); alloc calls it before failing
+        self._reclaim: Optional[Callable[[int], int]] = None
+
+    @property
+    def free_pages(self) -> int:
+        return len(self._free)
+
+    @property
+    def in_use(self) -> int:
+        return len(self._refs)
+
+    def set_reclaim(self, fn: Optional[Callable[[int], int]]) -> None:
+        self._reclaim = fn
+
+    def alloc(self, n: int) -> Optional[List[int]]:
+        """``n`` pages at one reference each, or None (no partial grant)."""
+        if n > len(self._free) and self._reclaim is not None:
+            self._reclaim(n - len(self._free))
+        if n > len(self._free):
+            return None
+        pages = [self._free.pop() for _ in range(n)]
+        for pg in pages:
+            self._refs[pg] = 1
+        return pages
+
+    def retain(self, pages: Sequence[int]) -> None:
+        for pg in pages:
+            if pg not in self._refs:
+                raise ValueError(f"retain of free window page {pg}")
+            self._refs[pg] += 1
+
+    def release(self, pages: Sequence[int]) -> None:
+        for pg in pages:
+            left = self._refs.get(pg, 0) - 1
+            if left < 0:
+                raise ValueError(f"release of free window page {pg}")
+            if left:
+                self._refs[pg] = left
+            else:
+                del self._refs[pg]
+                self._free.append(pg)
+
+    def reset(self) -> None:
+        self._free = list(range(self.num_pages - 1, 0, -1))
+        self._refs = {}
+
+    def problems(self) -> List[str]:
+        """Free and held pages partition ``range(1, num_pages)``."""
+        free, held = set(self._free), set(self._refs)
+        out = []
+        if len(free) != len(self._free) or free & held:
+            out.append("a window page is free twice, or free and held")
+        if free | held != set(range(1, self.num_pages)):
+            out.append("window pages leaked or invented")
+        return out
+
+
+def window_tail_pages(window: int, page_size: int) -> int:
+    """Pages of a window layer that a request resuming at a page boundary
+    reads behind it: the ``window - 1`` positions before the boundary."""
+    return -(-(window - 1) // page_size)
+
+
+def window_table_pages(window: int, chunk: int, page_size: int) -> int:
+    """Page-table slots a row needs in a window layer.  A row keeps the
+    tail of its last page boundary (so that its prefix is resumable there
+    when it finishes) and writes at most ``chunk`` positions from a cursor
+    less than a page past that boundary."""
+    return window_tail_pages(window, page_size) + \
+        (page_size + chunk - 2) // page_size + 1
+
+
 class PagedKVPool:
     """Free-list page allocator over per-layer k/v page arrays.
 
@@ -174,6 +272,20 @@ class PagedKVPool:
       ``latent_dim // 2``) and v_pages become the per-token fp32 absmax
       sidecar ``[P, 1, ps, 1]``.
 
+    - **by layer** (``layers``: one ``models.gpt.PageLayer`` a paged
+      layer): latent pages whose widths differ from layer to layer, in
+      two page-id spaces.  ``k_pages[a]`` is paged layer ``a``'s ONE
+      stream ``[P, 1, ps, latent + rope]``, ``c_kv | k_r`` (and the zero
+      lanes that fill the rotary part to 128) side by side, so that a
+      token's row is one gather; ``v_pages`` holds, for the layers that
+      have an indexer and in their order, the index-key stream ``[P, 1,
+      ps, index]``: the indexer's key of each token beside the latent,
+      under the same page ids.  A "full" layer's arrays have
+      ``num_pages`` pages and are addressed by a request's ``pages`` as
+      ever; a "window" layer's have ``window_pages`` pages of an id space
+      of their own (``self.window``), of which a request holds only what
+      its window reaches (``Request.win_pages``).
+
     Page-table math, the allocator, CoW refcounts, and the prefix cache
     never look inside a page, so they compose with any layout; only
     ``page_bytes`` / ``layout_tag`` observe the difference.
@@ -183,7 +295,8 @@ class PagedKVPool:
                  kv_heads: int, head_dim: int, dtype=jnp.float32,
                  mesh=None, kv_axis: str = "tp", debug: bool = False,
                  latent_dim: Optional[int] = None, rope_dim: int = 0,
-                 quant: Optional[str] = None):
+                 quant: Optional[str] = None, layers=None,
+                 window_pages: int = 0, window_tokens: int = 0):
         if num_pages < 2:
             raise ValueError(f"num_pages must be >= 2 (page 0 is the "
                              f"reserved trash page), got {num_pages}")
@@ -209,6 +322,17 @@ class PagedKVPool:
         self.latent_dim = None if latent_dim is None else int(latent_dim)
         self.rope_dim = int(rope_dim)
         self.quant = quant
+        self.layers = None if layers is None else tuple(layers)
+        self.window: Optional[WindowPages] = None
+        if self.layers is not None:
+            if quant is not None or mesh is not None or \
+                    len(self.layers) != num_layers:
+                raise ValueError("a pool laid out by layer holds one "
+                                 "PageLayer a paged layer, unquantized, on "
+                                 "one device")
+            if any(l.space == "window" for l in self.layers):
+                self.window = WindowPages(window_pages, window_tail_pages(
+                    window_tokens, page_size))
         if latent_dim is not None:
             if quant == "int8":
                 k_shape = (num_pages, 1, page_size, self.latent_dim)
@@ -240,10 +364,20 @@ class PagedKVPool:
             return jax.device_put(z, self.sharding) if self.sharding \
                 else z
 
-        self.k_pages: Tuple[jax.Array, ...] = tuple(
-            make(k_shape, k_dtype) for _ in range(num_layers))
-        self.v_pages: Tuple[jax.Array, ...] = tuple(
-            make(v_shape, v_dtype) for _ in range(num_layers))
+        if self.layers is None:
+            self.k_pages: Tuple[jax.Array, ...] = tuple(
+                make(k_shape, k_dtype) for _ in range(num_layers))
+            self.v_pages: Tuple[jax.Array, ...] = tuple(
+                make(v_shape, v_dtype) for _ in range(num_layers))
+        else:
+            def stream(l, width):
+                n = window_pages if l.space == "window" else num_pages
+                return jnp.zeros((n, 1, page_size, width), self.dtype)
+
+            self.k_pages = tuple(stream(l, l.latent + l.rope)
+                                 for l in self.layers)
+            self.v_pages = tuple(stream(l, l.index)
+                                 for l in self.layers if l.index)
         # LIFO free list: recently-freed pages are re-issued first (their
         # HBM is hot); page 0 reserved
         self._free: List[int] = list(range(num_pages - 1, 0, -1))
@@ -405,6 +539,8 @@ class PagedKVPool:
         self._allocated = set()
         self._cached = {}
         self.event_log = [(protocol_seq(), "reset", [])]
+        if self.window is not None:
+            self.window.reset()
         if clear_pages:
             self.k_pages = tuple(jnp.zeros_like(p) for p in self.k_pages)
             self.v_pages = tuple(jnp.zeros_like(p) for p in self.v_pages)
@@ -429,13 +565,24 @@ class PagedKVPool:
             self.num_pages, self._free, self._allocated, self._cached)
         if self.state_slots is not None:
             problems = list(problems) + self.state_slots.problems()
+        if self.window is not None:
+            problems = list(problems) + self.window.problems()
         assert not problems, "; ".join(problems)
 
     # -- accounting ----------------------------------------------------------
 
     @property
     def is_latent(self) -> bool:
-        return self.latent_dim is not None
+        return self.latent_dim is not None or self.layers is not None
+
+    def _arrays(self, space: str):
+        """The page arrays of one page-id space (every array of a pool
+        not laid out by layer is "full")."""
+        if self.layers is None:
+            return self.k_pages + self.v_pages if space == "full" else ()
+        return tuple(a for a, l in zip(self.k_pages, self.layers)
+                     if l.space == space) + \
+            (self.v_pages if space == "full" else ())
 
     def page_array_shapes(self) -> Tuple[Tuple[Tuple[int, ...], ...],
                                          Tuple[Tuple[int, ...], ...]]:
@@ -453,8 +600,14 @@ class PagedKVPool:
         one shared helper — transport pricing and metrics read this
         property, so they can never disagree with the real layout)."""
         return sum(page_shape_bytes(p.shape, p.dtype)
-                   for p in self.k_pages) + \
-            sum(page_shape_bytes(p.shape, p.dtype) for p in self.v_pages)
+                   for p in self._arrays("full"))
+
+    @property
+    def window_page_bytes(self) -> int:
+        """HBM bytes one page id of the window space holds across the
+        window layers (0 without them)."""
+        return sum(page_shape_bytes(p.shape, p.dtype)
+                   for p in self._arrays("window"))
 
     @property
     def kv_bytes_per_token(self) -> int:
@@ -469,6 +622,11 @@ class PagedKVPool:
         injected into the other and read back identically.  Salted into
         the prefix-cache digest so a latent replica and a full-head
         replica can never cross-match in the router."""
+        if self.layers is not None:
+            return (2, self.dtype.itemsize) + tuple(
+                x for l in self.layers
+                for x in (int(l.space == "window"), l.latent, l.rope,
+                          l.index))
         if self.is_latent:
             return (1, self.latent_dim, self.rope_dim,
                     _QUANT_CODES[self.quant], self.dtype.itemsize)

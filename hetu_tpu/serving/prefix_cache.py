@@ -52,6 +52,19 @@ pool calls :meth:`evict` through its reclaim hook when the free list
 runs dry, so cache reclamation happens BEFORE the scheduler falls back
 to recompute preemption; a page is removed from the index before it
 re-enters the free list, so the index never references a writable page.
+
+**Window layers** (a pool with ``pool.window``).  A window layer keeps a
+row's pages only where its window reaches, so a cached chain of full
+pages is not enough to resume at its end: the request's first queries
+read the ``window - 1`` positions BEFORE the boundary in every window
+layer.  An entry can therefore carry a **tail**: the window-space pages
+of those positions, one reference each (``WindowPages``), taken over from
+the request that finished there.  ``match`` stops at the deepest entry
+that has one; a request that resumes there shares the tail's pages
+(``Request.win_pages``, a reference each).  Tails are what the window
+space's reclaim lets go of, boundaries no request ever resumed from
+first, then least recently used; an entry that loses its tail stays a
+parent of deeper entries and stops being a place to resume.
 """
 from __future__ import annotations
 
@@ -126,6 +139,10 @@ class CacheEntry:
     last_use: int = 0           # LRU clock (monotonic ticks)
     refs: int = 0               # live requests sharing this page
     children: int = 0           # child entries extending this prefix
+    # window-space pages of the positions before this entry's END, oldest
+    # first (None: not a place to resume); hits = requests resumed here
+    tail: Optional[List[int]] = None
+    hits: int = 0
 
 
 class PrefixCache:
@@ -148,6 +165,9 @@ class PrefixCache:
         # indexed when the hook runs, which is what makes the chain
         # hash computable at all.
         self.on_evict = None
+        # window layers: the window space reclaims through ``drop_tails``
+        if pool.window is not None:
+            pool.window.set_reclaim(self.drop_tails)
 
     # -- introspection -------------------------------------------------------
 
@@ -216,6 +236,10 @@ class PrefixCache:
                 break
             out.append(e)
             parent = e.eid
+        if self.pool.window is not None:
+            # resumable only where the window layers' tail is cached
+            while out and out[-1].tail is None:
+                out.pop()
         return out
 
     def acquire(self, req) -> List[CacheEntry]:
@@ -232,6 +256,14 @@ class PrefixCache:
             e.last_use = self._tick
             self.pool.share_page(e.page)
         self._attached[req.req_id] = entries
+        if entries[-1].tail is not None:
+            # the request reads the boundary's window pages as they are
+            # (full pages, never written again): a reference each
+            tail = entries[-1].tail
+            entries[-1].hits += 1
+            self.pool.window.retain(tail)
+            req.win_pages = list(tail)
+            req.win_first = len(entries) - len(tail)
         return entries
 
     def release(self, req) -> int:
@@ -280,11 +312,44 @@ class PrefixCache:
         tail = req.pages[full:]
         if tail:
             self.pool.free(tail)
+        if self.pool.window is not None:
+            self._keep_tail(req, full, parent)
         self.release(req)
         freed = (full - len(shared) - inserted) + len(tail)
         req.pages = []
         req.shared_pages = 0
         return inserted, freed
+
+    def _keep_tail(self, req, full: int, last: int) -> None:
+        """The finishing request's window pages: those of the positions
+        before its last full page's end become that entry's tail (the
+        request's references pass to the entry), the rest are let go."""
+        window = self.pool.window
+        e = self._by_id.get(last) if full else None
+        n = min(window.tail_pages, full)
+        lo = full - n - req.win_first
+        if e is not None and e.tail is None and e.depth == full - 1 \
+                and 0 <= lo and lo + n <= len(req.win_pages):
+            e.tail = req.win_pages[lo: lo + n]
+            window.release(req.win_pages[:lo] + req.win_pages[lo + n:])
+        else:
+            window.release(req.win_pages)
+        req.win_pages, req.win_first = [], 0
+
+    def drop_tails(self, n: int) -> int:
+        """Let go of cached boundaries' tails until ``n`` window pages
+        came free or none is left: boundaries nothing resumed from first,
+        then the least recently used.  Returns the pages freed."""
+        window = self.pool.window
+        before = window.free_pages
+        for e in sorted((e for e in self._index.values()
+                         if e.tail is not None),
+                        key=lambda e: (e.hits > 0, e.last_use, e.eid)):
+            if window.free_pages - before >= n:
+                break
+            window.release(e.tail)
+            e.tail = None
+        return window.free_pages - before
 
     # -- eviction ------------------------------------------------------------
 
@@ -326,6 +391,9 @@ class PrefixCache:
             # stage BEFORE the index/page bookkeeping: the page is
             # still read-only cached and the parent chain still hashes
             self.on_evict(e, self.chain_hash_of(e))
+        if e.tail is not None:
+            self.pool.window.release(e.tail)
+            e.tail = None
         del self._index[(e.parent, e.tokens)]
         del self._by_id[e.eid]
         if e.parent != ROOT:

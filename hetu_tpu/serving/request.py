@@ -45,6 +45,10 @@ class Request:
     # cache on the most recent start (metrics / tests).
     shared_pages: int = 0
     cached_tokens: int = 0
+    # (the ``pages`` list it was made from, that list as int32): the
+    # engine's packing keeps a long page table between steps
+    page_array: Optional[tuple] = field(default=None, repr=False,
+                                        compare=False)
     # speculative decoding (serving/spec.py): greedy draft proposals
     # staged for the next packed step.  Non-empty only while the engine
     # runs a spec scheduler mode AND the request is decode-ready; the
@@ -56,6 +60,11 @@ class Request:
     # the recurrent-state slot of a hybrid stack (kv_pool.StateSlotStore),
     # held from admission to finish / preemption
     state_slot: Optional[int] = None
+    # window layers (kv_pool.WindowPages): the window-space pages of the
+    # logical pages ``win_first ..`` of the sequence, a reference each —
+    # only those the coming queries' windows reach
+    win_pages: List[int] = field(default_factory=list)
+    win_first: int = 0
     pos: int = 0                 # KV entries committed (next write index)
     state: str = WAITING
     # start of the CURRENT lifecycle segment (queued/running) for the

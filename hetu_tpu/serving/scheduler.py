@@ -84,6 +84,10 @@ class Scheduler:
         which this admission is about to pin."""
         admitted: List[Request] = []
         deferred: List[Request] = []
+        if len(running) >= self.max_batch:
+            # no row to give: the budget below walks the whole cache
+            # index, every step of a full batch over a long backlog
+            return admitted
         # free pages + LRU-reclaimable cached pages not yet claimed
         budget = self.pool.free_pages
         if self.cache is not None:
@@ -234,13 +238,16 @@ class Scheduler:
                 need_tokens = req.pos + 1 + len(req.spec_drafts)
                 have = len(req.pages) * self.pool.page_size
                 if have >= need_tokens:
-                    break              # current pages still have room
-                got = self.pool.alloc(self.pool.pages_for(need_tokens)
-                                      - len(req.pages))
+                    if self._slide_window(req):
+                        break          # current pages still have room
+                    got = None
+                else:
+                    got = self.pool.alloc(self.pool.pages_for(need_tokens)
+                                          - len(req.pages))
                 if got is not None:
                     req.pages.extend(got)
                     req.peak_pages = max(req.peak_pages, len(req.pages))
-                    break
+                    continue
                 if req.spec_drafts:
                     req.spec_drafts = []   # shed the burst, keep running
                     continue
@@ -260,6 +267,34 @@ class Scheduler:
                     break
         return [r for r in kept if r not in evicted], evicted
 
+    def _slide_window(self, req: Request) -> bool:
+        """The window layers' pages of ``req`` for its next step: let go
+        of the pages behind the tail of its last page boundary (every
+        coming query's window starts in or after that tail, and the tail
+        is what makes the prefix resumable when the request finishes),
+        take the pages up to the step's last write (at most a chunk
+        ahead).  False when the window space cannot give them (the caller
+        evicts)."""
+        ps, pages = self.pool.page_size, self.pool.window
+        if pages is None:
+            return True
+        lo = max(0, req.pos // ps - pages.tail_pages)
+        ahead = min(len(req.tokens) - req.pos, self.chunk)
+        hi = self.pool.pages_for(req.pos + max(ahead, 1))
+        drop = min(max(0, lo - req.win_first), len(req.win_pages))
+        if drop:
+            pages.release(req.win_pages[:drop])
+            del req.win_pages[:drop]
+        if not req.win_pages:
+            req.win_first = lo
+        else:
+            req.win_first += drop
+        got = pages.alloc(hi - req.win_first - len(req.win_pages))
+        if got is None:
+            return False
+        req.win_pages.extend(got)
+        return True
+
     def preempt(self, req: Request) -> None:
         """Recompute-style eviction: drop KV state, keep the token
         history — re-prefilling ``req.tokens`` (chunked like any other
@@ -276,6 +311,9 @@ class Scheduler:
             # re-prefills into whatever slot it is given then
             self.pool.state_slots.free(req.state_slot)
             req.state_slot = None
+        if req.win_pages:
+            self.pool.window.release(req.win_pages)
+            req.win_pages, req.win_first = [], 0
         req.pages = []
         req.shared_pages = 0
         req.cached_tokens = 0

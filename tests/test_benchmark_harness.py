@@ -279,10 +279,12 @@ def test_new_layer_metric_names_a_reader_and_a_cell(name):
     # a suffix names the cells of one traffic family; a metric lists
     # those of them its reader finds something to read in (a drawn
     # configuration's own metrics list its cell alone)
-    own = {"nemotron3s-ep4.serve-chat", "mistral4-ep4.serve-longdoc"}
+    own = {"nemotron3s-ep4.serve-chat", "mistral4-ep4.serve-longdoc",
+           "dots3-ep8.serve-longctx"}
     family = {"chat": {"cgpt590m.serve-chat", "nemotron3s-ep4.serve-chat"},
               "replay": {"cgpt590m.serve-prefix",
-                         "mistral4-ep4.serve-longdoc"},
+                         "mistral4-ep4.serve-longdoc",
+                         "dots3-ep8.serve-longctx"},
               "train": {"cgpt590m.train", "cgpt1.3b.train-dp4z3"}}[suffix]
     assert set(entry["workloads"]) <= family
     # (trace_step_edges: the dense cells' traced run, 287 s of the
@@ -291,7 +293,8 @@ def test_new_layer_metric_names_a_reader_and_a_cell(name):
     if spec["reader"] not in ("trace_phase_sum", "span_work_roofline",
                               "span_work_share", "engine_counter_rest",
                               "trace_step_edges") \
-            and not name.startswith(("moe_", "latent_")):
+            and not name.startswith(("moe_", "latent_", "index_",
+                                     "window_")):
         assert family - own <= set(entry["workloads"])
     if spec["reader"] == "trace_idle_by_phase":
         assert spec["args"]["per_span"] in ("engine.step", "g.run")
