@@ -64,7 +64,7 @@ from jax import lax
 
 from ..obs.phases import phase
 from ..ops.moe_grouped import ACTIVATIONS, grouped_experts
-from ..ops.ssd import causal_conv, ssd_chunk_scan, ssd_decode_step
+from ..ops.ssd import causal_conv, ssd_chunk_scan, ssd_decode_slots
 from .generate import norm_eps
 from .gpt import GPTConfig, LatentGeometry
 
@@ -467,13 +467,16 @@ def _ssm_inputs(cfg: GPTConfig, w: MambaWeights, conv_out, dt_raw):
 
 
 def mamba_rows(cfg: GPTConfig, w: MambaWeights, zxd, conv_state, ssm_state,
-               live, fresh):
-    """One token for each state slot: ``zxd`` [S, in_proj width] in SLOT
-    order, ``conv_state`` [S, K-1, conv_dim], ``ssm_state`` [S, H, P, N]
-    float32 — the whole store, updated where ``live`` and left as it is
-    elsewhere; a ``fresh`` slot (its sequence's first token) starts from
-    zeros.  Returns ``(y [S, inner] float32 before the gate, new conv
-    state, new ssm state)``."""
+               live, fresh, walk):
+    """One token for each LIVE state slot: ``zxd`` [S, in_proj width] in
+    SLOT order, ``conv_state`` [S, K-1, conv_dim] (a few MB: passed over
+    whole, kept where not ``live``), ``ssm_state`` [S, H, P, N] float32 —
+    the store, of which the recurrence reads and writes the slots of
+    ``walk`` (``ops.ssd.live_slot_list(live)``, built once a step) in
+    place and touches no other; a ``fresh`` slot (its sequence's first
+    token) starts from zeros whatever it holds.  Returns ``(y [S, inner]
+    float32 before the gate, zeros where not ``live``; new conv state;
+    the store)``."""
     _, xbc, dt_raw = _split_zxd(cfg, zxd)
     with phase("ssm_conv"):
         tail = jnp.where(fresh[:, None, None], 0, conv_state)
@@ -483,9 +486,8 @@ def mamba_rows(cfg: GPTConfig, w: MambaWeights, zxd, conv_state, ssm_state,
         new_conv = jnp.where(live[:, None, None], full[:, 1:], conv_state)
     with phase("ssm_scan"):
         x, b, c, dt, a = _ssm_inputs(cfg, w, conv, dt_raw)
-        s0 = jnp.where(fresh[:, None, None, None], 0.0, ssm_state)
-        y, new = ssd_decode_step(x, dt, a, b, c, w.d, s0)
-        new_ssm = jnp.where(live[:, None, None, None], new, ssm_state)
+        y, new_ssm = ssd_decode_slots(x, dt, a, b, c, w.d, ssm_state, *walk,
+                                      fresh)
     return y.reshape(y.shape[0], cfg.mamba_inner), new_conv, new_ssm
 
 

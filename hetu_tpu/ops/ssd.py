@@ -1,7 +1,7 @@
-"""State-space duality (Mamba-2) in plain ``jax.numpy`` / ``lax``.
+"""State-space duality (Mamba-2): the state-space vocabulary of the
+serving step (``serving/decode.py``).
 
-Three functions, the whole state-space vocabulary of the serving step
-(``serving/decode.py``):
+Plain ``jax.numpy`` / ``lax``:
 
 - :func:`causal_conv` — depthwise causal convolution over a run of
   tokens with a carried tail (the last ``K - 1`` inputs of the row).
@@ -10,7 +10,14 @@ Three functions, the whole state-space vocabulary of the serving step
   inside a chunk the recurrence is two matmuls against a decay mask,
   between chunks a short sequential pass carries the state.  Takes the
   row's initial state and returns its final one.
-- :func:`ssd_decode_step` — the one-token recurrence, batched over rows.
+- :func:`ssd_decode_step` — the one-token recurrence, batched over rows:
+  the reference of the walk below.
+
+One Pallas call:
+
+- :func:`ssd_decode_slots` — the one-token recurrence over the LIVE
+  slots of a state store (:func:`live_slot_list`), in place: what the
+  serving step runs.
 
 The recurrence, per head ``h`` (state ``S_h`` [P, N], float32)::
 
@@ -23,9 +30,16 @@ decay is 1 and its input 0, so it leaves the state as it found it.
 """
 from __future__ import annotations
 
+import functools
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .pallas import on_tpu
 
 F32 = jnp.float32
 
@@ -116,3 +130,118 @@ def ssd_decode_step(x, dt, a, b, c, d, state):
     y = jnp.einsum("rgkpn,rgn->rgkp", new, c.astype(F32))
     y = y.reshape(r, h, p) + x.astype(F32) * d.astype(F32)[:, None]
     return y, new.reshape(r, h, p, n)
+
+
+def live_slot_list(slot_live):
+    """``slot_live`` [S] bool -> ``(slots [S] int32, n [1] int32)``: the
+    ``n[0]`` live slots first (in slot order), every entry past them a
+    repeat of the last live one (slot 0 where none is live), so a walk's
+    block index stops moving after the last live slot.  Computed once a
+    step; every mamba2 layer's :func:`ssd_decode_slots` walks the same
+    list."""
+    n = jnp.sum(slot_live, dtype=jnp.int32)
+    order = jnp.argsort(jnp.logical_not(slot_live), stable=True)
+    last = jnp.maximum(n, 1) - 1
+    slots = order[jnp.minimum(jnp.arange(order.shape[0]), last)]
+    return slots.astype(jnp.int32), n[None]
+
+
+# the walk moves a slot in pieces of at most this many bytes: in and out,
+# each double-buffered, are four such pieces of VMEM
+_WALK_BLOCK_BYTES = 4 << 20
+
+
+def _decode_slots_kernel(slots_ref, n_ref, fresh_ref, decay_ref, dtx_ref,
+                         xd_ref, b_ref, c_ref, s_ref, y0_ref, y_ref, o_ref,
+                         *, heads: int, hg: int):
+    """Grid step ``(i, j)``: piece ``j`` (``hb`` heads: whole groups) of
+    the ``i``-th live slot; a head's decay is a scalar in SMEM."""
+    del y0_ref                          # the zeros y is aliased to
+    i, j = pl.program_id(0), pl.program_id(1)
+    hb = s_ref.shape[1]
+    n = n_ref[0]
+
+    @pl.when(i < n)
+    def _walk():
+        slot = slots_ref[i]
+        fresh = fresh_ref[slot] > 0
+
+        def group(g, carry):
+            at = pl.ds(pl.multiple_of(g * hg, hg), hg)
+            for k in range(hg):
+                # a fresh slot starts from zeros whatever it holds
+                s = jnp.where(fresh, 0.0, s_ref[0, g * hg + k])
+                o_ref[0, g * hg + k] = s * decay_ref[
+                    slot * heads + j * hb + g * hg + k]
+            bg = b_ref[0, pl.ds(j * (hb // hg) + g, 1), :]
+            cg = c_ref[0, pl.ds(j * (hb // hg) + g, 1), :]
+            new = o_ref[0, at] + dtx_ref[0, at][:, :, None] * bg[None]
+            o_ref[0, at] = new
+            y_ref[0, at] = jnp.sum(new * cg[None], axis=-1) + xd_ref[0, at]
+            return carry
+        lax.fori_loop(0, hb // hg, group, 0)
+
+    # no live slot at all: the blocks the walk maps are put back as they are
+    @pl.when(jnp.logical_and(n == 0, i == 0))
+    def _none():
+        o_ref[...] = s_ref[...]
+        y_ref[...] = jnp.zeros(y_ref.shape, y_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def ssd_decode_slots(x, dt, a, b, c, d, state, slots, n_live, fresh, *,
+                     interpret: Optional[bool] = None):
+    """:func:`ssd_decode_step` over the LIVE slots of a state store and
+    no other: ``state`` [S, H, P, N] float32 is the whole store, ``x``
+    [S, H, P], ``dt`` [S, H], ``b`` / ``c`` [S, G, N] and ``fresh`` [S]
+    are in SLOT order, ``slots`` / ``n_live`` come from
+    :func:`live_slot_list`.  A slot in the list is read (zeros taken in
+    its place where ``fresh``), updated and written back, in place where
+    the caller donates the store; a slot outside it is neither read nor
+    written and its ``y`` is zeros.  Returns ``(y [S, H, P] float32, the
+    store)``.  The Mosaic call is ``ssd_decode_slots`` on the device
+    trace: grid ``(S, pieces of a slot)``, a piece as many whole groups
+    of heads as 4 MB hold (the published widths: one slot a step), the
+    steps past ``n_live`` skipped with their block indices left on the
+    last live piece, so nothing moves for them."""
+    if interpret is None:
+        interpret = not on_tpu()
+    s_n, h, p = x.shape
+    g, n = b.shape[1], b.shape[2]
+    hg = h // g
+    gb = max(k for k in range(1, g + 1)
+             if g % k == 0 and (k == 1 or k * hg * p * n * 4
+                                <= _WALK_BLOCK_BYTES))
+    hb, nb = gb * hg, g // gb
+    dt, xf = dt.astype(F32), x.astype(F32)
+    decay = jnp.exp(dt * a.astype(F32)).reshape(s_n * h)
+
+    def at_slot(i, j, slots, n, *_):
+        return slots[i], 0, 0
+
+    def piece(i, j, slots, n, *_):
+        # past the live slots: stay on the last live piece
+        return slots[i], jnp.where(i < jnp.maximum(n[0], 1), j, nb - 1), 0
+
+    rows = pl.BlockSpec((1, hb, p), piece)
+    groups = pl.BlockSpec((1, g, n), at_slot)
+    block = pl.BlockSpec((1, hb, p, n), lambda *a: piece(*a) + (0,))
+    with jax.named_scope("ssd_decode_slots"):
+        y, new = pl.pallas_call(
+            functools.partial(_decode_slots_kernel, heads=h, hg=hg),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=4, grid=(s_n, nb),
+                in_specs=[rows, rows, groups, groups, block,
+                          pl.BlockSpec(memory_space=pl.ANY)],
+                out_specs=[rows, block]),
+            out_shape=[jax.ShapeDtypeStruct((s_n, h, p), F32),
+                       jax.ShapeDtypeStruct(state.shape, F32)],
+            input_output_aliases={8: 1, 9: 0},
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=4 * hb * p * n * 4 + (16 << 20)),
+            interpret=interpret,
+            name="ssd_decode_slots",
+        )(slots, n_live, fresh.astype(jnp.int32), decay,
+          dt[..., None] * xf, xf * d.astype(F32)[:, None],
+          b.astype(F32), c.astype(F32), state, jnp.zeros((s_n, h, p), F32))
+    return y, new

@@ -55,6 +55,7 @@ from ..ops.paged_attention import gather_pages, paged_attention_reference
 from ..ops.paged_kv_write import (kv_write_plan, paged_kv_write,
                                   paged_kv_write_reference, write_tile)
 from ..ops.quantization import quantize_rows
+from ..ops.ssd import live_slot_list
 from .kv_pool import window_table_pages
 from ..ops.ragged_paged_attention import (_dequant_latent,
                                           latent_paged_attention_reference,
@@ -823,15 +824,19 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
 
     The store has ``max_seqs`` slots, one per running sequence.  Decode
     rows run SLOT-major: the rows' projections are permuted into slot
-    order, the whole store is updated in place (a slot with no live
-    decode row this step keeps its content) and the outputs are permuted
-    back — the state is read and written once, never gathered.  A chunk
-    slot takes its row's state out of the store, carries it through the
-    chunk (conv tail, then the chunked scan from the state it found) and
-    puts it back; an idle chunk slot puts back what it took.  A row
-    whose first token sits at position 0 starts from zeros whatever the
-    slot holds.  ``moe_load`` counts, per expert layer, the live tokens
-    each held expert was chosen by."""
+    order and the outputs permuted back.  The slots with a live decode
+    row this step are listed once a step (``ops.ssd.live_slot_list``) and
+    every mamba2 layer's recurrence WALKS that list
+    (``ops.ssd.ssd_decode_slots``): a listed slot's state is read,
+    updated and written back in place, once, never gathered; a slot
+    outside the list is neither read nor written (the conv tails, 1.5 %
+    of the store's bytes, are passed over whole).  A chunk slot takes its
+    row's state out of the store, carries it through the chunk (conv
+    tail, then the chunked scan from the state it found) and puts it
+    back; an idle chunk slot puts back what it took.  A row whose first
+    token sits at position 0 starts from zeros whatever the slot holds.
+    ``moe_load`` counts, per expert layer, the live tokens each held
+    expert was chosen by."""
     from ..models import hybrid as hy
     c = cfg
     layout = StepLayout(c, max_seqs, chunk, prefill_rows, max_pages,
@@ -895,6 +900,9 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
             slot_live = slot_row >= 0
             slot_src = jnp.maximum(slot_row, 0)
             slot_fresh = slot_live & fresh_row[slot_src]
+            # the live slots, compact: what every mamba2 layer's
+            # recurrence walks (hy.mamba_rows)
+            walk = live_slot_list(slot_live) if mamba_of else None
         if use_kernel and attn_of:
             tile = write_tile((k_pages[0],) + tuple(v_pages[:1]))
             with phase("kv_scatter"):
@@ -1110,7 +1118,7 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
                     zxd_slots = zxd[:max_seqs][slot_src]
                 y_slots, conv_s, ssm_s = hy.mamba_rows(
                     c, w, zxd_slots, new_conv[m], new_ssm[m], slot_live,
-                    slot_fresh)
+                    slot_fresh, walk)
                 with phase("state_io"):
                     ys = [y_slots[state_slots[:max_seqs]]]
                 for row, start, width in slots:

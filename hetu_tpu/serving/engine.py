@@ -340,6 +340,10 @@ class Engine:
                           # (each expert's group padded to whole blocks)
                           "state_slot_allocs", "moe_assignments_local",
                           "moe_assignments_total", "moe_block_rows",
+                          # one mamba2 layer's, summed over the steps:
+                          # slots whose state the decode recurrence read
+                          # and wrote (the live decode rows) / the store's
+                          "ssm_slots_walked", "ssm_slots_store",
                           # latent (mla) layers of a hybrid stack: pages
                           # the rows attended, counted per row / once
                           # where several rows read one physical page
@@ -1169,7 +1173,11 @@ class Engine:
         # what the step spends on the engine's own counters and span
         # attributes (traced steps put the ``account`` span round it)
         ta = self._now() if traced else 0.0
-        attrs = self._account_hybrid(moe_load, kv_tokens) \
+        # classify by SLOT, not q_len: a chunk_size=1 prefill chunk is
+        # still a prefill chunk, and a verify row is neither
+        n_decode = sum(1 for _, _, row in rows if row < s)
+        n_chunk = sum(1 for _, _, row in rows if s <= row < vbase)
+        attrs = self._account_hybrid(moe_load, kv_tokens, n_decode) \
             if self.hybrid else {}
         if self._latent_group is not None:
             attrs.update(self._latent_reads(rows, page_tables))
@@ -1189,10 +1197,6 @@ class Engine:
                         exec=f"{self.name}/unified", step=self.steps,
                         rows=len(rows), tokens=kv_tokens,
                         h2d_bytes=packed.nbytes, **attrs)
-        # classify by SLOT, not q_len: a chunk_size=1 prefill chunk is
-        # still a prefill chunk, and a verify row is neither
-        n_decode = sum(1 for _, _, row in rows if row < s)
-        n_chunk = sum(1 for _, _, row in rows if s <= row < vbase)
         if n_decode:
             self.counters["decode_steps"].inc()
         self.counters["prefill_chunks"].inc(n_chunk)
@@ -1308,9 +1312,11 @@ class Engine:
             window_pairs=sum(capped(r.pos, r.pos + q, self.window)
                              for r, q, _ in rows) if self.window else 0)
 
-    def _account_hybrid(self, moe_load, live_tokens: int) -> Dict[str, Any]:
-        """Account the state slots and the expert layers' load; returns
-        the ``unified_step`` span's extra
+    def _account_hybrid(self, moe_load, live_tokens: int,
+                        decode_rows: int) -> Dict[str, Any]:
+        """Account the state slots (of the store's, the ``decode_rows``
+        whose state a mamba2 layer's recurrence walked this step) and the
+        expert layers' load; returns the ``unified_step`` span's extra
         attributes (``state_slots``: slots held; ``moe_local``: live
         assignments on the held experts; ``moe_load_peak``: the busiest
         held expert over the mean; ``moe_experts_hit``: held experts,
@@ -1321,6 +1327,8 @@ class Engine:
         if st is not None:
             self.gauges["state_slots_in_use"].set(st.in_use)
             attrs["state_slots"] = st.in_use
+            self.counters["ssm_slots_walked"].inc(decode_rows)
+            self.counters["ssm_slots_store"].inc(st.num_slots)
         if moe_load is not None and moe_load.size:
             local = int(moe_load.sum())
             self.counters["moe_assignments_local"].inc(local)

@@ -89,6 +89,10 @@ class StateSlotStore:
     There is no zeroing pass: the unified step starts a row whose first
     token sits at position 0 from zeros whatever its slot holds, so a
     slot's old content cannot reach the sequence that takes it next.
+    A decode step reads and writes, in place, the scan state of the slots
+    that have a live decode row (``ops.ssd.ssd_decode_slots``) and of no
+    other: a slot that is free, or whose sequence is waiting or
+    prefilling, keeps its bytes untouched.
 
     ``conv`` / ``ssm`` are tuples of per-layer arrays ``[slots, K - 1,
     conv_dim]`` / ``[slots, heads, head_dim, state]``; the jitted step

@@ -294,7 +294,7 @@ def test_new_layer_metric_names_a_reader_and_a_cell(name):
                               "span_work_share", "engine_counter_rest",
                               "trace_step_edges") \
             and not name.startswith(("moe_", "latent_", "index_",
-                                     "window_")):
+                                     "window_", "ssm_")):
         assert family - own <= set(entry["workloads"])
     if spec["reader"] == "trace_idle_by_phase":
         assert spec["args"]["per_span"] in ("engine.step", "g.run")

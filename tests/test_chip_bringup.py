@@ -33,6 +33,7 @@ rpa = importlib.import_module("hetu_tpu.ops.ragged_paged_attention")
 kvw = importlib.import_module("hetu_tpu.ops.paged_kv_write")
 fa = importlib.import_module("hetu_tpu.ops.pallas.flash_attention")
 mg = importlib.import_module("hetu_tpu.ops.moe_grouped")
+ssd = importlib.import_module("hetu_tpu.ops.ssd")
 
 I32, BF16, F32 = jnp.int32, jnp.bfloat16, jnp.float32
 # the serving step's ragged batch at GPT-2 124M widths: 16 decode rows +
@@ -127,6 +128,13 @@ def _kernel_cases():
             _sds((rows,), I32), _sds((rows + 1,), I32),
             _sds((rows, 272), I32), _sds((rows,), I32)))
 
+    def decode_slots(x, dt, a, b, c, d, store, slots, n, fresh):
+        # a mamba2 layer's one-token recurrence over the hybrid
+        # configuration's state store: 64 slots of 128 heads x 64 x 128
+        # float32, a whole slot (4 MB) a grid step
+        return ssd.ssd_decode_slots(x, dt, a, b, c, d, store, slots, n,
+                                    fresh, interpret=False)
+
     def flash_qkv_grad(x):
         return jax.grad(lambda x: fa.flash_attention_qkv(
             x, 12, causal=True).astype(F32).sum())(x)
@@ -170,6 +178,11 @@ def _kernel_cases():
             _sds((320, 1024), BF16), _sds((320, 22), I32),
             _sds((320, 22), F32), _sds((320,), jnp.bool_),
             _sds((128, 1024, 2688), BF16), _sds((128, 2688, 1024), BF16))),
+        "ssd_decode_slots": (decode_slots, (
+            _sds((64, 128, 64), F32), _sds((64, 128), F32), _sds((128,), F32),
+            _sds((64, 8, 128), F32), _sds((64, 8, 128), F32),
+            _sds((128,), F32), _sds((64, 128, 64, 128), F32),
+            _sds((64,), I32), _sds((1,), I32), _sds((64,), jnp.bool_))),
         "moe_grouped_gated_tiled": (grouped_gated, (
             _sds((288, 4096), BF16), _sds((288, 4), I32),
             _sds((288, 4), F32), _sds((288,), jnp.bool_),
@@ -188,12 +201,15 @@ def _kernel_cases():
 # grouped experts: two whole expert matrices double-buffered (33 MB of VMEM),
 # and the gated ones in tiles (three matrices of 4096 x 512, 25 MB); the
 # latent chunk region at 32 heads: q and output blocks over the whole
-# padded token axis in float32 (25 + 17 MB, twice)
+# padded token axis in float32 (25 + 17 MB, twice); the live-slot walk:
+# a 4 MB state block in and out, double-buffered (16 MB of VMEM), a
+# [heads, head_dim] vector spread along the state's lanes and a sum over
+# them back
 AOT_CASES = ("ragged_12kv_x64", "ragged_decode_region",
              "ragged_decode_gqa16", "latent_512_64",
              "latent_nf4", "kv_write_12kv_x128", "kv_write_int8_sidecar",
              "flash_qkv", "moe_grouped_experts", "moe_grouped_gated_tiled",
-             "latent_256_128_chunk_region")
+             "latent_256_128_chunk_region", "ssd_decode_slots")
 
 
 @pytest.fixture

@@ -128,6 +128,57 @@ def test_chunked_scan_and_recurrence_agree_with_the_sequential_scan():
         assert np.allclose(new[r], one_s, atol=1e-5)
 
 
+@pytest.mark.parametrize("n_live", [0, 1, 12, 64])
+def test_the_walk_updates_the_live_slots_and_touches_no_other(n_live):
+    """``ssd_decode_slots`` against ``ssd_decode_step`` over the whole
+    store: 64 slots of which ``n_live`` are live, scattered and listed in
+    any order, some of them fresh.  Live slots: ``y`` and the new state
+    to float32 tolerance; every other slot bit-identical to what it held
+    and its ``y`` zero; a fresh slot's old content (NaN here) reaches
+    neither ``y`` nor the new state."""
+    rng = np.random.RandomState(10 + n_live)
+    s_n, h, p, g, n = 64, 8, 8, 2, 16
+    x = rng.randn(s_n, h, p).astype(np.float32)
+    dt = np.log1p(np.exp(rng.randn(s_n, h))).astype(np.float32) * 0.3
+    a = -np.exp(rng.rand(h)).astype(np.float32)
+    b, c = (rng.randn(s_n, g, n).astype(np.float32) for _ in range(2))
+    d = rng.randn(h).astype(np.float32)
+    store = rng.randn(s_n, h, p, n).astype(np.float32)
+    live_ids = rng.permutation(s_n)[:n_live]
+    live = np.zeros(s_n, bool)
+    live[live_ids] = True
+    fresh = live & (rng.rand(s_n) < 0.3)
+    if n_live:
+        fresh[live_ids[0]] = True
+    store[fresh] = np.nan
+    # the list the step builds: live first, the rest the last live one
+    slots, n_arr = ssd.live_slot_list(jnp.asarray(live))
+    assert int(n_arr[0]) == n_live
+    assert sorted(np.asarray(slots)[:n_live]) == sorted(live_ids)
+    assert set(np.asarray(slots)[n_live:]) <= \
+        {int(np.asarray(slots)[max(n_live, 1) - 1])}
+    # ... and the same slots in another order
+    order = rng.permutation(live_ids) if n_live else np.zeros(1, np.int64)
+    shuffled = np.concatenate(
+        [order, np.full(s_n - len(order), order[-1])]).astype(np.int32)
+    want_y, want = ssd.ssd_decode_step(
+        x, dt, a, b, c, d, np.where(fresh[:, None, None, None], 0.0, store))
+    want_y, want = np.asarray(want_y), np.asarray(want)
+    for lst in (slots, jnp.asarray(shuffled)):
+        y, new = ssd.ssd_decode_slots(x, dt, a, b, c, d, jnp.asarray(store),
+                                      lst, n_arr, jnp.asarray(fresh))
+        y, new = np.asarray(y), np.asarray(new)
+        assert np.array_equal(new[~live].view(np.uint32),
+                              store[~live].view(np.uint32))
+        assert not y[~live].any()
+        if n_live:
+            assert np.isfinite(y[live]).all() and np.isfinite(new[live]).all()
+            assert np.abs(y[live] - want_y[live]).max() \
+                <= TENSOR_F32 * np.abs(want_y[live]).max()
+            assert np.abs(new[live] - want[live]).max() \
+                <= TENSOR_F32 * np.abs(want[live]).max()
+
+
 def test_causal_conv_carries_its_tail_across_a_ragged_chunk():
     rng = np.random.RandomState(2)
     k, ch = 4, 6
@@ -332,6 +383,35 @@ def test_engine_serves_the_same_tokens_and_counts_the_kernels_rows():
     steps = [e for e in eng.tracer.events() if e.name == "unified_step"]
     assert steps and sum(e.attrs["moe_blocks"] for e in steps) \
         * ROW_BLOCK == c["moe_block_rows"]
+
+
+def test_a_step_counts_the_slots_its_recurrence_walked():
+    """Three requests decoding while a fourth prefills: that step's
+    recurrence walks three of the store's four slots (the chunk row's
+    state goes through the chunked scan), the counters say so, and every
+    served token stays within ``GAP_F32`` of the reference."""
+    pub, cfg, state = build("*EMEM")
+    eng = engine(state, cfg)
+    ps = prompts((5, 3, 1, 19))
+    reqs = [eng.add_request(p, 8) for p in ps[:3]]
+    for _ in range(3):
+        eng.step()
+    assert all(r.n_generated >= 1 for r in reqs)
+    reqs.append(eng.add_request(ps[3], 8))
+    before = eng.metrics_summary()
+    eng.step()
+    after = eng.metrics_summary()
+    delta = {k: after[k] - before[k] for k in (
+        "ssm_slots_walked", "ssm_slots_store", "prefill_chunks")}
+    assert eng.state_store.num_slots == 4
+    assert delta == {"ssm_slots_walked": 3, "ssm_slots_store": 4,
+                     "prefill_chunks": 1}
+    eng.run()
+    c = eng.metrics_summary()
+    assert 0 < c["ssm_slots_walked"] < c["ssm_slots_store"]
+    assert c["ssm_slots_store"] == 4 * c["step_calls"]
+    for r, p in zip(reqs, ps):
+        assert worst_gap(pub, state, p, r.out_tokens) <= GAP_F32
 
 
 @pytest.mark.parametrize("chunk", [5, 32, None])
