@@ -8,7 +8,7 @@ the one place that knows the stack's PARAMETER NAMES and the mixers'
 arithmetic; the serving step (``serving/decode.py``) lays them over the
 ragged token axis and owns the state (K/V pages for the attention layers,
 state slots for the mamba2 layers).  The plain float32 reference is
-``models/hybrid_reference.py``.
+``benchmark/reference_hybrid.py``.
 
 Tensors (a projection ``W`` is ``[out, in]``, used as ``x @ W.T``)::
 

@@ -86,9 +86,9 @@ stream callbacks, gauges).  These real-time spans are mirrored into the
 jax profiler's trace (``hetu:`` prefix).  Two retroactive spans, host
 tracer only, size what the phases hide: ``pack_arrays`` (the packed
 host arrays, inside ``step.pack``; ``rows``, ``page_slots``) and
-``account`` (what the step spends on the engine's own counters and span
-attributes, inside ``step.commit``); with ``unified_step`` they carry
-the same ``step=`` as their ``engine_step``.  While a tracer is set
+``account`` (what the step spends on its counters and span attributes,
+``serving/step_account``, inside ``step.commit``); with ``unified_step``
+they carry the same ``step=`` as their ``engine_step``.  While a tracer is set
 through ``set_tracer`` the collector's runs are ``gc`` spans on track
 ``runtime`` (``obs/tracer.py``).  The default tracer is the
 shared no-op: every emission site guards on ``tracer.enabled``.
@@ -121,18 +121,16 @@ import numpy as np
 from ..models.generate import _Params
 from ..models.gpt import GPTConfig
 from ..obs.tracer import get_tracer
-from ..ops.moe_grouped import ROW_BLOCK, block_rows
 from ..ops.pallas import on_tpu
-from ..ops.ragged_paged_attention import (kv_call_blocking,
-                                          latent_pages_per_grid_step)
 from ..utils.metrics import make_instrument, render_prometheus
-from .decode import StepLayout, _regions, build_unified_step_fn
+from .decode import StepLayout, build_unified_step_fn
 from .kv_pool import (TRASH_PAGE, PagedKVPool, StateSlotStore,
                       protocol_seq, window_table_pages)
 from .prefix_cache import PrefixCache
 from .request import FINISHED, RUNNING, Request, RequestQueue
 from .scheduler import Scheduler
 from .spec import SpecConfig, SpecDecoder
+from .step_account import COUNTERS, GAUGES, StepAccount
 
 # default Prometheus-style latency bounds (seconds) for ttft/tbt; tests
 # and benches with a synthetic clock pass their own
@@ -338,11 +336,8 @@ class Engine:
                           # zero on non-spec engines)
                           "spec_proposed", "spec_accepted",
                           "spec_bonus_tokens",
-                          # self-drafting: rows one token from emitting
-                          # behind their prompt / those of them that rode
-                          # a verify slot with a draft; drafts the step's
-                          # own output staged for the next step
-                          "decode_rows", "spec_rows", "spec_drafted",
+                          # drafts a self-drafting step staged for the next
+                          "spec_drafted",
                           # SLO traffic plane (serving/slo): per-class
                           # admission/preemption counts and the host
                           # KV tier's page moves — always present (zero
@@ -353,37 +348,10 @@ class Engine:
                           "preempted_standard", "preempted_batch",
                           "host_evictions", "host_hits",
                           "host_refetch_bytes",
-                          # hybrid stacks (zero elsewhere): state slots
-                          # handed out; live (token, expert) assignments
-                          # that fell on the experts held here / made by
-                          # the router over all experts; rows the grouped
-                          # expert kernel computed for the local ones
-                          # (each expert's group padded to whole blocks)
-                          "state_slot_allocs", "moe_assignments_local",
-                          "moe_assignments_total", "moe_block_rows",
-                          # one mamba2 layer's, summed over the steps:
-                          # slots whose state the decode recurrence read
-                          # and wrote (the live decode rows) / the store's
-                          "ssm_slots_walked", "ssm_slots_store",
-                          # latent (mla) layers of a hybrid stack: pages
-                          # the rows attended, counted per row / once
-                          # where several rows read one physical page
-                          "latent_pages_attended",
-                          "latent_pages_attended_distinct",
-                          "latent_grid_steps",
-                          # a pattern stack's full plain K/V layers, one
-                          # layer's K (V's are the same again), summed
-                          # over the steps: (page, kv head) pairs under
-                          # the live rows' contexts / the blocks the
-                          # ragged call fetches them in
-                          "kv_page_heads", "kv_page_blocks",
-                          # indexed (dsa) and window (swa) layers, one
-                          # layer's, summed over the steps: (query,
-                          # position) pairs the indexer scored; positions
-                          # the attention then read; pages in use in the
-                          # window space and in the full one
-                          "index_pairs_scored", "index_positions_selected",
-                          "window_pages_held", "full_pages_held",
+                          # state slots handed out (zero without mamba2)
+                          "state_slot_allocs",
+                          # what the steps read (serving/step_account)
+                          *COUNTERS,
                           # the always-on clock (header): seconds of
                           # step() before / in / after the compiled call
                           # and between two steps with requests running;
@@ -401,10 +369,7 @@ class Engine:
                         # transport / metrics planes can never disagree
                         "kv_bytes_per_token", "kv_bytes_in_use",
                         # live host-tier page count (0 without one)
-                        "host_pages",
-                        # hybrid stacks: slots held; the last step's
-                        # busiest held expert over the mean load
-                        "state_slots_in_use", "moe_expert_load_peak")}
+                        "host_pages", *GAUGES)}
         self.gauges["kv_bytes_per_token"].set(
             self.pool.kv_bytes_per_token)
         lb = list(latency_buckets if latency_buckets is not None
@@ -473,33 +438,10 @@ class Engine:
                     self.scheduler.chunk)
         self.layout = StepLayout(cfg, s, ck, r, self.max_pages_per_seq,
                                  self.spec_k, page_size=page_size)
-        # pages a grid step of the latent call covers, by row slot: each
-        # region's call takes its group from its own width (the kernel
-        # wrapper reads the same rule from the same shapes)
-        self._latent_group = None
-        if cfg.layers_of("mla"):
-            self._latent_group = np.ones(self.layout.n_rows, np.int64)
-            for _, row, _, n, width in _regions(s, r, ck, self.spec_k):
-                self._latent_group[row: row + n] = \
-                    latent_pages_per_grid_step(
-                        width, cfg.num_heads, sum(cfg.latent_page_dims),
-                        self.max_pages_per_seq,
-                        (self.pool.k_pages[0], self.pool.v_pages[0]))
-        # kv heads a fetched block of the plain K/V ragged call holds, by
-        # row slot: each region's call of a pattern stack's full layers
-        # takes it from its own shapes (the kernel wrapper reads the same
-        # rule; q arrives in the pool's dtype)
-        self._kv_block_heads = None
-        full = next((a for a, i in enumerate(cfg.paged_layers)
-                     if self.hybrid and cfg.stack_pattern[i] == "attention"
-                     and not cfg.window_of(i)), None)
-        if full is not None:
-            pages = self.pool.k_pages[full]
-            self._kv_block_heads = np.ones(self.layout.n_rows, np.int64)
-            for _, row, _, n, width in _regions(s, r, ck, self.spec_k):
-                self._kv_block_heads[row: row + n] = kv_call_blocking(
-                    width, n * width, cfg.num_heads, pages.dtype, pages,
-                    self.max_pages_per_seq)[-1]
+        # what each step read, from its own inputs and outputs
+        self.account = StepAccount(cfg, self.layout, self.pool,
+                                   self.state_store, self.scheduler,
+                                   self.spec_k, self.counters, self.gauges)
         self._register_for_analysis()
 
     # -- submission ----------------------------------------------------------
@@ -1208,7 +1150,7 @@ class Engine:
         out = self.layout.split(np.asarray(out))
         self.counters["d2h_fetches"].inc()
         toks = out["next_tokens"]
-        accs, moe_load = out.get("accepted"), out.get("moe_load")
+        accs = out.get("accepted")
         drafts = out.get("draft")
         t1 = self._now()
         dt = t1 - t0
@@ -1225,17 +1167,7 @@ class Engine:
         # still a prefill chunk, and a verify row is neither
         n_decode = sum(1 for _, _, row in rows if row < s)
         n_chunk = sum(1 for _, _, row in rows if s <= row < vbase)
-        attrs = self._account_hybrid(moe_load, kv_tokens, n_decode) \
-            if self.hybrid else {}
-        if self._kv_block_heads is not None:
-            self._kv_reads(rows)
-        if self._latent_group is not None:
-            attrs.update(self._latent_reads(rows, page_tables))
-        if self.pool.layers is not None:
-            attrs.update(self._index_reads(rows, page_tables, traced))
-        if self.self_draft:
-            attrs.update(self._account_self_draft(
-                rows, out["mtp_load"], accs, page_tables, traced))
+        attrs = self.account(rows, fields, out, traced)
         self._calls += 1
         self.counters["step_calls"].inc()
         self.counters["kv_tokens_written"].inc(kv_tokens)
@@ -1283,193 +1215,6 @@ class Engine:
                 req.spec_drafts = [int(drafts[row])]
                 self.counters["spec_drafted"].inc()
         return produced
-
-    def _kv_reads(self, rows) -> None:
-        """What the step's rows ask of ONE full plain K/V layer's ragged
-        call, from the contexts the step packed: ``kv_page_heads``
-        ((page, kv head) pairs under the live rows' contexts, all
-        regions) and ``kv_page_blocks`` (the blocks they reach VMEM in:
-        a row's pages times ``kv_heads`` over the heads a block of its
-        region's call holds, ``ops/ragged_paged_attention.py::
-        kv_heads_per_block``)."""
-        ps, kvh = self.pool.page_size, self.cfg.kv_heads
-        pages = [-(-(req.pos + q) // ps) for req, q, _ in rows]
-        self.counters["kv_page_heads"].inc(sum(pages) * kvh)
-        self.counters["kv_page_blocks"].inc(sum(
-            n * (kvh // int(self._kv_block_heads[row]))
-            for n, (_, _, row) in zip(pages, rows)))
-
-    def _latent_reads(self, rows, page_tables) -> Dict[str, Any]:
-        """What the step's rows read of the latent page pool, as the
-        ``unified_step`` span's attributes: ``latent_ctx_tokens`` (sum of
-        the live rows' contexts), ``latent_pages`` (pages attended,
-        counted per row), ``latent_pages_distinct`` (distinct physical
-        pages among them: rows on one cached document share its pages),
-        ``latent_grid_steps`` (grid steps of the latent calls that run:
-        a row's pages over the group its region's call walks a step,
-        rounded up) and ``attn_pairs`` ((query, key) pairs inside the
-        causal mask); one layer's, every mla layer reads the same."""
-        ps = self.pool.page_size
-        ctx = [req.pos + q for req, q, _ in rows]
-        held = [page_tables[row, :-(-c // ps)]
-                for (_, _, row), c in zip(rows, ctx)]
-        pages = sum(len(h) for h in held)
-        distinct = len(np.unique(np.concatenate(held))) if held else 0
-        steps = sum(-(-len(h) // int(self._latent_group[row]))
-                    for (_, _, row), h in zip(rows, held))
-        self.counters["latent_pages_attended"].inc(pages)
-        self.counters["latent_pages_attended_distinct"].inc(distinct)
-        self.counters["latent_grid_steps"].inc(steps)
-        return dict(
-            latent_ctx_tokens=sum(ctx), latent_pages=pages,
-            latent_pages_distinct=distinct, latent_grid_steps=steps,
-            attn_pairs=sum(q * c - q * (q - 1) // 2
-                           for (_, q, _), c in zip(rows, ctx)))
-
-    def _index_reads(self, rows, page_tables,
-                     traced: bool) -> Dict[str, Any]:
-        """What the step's rows ask of the indexed (dsa) and window (swa)
-        layers, one layer's (every layer of a kind reads the same): the
-        counters, and for a traced step the ``unified_step`` span's
-        attributes.  ``index_pairs`` ((query, position) pairs the indexer
-        scores: every position up to the query), ``index_selected``
-        (positions the attention then reads: ``index_topk`` a query, or
-        all it has), ``index_selected_floor`` (DISTINCT positions among
-        them that can be proven from the host: rows whose page tables
-        start with one page share a document and may select the same
-        positions, so a group of them counts its largest selection once),
-        ``index_pages_distinct`` (distinct full-space pages under the
-        rows' contexts: the index keys read), ``window_pages``
-        (window-space pages in the rows' tables),
-        ``window_tokens_distinct`` (token slots of the distinct pages
-        among them: rows that resumed at one boundary share its tail) and
-        ``window_pairs`` ((query, key) pairs inside the windows).  The
-        counters are sums in closed form; what walks the page tables is
-        the span's alone."""
-        geo = self.cfg.mixer_geometry or {}
-        topk = geo["dsa"].index_topk if "dsa" in geo else 0
-
-        def capped(lo: int, hi: int, cap: int) -> int:
-            # sum of min(p, cap) over the positions seen p = lo+1 .. hi
-            mid = min(max(lo, cap), hi)
-            return (lo + 1 + mid) * (mid - lo) // 2 + cap * (hi - mid)
-
-        pairs = selected = 0
-        for req, q, _ in rows:
-            pairs += q * (req.pos + q) - q * (q - 1) // 2
-            if topk:
-                selected += capped(req.pos, req.pos + q, topk)
-        self.counters["index_pairs_scored"].inc(pairs)
-        self.counters["index_positions_selected"].inc(selected)
-        if self.pool.window is not None:
-            self.counters["window_pages_held"].inc(self.pool.window.in_use)
-        self.counters["full_pages_held"].inc(
-            self.pool.num_usable - self.pool.free_pages)
-        if not traced:
-            return {}
-        ps = self.pool.page_size
-        groups: Dict[int, int] = {}
-        held = []
-        if topk:
-            for req, q, row in rows:
-                ctx = req.pos + q
-                first = int(page_tables[row, 0])
-                groups[first] = max(groups.get(first, 0), min(ctx, topk))
-                held.append(page_tables[row, :-(-ctx // ps)])
-        return dict(
-            index_pairs=pairs, index_selected=selected,
-            index_selected_floor=sum(groups.values()),
-            index_pages_distinct=len(np.unique(
-                np.concatenate(held))) if held else 0,
-            window_pages=sum(len(r.win_pages) for r, _, _ in rows),
-            window_tokens_distinct=ps * len(
-                {pg for r, _, _ in rows for pg in r.win_pages}),
-            window_pairs=sum(capped(r.pos, r.pos + q, self.window)
-                             for r, q, _ in rows) if self.window else 0)
-
-    def _account_self_draft(self, rows, mtp_load, accs, page_tables,
-                            traced: bool) -> Dict[str, Any]:
-        """A self-drafting step's counters — ``decode_rows`` (rows one
-        token from emitting whose request has emitted before: what a
-        draft is for; not the last row of a preempted request's
-        re-prefill, which no step can have drafted for) and ``spec_rows``
-        (those of them in a verify slot, carrying a draft) — and, for a
-        traced step, the ``unified_step`` span's attributes:
-        ``verify_rows``, ``spec_accepted`` (drafts the accept head kept),
-        the MTP module's expert load apart from the stack's
-        (``mtp_moe_local`` / ``_experts_hit`` / ``_blocks``, as
-        ``_account_hybrid`` counts the stack's) and what the K/V layers
-        read: ``attn_pairs`` ((query, key) pairs inside the causal mask,
-        one full layer's), ``mtp_tokens`` / ``mtp_attn_pairs`` (the
-        positions the module's layer keeps, a verify row's up to its last
-        accepted one, and their pairs), ``kv_pages_distinct`` (distinct
-        full-space pages under the rows' contexts) and ``window_keys``
-        (positions inside the windows of each row's queries, summed over
-        the rows: what a window layer reads by the token, not by the
-        page)."""
-        vbase = self.scheduler.max_batch + self.scheduler.prefill_rows
-        decode = [(r, q, row) for r, q, row in rows
-                  if len(r.tokens) - r.pos == 1 and r.n_generated
-                  and not r.resuming]
-        verify = [row for r, _, row in decode
-                  if row >= vbase and r.spec_drafts]
-        self.counters["decode_rows"].inc(len(decode))
-        self.counters["spec_rows"].inc(len(verify))
-        if not traced:
-            return {}
-        ps = self.pool.page_size
-        ctx = [r.pos + q for r, q, _ in rows]
-        held = [page_tables[row, :-(-c // ps)]
-                for (_, _, row), c in zip(rows, ctx)]
-        kept = [1 + int(accs[row]) if row in verify else q
-                for _, q, row in rows]
-        return dict(
-            verify_rows=len(verify),
-            spec_accepted=int(sum(accs[row] for row in verify)),
-            mtp_moe_local=int(mtp_load.sum()),
-            mtp_moe_experts_hit=int((mtp_load > 0).sum()),
-            mtp_moe_blocks=int(block_rows(mtp_load)) // ROW_BLOCK,
-            attn_pairs=sum(q * c - q * (q - 1) // 2
-                           for (_, q, _), c in zip(rows, ctx)),
-            mtp_tokens=sum(kept),
-            mtp_attn_pairs=sum(q * (r.pos + q) - q * (q - 1) // 2
-                               for (r, _, _), q in zip(rows, kept)),
-            kv_pages_distinct=len(np.unique(np.concatenate(held)))
-            if held else 0,
-            window_keys=sum(min(c, self.window + q - 1)
-                            for (_, q, _), c in zip(rows, ctx)))
-
-    def _account_hybrid(self, moe_load, live_tokens: int,
-                        decode_rows: int) -> Dict[str, Any]:
-        """Account the state slots (of the store's, the ``decode_rows``
-        whose state a mamba2 layer's recurrence walked this step) and the
-        expert layers' load; returns the ``unified_step`` span's extra
-        attributes (``state_slots``: slots held; ``moe_local``: live
-        assignments on the held experts; ``moe_load_peak``: the busiest
-        held expert over the mean; ``moe_experts_hit``: held experts,
-        summed over the expert layers, that got >= 1 token;
-        ``moe_blocks``: row blocks the grouped expert kernel ran)."""
-        st = self.state_store
-        attrs: Dict[str, Any] = {}
-        if st is not None:
-            self.gauges["state_slots_in_use"].set(st.in_use)
-            attrs["state_slots"] = st.in_use
-            self.counters["ssm_slots_walked"].inc(decode_rows)
-            self.counters["ssm_slots_store"].inc(st.num_slots)
-        if moe_load is not None and moe_load.size:
-            local = int(moe_load.sum())
-            self.counters["moe_assignments_local"].inc(local)
-            self.counters["moe_assignments_total"].inc(
-                live_tokens * self.cfg.moe_top_k * moe_load.shape[0])
-            mean = moe_load.mean()
-            peak = float(moe_load.max() / mean) if mean else 0.0
-            self.gauges["moe_expert_load_peak"].set(peak)
-            rows = int(block_rows(moe_load))
-            self.counters["moe_block_rows"].inc(rows)
-            attrs.update(moe_local=local, moe_load_peak=peak,
-                         moe_experts_hit=int((moe_load > 0).sum()),
-                         moe_blocks=rows // ROW_BLOCK)
-        return attrs
 
     def _observe_token(self, req: Request, decode_slot: bool,
                        dt: float) -> None:

@@ -1,7 +1,7 @@
 """An indexed / window latent-attention stack with a leading dense layer
 (``model_type: dots3_note``: per published layer a "dsa" or "swa" mixer,
 then "mlp" or "moe") through the serving engine, against the plain float32
-reference (``models/dots3_reference.py``: NOT absorbed, no cache, the
+reference (``benchmark/reference_dots3.py``: NOT absorbed, no cache, the
 selection and the window as masks), at tiny widths on the CPU with seeded
 random weights.
 
@@ -27,24 +27,27 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from hetu_tpu import obs
-from hetu_tpu.models import dots3_reference as ref
-from hetu_tpu.models import hybrid as hy
-from hetu_tpu.serving import Engine
-from hetu_tpu.serving.kv_pool import (WindowPages, window_table_pages,
-                                      window_tail_pages)
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(REPO, "benchmark"))
+import reference_dots3 as ref  # noqa: E402
+
+from hetu_tpu import obs  # noqa: E402
+from hetu_tpu.models import hybrid as hy  # noqa: E402
+from hetu_tpu.serving import Engine  # noqa: E402
+from hetu_tpu.serving.kv_pool import (WindowPages,  # noqa: E402
+                                      window_table_pages, window_tail_pages)
 
 GAP_F32 = 1e-4
 GAP_BF16 = 0.03
 VOCAB = 256
 TOPK, WINDOW = 12, 13
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TYPES = ["full_attention", "full_attention", "sliding_attention",
          "sliding_attention", "sliding_attention"]
 
@@ -639,10 +642,3 @@ def test_what_is_not_built_for_window_layers_is_refused():
         **cfg.mixer_geometry, "swa": cfg.geometry("swa")._replace(
             latent=32)}), name="b").pool.layout_tag
     assert a != b and a[0] == 2
-
-
-def test_the_two_copies_of_the_reference_are_one_file():
-    a = open(os.path.join(REPO, "benchmark", "reference_dots3.py")).read()
-    b = open(os.path.join(REPO, "hetu_tpu", "models",
-                          "dots3_reference.py")).read()
-    assert a == b

@@ -1,6 +1,6 @@
 """Hybrid stacks (Mamba-2 / attention / latent MoE, one mixer a layer)
 through the serving engine, against the plain float32 reference
-(``models/hybrid_reference.py``), at tiny widths on the CPU with seeded
+(``benchmark/reference_hybrid.py``), at tiny widths on the CPU with seeded
 random weights.
 
 Tolerances, each with its reason:
@@ -28,25 +28,28 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from hetu_tpu.models import hybrid as hy
-from hetu_tpu.models import hybrid_reference as ref
-from hetu_tpu.ops import ssd
-from hetu_tpu.ops.moe_grouped import ROW_BLOCK, grouped_experts
-from hetu_tpu.serving import Engine
-from hetu_tpu.serving.kv_pool import StateSlotStore
-from hetu_tpu.serving.spec import SpecConfig
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(REPO, "benchmark"))
+import reference_hybrid as ref  # noqa: E402
+
+from hetu_tpu.models import hybrid as hy  # noqa: E402
+from hetu_tpu.ops import ssd  # noqa: E402
+from hetu_tpu.ops.moe_grouped import ROW_BLOCK, grouped_experts  # noqa: E402
+from hetu_tpu.serving import Engine  # noqa: E402
+from hetu_tpu.serving.kv_pool import StateSlotStore  # noqa: E402
+from hetu_tpu.serving.spec import SpecConfig  # noqa: E402
 
 GAP_F32 = 1e-4
 TENSOR_F32 = 2e-5
 GAP_BF16 = 0.006
 VOCAB = 128
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def published(pattern: str, **kw) -> dict:
@@ -617,13 +620,6 @@ def test_lower_precision_fails_the_tolerance():
         worst = max(worst, max(ref.lowp_choice_gaps(
             state, p, 8, spec, pad_to=64, max_new=32)))
     assert worst > GAP_BF16
-
-
-def test_the_two_copies_of_the_reference_are_one_file():
-    with open(os.path.join(REPO, "benchmark", "reference_hybrid.py")) as f, \
-            open(os.path.join(REPO, "hetu_tpu", "models",
-                              "hybrid_reference.py")) as g:
-        assert f.read() == g.read()
 
 
 def test_a_plain_config_means_what_it_meant():

@@ -1,6 +1,6 @@
 """A latent-attention / gated-expert stack (``model_type: mistral4``: the
 ``(L, E) x depth`` pattern) through the serving engine, against the plain
-float32 reference (``models/mistral4_reference.py``: NOT absorbed, no
+float32 reference (``benchmark/reference_mistral4.py``: NOT absorbed, no
 cache), at tiny widths on the CPU with seeded random weights.
 
 Tolerances, each with its reason:
@@ -25,24 +25,28 @@ from __future__ import annotations
 import dataclasses
 import math
 import os
+import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from hetu_tpu.models import hybrid as hy
-from hetu_tpu.models import mistral4_reference as ref
-from hetu_tpu.ops.moe_grouped import ffn_tile, grouped_experts
-from hetu_tpu.serving import Engine
-from hetu_tpu.serving.decode import build_unified_step_fn
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(REPO, "benchmark"))
+import reference_mistral4 as ref  # noqa: E402
+
+from hetu_tpu.models import hybrid as hy  # noqa: E402
+from hetu_tpu.ops.moe_grouped import (ffn_tile,  # noqa: E402
+                                     grouped_experts)
+from hetu_tpu.serving import Engine  # noqa: E402
+from hetu_tpu.serving.decode import build_unified_step_fn  # noqa: E402
 
 GAP_F32 = 1e-4
 TENSOR_F32 = 2e-5
 GAP_BF16 = 0.02
 VOCAB = 256
 ORIG = 16                 # original_max_position_embeddings of the tiny model
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def published(**kw) -> dict:
@@ -345,16 +349,16 @@ def test_the_grid_steps_counter_follows_the_kernels_own_rule():
             (eng.pool.k_pages[0], eng.pool.v_pages[0]))
 
     assert group(1) > 1              # else the count is the pages'
-    want, reads = [], eng._latent_reads
+    want, account = [], eng.account
 
-    def spy(rows, page_tables):
+    def spy(rows, *step):
         want.append(sum(
             -(-eng.pool.pages_for(req.pos + q)
               // group(1 if row < sch.max_batch else sch.chunk))
             for req, q, row in rows))
-        return reads(rows, page_tables)
+        return account(rows, *step)
 
-    eng._latent_reads = spy
+    eng.account = spy
     for n, out in ((47, 6), (21, 9), (70, 3)):
         eng.add_request(prompts([n], seed=n)[0], out)
     eng.run()
@@ -408,10 +412,3 @@ def test_refusals_follow_the_pattern_not_the_stack():
     _, hcfg, hstate = build_hybrid("*EM")
     with pytest.raises(ValueError, match="prefix_cache=True is not built"):
         Engine(hstate, hcfg, num_pages=16, page_size=8, prefix_cache=True)
-
-
-def test_the_two_copies_of_the_reference_are_one_file():
-    a = open(os.path.join(REPO, "benchmark", "reference_mistral4.py")).read()
-    b = open(os.path.join(REPO, "hetu_tpu", "models",
-                          "mistral4_reference.py")).read()
-    assert a == b

@@ -281,9 +281,10 @@ def test_kv_page_blocks_are_counted_where_the_rule_decides(family):
     serve(eng)
     heads, blocks = _kv_counters(eng)
     if family != "hybrid":
-        assert eng._kv_block_heads is None and (heads, blocks) == (0, 0)
+        assert eng.account.kv_block_heads is None \
+            and (heads, blocks) == (0, 0)
         return
-    assert set(eng._kv_block_heads) == {eng.cfg.kv_heads}
+    assert set(eng.account.kv_block_heads) == {eng.cfg.kv_heads}
     assert blocks > 0 and heads == eng.cfg.kv_heads * blocks
 
 
@@ -309,12 +310,12 @@ def test_kv_page_blocks_follow_each_regions_heads_a_block(monkeypatch):
         want[tag] = rpa.kv_call_blocking(
             width, n * width, eng.cfg.num_heads, pages.dtype, pages,
             eng.max_pages_per_seq)[-1]
-        assert set(eng._kv_block_heads[row: row + n]) == {want[tag]}
+        assert set(eng.account.kv_block_heads[row: row + n]) == {want[tag]}
     assert want == {"decode": 2, "chunk": 1, "verify": 2}
     seen = []
-    reads = eng._kv_reads
-    eng._kv_reads = lambda rows: seen.append(
-        [(r.pos + q, row) for r, q, row in rows]) or reads(rows)
+    account = eng.account
+    eng.account = lambda rows, *step: seen.append(
+        [(r.pos + q, row) for r, q, row in rows]) or account(rows, *step)
     kx.serve(eng, kx.prompts(kx.SHAPES[:2]))
     heads, blocks = _kv_counters(eng)
     ps, vbase = eng.pool.page_size, sch.max_batch + sch.prefill_rows
@@ -324,3 +325,196 @@ def test_kv_page_blocks_follow_each_regions_heads_a_block(monkeypatch):
     assert blocks == sum(n * 2 if sch.max_batch <= row < vbase else n
                          for n, row in by_slot)
     assert 1.0 < heads / blocks < 2.0
+
+
+# ---------------------------------------------------------------------------
+# what a step read (``serving/step_account``), recounted by brute force
+# ---------------------------------------------------------------------------
+
+def _account_engine(stack: str) -> Engine:
+    """A traced tiny engine of each stack, at its own tests' widths."""
+    if stack == "dots3":
+        import test_dots3_serving as d3
+        _, cfg, state = d3.build()
+        return d3.engine(state, cfg, tracer=SpanTracer(), name="account_d3")
+    if stack == "kexaone":
+        return _kexaone(tracer=SpanTracer())[0]
+    return make_engine({"mistral4": "latent"}.get(stack, stack),
+                       tracer=SpanTracer())
+
+
+def _recount(eng: Engine, step: dict) -> tuple:
+    """One step's ``(counters, gauges, span attributes)`` from the rows of
+    the tap (slot, position, query length; the page tables) and what the
+    spy saw beside them: sets of pages as Python sets, pairs by loops."""
+    from hetu_tpu.ops.moe_grouped import ROW_BLOCK
+    from hetu_tpu.ops.ragged_paged_attention import (
+        kv_call_blocking, latent_pages_per_grid_step)
+    from hetu_tpu.serving.decode import _regions
+    cfg, sch, pool = eng.cfg, eng.scheduler, eng.pool
+    ps, maxp = pool.page_size, eng.max_pages_per_seq
+    vbase = sch.max_batch + sch.prefill_rows
+    width = {row + i: w for _, row, _, n, w in _regions(
+        sch.max_batch, sch.prefill_rows, sch.chunk, eng.spec_k)
+        for i in range(n)}
+    rows, tables = step["rows"], step["page_tables"]
+    held = {row: [int(tables[row, j]) for j in range(maxp)
+                  if j * ps < pos + q] for row, pos, q in rows}
+    every = set().union(*held.values())
+
+    def pairs(lo, n, reach=None):
+        # (query, key) pairs of n queries from position lo, each reading
+        # the keys up to itself (the last ``reach`` of them)
+        return sum(1 for x in range(lo, lo + n) for k in range(x + 1)
+                   if reach is None or x - k < reach)
+
+    causal = sum(pairs(pos, q) for _, pos, q in rows)
+    c, g, a = {}, {}, {}
+    if cfg.layers_of("mamba2"):
+        g["state_slots_in_use"] = step["slots"]
+        c["ssm_slots_walked"] = sum(row < sch.max_batch for row, _, _ in rows)
+        c["ssm_slots_store"] = eng.state_store.num_slots
+
+    def experts(load, tag):
+        blocks = sum(-(-int(n) // ROW_BLOCK) for layer in load for n in layer)
+        return blocks, {f"{tag}_local": sum(int(n) for l in load for n in l),
+                        f"{tag}_experts_hit": sum(
+                            1 for l in load for n in l if n > 0),
+                        f"{tag}_blocks": blocks}
+
+    if cfg.layers_of("moe"):
+        load = step["out"]["moe_load"]
+        blocks, attrs = experts(load, "moe")
+        a.update(attrs)
+        a["moe_load_peak"] = g["moe_expert_load_peak"] = \
+            float(load.max() * load.size / max(a["moe_local"], 1))
+        c["moe_assignments_local"] = a["moe_local"]
+        c["moe_assignments_total"] = sum(q for _, _, q in rows) \
+            * cfg.moe_top_k * len(cfg.layers_of("moe"))
+        c["moe_block_rows"] = blocks * ROW_BLOCK
+    full = [n for n, i in enumerate(cfg.paged_layers)
+            if cfg.is_hybrid and cfg.stack_pattern[i] == "attention"
+            and not cfg.window_of(i)]
+    if full:
+        kv = pool.k_pages[full[0]]
+        c["kv_page_heads"] = cfg.kv_heads * sum(map(len, held.values()))
+        c["kv_page_blocks"] = sum(
+            len(held[row]) * (cfg.kv_heads // kv_call_blocking(
+                width[row], (sch.max_batch if row < sch.max_batch else 1)
+                * width[row], cfg.num_heads, kv.dtype, kv, maxp)[-1])
+            for row in held)
+    if cfg.layers_of("mla"):
+        a.update(latent_ctx_tokens=sum(pos + q for _, pos, q in rows),
+                 latent_pages=sum(map(len, held.values())),
+                 latent_pages_distinct=len(every), attn_pairs=causal,
+                 latent_grid_steps=sum(
+                     -(-len(held[row]) // latent_pages_per_grid_step(
+                         width[row], cfg.num_heads,
+                         sum(cfg.latent_page_dims), maxp,
+                         (pool.k_pages[0], pool.v_pages[0])))
+                     for row in held))
+        c.update(latent_pages_attended=a["latent_pages"],
+                 latent_pages_attended_distinct=len(every),
+                 latent_grid_steps=a["latent_grid_steps"])
+    if cfg.page_layers is not None:
+        topk = cfg.mixer_geometry["dsa"].index_topk \
+            if cfg.layers_of("dsa") else 0
+        docs = {}
+        for row, pos, q in rows if topk else ():
+            docs[held[row][0]] = max(docs.get(held[row][0], 0),
+                                     min(pos + q, topk))
+        wins = [pg for pages in step["win_pages"] for pg in pages]
+        a.update(index_pairs=causal,
+                 index_selected=sum(pairs(pos, q, topk) for _, pos, q in rows)
+                 if topk else 0,
+                 index_selected_floor=sum(docs.values()),
+                 index_pages_distinct=len(every) if topk else 0,
+                 window_pages=len(wins),
+                 window_tokens_distinct=ps * len(set(wins)),
+                 window_pairs=sum(pairs(pos, q, cfg.window_tokens)
+                                  for _, pos, q in rows))
+        c.update(index_pairs_scored=causal,
+                 index_positions_selected=a["index_selected"],
+                 window_pages_held=step["window_in_use"],
+                 full_pages_held=step["full_in_use"])
+    if eng.self_draft:
+        decode = [row for (row, _, _), d in zip(rows, step["decode"]) if d]
+        verify = [row for (row, _, _), d, v in zip(
+            rows, step["decode"], step["drafted"])
+            if d and v and row >= vbase]
+        acc = step["out"]["accepted"]
+        kept = {row: 1 + int(acc[row]) if row in verify else q
+                for row, _, q in rows}
+        c.update(decode_rows=len(decode), spec_rows=len(verify))
+        a.update(experts(step["out"]["mtp_load"], "mtp_moe")[1],
+                 verify_rows=len(verify),
+                 spec_accepted=sum(int(acc[row]) for row in verify),
+                 attn_pairs=causal, mtp_tokens=sum(kept.values()),
+                 mtp_attn_pairs=sum(pairs(pos, kept[row])
+                                    for row, pos, _ in rows),
+                 kv_pages_distinct=len(every),
+                 window_keys=sum(len({
+                     k for x in range(pos, pos + q) for k in range(x + 1)
+                     if x - k < cfg.window_tokens}) for _, pos, q in rows))
+    return c, g, a
+
+
+@pytest.mark.parametrize(
+    "stack", ("dense", "hybrid", "mistral4", "dots3", "kexaone"))
+def test_the_account_is_what_the_steps_rows_read(stack):
+    """Every counter and gauge ``serving/step_account`` writes and every
+    attribute it gives the ``unified_step`` span, against a recount from
+    the tap's rows: two waves of requests, the second two rows on one
+    document (shared pages where the stack keeps a prefix cache).  The
+    dense step is accounted nothing."""
+    from hetu_tpu.serving.step_account import COUNTERS, GAUGES
+    eng = _account_engine(stack)
+    account, seen = eng.account, []
+
+    def spy(rows, fields, out, traced):
+        st, win = eng.state_store, eng.pool.window
+        seen.append(dict(
+            out={k: v.copy() for k, v in out.items()},
+            win_pages=[list(r.win_pages) for r, _, _ in rows],
+            decode=[len(r.tokens) - r.pos == 1 and r.n_generated > 0
+                    and not r.resuming for r, _, _ in rows],
+            drafted=[bool(r.spec_drafts) for r, _, _ in rows],
+            slots=st.in_use if st is not None else 0,
+            window_in_use=win.in_use if win is not None else 0,
+            full_in_use=eng.pool.num_usable - eng.pool.free_pages))
+        return account(rows, fields, out, traced)
+
+    eng.account = spy
+    rng = np.random.RandomState(5)
+
+    def draw(n):
+        return rng.randint(1, eng.cfg.vocab_size, n).tolist()
+
+    doc = draw(20)
+    for prompt, new in ((draw(19), 6), (draw(5), 9), (doc, 4)):
+        eng.add_request(prompt, new)
+    eng.run()
+    for tail in ([3, 4, 5], [7]):
+        eng.add_request(doc + tail, 5)
+    eng.run()
+    taps = [t for t in eng.tap if t["kind"] == "unified"]
+    spans = [e.attrs for e in eng.tracer.events() if e.name == "unified_step"]
+    assert len(taps) == len(seen) == len(spans) > 10
+    base = {"exec", "step", "rows", "tokens", "h2d_bytes"}
+    counters = dict.fromkeys(COUNTERS, 0)
+    gauges = dict.fromkeys(GAUGES, 0)
+    for tap, step, span in zip(taps, seen, spans):
+        c, g, a = _recount(eng, {**tap, **step})
+        for k, v in c.items():
+            counters[k] += v
+        gauges.update(g)
+        assert {k: span[k] for k in set(span) - base} == a
+    m = eng.metrics_summary()
+    assert {k: m[k] for k in counters} == counters
+    assert {k: m[k] for k in gauges} == pytest.approx(gauges)
+    if stack == "dense":
+        assert not any(counters.values()) and not any(gauges.values())
+    else:
+        assert any(counters.values())
+    if eng.prefix_cache is not None and stack != "dense":
+        assert m["prefix_cache_hits"] >= 1
