@@ -177,6 +177,15 @@ class GPTConfig:
     # predicts the token after, sharing ``wte`` and ``lm_head``.  The
     # serving step runs it to draft for its own verify rows.
     mtp_pattern: Tuple[str, ...] = ()
+    # Generation by diffusion over blocks (a layer_pattern stack, serving
+    # path): the model's block length ``diffusion_block`` (0: one position
+    # after another) and the id it was trained to read as "not yet known".
+    # Key ``j`` is visible to the query at ``p`` iff ``j // B <= p // B``:
+    # causal across blocks, both ways inside one; the logits at ``p`` score
+    # the token AT ``p``.  The serving step's block region and the engine's
+    # block loop follow from these two (DESIGN.md section 29).
+    diffusion_block: int = 0
+    mask_token_id: Optional[int] = None
     norm_eps: Optional[float] = None        # None -> the norm's own default
     mamba_num_heads: int = 0
     mamba_head_dim: int = 0
@@ -249,13 +258,22 @@ class GPTConfig:
                     f"got {self.mtp_pattern}")
             if self.attn_rope not in ("all", "window"):
                 raise ValueError(f"unknown attn_rope {self.attn_rope!r}")
+            if self.diffusion_block and not (
+                    self.diffusion_block >= 2 and self.mask_token_id
+                    is not None
+                    and 0 <= self.mask_token_id < self.vocab_size):
+                raise ValueError(
+                    "diffusion_block is a block of >= 2 positions and comes "
+                    "with the mask_token_id (a row of the vocabulary) its "
+                    "masked positions are fed as")
         elif self.attn_head_dim or self.attn_qk_norm or self.attn_window \
-                or self.mtp_pattern:
+                or self.mtp_pattern or self.diffusion_block:
             raise ValueError(
-                "attn_head_dim / attn_qk_norm / attn_window / mtp_pattern "
+                "attn_head_dim / attn_qk_norm / attn_window / mtp_pattern / "
+                "diffusion_block "
                 "describe the attention mixer of a layer_pattern stack; the "
                 "plain block (training, generate) keeps head_dim = hidden / "
-                "heads and has no MTP module")
+                "heads, has no MTP module and no block mask")
         if self.moe_router not in ("softmax", "sigmoid_bias"):
             raise ValueError(f"unknown moe_router {self.moe_router!r}")
         if self.layer_pattern is None and (

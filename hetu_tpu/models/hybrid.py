@@ -60,7 +60,10 @@ translation for the ``(L, E) x depth`` stacks of ``model_type: mistral4``,
 layers of different geometry, a leading dense layer),
 ``exaone_moe_config`` for ``model_type: exaone_moe`` (plain K/V attention
 at its own head width behind QK-norms, window layers beside full ones,
-an MTP module that drafts for the serving step's verify rows).
+an MTP module that drafts for the serving step's verify rows),
+``sdar_moe_config`` for ``model_type: sdar_moe`` (the same K/V attention
+and gated experts under a softmax router, generated by diffusion over
+blocks: ``GPTConfig.diffusion_block`` / ``mask_token_id``).
 """
 from __future__ import annotations
 
@@ -335,6 +338,61 @@ def exaone_moe_config(pub: dict, **overrides) -> GPTConfig:
         **_expert_keys(dict(pub, n_routed_experts=pub["num_experts"],
                             n_shared_experts=pub["num_shared_experts"]),
                        "moe_intermediate_size"))
+    kw.update(overrides)
+    return GPTConfig(**kw)
+
+
+def sdar_moe_config(pub: dict, **overrides) -> GPTConfig:
+    """The one translation from the published ``config.json`` keys of an
+    ``sdar_moe``-type model (a Qwen3-MoE stack generated by diffusion over
+    blocks) to ``GPTConfig``.  Every published layer is two pattern
+    entries: "attention" — plain K/V heads of ``head_dim`` lanes behind an
+    RMSNorm on q and on k, rotated by halves at base ``rope_theta`` in
+    every layer — then "moe": softmax over all ``num_experts`` in float32,
+    the ``num_experts_per_tok`` largest renormalised (``norm_topk_prob``),
+    gated silu experts ``moe_intermediate_size`` wide, no shared expert,
+    every expert held.  What is the MODEL'S and not the request's rides on
+    the configuration: ``diffusion_block`` (the block length the model was
+    trained under) and ``mask_token_id``; ``config.json`` carries neither,
+    so the caller states them (``pub["assumed"]``: ``block_length``,
+    ``mask_token_id``).  ``intermediate_size`` is read by no layer
+    (``mlp_only_layers`` is empty and ``decoder_sparse_step`` 1)."""
+    _refuse_unbuilt(pub)
+    assumed = pub.get("assumed", {})
+    unbuilt = [k for k, ok in (
+        ("mlp_only_layers", not pub.get("mlp_only_layers")),
+        ("decoder_sparse_step", pub.get("decoder_sparse_step", 1) == 1),
+        ("use_sliding_window", not pub.get("use_sliding_window")),
+        ("rope_scaling", pub.get("rope_scaling") is None),
+        ("attention_bias", not pub.get("attention_bias")),
+        ("norm_topk_prob", bool(pub.get("norm_topk_prob", True))),
+        ("assumed.block_length", "block_length" in assumed),
+        ("assumed.mask_token_id", "mask_token_id" in assumed)) if not ok]
+    if unbuilt:
+        raise ValueError(
+            "sdar_moe is built with an expert layer in every layer, no "
+            "window, no scaled rotary stream, no biases, the chosen experts "
+            "renormalised, and the block length and mask id stated under "
+            f"assumed; not built: {', '.join(unbuilt)}")
+    layers = pub["num_hidden_layers"]
+    kw = dict(
+        vocab_size=pub["vocab_size"], hidden_size=pub["hidden_size"],
+        num_layers=2 * layers, num_heads=pub["num_attention_heads"],
+        num_kv_heads=pub["num_key_value_heads"],
+        attn_head_dim=pub["head_dim"], attn_qk_norm=True, attn_rope="all",
+        rope_theta=float(pub["rope_theta"]),
+        max_seq_len=pub["max_position_embeddings"],
+        activation=pub["hidden_act"], norm="rmsnorm", position="rotary",
+        norm_eps=float(pub["rms_norm_eps"]),
+        tie_embeddings=bool(pub["tie_word_embeddings"]), sp=False,
+        dtype=pub.get("dtype", "bfloat16"),
+        layer_pattern=("attention", "moe") * layers,
+        moe_router="softmax", moe_norm_topk=True, moe_gated=True,
+        num_experts=pub["num_experts"], experts_held=pub["num_experts"],
+        moe_top_k=pub["num_experts_per_tok"],
+        moe_ffn_size=pub["moe_intermediate_size"],
+        diffusion_block=int(assumed["block_length"]),
+        mask_token_id=int(assumed["mask_token_id"]))
     kw.update(overrides)
     return GPTConfig(**kw)
 

@@ -43,13 +43,17 @@ __all__ = ["PHASES", "UNMAPPED", "phase", "current_phase",
 # self-drafting step's MTP module (serving/decode.py) runs under four of
 # its own, OUTSIDE its mixers' (the first word on a path wins), so that no
 # share of the stack's phases holds its time: its way in (mtp_proj), its
-# attention layer (mtp_attn), its FFN (mtp_moe), its norm and head (mtp_head)
+# attention layer (mtp_attn), its FFN (mtp_moe), its norm and head
+# (mtp_head); a block-wise model's step ends in its block head (block_head:
+# logits at the block slots' positions, the choices, their confidences and
+# the selection of what the pass unmasks)
 PHASES = ("embed", "norm", "attn_proj", "attn_core", "mlp", "lm_head_ce",
           "optimizer", "grad_comm", "param_gather", "kv_scatter", "sample",
           "ssm_proj", "ssm_conv", "ssm_scan", "state_io",
           "moe_router", "moe_latent", "moe_routed", "moe_shared", "mla_absorb",
           "attn_index", "attn_sparse", "attn_window", "attn_gate",
-          "mlp_dense", "mtp_proj", "mtp_attn", "mtp_moe", "mtp_head")
+          "mlp_dense", "mtp_proj", "mtp_attn", "mtp_moe", "mtp_head",
+          "block_head")
 UNMAPPED = "unmapped"
 
 # scope names that predate the vocabulary (parallel/comm.py's comm_tag

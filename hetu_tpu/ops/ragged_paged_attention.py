@@ -113,6 +113,19 @@ def _check_ragged_shapes(q, k_pages, v_pages, q_lens, cu_q, page_tables,
     return t, nh, hd, ps, kvh, s
 
 
+def block_mask(cols, qpos, ctx, mask_block: int):
+    """Which key positions ``cols`` the query at ``qpos`` of a row that
+    holds ``ctx`` keys sees.  ``mask_block`` 1 is the causal mask, ``cols
+    <= qpos``, the line as it was.  ``mask_block`` B > 1 is the BLOCK-WISE
+    mask of a model that generates by diffusion over blocks
+    (``GPTConfig.diffusion_block``): causal across blocks of B positions,
+    both ways inside one — every key the row holds up to the end of the
+    query's own block, ``cols < min(ctx, (qpos // B + 1) * B)``."""
+    if mask_block == 1:
+        return cols <= qpos
+    return cols < jnp.minimum(ctx, (qpos // mask_block + 1) * mask_block)
+
+
 # ---------------------------------------------------------------------------
 # reference path (CPU / oracle)
 # ---------------------------------------------------------------------------
@@ -122,13 +135,14 @@ def ragged_paged_attention_reference(q: jax.Array, k_pages: jax.Array,
                                      cu_q: jax.Array,
                                      page_tables: jax.Array,
                                      ctx_lens: jax.Array, *, max_q: int,
-                                     softmax_scale: Optional[float] = None
-                                     ) -> jax.Array:
+                                     softmax_scale: Optional[float] = None,
+                                     mask_block: int = 1) -> jax.Array:
     """Dense oracle for the ragged contract: per row, gather its pages
     in position order and run masked fp32 attention over a static
     ``max_q`` query window at ``cu_q[i]``.  Returns ``[T, nh, hd]``;
     rows' padding windows never leak into neighbouring rows (masked
-    read-modify-write, mirroring the kernel)."""
+    read-modify-write, mirroring the kernel).  ``mask_block`` is
+    :func:`block_mask`'s."""
     t, nh, hd, ps, kvh, s = _check_ragged_shapes(
         q, k_pages, v_pages, q_lens, cu_q, page_tables, ctx_lens, max_q)
     maxp = page_tables.shape[1]
@@ -148,7 +162,8 @@ def ragged_paged_attention_reference(q: jax.Array, k_pages: jax.Array,
             sc = jnp.einsum("qhgd,khd->qhgk", qg,
                             k.astype(jnp.float32)) * scale
             qpos = (ctx - qlen) + jnp.arange(max_q)       # absolute pos
-            valid = kv_pos[None, :] <= qpos[:, None]      # causal in-row
+            valid = block_mask(kv_pos[None, :], qpos[:, None], ctx,
+                               mask_block)
             sc = jnp.where(valid[:, None, None, :], sc,
                            DEFAULT_MASK_VALUE)
             pr = jax.nn.softmax(sc, axis=-1)
@@ -168,7 +183,7 @@ def ragged_paged_attention_reference(q: jax.Array, k_pages: jax.Array,
 # ---------------------------------------------------------------------------
 
 def _ragged_kernel(*refs, scale: float, ps: int, maxp: int, gp: int,
-                   sub: int, kpg: int = 1, hb: int = 1):
+                   sub: int, kpg: int = 1, hb: int = 1, mask_block: int = 1):
     """One (block of kv heads, row, page group) grid step over a
     ``win``-row query tile a head; a group is ``kpg`` consecutive
     page-table slots (1: a page), a block the ``hb`` kv heads a slot is
@@ -187,7 +202,7 @@ def _ragged_kernel(*refs, scale: float, ps: int, maxp: int, gp: int,
     fetch); the output; the scratch: ``hb`` of ``m``, ``hb`` of ``l``,
     ``hb`` of ``acc`` (a head's each).  The heads of the block run one
     after the other inside the step, each its own online softmax over
-    the group's keys under the one mask."""
+    the group's keys under the one mask (:func:`block_mask`)."""
     ql_ref, cu_ref, pt_ref, cl_ref = refs[:4]
     q_ref, *kv_refs = refs[4: 5 + 2 * kpg]
     o_ref, *state = refs[5 + 2 * kpg:]
@@ -247,7 +262,7 @@ def _ragged_kernel(*refs, scale: float, ps: int, maxp: int, gp: int,
             if seen is None:
                 cols = p * ps + lax.broadcasted_iota(jnp.int32, (win, ps), 1)
                 qpos = (ctx - qlen) + query_index((win, ps))  # absolute
-                seen = cols <= qpos
+                seen = block_mask(cols, qpos, ctx, mask_block)
             s = jnp.where(seen, s, DEFAULT_MASK_VALUE)
             m_prev = m_scr[:, :1]                          # [win, 1]
             m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -385,7 +400,8 @@ def kv_call_blocking(max_q: int, tokens: int, num_heads: int, q_dtype,
 @functools.partial(jax.jit, static_argnames=("max_q", "softmax_scale",
                                              "interpret", "name",
                                              "pages_per_step",
-                                             "heads_per_block"))
+                                             "heads_per_block",
+                                             "mask_block"))
 def ragged_paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
                                   v_pages: jax.Array, q_lens: jax.Array,
                                   cu_q: jax.Array, page_tables: jax.Array,
@@ -394,8 +410,8 @@ def ragged_paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
                                   interpret: Optional[bool] = None,
                                   name: str = "ragged_paged_attention",
                                   pages_per_step: Optional[int] = None,
-                                  heads_per_block: Optional[int] = None
-                                  ) -> jax.Array:
+                                  heads_per_block: Optional[int] = None,
+                                  mask_block: int = 1) -> jax.Array:
     """Pallas ragged paged attention (same contract as the reference).
 
     Grid is ``(kvh / hb, S, ceil(maxp / kpg))`` with the groups of
@@ -416,6 +432,8 @@ def ragged_paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
     as it was — until the benchmark's replay can hold what groups and
     head blocks give it (``serving/decode.py``).  ``heads_per_block``
     overrides :func:`kv_heads_per_block` for the kernel's tests alone.
+    ``mask_block`` B > 1 (static) is the block-wise mask of
+    :func:`block_mask`; at 1 the call traces op for op as it did.
     """
     t, nh, hd, ps, kvh, s = _check_ragged_shapes(
         q, k_pages, v_pages, q_lens, cu_q, page_tables, ctx_lens, max_q)
@@ -435,7 +453,8 @@ def ragged_paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
     # a table the group does not divide ends in the trash page's slots
     page_tables = jnp.pad(page_tables, ((0, 0), (0, groups * kpg - maxp)))
     kernel = functools.partial(_ragged_kernel, scale=float(scale), ps=ps,
-                               maxp=groups, gp=gp, sub=sub, kpg=kpg, hb=hb)
+                               maxp=groups, gp=gp, sub=sub, kpg=kpg, hb=hb,
+                               mask_block=int(mask_block))
     tokens = lambda h, i, p, *_: (h, 0, 0)               # noqa: E731
 
     def slot(j):                     # slot j of the grid step's group

@@ -19,20 +19,24 @@ read-only pages, LRU eviction — on by default, disable with
 replicas, disaggregated prefill/decode, priced KV-page streaming), and
 §20 for draft-model speculative decoding
 (``Engine(spec=SpecConfig(draft_state, draft_cfg, k=4))``: ragged
-verify rows, on-device accept, temp-0 output still bit-for-bit).
+verify rows, on-device accept, temp-0 output still bit-for-bit), and
+§29 for block-wise generation (a ``cfg.diffusion_block`` model: a
+generating request's step is its open block, denoised in passes under
+``Engine(denoise=DenoiseRule(steps=, rule=, tau=))`` and then committed).
 """
 from .cluster import (ClusterRequest, EngineCluster, LocalPageTransport,
                       PageTransport, Replica, Router)
 from .engine import Engine
 from .kv_pool import PagedKVPool, TRASH_PAGE
 from .prefix_cache import CacheEntry, PrefixCache
-from .request import FINISHED, RUNNING, WAITING, Request, RequestQueue
+from .request import (FINISHED, RUNNING, WAITING, DenoiseRule, Request,
+                      RequestQueue)
 from .scheduler import Scheduler
 from .spec import SpecConfig, SpecDecoder
 
 __all__ = ["Engine", "PagedKVPool", "TRASH_PAGE", "PrefixCache",
            "CacheEntry", "Request", "RequestQueue", "Scheduler",
            "WAITING", "RUNNING", "FINISHED",
-           "SpecConfig", "SpecDecoder",
+           "SpecConfig", "SpecDecoder", "DenoiseRule",
            "EngineCluster", "ClusterRequest", "Replica", "Router",
            "PageTransport", "LocalPageTransport"]

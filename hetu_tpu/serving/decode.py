@@ -85,7 +85,7 @@ def _rope_tok(x, cos_g, sin_g):
 
 
 def _chunk_slots(max_seqs: int, prefill_rows: int, chunk: int,
-                 spec_k: int):
+                 spec_k: int, block: int = 0):
     """The multi-token slot layout shared by the region map, the
     split-attention fallback and the engine's ``cu_q``: a list of
     ``(row_index, token_start, width)``.  Plain prefill chunk slots
@@ -94,12 +94,15 @@ def _chunk_slots(max_seqs: int, prefill_rows: int, chunk: int,
     ``spec_k + 1`` — a verify row is structurally a prefill chunk, but
     giving it its own narrow slot means verifying k drafts prices
     ``k + 1`` tokens of compute, not a whole ``chunk``-wide slot, and
-    verify traffic never competes with prompt prefills for slots."""
+    verify traffic never competes with prompt prefills for slots.  A
+    model that generates by diffusion over blocks (``block`` positions,
+    ``GPTConfig.diffusion_block``) owns the same ``max_seqs`` narrow slots
+    at width ``block``: a generating row's step is its open block."""
     slots = [(max_seqs + r, max_seqs + r * chunk, chunk)
              for r in range(prefill_rows)]
-    if spec_k:
+    if spec_k or block:
         base = max_seqs + prefill_rows * chunk
-        vk = spec_k + 1
+        vk = block or spec_k + 1
         slots += [(max_seqs + prefill_rows + j, base + j * vk, vk)
                   for j in range(max_seqs)]
     return slots
@@ -267,16 +270,19 @@ def _split_latent_ragged_attention(cfg: GPTConfig, q_cat, cp, rp, q_lens,
     return jnp.concatenate(outs, axis=0)
 
 
-def _regions(max_seqs: int, prefill_rows: int, chunk: int, spec_k: int):
+def _regions(max_seqs: int, prefill_rows: int, chunk: int, spec_k: int,
+             block: int = 0):
     """The static token axis as regions of equal-width rows: a list of
     ``(tag, first_row, first_token, rows, width)`` — the decode slots,
-    the chunk slots and (spec mode) the verify slots.  Attention and the
-    KV write are both issued per region; nothing selects one at run
+    the chunk slots and the narrow slots behind them: (spec mode) the
+    verify slots or (a block-wise model) the block slots.  Attention and
+    the KV write are both issued per region; nothing selects one at run
     time."""
-    slots = _chunk_slots(max_seqs, prefill_rows, chunk, spec_k)
+    slots = _chunk_slots(max_seqs, prefill_rows, chunk, spec_k, block)
     regions = [("decode", 0, 0, max_seqs, 1)]
     for tag, part in (("chunk", slots[:prefill_rows]),
-                      ("verify", slots[prefill_rows:])):
+                      ("block" if block else "verify",
+                       slots[prefill_rows:])):
         if part:
             row, tok, width = part[0]
             regions.append((tag, row, tok, len(part), width))
@@ -299,6 +305,14 @@ class StepLayout:
                            the row's last fed one where the host knows it —
                            a prompt's chunk — and -1 where the step itself
                            samples it)
+        unmask_k [r]  unmask_tau [r] f32
+                           (a block-wise model, ``cfg.diffusion_block``: how
+                           many of a block row's masked positions this pass
+                           unmasks by rank — of confidence, or, negative, of
+                           position; 0 on a commit pass — and the confidence
+                           above which a masked position is unmasked
+                           whatever its rank, 2.0 where the rule is static:
+                           ``serving/request.py::DenoiseRule.unmask``)
         state_slots [r]    (a hybrid stack)
         win_tables [r, window pages]  win_base [r]  win_token_page [t]
                            (a stack with window layers: a row's pages in
@@ -317,14 +331,19 @@ class StepLayout:
     stack; a self-drafting build adds ``mtp_load``, its MTP module's),
     then ``accepted [r]`` (a speculative build), then ``draft [r]`` (a
     self-drafting build: the token its MTP module proposes behind the
-    row's last committed one)."""
+    row's last committed one); a block-wise model adds, a block slot,
+    ``block_tokens [max_seqs, B]`` (the pass's choice at a masked
+    position, the fed token elsewhere), ``block_flags [max_seqs]`` (bit
+    ``j``: position ``j`` was unmasked by this pass) and ``block_conf
+    [max_seqs, B]`` (the choices' confidences, float32 by bit pattern)."""
 
-    F32 = ("temps", "top_ps")
+    F32 = ("temps", "top_ps", "unmask_tau")
 
     def __init__(self, cfg: GPTConfig, max_seqs: int, chunk: int,
                  prefill_rows: int, max_pages: int, spec_k: int = 0,
                  page_size: int = 0):
-        regions = _regions(max_seqs, prefill_rows, chunk, spec_k)
+        block = cfg.diffusion_block
+        regions = _regions(max_seqs, prefill_rows, chunk, spec_k, block)
         _, row, tok, n, width = regions[-1]
         self.n_rows, self.n_tokens = row + n, tok + n * width
         self.cu_q = np.concatenate(
@@ -341,6 +360,8 @@ class StepLayout:
             shapes["spec_lens"] = (r,)
         if self_draft:
             shapes["next_tok"] = (r,)
+        if block:
+            shapes.update(unmask_k=(r,), unmask_tau=(r,))
         if cfg.is_hybrid:
             shapes["state_slots"] = (r,)
         if cfg.is_hybrid and cfg.window_tokens:
@@ -359,6 +380,10 @@ class StepLayout:
             outs["accepted"] = (r,)
         if self_draft:
             outs["draft"] = (r,)
+        if block:
+            outs.update(block_tokens=(max_seqs, block),
+                        block_flags=(max_seqs,),
+                        block_conf=(max_seqs, block))
         self.outs, self.out_size = self._offsets(outs)
 
     @staticmethod
@@ -410,7 +435,8 @@ class StepLayout:
 
 def _attend_by_region(kernel, name: str, q, q_lens, cu_q, page_tables,
                       ctx_lens, max_seqs: int, prefill_rows: int,
-                      chunk: int, spec_k: int = 0, kv_base=None):
+                      chunk: int, spec_k: int = 0, kv_base=None,
+                      block: int = 0):
     """On-TPU ragged attention over the structured serving layout: one
     ``kernel`` call per REGION of the static token axis, so a grid step
     computes a query window of the region's own width — one token for
@@ -420,12 +446,13 @@ def _attend_by_region(kernel, name: str, q, q_lens, cu_q, page_tables,
     pages bound (``functools.partial``); each call gets, by keyword, its
     slice of the token and row axes with ``cu_q`` rebased to the slice,
     and the outputs are concatenated on the token axis.  On the device
-    trace the calls are ``<name>_decode`` / ``_chunk`` / ``_verify``.
+    trace the calls are ``<name>_decode`` / ``_chunk`` / ``_verify`` /
+    ``_block`` (a block-wise model's generating rows, width ``block``).
     ``kv_base [rows]`` (a window layer's ``kernel``: the position each
     row's table starts at) is sliced like the other per-row arrays."""
     outs = []
     for tag, row, tok, n, width in _regions(max_seqs, prefill_rows, chunk,
-                                            spec_k):
+                                            spec_k, block):
         rows, toks = slice(row, row + n), slice(tok, tok + n * width)
         based = {} if kv_base is None else {"kv_base": kv_base[rows]}
         outs.append(kernel(
@@ -565,6 +592,7 @@ def build_unified_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
                 "rejected draft cannot be rolled out of recurrent (mamba2) "
                 "state, and the latent layers' by-region calls have no "
                 "verify region")
+        _refuse_unbuilt_block(c, chunk, page_size, spec_k)
         return _build_hybrid_step_fn(c, max_seqs, chunk, prefill_rows,
                                      max_pages, page_size, use_kernel,
                                      spec_k)
@@ -830,6 +858,87 @@ def build_unified_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
     return run
 
 
+def _block_head(cfg: GPTConfig, p, x, tokens, token_pos, live, sampling,
+                unmask_k, unmask_tau):
+    """The head of a block-wise model's step over its block slots: ``x [S
+    * B, H]`` (normed), the fed ``tokens`` and their positions, ``live``
+    (the slot holds a row), the slots' ``(temps, top_ps, top_ks, seeds)``
+    and unmask rule, all ``[S]``.  Logits at every block position (they
+    score the token AT the position), the mask id's left out (a position
+    is never unmasked INTO the mask id: with seeded weights it would be the
+    arg-max once in a vocabulary's worth of positions, and the block would
+    never close); ``x0`` from the repo's one per-row
+    sampler, keyed by ``(seed, the position's index)`` — greedy at
+    temperature 0 — and ``c = softmax(logits / T)[x0]`` (the whole
+    tempered distribution: a top-k / top-p cut moves the draw, not ``c``);
+    then, a row, of the MASKED positions the ``|unmask_k|`` first by rank —
+    of highest ``c`` (a stable sort: ties to the lower position; fewer
+    where fewer are masked) or, ``unmask_k < 0``, of lowest position (the
+    sequential rule) — together with every masked one whose ``c >
+    unmask_tau``.  A commit pass sends 0 and 2.0 and holds no mask:
+    nothing is selected.
+    Returns the layout's ``block_tokens`` (``x0`` where masked, the fed
+    token elsewhere), ``block_flags`` and ``block_conf`` (bit pattern)."""
+    b = cfg.diffusion_block
+    n = unmask_k.shape[0]
+    head = p("lm_head.weight")
+    head = head if head is not None else p("wte.weight")
+    # the stack's dtype on both sides, float32 sums: no float32 copy of a
+    # 150k-row head
+    logits = lax.dot_general(x.astype(head.dtype), head,
+                             (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    logits = jnp.where(jnp.arange(logits.shape[1])[None, :] ==
+                       cfg.mask_token_id, -jnp.inf, logits)
+    temps, top_ps, top_ks, seeds = (jnp.repeat(a, b) for a in sampling)
+    x0 = sample_rows(logits, temps, top_ps, top_ks, seeds, token_pos)
+    scaled = logits / jnp.where(temps > 0, temps, 1.0)[:, None]
+    chosen = jnp.take_along_axis(scaled, x0[:, None], axis=1)[:, 0]
+    conf = jnp.exp(chosen - jax.nn.logsumexp(scaled, axis=-1))
+    masked = ((tokens == cfg.mask_token_id) & live).reshape(n, b)
+    conf = conf.reshape(n, b)
+    by_conf = jnp.where(unmask_k[:, None] < 0, 0.0, conf)
+    order = jnp.argsort(-jnp.where(masked, by_conf, -1.0), axis=1,
+                        stable=True)
+    rank = jnp.argsort(order, axis=1)
+    pick = masked & ((rank < jnp.abs(unmask_k)[:, None]) |
+                     (conf > unmask_tau[:, None]))
+    flags = jnp.sum(pick.astype(jnp.int32) << jnp.arange(b)[None, :], axis=1)
+    return dict(
+        block_tokens=jnp.where(masked, x0.reshape(n, b),
+                               tokens.reshape(n, b)),
+        block_flags=flags,
+        block_conf=lax.bitcast_convert_type(conf, jnp.int32))
+
+
+def _refuse_unbuilt_block(cfg: GPTConfig, chunk: int, page_size: int,
+                          spec_k: int) -> None:
+    """What is not built with a block-wise model (``cfg.diffusion_block``),
+    refused where the step is built, by name."""
+    b = cfg.diffusion_block
+    if not b:
+        return
+    other = sorted(set(cfg.layer_pattern) - {"attention", "moe", "mlp"})
+    if other or cfg.window_tokens:
+        raise ValueError(
+            "block-wise generation (diffusion_block) is built over full "
+            "plain K/V attention layers: a denoise pass's provisional state "
+            "cannot be rolled out of recurrent (mamba2) state, the latent "
+            "(mla / dsa / swa) calls have no block mask, and a window layer "
+            f"would slide inside an open block; the stack has "
+            f"{other or 'window layers (attn_window)'}")
+    if spec_k or cfg.mtp_pattern:
+        raise ValueError(
+            "speculative decoding is not built with block-wise generation "
+            "(diffusion_block): a block row's step is its open block, "
+            "there is no next token to draft")
+    if chunk % b or page_size % b:
+        raise ValueError(
+            f"a chunk ({chunk}) and a page ({page_size}) are multiples of "
+            f"the block length ({b}): a prompt's chunks and a cached page "
+            "end where a block ends")
+
+
 def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
                           prefill_rows: int, max_pages: int, page_size: int,
                           use_kernel: bool, spec_k: int = 0):
@@ -904,18 +1013,39 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
     under the row's full-space pages and proposes, at each row's last
     committed position, the draft of the row's next step (``draft``).  A
     verify row's position behind a rejected draft is dead to the module's
-    experts and its K/V is overwritten by the next step, as the stack's."""
+    experts and its K/V is overwritten by the next step, as the stack's.
+
+    ``cfg.diffusion_block`` = B (BLOCK-WISE generation, DESIGN.md §29): the
+    narrow slots behind the chunk slots are the BLOCK slots, ``max_seqs``
+    rows of B positions on the verify slots' scaffolding (the same
+    regions, write plan and one pass over the weights with the decode
+    slots).  A generating row feeds its open block — known tokens, and
+    ``cfg.mask_token_id`` where a position is not yet known — at the
+    block's positions; every layer writes the pass's K/V under the row's
+    own pages there (a later pass overwrites it, as a rejected draft's;
+    the commit pass's stands) and attends under the block-wise mask
+    (``mask_block=B``: every region, the prompt's chunks too).  The step
+    ends in the BLOCK HEAD, not the next-token head: logits at the block
+    slots' positions (position ``p``'s score the token AT ``p``), the
+    per-row sampler's choice ``x0`` and its confidence ``c`` there, and
+    the selection — of a row's masked positions the ``unmask_k`` of
+    highest ``c`` (ties to the lower position) and every one with ``c >
+    unmask_tau`` — all on the device; ``block_tokens`` / ``block_flags`` /
+    ``block_conf`` ride the one output vector."""
     from ..models import hybrid as hy
     c = cfg
+    block = c.diffusion_block
+    wide = bool(spec_k or block)            # narrow slots behind the chunks
+    mask_block = block or 1
     layout = StepLayout(c, max_seqs, chunk, prefill_rows, max_pages, spec_k,
                         page_size=page_size)
     t_tokens, n_rows = layout.n_tokens, layout.n_rows
     cdt = jnp.bfloat16 if c.dtype == "bfloat16" else jnp.float32
     hd, nh, nkv = c.head_dim, c.num_heads, c.kv_heads
     slots = _chunk_slots(max_seqs, prefill_rows, chunk, 0)
-    regions = _regions(max_seqs, prefill_rows, chunk, spec_k)
+    regions = _regions(max_seqs, prefill_rows, chunk, spec_k, block)
     write_regions = tuple((row, n, width) for _, row, _, n, width in regions)
-    v0 = max_seqs + prefill_rows            # the first verify row
+    v0 = max_seqs + prefill_rows            # the first verify / block row
     _, _, vtok, _, vk = regions[-1]         # ... its first token, its width
     attn_of = {i: a for a, i in enumerate(c.paged_layers)}
     by_layer_kv = c.page_layers is not None and c.page_layers[0].kv_heads
@@ -940,12 +1070,13 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
         """``f(tokens) -> per-token`` over the decode slots and, under
         ``lax.cond``, each chunk slot; both sides are arrays ``[n, ...]``
         or trees of them.  An idle chunk slot gives zeros and pays
-        nothing; the verify slots (a few dozen tokens) run WITH the
-        decode slots, as one group: one pass over ``f``'s weights, not
-        two (a self-drafting step's rows are nearly all in the verify
-        slots and its decode slots idle)."""
+        nothing; the verify or block slots (a few dozen to a few hundred
+        tokens) run WITH the decode slots, as one group: one pass over
+        ``f``'s weights, not two (a self-drafting step's rows are nearly
+        all in the verify slots and its decode slots idle; a block-wise
+        model's decode slots always are)."""
         tmap = jax.tree_util.tree_map
-        if spec_k:
+        if wide:
             both = f(tmap(lambda a: jnp.concatenate(
                 [a[:max_seqs], a[vtok:]], axis=0), h))
             outs = [tmap(lambda a: a[:max_seqs], both)]
@@ -958,7 +1089,7 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
                 q_lens[row] > 0, f,
                 lambda s, z=zero: tmap(
                     lambda a: jnp.zeros(a.shape, a.dtype), z), sl))
-        if spec_k:
+        if wide:
             outs.append(tmap(lambda a: a[max_seqs:], both))
         return tmap(lambda *a: jnp.concatenate(a, axis=0), *outs)
 
@@ -975,7 +1106,7 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
         tok_row = jnp.concatenate(
             [jnp.arange(max_seqs)] +
             [jnp.full((w,), r) for r, _, w in slots] +
-            ([jnp.repeat(jnp.arange(v0, n_rows), vk)] if spec_k else []))
+            ([jnp.repeat(jnp.arange(v0, n_rows), vk)] if wide else []))
         tok_idx = jnp.arange(t_tokens) - cu_q[tok_row]
         live = tok_idx < q_lens[tok_row]
         fresh_row = (ctx_lens - q_lens) == 0
@@ -1077,11 +1208,12 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
                         win, hd ** -0.5).astype(q.dtype)
             elif use_kernel:
                 kernel = functools.partial(
-                    ragged_paged_attention_pallas, k_pages=kp, v_pages=vp)
+                    ragged_paged_attention_pallas, k_pages=kp, v_pages=vp,
+                    mask_block=mask_block)
             else:
                 def kernel(name, **kw):
                     return ragged_paged_attention_reference(
-                        k_pages=kp, v_pages=vp, **kw)
+                        k_pages=kp, v_pages=vp, mask_block=mask_block, **kw)
 
             def call(**kw):
                 # a region with no live row (the decode slots, when every
@@ -1094,7 +1226,8 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
                     call, "ragged_paged_attention", q, q_lens, cu_q,
                     fields["win_tables"] if win else page_tables, ctx_lens,
                     max_seqs, prefill_rows, chunk, spec_k,
-                    kv_base=fields["win_base"] if win else None)
+                    kv_base=fields["win_base"] if win else None,
+                    block=block)
                 attn = attn.reshape(t_tokens, nh * hd).astype(h.dtype)
             with phase("attn_proj"):
                 return by_region(
@@ -1308,6 +1441,20 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
         x_last = x
         with phase("norm"):
             x = _norm_apply(c, p("ln_f.weight"), None, x)
+        stack = lambda ls: jnp.stack(ls) if ls else \
+            jnp.zeros((0, max(c.held_experts, 1)), jnp.int32)  # noqa: E731
+        if block:
+            # no row has a next token: the block head is the step's head
+            with phase("block_head"):
+                heads = _block_head(
+                    c, p, x[vtok:], tokens[vtok:], token_pos[vtok:],
+                    live[vtok:], tuple(a[v0:] for a in (
+                        temps, top_ps, top_ks, seeds)),
+                    fields["unmask_k"][v0:], fields["unmask_tau"][v0:])
+            return (layout.join(next_tokens=jnp.zeros((n_rows,), jnp.int32),
+                                moe_load=stack(loads), **heads),
+                    tuple(new_k), tuple(new_v), tuple(new_conv),
+                    tuple(new_ssm))
         last = jnp.clip(cu_q[:n_rows] + jnp.maximum(q_lens, 1) - 1, 0,
                         t_tokens - 1)
         with phase("lm_head_ce"):
@@ -1315,8 +1462,6 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
         with phase("sample"):
             next_tokens = sample_rows(logits, temps, top_ps, top_ks, seeds,
                                       ctx_lens)
-        stack = lambda ls: jnp.stack(ls) if ls else \
-            jnp.zeros((0, max(c.held_experts, 1)), jnp.int32)  # noqa: E731
         outs = dict(next_tokens=next_tokens, moe_load=stack(loads))
         if spec_k:
             next_tokens, accepted, draft_next = _verify_rows(

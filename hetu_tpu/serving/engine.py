@@ -42,6 +42,19 @@ returns the longest-accepted-prefix length plus a bonus token per row
 bit-for-bit, ``host_logit_fetches`` still 0, and the draft's three
 fixed-shape programs join the compile-count guard.
 
+Block-wise generation (DESIGN.md §29, a model with
+``cfg.diffusion_block``): a generating request's tip is its OPEN BLOCK
+(``Request.block``: known ids and the mask id), the scheduler gives it a
+block slot every step, the step's block head says which masked positions
+the pass unmasks and with what (``DenoiseRule``: two numbers a row cover
+the three published rules), and ``_commit_block`` applies them; a block
+with no mask left goes in once more as its COMMIT pass, whose K/V stands,
+and its tokens are emitted in position order — 0 to B tokens a row a step.
+Counters ``block_row_passes`` / ``block_commit_passes`` /
+``block_tokens_unmasked`` / ``blocks_committed`` / ``block_positions`` /
+``block_positions_masked`` / ``kv_tokens_provisional``
+(``serving/step_account.py``).
+
 Prefix reuse (``serving/prefix_cache.py``, on by default): finished
 requests' fully-written pages enter a chained-hash index; a new request
 whose page-aligned token prefix is cached attaches those pages
@@ -127,7 +140,8 @@ from .decode import StepLayout, build_unified_step_fn
 from .kv_pool import (TRASH_PAGE, PagedKVPool, StateSlotStore,
                       protocol_seq, window_table_pages)
 from .prefix_cache import PrefixCache
-from .request import FINISHED, RUNNING, Request, RequestQueue
+from .request import (FINISHED, RUNNING, DenoiseRule, Request,
+                      RequestQueue)
 from .scheduler import Scheduler
 from .spec import SpecConfig, SpecDecoder
 from .step_account import COUNTERS, GAUGES, StepAccount
@@ -161,7 +175,8 @@ class Engine:
                  spec: Optional[SpecConfig] = None,
                  page_quant: Optional[str] = None,
                  host_tier=None, window_pages: Optional[int] = None,
-                 early_fetch: bool = False):
+                 early_fetch: bool = False,
+                 denoise: Optional[DenoiseRule] = None):
         self.cfg = cfg
         self.name = name
         # start the step's one device-to-host copy when the call is
@@ -232,6 +247,34 @@ class Engine:
                     "speculative decoding is not built for a stack with "
                     "recurrent (mamba2) layers: a rejected draft cannot "
                     "be rolled back out of the state")
+        # a block-wise model (cfg.diffusion_block, DESIGN.md §29): a
+        # generating request's step is its open block, denoised in passes
+        # under ``denoise`` (the engine's one rule) and then committed.
+        # What is not built with it is refused here, by name
+        self.block = cfg.diffusion_block
+        if denoise is not None and not self.block:
+            raise ValueError("denoise= describes block-wise generation "
+                             "(cfg.diffusion_block); this model emits one "
+                             "position after another")
+        if self.block:
+            self.denoise = (denoise or DenoiseRule()).check(self.block)
+            if spec is not None:
+                raise ValueError(
+                    "speculative decoding is not built with block-wise "
+                    "generation (diffusion_block): a block row's step is "
+                    "its open block, there is no next token to draft")
+            if mesh is not None:
+                raise ValueError(
+                    "a mesh is not built with block-wise generation "
+                    "(diffusion_block): the block head's selection and the "
+                    "block-wise mask are built for one device's pool")
+            if prefix_cache and page_size % self.block:
+                raise ValueError(
+                    f"prefix_cache=True with block-wise generation needs "
+                    f"page_size % diffusion_block == 0 (page {page_size}, "
+                    f"block {self.block}): a cached page's K/V then "
+                    "depends on the tokens up to its own end, which its "
+                    "hash covers — pass prefix_cache=False")
         self.self_draft = spec is not None and bool(spec.self_draft)
         if spec is not None and self.hybrid and not self.self_draft:
             raise ValueError(
@@ -413,6 +456,7 @@ class Engine:
                                         self.max_model_len, self.spec_k)
             self.scheduler.verify_slots = self.scheduler.max_batch
             self.scheduler.spec_width = self.spec_k + 1
+        self.scheduler.block = self.block
         # THE executable: fixed (max_seqs, chunk, prefill_rows) shapes,
         # compiled exactly once — no bucket grid, no per-request prefill.
         # ``step_fn`` lets N identically-shaped engines (cluster
@@ -458,6 +502,10 @@ class Engine:
             raise ValueError("empty prompt")
         if max_new_tokens < 1:
             raise ValueError("max_new_tokens must be >= 1")
+        if self.block and self.cfg.mask_token_id in prompt:
+            raise ValueError(
+                f"the mask id {self.cfg.mask_token_id} in a prompt: a "
+                "position fed as it reads as not yet known")
         total = len(prompt) + int(max_new_tokens)
         if total > self.max_model_len:
             raise ValueError(
@@ -529,6 +577,9 @@ class Engine:
         if self.window:
             raise ValueError("adoption is not built for window layers: a "
                              "handoff carries no window pages")
+        if self.block:
+            raise ValueError("adoption is not built with block-wise "
+                             "generation: a handoff carries no open block")
         total = len(prompt) + int(max_new_tokens)
         if total > self.max_model_len:
             raise ValueError(
@@ -951,6 +1002,7 @@ class Engine:
             req.shared_pages = 0
             req.cached_tokens = 0
             req.spec_drafts = []
+            req.block, req.block_pass = None, 0
             req.pos = 0
             req.state = FINISHED          # terminal, but never collected
         self.queue.clear()
@@ -1035,9 +1087,19 @@ class Engine:
                 f["state_slots"][row] = req.state_slot
             start = int(lay.cu_q[row])
             pos = np.arange(req.pos, req.pos + qlen)
-            seq = req.tokens if not (row >= vbase and req.spec_drafts) \
-                else req.tokens + req.spec_drafts
-            tokens[start:start + qlen] = seq[req.pos:req.pos + qlen]
+            if self.block and row >= vbase:
+                # a generating row: its open block, and this pass's rule
+                # (a block with no mask left is committed: nothing to pick)
+                tokens[start:start + qlen] = req.block
+                if self.cfg.mask_token_id in req.block:
+                    f["unmask_k"][row], f["unmask_tau"][row] = \
+                        self.denoise.unmask(self.block, req.block_pass)
+                else:
+                    f["unmask_tau"][row] = 2.0
+            else:
+                seq = req.tokens if not (row >= vbase and req.spec_drafts) \
+                    else req.tokens + req.spec_drafts
+                tokens[start:start + qlen] = seq[req.pos:req.pos + qlen]
             token_pos[start:start + qlen] = pos
             pages = self._page_array(req)
             token_page[start:start + qlen] = pages[pos // ps]
@@ -1092,6 +1154,12 @@ class Engine:
                 # this row commits a token the drafts never saw, so
                 # they are stale and dropped before the step
                 req.spec_drafts = []
+            if self.block and row >= vbase and req.block is None:
+                # the prompt's whole blocks are in: open the next block with
+                # what is left of the tokens, the rest not yet known
+                known = req.tokens[req.pos:]
+                req.block = known + [self.cfg.mask_token_id] * (
+                    self.block - len(known))
         tr = self.tracer
         traced = tr.enabled
         tp = self._now() if traced else 0.0
@@ -1186,7 +1254,16 @@ class Engine:
             self.counters["decode_steps"].inc()
         self.counters["prefill_chunks"].inc(n_chunk)
         produced = 0
+        if self.block:
+            # plain lists once a step, not an array scalar a position
+            blocks = (out["block_tokens"].tolist(),
+                      out["block_flags"].tolist(),
+                      out["block_conf"].view(np.float32).tolist()
+                      if self.tap is not None else None)
         for req, qlen, row in rows:
+            if self.block and row >= vbase:
+                produced += self._commit_block(req, blocks, row - vbase, dt)
+                continue
             pre = max(0, min(qlen, req.prompt_len - req.pos))
             if pre:
                 self.counters["prefill_tokens"].inc(pre)
@@ -1202,8 +1279,8 @@ class Engine:
                     req, int(accs[row]), int(toks[row]), t0, dt)
             else:
                 req.pos += qlen
-                if req.pos != len(req.tokens):
-                    continue
+                if self.block or req.pos != len(req.tokens):
+                    continue       # (a block-wise model's chunk emits nothing)
                 self._emit(req, int(toks[row]))  # the row reached its tip:
                 produced += 1                    # commit the sample
                 req.resuming = False
@@ -1233,6 +1310,56 @@ class Engine:
                 now - (req.last_token_time or now))
             self.histograms["tpot"].observe(dt)
         req.last_token_time = now
+
+    def _commit_block(self, req: Request, blocks: Tuple[list, list, Any],
+                      slot: int, dt: float) -> int:
+        """A block row's outcome.  A DENOISE pass (the block went in with
+        masks): the positions the step's selection flagged take its
+        tokens; nothing of the pass's K/V is kept — the next pass
+        overwrites it, the context has not moved.  A COMMIT pass (no mask
+        went in): its K/V stands, the block's tokens that the request did
+        not bring itself are emitted in position order (those past
+        ``max_new_tokens`` or behind an end-of-sequence token are dropped
+        and the request ends), the context moves by the block and the
+        next block opens at the request's next step.  ``blocks``: the
+        step's ``block_tokens`` / ``block_flags`` / ``block_conf`` as lists
+        (the last only while the analysis tap is on).  Returns the tokens
+        emitted."""
+        b, mask = self.block, self.cfg.mask_token_id
+        x = req.block
+        toks, flags = blocks[0][slot], blocks[1][slot]
+        masked = [j for j in range(b) if x[j] == mask]
+        picked = [j for j in masked if flags >> j & 1]
+        if self.tap is not None:
+            conf = blocks[2][slot]
+            req.denoise_log.append((
+                req.pos, tuple(x), tuple(picked),
+                tuple(toks[j] for j in picked),
+                tuple(conf[j] for j in masked)))
+        if masked:
+            for j in picked:
+                x[j] = toks[j]
+            req.block_pass += 1
+            return 0
+        known = len(req.tokens) - req.pos      # what the request brought
+        self.counters["prefill_tokens"].inc(
+            max(0, min(b, req.prompt_len - req.pos)))
+        emitted = 0
+        for tok in x[known:]:
+            if req.done:
+                break
+            self._emit(req, tok)
+            emitted += 1
+            self._observe_token(req, False, dt)
+        if known + emitted == b:
+            # (a block cut short stays out of ``pos``: its K/V saw tokens
+            # the request does not hold, and no page with it is cached)
+            req.pos += b
+        req.block, req.block_pass = None, 0
+        req.resuming = False
+        self.counters["blocks_committed"].inc()
+        self._maybe_finish(req)
+        return emitted
 
     def _commit_verify(self, req: Request, accepted: int,
                        bonus: int, t0: float, dt: float) -> int:

@@ -12,6 +12,46 @@ RUNNING = "running"
 FINISHED = "finished"
 
 
+UNMASK_RULES = ("low_confidence_dynamic", "low_confidence_static",
+                "sequential")
+
+
+@dataclass(frozen=True)
+class DenoiseRule:
+    """How a block-wise model's open block is denoised (the ENGINE'S
+    choice, ``Engine(denoise=)``; the block length and the mask id are the
+    model's, ``GPTConfig.diffusion_block`` / ``mask_token_id``): ``steps``
+    passes would unmask a whole block of B masks by the family's schedule
+    — ``B // steps`` positions a pass, the first ``B % steps`` passes one
+    more — under one of the three published rules.  The defaults are the
+    family's (``generate.py``)."""
+    steps: int = 4
+    rule: str = "low_confidence_dynamic"
+    tau: float = 0.9
+
+    def check(self, block: int) -> "DenoiseRule":
+        if self.rule not in UNMASK_RULES:
+            raise ValueError(f"unknown unmask rule {self.rule!r}; one of "
+                             f"{UNMASK_RULES}")
+        if not 1 <= self.steps <= block:
+            raise ValueError(
+                f"denoising steps {self.steps} must lie in 1..{block} (the "
+                "block length): a pass unmasks at least one position")
+        return self
+
+    def unmask(self, block: int, pass_index: int):
+        """``(unmask_k, unmask_tau)`` of denoise pass ``pass_index`` of a
+        block, the two numbers a row the step's one selection reads: of
+        the masked positions the ``|k|`` first by rank — of confidence,
+        or, ``k < 0``, of position (the sequential rule) — and every one
+        whose confidence passes ``tau`` (2.0: none, the static rules)."""
+        base, more = divmod(block, self.steps)
+        k = base + (pass_index < more)
+        if self.rule == "sequential":
+            return -k, 2.0
+        return k, self.tau if self.rule == "low_confidence_dynamic" else 2.0
+
+
 @dataclass
 class Request:
     """One generation request flowing through the engine.
@@ -65,6 +105,20 @@ class Request:
     # row that gets there is a prefill row whatever its width, and no draft
     # can have been staged for it
     resuming: bool = False
+    # block-wise generation (``GPTConfig.diffusion_block``, DESIGN.md §29):
+    # the request's tip is its OPEN BLOCK — the B ids at positions ``pos ..
+    # pos + B`` (``pos`` a multiple of B: the committed K/V), the mask id
+    # where a position is not yet known — and the denoise passes it has
+    # had.  None until the prompt's whole blocks are prefilled; ``tokens``
+    # holds the prompt and the COMMITTED blocks' tokens only, so a
+    # preemption loses the open block and nothing else
+    block: Optional[List[int]] = None
+    block_pass: int = 0
+    # every pass of every block while the engine's analysis tap is on:
+    # (the block's first position, the state going in, the positions the
+    # pass unmasked, their tokens, the served confidences of the positions
+    # masked going in); a commit pass unmasks nothing
+    denoise_log: List[tuple] = field(default_factory=list, repr=False)
     # the recurrent-state slot of a hybrid stack (kv_pool.StateSlotStore),
     # held from admission to finish / preemption
     state_slot: Optional[int] = None

@@ -51,6 +51,10 @@ class Scheduler:
         # prompt prefills for chunk slots
         self.verify_slots = 0
         self.spec_width = 0
+        # a block-wise model (set by the engine from
+        # ``cfg.diffusion_block``): the narrow slots are BLOCK slots, one
+        # per sequence slot, and a generating request rides one every step
+        self.block = 0
         # optional serving.prefix_cache.PrefixCache: admission charges
         # only the UNCACHED suffix against the page budget (and counts
         # refcount-0 cached pages as reclaimable), preemption releases
@@ -61,7 +65,8 @@ class Scheduler:
     def token_budget(self) -> int:
         """Tokens one packed step can carry (the executable's T)."""
         return self.max_batch + self.prefill_rows * self.chunk \
-            + self.verify_slots * self.spec_width
+            + self.verify_slots * self.spec_width \
+            + self.max_batch * self.block
 
     # -- admission -----------------------------------------------------------
 
@@ -109,7 +114,7 @@ class Scheduler:
             # lookup condition exactly (fresh pos-0 requests only):
             # charging a cached page the start path won't attach would
             # wedge admission the same way ignoring owned pages did
-            need = self.pool.pages_for(len(req.tokens) + 1) \
+            need = self.pool.pages_for(len(req.tokens) + max(1, self.block)) \
                 - len(req.pages)
             new_pins = []
             if self.cache is not None and req.pos == 0 and not req.pages:
@@ -162,6 +167,8 @@ class Scheduler:
         ride this step."""
         live = sorted((r for r in running if r.state == RUNNING),
                       key=lambda r: (r.rank, r.arrival_time, r.req_id))
+        if self.block:
+            return self._pack_blocks(live)
         rows: List[Tuple[Request, int, int]] = []
         verified = set()
         vrow = 0
@@ -190,6 +197,31 @@ class Scheduler:
                 chunk_row += 1
         return rows
 
+    def _pack_blocks(self, live: List[Request]
+                     ) -> List[Tuple[Request, int, int]]:
+        """The rows of a block-wise model's step.  A request whose
+        prompt's WHOLE blocks are not all prefilled yet takes a chunk slot
+        (``q_len`` up to the last whole block, at most ``chunk``, which is
+        a multiple of the block length: a chunk ends where a block ends);
+        every other request is GENERATING and rides a block slot with its
+        open block, ``q_len`` = the block length, every step — a denoise
+        pass or the block's commit pass, the engine says which.  No row is
+        ever one token wide: the decode slots stay idle."""
+        b = self.block
+        rows: List[Tuple[Request, int, int]] = []
+        vbase = self.max_batch + self.prefill_rows
+        brow = chunk_row = 0
+        for r in live:
+            whole = len(r.tokens) // b * b - r.pos
+            if whole <= 0:
+                rows.append((r, b, vbase + brow))
+                brow += 1
+            elif chunk_row < self.prefill_rows:
+                rows.append((r, min(whole, self.chunk),
+                             self.max_batch + chunk_row))
+                chunk_row += 1
+        return rows
+
     def slot_mix(self, rows: List[Tuple[Request, int, int]]
                  ) -> dict:
         """The step's packing decision as a flat dict — the trace
@@ -200,9 +232,12 @@ class Scheduler:
         vbase = self.max_batch + self.prefill_rows
         n_decode = sum(1 for _, _, row in rows if row < self.max_batch)
         n_verify = sum(1 for _, _, row in rows if row >= vbase)
+        n_block = n_verify if self.block else 0
+        n_verify -= n_block
         return {"decode_slots": n_decode,
-                "chunk_slots": len(rows) - n_decode - n_verify,
+                "chunk_slots": len(rows) - n_decode - n_verify - n_block,
                 "verify_slots": n_verify,
+                "block_slots": n_block,
                 "spec_tokens": int(sum(len(r.spec_drafts)
                                        for r, _, row in rows
                                        if row >= vbase)),
@@ -224,7 +259,9 @@ class Scheduler:
         allocates here — one page per decode step, or up to
         ``ceil((1 + staged drafts) / page_size)`` for a speculative
         verify row (its burst writes ``pos .. pos + spec_len``, which
-        may cross a page boundary).  A page squeeze sheds the
+        may cross a page boundary), or the pages under a block-wise
+        model's open block (a block ahead of the committed K/V).  A page
+        squeeze sheds the
         requester's staged drafts FIRST — degrading a burst to a plain
         decode is free, while preempting any request costs its whole
         prefill — and only then falls back to eviction."""
@@ -235,7 +272,9 @@ class Scheduler:
             if req in evicted:
                 continue
             while True:
-                need_tokens = req.pos + 1 + len(req.spec_drafts)
+                # (a block-wise model's pass writes its whole open block)
+                need_tokens = req.pos + max(1, self.block) \
+                    + len(req.spec_drafts)
                 have = len(req.pages) * self.pool.page_size
                 if have >= need_tokens:
                     if self._slide_window(req):
@@ -321,6 +360,7 @@ class Scheduler:
         req.shared_pages = 0
         req.cached_tokens = 0
         req.spec_drafts = []
+        req.block, req.block_pass = None, 0
         req.pos = 0
         req.resuming = True
         req.state = WAITING

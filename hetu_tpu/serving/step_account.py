@@ -39,7 +39,15 @@ COUNTERS = (
     "index_pairs_scored", "index_positions_selected",
     "window_pages_held", "full_pages_held",
     # self-drafting: rows one token from emitting / those with a draft
-    "decode_rows", "spec_rows")
+    "decode_rows", "spec_rows",
+    # block-wise generation: block rows computed (a pass of a block each) /
+    # those that were commit passes; positions the denoise passes unmasked;
+    # blocks committed (the engine's commit, equal to the commit passes);
+    # positions computed in block rows / those masked going in; K/V rows
+    # written by denoise passes (overwritten, never read by a later step)
+    "block_row_passes", "block_commit_passes", "block_tokens_unmasked",
+    "blocks_committed", "block_positions", "block_positions_masked",
+    "kv_tokens_provisional")
 GAUGES = (
     # state slots held; the last step's busiest held expert over the mean
     "state_slots_in_use", "moe_expert_load_peak")
@@ -58,6 +66,17 @@ def _pairs(qs, his) -> int:
     """(query, key) pairs inside the causal mask, summed over the rows: a
     row's ``q`` queries, the last at position ``hi``."""
     return sum(q * hi - q * (q - 1) // 2 for q, hi in zip(qs, his))
+
+
+def _block_pairs(st: "_Rows", b: int) -> int:
+    """(query, key) pairs inside the block-wise mask: the query at ``p``
+    sees the row's keys up to the end of its own block of ``b``."""
+    total = 0
+    for lo, hi in zip(st.pos, st.ctx):
+        for start in range(lo // b * b, hi, b):
+            total += (min(start + b, hi) - max(start, lo)) * \
+                min(start + b, hi)
+    return total
 
 
 def _expert_load(load, prefix: str):
@@ -104,9 +123,10 @@ class StepAccount:
         geo = cfg.mixer_geometry or {}
         self.index_topk = geo["dsa"].index_topk if "dsa" in geo else 0
         self.window = cfg.window_tokens
+        self.block, self.mask_id = cfg.diffusion_block, cfg.mask_token_id
         max_pages = layout.fields["page_tables"][1][1]
         regions = _regions(scheduler.max_batch, scheduler.prefill_rows,
-                           scheduler.chunk, spec_k)
+                           scheduler.chunk, spec_k, self.block)
 
         def by_slot(rule):
             # by row slot, each region's own: what the kernel wrapper calls
@@ -136,7 +156,8 @@ class StepAccount:
             (full is not None, self._kv),
             (cfg.layers_of("mla"), self._latent),
             (cfg.page_layers is not None, self._index),
-            ("draft" in layout.outs, self._self_draft)) if on)
+            ("draft" in layout.outs, self._self_draft),
+            (self.block, self._block)) if on)
 
     def __call__(self, rows, fields, out, traced: bool) -> Dict[str, Any]:
         if not self.parts:
@@ -219,6 +240,34 @@ class StepAccount:
             window_tokens_distinct=pool.page_size * len(
                 {pg for r in st.reqs for pg in r.win_pages}),
             window_pairs=_capped(st, self.window))
+
+    def _block(self, st, out, traced):
+        """Block rows are the rows in the narrow slots; a row whose block
+        went in with no mask is a commit pass, every other a denoise pass,
+        whose K/V rows are provisional.  ``attn_pairs``: pairs inside the
+        block-wise mask, every row of the step (the prompt's chunks run
+        under it too)."""
+        b = self.block
+        rows = [(r, row) for r, row in zip(st.reqs, st.row)
+                if row >= self.vbase]
+        masked = [sum(t == self.mask_id for t in r.block) for r, _ in rows]
+        commits = sum(m == 0 for m in masked)
+        flags = out["block_flags"]
+        unmasked = sum(bin(int(flags[row - self.vbase])).count("1")
+                       for (_, row), m in zip(rows, masked) if m)
+        c = self.counters
+        c["block_row_passes"].inc(len(rows))
+        c["block_commit_passes"].inc(commits)
+        c["block_tokens_unmasked"].inc(unmasked)
+        c["block_positions"].inc(b * len(rows))
+        c["block_positions_masked"].inc(sum(masked))
+        c["kv_tokens_provisional"].inc(b * (len(rows) - commits))
+        if not traced:
+            return None
+        return dict(block_rows=len(rows), block_commit_rows=commits,
+                    block_unmasked=unmasked, block_masked=sum(masked),
+                    attn_pairs=_block_pairs(st, b),
+                    kv_pages_distinct=st.distinct_pages)
 
     def _self_draft(self, st, out, traced):
         """``decode_rows``: rows one token from emitting whose request has

@@ -280,9 +280,10 @@ def test_new_layer_metric_names_a_reader_and_a_cell(name):
     # those of them its reader finds something to read in (a drawn
     # configuration's own metrics list its cell alone)
     own = {"nemotron3s-ep4.serve-chat", "mistral4-ep4.serve-longdoc",
-           "dots3-ep8.serve-longctx", "kexaone-ep8.serve-reason"}
+           "dots3-ep8.serve-longctx", "kexaone-ep8.serve-reason",
+           "sdar30b-pp8.serve-chat"}
     family = {"chat": {"cgpt590m.serve-chat", "nemotron3s-ep4.serve-chat",
-                       "kexaone-ep8.serve-reason"},
+                       "kexaone-ep8.serve-reason", "sdar30b-pp8.serve-chat"},
               "replay": {"cgpt590m.serve-prefix",
                          "mistral4-ep4.serve-longdoc",
                          "dots3-ep8.serve-longctx"},
@@ -296,7 +297,8 @@ def test_new_layer_metric_names_a_reader_and_a_cell(name):
                               "trace_step_edges") \
             and not name.startswith(("moe_", "latent_", "index_",
                                      "window_", "ssm_", "mtp_",
-                                     "kv_page_")):
+                                     "kv_page_", "block_",
+                                     "kv_provisional_")):
         assert family - own <= set(entry["workloads"])
     if spec["reader"] == "trace_idle_by_phase":
         assert spec["args"]["per_span"] in ("engine.step", "g.run")

@@ -17,3 +17,20 @@ _spec.loader.exec_module(_mod)
 bench, config = _mod.bench, _mod.config           # the module's fixtures
 globals().update({k: v for k, v in vars(_mod).items()
                   if k.startswith("test_")})
+
+
+def test_cell_is_listed_where_its_readers_find_something(bench):  # noqa: F811
+    """That test holds its cell to be the benchmark's LAST and its new
+    metrics to list it alone; a later ``model_config`` PR appends a cell,
+    appends its name to lists, and may not edit that file.  So the test is
+    handed the benchmark without the later cells' names, and nothing else
+    cut (ROADMAP B0 (ix) asks the ``benchmark`` issue to loosen the two
+    lines there)."""
+    names = [w["name"] for w in bench["workloads"]]
+    cut = names.index(_mod.CELL) + 1
+    later = set(names[cut:])
+    _mod.test_cell_is_listed_where_its_readers_find_something(dict(
+        bench, workloads=bench["workloads"][:cut],
+        per_layer=[dict(m, workloads=[w for w in m["workloads"]
+                                      if w not in later])
+                   if "workloads" in m else m for m in bench["per_layer"]]))

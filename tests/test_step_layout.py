@@ -518,3 +518,136 @@ def test_the_account_is_what_the_steps_rows_read(stack):
         assert any(counters.values())
     if eng.prefix_cache is not None and stack != "dense":
         assert m["prefix_cache_hits"] >= 1
+
+
+# ---------------------------------------------------------------------------
+# a block-wise model's step (PR 48): the block slots on the verify slots'
+# scaffolding, two more fields in, three more arrays out
+# ---------------------------------------------------------------------------
+
+def _sdar(**kw):
+    """The block-diffusion stack at its own tests' tiny widths."""
+    import test_sdar_serving as sd
+    cfg, state, _ = sd.build()
+    return sd.engine(cfg, state, name="layout_sdar",
+                     time_fn=lambda: 0.0, **kw), sd
+
+
+def test_block_slots_stand_where_the_verify_slots_stand():
+    """The block region is the narrow region behind the chunk slots:
+    ``max_batch`` rows of ``B`` tokens, tagged ``block``, the layout's last;
+    the decode and chunk regions are where every build has them."""
+    from hetu_tpu.serving.decode import _chunk_slots, _regions
+    eng, sd = _sdar()
+    sch, b = eng.scheduler, eng.cfg.diffusion_block
+    regions = _regions(sch.max_batch, sch.prefill_rows, sch.chunk, 0, b)
+    assert [r[0] for r in regions] == ["decode", "chunk", "block"]
+    assert regions[:2] == _regions(sch.max_batch, sch.prefill_rows,
+                                   sch.chunk, 0)
+    # the same rows and tokens as the verify slots of a build with drafts
+    # of B - 1
+    verify = _regions(sch.max_batch, sch.prefill_rows, sch.chunk, b - 1)
+    assert regions[2][1:] == verify[2][1:] and verify[2][0] == "verify"
+    assert _chunk_slots(sch.max_batch, sch.prefill_rows, sch.chunk, 0, b) \
+        == _chunk_slots(sch.max_batch, sch.prefill_rows, sch.chunk, b - 1)
+    lay = eng.layout
+    assert lay.n_rows == 2 * sch.max_batch + sch.prefill_rows
+    assert lay.n_tokens == sch.max_batch * (1 + b) + sch.chunk
+    assert sch.token_budget == lay.n_tokens
+    assert {"unmask_k", "unmask_tau"} <= set(lay.fields)
+    assert "spec_lens" not in lay.fields and "next_tok" not in lay.fields
+    assert list(lay.outs) == ["next_tokens", "moe_load", "block_tokens",
+                              "block_flags", "block_conf"]
+    assert lay.outs["block_tokens"][1] == (sch.max_batch, b) == \
+        lay.outs["block_conf"][1]
+
+
+def test_a_block_step_is_one_buffer_each_way_and_the_rule_rides_it():
+    """One transfer each way a step; ``unmask_k`` / ``unmask_tau`` are the
+    schedule's count for the pass and 2.0 under the static rule, 0 / 2.0 on
+    a commit pass; the float travels by its bit pattern."""
+    eng, sd = _sdar()
+    h = eng.add_request(sd.prompts([9])[0], 6)
+    seen = []
+    real = eng._compiled["unified"]
+
+    def spy(params, packed, *rest):
+        f = eng.layout.views(np.asarray(packed).copy())
+        rows = [r for r in range(eng.layout.n_rows) if f["q_lens"][r]]
+        seen.append([(r, int(f["q_lens"][r]), int(f["unmask_k"][r]),
+                      float(f["unmask_tau"][r])) for r in rows])
+        return real(params, packed, *rest)
+    eng._compiled["unified"] = spy
+    eng.run()
+    c = eng.metrics_summary()
+    assert c["h2d_copies"] == c["d2h_fetches"] == c["step_calls"] == \
+        len(seen)
+    vbase = eng.scheduler.max_batch + eng.scheduler.prefill_rows
+    # 9 tokens: two whole blocks in a chunk, then block 2 opens with one
+    # prompt token: masks 3 -> passes (2, 1) + commit; block 3: (2, 2) + c.
+    assert seen[0] == [(eng.scheduler.max_batch, 8, 0, 0.0)]
+    assert [s[0][1:] for s in seen[1:]] == [
+        (4, 2, 2.0), (4, 2, 2.0), (4, 0, 2.0),
+        (4, 2, 2.0), (4, 2, 2.0), (4, 0, 2.0)]
+    assert all(s[0][0] == vbase for s in seen[1:])
+    assert len(h.out_tokens) == 6
+
+
+@pytest.mark.parametrize("rule,k,tau", [
+    ("low_confidence_static", 1, 2.0), ("sequential", -1, 2.0),
+    ("low_confidence_dynamic", 1, 0.9)])
+def test_the_three_rules_are_two_numbers(rule, k, tau):
+    from hetu_tpu.serving import DenoiseRule
+    r = DenoiseRule(steps=3, rule=rule)
+    assert [r.unmask(4, t) for t in range(3)] == \
+        [(2 * k, tau), (k, tau), (k, tau)]
+
+
+def test_the_block_head_selects_on_the_device():
+    """``_block_head`` alone, on logits built by hand: rank by confidence
+    with ties to the lower position, the sequential rule by position, the
+    threshold whatever the rank, never the mask id, nothing on a commit."""
+    import jax.numpy as jnp
+    from hetu_tpu.serving.decode import _block_head
+    eng, sd = _sdar()
+    cfg, mask = eng.cfg, eng.cfg.mask_token_id
+    v, h = cfg.vocab_size, cfg.hidden_size
+    # a head whose row t is e_t, and hidden states that score one token
+    head = jnp.zeros((v, h)).at[jnp.arange(h), jnp.arange(h)].set(1.0)
+    p = lambda name: head if name == "lm_head.weight" else None  # noqa: E731
+    peak = lambda tok, s: jnp.zeros((h,)).at[tok].set(s)         # noqa: E731
+    x = jnp.stack([peak(3, 9.0), peak(4, 5.0), peak(5, 9.0), peak(6, 7.0),
+                   peak(7, 1.0), peak(8, 2.0), peak(9, 3.0), peak(10, 4.0)])
+    tokens = jnp.asarray([mask, mask, mask, 11, mask, mask, mask, mask])
+    live = jnp.ones((8,), bool)
+    zeros = jnp.zeros((2,))
+    sampling = (zeros, zeros, jnp.zeros((2,), jnp.int32),
+                jnp.zeros((2,), jnp.int32))
+
+    def picks(ks, taus):
+        out = _block_head(cfg, p, x, tokens, jnp.arange(8), live, sampling,
+                          jnp.asarray(ks, jnp.int32),
+                          jnp.asarray(taus, jnp.float32))
+        return (np.asarray(out["block_tokens"]).tolist(),
+                [int(f) for f in np.asarray(out["block_flags"])])
+    toks, flags = picks([1, 2], [2.0, 2.0])
+    # row 0: positions 0 and 2 tie at the top: the lower; 3 is not masked.
+    # row 1: the two most confident are the last two
+    assert flags == [0b0001, 0b1100]
+    assert toks == [[3, 4, 5, 11], [7, 8, 9, 10]]
+    assert picks([-1, -2], [2.0, 2.0])[1] == [0b0001, 0b0011]   # by position
+    conf = np.asarray(_block_head(
+        cfg, p, x, tokens, jnp.arange(8), live, sampling,
+        jnp.asarray([0, 0], jnp.int32), jnp.asarray([2.0, 2.0]))[
+            "block_conf"]).view(np.float32)
+    assert picks([0, 0], [2.0, 2.0])[1] == [0, 0]               # a commit
+    tau = float((conf[0, 1] + conf[0, 0]) / 2)
+    assert picks([1, 1], [tau, 2.0])[1] == [0b0101, 0b1000]     # above tau
+    # the mask id is never the choice, however it scores
+    loud = head.at[mask, 0].set(50.0)
+    out = _block_head(cfg, lambda name: loud if name == "lm_head.weight"
+                      else None, x.at[:, 0].add(0.5), tokens, jnp.arange(8),
+                      live, sampling, jnp.asarray([4, 4], jnp.int32),
+                      jnp.asarray([2.0, 2.0]))
+    assert np.asarray(out["block_tokens"]).tolist() == \
+        [[3, 4, 5, 11], [7, 8, 9, 10]]
