@@ -77,6 +77,8 @@ import numpy as np
 from jax import lax
 
 from ..obs.phases import phase
+from ..ops.index_score import by_blocks as _by_blocks
+from ..ops.index_score import index_score_pages, index_scores  # noqa: F401
 from ..ops.moe_grouped import ACTIVATIONS, grouped_experts
 from ..ops.ssd import causal_conv, ssd_chunk_scan, ssd_decode_slots
 from .generate import norm_eps
@@ -763,7 +765,7 @@ def index_positions(cfg: GPTConfig, params: dict, i: int, u, positions):
     """The positions dsa layer ``i`` selects for the queries at
     ``positions`` of ONE sequence whose normed layer input is ``u`` [T,
     H]: the serving step's own indexer arithmetic (``index_queries`` /
-    ``index_keys`` / ``rotate_index`` / ``index_scores`` /
+    ``index_keys`` / ``rotate_index`` / ``index_score_pages`` /
     ``index_select`` in the model's dtype) on a whole sequence at once,
     for the check of the selection against the plain reference.
     Returns ``[len(positions), k]`` int32, an empty place holding ``T``."""
@@ -785,14 +787,18 @@ def _index_positions(params, u, pos, cos, sin, *, i: int, geo, eps: float):
     q, w = index_queries(params, i, uq, c_q, geo)
     q = rotate_index(q, cos[pos], sin[pos])
     keys = rotate_index(index_keys(params, i, u), cos, sin)
+    # the sequence's keys laid as pages (of the cells' 64 tokens) under an
+    # identity table: the entry point that serves scores them
+    ps, t = 64, u.shape[0]
+    pages = jnp.pad(keys, ((0, -t % ps), (0, 0))).reshape(
+        -1, 1, ps, keys.shape[-1])
+    scores = index_score_pages(q, w, pages, jnp.arange(pages.shape[0]), t)
 
     def one(args):
-        q_b, w_b, pos_b = args
-        sel, valid = index_select(index_scores(q_b, keys, w_b), pos_b,
-                                  geo.index_topk)
-        return jnp.where(valid, sel, u.shape[0])
+        sel, valid = index_select(args[0], args[1], geo.index_topk)
+        return jnp.where(valid, sel, t)
 
-    return _by_blocks(one, (q, w, pos), 32)
+    return _by_blocks(one, (scores, pos), 32)
 
 
 def rotate_halves(x, cos_t, sin_t):
@@ -805,30 +811,36 @@ def rotate_halves(x, cos_t, sin_t):
             rot.astype(F32) * sin_t.reshape(shape)).astype(x.dtype)
 
 
-def index_scores(q, keys, w):
-    """The indexer's score of every (query, cached position) pair,
-    float32: ``sum_j w[t, j] relu(q[t, j] . k[s])``, the products
-    accumulated in float32.  ``q [n, IH, ID]``, ``w [n, IH]`` float32;
-    ``keys`` is ``[S, ID]`` (one row's context, shared by the ``n``
-    queries) or ``[n, S, ID]`` (a context a query)."""
-    eq = "njd,sd->njs" if keys.ndim == 2 else "njd,nsd->njs"
-    s = jnp.einsum(eq, q, keys, preferred_element_type=F32)
-    return jnp.einsum("njs,nj->ns", jax.nn.relu(s), w)
+def _prefix_counts(blocks):
+    """Inclusive running counts along the 128 lanes of ``blocks [n, nb,
+    128]`` (bool), float32: one matmul with a triangle of ones.  0 / 1
+    operands and float32 accumulation: whole numbers up to 128, exact (in
+    bfloat16 too, which is how they are multiplied again)."""
+    lanes = blocks.shape[-1]
+    tri = jnp.arange(lanes)[:, None] <= jnp.arange(lanes)[None, :]
+    return jnp.einsum("nbl,lm->nbm", blocks.astype(jnp.bfloat16),
+                      tri.astype(jnp.bfloat16), preferred_element_type=F32)
 
 
 def index_select(scores, qpos, topk: int):
     """The ``topk`` best positions of each query among ``s <= qpos``,
     EXACT: the set ``lax.top_k`` of the float32 scores gives (a tie at the
-    k-th score to the lower position), found without a sort.  The k-th
-    largest score is searched bit by bit on the scores' ordered integer
-    image (32 counts over the row); the positions above it, and the first
-    ties, are then read out in position order: a slot of the result finds
-    its 128-position block by the blocks' running counts and its lane by
-    the running count inside that block.  Returns ``(positions [n, k]
-    int32 ascending, valid [n, k])``; a query with fewer than ``k``
-    positions behind it reads them all (a full sort of 33,792 scores for
-    each of a chunk's queries was the longest thing in a chunk step:
-    PERF.md, PR 39)."""
+    k-th score to the lower position), found without a sort, a gather or
+    a scan.  The k-th largest score is searched bit by bit on the scores'
+    ordered integer image (32 counts over the row); the positions above
+    it, and the first ties, are then read out in position order: a slot
+    of the result finds its 128-position block by the blocks' running
+    counts and its lane by the running count inside that block.  Every
+    running count inside a block is a product with a triangle of ones and
+    a slot's block is picked by a one-hot product (:func:`_prefix_counts`:
+    whole numbers up to 128, exact on the MXU), because a gather of 2,048
+    rows a query costs the device ~15 ns a ROW and a ``cumsum`` a pass a
+    step of its scan: 1.28 -> 0.40 ms for 32 queries over 33,792
+    positions, 9.2 -> 1.4 ms a 256-token chunk (PERF.md section 6, PR 49;
+    a full sort of the scores was the longest thing in a chunk step before
+    that: PR 39).  Returns ``(positions [n, k] int32 ascending, valid [n,
+    k])``; a query with fewer than ``k`` positions behind it reads them
+    all."""
     n, s = scores.shape
     k = min(topk, s)
     lanes = 128
@@ -847,19 +859,25 @@ def index_select(scores, qpos, topk: int):
         return jnp.where(enough, cand, thr)
 
     thr = lax.fori_loop(0, 32, refine, jnp.zeros((n,), jnp.uint32))[:, None]
-    above, tie = key > thr, (key == thr) & (key > 0)
-    room = k - jnp.sum(above, -1, keepdims=True)
-    member = above | (tie & (jnp.cumsum(tie, -1) <= room))
-    blocks = member.reshape(n, -1, lanes)
-    count = blocks.sum(-1)                                  # [n, nb]
-    end = jnp.cumsum(count, -1)
+    by_block = lambda a: a.reshape(n, -1, lanes)             # noqa: E731
+    above, tie = by_block(key > thr), by_block((key == thr) & (key > 0))
+    room = k - jnp.sum(above, (1, 2))[:, None, None]
+    # a tie's rank among the row's ties: inside its block + the blocks before
+    rank = _prefix_counts(tie)
+    ties = rank[..., -1]
+    rank = rank + (jnp.cumsum(ties, -1) - ties)[..., None]
+    inside = _prefix_counts(above | (tie & (rank <= room)))  # [n, nb, lanes]
+    nb = inside.shape[1]
+    end = jnp.cumsum(inside[..., -1].astype(jnp.int32), -1)  # [n, nb]
     j = jnp.arange(k)
-    blk = jnp.sum(end[:, None, :] <= j[None, :, None], -1)  # [n, k]
-    blk = jnp.minimum(blk, blocks.shape[1] - 1)
-    before = jnp.take_along_axis(end - count, blk, 1)
-    inside = jnp.cumsum(jnp.take_along_axis(
-        blocks, blk[:, :, None], 1).astype(jnp.int32), -1)  # [n, k, lanes]
-    lane = jnp.sum(inside <= (j[None, :] - before)[:, :, None], -1)
+    done = end[:, None, :] <= j[None, :, None]               # [n, k, nb]
+    blk = jnp.minimum(jnp.sum(done, -1), nb - 1)             # [n, k]
+    before = jnp.max(jnp.where(done, end[:, None, :], 0), -1)
+    pick = jnp.arange(nb)[None, None, :] == blk[:, :, None]
+    mine = jnp.einsum("nkb,nbl->nkl", pick.astype(jnp.bfloat16),
+                      inside.astype(jnp.bfloat16),
+                      preferred_element_type=F32)            # [n, k, lanes]
+    lane = jnp.sum(mine <= (j[None, :] - before)[:, :, None].astype(F32), -1)
     valid = j[None, :] < end[:, -1:]
     pos = jnp.where(valid, blk * lanes + lane, 0)
     return pos.astype(jnp.int32), valid
@@ -881,57 +899,37 @@ def selected_attention(q_cat, sel, d_c: int, valid, scale: float):
                       preferred_element_type=F32)
 
 
-def _by_blocks(f, arrays, block: int):
-    """``f`` over blocks of ``block`` leading rows of the arrays of
-    ``arrays`` (one length ``n``), one block live at a time, the results
-    joined: what bounds a step's temporaries by the block and not by the
-    chunk."""
-    n = arrays[0].shape[0]
-    if n <= block:
-        return f(arrays)
-    pad = -n % block
-    cut = lambda a: jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1)  # noqa: E731
-                            ).reshape((-1, block) + a.shape[1:])
-    out = lax.map(f, tuple(cut(a) for a in arrays))
-    return out.reshape((-1,) + out.shape[2:])[:n]
-
-
 def indexed_attention(geo: LatentGeometry, iq, iw, q_cat, qpos, table,
-                      pools, block: int = 32):
+                      pools, block: int = 32, use_kernel=None):
     """A dsa layer's attention for ``n`` query tokens at positions
     ``qpos`` [n]: the indexer scores every cached position of the
     query's context, the ``index_topk`` best are gathered out of the
     pages and the absorbed attention runs over them alone.  ``table`` is
     ONE row's page table ``[maxp]`` (a chunk: the queries share a
-    context, its index keys are gathered once) or ``[n, maxp]`` (decode
-    rows: a context a query).  ``pools`` = the layer's latent stream
-    (``c_kv | k_r | zero lanes``) and its index-key stream ``[P, 1, ps,
-    w]``; ``iq [n, IH, ID]`` / ``iw [n, IH]`` the rotated index queries
-    and head weights, ``q_cat [n, nh, w]`` the absorbed query, its rotary
-    part padded as the page's.  ``block`` queries are live at a time.
-    Returns the latent output ``[n, nh, d_c]`` float32.  The selection
-    stays on the device: nothing of it leaves the step."""
+    context, which reaches to the last of them) or ``[n, maxp]`` (decode
+    rows: a context a query, up to itself).  ``pools`` = the layer's
+    latent stream (``c_kv | k_r | zero lanes``) and its index-key stream
+    ``[P, 1, ps, w]``; ``iq [n, IH, ID]`` / ``iw [n, IH]`` the rotated
+    index queries and head weights, ``q_cat [n, nh, w]`` the absorbed
+    query, its rotary part padded as the page's.  The scores come out of
+    the key pages in one call (``ops/index_score.py``: a Pallas kernel on
+    the TPU, ``use_kernel`` as everywhere); the selection and the read
+    behind it run ``block`` queries at a time.  Returns the latent output
+    ``[n, nh, d_c]`` float32.  The selection stays on the device: nothing
+    of it leaves the step."""
     cp = pools[0].reshape(-1, pools[0].shape[-1])
     ps = pools[0].shape[2]
     shared = table.ndim == 1
-
-    def keys_of(tab):
-        """A context's index keys, gathered a page at a time (a page's
-        rows lie together: one 16 KB copy, not 64 of 256 B)."""
-        k = pools[1][tab]                        # [.., maxp, 1, ps, ID]
-        return k.reshape(tab.shape[:-1] + (-1, k.shape[-1]))
-
-    if shared:
-        with phase("attn_index"):
-            keys = keys_of(table)                               # [S, ID]
+    with phase("attn_index"):
+        scores = index_score_pages(
+            iq, iw, pools[1], table,
+            jnp.max(qpos) + 1 if shared else qpos + 1, use_kernel=use_kernel)
 
     def one(args):
-        iq_b, iw_b, qc_b, qpos_b, *tab = args
+        sc_b, qc_b, qpos_b, *tab = args
         tab = table[None] if shared else tab[0]
         with phase("attn_index"):
-            k = keys if shared else keys_of(tab)
-            pos, valid = index_select(index_scores(iq_b, k, iw_b), qpos_b,
-                                      geo.index_topk)
+            pos, valid = index_select(sc_b, qpos_b, geo.index_topk)
         with phase("attn_sparse"):
             page = jnp.take_along_axis(
                 jnp.broadcast_to(tab, (pos.shape[0], tab.shape[-1])),
@@ -940,7 +938,7 @@ def indexed_attention(geo: LatentGeometry, iq, iw, q_cat, qpos, table,
             return selected_attention(qc_b, cp[slot], geo.latent, valid,
                                       geo.scale)
 
-    return _by_blocks(one, (iq, iw, q_cat, qpos) +
+    return _by_blocks(one, (scores, q_cat, qpos) +
                       (() if shared else (table,)), block)
 
 

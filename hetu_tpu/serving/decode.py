@@ -1369,7 +1369,8 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
                     o_lat = latent_by_region(
                         lambda iq_, iw_, qc, qp, tab, geo=geo, pools=(
                             kp, xp): hy.indexed_attention(
-                                geo, iq_, iw_, qc, qp, tab, pools),
+                                geo, iq_, iw_, qc, qp, tab, pools,
+                                use_kernel=use_kernel),
                         iq, iw, q_cat, token_pos, per_row=(page_tables,))
                 else:
                     (kp,) = write_pages(a, news)

@@ -16,6 +16,7 @@ from typing import Any, Dict
 
 import numpy as np
 
+from ..ops.index_score import index_score_blocking
 from ..ops.moe_grouped import ROW_BLOCK, block_rows
 from ..ops.ragged_paged_attention import (kv_call_blocking,
                                           latent_pages_per_grid_step)
@@ -35,8 +36,11 @@ COUNTERS = (
     # again): (page, kv head) pairs / the blocks the call fetches them in
     "kv_page_heads", "kv_page_blocks",
     # indexed (dsa) and window (swa): pairs the indexer scored; positions
-    # the attention then read; pages in use in the window / the full space
+    # the attention then read; index-key pages the scoring calls walked (a
+    # chunk's once a query block) / their grid steps that ran; pages in use
+    # in the window / the full space
     "index_pairs_scored", "index_positions_selected",
+    "index_key_pages_scored", "index_grid_steps",
     "window_pages_held", "full_pages_held",
     # self-drafting: rows one token from emitting / those with a draft
     "decode_rows", "spec_rows",
@@ -140,6 +144,7 @@ class StepAccount:
                      if cfg.is_hybrid and cfg.stack_pattern[i] == "attention"
                      and not cfg.window_of(i)), None)
         self.kv_block_heads = self.latent_group = None
+        self.index_blocks = self.index_group = None
         if full is not None:
             pages = pool.k_pages[full]
             self.kv_block_heads = by_slot(lambda width, n: kv_call_blocking(
@@ -150,6 +155,17 @@ class StepAccount:
                 lambda width, n: latent_pages_per_grid_step(
                     width, cfg.num_heads, sum(cfg.latent_page_dims),
                     max_pages, (pool.k_pages[0], pool.v_pages[0])))
+        if cfg.layers_of("dsa"):
+            # a row's query blocks in its region's scoring call, and the
+            # page-table slots a grid step of that call walks
+            def walk(width, n):
+                return index_score_blocking(
+                    width if width > 1 else n, geo["dsa"].index_heads,
+                    max_pages, width > 1, pool.v_pages[0])
+
+            self.index_blocks = by_slot(
+                lambda width, n: -(-width // walk(width, n)[0]))
+            self.index_group = by_slot(lambda width, n: walk(width, n)[1])
         self.parts = tuple(part for on, part in (
             (cfg.layers_of("mamba2"), self._state),
             (cfg.layers_of("moe"), self._moe),
@@ -212,16 +228,27 @@ class StepAccount:
 
     def _index(self, st, out, traced):
         """``index_selected``: positions the attention reads, ``index_topk``
-        a query or all it has.  ``index_selected_floor``: DISTINCT ones the
-        host can prove (rows whose page tables start with one page share a
-        document and may select the same positions, so a group counts its
-        largest selection once).  ``window_tokens_distinct``: token slots
+        a query or all it has.  ``index_key_pages`` / ``index_grid_steps``:
+        a row's pages once for each query block of its region's scoring
+        call (``ops/index_score.py``), and the grid steps that walk them:
+        each block's pages over the group, rounded up.
+        ``index_selected_floor``: DISTINCT positions the host can prove
+        (rows whose page tables start with one page share a document and
+        may select the same positions, so a group counts its largest
+        selection once).  ``window_tokens_distinct``: token slots
         of the distinct window pages (rows that resumed at one boundary
         share its tail)."""
         topk, pool = self.index_topk, self.pool
         selected = _capped(st, topk)
         self.counters["index_pairs_scored"].inc(st.pairs)
         self.counters["index_positions_selected"].inc(selected)
+        key_pages = steps = 0
+        for n, row in zip(st.pages, st.row) if topk else ():
+            blocks = self.index_blocks[row]
+            key_pages += blocks * n
+            steps += blocks * -(-n // self.index_group[row])
+        self.counters["index_key_pages_scored"].inc(key_pages)
+        self.counters["index_grid_steps"].inc(steps)
         if pool.window is not None:
             self.counters["window_pages_held"].inc(pool.window.in_use)
         self.counters["full_pages_held"].inc(
@@ -234,6 +261,7 @@ class StepAccount:
             groups[first] = max(groups.get(first, 0), min(c, topk))
         return dict(
             index_pairs=st.pairs, index_selected=selected,
+            index_key_pages=key_pages, index_grid_steps=steps,
             index_selected_floor=sum(groups.values()),
             index_pages_distinct=st.distinct_pages if topk else 0,
             window_pages=sum(len(r.win_pages) for r in st.reqs),

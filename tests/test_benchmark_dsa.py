@@ -23,8 +23,10 @@ def test_cell_is_listed_where_its_readers_find_something(bench):
     """The benchmark-side case pins the cell's nine metrics as the LAST
     nine of ``per_layer``, as they were when it was written; entries
     appended since (which a PR may not put anywhere else, nor edit that
-    file) are not its business."""
-    last = max(i for i, m in enumerate(bench["per_layer"])
-               if m.get("workloads") == [_mod.CELL])
+    file), the cell's own later ones among them
+    (``index_key_pages_per_grid_step.replay``, PR 49), are not its
+    business: the list is cut behind the ninth that names the cell alone."""
+    own = [i for i, m in enumerate(bench["per_layer"])
+           if m.get("workloads") == [_mod.CELL]]
     _mod.test_cell_is_listed_where_its_readers_find_something(
-        dict(bench, per_layer=bench["per_layer"][:last + 1]))
+        dict(bench, per_layer=bench["per_layer"][:own[8] + 1]))

@@ -34,6 +34,7 @@ kvw = importlib.import_module("hetu_tpu.ops.paged_kv_write")
 fa = importlib.import_module("hetu_tpu.ops.pallas.flash_attention")
 mg = importlib.import_module("hetu_tpu.ops.moe_grouped")
 ssd = importlib.import_module("hetu_tpu.ops.ssd")
+ix = importlib.import_module("hetu_tpu.ops.index_score")
 
 I32, BF16, F32 = jnp.int32, jnp.bfloat16, jnp.float32
 # the serving step's ragged batch at GPT-2 124M widths: 16 decode rows +
@@ -136,6 +137,21 @@ def _kernel_cases():
         return ssd.ssd_decode_slots(x, dt, a, b, c, d, store, slots, n,
                                     fresh, interpret=False)
 
+    def index_score(rows, shared):
+        # a full layer's indexer over the indexed / window latent
+        # configuration's step: 64 index heads x 128, 528 slots of 64 a
+        # row; a 256-token chunk scores ONE context 64 queries x 16 slots a
+        # grid step (two float32 tiles of 4096 x 1024), 32 decode rows a
+        # context each, 32 slots a step
+        def run(iq, iw, keys, table, ctx):
+            return ix.index_score_pages_pallas(iq, iw, keys, table, ctx,
+                                               interpret=False)
+        return (run, (
+            _sds((rows, 64, 128), BF16), _sds((rows, 64), F32),
+            _sds((PAGES, 1, PAGE, 128), BF16),
+            _sds((528,) if shared else (rows, 528), I32),
+            _sds(() if shared else (rows,), I32)))
+
     def flash_qkv_grad(x):
         return jax.grad(lambda x: fa.flash_attention_qkv(
             x, 12, causal=True).astype(F32).sum())(x)
@@ -190,6 +206,8 @@ def _kernel_cases():
             _sds((64, 8, 128), F32), _sds((64, 8, 128), F32),
             _sds((128,), F32), _sds((64, 128, 64, 128), F32),
             _sds((64,), I32), _sds((1,), I32), _sds((64,), jnp.bool_))),
+        "index_score_chunk": index_score(CHUNK, True),
+        "index_score_decode": index_score(32, False),
         "moe_grouped_gated_tiled": (grouped_gated, (
             _sds((288, 4096), BF16), _sds((288, 4), I32),
             _sds((288, 4), F32), _sds((288,), jnp.bool_),
@@ -213,14 +231,18 @@ def _kernel_cases():
 # [heads, head_dim] vector spread along the state's lanes and a sum over
 # them back; the K/V call with 16 blocks of K and of V a grid step, a
 # slot's 8 kv heads each (verify: 15 MB of VMEM), and with 4 of 4 heads
-# beside a 256-token chunk's score tiles (35 MB, four heads unrolled)
+# beside a 256-token chunk's score tiles (35 MB, four heads unrolled);
+# the indexer's scoring call: a chunk's two float32 tiles of 4096 x 1024
+# (32 MB) and a head-major sum over whole tiles, a decode row's 64-row
+# operand summed over sublanes into a one-row output block
 AOT_CASES = ("ragged_12kv_x64", "ragged_decode_region",
              "ragged_chunk_region", "ragged_decode_gqa16",
              "ragged_verify_gqa8_x272", "ragged_chunk_gqa8_x272",
              "latent_512_64",
              "latent_nf4", "kv_write_12kv_x128", "kv_write_int8_sidecar",
              "flash_qkv", "moe_grouped_experts", "moe_grouped_gated_tiled",
-             "latent_256_128_chunk_region", "ssd_decode_slots")
+             "latent_256_128_chunk_region", "ssd_decode_slots",
+             "index_score_chunk", "index_score_decode")
 
 
 @pytest.fixture
@@ -237,6 +259,48 @@ def test_kernels_lower_for_tpu(name, kernels_not_interpreted):
     fn, args = _kernel_cases()[name]
     exp = jax.export.export(jax.jit(fn), platforms=["tpu"])(*args)
     assert "tpu_custom_call" in exp.mlir_module()
+
+
+@pytest.mark.parametrize("rows,shared", [(CHUNK, True), (32, False)])
+def test_the_indexed_layer_lowers_with_no_score_tile_and_no_key_copy(
+        rows, shared, monkeypatch):
+    """``hy.indexed_attention`` lowered for the TPU at the indexed / window
+    latent configuration's shapes (128 heads over a 512 | 64 latent padded
+    to 640, 64 index heads x 128, top 2,048 of 528 slots x 64): the scores
+    come out of ONE Mosaic call, and the module's widest float32 array
+    over the 33,792 positions is the scores themselves, a row a query: no
+    ``[32, 64, 33792]`` tile, and no gathered ``[33792, 128]`` copy of a
+    context's index keys (the XLA arithmetic has both: the control)."""
+    import re
+    from hetu_tpu.models import hybrid as hy
+    monkeypatch.setattr(ix, "on_tpu", lambda: True)
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "dots3-ep8.json")) as f:
+        geo = hy.dots3_config(json.load(f)).geometry("dsa")
+    args = (_sds((rows, 64, 128), BF16), _sds((rows, 64), F32),
+            _sds((rows, geo.heads, 640), F32), _sds((rows,), I32),
+            _sds((528,) if shared else (rows, 528), I32),
+            _sds((PAGES, 1, PAGE, 640), BF16),
+            _sds((PAGES, 1, PAGE, 128), BF16))
+
+    def lowered(use_kernel):
+        def run(iq, iw, qc, qpos, table, cp, xp):
+            return hy.indexed_attention(geo, iq, iw, qc, qpos, table,
+                                        (cp, xp), use_kernel=use_kernel)
+        return jax.export.export(jax.jit(run), platforms=["tpu"])(
+            *args).mlir_module()
+
+    def widest(text):
+        # most float32 rows of 33,792 positions in one array
+        return max(int(np.prod([int(d) for d in m.split("x")]))
+                   for m in re.findall(r"tensor<([\dx]+)x33792xf32>", text))
+
+    copy = re.compile(r"tensor<(\d+x)?33792x128xbf16>")
+    text = lowered(True)
+    assert text.count("tpu_custom_call") == 1
+    assert widest(text) == rows and not copy.search(text)
+    xla = lowered(False)
+    assert widest(xla) == 32 * 64 and copy.search(xla)
 
 
 _AOT_SCRIPT = r"""

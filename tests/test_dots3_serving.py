@@ -474,7 +474,8 @@ def test_the_programs_selection_is_the_references_in_float32():
 
 
 @pytest.mark.parametrize("n,s,k", [(5, 96, 12), (4, 300, 64),
-                                   (2, 4224, 2048)])
+                                   (2, 4224, 2048), (3, 128, 128),
+                                   (3, 1000, 2048), (2, 33792, 2048)])
 def test_the_sort_free_selection_is_top_ks_set(n, s, k):
     """``hy.index_select`` against ``lax.top_k`` on scores with many exact
     ties (every 7th equal), zeros of both signs, a query that sees
