@@ -77,6 +77,7 @@ import numpy as np
 from jax import lax
 
 from ..obs.phases import phase
+from ..ops.index_score import INDEX_SELECT_BLOCK
 from ..ops.index_score import by_blocks as _by_blocks
 from ..ops.index_score import index_score_pages, index_scores  # noqa: F401
 from ..ops.moe_grouped import ACTIVATIONS, grouped_experts
@@ -798,7 +799,7 @@ def _index_positions(params, u, pos, cos, sin, *, i: int, geo, eps: float):
         sel, valid = index_select(args[0], args[1], geo.index_topk)
         return jnp.where(valid, sel, t)
 
-    return _by_blocks(one, (scores, pos), 32)
+    return _by_blocks(one, (scores, pos), INDEX_SELECT_BLOCK)
 
 
 def rotate_halves(x, cos_t, sin_t):
@@ -900,7 +901,8 @@ def selected_attention(q_cat, sel, d_c: int, valid, scale: float):
 
 
 def indexed_attention(geo: LatentGeometry, iq, iw, q_cat, qpos, table,
-                      pools, block: int = 32, use_kernel=None):
+                      pools, block: int = INDEX_SELECT_BLOCK,
+                      use_kernel=None, live=None):
     """A dsa layer's attention for ``n`` query tokens at positions
     ``qpos`` [n]: the indexer scores every cached position of the
     query's context, the ``index_topk`` best are gathered out of the
@@ -914,9 +916,14 @@ def indexed_attention(geo: LatentGeometry, iq, iw, q_cat, qpos, table,
     query, its rotary part padded as the page's.  The scores come out of
     the key pages in one call (``ops/index_score.py``: a Pallas kernel on
     the TPU, ``use_kernel`` as everywhere); the selection and the read
-    behind it run ``block`` queries at a time.  Returns the latent output
-    ``[n, nh, d_c]`` float32.  The selection stays on the device: nothing
-    of it leaves the step."""
+    behind it run ``block`` queries at a time.  ``live`` (a chunk only: a
+    traced scalar, the row's ``q_len``) says how many leading queries hold
+    a token: the blocks behind the last of them are neither selected for
+    nor read for and give zeros, which nothing reads (a dead token writes
+    no page and no token is sampled from it); the scoring call scores the
+    slot's rows as before.  Returns the latent output ``[n, nh, d_c]``
+    float32.  The selection stays on the device: nothing of it leaves the
+    step."""
     cp = pools[0].reshape(-1, pools[0].shape[-1])
     ps = pools[0].shape[2]
     shared = table.ndim == 1
@@ -939,7 +946,8 @@ def indexed_attention(geo: LatentGeometry, iq, iw, q_cat, qpos, table,
                                       geo.scale)
 
     return _by_blocks(one, (scores, q_cat, qpos) +
-                      (() if shared else (table,)), block)
+                      (() if shared else (table,)), block,
+                      live if shared else None)
 
 
 def window_attention(geo: LatentGeometry, q_cat, qpos, table, base, pool):

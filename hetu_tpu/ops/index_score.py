@@ -42,6 +42,10 @@ F32 = jnp.float32
 # walks, and the VMEM the group's key buffers and float32 score tiles may
 # take (PERF.md section 6, PR 49, step 0)
 INDEX_QUERY_BLOCK = 64
+# queries of a region whose selection and sparse read are live together
+# (``models/hybrid.py::indexed_attention``; ``serving/step_account.py``
+# counts a chunk slot's blocks by it)
+INDEX_SELECT_BLOCK = 32
 INDEX_GROUP_MAX = 32
 INDEX_GROUP_VMEM = 40 << 20
 
@@ -57,19 +61,30 @@ def index_scores(q, keys, w):
     return jnp.einsum("njs,nj->ns", jax.nn.relu(s), w)
 
 
-def by_blocks(f, arrays, block: int):
+def by_blocks(f, arrays, block: int, live=None):
     """``f`` over blocks of ``block`` leading rows of the arrays of
     ``arrays`` (one length ``n``), one block live at a time, the results
     joined: what bounds a step's temporaries by the block and not by the
-    chunk."""
+    chunk.  ``live`` (a traced scalar: the leading rows that are real)
+    bounds the WORK too, in the one executable: only the blocks that hold
+    one of the first ``live`` rows call ``f``, under a traced trip count
+    over a zeroed result, and the rows behind them read zeros."""
     n = arrays[0].shape[0]
     if n <= block:
         return f(arrays)
     pad = -n % block
     cut = lambda a: jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1)  # noqa: E731
                             ).reshape((-1, block) + a.shape[1:])
-    out = lax.map(f, tuple(cut(a) for a in arrays))
-    return out.reshape((-1,) + out.shape[2:])[:n]
+    blocks = tuple(cut(a) for a in arrays)
+    if live is None:
+        out = lax.map(f, blocks)
+        return out.reshape((-1,) + out.shape[2:])[:n]
+    one = jax.eval_shape(f, tuple(a[0] for a in blocks))
+    return lax.fori_loop(
+        0, (live + block - 1) // block,
+        lambda i, out: lax.dynamic_update_slice_in_dim(
+            out, f(tuple(a[i] for a in blocks)), i * block, 0),
+        jnp.zeros((n + pad,) + one.shape[1:], one.dtype))[:n]
 
 
 def _group_vmem(q_blk: int, heads: int, pages: int, key_pages):

@@ -1366,12 +1366,17 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
                     kp, xp = write_pages(
                         a, news + (ik.astype(cdt)[:, None],), index_of[i])
                     new_v[index_of[i]] = xp
+                    # a chunk slot's selection and read run the blocks up
+                    # to its row's live tokens (a suffix's last chunk is
+                    # part-filled); the decode region's per-row tables
+                    # take no count
                     o_lat = latent_by_region(
-                        lambda iq_, iw_, qc, qp, tab, geo=geo, pools=(
+                        lambda iq_, iw_, qc, qp, tab, ql, geo=geo, pools=(
                             kp, xp): hy.indexed_attention(
                                 geo, iq_, iw_, qc, qp, tab, pools,
-                                use_kernel=use_kernel),
-                        iq, iw, q_cat, token_pos, per_row=(page_tables,))
+                                use_kernel=use_kernel, live=ql),
+                        iq, iw, q_cat, token_pos,
+                        per_row=(page_tables, q_lens))
                 else:
                     (kp,) = write_pages(a, news)
                     with phase("attn_window"):

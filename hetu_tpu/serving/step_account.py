@@ -16,7 +16,7 @@ from typing import Any, Dict
 
 import numpy as np
 
-from ..ops.index_score import index_score_blocking
+from ..ops.index_score import INDEX_SELECT_BLOCK, index_score_blocking
 from ..ops.moe_grouped import ROW_BLOCK, block_rows
 from ..ops.ragged_paged_attention import (kv_call_blocking,
                                           latent_pages_per_grid_step)
@@ -37,10 +37,13 @@ COUNTERS = (
     "kv_page_heads", "kv_page_blocks",
     # indexed (dsa) and window (swa): pairs the indexer scored; positions
     # the attention then read; index-key pages the scoring calls walked (a
-    # chunk's once a query block) / their grid steps that ran; pages in use
-    # in the window / the full space
+    # chunk's once a query block) / their grid steps that ran; blocks of
+    # the selection and the sparse read that hold a live query of a chunk
+    # row, and so run / that the row's padded slot holds; pages in use in
+    # the window / the full space
     "index_pairs_scored", "index_positions_selected",
     "index_key_pages_scored", "index_grid_steps",
+    "index_chunk_blocks_live", "index_chunk_blocks_padded",
     "window_pages_held", "full_pages_held",
     # self-drafting: rows one token from emitting / those with a draft
     "decode_rows", "spec_rows",
@@ -144,7 +147,7 @@ class StepAccount:
                      if cfg.is_hybrid and cfg.stack_pattern[i] == "attention"
                      and not cfg.window_of(i)), None)
         self.kv_block_heads = self.latent_group = None
-        self.index_blocks = self.index_group = None
+        self.index_blocks = self.index_group = self.select_blocks = None
         if full is not None:
             pages = pool.k_pages[full]
             self.kv_block_heads = by_slot(lambda width, n: kv_call_blocking(
@@ -166,6 +169,11 @@ class StepAccount:
             self.index_blocks = by_slot(
                 lambda width, n: -(-width // walk(width, n)[0]))
             self.index_group = by_slot(lambda width, n: walk(width, n)[1])
+            # blocks of the selection and the read a chunk slot holds (0:
+            # a decode row, whose region is one block whatever is live)
+            self.select_blocks = by_slot(
+                lambda width, n: -(-width // INDEX_SELECT_BLOCK)
+                if width > 1 else 0)
         self.parts = tuple(part for on, part in (
             (cfg.layers_of("mamba2"), self._state),
             (cfg.layers_of("moe"), self._moe),
@@ -232,6 +240,10 @@ class StepAccount:
         a row's pages once for each query block of its region's scoring
         call (``ops/index_score.py``), and the grid steps that walk them:
         each block's pages over the group, rounded up.
+        ``index_chunk_blocks_padded`` / ``_live``: the blocks of the
+        selection and the sparse read a live chunk row's slot holds, and
+        those up to its live tokens, the ones that run
+        (``hy.indexed_attention``).
         ``index_selected_floor``: DISTINCT positions the host can prove
         (rows whose page tables start with one page share a document and
         may select the same positions, so a group counts its largest
@@ -242,13 +254,20 @@ class StepAccount:
         selected = _capped(st, topk)
         self.counters["index_pairs_scored"].inc(st.pairs)
         self.counters["index_positions_selected"].inc(selected)
-        key_pages = steps = 0
-        for n, row in zip(st.pages, st.row) if topk else ():
+        key_pages = steps = live = padded = 0
+        for n, q, row in zip(st.pages, st.q, st.row) if topk else ():
             blocks = self.index_blocks[row]
             key_pages += blocks * n
             steps += blocks * -(-n // self.index_group[row])
+            held = self.select_blocks[row]
+            if held:
+                live += -(-q // INDEX_SELECT_BLOCK)
+                padded += held
         self.counters["index_key_pages_scored"].inc(key_pages)
         self.counters["index_grid_steps"].inc(steps)
+        if padded:
+            self.counters["index_chunk_blocks_live"].inc(live)
+            self.counters["index_chunk_blocks_padded"].inc(padded)
         if pool.window is not None:
             self.counters["window_pages_held"].inc(pool.window.in_use)
         self.counters["full_pages_held"].inc(
@@ -262,6 +281,7 @@ class StepAccount:
         return dict(
             index_pairs=st.pairs, index_selected=selected,
             index_key_pages=key_pages, index_grid_steps=steps,
+            index_chunk_blocks_live=live, index_chunk_blocks_padded=padded,
             index_selected_floor=sum(groups.values()),
             index_pages_distinct=st.distinct_pages if topk else 0,
             window_pages=sum(len(r.win_pages) for r in st.reqs),

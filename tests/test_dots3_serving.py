@@ -643,3 +643,43 @@ def test_what_is_not_built_for_window_layers_is_refused():
         **cfg.mixer_geometry, "swa": cfg.geometry("swa")._replace(
             latent=32)}), name="b").pool.layout_tag
     assert a != b and a[0] == 2
+
+
+# -- (g) a part-filled chunk pays for its live query blocks only --------------
+
+def test_part_filled_chunks_serve_the_tokens_of_every_block_run(monkeypatch):
+    """Chunks of 64 (two blocks of the selection and the read): prompts of
+    70, 33, 64 and 100 tokens end in chunks of 6, 33, 64 and 36 live
+    tokens.  The greedy tokens are those of the same engine whose dsa mixer
+    never hears of the live count and runs every block (the parent's), and
+    the account counts the live blocks against those the slots hold."""
+    _, cfg, state = build()
+    kw = dict(chunk_size=64, max_model_len=128, num_pages=96,
+              prefix_cache=False)
+    ps = prompts([70, 33, 64, 100], seed=4)
+
+    def serve():
+        eng = engine(state, cfg, **kw)
+        hs = [eng.add_request(p, 5) for p in ps]
+        eng.run()
+        assert eng.compile_count == 1
+        return [h.out_tokens for h in hs], eng.metrics_summary()
+
+    outs, m = serve()
+    # 64 + 6, 33, 64, 64 + 36: six chunk rows of two blocks; 2 + 1, 2, 2, 2 + 2
+    assert (m["index_chunk_blocks_padded"],
+            m["index_chunk_blocks_live"]) == (12, 11)
+    every_block, counts = hy.indexed_attention, []
+
+    def parents(*a, live=None, **k):
+        counts.append(live)
+        return every_block(*a, **k)
+
+    monkeypatch.setattr(hy, "indexed_attention", parents)
+    theirs, m_p = serve()
+    # each full layer traced its decode region and its chunk slot through
+    # it: the slot was handed its row's count (a scalar), the decode rows
+    # theirs, which the per-row tables take no notice of
+    assert sum(c.ndim == 0 for c in counts) == len(counts) // 2 > 0
+    assert outs == theirs and all(len(o) == 5 for o in outs)
+    assert m_p["index_chunk_blocks_live"] == 11     # the account is the host's

@@ -4,6 +4,9 @@ keys the test gathers itself; the blocking rule at the cell's shapes; and
 a tiny ``dots3`` engine serving the same tokens through either path."""
 from __future__ import annotations
 
+import functools
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,6 +14,7 @@ import pytest
 
 import test_dots3_serving as d3
 from hetu_tpu.models import hybrid as hy
+from hetu_tpu.models.gpt import LatentGeometry
 from hetu_tpu.ops import index_score as ix
 
 HEADS, DIM, PS, PAGES = 4, 16, 8, 40
@@ -153,3 +157,138 @@ def test_the_engine_serves_the_same_tokens_through_the_kernel(dtype):
         assert m["index_grid_steps"] > 0
         assert m["index_key_pages_scored"] >= m["index_grid_steps"]
     assert outs[0] == outs[1] and all(len(o) == 6 for o in outs[0])
+
+
+# -- a part-filled chunk: the selection and the read follow the live queries --
+
+LIVES = (1, 32, 33, 200, 255, 256)
+CHUNK, CTX, LATENT, WIDTH, NH = 256, 300, 32, 48, 4
+
+
+def _parent_by_blocks(f, arrays, block: int):
+    """``by_blocks`` as it stood before it took ``live`` (commit 5ab4529),
+    line for line: what ``live=None`` still has to lower to."""
+    n = arrays[0].shape[0]
+    if n <= block:
+        return f(arrays)
+    pad = -n % block
+    cut = lambda a: jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1)  # noqa: E731
+                            ).reshape((-1, block) + a.shape[1:])
+    out = jax.lax.map(f, tuple(cut(a) for a in arrays))
+    return out.reshape((-1,) + out.shape[2:])[:n]
+
+
+def _live_blocks(live: int) -> int:
+    """Rows of the blocks that hold one of the first ``live`` rows."""
+    return -(-live // ix.INDEX_SELECT_BLOCK) * ix.INDEX_SELECT_BLOCK
+
+
+def _chunk_qpos(live: int):
+    """A chunk slot as the engine packs it: the first ``live`` queries hold
+    the context's last tokens, the rest position 0."""
+    i = np.arange(CHUNK)
+    return jnp.asarray(np.where(i < live, CTX - live + i, 0), jnp.int32)
+
+
+@functools.lru_cache(maxsize=None)
+def _chunk_selection():
+    """The selection of a 256-query chunk over a context of 300 (38 pages
+    of 8, top-12) through ``by_blocks``, jitted once with the live count
+    traced (``bounded`` False: the call without one, today's)."""
+    iq, iw, pages, table = _inputs(CHUNK, 38, True, seed=5)
+    scores = ix.index_score_pages(iq, iw, pages, table,
+                                  jnp.asarray(CTX, jnp.int32))
+
+    def select(args):
+        pos, valid = hy.index_select(args[0], args[1], TOPK)
+        return jnp.where(valid, pos, -1)
+
+    return jax.jit(lambda qpos, live, bounded: ix.by_blocks(
+        select, (scores, qpos), ix.INDEX_SELECT_BLOCK,
+        live if bounded else None), static_argnames="bounded")
+
+
+@pytest.mark.parametrize("live", LIVES)
+def test_by_blocks_runs_the_blocks_that_hold_a_live_row(live):
+    """256 rows in blocks of 32: with ``live`` the blocks up to row ``live
+    - 1`` give what the call without it gives (the exact top-k's positions
+    here), the blocks behind them zeros."""
+    run, qpos = _chunk_selection(), _chunk_qpos(live)
+    got = np.asarray(run(qpos, jnp.asarray(live, jnp.int32), bounded=True))
+    want = np.asarray(run(qpos, jnp.asarray(live, jnp.int32), bounded=False))
+    up = _live_blocks(live)
+    assert got.shape == want.shape == (CHUNK, TOPK)
+    assert (want[:live] >= 0).all()        # a live query selects all TOPK
+    np.testing.assert_array_equal(got[:up], want[:up])
+    assert not got[up:].any()
+
+
+@functools.lru_cache(maxsize=None)
+def _chunk_region():
+    """One 256-query chunk region of a dsa layer at tiny widths, jitted
+    once with the live count traced."""
+    geo = LatentGeometry(heads=NH, q_rank=24, latent=LATENT, nope=16, rope=8,
+                         v=16, theta=8e7, scale=24 ** -0.5,
+                         index_heads=HEADS, index_dim=DIM, index_rope=8,
+                         index_topk=TOPK)
+    iq, iw, pages, table = _inputs(CHUNK, 38, True, seed=5)
+    k = jax.random.split(jax.random.PRNGKey(11), 2)
+    pool = jax.random.normal(k[0], (PAGES, 1, PS, WIDTH), jnp.float32)
+    q_cat = jax.random.normal(k[1], (CHUNK, NH, WIDTH), jnp.float32)
+
+    def run(qpos, live, bounded: bool):
+        return hy.indexed_attention(
+            geo, iq, iw, q_cat, qpos, table, (pool, pages),
+            use_kernel=False, live=live if bounded else None)
+
+    return jax.jit(run, static_argnames="bounded")
+
+
+@pytest.mark.parametrize("live", LIVES)
+def test_a_chunks_region_reads_for_its_live_blocks_alone(live):
+    """``indexed_attention`` over a shared table: the live blocks' outputs
+    are the call's without a count bit for bit, the blocks behind them
+    zeros (where that call attends over padding)."""
+    run, qpos = _chunk_region(), _chunk_qpos(live)
+    got = np.asarray(run(qpos, jnp.asarray(live, jnp.int32), bounded=True))
+    want = np.asarray(run(qpos, jnp.asarray(live, jnp.int32), bounded=False))
+    up = _live_blocks(live)
+    assert got.shape == (CHUNK, NH, LATENT) and np.abs(want[:live]).min() > 0
+    np.testing.assert_array_equal(got[:up], want[:up])
+    assert not got[up:].any() and (up == CHUNK or want[up:].any())
+
+
+def test_the_chunk_regions_loop_takes_its_trip_count_from_the_live_count():
+    """With a count the loop over the region's blocks is a ``while`` whose
+    bound is traced, in place of the ``lax.map`` over all eight (a ``scan``
+    of length 8: the one left is the XLA scoring arithmetic's, which takes
+    no count); without one it is the program it was."""
+    run = _chunk_region()
+    args = (_chunk_qpos(40), jnp.asarray(40, jnp.int32))
+    eight = rf"length={CHUNK // ix.INDEX_SELECT_BLOCK}\b"
+
+    def loops(bounded):
+        text = str(jax.make_jaxpr(
+            lambda *a: run(*a, bounded=bounded))(*args))
+        return text.count("while["), len(re.findall(eight, text))
+
+    assert loops(True) == (1, 1) and loops(False) == (0, 2)
+    assert "stablehlo.while" in run.lower(*args, bounded=True).as_text()
+
+
+def test_by_blocks_without_a_live_count_lowers_to_the_parents_text():
+    """``by_blocks(f, arrays, block)`` — the decode rows' region, the
+    benchmark's check ``hy._index_positions`` — is the program it was, and
+    rows that are no whole blocks come out at their own length."""
+    a = jnp.arange(70 * 3, dtype=jnp.float32).reshape(70, 3)
+    b = jnp.arange(70, dtype=jnp.int32)
+    f = lambda x: x[0] * 2.0 + x[1][:, None]                # noqa: E731
+    text = lambda g: jax.jit(                                # noqa: E731
+        lambda a, b: g(f, (a, b), 32)).lower(a, b).as_text()
+    assert text(ix.by_blocks) == text(_parent_by_blocks)
+    bounded = jax.jit(lambda a, b, n: ix.by_blocks(f, (a, b), 32, n))
+    want = np.asarray(ix.by_blocks(f, (a, b), 32))
+    for live, up in ((1, 32), (32, 32), (33, 64), (65, 70), (70, 70)):
+        got = np.asarray(bounded(a, b, live))
+        np.testing.assert_array_equal(got[:up], want[:up])
+        assert got.shape == want.shape and not got[up:].any()

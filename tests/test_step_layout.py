@@ -347,7 +347,8 @@ def _recount(eng: Engine, step: dict) -> tuple:
     """One step's ``(counters, gauges, span attributes)`` from the rows of
     the tap (slot, position, query length; the page tables) and what the
     spy saw beside them: sets of pages as Python sets, pairs by loops."""
-    from hetu_tpu.ops.index_score import index_score_blocking
+    from hetu_tpu.ops.index_score import (INDEX_SELECT_BLOCK,
+                                          index_score_blocking)
     from hetu_tpu.ops.moe_grouped import ROW_BLOCK
     from hetu_tpu.ops.ragged_paged_attention import (
         kv_call_blocking, latent_pages_per_grid_step)
@@ -426,9 +427,10 @@ def _recount(eng: Engine, step: dict) -> tuple:
                                      min(pos + q, topk))
         wins = [pg for pages in step["win_pages"] for pg in pages]
         # every query block of a row's scoring call walks the row's pages,
-        # a group of slots a grid step
-        key_pages = grid = 0
-        for row, _, _ in rows if topk else ():
+        # a group of slots a grid step; a chunk slot's selection and read
+        # run its blocks of queries up to the row's live tokens
+        key_pages = grid = blocks_live = blocks_padded = 0
+        for row, _, q in rows if topk else ():
             q_blk, group = index_score_blocking(
                 width[row] if width[row] > 1 else sch.max_batch,
                 cfg.mixer_geometry["dsa"].index_heads, maxp, width[row] > 1,
@@ -436,8 +438,14 @@ def _recount(eng: Engine, step: dict) -> tuple:
             for _ in range(0, width[row], q_blk):
                 key_pages += len(held[row])
                 grid += len(range(0, len(held[row]), group))
+            if sch.max_batch <= row < vbase:
+                blocks_live += len(range(0, q, INDEX_SELECT_BLOCK))
+                blocks_padded += len(range(0, width[row],
+                                           INDEX_SELECT_BLOCK))
         a.update(index_pairs=causal, index_key_pages=key_pages,
                  index_grid_steps=grid,
+                 index_chunk_blocks_live=blocks_live,
+                 index_chunk_blocks_padded=blocks_padded,
                  index_selected=sum(pairs(pos, q, topk) for _, pos, q in rows)
                  if topk else 0,
                  index_selected_floor=sum(docs.values()),
@@ -449,6 +457,8 @@ def _recount(eng: Engine, step: dict) -> tuple:
         c.update(index_pairs_scored=causal,
                  index_positions_selected=a["index_selected"],
                  index_key_pages_scored=key_pages, index_grid_steps=grid,
+                 index_chunk_blocks_live=blocks_live,
+                 index_chunk_blocks_padded=blocks_padded,
                  window_pages_held=step["window_in_use"],
                  full_pages_held=step["full_in_use"])
     if eng.self_draft:
