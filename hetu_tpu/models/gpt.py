@@ -29,7 +29,9 @@ from ..obs.phases import phase
 from jax.sharding import PartitionSpec as P
 
 
-MIXERS = ("mamba2", "attention", "moe", "mla", "dsa", "swa", "mlp")
+MIXERS = ("mamba2", "attention", "moe", "mla", "dsa", "swa", "mlp", "mamba1")
+# mixers that keep a recurrent state in the slot store; a pattern holds one
+STATE_MIXERS = ("mamba2", "mamba1")
 # mixers that keep pages in the K/V pool
 PAGED_MIXERS = ("attention", "mla", "dsa", "swa")
 
@@ -193,6 +195,13 @@ class GPTConfig:
     mamba_state_dim: int = 0                # ssm_state_size N
     mamba_conv_kernel: int = 4
     mamba_chunk_size: int = 128             # SSD chunk of the matmul form
+    # the "mamba1" mixer (a selective scan: a decay for every (channel,
+    # state) pair, ``ops/selective_scan.py``): its channels and the rank of
+    # the time step's projection; ``mamba_state_dim`` / ``mamba_conv_kernel``
+    # are shared with "mamba2".  The conv runs over the x channels alone and
+    # ``dt``, ``B``, ``C`` each pass an RMSNorm.
+    mamba1_inner: int = 0
+    mamba1_dt_rank: int = 0
     # expert layer of a hybrid stack: ``num_experts`` is the ROUTER's
     # width (all experts of the deployment), of which this program holds
     # ``experts_held`` starting at ``expert_offset`` (default: all) and
@@ -235,6 +244,10 @@ class GPTConfig:
                         f"a {kind} layer takes its sizes from "
                         f"mixer_geometry[{kind!r}], and latent pages do "
                         f"not stand beside plain attention layers")
+            if len(set(self.layer_pattern) & set(STATE_MIXERS)) > 1:
+                raise ValueError(
+                    "one pattern holds one kind of recurrent mixer "
+                    f"({STATE_MIXERS}): the slot store has one layout")
             if "mlp" in self.layer_pattern and not self.ffn_hidden_size:
                 raise ValueError("an mlp layer needs ffn_hidden_size")
             if "moe" in self.layer_pattern:
@@ -416,6 +429,14 @@ class GPTConfig:
                 else ()
         return tuple(i for i, m in enumerate(self.layer_pattern)
                      if m == mixer)
+
+    @property
+    def state_mixer(self) -> Optional[str]:
+        """The pattern's recurrent mixer kind (None: it keeps no state)."""
+        for kind in STATE_MIXERS:
+            if self.layers_of(kind):
+                return kind
+        return None
 
     @property
     def held_experts(self) -> int:

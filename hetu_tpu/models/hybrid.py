@@ -3,12 +3,13 @@ attention (MLA; over an indexer's selection "dsa"; over a window "swa") /
 MoE / dense gated MLP.
 
 A ``GPTConfig`` with a ``layer_pattern`` runs ``x = x + mixer_l(RMSNorm_l
-(x))`` for every layer, a final norm and an untied head.  This module is
+(x))`` for every layer, a final norm and a head (``lm_head.weight``, or
+``wte.weight`` again where ``cfg.tie_embeddings``).  This module is
 the one place that knows the stack's PARAMETER NAMES and the mixers'
 arithmetic; the serving step (``serving/decode.py``) lays them over the
 ragged token axis and owns the state (K/V pages for the attention layers,
-state slots for the mamba2 layers).  The plain float32 reference is
-``benchmark/reference_hybrid.py``.
+state slots for the mamba2 / mamba1 layers).  The plain float32 reference
+is ``benchmark/reference_hybrid.py`` (``reference_jamba.py`` for mamba1).
 
 Tensors (a projection ``W`` is ``[out, in]``, used as ``x @ W.T``)::
 
@@ -18,6 +19,14 @@ Tensors (a projection ``W`` is ``[out, in]``, used as ``x @ W.T``)::
     h{i}.mamba.conv.weight [K, inner + 2*G*N]   .conv.bias [inner + 2*G*N]
     h{i}.mamba.dt_bias / .A_log / .D [heads]    (float32)
     h{i}.mamba.norm.weight [inner]    h{i}.mamba.out_proj.weight [H, inner]
+    mamba1 (``inner`` channels C, state N, dt rank R):
+    h{i}.mamba.in_proj.weight [2*C, H]      xs | z
+    h{i}.mamba.conv.weight [K, C]   .conv.bias [C]        (the x channels)
+    h{i}.mamba.x_proj.weight [R + 2*N, C]   dt_r | B | C
+    h{i}.mamba.dt_norm.weight [R]   .b_norm.weight / .c_norm.weight [N]
+    h{i}.mamba.dt_proj.weight [C, R]   .dt_proj.bias [C]  (bias float32)
+    h{i}.mamba.A_log [N, C] / .D [C]  (float32; the published [C, N] laid
+                          as the scan walks it)   .out_proj.weight [H, C]
     h{i}.attn.qkv.weight [(nh + 2*kv)*hd, H]    h{i}.attn.out.weight [H, nh*hd]
     h{i}.attn.q_norm.weight / .k_norm.weight [hd]   (``cfg.attn_qk_norm``)
     mla (``L``; nope / rope / v widths n / r / v, latent d_c, q rank R):
@@ -63,7 +72,9 @@ at its own head width behind QK-norms, window layers beside full ones,
 an MTP module that drafts for the serving step's verify rows),
 ``sdar_moe_config`` for ``model_type: sdar_moe`` (the same K/V attention
 and gated experts under a softmax router, generated by diffusion over
-blocks: ``GPTConfig.diffusion_block`` / ``mask_token_id``).
+blocks: ``GPTConfig.diffusion_block`` / ``mask_token_id``),
+``jamba_config`` for ``model_type: jamba`` with dense feed-forwards
+(mamba1 / attention, each followed by a gated MLP; a tied head).
 """
 from __future__ import annotations
 
@@ -81,6 +92,8 @@ from ..ops.index_score import INDEX_SELECT_BLOCK
 from ..ops.index_score import by_blocks as _by_blocks
 from ..ops.index_score import index_score_pages, index_scores  # noqa: F401
 from ..ops.moe_grouped import ACTIVATIONS, grouped_experts
+from ..ops.selective_scan import (selective_scan_chunk,
+                                   selective_scan_slots)
 from ..ops.ssd import causal_conv, ssd_chunk_scan, ssd_decode_slots
 from .generate import norm_eps
 from .gpt import GPTConfig, LatentGeometry
@@ -91,8 +104,8 @@ _MASKED = -1e30                 # a score outside the selection / window
 MIXER_OF = {"M": "mamba2", "*": "attention", "E": "moe", "L": "mla"}
 # float32 whatever the model's dtype: the recurrence's own parameters and
 # the router (its scores decide a top-k)
-_F32_PARAMS = ("mamba.dt_bias", "mamba.A_log", "mamba.D",
-               "moe.router.weight", "moe.router.bias")
+_F32_PARAMS = ("mamba.dt_bias", "mamba.dt_proj.bias", "mamba.A_log",
+               "mamba.D", "moe.router.weight", "moe.router.bias")
 
 
 def _refuse_unbuilt(pub: dict) -> None:
@@ -400,6 +413,51 @@ def sdar_moe_config(pub: dict, **overrides) -> GPTConfig:
     return GPTConfig(**kw)
 
 
+def jamba_config(pub: dict, **overrides) -> GPTConfig:
+    """The one translation from the published ``config.json`` keys of a
+    ``jamba``-type model whose feed-forwards are all dense
+    (``num_experts: 1``) to ``GPTConfig``.  A published layer is two
+    pattern entries, each behind its own RMSNorm and residual: its mixer —
+    "attention" where ``i % attn_layer_period == attn_layer_offset``
+    (``num_key_value_heads`` K/V heads of ``hidden / heads`` lanes, no
+    bias, NO positional encoding), else "mamba1" (``mamba_expand *
+    hidden`` channels, ``mamba_d_state`` states, a time step out of a
+    rank-``mamba_dt_rank`` projection, RMSNorms on ``dt``, ``B``, ``C``,
+    no gated norm before the way out) — then "mlp", ``down(silu(gate u) *
+    up u)`` of ``intermediate_size``.  The head is ``wte`` again where
+    ``tie_word_embeddings``."""
+    unbuilt = [k for k, ok in (
+        ("num_experts", pub.get("num_experts", 1) == 1),
+        ("sliding_window", pub.get("sliding_window") is None),
+        ("mamba_proj_bias", not pub.get("mamba_proj_bias")),
+        ("mamba_conv_bias", bool(pub.get("mamba_conv_bias", True)))) if not ok]
+    if unbuilt:
+        raise ValueError(
+            "jamba is built with dense feed-forwards (num_experts 1), no "
+            "window, a conv bias and no projection bias; not built: "
+            + ", ".join(unbuilt))
+    layers = pub["num_hidden_layers"]
+    period, offset = pub["attn_layer_period"], pub["attn_layer_offset"]
+    pattern = tuple(m for i in range(layers) for m in (
+        "attention" if i % period == offset else "mamba1", "mlp"))
+    kw = dict(
+        vocab_size=pub["vocab_size"], hidden_size=pub["hidden_size"],
+        num_layers=len(pattern), num_heads=pub["num_attention_heads"],
+        num_kv_heads=pub["num_key_value_heads"],
+        ffn_hidden_size=pub["intermediate_size"],
+        max_seq_len=pub["max_position_embeddings"],
+        activation=pub["hidden_act"], norm="rmsnorm", position="none",
+        norm_eps=float(pub["rms_norm_eps"]),
+        tie_embeddings=bool(pub["tie_word_embeddings"]), sp=False,
+        dtype=pub.get("dtype", "bfloat16"), layer_pattern=pattern,
+        mamba1_inner=pub["mamba_expand"] * pub["hidden_size"],
+        mamba1_dt_rank=pub["mamba_dt_rank"],
+        mamba_state_dim=pub["mamba_d_state"],
+        mamba_conv_kernel=pub["mamba_d_conv"])
+    kw.update(overrides)
+    return GPTConfig(**kw)
+
+
 def rotary_tables(theta: float, dim: int, max_len: int):
     """``(cos, sin) [max_len, dim]`` float32 of a plain rotary stream at
     base ``theta``, the halves laid ``[angles | angles]``
@@ -451,8 +509,9 @@ def param_shapes(cfg: GPTConfig) -> Dict[str, Tuple[int, ...]]:
     """Every tensor of a hybrid stack under its name."""
     c = cfg
     hd = c.hidden_size
-    out = {"wte.weight": (c.vocab_size, hd),
-           "lm_head.weight": (c.vocab_size, hd), "ln_f.weight": (hd,)}
+    out = {"wte.weight": (c.vocab_size, hd), "ln_f.weight": (hd,)}
+    if not c.tie_embeddings:
+        out["lm_head.weight"] = (c.vocab_size, hd)
     inner, cd, mh = c.mamba_inner, c.mamba_conv_dim, c.mamba_num_heads
     lat = c.moe_latent_dim or hd
     if c.mtp_pattern:
@@ -470,6 +529,20 @@ def param_shapes(cfg: GPTConfig) -> Dict[str, Tuple[int, ...]]:
                 out[p + "mamba." + n] = (mh,)
             out[p + "mamba.norm.weight"] = (inner,)
             out[p + "mamba.out_proj.weight"] = (hd, inner)
+        elif mixer == "mamba1":
+            ch, n, r = c.mamba1_inner, c.mamba_state_dim, c.mamba1_dt_rank
+            out[p + "mamba.in_proj.weight"] = (2 * ch, hd)
+            out[p + "mamba.conv.weight"] = (c.mamba_conv_kernel, ch)
+            out[p + "mamba.conv.bias"] = (ch,)
+            out[p + "mamba.x_proj.weight"] = (r + 2 * n, ch)
+            out[p + "mamba.dt_norm.weight"] = (r,)
+            out[p + "mamba.b_norm.weight"] = (n,)
+            out[p + "mamba.c_norm.weight"] = (n,)
+            out[p + "mamba.dt_proj.weight"] = (ch, r)
+            out[p + "mamba.dt_proj.bias"] = (ch,)
+            out[p + "mamba.A_log"] = (n, ch)
+            out[p + "mamba.D"] = (ch,)
+            out[p + "mamba.out_proj.weight"] = (hd, ch)
         elif mixer == "attention":
             q, kv = c.num_heads * c.head_dim, c.kv_heads * c.head_dim
             out[p + "attn.qkv.weight"] = (q + 2 * kv, hd)
@@ -541,7 +614,9 @@ def init_state(cfg: GPTConfig, seed: int, time_step=(0.001, 0.1, 1e-4),
     in ``[time_step_min, time_step_max]`` floored at ``time_step_floor``
     and stored as ``dt_bias = dt + log(-expm1(-dt))`` (inverse softplus),
     ``A`` uniform 1..16 as ``A_log``, ``D`` 1 — the published
-    initialiser.  The hardware bit generator (``rbg``) keeps the
+    initialiser (a mamba1 layer's ``A_log`` [N, C] is ``log(1..N)`` along
+    its state axis and its ``dt_proj.bias`` the inverse softplus, the
+    published Mamba-1 one).  The hardware bit generator (``rbg``) keeps the
     temporaries at the tensors' own size."""
     shapes = param_shapes(cfg)
     groups: Dict[str, Dict[str, Tuple[int, ...]]] = {}   # layer -> tails
@@ -561,9 +636,12 @@ def init_state(cfg: GPTConfig, seed: int, time_step=(0.001, 0.1, 1e-4),
             return jnp.zeros(shape, dt)
         if tail == "moe.router.bias":
             return router_bias_std * jax.random.normal(key, shape, dt)
+        if tail == "mamba.A_log" and len(shape) == 2:
+            return jnp.broadcast_to(jnp.log(jnp.arange(
+                1, shape[0] + 1, dtype=dt))[:, None], shape)
         if tail == "mamba.A_log":
             return jnp.log(jax.random.uniform(key, shape, dt, 1.0, 16.0))
-        if tail == "mamba.dt_bias":
+        if tail in ("mamba.dt_bias", "mamba.dt_proj.bias"):
             step = jnp.exp(jax.random.uniform(
                 key, shape, dt, math.log(t_min), math.log(t_max)))
             step = jnp.maximum(step, t_floor)
@@ -623,6 +701,20 @@ def _ssm_inputs(cfg: GPTConfig, w: MambaWeights, conv_out, dt_raw):
     return x, b, cc, dt, -jnp.exp(w.a_log.astype(F32))
 
 
+def _conv_rows(w, x, conv_state, live, fresh):
+    """The conv's one-token step for every slot: ``x`` [S, channels] in
+    SLOT order behind ``conv_state`` [S, K-1, channels] (zeros where
+    ``fresh``).  Returns ``(conv output [S, channels] float32, the new
+    state: moved on where ``live``, kept elsewhere)``; ``w`` carries
+    ``conv_w`` / ``conv_b``."""
+    with phase("ssm_conv"):
+        tail = jnp.where(fresh[:, None, None], 0, conv_state)
+        full = jnp.concatenate([tail, x[:, None].astype(tail.dtype)], 1)
+        conv = jnp.einsum("skc,kc->sc", full.astype(F32),
+                          w.conv_w.astype(F32)) + w.conv_b.astype(F32)
+        return conv, jnp.where(live[:, None, None], full[:, 1:], conv_state)
+
+
 def mamba_rows(cfg: GPTConfig, w: MambaWeights, zxd, conv_state, ssm_state,
                live, fresh, walk):
     """One token for each LIVE state slot: ``zxd`` [S, in_proj width] in
@@ -635,12 +727,7 @@ def mamba_rows(cfg: GPTConfig, w: MambaWeights, zxd, conv_state, ssm_state,
     float32 before the gate, zeros where not ``live``; new conv state;
     the store)``."""
     _, xbc, dt_raw = _split_zxd(cfg, zxd)
-    with phase("ssm_conv"):
-        tail = jnp.where(fresh[:, None, None], 0, conv_state)
-        full = jnp.concatenate([tail, xbc[:, None].astype(tail.dtype)], 1)
-        conv = jnp.einsum("skc,kc->sc", full.astype(F32),
-                          w.conv_w.astype(F32)) + w.conv_b.astype(F32)
-        new_conv = jnp.where(live[:, None, None], full[:, 1:], conv_state)
+    conv, new_conv = _conv_rows(w, xbc, conv_state, live, fresh)
     with phase("ssm_scan"):
         x, b, c, dt, a = _ssm_inputs(cfg, w, conv, dt_raw)
         y, new_ssm = ssd_decode_slots(x, dt, a, b, c, w.d, ssm_state, *walk,
@@ -679,6 +766,87 @@ def mamba_gate_norm(cfg: GPTConfig, w: MambaWeights, y, z, dtype):
     v = (y * jax.nn.silu(z.astype(F32))).reshape(n, g, -1)
     v = v * lax.rsqrt(jnp.mean(v * v, -1, keepdims=True) + norm_eps(cfg))
     return (v.reshape(n, -1) * w.norm.astype(F32)).astype(dtype)
+
+
+class Mamba1Weights:
+    """One mamba1 layer's tensors, looked up once."""
+
+    def __init__(self, params: dict, i: int):
+        g = lambda n: params[f"h{i}.mamba.{n}"]  # noqa: E731
+        self.in_proj, self.out_proj = g("in_proj.weight"), g("out_proj.weight")
+        self.conv_w, self.conv_b = g("conv.weight"), g("conv.bias")
+        self.x_proj, self.dt_proj = g("x_proj.weight"), g("dt_proj.weight")
+        self.dt_bias, self.a_log, self.d = g("dt_proj.bias"), g("A_log"), g("D")
+        self.norms = tuple(g(n + "_norm.weight") for n in ("dt", "b", "c"))
+
+
+def _scan_inputs(cfg: GPTConfig, w: Mamba1Weights, conv_out):
+    """What the selective scan reads, from the conv's output [n, C]
+    float32: ``xc = silu(conv)``; ``[dt_r | B | C] = W_x xc``, each behind
+    its own RMSNorm; ``dt = softplus(W_dt dt_r + b_dt)``.  The two
+    projections run in the model's dtype (phase ``ssm_proj``), the rest in
+    float32 (``ssm_scan``: the phases follow one another, a nested one
+    would count as its outer).  Returns
+    ``(xc [n, C], dt [n, C], B [n, N], C [n, N], A [N, C])`` float32."""
+    n, r = cfg.mamba_state_dim, cfg.mamba1_dt_rank
+    with phase("ssm_scan"):
+        xc = jax.nn.silu(conv_out)
+    with phase("ssm_proj"):
+        dbc = xc.astype(w.x_proj.dtype) @ w.x_proj.T
+    with phase("ssm_scan"):
+        parts = (dbc[:, :r], dbc[:, r:r + n], dbc[:, r + n:])
+        dt_r, b, c = (_rms(cfg, v, nw) for v, nw in zip(parts, w.norms))
+    with phase("ssm_proj"):
+        dt_raw = dt_r @ w.dt_proj.T
+    with phase("ssm_scan"):
+        dt = jax.nn.softplus(dt_raw.astype(F32) + w.dt_bias.astype(F32))
+        return (xc, dt, b.astype(F32), c.astype(F32),
+                -jnp.exp(w.a_log.astype(F32)))
+
+
+def mamba1_rows(cfg: GPTConfig, w: Mamba1Weights, xz, conv_state, ssm_state,
+                live, fresh, walk):
+    """``mamba_rows`` for a mamba1 layer: ``xz`` [S, 2 * C] (``xs | z``) in
+    SLOT order, ``conv_state`` [S, K-1, C], ``ssm_state`` [S, N, C/128,
+    128] float32 — the store, of which the scan reads and writes the slots
+    of ``walk`` in place (``ops.selective_scan.selective_scan_slots``).
+    Returns ``(y [S, C] float32 before the gate, zeros where not ``live``;
+    new conv state; the store)``."""
+    conv, new_conv = _conv_rows(w, xz[:, :cfg.mamba1_inner], conv_state,
+                                live, fresh)
+    xc, dt, b, c, a = _scan_inputs(cfg, w, conv)
+    with phase("ssm_scan"):
+        y, new_ssm = selective_scan_slots(xc, dt, a, b, c, w.d, ssm_state,
+                                          *walk, fresh)
+    return y, new_conv, new_ssm
+
+
+def mamba1_chunk(cfg: GPTConfig, w: Mamba1Weights, xz, conv_state, ssm_state,
+                 slot, length, fresh):
+    """A run of ``length`` (<= T) consecutive tokens of ONE sequence whose
+    state is slot ``slot`` of the stores: ``xz`` [T, 2 * C].  The conv
+    tail is taken out and put back; the scan state is carried in place
+    (``selective_scan_chunk``), tokens past ``length`` leaving both as
+    they found them.  Returns ``(y [T, C] float32 before the gate, the
+    conv store, the scan store)``."""
+    xs = xz[:, :cfg.mamba1_inner]
+    with phase("state_io"):
+        tail0 = conv_state[slot]
+    with phase("ssm_conv"):
+        conv, tail1 = causal_conv(xs, w.conv_w, w.conv_b,
+                                  jnp.where(fresh, 0, tail0), length)
+    with phase("state_io"):
+        conv_state = conv_state.at[slot].set(tail1)
+    xc, dt, b, c, a = _scan_inputs(cfg, w, conv)
+    with phase("ssm_scan"):
+        y, ssm_state = selective_scan_chunk(xc, dt, a, b, c, w.d, ssm_state,
+                                            slot, length, fresh)
+    return y, conv_state, ssm_state
+
+
+def mamba1_gate(y, z, dtype):
+    """``y * silu(z)``: the gate, with no norm behind it."""
+    return (y * jax.nn.silu(z.astype(F32))).astype(dtype)
 
 
 def _rms_eps(eps: float, v, w):

@@ -361,6 +361,14 @@ def kv_heads_per_block(win: int, rows: int, kv_heads: int, hd: int,
                     kv_dtype)) <= budget] or [1])
 
 
+def _token_tile_rows(g: int, q_dtype):
+    """``(sub, gp)``: the rows of one packed tile of ``q_dtype`` and the
+    tile rows a token's ``g`` query heads of one kv head take (``g`` where
+    it divides the tile, else whole tiles)."""
+    sub = SUBLANES * max(1, 4 // jnp.dtype(q_dtype).itemsize)
+    return sub, g if sub % g == 0 else -(-g // sub) * sub
+
+
 def kv_call_blocking(max_q: int, tokens: int, num_heads: int, q_dtype,
                      pages, maxp: int, pages_per_step: Optional[int] = None,
                      heads_per_block: Optional[int] = None):
@@ -379,9 +387,7 @@ def kv_call_blocking(max_q: int, tokens: int, num_heads: int, q_dtype,
     ``Engine`` for its ``kv_page_blocks`` counter.  ``pages_per_step`` /
     ``heads_per_block`` stand in for a rule where given."""
     _, kvh, ps, hd = pages.shape
-    g = num_heads // kvh
-    sub = SUBLANES * max(1, 4 // jnp.dtype(q_dtype).itemsize)
-    gp = g if sub % g == 0 else -(-g // sub) * sub
+    sub, gp = _token_tile_rows(num_heads // kvh, q_dtype)
     win = -(-(max_q * gp + max(sub - gp, 0)) // sub) * sub
     rows = -(-(tokens * gp) // sub) * sub + win
     kpg = pages_per_step or kv_pages_per_grid_step(win, ps, maxp)
@@ -391,6 +397,35 @@ def kv_call_blocking(max_q: int, tokens: int, num_heads: int, q_dtype,
         raise ValueError(f"heads_per_block {hb} does not divide kv_heads "
                          f"{kvh}")
     return sub, gp, win, rows, kpg, hb
+
+
+# the aligned window of one row's queries (``max_q`` tokens x the tile rows
+# a token takes) a K/V call may hold: its float32 accumulator, the q and
+# output blocks and the score tiles' spills grow with it, and at 32,768
+# rows (1,024 tokens x 20 query heads of one kv head, 32 rows a token)
+# Mosaic runs out of the 128 MiB of VMEM.  A window wider than
+# ``MAX_WINDOW_ROWS`` — the widest an accepted cell runs (256 tokens x 16
+# rows), so that their calls stay the calls they were — is cut into
+# sub-windows of ``SPLIT_WINDOW_ROWS``: the narrower the window, the more
+# page-table slots a grid step covers (``kv_pages_per_grid_step``) and the
+# finer the causal skip; that chunk at 24k cached positions, ms a call on
+# the chip by sub-window rows: 16,384: 33.4, 8,192: 11.8, 4,096: 9.1,
+# 2,048: 5.7, 1,024: 4.4 (PERF.md section 6, PR 53, step 0b)
+MAX_WINDOW_ROWS = 4096
+SPLIT_WINDOW_ROWS = 1024
+
+
+def window_split(max_q: int, num_heads: int, kv_heads: int, q_dtype) -> int:
+    """Into how many sub-windows of ``max_q / n`` tokens the K/V call cuts
+    each row's window (1: the call as it is; every call of a configuration
+    accepted before the rule reads 1)."""
+    _, gp = _token_tile_rows(num_heads // kv_heads, q_dtype)
+    n = 1
+    if max_q * gp <= MAX_WINDOW_ROWS:
+        return n
+    while max_q * gp > n * SPLIT_WINDOW_ROWS and max_q % (2 * n) == 0:
+        n *= 2
+    return n
 
 
 # jitted: the serving step calls this once per layer and region with the
@@ -442,6 +477,24 @@ def ragged_paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
     if interpret is None:
         interpret = not on_tpu()
     g = nh // kvh
+    n = window_split(max_q, nh, kvh, q.dtype) if mask_block == 1 else 1
+    if n > 1:
+        # a window too wide for one call: each row becomes ``n``
+        # rows of ``max_q / n`` tokens over the same table, a sub-row's
+        # context reaching to its own last query (the keys behind it are
+        # in the pages already: the step writes before it attends)
+        w = max_q // n
+        at = jnp.arange(n, dtype=jnp.int32) * w
+        sub_q = jnp.clip(q_lens.astype(jnp.int32)[:, None] - at, 0, w)
+        first = (ctx_lens - q_lens).astype(jnp.int32)[:, None]
+        sub_ctx = jnp.where(sub_q > 0, first + at + sub_q, 0)
+        starts = cu_q.astype(jnp.int32)[:-1, None] + at
+        return ragged_paged_attention_pallas(
+            q, k_pages, v_pages, sub_q.reshape(-1),
+            jnp.concatenate([starts.reshape(-1), cu_q[-1:].astype(jnp.int32)]),
+            jnp.repeat(page_tables, n, axis=0), sub_ctx.reshape(-1), max_q=w,
+            softmax_scale=softmax_scale, interpret=interpret, name=name,
+            pages_per_step=pages_per_step, heads_per_block=heads_per_block)
     sub, gp, win, rows, kpg, hb = kv_call_blocking(
         max_q, t, nh, q.dtype, k_pages, maxp, pages_per_step,
         heads_per_block)

@@ -584,13 +584,13 @@ def build_unified_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
                 "a pattern stack (layer_pattern) is built without page "
                 "quantization: its page layouts have no quantized form")
         if spec_k and (spec_k > 1 or not c.mtp_pattern or set(
-                c.layer_pattern) & {"mamba2", "mla", "dsa", "swa"}):
+                c.layer_pattern) & {"mamba2", "mamba1", "mla", "dsa", "swa"}):
             raise ValueError(
                 "speculative verify rows on a pattern stack are fed by its "
                 "own MTP module, one draft a row (spec_k 1, "
                 "cfg.mtp_pattern), over plain K/V attention layers: a "
-                "rejected draft cannot be rolled out of recurrent (mamba2) "
-                "state, and the latent layers' by-region calls have no "
+                "rejected draft cannot be rolled out of recurrent (mamba2 / "
+                "mamba1) state, and the latent layers' by-region calls have no "
                 "verify region")
         _refuse_unbuilt_block(c, chunk, page_size, spec_k)
         return _build_hybrid_step_fn(c, max_seqs, chunk, prefill_rows,
@@ -956,7 +956,7 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
                                            # state_slots [rows] i32: the
                                            # row's slot in the store
        k_pages, v_pages,                # paged layers only
-       conv_states, ssm_states)         # mamba2 layers: [slots, ...]
+       conv_states, ssm_states)         # state layers: [slots, ...]
       -> (out [layout.out_size] i32,    # next_tokens [rows], then
                                         # moe_load [moe layers, held]
           new k_pages, v_pages, conv_states, ssm_states)
@@ -1056,7 +1056,8 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
         cos, sin, q_scale = hy.mla_rotary_tables(c, max_pages * page_size)
         # the rotary stream's zero lanes, key and query alike
         rope_pad = ((0, 0), (0, c.latent_page_dims[1] - c.rope_dim))
-    mamba_of = {i: m for m, i in enumerate(c.layers_of("mamba2"))}
+    mamba_of = {i: m for m, i in enumerate(
+        c.layers_of(c.state_mixer) if c.state_mixer else ())}
     # a layer's second page array (index keys; a K/V layer's V), in order
     index_of = {i: n for n, i in enumerate(
         i for i, l in zip(c.paged_layers, c.page_layers or ())
@@ -1119,8 +1120,8 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
             slot_live = slot_row >= 0
             slot_src = jnp.maximum(slot_row, 0)
             slot_fresh = slot_live & fresh_row[slot_src]
-            # the live slots, compact: what every mamba2 layer's
-            # recurrence walks (hy.mamba_rows)
+            # the live slots, compact: what every state-space layer's
+            # recurrence walks (hy.mamba_rows / hy.mamba1_rows)
             walk = live_slot_list(slot_live) if mamba_of else None
         if use_kernel and attn_of:
             tile = write_tile((k_pages[0],) + tuple(v_pages[:1]))
@@ -1436,6 +1437,39 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
                     y = hy.mamba_gate_norm(
                         c, w, jnp.concatenate(ys, axis=0),
                         zxd[..., :c.mamba_inner], x.dtype)
+                with phase("ssm_proj"):
+                    out = by_region(
+                        lambda yy, w=w: yy @ w.out_proj.T, y, q_lens)
+                new_conv[m], new_ssm[m] = conv_s, ssm_s
+            elif mixer == "mamba1":
+                m = mamba_of[i]
+                w = hy.Mamba1Weights(params, i)
+                with phase("ssm_proj"):
+                    xz = by_region(
+                        lambda hh, w=w: hh @ w.in_proj.T, h, q_lens)
+                with phase("state_io"):
+                    xz_slots = xz[:max_seqs][slot_src]
+                y_slots, conv_s, ssm_s = hy.mamba1_rows(
+                    c, w, xz_slots, new_conv[m], new_ssm[m], slot_live,
+                    slot_fresh, walk)
+                with phase("state_io"):
+                    ys = [y_slots[state_slots[:max_seqs]]]
+                for row, start, width in slots:
+                    # the row's state stays in its slot: the scan carries
+                    # it through the chunk in place
+                    y_c, conv_s, ssm_s = lax.cond(
+                        q_lens[row] > 0,
+                        lambda z, cs, ss, sl, n, f, w=w: hy.mamba1_chunk(
+                            c, w, z, cs, ss, sl, n, f),
+                        lambda z, cs, ss, sl, n, f: (
+                            jnp.zeros((width, c.mamba1_inner), jnp.float32),
+                            cs, ss),
+                        xz[start: start + width], conv_s, ssm_s,
+                        state_slots[row], q_lens[row], fresh_row[row])
+                    ys.append(y_c)
+                with phase("ssm_scan"):
+                    y = hy.mamba1_gate(jnp.concatenate(ys, axis=0),
+                                       xz[..., c.mamba1_inner:], x.dtype)
                 with phase("ssm_proj"):
                     out = by_region(
                         lambda yy, w=w: yy @ w.out_proj.T, y, q_lens)

@@ -135,6 +135,7 @@ from ..models.generate import _Params
 from ..models.gpt import GPTConfig
 from ..obs.tracer import get_tracer
 from ..ops.pallas import on_tpu
+from ..ops.selective_scan import state_shape
 from ..utils.metrics import make_instrument, render_prometheus
 from .decode import StepLayout, build_unified_step_fn
 from .kv_pool import (TRASH_PAGE, PagedKVPool, StateSlotStore,
@@ -232,20 +233,21 @@ class Engine:
         self.page_quant = page_quant
         # a hybrid stack (cfg.layer_pattern) keeps K/V for its attention
         # layers only and a recurrent-state slot per running sequence for
-        # its mamba2 layers.  What is not built for recurrent state is
+        # its mamba2 / mamba1 layers.  What is not built for recurrent state is
         # refused here, not run wrong: a cached prefix would need the
         # state AT the cached boundary, a rejected draft a roll-back
         self.hybrid = cfg.is_hybrid
-        if self.hybrid and cfg.layers_of("mamba2"):
+        kind = cfg.state_mixer if self.hybrid else None
+        if kind:
             if prefix_cache:
                 raise ValueError(
                     "prefix_cache=True is not built for a stack with "
-                    "recurrent (mamba2) layers: a cached page prefix "
+                    f"recurrent ({kind}) layers: a cached page prefix "
                     "carries no state snapshot — pass prefix_cache=False")
             if spec is not None:
                 raise ValueError(
                     "speculative decoding is not built for a stack with "
-                    "recurrent (mamba2) layers: a rejected draft cannot "
+                    f"recurrent ({kind}) layers: a rejected draft cannot "
                     "be rolled back out of the state")
         # a block-wise model (cfg.diffusion_block, DESIGN.md §29): a
         # generating request's step is its open block, denoised in passes
@@ -319,12 +321,19 @@ class Engine:
                                 window_pages=window_pages or 0,
                                 window_tokens=self.window)
         self.state_store: Optional[StateSlotStore] = None
-        if cfg.layers_of("mamba2"):
+        if kind:
+            # a mamba1 layer's conv runs over its x channels alone and its
+            # scan state lies as the scan walks it
+            conv_dim, shape = (
+                cfg.mamba1_inner, state_shape(cfg.mamba1_inner,
+                                              cfg.mamba_state_dim)) \
+                if kind == "mamba1" else (
+                    cfg.mamba_conv_dim, (cfg.mamba_num_heads,
+                                         cfg.mamba_head_dim,
+                                         cfg.mamba_state_dim))
             self.state_store = StateSlotStore(
-                len(cfg.layers_of("mamba2")), int(max_batch),
-                cfg.mamba_conv_kernel, cfg.mamba_conv_dim,
-                cfg.mamba_num_heads, cfg.mamba_head_dim,
-                cfg.mamba_state_dim, conv_dtype=dtype)
+                len(cfg.layers_of(kind)), int(max_batch),
+                cfg.mamba_conv_kernel, conv_dim, shape, conv_dtype=dtype)
             self.pool.state_slots = self.state_store
         # copy-on-write prefix reuse: finished requests' full pages are
         # indexed by chained token hash; _start attaches the longest
@@ -391,7 +400,7 @@ class Engine:
                           "preempted_standard", "preempted_batch",
                           "host_evictions", "host_hits",
                           "host_refetch_bytes",
-                          # state slots handed out (zero without mamba2)
+                          # state slots handed out (zero without state layers)
                           "state_slot_allocs",
                           # what the steps read (serving/step_account)
                           *COUNTERS,

@@ -81,8 +81,10 @@ class StateSlotStore:
 
     A page holds a few tokens' K/V and a sequence grows into more of
     them; a slot holds a sequence's WHOLE recurrent state (the conv's
-    last ``K - 1`` inputs and the ``[heads, head_dim, state]`` float32
-    scan state of each mamba2 layer) and never grows.  The engine owns
+    last ``K - 1`` inputs and the float32 scan state of each state-space
+    layer: ``[heads, head_dim, state]`` for mamba2, ``[state, channels /
+    128, 128]`` for mamba1 — the layout its scan walks,
+    ``ops.selective_scan.state_shape``) and never grows.  The engine owns
     the store beside the pool and moves a request's slot with its pages:
     allocated at admission, freed at finish, at preemption (recompute:
     the state is dropped and the sequence re-prefilled) and at abort.
@@ -90,16 +92,17 @@ class StateSlotStore:
     token sits at position 0 from zeros whatever its slot holds, so a
     slot's old content cannot reach the sequence that takes it next.
     A decode step reads and writes, in place, the scan state of the slots
-    that have a live decode row (``ops.ssd.ssd_decode_slots``) and of no
+    that have a live decode row (``ops.ssd.ssd_decode_slots``,
+    ``ops.selective_scan.selective_scan_slots``) and of no
     other: a slot that is free, or whose sequence is waiting or
     prefilling, keeps its bytes untouched.
 
     ``conv`` / ``ssm`` are tuples of per-layer arrays ``[slots, K - 1,
-    conv_dim]`` / ``[slots, heads, head_dim, state]``; the jitted step
-    takes them donated and returns them (``set_arrays``)."""
+    conv_dim]`` / ``[slots, *state_shape]``; the jitted step takes them
+    donated and returns them (``set_arrays``)."""
 
     def __init__(self, num_layers: int, num_slots: int, conv_kernel: int,
-                 conv_dim: int, heads: int, head_dim: int, state_dim: int,
+                 conv_dim: int, state_shape: Sequence[int],
                  conv_dtype=jnp.float32):
         if num_slots < 1:
             raise ValueError(f"num_slots must be >= 1, got {num_slots}")
@@ -108,7 +111,7 @@ class StateSlotStore:
             jnp.zeros((num_slots, conv_kernel - 1, conv_dim), conv_dtype)
             for _ in range(num_layers))
         self.ssm: Tuple[jax.Array, ...] = tuple(
-            jnp.zeros((num_slots, heads, head_dim, state_dim), jnp.float32)
+            jnp.zeros((num_slots,) + tuple(state_shape), jnp.float32)
             for _ in range(num_layers))
         self._free: List[int] = list(range(num_slots - 1, -1, -1))
         self._owner: Dict[int, int] = {}          # slot -> req_id

@@ -25,6 +25,9 @@ from .decode import _regions
 COUNTERS = (
     # mamba2: slots the decode recurrence walked (live decode rows) / held
     "ssm_slots_walked", "ssm_slots_store",
+    # mamba1: live tokens the chunk scan walked / the tokens its chunk
+    # slots hold (what a scan padded to the slot would walk)
+    "ssm_chunk_tokens_walked", "ssm_chunk_tokens_padded",
     # experts: live (token, expert) assignments on the held experts / over
     # all experts; rows the grouped kernel computed
     "moe_assignments_local", "moe_assignments_total", "moe_block_rows",
@@ -125,7 +128,7 @@ class StepAccount:
                  spec_k: int, counters, gauges):
         self.cfg, self.pool, self.state_store = cfg, pool, state_store
         self.counters, self.gauges = counters, gauges
-        self.max_batch = scheduler.max_batch
+        self.max_batch, self.chunk = scheduler.max_batch, scheduler.chunk
         self.vbase = scheduler.max_batch + scheduler.prefill_rows
         geo = cfg.mixer_geometry or {}
         self.index_topk = geo["dsa"].index_topk if "dsa" in geo else 0
@@ -175,7 +178,8 @@ class StepAccount:
                 lambda width, n: -(-width // INDEX_SELECT_BLOCK)
                 if width > 1 else 0)
         self.parts = tuple(part for on, part in (
-            (cfg.layers_of("mamba2"), self._state),
+            (cfg.state_mixer, self._state),
+            (cfg.layers_of("mamba1"), self._scan),
             (cfg.layers_of("moe"), self._moe),
             (full is not None, self._kv),
             (cfg.layers_of("mla"), self._latent),
@@ -198,6 +202,23 @@ class StepAccount:
         self.counters["ssm_slots_walked"].inc(
             sum(row < self.max_batch for row in st.row))
         self.counters["ssm_slots_store"].inc(store.num_slots)
+
+    def _scan(self, st, out, traced):
+        """The selective scan's walks (one mamba1 layer's): the live
+        tokens of the chunk rows, their slots' width, the decode rows; and
+        what the attention layers beside it read."""
+        chunk = [q for q, row in zip(st.q, st.row)
+                 if self.max_batch <= row < self.vbase]
+        walked, padded = sum(chunk), len(chunk) * self.chunk
+        self.counters["ssm_chunk_tokens_walked"].inc(walked)
+        self.counters["ssm_chunk_tokens_padded"].inc(padded)
+        if not traced:
+            return None
+        return dict(ssm_chunk_tokens=walked, ssm_chunk_padded=padded,
+                    ssm_chunk_rows=len(chunk),
+                    ssm_decode_rows=len(st.q) - len(chunk),
+                    attn_pairs=st.pairs,
+                    kv_pages_distinct=st.distinct_pages)
 
     def _moe(self, st, out, traced):
         load = out["moe_load"]

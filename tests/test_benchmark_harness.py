@@ -281,12 +281,13 @@ def test_new_layer_metric_names_a_reader_and_a_cell(name):
     # configuration's own metrics list its cell alone)
     own = {"nemotron3s-ep4.serve-chat", "mistral4-ep4.serve-longdoc",
            "dots3-ep8.serve-longctx", "kexaone-ep8.serve-reason",
-           "sdar30b-pp8.serve-chat"}
+           "sdar30b-pp8.serve-chat", "jamba2-3b.serve-longdoc"}
     family = {"chat": {"cgpt590m.serve-chat", "nemotron3s-ep4.serve-chat",
                        "kexaone-ep8.serve-reason", "sdar30b-pp8.serve-chat"},
               "replay": {"cgpt590m.serve-prefix",
                          "mistral4-ep4.serve-longdoc",
-                         "dots3-ep8.serve-longctx"},
+                         "dots3-ep8.serve-longctx",
+                         "jamba2-3b.serve-longdoc"},
               "train": {"cgpt590m.train", "cgpt1.3b.train-dp4z3"}}[suffix]
     assert set(entry["workloads"]) <= family
     # (trace_step_edges: the dense cells' traced run, 287 s of the

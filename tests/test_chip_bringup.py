@@ -35,6 +35,7 @@ fa = importlib.import_module("hetu_tpu.ops.pallas.flash_attention")
 mg = importlib.import_module("hetu_tpu.ops.moe_grouped")
 ssd = importlib.import_module("hetu_tpu.ops.ssd")
 ix = importlib.import_module("hetu_tpu.ops.index_score")
+sscan = importlib.import_module("hetu_tpu.ops.selective_scan")
 
 I32, BF16, F32 = jnp.int32, jnp.bfloat16, jnp.float32
 # the serving step's ragged batch at GPT-2 124M widths: 16 decode rows +
@@ -137,6 +138,24 @@ def _kernel_cases():
         return ssd.ssd_decode_slots(x, dt, a, b, c, d, store, slots, n,
                                     fresh, interpret=False)
 
+    def scan_chunk(x, dt, a, b, c, d, store, slot, n, fresh):
+        # a mamba1 layer's selective scan over a 1,024-token chunk at the
+        # whole-model configuration's widths: 5,120 channels x 16 states,
+        # the row's state in its slot of a 16-slot store; 64 tokens of x,
+        # dt and y in VMEM at a time, B and C as scalars out of SMEM
+        return sscan.selective_scan_chunk(x, dt, a, b, c, d, store, slot, n,
+                                          fresh, interpret=False)
+
+    def scan_slots(x, dt, a, b, c, d, store, slots, n, fresh):
+        # ... and its one-token walk over the live slots
+        return sscan.selective_scan_slots(x, dt, a, b, c, d, store, slots,
+                                          n, fresh, interpret=False)
+
+    def scan_args(t, *tail):
+        return (_sds((t, 5120), F32), _sds((t, 5120), F32),
+                _sds((16, 5120), F32), _sds((t, 16), F32), _sds((t, 16), F32),
+                _sds((5120,), F32), _sds((16, 16, 40, 128), F32), *tail)
+
     def index_score(rows, shared):
         # a full layer's indexer over the indexed / window latent
         # configuration's step: 64 index heads x 128, 528 slots of 64 a
@@ -206,6 +225,14 @@ def _kernel_cases():
             _sds((64, 8, 128), F32), _sds((64, 8, 128), F32),
             _sds((128,), F32), _sds((64, 128, 64, 128), F32),
             _sds((64,), I32), _sds((1,), I32), _sds((64,), jnp.bool_))),
+        "selective_scan_chunk": (scan_chunk, scan_args(
+            1024, _sds((), I32), _sds((), I32), _sds((), jnp.bool_))),
+        "selective_scan_slots": (scan_slots, scan_args(
+            16, _sds((16,), I32), _sds((1,), I32), _sds((16,), jnp.bool_))),
+        # 20 query heads on ONE kv head at a 1,024-token chunk: the window
+        # is cut in two (ragged_paged_attention.window_split)
+        "ragged_chunk_mqa20_x528": region(1, 1024, 20, 1, 528),
+        "ragged_decode_mqa20_x528": region(16, 1, 20, 1, 528),
         "index_score_chunk": index_score(CHUNK, True),
         "index_score_decode": index_score(32, False),
         "moe_grouped_gated_tiled": (grouped_gated, (
@@ -234,7 +261,11 @@ def _kernel_cases():
 # beside a 256-token chunk's score tiles (35 MB, four heads unrolled);
 # the indexer's scoring call: a chunk's two float32 tiles of 4096 x 1024
 # (32 MB) and a head-major sum over whole tiles, a decode row's 64-row
-# operand summed over sublanes into a one-row output block
+# operand summed over sublanes into a one-row output block; the selective
+# scan: blocks of B and C scalars in SMEM (whole 1,024-word tiles), 16
+# state registers a channel group over a token loop; the K/V call of 20
+# query heads on one kv head, whose 1,024-token window runs out of VMEM
+# uncut
 AOT_CASES = ("ragged_12kv_x64", "ragged_decode_region",
              "ragged_chunk_region", "ragged_decode_gqa16",
              "ragged_verify_gqa8_x272", "ragged_chunk_gqa8_x272",
@@ -242,7 +273,9 @@ AOT_CASES = ("ragged_12kv_x64", "ragged_decode_region",
              "latent_nf4", "kv_write_12kv_x128", "kv_write_int8_sidecar",
              "flash_qkv", "moe_grouped_experts", "moe_grouped_gated_tiled",
              "latent_256_128_chunk_region", "ssd_decode_slots",
-             "index_score_chunk", "index_score_decode")
+             "index_score_chunk", "index_score_decode",
+             "selective_scan_chunk", "selective_scan_slots",
+             "ragged_chunk_mqa20_x528")
 
 
 @pytest.fixture

@@ -504,7 +504,7 @@ def test_slot_store_keeps_its_invariants_under_the_protocol_chaos_trace():
     partition the store after every event."""
     from hetu_tpu.analysis.protocol import fuzz_trace
     events = fuzz_trace(seed=0, n_events=300)
-    store = StateSlotStore(2, 8, 4, 12, 2, 4, 4)
+    store = StateSlotStore(2, 8, 4, 12, (2, 4, 4))
     held = {}
     for e in events:
         if e.kind == "req.admit" and e.key not in held:
