@@ -29,10 +29,26 @@ a lane block of a wider array and is transposed to [b*h, s, d] round the
 call ("head_major").  One grid, one set of kernel bodies; the path is
 chosen by the shape alone and counted in ``obs.counts("flash_calls")``.
 Causal masking is block-skipped (fully-masked kv blocks are not
-computed).  ``segment_ids`` gives packed/varlen semantics (the
-cu_seqlens path of the reference, ``ops/Attention.h:286``).  Narrow
-(8-lane) layouts are used for the lse / delta / q-segment operands — not
-full 128-lane broadcasts.
+computed), and which of the other blocks is masked is a BRANCH, not a
+value: ``_tiles`` runs a kernel's whole compute body under one of two
+``pl.when`` — a block below the diagonal unmasked, a block the diagonal
+crosses under the iota mask — where the seed wrapped the mask alone in a
+``lax.cond`` over the [bq, bk] float32 scores.  That ``cond`` stood
+between the first matmul and the softmax of EVERY block and cost the
+forward 45 % and the fused backward 25 % of their time at the train
+cells' shape on a v5e ([4, 2048, 12 x 128] bf16: 1.182 -> 0.649 and
+1.798 -> 1.340 ms a call; PERF.md, PR 54).  A diagonal block is still
+computed WHOLE: walking it in sub-blocks of q rows, each against the kv
+columns it can see (3 of 4 or 10 of 16 sub-tiles), read 0.717 - 0.758 ms
+forward and 1.297 - 1.355 backward, sub-blocks of kv columns 0.825: the
+narrower matmuls and the extra passes over the softmax state cost what
+the masked area saves (``_chip/step0_54.py``; the walk is
+``_chip/walk_54.patch``).  The same holds for ring attention's shifted
+diagonal (``causal_offset``), unequal blocks and the single-kv-block
+fast path, whose one block is always masked.
+``segment_ids`` gives packed/varlen semantics (the cu_seqlens path of the
+reference, ``ops/Attention.h:286``).  Narrow (8-lane) layouts are used
+for the lse / delta / q-segment operands — not full 128-lane broadcasts.
 
 On CPU the kernel runs in interpret mode so the whole path is testable on
 the simulated mesh (SURVEY.md §4 takeaway).
@@ -199,22 +215,61 @@ def _dim_semantics(*sem):
 
 
 def _nosegs_kernel(kernel, *refs, **kw):
-    """Adapter: invoke a seg-aware kernel with no segment operands
-    (use_segs=False guarantees the seg refs are never read)."""
+    """Adapter: invoke a seg-aware kernel with no segment operands (the
+    kernels read ``q_seg_ref is None`` as "not packed")."""
     return kernel(None, None, *refs, **kw)
 
 
-def _causal_mask(s, q_idx, kv_idx, bq, bk, offset):
-    """Apply the causal mask to a score block — diag-specialized (fa2
-    sweep): blocks fully below the diagonal skip the iota mask entirely,
-    so half the causal blocks pay zero masking VPU work.  Shared by all
-    four kernels so fwd/bwd masking can never desynchronize."""
-    def _masked(sv):
-        rows = q_idx * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        cols = kv_idx * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        return jnp.where(cols <= rows + offset, sv, DEFAULT_MASK_VALUE)
-    is_diag = kv_idx * bk + bk - 1 > q_idx * bq + offset
-    return lax.cond(is_diag, _masked, lambda sv: sv, s)
+def _causal_mask(s, q_idx, kv_idx, offset):
+    """The causal mask of a [bq, bk] score block that crosses the
+    diagonal.  Shared by all four kernels so fwd/bwd masking can never
+    desynchronize."""
+    bq, bk = s.shape
+    row = lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+    col = lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    if offset == 0 and bq == bk:
+        # the block ON an unshifted diagonal: the same triangle whatever
+        # its indices (an operation a score element less: the kernels are
+        # bound by the VPU's passes, and the forward read 0.649 ms a call
+        # for 0.726 with the scalar below)
+        keep = col <= row
+    else:
+        keep = col - row <= q_idx * bq + offset - kv_idx * bk
+    return jnp.where(keep, s, DEFAULT_MASK_VALUE)
+
+
+def _tiles(causal, offset, q_idx, kv_idx, bq, bk, tile):
+    """Run ``tile(masked)`` where this grid step's [bq, bk] score block
+    holds a visible position: a block below the diagonal unmasked, a block
+    the diagonal crosses under ``_causal_mask``, a block past it not at
+    all.  The two are BRANCHES (``pl.when``), each a straight line from
+    the scores to the accumulators, and not a ``lax.cond`` round the mask
+    (module docstring)."""
+    if not causal:
+        tile(False)
+        return
+    # offset shifts the diagonal right: rows are offset global positions
+    # ahead of cols — the SYM tail-half case
+    first_row, first_col = q_idx * bq + offset, kv_idx * bk
+    crossed = first_col + bk - 1 > first_row      # some position is masked
+    visible = first_col <= first_row + bq - 1     # and some is not
+    pl.when(jnp.logical_not(crossed))(lambda: tile(False))
+    pl.when(jnp.logical_and(crossed, visible))(lambda: tile(True))
+
+
+def _score_tile(q, k, q_seg_ref, kv_seg_ref, masked, q_idx, kv_idx, offset):
+    """Base-2 logits of a q block against a kv block, causal (``masked``)
+    and segment masks applied."""
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    if masked:
+        s = _causal_mask(s, q_idx, kv_idx, offset)
+    if q_seg_ref is not None:
+        # narrow-lane q ids against sublane-padded kv ids
+        seg_ok = (q_seg_ref[0, :, 0][:, None]
+                  == kv_seg_ref[0, 0, :][None, :])
+        s = jnp.where(seg_ok, s, DEFAULT_MASK_VALUE)
+    return s
 
 
 def _block_sizes(s: int, d: int, dtype, role: str = "fwd"
@@ -256,32 +311,23 @@ def _fwd_kernel(q_seg_ref, kv_seg_ref, q_ref, k_ref, v_ref,  # inputs
                 o_ref, lse_ref,                              # outputs
                 qs_ref, acc_ref, m_ref, l_ref,               # scratch
                 *, qscale: float, causal: bool, offset: int, bq: int,
-                bk: int, num_kv: int, use_segs: bool):
+                bk: int, num_kv: int):
     # the q block is scaled by softmax_scale * LOG2E once, at its first
     # kv block (qs_ref): scores are base-2 logits and all exps are exp2
     # (see module constant note).
     q_idx = pl.program_id(2)
     kv_idx = pl.program_id(3)
+    use_segs = q_seg_ref is not None
 
-    def _scores(q):
-        k = k_ref[0]                       # [bk, d]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [bq, bk] base-2
-        if causal:
-            s = _causal_mask(s, q_idx, kv_idx, bq, bk, offset)
-        if use_segs:
-            qs = q_seg_ref[0, :, 0]        # [bq] (narrow-lane layout)
-            ks = kv_seg_ref[0, 0, :]       # [bk] (sublane-padded layout)
-            seg_ok = qs[:, None] == ks[None, :]
-            s = jnp.where(seg_ok, s, DEFAULT_MASK_VALUE)
-        return s
+    def _scores(q, masked):
+        return _score_tile(q, k_ref[0], q_seg_ref, kv_seg_ref, masked, q_idx,
+                           kv_idx, offset)
 
     if num_kv == 1 and (not causal or offset == 0):
         # single-kv-block fast path (the whole kv sequence is one block,
         # and the block is never fully skipped): no online-softmax carry,
         # no scratch traffic, outputs written directly
-        s = _scores(_scaled(q_ref, qscale))
+        s = _scores(_scaled(q_ref, qscale), causal)
         m = jnp.max(s, axis=1)
         p = jnp.exp2(s - m[:, None])
         l = jnp.sum(p, axis=1)             # >= 1: exp2(0) at the max
@@ -307,16 +353,8 @@ def _fwd_kernel(q_seg_ref, kv_seg_ref, q_ref, k_ref, v_ref,  # inputs
         m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    # block-level causal skip: kv block strictly after q block -> no
-    # work (offset shifts the diagonal right: rows are offset global
-    # positions ahead of cols — the SYM tail-half case)
-    run = True
-    if causal:
-        run = kv_idx * bk <= q_idx * bq + bq - 1 + offset
-
-    @pl.when(run)
-    def _compute():
-        s = _scores(qs_ref[:])
+    def _compute(masked):
+        s = _scores(qs_ref[:], masked)
         m_prev = m_ref[:, 0]               # [bq]
         m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
         p = jnp.exp2(s - m_cur[:, None])
@@ -327,6 +365,8 @@ def _fwd_kernel(q_seg_ref, kv_seg_ref, q_ref, k_ref, v_ref,  # inputs
             p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[:] = jnp.broadcast_to(m_cur[:, None], m_ref.shape)
+
+    _tiles(causal, offset, q_idx, kv_idx, bq, bk, _compute)
 
     @pl.when(kv_idx == num_kv - 1)
     def _finalize():
@@ -361,7 +401,7 @@ def _fwd_call(lay, q, k, v, heads0, sq, sk, scale, causal, segment_ids,
 
     kernel = functools.partial(
         _fwd_kernel, qscale=scale * LOG2E, causal=causal,
-        offset=causal_offset, bq=bq, bk=bk, num_kv=num_kv, use_segs=use_segs)
+        offset=causal_offset, bq=bq, bk=bk, num_kv=num_kv)
     if not use_segs:
         kernel = functools.partial(_nosegs_kernel, kernel)
 
@@ -407,12 +447,28 @@ def _flash_fwd(q, k, v, scale, causal, segment_ids, causal_offset=0):
 # backward — fused single kernel (dq + dk + dv)
 # ---------------------------------------------------------------------------
 
+def _p_ds(q, k, v, do, lse, delta, q_seg_ref, kv_seg_ref, masked, q_idx,
+          kv_idx, offset):
+    """(p float32, ds in q's dtype) of one score tile: q already scaled
+    by softmax_scale * LOG2E and ``lse`` by LOG2E, so p = exp2(s2 - lse2)
+    with no per-element scale multiplies."""
+    s = _score_tile(q, k, q_seg_ref, kv_seg_ref, masked, q_idx, kv_idx,
+                    offset)
+    p = jnp.exp2(s - lse[:, None])
+    if q_seg_ref is not None or offset != 0:
+        # fully-skipped q rows carry lse == -inf (never occurs in the
+        # plain causal path — every row sees its diagonal)
+        p = jnp.where(jnp.isfinite(lse)[:, None], p, 0.0)
+    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    return p, (p * (dp - delta[:, None])).astype(q.dtype)
+
+
 def _bwd_fused_kernel(q_seg_ref, kv_seg_ref, q_ref, k_ref, v_ref, do_ref,
                       o_ref, lse_ref,
                       dq_ref, dk_ref, dv_ref,
                       qs_ref, dq_acc, dk_acc, dv_acc, delta_scr,
-                      *, scale, causal, offset, bq, bk, num_q, num_kv,
-                      use_segs):
+                      *, scale, causal, offset, bq, bk, num_q, num_kv):
     # the q block is scaled by softmax_scale * LOG2E at its first kv block
     # (qs_ref) and lse arrives pre-scaled by LOG2E, so p = exp2(s2 - lse2)
     # with no per-element scale multiplies; the deferred scales land on
@@ -435,45 +491,23 @@ def _bwd_fused_kernel(q_seg_ref, kv_seg_ref, q_ref, k_ref, v_ref, do_ref,
         delta = jnp.sum(do * o, axis=1)          # rowsum(do*o), in-kernel
         delta_scr[:] = jnp.broadcast_to(delta[:, None], delta_scr.shape)
 
-    # fully-masked (q, kv) block pairs contribute to none of dq/dk/dv
-    run = True
-    if causal:
-        run = kv_idx * bk <= q_idx * bq + bq - 1 + offset
-
-    @pl.when(run)
-    def _compute():
-        q = qs_ref[:]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if causal:
-            s = _causal_mask(s, q_idx, kv_idx, bq, bk, offset)
-        if use_segs:
-            seg_ok = (q_seg_ref[0, :, 0][:, None]
-                      == kv_seg_ref[0, 0, :][None, :])
-            s = jnp.where(seg_ok, s, DEFAULT_MASK_VALUE)
-        lse = lse_ref[0, :, 0]
-        p = jnp.exp2(s - lse[:, None])
-        if use_segs or offset != 0:
-            # fully-skipped q rows carry lse == -inf (never occurs in the
-            # plain causal path — every row sees its diagonal)
-            p = jnp.where(jnp.isfinite(lse)[:, None], p, 0.0)
-        dv_acc[pl.dslice(kv_idx * bk, bk), :] += jax.lax.dot_general(
+    def _compute(masked):
+        q, k, do = qs_ref[:], k_ref[0], do_ref[0]
+        p, ds = _p_ds(q, k, v_ref[0], do, lse_ref[0, :, 0], delta_scr[:, 0],
+                      q_seg_ref, kv_seg_ref, masked, q_idx, kv_idx, offset)
+        kv_rows = pl.dslice(kv_idx * bk, bk)
+        dv_acc[kv_rows, :] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        delta = delta_scr[:, 0]
-        ds = p * (dp - delta[:, None])
-        dsl = ds.astype(q.dtype)
         dq_acc[:] += jax.lax.dot_general(
-            dsl, k, (((1,), (0,)), ((), ())),
+            ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dk_acc[pl.dslice(kv_idx * bk, bk), :] += jax.lax.dot_general(
-            dsl, q, (((0,), (0,)), ((), ())),
+        dk_acc[kv_rows, :] += jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+
+    # fully-masked (q, kv) block pairs contribute to none of dq/dk/dv
+    _tiles(causal, offset, q_idx, kv_idx, bq, bk, _compute)
 
     @pl.when(kv_idx == num_kv - 1)
     def _fin_q():
@@ -505,7 +539,7 @@ def _bwd_fused_call(lay, q, k, v, heads0, do, out, lse, sq, sk, scale,
 
     kernel = functools.partial(
         _bwd_fused_kernel, scale=scale, causal=causal, offset=causal_offset,
-        bq=bq, bk=bk, num_q=num_q, num_kv=num_kv, use_segs=use_segs)
+        bq=bq, bk=bk, num_q=num_q, num_kv=num_kv)
     if not use_segs:
         kernel = functools.partial(_nosegs_kernel, kernel)
     return pl.pallas_call(
@@ -546,7 +580,7 @@ def _bwd_fused_call(lay, q, k, v, heads0, do, out, lse, sq, sk, scale,
 
 def _bwd_dq_kernel(q_seg_ref, kv_seg_ref, q_ref, k_ref, v_ref, do_ref,
                    lse_ref, delta_ref, dq_ref, qs_ref, dq_acc,
-                   *, scale, causal, offset, bq, bk, num_kv, use_segs):
+                   *, scale, causal, offset, bq, bk, num_kv):
     q_idx = pl.program_id(2)
     kv_idx = pl.program_id(3)
 
@@ -555,34 +589,16 @@ def _bwd_dq_kernel(q_seg_ref, kv_seg_ref, q_ref, k_ref, v_ref, do_ref,
         qs_ref[:] = _scaled(q_ref, scale * LOG2E)
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    run = True
-    if causal:
-        run = kv_idx * bk <= q_idx * bq + bq - 1 + offset
-
-    @pl.when(run)
-    def _compute():
-        q = qs_ref[:]
+    def _compute(masked):
         k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if causal:
-            s = _causal_mask(s, q_idx, kv_idx, bq, bk, offset)
-        if use_segs:
-            seg_ok = q_seg_ref[0, :, 0][:, None] == kv_seg_ref[0, 0, :][None, :]
-            s = jnp.where(seg_ok, s, DEFAULT_MASK_VALUE)
-        lse = lse_ref[0, :, 0]
-        p = jnp.exp2(s - lse[:, None])
-        if use_segs or offset != 0:
-            p = jnp.where(jnp.isfinite(lse)[:, None], p, 0.0)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        delta = delta_ref[0, :, 0]
-        ds = p * (dp - delta[:, None])
+        _, ds = _p_ds(qs_ref[:], k, v_ref[0], do_ref[0], lse_ref[0, :, 0],
+                      delta_ref[0, :, 0], q_seg_ref, kv_seg_ref, masked,
+                      q_idx, kv_idx, offset)
         dq_acc[:] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+
+    _tiles(causal, offset, q_idx, kv_idx, bq, bk, _compute)
 
     @pl.when(kv_idx == num_kv - 1)
     def _finalize():
@@ -591,7 +607,7 @@ def _bwd_dq_kernel(q_seg_ref, kv_seg_ref, q_ref, k_ref, v_ref, do_ref,
 
 def _bwd_dkv_kernel(q_seg_ref, kv_seg_ref, q_ref, k_ref, v_ref, do_ref,
                     lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc,
-                    *, scale, causal, offset, bq, bk, num_q, use_segs):
+                    *, scale, causal, offset, bq, bk, num_q):
     kv_idx = pl.program_id(2)
     q_idx = pl.program_id(3)
 
@@ -600,39 +616,22 @@ def _bwd_dkv_kernel(q_seg_ref, kv_seg_ref, q_ref, k_ref, v_ref, do_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    run = True
-    if causal:
-        # q block strictly before kv block -> fully masked
-        run = q_idx * bq + bq - 1 + offset >= kv_idx * bk
-
-    @pl.when(run)
-    def _compute():
+    def _compute(masked):
         # a new q block every step here: scaled where it is used
         q = _scaled(q_ref, scale * LOG2E)
-        k = k_ref[0]
-        v = v_ref[0]
         do = do_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if causal:
-            s = _causal_mask(s, q_idx, kv_idx, bq, bk, offset)
-        if use_segs:
-            seg_ok = q_seg_ref[0, :, 0][:, None] == kv_seg_ref[0, 0, :][None, :]
-            s = jnp.where(seg_ok, s, DEFAULT_MASK_VALUE)
-        lse = lse_ref[0, :, 0]
-        p = jnp.exp2(s - lse[:, None])
-        if use_segs or offset != 0:
-            p = jnp.where(jnp.isfinite(lse)[:, None], p, 0.0)
+        p, ds = _p_ds(q, k_ref[0], v_ref[0], do, lse_ref[0, :, 0],
+                      delta_ref[0, :, 0], q_seg_ref, kv_seg_ref, masked,
+                      q_idx, kv_idx, offset)
         dv_acc[:] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        delta = delta_ref[0, :, 0]
-        ds = p * (dp - delta[:, None])
         dk_acc[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+
+    # (a q block strictly before the kv block is fully masked)
+    _tiles(causal, offset, q_idx, kv_idx, bq, bk, _compute)
 
     @pl.when(q_idx == num_q - 1)
     def _finalize():
@@ -662,7 +661,7 @@ def _bwd_split_call(lay, q, k, v, heads0, do, out, lse, sq, sk, scale,
 
     dq_kernel = functools.partial(
         _bwd_dq_kernel, scale=scale, causal=causal, offset=causal_offset,
-        bq=bq, bk=bk, num_kv=num_kv, use_segs=use_segs)
+        bq=bq, bk=bk, num_kv=num_kv)
     if not use_segs:
         dq_kernel = functools.partial(_nosegs_kernel, dq_kernel)
     dq = pl.pallas_call(
@@ -689,7 +688,7 @@ def _bwd_split_call(lay, q, k, v, heads0, do, out, lse, sq, sk, scale,
 
     dkv_kernel = functools.partial(
         _bwd_dkv_kernel, scale=scale, causal=causal, offset=causal_offset,
-        bq=bq, bk=bk, num_q=num_q, use_segs=use_segs)
+        bq=bq, bk=bk, num_q=num_q)
     if not use_segs:
         dkv_kernel = functools.partial(_nosegs_kernel, dkv_kernel)
     # grid (b, h, kv, q): the kv block index is g[0], the q block's g[1]

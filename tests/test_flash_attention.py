@@ -226,6 +226,91 @@ class TestBothLayouts:
         assert moved == []
 
 
+def _dense(q, k, v, offset, q_ids, kv_ids):
+    """(out, lse) of causal attention with the diagonal shifted right by
+    ``offset`` and (q, kv) segment ids, through the plain reference: the
+    segment mask goes in as its ``bias``."""
+    bias = jnp.where(q_ids[:, None, :, None] == kv_ids[:, None, None, :],
+                     0.0, -jnp.inf)
+    out = sdpa_reference(q, k, v, causal=True, bias=bias)
+    assert k.shape[1] - q.shape[1] == offset    # the reference's own shift
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    qi = jnp.arange(q.shape[1])[:, None] + offset
+    logits = jnp.where(jnp.arange(k.shape[1])[None, :] <= qi, logits + bias,
+                       -jnp.inf)
+    return out, jax.nn.logsumexp(logits, axis=-1)
+
+
+class TestBlockGrid:
+    """Grids of 2 x 2 and 2 x 3 blocks of 512: blocks below the diagonal,
+    blocks it crosses (shifted or not) and blocks past it, in all four
+    kernels — the file's other cases run one or two blocks a sequence."""
+
+    @head_dims
+    @pytest.mark.parametrize("packed", [False, True])
+    @pytest.mark.parametrize("split", [False, True])
+    @pytest.mark.parametrize("offset", [0, 512])
+    def test_matches_reference(self, d, packed, split, offset, monkeypatch):
+        monkeypatch.setenv("HETU_TPU_FLASH_BLOCK_FWD", "512")
+        if split:
+            monkeypatch.setattr(fa, "_FUSED_DKV_VMEM_BYTES", 0)
+        sk = 1024 + offset
+        q, k, v = _mk(b=1, s=sk, h=2, d=d)
+        q = q[:, offset:]
+        # documents that end inside blocks, one of them past the offset
+        ends = (300, 724, 1100, sk) if packed else (sk,)
+        kv_ids = jnp.asarray(np.searchsorted(ends, np.arange(sk),
+                                             side="right")[None], jnp.int32)
+        segs = (kv_ids[:, offset:], kv_ids) if packed else None
+        scale = 1.0 / np.sqrt(d)
+
+        out, lse = fa._flash_fwd(q, k, v, scale, True, segs,
+                                 causal_offset=offset)
+        do = jnp.asarray(np.random.RandomState(1).randn(*out.shape),
+                         jnp.float32)
+        got = fa._flash_bwd(scale, True, segs, (q, k, v, out, lse), do,
+                            causal_offset=offset)
+
+        (ref, ref_lse), vjp = jax.vjp(
+            lambda *a: _dense(*a, offset, kv_ids[:, offset:], kv_ids),
+            q, k, v)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=1e-4, atol=1e-4)
+        np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse),
+                                   rtol=1e-4, atol=1e-4)
+        _assert_grads(got, vjp((do, jnp.zeros_like(ref_lse))))
+
+
+    @pytest.mark.parametrize("split", [False, True])
+    def test_no_cond_carries_a_score_tile(self, split, monkeypatch):
+        """Which blocks are masked is a branch round a kernel's whole
+        compute body (``pl.when``: a ``cond`` over refs), never a ``cond``
+        that takes the [bq, bk] scores in and hands them back: that one
+        cost the forward 45 % of its time on a v5e (module docstring)."""
+        monkeypatch.setenv("HETU_TPU_FLASH_BLOCK_FWD", "512")
+        if split:
+            monkeypatch.setattr(fa, "_FUSED_DKV_VMEM_BYTES", 0)
+        q, k, v = _mk(b=1, s=1024, h=1, d=128)
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda *a: _sq(flash_attention(*a)), argnums=(0, 1, 2)))(q, k, v)
+
+        def conds(jp):
+            for e in jp.eqns:
+                if e.primitive.name == "cond":
+                    yield e
+                for sub in jax.core.jaxprs_in_params(e.params):
+                    yield from conds(sub)
+
+        found = list(conds(jaxpr.jaxpr))
+        # forward: init, below, crossed, finalize; backward: six, or 4 + 4
+        assert len(found) == (12 if split else 10)
+        for e in found:
+            tiles = [v.aval for v in (*e.invars, *e.outvars)
+                     if isinstance(v.aval, jax.core.ShapedArray)
+                     and v.aval.ndim == 2 and v.aval.size >= 512 * 512]
+            assert tiles == [], tiles
+
+
 @pytest.mark.slow
 class TestFlashBackward:
     def test_grads_match_reference(self):
