@@ -97,12 +97,14 @@ def _chunk_slots(max_seqs: int, prefill_rows: int, chunk: int,
     verify traffic never competes with prompt prefills for slots.  A
     model that generates by diffusion over blocks (``block`` positions,
     ``GPTConfig.diffusion_block``) owns the same ``max_seqs`` narrow slots
-    at width ``block``: a generating row's step is its open block."""
+    at width ``2 * block``: a generating row's step is its open block in
+    the slot's first half, or (a FUSED row) the block it commits and,
+    behind it, the next one's first denoise pass."""
     slots = [(max_seqs + r, max_seqs + r * chunk, chunk)
              for r in range(prefill_rows)]
     if spec_k or block:
         base = max_seqs + prefill_rows * chunk
-        vk = block or spec_k + 1
+        vk = 2 * block or spec_k + 1
         slots += [(max_seqs + prefill_rows + j, base + j * vk, vk)
                   for j in range(max_seqs)]
     return slots
@@ -309,7 +311,9 @@ class StepLayout:
                            (a block-wise model, ``cfg.diffusion_block``: how
                            many of a block row's masked positions this pass
                            unmasks by rank — of confidence, or, negative, of
-                           position; 0 on a commit pass — and the confidence
+                           position; 0 on a plain commit pass, the next
+                           block's first pass's on a fused row (``q_lens``
+                           2B) — and the confidence
                            above which a masked position is unmasked
                            whatever its rank, 2.0 where the rule is static:
                            ``serving/request.py::DenoiseRule.unmask``)
@@ -331,7 +335,8 @@ class StepLayout:
     stack; a self-drafting build adds ``mtp_load``, its MTP module's),
     then ``accepted [r]`` (a speculative build), then ``draft [r]`` (a
     self-drafting build: the token its MTP module proposes behind the
-    row's last committed one); a block-wise model adds, a block slot,
+    row's last committed one); a block-wise model adds, a block slot and
+    of its OPEN block (a fused row's second half),
     ``block_tokens [max_seqs, B]`` (the pass's choice at a masked
     position, the fed token elsewhere), ``block_flags [max_seqs]`` (bit
     ``j``: position ``j`` was unmasked by this pass) and ``block_conf
@@ -447,7 +452,7 @@ def _attend_by_region(kernel, name: str, q, q_lens, cu_q, page_tables,
     slice of the token and row axes with ``cu_q`` rebased to the slice,
     and the outputs are concatenated on the token axis.  On the device
     trace the calls are ``<name>_decode`` / ``_chunk`` / ``_verify`` /
-    ``_block`` (a block-wise model's generating rows, width ``block``).
+    ``_block`` (a block-wise model's generating rows, two blocks wide).
     ``kv_base [rows]`` (a window layer's ``kernel``: the position each
     row's table starts at) is sliced like the other per-row arrays."""
     outs = []
@@ -858,13 +863,18 @@ def build_unified_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
     return run
 
 
-def _block_head(cfg: GPTConfig, p, x, tokens, token_pos, live, sampling,
-                unmask_k, unmask_tau):
+def _block_head(cfg: GPTConfig, p, x, tokens, token_pos, live, q_lens,
+                sampling, unmask_k, unmask_tau):
     """The head of a block-wise model's step over its block slots: ``x [S
-    * B, H]`` (normed), the fed ``tokens`` and their positions, ``live``
-    (the slot holds a row), the slots' ``(temps, top_ps, top_ks, seeds)``
-    and unmask rule, all ``[S]``.  Logits at every block position (they
-    score the token AT the position), the mask id's left out (a position
+    * 2B, H]`` (normed), the fed ``tokens`` and their positions, ``live``
+    (the slot holds a row there), the slots' ``q_lens``, ``(temps, top_ps,
+    top_ks, seeds)`` and unmask rule, all ``[S]``.  Logits at the OPEN
+    block's B positions of each slot and nowhere else: the slot's first B
+    for a plain row, its second B for a FUSED one (``q_len`` 2B: the
+    committed block's clean tokens, whose K/V the pass writes and whose
+    tokens nothing reads, then the next block all masks; the rule a fused
+    row sends is pass 0's of that next block).  The logits
+    score the token AT the position, the mask id's left out (a position
     is never unmasked INTO the mask id: with seeded weights it would be the
     arg-max once in a vocabulary's worth of positions, and the block would
     never close); ``x0`` from the repo's one per-row
@@ -875,12 +885,22 @@ def _block_head(cfg: GPTConfig, p, x, tokens, token_pos, live, sampling,
     of highest ``c`` (a stable sort: ties to the lower position; fewer
     where fewer are masked) or, ``unmask_k < 0``, of lowest position (the
     sequential rule) — together with every masked one whose ``c >
-    unmask_tau``.  A commit pass sends 0 and 2.0 and holds no mask:
+    unmask_tau``.  A plain commit pass sends 0 and 2.0 and holds no mask:
     nothing is selected.
     Returns the layout's ``block_tokens`` (``x0`` where masked, the fed
-    token elsewhere), ``block_flags`` and ``block_conf`` (bit pattern)."""
+    token elsewhere), ``block_flags`` and ``block_conf`` (bit pattern), of
+    the open block."""
     b = cfg.diffusion_block
     n = unmask_k.shape[0]
+
+    def open_block(a):
+        halves = a.reshape((n, 2, b) + a.shape[1:])
+        fused = (q_lens > b).reshape((n,) + (1,) * a.ndim)
+        return jnp.where(fused, halves[:, 1], halves[:, 0]).reshape(
+            (n * b,) + a.shape[1:])
+
+    x, tokens, token_pos, live = (
+        open_block(a) for a in (x, tokens, token_pos, live))
     head = p("lm_head.weight")
     head = head if head is not None else p("wte.weight")
     # the stack's dtype on both sides, float32 sums: no float32 copy of a
@@ -1017,16 +1037,23 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
 
     ``cfg.diffusion_block`` = B (BLOCK-WISE generation, DESIGN.md §29): the
     narrow slots behind the chunk slots are the BLOCK slots, ``max_seqs``
-    rows of B positions on the verify slots' scaffolding (the same
+    rows of 2B positions on the verify slots' scaffolding (the same
     regions, write plan and one pass over the weights with the decode
     slots).  A generating row feeds its open block — known tokens, and
     ``cfg.mask_token_id`` where a position is not yet known — at the
-    block's positions; every layer writes the pass's K/V under the row's
+    block's positions, the slot's first B (``q_len`` B: the second half is
+    dead, as the tail of a part-filled chunk — routed to no expert,
+    written to no page, skipped by the kernel); every layer writes the
+    pass's K/V under the row's
     own pages there (a later pass overwrites it, as a rejected draft's;
     the commit pass's stands) and attends under the block-wise mask
-    (``mask_block=B``: every region, the prompt's chunks too).  The step
-    ends in the BLOCK HEAD, not the next-token head: logits at the block
-    slots' positions (position ``p``'s score the token AT ``p``), the
+    (``mask_block=B``: every region, the prompt's chunks too).  A FUSED
+    row (``q_len`` 2B) feeds the block it commits and, behind it, the next
+    block all masks: under that mask the first half sees what a commit
+    pass of its own sees and the second half what the next block's first
+    denoise pass sees, so one row is the two forwards.  The step
+    ends in the BLOCK HEAD, not the next-token head: logits at each slot's
+    OPEN block (position ``p``'s score the token AT ``p``), the
     per-row sampler's choice ``x0`` and its confidence ``c`` there, and
     the selection — of a row's masked positions the ``unmask_k`` of
     highest ``c`` (ties to the lower position) and every one with ``c >
@@ -1488,7 +1515,7 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
             with phase("block_head"):
                 heads = _block_head(
                     c, p, x[vtok:], tokens[vtok:], token_pos[vtok:],
-                    live[vtok:], tuple(a[v0:] for a in (
+                    live[vtok:], q_lens[v0:], tuple(a[v0:] for a in (
                         temps, top_ps, top_ks, seeds)),
                     fields["unmask_k"][v0:], fields["unmask_tau"][v0:])
             return (layout.join(next_tokens=jnp.zeros((n_rows,), jnp.int32),

@@ -50,10 +50,18 @@ the pass unmasks and with what (``DenoiseRule``: two numbers a row cover
 the three published rules), and ``_commit_block`` applies them; a block
 with no mask left goes in once more as its COMMIT pass, whose K/V stands,
 and its tokens are emitted in position order — 0 to B tokens a row a step.
+Where the request goes on and the next block's page is at hand, that
+commit rides in one FUSED row with the next block's first denoise pass
+(2B positions: the block's clean tokens, then B masks): under the
+block-wise mask the committed half sees nothing of the next block, so it
+is the same forward "of its own" (``configs/sdar30b-pp8.json``
+``assumed.d_commit``), and a generating row costs two steps a block under
+the two-pass rule, not three.
 Counters ``block_row_passes`` / ``block_commit_passes`` /
+``block_commits_fused`` /
 ``block_tokens_unmasked`` / ``blocks_committed`` / ``block_positions`` /
 ``block_positions_masked`` / ``kv_tokens_provisional``
-(``serving/step_account.py``).
+(``serving/step_account.py``: they count block forwards, a fused row two).
 
 Prefix reuse (``serving/prefix_cache.py``, on by default): finished
 requests' fully-written pages enter a chained-hash index; a new request
@@ -466,6 +474,7 @@ class Engine:
             self.scheduler.verify_slots = self.scheduler.max_batch
             self.scheduler.spec_width = self.spec_k + 1
         self.scheduler.block = self.block
+        self.scheduler.mask_id = cfg.mask_token_id
         # THE executable: fixed (max_seqs, chunk, prefill_rows) shapes,
         # compiled exactly once — no bucket grid, no per-request prefill.
         # ``step_fn`` lets N identically-shaped engines (cluster
@@ -1098,11 +1107,16 @@ class Engine:
             pos = np.arange(req.pos, req.pos + qlen)
             if self.block and row >= vbase:
                 # a generating row: its open block, and this pass's rule
-                # (a block with no mask left is committed: nothing to pick)
-                tokens[start:start + qlen] = req.block
-                if self.cfg.mask_token_id in req.block:
+                # (a block with no mask left is committed: nothing to
+                # pick); a fused row's second half is the next block, all
+                # masks, under ITS first pass's rule
+                fused = qlen > self.block
+                tokens[start:start + qlen] = req.block + \
+                    [self.cfg.mask_token_id] * (qlen - self.block)
+                if fused or self.cfg.mask_token_id in req.block:
                     f["unmask_k"][row], f["unmask_tau"][row] = \
-                        self.denoise.unmask(self.block, req.block_pass)
+                        self.denoise.unmask(
+                            self.block, 0 if fused else req.block_pass)
                 else:
                     f["unmask_tau"][row] = 2.0
             else:
@@ -1272,6 +1286,11 @@ class Engine:
         for req, qlen, row in rows:
             if self.block and row >= vbase:
                 produced += self._commit_block(req, blocks, row - vbase, dt)
+                if qlen > self.block:
+                    # a fused row: the commit above, then the next block
+                    # opened and its first pass's picks, in that order
+                    req.block = [self.cfg.mask_token_id] * self.block
+                    self._commit_block(req, blocks, row - vbase, dt)
                 continue
             pre = max(0, min(qlen, req.prompt_len - req.pos))
             if pre:
@@ -1322,18 +1341,26 @@ class Engine:
 
     def _commit_block(self, req: Request, blocks: Tuple[list, list, Any],
                       slot: int, dt: float) -> int:
-        """A block row's outcome.  A DENOISE pass (the block went in with
-        masks): the positions the step's selection flagged take its
-        tokens; nothing of the pass's K/V is kept — the next pass
-        overwrites it, the context has not moved.  A COMMIT pass (no mask
-        went in): its K/V stands, the block's tokens that the request did
-        not bring itself are emitted in position order (those past
-        ``max_new_tokens`` or behind an end-of-sequence token are dropped
-        and the request ends), the context moves by the block and the
-        next block opens at the request's next step.  ``blocks``: the
+        """The outcome of one forward of a block row.  A DENOISE pass (the
+        block went in with masks): the positions the step's selection
+        flagged take its tokens; nothing of the pass's K/V is kept — the
+        next pass overwrites it, the context has not moved.  A COMMIT pass
+        (no mask went in): its K/V stands, the block's tokens that the
+        request did not bring itself are emitted in position order (those
+        past ``max_new_tokens`` or behind an end-of-sequence token are
+        dropped and the request ends), the context moves by the block and
+        the next block opens at the request's next step — or, in a FUSED
+        row, in this one: the row fed the committed block and the next one
+        all masks, so the caller applies the commit, opens the next block
+        and calls again for its first denoise pass (the step's selection
+        is that pass's).  Under the block-wise mask the committed half is
+        the forward "of its own" that a plain commit is (the family's
+        ``store_kv``): tokens are emitted AT the commit either way.
+        ``blocks``: the
         step's ``block_tokens`` / ``block_flags`` / ``block_conf`` as lists
-        (the last only while the analysis tap is on).  Returns the tokens
-        emitted."""
+        (the last only while the analysis tap is on), of each slot's open
+        block — a plain commit's own, which selects nothing.  Returns the
+        tokens emitted."""
         b, mask = self.block, self.cfg.mask_token_id
         x = req.block
         toks, flags = blocks[0][slot], blocks[1][slot]

@@ -52,9 +52,11 @@ class Scheduler:
         self.verify_slots = 0
         self.spec_width = 0
         # a block-wise model (set by the engine from
-        # ``cfg.diffusion_block``): the narrow slots are BLOCK slots, one
-        # per sequence slot, and a generating request rides one every step
+        # ``cfg.diffusion_block`` / ``cfg.mask_token_id``): the narrow slots
+        # are BLOCK slots, two blocks wide, one per sequence slot, and a
+        # generating request rides one every step
         self.block = 0
+        self.mask_id = None
         # optional serving.prefix_cache.PrefixCache: admission charges
         # only the UNCACHED suffix against the page budget (and counts
         # refcount-0 cached pages as reclaimable), preemption releases
@@ -66,7 +68,7 @@ class Scheduler:
         """Tokens one packed step can carry (the executable's T)."""
         return self.max_batch + self.prefill_rows * self.chunk \
             + self.verify_slots * self.spec_width \
-            + self.max_batch * self.block
+            + self.max_batch * 2 * self.block
 
     # -- admission -----------------------------------------------------------
 
@@ -203,10 +205,17 @@ class Scheduler:
         prompt's WHOLE blocks are not all prefilled yet takes a chunk slot
         (``q_len`` up to the last whole block, at most ``chunk``, which is
         a multiple of the block length: a chunk ends where a block ends);
-        every other request is GENERATING and rides a block slot with its
-        open block, ``q_len`` = the block length, every step — a denoise
-        pass or the block's commit pass, the engine says which.  No row is
-        ever one token wide: the decode slots stay idle."""
+        every other request is GENERATING and rides a block slot every
+        step.  Its row is its open block, ``q_len`` = the block length — a
+        denoise pass or the block's commit pass, the engine says which —
+        or, FUSED, ``q_len`` = two blocks: the block it commits and the
+        next one's first denoise pass in one row (the same two forwards
+        under the block-wise mask: a committed block's positions do not
+        see the next block).  A row is fused where its open block holds no
+        mask, the request goes on after this commit (``_goes_on``) and the
+        pages under ``pos + 2B`` are at hand (``ensure_decode_pages`` asked
+        for them); else it is the plain row.  No row is ever one token
+        wide: the decode slots stay idle."""
         b = self.block
         rows: List[Tuple[Request, int, int]] = []
         vbase = self.max_batch + self.prefill_rows
@@ -214,13 +223,29 @@ class Scheduler:
         for r in live:
             whole = len(r.tokens) // b * b - r.pos
             if whole <= 0:
-                rows.append((r, b, vbase + brow))
+                fused = self._goes_on(r) and \
+                    self.pool.pages_for(r.pos + 2 * b) <= len(r.pages)
+                rows.append((r, 2 * b if fused else b, vbase + brow))
                 brow += 1
             elif chunk_row < self.prefill_rows:
                 rows.append((r, min(whole, self.chunk),
                              self.max_batch + chunk_row))
                 chunk_row += 1
         return rows
+
+    def _goes_on(self, req: Request) -> bool:
+        """The request's open block holds no mask — its next row is the
+        block's commit — and the request opens another block behind it:
+        decided from what the host holds before the step.  The commit
+        emits the block's tokens that the request did not bring; where
+        they reach ``max_new_tokens`` or hold an end-of-sequence id the
+        request honours, it ends there."""
+        x = req.block
+        if x is None or self.mask_id in x:
+            return False
+        emits = x[len(req.tokens) - req.pos:]
+        return req.n_generated + len(emits) < req.max_new_tokens and \
+            req.eos_token_id not in emits
 
     def slot_mix(self, rows: List[Tuple[Request, int, int]]
                  ) -> dict:
@@ -260,7 +285,9 @@ class Scheduler:
         ``ceil((1 + staged drafts) / page_size)`` for a speculative
         verify row (its burst writes ``pos .. pos + spec_len``, which
         may cross a page boundary), or the pages under a block-wise
-        model's open block (a block ahead of the committed K/V).  A page
+        model's open block (a block ahead of the committed K/V; the block
+        behind it too where the row would be fused, but only from what is
+        free).  A page
         squeeze sheds the
         requester's staged drafts FIRST — degrading a burst to a plain
         decode is free, while preempting any request costs its whole
@@ -304,7 +331,20 @@ class Scheduler:
                 evicted.append(victim)
                 if victim is req:
                     break
-        return [r for r in kept if r not in evicted], evicted
+        kept = [r for r in kept if r not in evicted]
+        for req in kept if self.block else ():
+            # a commit that would ride with the next block's first pass
+            # asks for that block's page too, once every row has what it
+            # must have: nobody is preempted for it, and without it the
+            # row is the plain commit
+            short = self.pool.pages_for(req.pos + 2 * self.block) \
+                - len(req.pages)
+            got = self.pool.alloc(short) \
+                if short > 0 and self._goes_on(req) else None
+            if got:
+                req.pages.extend(got)
+                req.peak_pages = max(req.peak_pages, len(req.pages))
+        return kept, evicted
 
     def _slide_window(self, req: Request) -> bool:
         """The window layers' pages of ``req`` for its next step: let go

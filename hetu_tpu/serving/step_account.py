@@ -50,12 +50,15 @@ COUNTERS = (
     "window_pages_held", "full_pages_held",
     # self-drafting: rows one token from emitting / those with a draft
     "decode_rows", "spec_rows",
-    # block-wise generation: block rows computed (a pass of a block each) /
-    # those that were commit passes; positions the denoise passes unmasked;
+    # block-wise generation: block forwards computed (a pass of a block
+    # each; a fused row is two) / those that were commit passes / the
+    # commits that rode in one row with the next block's first pass;
+    # positions the denoise passes unmasked;
     # blocks committed (the engine's commit, equal to the commit passes);
     # positions computed in block rows / those masked going in; K/V rows
     # written by denoise passes (overwritten, never read by a later step)
-    "block_row_passes", "block_commit_passes", "block_tokens_unmasked",
+    "block_row_passes", "block_commit_passes", "block_commits_fused",
+    "block_tokens_unmasked",
     "blocks_committed", "block_positions", "block_positions_masked",
     "kv_tokens_provisional")
 GAUGES = (
@@ -311,29 +314,39 @@ class StepAccount:
             window_pairs=_capped(st, self.window))
 
     def _block(self, st, out, traced):
-        """Block rows are the rows in the narrow slots; a row whose block
-        went in with no mask is a commit pass, every other a denoise pass,
-        whose K/V rows are provisional.  ``attn_pairs``: pairs inside the
-        block-wise mask, every row of the step (the prompt's chunks run
-        under it too)."""
+        """Block rows are the rows in the narrow slots, and the counters
+        count BLOCK FORWARDS: B positions of one block through every
+        layer.  A plain row is one — a commit pass where its block went in
+        with no mask, else a denoise pass, whose K/V rows are provisional.
+        A FUSED row (two blocks wide) is two: the commit pass of the block
+        it fed clean and the first denoise pass of the next, all masks; no
+        forward is dropped, so the passes still sum to what the blocks
+        take by the rule.  ``block_commits_fused`` says how often a commit
+        rode that way.  ``attn_pairs``: pairs inside the block-wise mask,
+        every row of the step (the prompt's chunks run under it too)."""
         b = self.block
-        rows = [(r, row) for r, row in zip(st.reqs, st.row)
+        rows = [(r, q, row) for r, q, row in zip(st.reqs, st.q, st.row)
                 if row >= self.vbase]
-        masked = [sum(t == self.mask_id for t in r.block) for r, _ in rows]
-        commits = sum(m == 0 for m in masked)
+        fused = sum(q > b for _, q, _ in rows)
+        passes = len(rows) + fused
+        masked = [sum(t == self.mask_id for t in r.block) + q - b
+                  for r, q, _ in rows]
+        commits = fused + sum(m == 0 for m in masked)
         flags = out["block_flags"]
         unmasked = sum(bin(int(flags[row - self.vbase])).count("1")
-                       for (_, row), m in zip(rows, masked) if m)
+                       for (_, _, row), m in zip(rows, masked) if m)
         c = self.counters
-        c["block_row_passes"].inc(len(rows))
+        c["block_row_passes"].inc(passes)
         c["block_commit_passes"].inc(commits)
+        c["block_commits_fused"].inc(fused)
         c["block_tokens_unmasked"].inc(unmasked)
-        c["block_positions"].inc(b * len(rows))
+        c["block_positions"].inc(b * passes)
         c["block_positions_masked"].inc(sum(masked))
-        c["kv_tokens_provisional"].inc(b * (len(rows) - commits))
+        c["kv_tokens_provisional"].inc(b * (passes - commits))
         if not traced:
             return None
-        return dict(block_rows=len(rows), block_commit_rows=commits,
+        return dict(block_rows=passes, block_commit_rows=commits,
+                    block_fused_rows=fused,
                     block_unmasked=unmasked, block_masked=sum(masked),
                     attn_pairs=_block_pairs(st, b),
                     kv_pages_distinct=st.distinct_pages)

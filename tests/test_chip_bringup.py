@@ -59,18 +59,20 @@ def _kernel_cases():
             q, k, v, causal=True).astype(F32).sum(), argnums=(0, 1, 2))(
                 q, k, v)
 
-    def ragged(q, kp, vp, *d, max_q=CHUNK):
+    def ragged(q, kp, vp, *d, max_q=CHUNK, mask_block=1):
         return rpa.ragged_paged_attention_pallas(
-            q, kp, vp, *d, max_q=max_q, interpret=False)
+            q, kp, vp, *d, max_q=max_q, interpret=False,
+            mask_block=mask_block)
 
-    def region(rows, max_q, heads=12, kv_heads=12, maxp=32):
+    def region(rows, max_q, heads=12, kv_heads=12, maxp=32, mask_block=1):
         # one region of the serving step at the benchmark's widths
         # (Cerebras-GPT-590M: 12 kv heads x 128, 64-token pages, 32
         # pages a row; the hybrid configuration: 32 query heads on 2 kv
         # heads, 64 decode slots; the window / full K/V stack: 64 query
         # heads on 8 kv heads, 48 rows, 272 pages a row)
         t = rows * max_q
-        return (functools.partial(ragged, max_q=max_q), (
+        return (functools.partial(ragged, max_q=max_q,
+                                  mask_block=mask_block), (
             _sds((t, heads, 128), BF16),
             *(_sds((PAGES, kv_heads, PAGE, 128), BF16),) * 2,
             _sds((rows,), I32), _sds((rows + 1,), I32),
@@ -231,6 +233,10 @@ def _kernel_cases():
             16, _sds((16,), I32), _sds((1,), I32), _sds((16,), jnp.bool_))),
         # 20 query heads on ONE kv head at a 1,024-token chunk: the window
         # is cut in two (ragged_paged_attention.window_split)
+        # the block-wise model's block region: 64 slots two blocks of 4
+        # wide (a fused row fills one), 32 query heads on 4 kv heads, 41
+        # pages a row, under the block-wise mask
+        "ragged_block_gqa8_x41": region(64, 8, 32, 4, 41, mask_block=4),
         "ragged_chunk_mqa20_x528": region(1, 1024, 20, 1, 528),
         "ragged_decode_mqa20_x528": region(16, 1, 20, 1, 528),
         "index_score_chunk": index_score(CHUNK, True),
@@ -265,7 +271,7 @@ def _kernel_cases():
 # scan: blocks of B and C scalars in SMEM (whole 1,024-word tiles), 16
 # state registers a channel group over a token loop; the K/V call of 20
 # query heads on one kv head, whose 1,024-token window runs out of VMEM
-# uncut
+# uncut; the block region's call at two blocks a slot under the block mask
 AOT_CASES = ("ragged_12kv_x64", "ragged_decode_region",
              "ragged_chunk_region", "ragged_decode_gqa16",
              "ragged_verify_gqa8_x272", "ragged_chunk_gqa8_x272",
@@ -275,7 +281,7 @@ AOT_CASES = ("ragged_12kv_x64", "ragged_decode_region",
              "latent_256_128_chunk_region", "ssd_decode_slots",
              "index_score_chunk", "index_score_decode",
              "selective_scan_chunk", "selective_scan_slots",
-             "ragged_chunk_mqa20_x528")
+             "ragged_chunk_mqa20_x528", "ragged_block_gqa8_x41")
 
 
 @pytest.fixture

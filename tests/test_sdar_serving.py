@@ -288,7 +288,7 @@ def test_a_preemption_in_an_open_block(model):
     cfg, state, spec = model
     eng = engine(cfg, state, num_pages=8, max_batch=2)
     hs = [eng.add_request(p, n) for p, n in zip(
-        prompts([6, 27], seed=4), (40, 18))]
+        prompts([6, 29], seed=4), (40, 18))]
     eng.run()
     assert eng.counters["preemptions"].value > 0
     lost = 0
@@ -304,6 +304,107 @@ def test_a_preemption_in_an_open_block(model):
     assert eng.counters["block_commit_passes"].value == \
         eng.counters["blocks_committed"].value
     assert eng.pool.free_pages == eng.pool.num_usable
+
+
+# -- the fused row: a commit rides the next block's first pass ----------------
+
+def _row_passes_recounted(hs):
+    """The benchmark driver's recount of the block forwards, from the
+    requests' own lengths (``serve_open_loop_block.expected_row_passes``:
+    a committed block's passes by the schedule "+ 1 commit")."""
+    from drivers.serve_open_loop_block import expected_row_passes
+    return expected_row_passes(hs, hs, B, ref.schedule(B, STATIC2.steps))
+
+
+def _eos_in_the_second_block(cfg, state):
+    free = engine(cfg, state)
+    full = free.add_request(prompts([8], seed=4)[0], 12)
+    free.run()
+    return full.out_tokens[5]
+
+
+# case -> (prompt lengths, max_new_tokens, engine arguments, commits fused,
+# preemptions); every request arrives at once
+FUSED_CASES = {
+    # three blocks behind a prompt of whole blocks: fused, fused, plain
+    "several_blocks": ([8], [12], {}, 2, 0),
+    # L mod 4 = 2: the first block opens with two prompt tokens
+    "prompt_tail": ([10], [10], {}, 2, 0),
+    # one block: its commit is the request's last, a plain one
+    "last_block_plain": ([8], [4], {}, 0, 0),
+    # an end-of-sequence id in the second block: that commit is plain
+    "eos_in_a_block": ([8], [12], {}, 1, 0),
+    # four pages for two requests of two pages each: at its second commit
+    # the longer one finds no page for the block behind it (the shorter one
+    # ends in that step and holds its two), commits plainly and takes the
+    # page a step later, from what the other freed
+    "no_page_for_the_next_block": (
+        [8, 8], [8, 12], dict(num_pages=5, prefill_rows=2), 2, 0),
+    # the same squeeze with the earlier request the longer: it falls back
+    # at its first commit while the later one rides a fused row, then asks
+    # for its page and the later one is evicted with a first pass done
+    "preempted_behind_a_fused_row": (
+        [12, 8], [8, 12], dict(num_pages=5, prefill_rows=2), 2, 1),
+    # a fused row, a plain denoise row and a prompt chunk in one step
+    "mixed_step": ([8, 9, 37], [12, 9, 6], {}, 2 + 2 + 1, 0),
+}
+
+
+@pytest.mark.parametrize("case", FUSED_CASES)
+def test_a_commit_rides_the_next_blocks_first_pass(model, case):
+    """Served tokens and every pass of ``denoise_log`` (place, state going
+    in, picked set, tokens, confidences) equal the reference's, where a
+    commit and the next block's first denoise pass are ONE row of 2B
+    positions; the counters count block forwards, so the driver's recount
+    holds with a fused row counted twice; and how often the mechanism
+    engages is what the case says."""
+    cfg, state, spec = model
+    lengths, new, kw, fused, preemptions = FUSED_CASES[case]
+    eos = _eos_in_the_second_block(cfg, state) \
+        if case == "eos_in_a_block" else None
+    eng = engine(cfg, state, **kw)
+    hs = [eng.add_request(p, n, eos_token_id=eos)
+          for p, n in zip(prompts(lengths, seed=4), new)]
+    eng.run()
+    c = {k: v.value for k, v in eng.counters.items()}
+    lost = 0
+    for h in hs:
+        want = assert_served_as_generated(h, state, spec, STATIC2, eos=eos,
+                                          log=not h.n_preemptions)
+        assert [e[:4] for e in h.denoise_log if MASK not in e[1]] == \
+            [e[:4] for e in want if MASK not in e[1]]
+        lost += len(h.denoise_log) - len(want)
+    assert c["preemptions"] == preemptions
+    assert c["block_row_passes"] == _row_passes_recounted(hs) + lost
+    assert c["block_commit_passes"] == c["blocks_committed"]
+    assert c["block_commits_fused"] == fused
+    assert eng.compile_count == 1
+    assert eng.pool.free_pages == eng.pool.num_usable
+    steps = [t["rows"] for t in eng.tap if t["kind"] == "unified"]
+    vbase = eng.scheduler.max_batch + eng.scheduler.prefill_rows
+    if len(hs) == 1 and not lengths[0] % B and eos is None:
+        # n blocks under the two-pass rule: 2n + 1 generating steps (3n
+        # with a commit pass of its own a block)
+        generating = [r for r in steps if r[0][0] >= vbase]
+        assert len(generating) == 2 * (new[0] // B) + 1
+    if case == "eos_in_a_block":
+        assert len(hs[0].out_tokens) == 6
+    if case == "no_page_for_the_next_block":
+        # the second commit of the longer request went in B wide although
+        # the request went on
+        assert [q for r in steps for row, pos, q in r
+                if row == vbase + 1 and pos == 12] == [B] * 2
+    if case == "preempted_behind_a_fused_row":
+        # exactly the fused row's first pass of the next block was lost
+        assert lost == 1 and hs[1].n_preemptions == 1
+        late = hs[1].denoise_log
+        i = next(i for i, e in enumerate(late) if e[0] == 12)
+        assert late[i - 1][0] == 8 and MASK not in late[i - 1][1]
+        assert late[i][1] == (MASK,) * B == late[i + 1][1]
+    if case == "mixed_step":
+        kinds = [{"chunk" if row < vbase else q for row, _, q in r}
+                 for r in steps]
+        assert {"chunk", B, 2 * B} in kinds
 
 
 def test_drawn_sampling_replays_and_ignores_the_batch(model):
@@ -371,15 +472,21 @@ def test_counts_of_passes_and_provisional_kv(model):
     assert c["decode_steps"] == 0
     steps = [e for e in tracer.events() if e.name == "unified_step"]
     assert len(steps) == c["step_calls"]
-    for k in ("block_rows", "block_commit_rows", "block_unmasked",
-              "block_masked", "attn_pairs", "kv_pages_distinct", "moe_local",
+    for k in ("block_rows", "block_commit_rows", "block_fused_rows",
+              "block_unmasked", "block_masked", "attn_pairs", "kv_pages_distinct", "moe_local",
               "moe_experts_hit", "moe_blocks", "moe_load_peak"):
         assert all(k in e.attrs for e in steps), k
     assert sum(e.attrs["block_rows"] for e in steps) == expected
     assert sum(e.attrs["block_commit_rows"] for e in steps) == blocks
     mixes = [e.attrs for e in tracer.events() if e.name == "engine_step"
              and "block_slots" in e.attrs]
-    assert sum(a["block_slots"] for a in mixes) == expected
+    # a fused row is one slot and two forwards: every commit but a
+    # request's last rides with the next block's first pass
+    fused = blocks - len(hs)
+    assert c["block_commits_fused"] == fused == sum(
+        e.attrs["block_fused_rows"] for e in steps)
+    assert sum(a["block_slots"] for a in mixes) + fused == expected
+    assert sum(a["tokens"] for a in mixes) == c["kv_tokens_written"]
     assert all(a["decode_slots"] == 0 == a["verify_slots"] for a in mixes)
 
 

@@ -559,8 +559,9 @@ def _sdar(**kw):
 
 def test_block_slots_stand_where_the_verify_slots_stand():
     """The block region is the narrow region behind the chunk slots:
-    ``max_batch`` rows of ``B`` tokens, tagged ``block``, the layout's last;
-    the decode and chunk regions are where every build has them."""
+    ``max_batch`` rows of ``2 B`` tokens (a plain row fills the first B, a
+    fused row all of them), tagged ``block``, the layout's last; the decode
+    and chunk regions are where every build has them."""
     from hetu_tpu.serving.decode import _chunk_slots, _regions
     eng, sd = _sdar()
     sch, b = eng.scheduler, eng.cfg.diffusion_block
@@ -569,27 +570,48 @@ def test_block_slots_stand_where_the_verify_slots_stand():
     assert regions[:2] == _regions(sch.max_batch, sch.prefill_rows,
                                    sch.chunk, 0)
     # the same rows and tokens as the verify slots of a build with drafts
-    # of B - 1
-    verify = _regions(sch.max_batch, sch.prefill_rows, sch.chunk, b - 1)
+    # of 2 B - 1
+    verify = _regions(sch.max_batch, sch.prefill_rows, sch.chunk, 2 * b - 1)
     assert regions[2][1:] == verify[2][1:] and verify[2][0] == "verify"
+    assert regions[2][4] == 2 * b
     assert _chunk_slots(sch.max_batch, sch.prefill_rows, sch.chunk, 0, b) \
-        == _chunk_slots(sch.max_batch, sch.prefill_rows, sch.chunk, b - 1)
+        == _chunk_slots(sch.max_batch, sch.prefill_rows, sch.chunk, 2 * b - 1)
     lay = eng.layout
     assert lay.n_rows == 2 * sch.max_batch + sch.prefill_rows
-    assert lay.n_tokens == sch.max_batch * (1 + b) + sch.chunk
+    assert lay.n_tokens == sch.max_batch * (1 + 2 * b) + sch.chunk
     assert sch.token_budget == lay.n_tokens
     assert {"unmask_k", "unmask_tau"} <= set(lay.fields)
     assert "spec_lens" not in lay.fields and "next_tok" not in lay.fields
     assert list(lay.outs) == ["next_tokens", "moe_load", "block_tokens",
                               "block_flags", "block_conf"]
+    # the head's work is the open block's: B positions a slot, not 2 B
     assert lay.outs["block_tokens"][1] == (sch.max_batch, b) == \
         lay.outs["block_conf"][1]
+
+
+@pytest.mark.parametrize("spec_k", [0, 1, 3])
+def test_slots_without_a_block_are_what_they_were(spec_k):
+    """``block=0`` (every ``spec_k`` a configuration uses: none, the MTP
+    module's 1, a draft model's 3): the slots and regions written out by
+    hand, as they were before a block slot was two blocks wide."""
+    from hetu_tpu.serving.decode import _chunk_slots, _regions
+    s, r, chunk = 4, 2, 16
+    slots = [(4, 4, 16), (5, 20, 16)]
+    regions = [("decode", 0, 0, 4, 1), ("chunk", 4, 4, 2, 16)]
+    if spec_k:
+        w = spec_k + 1
+        slots += [(6 + j, 36 + j * w, w) for j in range(4)]
+        regions.append(("verify", 6, 36, 4, w))
+    for args in ((s, r, chunk, spec_k), (s, r, chunk, spec_k, 0)):
+        assert _chunk_slots(*args) == slots
+        assert _regions(*args) == regions
 
 
 def test_a_block_step_is_one_buffer_each_way_and_the_rule_rides_it():
     """One transfer each way a step; ``unmask_k`` / ``unmask_tau`` are the
     schedule's count for the pass and 2.0 under the static rule, 0 / 2.0 on
-    a commit pass; the float travels by its bit pattern."""
+    a plain commit pass, the next block's first pass's on a fused row; the
+    float travels by its bit pattern."""
     eng, sd = _sdar()
     h = eng.add_request(sd.prompts([9])[0], 6)
     seen = []
@@ -608,11 +630,12 @@ def test_a_block_step_is_one_buffer_each_way_and_the_rule_rides_it():
         len(seen)
     vbase = eng.scheduler.max_batch + eng.scheduler.prefill_rows
     # 9 tokens: two whole blocks in a chunk, then block 2 opens with one
-    # prompt token: masks 3 -> passes (2, 1) + commit; block 3: (2, 2) + c.
+    # prompt token: masks 3 -> passes (2, 1), then its commit FUSED with
+    # block 3's first pass (8 positions under that pass's count, 2); block
+    # 3's second pass and, the request ending there, a plain commit.
     assert seen[0] == [(eng.scheduler.max_batch, 8, 0, 0.0)]
     assert [s[0][1:] for s in seen[1:]] == [
-        (4, 2, 2.0), (4, 2, 2.0), (4, 0, 2.0),
-        (4, 2, 2.0), (4, 2, 2.0), (4, 0, 2.0)]
+        (4, 2, 2.0), (4, 2, 2.0), (8, 2, 2.0), (4, 2, 2.0), (4, 0, 2.0)]
     assert all(s[0][0] == vbase for s in seen[1:])
     assert len(h.out_tokens) == 6
 
@@ -630,7 +653,9 @@ def test_the_three_rules_are_two_numbers(rule, k, tau):
 def test_the_block_head_selects_on_the_device():
     """``_block_head`` alone, on logits built by hand: rank by confidence
     with ties to the lower position, the sequential rule by position, the
-    threshold whatever the rank, never the mask id, nothing on a commit."""
+    threshold whatever the rank, never the mask id, nothing on a commit —
+    over each slot's OPEN block: the first half of a plain row's slot, the
+    second half of a fused one's, whatever the other half holds."""
     import jax.numpy as jnp
     from hetu_tpu.serving.decode import _block_head
     eng, sd = _sdar()
@@ -640,18 +665,27 @@ def test_the_block_head_selects_on_the_device():
     head = jnp.zeros((v, h)).at[jnp.arange(h), jnp.arange(h)].set(1.0)
     p = lambda name: head if name == "lm_head.weight" else None  # noqa: E731
     peak = lambda tok, s: jnp.zeros((h,)).at[tok].set(s)         # noqa: E731
-    x = jnp.stack([peak(3, 9.0), peak(4, 5.0), peak(5, 9.0), peak(6, 7.0),
-                   peak(7, 1.0), peak(8, 2.0), peak(9, 3.0), peak(10, 4.0)])
-    tokens = jnp.asarray([mask, mask, mask, 11, mask, mask, mask, mask])
-    live = jnp.ones((8,), bool)
+    loud = [peak(20, 30.0)] * 4
+    # slot 0 a plain row: its block, then four dead positions; slot 1 a
+    # fused row: the committed block, then the open one
+    x = jnp.stack([peak(3, 9.0), peak(4, 5.0), peak(5, 9.0), peak(6, 7.0)]
+                  + loud + loud +
+                  [peak(7, 1.0), peak(8, 2.0), peak(9, 3.0), peak(10, 4.0)])
+    tokens = jnp.asarray([mask, mask, mask, 11] + [mask] * 4 +
+                         [12, 13, 14, 15] + [mask] * 4)
+    q_lens = jnp.asarray([4, 8])
+    live = jnp.asarray([True] * 4 + [False] * 4 + [True] * 8)
     zeros = jnp.zeros((2,))
     sampling = (zeros, zeros, jnp.zeros((2,), jnp.int32),
                 jnp.zeros((2,), jnp.int32))
 
+    def head_of(p, x, ks, taus):
+        return _block_head(cfg, p, x, tokens, jnp.arange(16), live, q_lens,
+                           sampling, jnp.asarray(ks, jnp.int32),
+                           jnp.asarray(taus, jnp.float32))
+
     def picks(ks, taus):
-        out = _block_head(cfg, p, x, tokens, jnp.arange(8), live, sampling,
-                          jnp.asarray(ks, jnp.int32),
-                          jnp.asarray(taus, jnp.float32))
+        out = head_of(p, x, ks, taus)
         return (np.asarray(out["block_tokens"]).tolist(),
                 [int(f) for f in np.asarray(out["block_flags"])])
     toks, flags = picks([1, 2], [2.0, 2.0])
@@ -660,18 +694,14 @@ def test_the_block_head_selects_on_the_device():
     assert flags == [0b0001, 0b1100]
     assert toks == [[3, 4, 5, 11], [7, 8, 9, 10]]
     assert picks([-1, -2], [2.0, 2.0])[1] == [0b0001, 0b0011]   # by position
-    conf = np.asarray(_block_head(
-        cfg, p, x, tokens, jnp.arange(8), live, sampling,
-        jnp.asarray([0, 0], jnp.int32), jnp.asarray([2.0, 2.0]))[
-            "block_conf"]).view(np.float32)
+    conf = np.asarray(head_of(p, x, [0, 0], [2.0, 2.0])[
+        "block_conf"]).view(np.float32)
     assert picks([0, 0], [2.0, 2.0])[1] == [0, 0]               # a commit
     tau = float((conf[0, 1] + conf[0, 0]) / 2)
     assert picks([1, 1], [tau, 2.0])[1] == [0b0101, 0b1000]     # above tau
     # the mask id is never the choice, however it scores
-    loud = head.at[mask, 0].set(50.0)
-    out = _block_head(cfg, lambda name: loud if name == "lm_head.weight"
-                      else None, x.at[:, 0].add(0.5), tokens, jnp.arange(8),
-                      live, sampling, jnp.asarray([4, 4], jnp.int32),
-                      jnp.asarray([2.0, 2.0]))
+    shout = head.at[mask, 0].set(50.0)
+    out = head_of(lambda name: shout if name == "lm_head.weight" else None,
+                  x.at[:, 0].add(0.5), [4, 4], [2.0, 2.0])
     assert np.asarray(out["block_tokens"]).tolist() == \
         [[3, 4, 5, 11], [7, 8, 9, 10]]
