@@ -29,9 +29,10 @@ from ..obs.phases import phase
 from jax.sharding import PartitionSpec as P
 
 
-MIXERS = ("mamba2", "attention", "moe", "mla", "dsa", "swa", "mlp", "mamba1")
+MIXERS = ("mamba2", "attention", "moe", "mla", "dsa", "swa", "mlp", "mamba1",
+          "gdn")
 # mixers that keep a recurrent state in the slot store; a pattern holds one
-STATE_MIXERS = ("mamba2", "mamba1")
+STATE_MIXERS = ("mamba2", "mamba1", "gdn")
 # mixers that keep pages in the K/V pool
 PAGED_MIXERS = ("attention", "mla", "dsa", "swa")
 
@@ -147,10 +148,10 @@ class GPTConfig:
     q_pos_scale: Optional[Tuple[float, int]] = None
     # Hybrid stacks (serving path; models/hybrid.py owns the parameter
     # names): ``layer_pattern`` gives ONE mixer per layer behind one
-    # pre-norm and one residual — "mamba2" | "attention" | "moe".  None
+    # norm (``norm_position``) and one residual — one of ``MIXERS``.  None
     # is today's block (attention + MLP in every layer).  The K/V pool
     # then holds the attention layers only and a state-slot store the
-    # mamba2 layers (serving/kv_pool.py).  "dsa" (latent attention over
+    # recurrent (``STATE_MIXERS``) layers (serving/kv_pool.py).  "dsa" (latent attention over
     # the positions an indexer picks) and "swa" (latent attention over a
     # window) take their sizes from ``mixer_geometry[kind]``, so one
     # stack holds latent layers of different geometry; "mlp" is a dense
@@ -169,6 +170,12 @@ class GPTConfig:
     # rotated).
     attn_head_dim: Optional[int] = None
     attn_qk_norm: bool = False
+    # the QK-norm over the whole q (k) vector of a token, all heads
+    # together (weights [heads * head_dim]), not head by head
+    attn_qk_norm_full: bool = False
+    # where a pattern stack's sublayer norm sits: "pre" ``x + f(Norm(x))``,
+    # "post" ``x + Norm(f(x))`` (the norm on the sublayer's OUTPUT)
+    norm_position: str = "pre"
     attn_window: int = 0
     attn_window_layers: Tuple[int, ...] = ()
     attn_rope: str = "all"
@@ -202,6 +209,17 @@ class GPTConfig:
     # ``dt``, ``B``, ``C`` each pass an RMSNorm.
     mamba1_inner: int = 0
     mamba1_dt_rank: int = 0
+    # the "gdn" mixer (a gated delta rule, ``ops/gated_delta.py``: a
+    # ``[key_dim, value_dim]`` matrix state a head whose update reads the
+    # state it writes): the published ``linear_*`` sizes.  q, k and v pass
+    # one causal conv of ``linear_conv_kernel`` taps; ``linear_neg_eigval``
+    # lets ``beta`` reach 2 (a state eigenvalue of -1).
+    linear_key_heads: int = 0
+    linear_value_heads: int = 0
+    linear_key_dim: int = 0
+    linear_value_dim: int = 0
+    linear_conv_kernel: int = 4
+    linear_neg_eigval: bool = False
     # expert layer of a hybrid stack: ``num_experts`` is the ROUTER's
     # width (all experts of the deployment), of which this program holds
     # ``experts_held`` starting at ``expert_offset`` (default: all) and
@@ -247,7 +265,19 @@ class GPTConfig:
             if len(set(self.layer_pattern) & set(STATE_MIXERS)) > 1:
                 raise ValueError(
                     "one pattern holds one kind of recurrent mixer "
-                    f"({STATE_MIXERS}): the slot store has one layout")
+                    f"({' / '.join(STATE_MIXERS)}): the slot store has one "
+                    "layout")
+            if "gdn" in self.layer_pattern and not (
+                    self.linear_key_heads == self.linear_value_heads > 0
+                    and self.linear_key_dim > 0 and self.linear_value_dim > 0):
+                raise ValueError(
+                    "a gdn layer needs linear_key_dim, linear_value_dim and "
+                    "as many key heads as value heads (linear_key_heads == "
+                    "linear_value_heads: value heads that share a key head "
+                    "are not built)")
+            if self.norm_position not in ("pre", "post"):
+                raise ValueError(
+                    f"unknown norm_position {self.norm_position!r}")
             if "mlp" in self.layer_pattern and not self.ffn_hidden_size:
                 raise ValueError("an mlp layer needs ffn_hidden_size")
             if "moe" in self.layer_pattern:
@@ -280,10 +310,11 @@ class GPTConfig:
                     "with the mask_token_id (a row of the vocabulary) its "
                     "masked positions are fed as")
         elif self.attn_head_dim or self.attn_qk_norm or self.attn_window \
-                or self.mtp_pattern or self.diffusion_block:
+                or self.mtp_pattern or self.diffusion_block \
+                or self.norm_position != "pre":
             raise ValueError(
                 "attn_head_dim / attn_qk_norm / attn_window / mtp_pattern / "
-                "diffusion_block "
+                "diffusion_block / norm_position "
                 "describe the attention mixer of a layer_pattern stack; the "
                 "plain block (training, generate) keeps head_dim = hidden / "
                 "heads, has no MTP module and no block mask")
@@ -442,6 +473,17 @@ class GPTConfig:
     def held_experts(self) -> int:
         return self.num_experts if self.experts_held is None \
             else int(self.experts_held)
+
+    def qk_norm_width(self, full: int) -> int:
+        """Lanes a QK-norm's weight spans: a head's, or (``attn_qk_norm_
+        full``) the ``full`` vector's."""
+        return full if self.attn_qk_norm_full else self.head_dim
+
+    @property
+    def linear_conv_dim(self) -> int:
+        """Channels a gdn layer's causal conv runs over: q | k | v."""
+        return 2 * self.linear_key_heads * self.linear_key_dim + \
+            self.linear_value_heads * self.linear_value_dim
 
     @property
     def mamba_inner(self) -> int:

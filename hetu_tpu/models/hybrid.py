@@ -8,8 +8,8 @@ A ``GPTConfig`` with a ``layer_pattern`` runs ``x = x + mixer_l(RMSNorm_l
 the one place that knows the stack's PARAMETER NAMES and the mixers'
 arithmetic; the serving step (``serving/decode.py``) lays them over the
 ragged token axis and owns the state (K/V pages for the attention layers,
-state slots for the mamba2 / mamba1 layers).  The plain float32 reference
-is ``benchmark/reference_hybrid.py`` (``reference_jamba.py`` for mamba1).
+state slots for the recurrent layers).  The plain float32 references are
+``benchmark/reference_hybrid.py`` / ``_jamba.py`` / ``_olmo_hybrid.py``.
 
 Tensors (a projection ``W`` is ``[out, in]``, used as ``x @ W.T``)::
 
@@ -60,21 +60,19 @@ the same names and, around them::
 
 What the serving engine refuses depends on the pattern, not on the
 stack being a pattern: the prefix cache and speculation for a pattern
-with an ``M`` layer (a page prefix carries no recurrent state, a rejected
-draft cannot be rolled out of it); speculation for latent layers (their
-calls have no verify region); page quantisation for every pattern; ``L``
-beside ``*`` (one pool, one layout).  ``mistral4_config`` is the
-translation for the ``(L, E) x depth`` stacks of ``model_type: mistral4``,
-``dots3_config`` for ``model_type: dots3_note`` (indexed and window latent
-layers of different geometry, a leading dense layer),
-``exaone_moe_config`` for ``model_type: exaone_moe`` (plain K/V attention
-at its own head width behind QK-norms, window layers beside full ones,
-an MTP module that drafts for the serving step's verify rows),
-``sdar_moe_config`` for ``model_type: sdar_moe`` (the same K/V attention
-and gated experts under a softmax router, generated by diffusion over
-blocks: ``GPTConfig.diffusion_block`` / ``mask_token_id``),
-``jamba_config`` for ``model_type: jamba`` with dense feed-forwards
-(mamba1 / attention, each followed by a gated MLP; a tied head).
+with a recurrent layer (a page prefix carries no recurrent state, a
+rejected draft cannot be rolled out of it); speculation for latent layers
+(their calls have no verify region); page quantisation for every pattern;
+``L`` beside ``*`` (one pool, one layout).  The translations, one a
+published family: ``hybrid_config`` (``nemotron_h``), ``mistral4_config``
+(the ``(L, E) x depth`` stacks), ``dots3_config`` (``dots3_note``: indexed
+and window latent layers of different geometry, a leading dense layer),
+``exaone_moe_config`` (plain K/V attention at its own head width behind
+QK-norms, window layers beside full ones, an MTP module that drafts for
+the verify rows), ``sdar_moe_config`` (the same attention and gated
+experts under a softmax router, generated by diffusion over blocks),
+``jamba_config`` (mamba1 / attention, each followed by a gated MLP; a tied
+head) and, with the gdn mixer at the END of the file, ``olmo_hybrid_config``.
 """
 from __future__ import annotations
 
@@ -88,6 +86,7 @@ import numpy as np
 from jax import lax
 
 from ..obs.phases import phase
+from ..ops.gated_delta import gated_delta_chunk, gated_delta_slots
 from ..ops.index_score import INDEX_SELECT_BLOCK
 from ..ops.index_score import by_blocks as _by_blocks
 from ..ops.index_score import index_score_pages, index_scores  # noqa: F401
@@ -104,8 +103,8 @@ _MASKED = -1e30                 # a score outside the selection / window
 MIXER_OF = {"M": "mamba2", "*": "attention", "E": "moe", "L": "mla"}
 # float32 whatever the model's dtype: the recurrence's own parameters and
 # the router (its scores decide a top-k)
-_F32_PARAMS = ("mamba.dt_bias", "mamba.dt_proj.bias", "mamba.A_log",
-               "mamba.D", "moe.router.weight", "moe.router.bias")
+_F32_PARAMS = (".dt_bias", "mamba.dt_proj.bias", ".A_log", "mamba.D",
+               "moe.router.weight", "moe.router.bias")  # mamba.* and gdn.*
 
 
 def _refuse_unbuilt(pub: dict) -> None:
@@ -543,13 +542,15 @@ def param_shapes(cfg: GPTConfig) -> Dict[str, Tuple[int, ...]]:
             out[p + "mamba.A_log"] = (n, ch)
             out[p + "mamba.D"] = (ch,)
             out[p + "mamba.out_proj.weight"] = (hd, ch)
+        elif mixer == "gdn":
+            out.update(_gdn_param_shapes(c, p))
         elif mixer == "attention":
             q, kv = c.num_heads * c.head_dim, c.kv_heads * c.head_dim
             out[p + "attn.qkv.weight"] = (q + 2 * kv, hd)
             out[p + "attn.out.weight"] = (hd, q)
             if c.attn_qk_norm:
-                out[p + "attn.q_norm.weight"] = (c.head_dim,)
-                out[p + "attn.k_norm.weight"] = (c.head_dim,)
+                out[p + "attn.q_norm.weight"] = (c.qk_norm_width(q),)
+                out[p + "attn.k_norm.weight"] = (c.qk_norm_width(kv),)
         elif mixer in ("mla", "dsa", "swa"):
             g = c.geometry(mixer)
             nh, d_c, qk = g.heads, g.latent, g.nope + g.rope
@@ -608,16 +609,13 @@ def init_state(cfg: GPTConfig, seed: int, time_step=(0.001, 0.1, 1e-4),
     layer so that no more than one layer's temporaries are live: matrices
     normal(0, init_std), the projections back into the residual stream
     (``out_proj``, ``attn.out``, ``latent_up``, ``shared.down``,
-    ``mlp.down``) scaled by
-    ``1 / sqrt(num_layers)`` (the published ``rescale_prenorm_residual``:
-    one residual branch a layer); norms 1, conv bias 0; ``dt`` log-uniform
-    in ``[time_step_min, time_step_max]`` floored at ``time_step_floor``
-    and stored as ``dt_bias = dt + log(-expm1(-dt))`` (inverse softplus),
-    ``A`` uniform 1..16 as ``A_log``, ``D`` 1 — the published
-    initialiser (a mamba1 layer's ``A_log`` [N, C] is ``log(1..N)`` along
-    its state axis and its ``dt_proj.bias`` the inverse softplus, the
-    published Mamba-1 one).  The hardware bit generator (``rbg``) keeps the
-    temporaries at the tensors' own size."""
+    ``mlp.down``) scaled by ``1 / sqrt(num_layers)`` (the published
+    ``rescale_prenorm_residual``); norms 1, conv bias 0; ``dt`` log-uniform
+    in ``[time_step_min, time_step_max]`` floored at ``time_step_floor``,
+    stored through the inverse softplus; ``A`` uniform 1..16 as ``A_log``
+    (a gdn layer's 0..16; a mamba1 layer's [N, C] is ``log(1..N)`` along
+    its state axis), ``D`` 1: the published initialisers.  The hardware bit
+    generator (``rbg``) keeps the temporaries at the tensors' own size."""
     shapes = param_shapes(cfg)
     groups: Dict[str, Dict[str, Tuple[int, ...]]] = {}   # layer -> tails
     for name, shape in shapes.items():
@@ -641,15 +639,17 @@ def init_state(cfg: GPTConfig, seed: int, time_step=(0.001, 0.1, 1e-4),
                 1, shape[0] + 1, dtype=dt))[:, None], shape)
         if tail == "mamba.A_log":
             return jnp.log(jax.random.uniform(key, shape, dt, 1.0, 16.0))
-        if tail in ("mamba.dt_bias", "mamba.dt_proj.bias"):
+        if tail == "gdn.A_log":
+            return jnp.log(jax.random.uniform(key, shape, dt, 1e-4, 16.0))
+        if tail.endswith((".dt_bias", "mamba.dt_proj.bias")):
             step = jnp.exp(jax.random.uniform(
                 key, shape, dt, math.log(t_min), math.log(t_max)))
             step = jnp.maximum(step, t_floor)
             return step + jnp.log(-jnp.expm1(-step))
-        s = down if tail in ("mamba.out_proj.weight", "attn.out.weight",
-                             "moe.latent_up.weight", "mlp.down.weight",
-                             "moe.shared.down.weight") else std
-        if tail == "mamba.conv.weight":             # fan-in K
+        s = down if tail.endswith((".out_proj.weight", "attn.out.weight",
+                                   "moe.latent_up.weight", "mlp.down.weight",
+                                   "moe.shared.down.weight")) else std
+        if tail.endswith(".conv.weight"):           # fan-in K
             s = 1.0 / math.sqrt(shape[0])
         return s * jax.random.normal(key, shape, dt)
 
@@ -1174,7 +1174,7 @@ def kv_window_attention(q, qpos, table, base, k_pages, v_pages,
 def attention_qkv(cfg: GPTConfig, params: dict, i: int, u):
     """A plain attention layer's way in, token by token on ``u`` [n, H]
     (normed): ``(q [n, nh, hd], k, v [n, kv, hd])``, q and k behind their
-    head-wise RMSNorm where the stack has one, before any rotation."""
+    RMSNorm (``_qk_norm``) where the stack has one, before any rotation."""
     nh, kv, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
     n = u.shape[0]
     qkv = u @ params[f"h{i}.attn.qkv.weight"].T
@@ -1182,8 +1182,8 @@ def attention_qkv(cfg: GPTConfig, params: dict, i: int, u):
     k = qkv[:, nh * hd:(nh + kv) * hd].reshape(n, kv, hd)
     v = qkv[:, (nh + kv) * hd:].reshape(n, kv, hd)
     if cfg.attn_qk_norm:
-        q = _rms(cfg, q, params[f"h{i}.attn.q_norm.weight"])
-        k = _rms(cfg, k, params[f"h{i}.attn.k_norm.weight"])
+        q = _qk_norm(cfg, q, params[f"h{i}.attn.q_norm.weight"])
+        k = _qk_norm(cfg, k, params[f"h{i}.attn.k_norm.weight"])
     return q, k, v
 
 
@@ -1314,3 +1314,203 @@ def latent_moe(cfg: GPTConfig, params: dict, i: int, u, live):
     idx, w, lat = moe_route_down(cfg, params, i, u)
     r, load = moe_routed(cfg, params, i, lat, idx, w, live)
     return moe_up_shared(cfg, params, i, u, r), load
+
+
+# -- the gdn mixer (a gated delta rule) and its stack -------------------------
+#
+# Everything of this mixer stands BEHIND the code above, and what it had to
+# change above keeps every other line where it was: a Pallas kernel's
+# payload carries its call stack, line numbers and all, so a line that moves
+# above another mixer's kernel call is another compile-cache key for that
+# mixer's stacks.
+#
+# Tensors (``nh`` heads of key width ``dk`` and value width ``dv``)::
+#
+#     h{i}.gdn.in_proj.weight [2*nh*dk + 2*nh*dv + 2*nh, H]  q | k | v | z | a | b
+#     h{i}.gdn.conv.weight [K, 2*nh*dk + nh*dv]     (over q | k | v; no bias)
+#     h{i}.gdn.dt_bias / .A_log [nh]  (float32)   h{i}.gdn.norm.weight [dv]
+#     h{i}.gdn.out_proj.weight [H, nh*dv]
+#     h{i}.attn.q_norm.weight / .k_norm.weight [nh*hd] / [kv*hd]
+#                                        (``cfg.attn_qk_norm_full``)
+
+L2_NORM_EPS = 1e-6              # under the root of a gdn head's q / k norm
+
+
+def _qk_norm(cfg: GPTConfig, t, w):
+    """The RMSNorm on q (k) ``t`` [n, heads, hd]: head by head, or over the
+    token's whole vector, all heads together (``attn_qk_norm_full``)."""
+    if not cfg.attn_qk_norm_full:
+        return _rms(cfg, t, w)
+    return _rms(cfg, t.reshape(t.shape[0], -1), w).reshape(t.shape)
+
+
+def _gdn_param_shapes(cfg: GPTConfig, p: str) -> Dict[str, Tuple[int, ...]]:
+    c, hd = cfg, cfg.hidden_size
+    nh, qk = c.linear_value_heads, c.linear_key_heads * c.linear_key_dim
+    val = nh * c.linear_value_dim
+    return {p + "gdn.in_proj.weight": (2 * qk + 2 * val + 2 * nh, hd),
+            p + "gdn.conv.weight": (c.linear_conv_kernel, c.linear_conv_dim),
+            p + "gdn.dt_bias": (nh,), p + "gdn.A_log": (nh,),
+            p + "gdn.norm.weight": (c.linear_value_dim,),
+            p + "gdn.out_proj.weight": (hd, val)}
+
+
+class GdnWeights:
+    """One gdn layer's tensors, looked up once."""
+
+    def __init__(self, params: dict, i: int):
+        g = lambda n: params[f"h{i}.gdn.{n}"]  # noqa: E731
+        self.in_proj, self.out_proj = g("in_proj.weight"), g("out_proj.weight")
+        # no conv bias: a zero where ``_conv_rows`` adds one
+        self.conv_w, self.conv_b = g("conv.weight"), jnp.zeros((), F32)
+        self.dt_bias, self.a_log = g("dt_bias"), g("A_log")
+        self.norm = g("norm.weight")
+
+
+def gdn_split(cfg: GPTConfig, proj):
+    """``proj`` [n, in_proj width] -> ``(qkv [n, conv_dim], z [n, nh * dv],
+    a [n, nh], b [n, nh])``."""
+    cd, nh = cfg.linear_conv_dim, cfg.linear_value_heads
+    val = nh * cfg.linear_value_dim
+    return (proj[..., :cd], proj[..., cd:cd + val],
+            proj[..., cd + val:cd + val + nh], proj[..., cd + val + nh:])
+
+
+def _delta_inputs(cfg: GPTConfig, w: GdnWeights, conv_out, a_raw, b_raw):
+    """What the delta rule reads, from the conv's output [n, conv_dim]
+    float32: ``silu``, the split into heads, q and k to unit length (q x
+    ``dk ** -0.5``), ``beta = sigmoid(b)`` (x 2 under ``linear_neg_
+    eigval``), ``alpha = exp(-exp(A_log) softplus(a + dt_bias))``."""
+    c = cfg
+    n, nh, dk, dv = (conv_out.shape[0], c.linear_value_heads,
+                     c.linear_key_dim, c.linear_value_dim)
+    x = jax.nn.silu(conv_out)
+    q = x[:, :nh * dk].reshape(n, nh, dk)
+    k = x[:, nh * dk:2 * nh * dk].reshape(n, nh, dk)
+    v = x[:, 2 * nh * dk:].reshape(n, nh, dv)
+    unit = lambda t: t * lax.rsqrt(                          # noqa: E731
+        jnp.sum(t * t, -1, keepdims=True) + L2_NORM_EPS)
+    beta = jax.nn.sigmoid(b_raw.astype(F32)) * \
+        (2.0 if c.linear_neg_eigval else 1.0)
+    alpha = jnp.exp(-jnp.exp(w.a_log.astype(F32)) * jax.nn.softplus(
+        a_raw.astype(F32) + w.dt_bias.astype(F32)))
+    return unit(q) * dk ** -0.5, unit(k), v, alpha, beta
+
+
+def gdn_rows(cfg: GPTConfig, w: GdnWeights, proj, conv_state, ssm_state,
+             live, fresh, walk):
+    """``mamba_rows`` for a gdn layer: ``proj`` [S, in_proj width] in SLOT
+    order, ``conv_state`` [S, K-1, conv_dim], ``ssm_state`` [S, nh / p, dk,
+    p * dv] float32 — the store, of which the recurrence reads and writes
+    the slots of ``walk`` in place (``ops.gated_delta.gated_delta_slots``).
+    Returns ``(o [S, nh * dv] float32 before the gated norm, zeros where
+    not ``live``; new conv state; the store)``."""
+    qkv, _, a_raw, b_raw = gdn_split(cfg, proj)
+    conv, new_conv = _conv_rows(w, qkv, conv_state, live, fresh)
+    with phase("ssm_scan"):
+        q, k, v, alpha, beta = _delta_inputs(cfg, w, conv, a_raw, b_raw)
+        o, new_ssm = gated_delta_slots(q, k, v, alpha, beta, ssm_state,
+                                       *walk, fresh)
+    return o.reshape(o.shape[0], -1), new_conv, new_ssm
+
+
+def gdn_chunk(cfg: GPTConfig, w: GdnWeights, proj, conv_state, ssm_state,
+              slot, length, fresh):
+    """A run of ``length`` (<= T) consecutive tokens of ONE sequence whose
+    state is slot ``slot`` of the stores: ``proj`` [T, in_proj width].  The
+    conv tail is taken out and put back; the matrix state is carried in
+    place (``gated_delta_chunk``), tokens past ``length`` leaving both as
+    they found them.  Returns ``(o [T, nh * dv] float32 before the gated
+    norm, the conv store, the state store)``."""
+    qkv, _, a_raw, b_raw = gdn_split(cfg, proj)
+    with phase("state_io"):
+        tail0 = conv_state[slot]
+    with phase("ssm_conv"):
+        conv, tail1 = causal_conv(qkv, w.conv_w, None,
+                                  jnp.where(fresh, 0, tail0), length)
+    with phase("state_io"):
+        conv_state = conv_state.at[slot].set(tail1)
+    with phase("ssm_scan"):
+        q, k, v, alpha, beta = _delta_inputs(cfg, w, conv, a_raw, b_raw)
+        o, ssm_state = gated_delta_chunk(q, k, v, alpha, beta, ssm_state,
+                                         slot, length, fresh)
+    return o.reshape(o.shape[0], -1), conv_state, ssm_state
+
+
+def gdn_gate_norm(cfg: GPTConfig, w: GdnWeights, o, z, dtype):
+    """``RMSNorm_dv(o; w) * silu(z)``, head by head: the norm comes before
+    the gate (a mamba2 layer's gate comes before its norm)."""
+    n = o.shape[0]
+    v = _rms(cfg, o.reshape(n, -1, cfg.linear_value_dim), w.norm)
+    return (v.reshape(n, -1) * jax.nn.silu(z.astype(F32))).astype(dtype)
+
+
+# The mixers whose chunk form carries a row's state IN its slot of the
+# store, as the serving step's one branch for them calls them: ``(weights,
+# rows, chunk, gate)`` — ``rows(cfg, w, proj [S, width], conv store, state
+# store, live, fresh, walk)`` and ``chunk(cfg, w, proj [T, width], conv
+# store, state store, slot, length, fresh)`` both return ``(y before the
+# gate, conv store, state store)``; ``gate(cfg, w, y, proj, dtype)`` what
+# the way out reads.
+IN_SLOT_MIXERS = {
+    "mamba1": (Mamba1Weights, mamba1_rows, mamba1_chunk,
+               lambda cfg, w, y, xz, dtype: mamba1_gate(
+                   y, xz[..., cfg.mamba1_inner:], dtype)),
+    "gdn": (GdnWeights, gdn_rows, gdn_chunk,
+            lambda cfg, w, o, proj, dtype: gdn_gate_norm(
+                cfg, w, o, gdn_split(cfg, proj)[1], dtype)),
+}
+
+
+def olmo_hybrid_config(pub: dict, **overrides) -> GPTConfig:
+    """The one translation from the published ``config.json`` keys of an
+    ``olmo_hybrid``-type model to ``GPTConfig``.  A published layer is two
+    pattern entries, each with its own RMSNorm and residual: its mixer —
+    "gdn" for a ``linear_attention`` entry of ``layer_types`` (a gated
+    delta rule over ``linear_num_value_heads`` heads of ``linear_key_head_
+    dim`` x ``linear_value_head_dim``, q | k | v behind one causal conv of
+    ``linear_conv_kernel_dim`` taps, ``beta`` up to 2 where
+    ``linear_allow_neg_eigval``), "attention" for a ``full_attention`` one
+    (``num_key_value_heads`` K/V heads of ``hidden / heads`` lanes, no
+    bias) — then "mlp", ``down(silu(gate u) * up u)`` of
+    ``intermediate_size``.  What the keys do not spell is the family's
+    convention, which the caller states (``pub["assumed"]``) and this
+    translation builds: the norm on a sublayer's OUTPUT (``norm_position``
+    "post"), the QK-norm over a token's whole q (k) vector, no rotation
+    (``rope_theta`` null)."""
+    kinds = {"linear_attention": "gdn", "full_attention": "attention"}
+    types = pub["layer_types"]
+    unbuilt = [k for k, ok in (
+        ("layer_types", len(types) == pub["num_hidden_layers"]
+         and not set(types) - set(kinds)),
+        ("attention_bias", not pub.get("attention_bias")),
+        ("rope_parameters.rope_theta",
+         (pub.get("rope_parameters") or {}).get("rope_theta") is None),
+        ("linear_num_key_heads", pub["linear_num_key_heads"]
+         == pub["linear_num_value_heads"])) if not ok]
+    if unbuilt:
+        raise ValueError(
+            "olmo_hybrid is built with linear_attention / full_attention "
+            "layers, one for each of num_hidden_layers, no projection bias, "
+            "no rotation and as many key heads as value heads; not built: "
+            + ", ".join(unbuilt))
+    pattern = tuple(m for t in types for m in (kinds[t], "mlp"))
+    kw = dict(
+        vocab_size=pub["vocab_size"], hidden_size=pub["hidden_size"],
+        num_layers=len(pattern), num_heads=pub["num_attention_heads"],
+        num_kv_heads=pub["num_key_value_heads"],
+        attn_qk_norm=True, attn_qk_norm_full=True, norm_position="post",
+        ffn_hidden_size=pub["intermediate_size"],
+        max_seq_len=pub["max_position_embeddings"],
+        activation=pub["hidden_act"], norm="rmsnorm", position="none",
+        norm_eps=float(pub["rms_norm_eps"]),
+        tie_embeddings=bool(pub["tie_word_embeddings"]), sp=False,
+        dtype=pub.get("dtype", "bfloat16"), layer_pattern=pattern,
+        linear_key_heads=pub["linear_num_key_heads"],
+        linear_value_heads=pub["linear_num_value_heads"],
+        linear_key_dim=pub["linear_key_head_dim"],
+        linear_value_dim=pub["linear_value_head_dim"],
+        linear_conv_kernel=pub["linear_conv_kernel_dim"],
+        linear_neg_eigval=bool(pub["linear_allow_neg_eigval"]))
+    kw.update(overrides)
+    return GPTConfig(**kw)

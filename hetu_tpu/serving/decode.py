@@ -49,7 +49,7 @@ from jax import lax
 
 from ..models.generate import (_act, _lm_head, _moe_mlp, _norm_apply,
                                _Params, _rotary_tables)
-from ..models.gpt import GPTConfig
+from ..models.gpt import STATE_MIXERS, GPTConfig
 from ..obs.phases import phase
 from ..ops.paged_attention import gather_pages, paged_attention_reference
 from ..ops.paged_kv_write import (kv_write_plan, paged_kv_write,
@@ -589,14 +589,14 @@ def build_unified_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
                 "a pattern stack (layer_pattern) is built without page "
                 "quantization: its page layouts have no quantized form")
         if spec_k and (spec_k > 1 or not c.mtp_pattern or set(
-                c.layer_pattern) & {"mamba2", "mamba1", "mla", "dsa", "swa"}):
+                c.layer_pattern) & {*STATE_MIXERS, "mla", "dsa", "swa"}):
             raise ValueError(
                 "speculative verify rows on a pattern stack are fed by its "
                 "own MTP module, one draft a row (spec_k 1, "
                 "cfg.mtp_pattern), over plain K/V attention layers: a "
-                "rejected draft cannot be rolled out of recurrent (mamba2 / "
-                "mamba1) state, and the latent layers' by-region calls have no "
-                "verify region")
+                "rejected draft cannot be rolled out of recurrent "
+                f"({' / '.join(STATE_MIXERS)}) state, and the latent layers' "
+                "by-region calls have no verify region")
         _refuse_unbuilt_block(c, chunk, page_size, spec_k)
         return _build_hybrid_step_fn(c, max_seqs, chunk, prefill_rows,
                                      max_pages, page_size, use_kernel,
@@ -999,8 +999,8 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
     rows run SLOT-major: the rows' projections are permuted into slot
     order and the outputs permuted back.  The slots with a live decode
     row this step are listed once a step (``ops.ssd.live_slot_list``) and
-    every mamba2 layer's recurrence WALKS that list
-    (``ops.ssd.ssd_decode_slots``): a listed slot's state is read,
+    every recurrent layer's recurrence WALKS that list (``ops.ssd.
+    ssd_decode_slots``, ``hy.IN_SLOT_MIXERS``): a listed slot's state is read,
     updated and written back in place, once, never gathered; a slot
     outside the list is neither read nor written (the conv tails, 1.5 %
     of the store's bytes, are passed over whole).  A chunk slot takes its
@@ -1147,8 +1147,8 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
             slot_live = slot_row >= 0
             slot_src = jnp.maximum(slot_row, 0)
             slot_fresh = slot_live & fresh_row[slot_src]
-            # the live slots, compact: what every state-space layer's
-            # recurrence walks (hy.mamba_rows / hy.mamba1_rows)
+            # the live slots, compact: what every recurrent layer's
+            # recurrence walks (hy.mamba_rows, hy.IN_SLOT_MIXERS' rows)
             walk = live_slot_list(slot_live) if mamba_of else None
         if use_kernel and attn_of:
             tile = write_tile((k_pages[0],) + tuple(v_pages[:1]))
@@ -1276,7 +1276,7 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
 
         for i, mixer in enumerate(c.layer_pattern):
             with phase("norm"):
-                h = _norm_apply(c, p.layer(i, "norm.weight"), None, x)
+                h = norm_in(p.layer(i, "norm.weight"), x)
             if mixer == "attention" and by_layer_kv:
                 out = kv_attention(i, h)
             elif mixer == "attention":
@@ -1468,35 +1468,35 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
                     out = by_region(
                         lambda yy, w=w: yy @ w.out_proj.T, y, q_lens)
                 new_conv[m], new_ssm[m] = conv_s, ssm_s
-            elif mixer == "mamba1":
+            elif mixer in hy.IN_SLOT_MIXERS:
                 m = mamba_of[i]
-                w = hy.Mamba1Weights(params, i)
+                weights, rows_of, chunk_of, gate = hy.IN_SLOT_MIXERS[mixer]
+                w = weights(params, i)
                 with phase("ssm_proj"):
                     xz = by_region(
                         lambda hh, w=w: hh @ w.in_proj.T, h, q_lens)
                 with phase("state_io"):
                     xz_slots = xz[:max_seqs][slot_src]
-                y_slots, conv_s, ssm_s = hy.mamba1_rows(
+                y_slots, conv_s, ssm_s = rows_of(
                     c, w, xz_slots, new_conv[m], new_ssm[m], slot_live,
                     slot_fresh, walk)
                 with phase("state_io"):
                     ys = [y_slots[state_slots[:max_seqs]]]
                 for row, start, width in slots:
-                    # the row's state stays in its slot: the scan carries
-                    # it through the chunk in place
+                    # the row's state stays in its slot: the chunk form
+                    # carries it through the chunk in place
                     y_c, conv_s, ssm_s = lax.cond(
                         q_lens[row] > 0,
-                        lambda z, cs, ss, sl, n, f, w=w: hy.mamba1_chunk(
+                        lambda z, cs, ss, sl, n, f, w=w: chunk_of(
                             c, w, z, cs, ss, sl, n, f),
                         lambda z, cs, ss, sl, n, f: (
-                            jnp.zeros((width, c.mamba1_inner), jnp.float32),
-                            cs, ss),
+                            jnp.zeros((width, y_slots.shape[1]),
+                                      jnp.float32), cs, ss),
                         xz[start: start + width], conv_s, ssm_s,
                         state_slots[row], q_lens[row], fresh_row[row])
                     ys.append(y_c)
                 with phase("ssm_scan"):
-                    y = hy.mamba1_gate(jnp.concatenate(ys, axis=0),
-                                       xz[..., c.mamba1_inner:], x.dtype)
+                    y = gate(c, w, jnp.concatenate(ys, axis=0), xz, x.dtype)
                 with phase("ssm_proj"):
                     out = by_region(
                         lambda yy, w=w: yy @ w.out_proj.T, y, q_lens)
@@ -1504,7 +1504,7 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
             else:
                 out, load = expert_layer(i, h, live)
                 loads.append(load)
-            x = x + out.astype(x.dtype)
+            x = x + norm_out(p.layer(i, "norm.weight"), out.astype(x.dtype))
         x_last = x
         with phase("norm"):
             x = _norm_apply(c, p("ln_f.weight"), None, x)
@@ -1577,5 +1577,22 @@ def _build_hybrid_step_fn(cfg: GPTConfig, max_seqs: int, chunk: int,
                         draft=draft, mtp_load=stack(mtp_loads))
         return (layout.join(**outs), tuple(new_k), tuple(new_v),
                 tuple(new_conv), tuple(new_ssm))
+
+    # the sublayer's one norm, where the configuration puts it: on the
+    # sublayer's input ("pre", under the loop's own phase) or on its output
+    # ("post": ``x + Norm(f(x))``).  Defined behind ``run`` so that no line
+    # of it moves: a Pallas kernel's payload carries its call stack, line
+    # numbers and all, and a shifted line is another compile-cache key for
+    # every stack this builder serves
+    post_norm = c.norm_position == "post"
+
+    def norm_in(w, x):
+        return x if post_norm else _norm_apply(c, w, None, x)
+
+    def norm_out(w, out):
+        if not post_norm:
+            return out
+        with phase("norm"):
+            return _norm_apply(c, w, None, out)
 
     return jax.jit(run, donate_argnums=(2, 3, 4, 5))

@@ -143,11 +143,10 @@ from ..models.generate import _Params
 from ..models.gpt import GPTConfig
 from ..obs.tracer import get_tracer
 from ..ops.pallas import on_tpu
-from ..ops.selective_scan import state_shape
 from ..utils.metrics import make_instrument, render_prometheus
 from .decode import StepLayout, build_unified_step_fn
 from .kv_pool import (TRASH_PAGE, PagedKVPool, StateSlotStore,
-                      protocol_seq, window_table_pages)
+                      protocol_seq, state_store_layout, window_table_pages)
 from .prefix_cache import PrefixCache
 from .request import (FINISHED, RUNNING, DenoiseRule, Request,
                       RequestQueue)
@@ -241,8 +240,8 @@ class Engine:
         self.page_quant = page_quant
         # a hybrid stack (cfg.layer_pattern) keeps K/V for its attention
         # layers only and a recurrent-state slot per running sequence for
-        # its mamba2 / mamba1 layers.  What is not built for recurrent state is
-        # refused here, not run wrong: a cached prefix would need the
+        # its recurrent (models.gpt.STATE_MIXERS) layers.  What is not built
+        # for recurrent state is refused here, not run wrong: a cached prefix would need the
         # state AT the cached boundary, a rejected draft a roll-back
         self.hybrid = cfg.is_hybrid
         kind = cfg.state_mixer if self.hybrid else None
@@ -330,18 +329,19 @@ class Engine:
                                 window_tokens=self.window)
         self.state_store: Optional[StateSlotStore] = None
         if kind:
-            # a mamba1 layer's conv runs over its x channels alone and its
-            # scan state lies as the scan walks it
-            conv_dim, shape = (
-                cfg.mamba1_inner, state_shape(cfg.mamba1_inner,
-                                              cfg.mamba_state_dim)) \
-                if kind == "mamba1" else (
-                    cfg.mamba_conv_dim, (cfg.mamba_num_heads,
-                                         cfg.mamba_head_dim,
-                                         cfg.mamba_state_dim))
+            # by kind (kv_pool.state_store_layout): the taps and channels
+            # of its conv (a mamba1 layer's runs over its x alone, a gdn
+            # layer's over q | k | v) and its state as its recurrence
+            # walks it: [heads, head_dim, state] for mamba2, [state,
+            # channels / 128, 128] for mamba1, [heads / 2, key_dim, 2 x
+            # value_dim] for gdn (two heads side by side fill the
+            # lanes).  One layout a store, so one kind a pattern; what a
+            # slot costs a sequence follows from it: 27.4 MB at the
+            # widths of olmohybrid-pp2 (12 layers), 9.3 at jamba2-3b's
+            # (26 layers)
             self.state_store = StateSlotStore(
                 len(cfg.layers_of(kind)), int(max_batch),
-                cfg.mamba_conv_kernel, conv_dim, shape, conv_dtype=dtype)
+                *state_store_layout(cfg, kind), conv_dtype=dtype)
             self.pool.state_slots = self.state_store
         # copy-on-write prefix reuse: finished requests' full pages are
         # indexed by chained token hash; _start attaches the longest

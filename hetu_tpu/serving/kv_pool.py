@@ -41,6 +41,8 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..ops import gated_delta, selective_scan
+
 TRASH_PAGE = 0
 
 # Process-global protocol sequence counter.  Every record plane the
@@ -84,7 +86,8 @@ class StateSlotStore:
     last ``K - 1`` inputs and the float32 scan state of each state-space
     layer: ``[heads, head_dim, state]`` for mamba2, ``[state, channels /
     128, 128]`` for mamba1 — the layout its scan walks,
-    ``ops.selective_scan.state_shape``) and never grows.  The engine owns
+    ``ops.selective_scan.state_shape`` — ``[heads / p, key_dim, p *
+    value_dim]`` for gdn, ``ops.gated_delta.state_shape``) and never grows.  The engine owns
     the store beside the pool and moves a request's slot with its pages:
     allocated at admission, freed at finish, at preemption (recompute:
     the state is dropped and the sequence re-prefilled) and at abort.
@@ -93,7 +96,8 @@ class StateSlotStore:
     slot's old content cannot reach the sequence that takes it next.
     A decode step reads and writes, in place, the scan state of the slots
     that have a live decode row (``ops.ssd.ssd_decode_slots``,
-    ``ops.selective_scan.selective_scan_slots``) and of no
+    ``ops.selective_scan.selective_scan_slots``,
+    ``ops.gated_delta.gated_delta_slots``) and of no
     other: a slot that is free, or whose sequence is waiting or
     prefilling, keeps its bytes untouched.
 
@@ -164,6 +168,27 @@ class StateSlotStore:
             out.append("state slots leaked or invented: "
                        f"{sorted(set(range(self.num_slots)) ^ (free | held))}")
         return out
+
+
+def state_store_layout(cfg, kind: str):
+    """``(conv taps, conv channels, per-slot state shape)`` of the store a
+    pattern with recurrent mixer ``kind`` (``models.gpt.STATE_MIXERS``)
+    keeps: the channels its conv runs over, and its state as its
+    recurrence walks it."""
+    if kind == "mamba2":
+        return (cfg.mamba_conv_kernel, cfg.mamba_conv_dim,
+                (cfg.mamba_num_heads, cfg.mamba_head_dim,
+                 cfg.mamba_state_dim))
+    if kind == "mamba1":
+        return (cfg.mamba_conv_kernel, cfg.mamba1_inner,
+                selective_scan.state_shape(cfg.mamba1_inner,
+                                           cfg.mamba_state_dim))
+    if kind == "gdn":
+        return (cfg.linear_conv_kernel, cfg.linear_conv_dim,
+                gated_delta.state_shape(
+                    cfg.linear_value_heads, cfg.linear_key_dim,
+                    cfg.linear_value_dim))
+    raise ValueError(f"no state store is laid out for mixer {kind!r}")
 
 
 class WindowPages:

@@ -23,10 +23,11 @@ from ..ops.ragged_paged_attention import (kv_call_blocking,
 from .decode import _regions
 
 COUNTERS = (
-    # mamba2: slots the decode recurrence walked (live decode rows) / held
+    # recurrent state: slots the decode recurrence walked (live decode
+    # rows) / held
     "ssm_slots_walked", "ssm_slots_store",
-    # mamba1: live tokens the chunk scan walked / the tokens its chunk
-    # slots hold (what a scan padded to the slot would walk)
+    # mamba1 / gdn: live tokens the chunk form walked / the tokens its chunk
+    # slots hold (what a form padded to the slot would walk)
     "ssm_chunk_tokens_walked", "ssm_chunk_tokens_padded",
     # experts: live (token, expert) assignments on the held experts / over
     # all experts; rows the grouped kernel computed
@@ -132,6 +133,9 @@ class StepAccount:
         self.cfg, self.pool, self.state_store = cfg, pool, state_store
         self.counters, self.gauges = counters, gauges
         self.max_batch, self.chunk = scheduler.max_batch, scheduler.chunk
+        # one row's recurrent state, ONE layer's, as the store lays it
+        self.state_row_bytes = state_store.ssm[0].nbytes // \
+            state_store.num_slots if state_store is not None else 0
         self.vbase = scheduler.max_batch + scheduler.prefill_rows
         geo = cfg.mixer_geometry or {}
         self.index_topk = geo["dsa"].index_topk if "dsa" in geo else 0
@@ -182,7 +186,7 @@ class StepAccount:
                 if width > 1 else 0)
         self.parts = tuple(part for on, part in (
             (cfg.state_mixer, self._state),
-            (cfg.layers_of("mamba1"), self._scan),
+            (cfg.state_mixer in ("mamba1", "gdn"), self._scan),
             (cfg.layers_of("moe"), self._moe),
             (full is not None, self._kv),
             (cfg.layers_of("mla"), self._latent),
@@ -207,9 +211,11 @@ class StepAccount:
         self.counters["ssm_slots_store"].inc(store.num_slots)
 
     def _scan(self, st, out, traced):
-        """The selective scan's walks (one mamba1 layer's): the live
-        tokens of the chunk rows, their slots' width, the decode rows; and
-        what the attention layers beside it read."""
+        """The walks of a recurrence that carries a chunk row's state in
+        its slot (one mamba1 or gdn layer's): the live tokens of the chunk
+        rows, their slots' width, the decode rows, the bytes of state the
+        walked rows move (in and out, once); and what the attention layers
+        beside it read."""
         chunk = [q for q, row in zip(st.q, st.row)
                  if self.max_batch <= row < self.vbase]
         walked, padded = sum(chunk), len(chunk) * self.chunk
@@ -220,6 +226,7 @@ class StepAccount:
         return dict(ssm_chunk_tokens=walked, ssm_chunk_padded=padded,
                     ssm_chunk_rows=len(chunk),
                     ssm_decode_rows=len(st.q) - len(chunk),
+                    ssm_state_bytes=2 * len(st.q) * self.state_row_bytes,
                     attn_pairs=st.pairs,
                     kv_pages_distinct=st.distinct_pages)
 

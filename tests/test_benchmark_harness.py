@@ -281,9 +281,11 @@ def test_new_layer_metric_names_a_reader_and_a_cell(name):
     # configuration's own metrics list its cell alone)
     own = {"nemotron3s-ep4.serve-chat", "mistral4-ep4.serve-longdoc",
            "dots3-ep8.serve-longctx", "kexaone-ep8.serve-reason",
-           "sdar30b-pp8.serve-chat", "jamba2-3b.serve-longdoc"}
+           "sdar30b-pp8.serve-chat", "jamba2-3b.serve-longdoc",
+           "olmohybrid-pp2.serve-reason"}
     family = {"chat": {"cgpt590m.serve-chat", "nemotron3s-ep4.serve-chat",
-                       "kexaone-ep8.serve-reason", "sdar30b-pp8.serve-chat"},
+                       "kexaone-ep8.serve-reason", "sdar30b-pp8.serve-chat",
+                       "olmohybrid-pp2.serve-reason"},
               "replay": {"cgpt590m.serve-prefix",
                          "mistral4-ep4.serve-longdoc",
                          "dots3-ep8.serve-longctx",

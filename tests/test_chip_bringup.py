@@ -36,6 +36,7 @@ mg = importlib.import_module("hetu_tpu.ops.moe_grouped")
 ssd = importlib.import_module("hetu_tpu.ops.ssd")
 ix = importlib.import_module("hetu_tpu.ops.index_score")
 sscan = importlib.import_module("hetu_tpu.ops.selective_scan")
+gdelta = importlib.import_module("hetu_tpu.ops.gated_delta")
 
 I32, BF16, F32 = jnp.int32, jnp.bfloat16, jnp.float32
 # the serving step's ragged batch at GPT-2 124M widths: 16 decode rows +
@@ -153,6 +154,27 @@ def _kernel_cases():
         return sscan.selective_scan_slots(x, dt, a, b, c, d, store, slots,
                                           n, fresh, interpret=False)
 
+    def delta_chunk(q, k, v, a, b, store, slot, n, fresh):
+        # a gdn layer's chunk form over a 256-token chunk at the two-stage
+        # configuration's widths: 30 heads of 96 x 192, two side by side
+        # on the state's 384 lanes, the row's state in its slot of a
+        # 48-slot store; a triangular solve and five float32 matmuls a
+        # block of 64 tokens
+        return gdelta.gated_delta_chunk(q, k, v, a, b, store, slot, n,
+                                        fresh, interpret=False)
+
+    def delta_slots(q, k, v, a, b, store, slots, n, fresh):
+        # ... and its one-token walk over the live slots: a whole 2.2 MB
+        # slot a grid step, sums over sublanes against lane-broadcast
+        # columns
+        return gdelta.gated_delta_slots(q, k, v, a, b, store, slots, n,
+                                        fresh, interpret=False)
+
+    def delta_args(t, *tail):
+        return (_sds((t, 30, 96), F32), _sds((t, 30, 96), F32),
+                _sds((t, 30, 192), F32), _sds((t, 30), F32),
+                _sds((t, 30), F32), _sds((48, 15, 96, 384), F32), *tail)
+
     def scan_args(t, *tail):
         return (_sds((t, 5120), F32), _sds((t, 5120), F32),
                 _sds((16, 5120), F32), _sds((t, 16), F32), _sds((t, 16), F32),
@@ -231,6 +253,10 @@ def _kernel_cases():
             1024, _sds((), I32), _sds((), I32), _sds((), jnp.bool_))),
         "selective_scan_slots": (scan_slots, scan_args(
             16, _sds((16,), I32), _sds((1,), I32), _sds((16,), jnp.bool_))),
+        "gated_delta_chunk": (delta_chunk, delta_args(
+            256, _sds((), I32), _sds((), I32), _sds((), jnp.bool_))),
+        "gated_delta_slots": (delta_slots, delta_args(
+            48, _sds((48,), I32), _sds((1,), I32), _sds((48,), jnp.bool_))),
         # 20 query heads on ONE kv head at a 1,024-token chunk: the window
         # is cut in two (ragged_paged_attention.window_split)
         # the block-wise model's block region: 64 slots two blocks of 4
@@ -271,7 +297,9 @@ def _kernel_cases():
 # scan: blocks of B and C scalars in SMEM (whole 1,024-word tiles), 16
 # state registers a channel group over a token loop; the K/V call of 20
 # query heads on one kv head, whose 1,024-token window runs out of VMEM
-# uncut; the block region's call at two blocks a slot under the block mask
+# uncut; the block region's call at two blocks a slot under the block mask;
+# the gated delta rule: float32 matmuls of 64 x 96 and 96 x 384 operands
+# (neither a multiple of 128) and a 2.2 MB state block in and out
 AOT_CASES = ("ragged_12kv_x64", "ragged_decode_region",
              "ragged_chunk_region", "ragged_decode_gqa16",
              "ragged_verify_gqa8_x272", "ragged_chunk_gqa8_x272",
@@ -281,7 +309,8 @@ AOT_CASES = ("ragged_12kv_x64", "ragged_decode_region",
              "latent_256_128_chunk_region", "ssd_decode_slots",
              "index_score_chunk", "index_score_decode",
              "selective_scan_chunk", "selective_scan_slots",
-             "ragged_chunk_mqa20_x528", "ragged_block_gqa8_x41")
+             "ragged_chunk_mqa20_x528", "ragged_block_gqa8_x41",
+             "gated_delta_chunk", "gated_delta_slots")
 
 
 @pytest.fixture
