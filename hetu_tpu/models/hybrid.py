@@ -91,8 +91,8 @@ from ..ops.index_score import INDEX_SELECT_BLOCK
 from ..ops.index_score import by_blocks as _by_blocks
 from ..ops.index_score import index_score_pages, index_scores  # noqa: F401
 from ..ops.moe_grouped import ACTIVATIONS, grouped_experts
-from ..ops.selective_scan import (selective_scan_chunk,
-                                   selective_scan_slots)
+from ..ops.selected_attention import attend_selected, selected_slots
+from ..ops.selective_scan import selective_scan_chunk, selective_scan_slots
 from ..ops.ssd import causal_conv, ssd_chunk_scan, ssd_decode_slots
 from .generate import norm_eps
 from .gpt import GPTConfig, LatentGeometry
@@ -1082,16 +1082,15 @@ def indexed_attention(geo: LatentGeometry, iq, iw, q_cat, qpos, table,
     ``[P, 1, ps, w]``; ``iq [n, IH, ID]`` / ``iw [n, IH]`` the rotated
     index queries and head weights, ``q_cat [n, nh, w]`` the absorbed
     query, its rotary part padded as the page's.  The scores come out of
-    the key pages in one call (``ops/index_score.py``: a Pallas kernel on
-    the TPU, ``use_kernel`` as everywhere); the selection and the read
-    behind it run ``block`` queries at a time.  ``live`` (a chunk only: a
-    traced scalar, the row's ``q_len``) says how many leading queries hold
-    a token: the blocks behind the last of them are neither selected for
-    nor read for and give zeros, which nothing reads (a dead token writes
-    no page and no token is sampled from it); the scoring call scores the
-    slot's rows as before.  Returns the latent output ``[n, nh, d_c]``
-    float32.  The selection stays on the device: nothing of it leaves the
-    step."""
+    the key pages in one call (``ops/index_score.py``) and the attention
+    over the gathered rows is one more (``ops/selected_attention.py``):
+    Pallas kernels on the TPU, ``use_kernel`` as everywhere, which reaches
+    both.  Selection and read run ``block`` queries at a time.  ``live`` (a
+    chunk only: a traced scalar, the row's ``q_len``): how many leading
+    queries hold a token; the blocks behind the last of them are neither
+    selected for nor read for and give zeros, which nothing reads (a dead
+    token writes no page, none is sampled from it); the scoring call is as
+    before.  Returns the latent output ``[n, nh, d_c]`` float32."""
     cp = pools[0].reshape(-1, pools[0].shape[-1])
     ps = pools[0].shape[2]
     shared = table.ndim == 1
@@ -1106,12 +1105,13 @@ def indexed_attention(geo: LatentGeometry, iq, iw, q_cat, qpos, table,
         with phase("attn_index"):
             pos, valid = index_select(sc_b, qpos_b, geo.index_topk)
         with phase("attn_sparse"):
-            page = jnp.take_along_axis(
-                jnp.broadcast_to(tab, (pos.shape[0], tab.shape[-1])),
-                pos // ps, axis=1)
-            slot = page * ps + pos % ps                         # [n, k]
-            return selected_attention(qc_b, cp[slot], geo.latent, valid,
-                                      geo.scale)
+            # the pages by a one-hot product, not a gather of 2,048 words a
+            # query out of the table: 0.67 -> 0.03 ms a block of 32 queries
+            # (PERF.md section 6, PR 59, step 0)
+            slot = selected_slots(tab, pos, ps)                 # [n, k]
+            return attend_selected(
+                qc_b, cp[slot], geo.latent, valid, geo.scale,
+                xla=selected_attention, use_kernel=use_kernel)
 
     return _by_blocks(one, (scores, q_cat, qpos) +
                       (() if shared else (table,)), block,

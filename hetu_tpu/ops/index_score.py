@@ -42,9 +42,9 @@ F32 = jnp.float32
 # walks, and the VMEM the group's key buffers and float32 score tiles may
 # take (PERF.md section 6, PR 49, step 0)
 INDEX_QUERY_BLOCK = 64
-# queries of a region whose selection and sparse read are live together
-# (``models/hybrid.py::indexed_attention``; ``serving/step_account.py``
-# counts a chunk slot's blocks by it)
+# queries whose selection and sparse read are live together: top-k, look-up,
+# row gather and ``ops/selected_attention.py``'s call over the gathered rows
+# (``hy.indexed_attention``; ``serving/step_account.py`` counts blocks by it)
 INDEX_SELECT_BLOCK = 32
 INDEX_GROUP_MAX = 32
 INDEX_GROUP_VMEM = 40 << 20

@@ -37,6 +37,7 @@ ssd = importlib.import_module("hetu_tpu.ops.ssd")
 ix = importlib.import_module("hetu_tpu.ops.index_score")
 sscan = importlib.import_module("hetu_tpu.ops.selective_scan")
 gdelta = importlib.import_module("hetu_tpu.ops.gated_delta")
+selatt = importlib.import_module("hetu_tpu.ops.selected_attention")
 
 I32, BF16, F32 = jnp.int32, jnp.bfloat16, jnp.float32
 # the serving step's ragged batch at GPT-2 124M widths: 16 decode rows +
@@ -195,6 +196,13 @@ def _kernel_cases():
             _sds((528,) if shared else (rows, 528), I32),
             _sds(() if shared else (rows,), I32)))
 
+    def selected(q_cat, sel, valid):
+        # a block of 32 queries of a full layer behind its gather: 128
+        # heads over 2,048 rows of 640 bf16 lanes a query, a query a grid
+        # step (its rows double-buffered: 5.2 MB of VMEM)
+        return selatt.selected_attention_pallas(
+            q_cat, sel, valid, d_c=512, scale=192 ** -0.5, interpret=False)
+
     def flash_qkv_grad(x):
         return jax.grad(lambda x: fa.flash_attention_qkv(
             x, 12, causal=True).astype(F32).sum())(x)
@@ -267,6 +275,9 @@ def _kernel_cases():
         "ragged_decode_mqa20_x528": region(16, 1, 20, 1, 528),
         "index_score_chunk": index_score(CHUNK, True),
         "index_score_decode": index_score(32, False),
+        "selected_attention": (selected, (
+            _sds((32, 128, 640), F32), _sds((32, 2048, 640), BF16),
+            _sds((32, 2048), jnp.bool_))),
         "moe_grouped_gated_tiled": (grouped_gated, (
             _sds((288, 4096), BF16), _sds((288, 4), I32),
             _sds((288, 4), F32), _sds((288,), jnp.bool_),
@@ -299,7 +310,10 @@ def _kernel_cases():
 # query heads on one kv head, whose 1,024-token window runs out of VMEM
 # uncut; the block region's call at two blocks a slot under the block mask;
 # the gated delta rule: float32 matmuls of 64 x 96 and 96 x 384 operands
-# (neither a multiple of 128) and a 2.2 MB state block in and out
+# (neither a multiple of 128) and a 2.2 MB state block in and out; the
+# attention over a dsa layer's selected rows: a query's 2.6 MB of rows
+# double-buffered beside its float32 score tile of 128 x 2048, a product
+# with the rows transposed and one with their first 512 lanes
 AOT_CASES = ("ragged_12kv_x64", "ragged_decode_region",
              "ragged_chunk_region", "ragged_decode_gqa16",
              "ragged_verify_gqa8_x272", "ragged_chunk_gqa8_x272",
@@ -310,7 +324,7 @@ AOT_CASES = ("ragged_12kv_x64", "ragged_decode_region",
              "index_score_chunk", "index_score_decode",
              "selective_scan_chunk", "selective_scan_slots",
              "ragged_chunk_mqa20_x528", "ragged_block_gqa8_x41",
-             "gated_delta_chunk", "gated_delta_slots")
+             "gated_delta_chunk", "gated_delta_slots", "selected_attention")
 
 
 @pytest.fixture
@@ -338,10 +352,14 @@ def test_the_indexed_layer_lowers_with_no_score_tile_and_no_key_copy(
     come out of ONE Mosaic call, and the module's widest float32 array
     over the 33,792 positions is the scores themselves, a row a query: no
     ``[32, 64, 33792]`` tile, and no gathered ``[33792, 128]`` copy of a
-    context's index keys (the XLA arithmetic has both: the control)."""
+    context's index keys (the XLA arithmetic has both: the control).  The
+    attention over the selected rows is the module's SECOND Mosaic call,
+    and the ``[32, 128, 2048]`` float32 tile of its scores is gone with it
+    (the control has it)."""
     import re
     from hetu_tpu.models import hybrid as hy
     monkeypatch.setattr(ix, "on_tpu", lambda: True)
+    monkeypatch.setattr(selatt, "on_tpu", lambda: True)
     with open(os.path.join(REPO, "benchmark", "configs",
                            "dots3-ep8.json")) as f:
         geo = hy.dots3_config(json.load(f)).geometry("dsa")
@@ -364,11 +382,14 @@ def test_the_indexed_layer_lowers_with_no_score_tile_and_no_key_copy(
                    for m in re.findall(r"tensor<([\dx]+)x33792xf32>", text))
 
     copy = re.compile(r"tensor<(\d+x)?33792x128xbf16>")
+    tile = "tensor<32x128x2048xf32>"
     text = lowered(True)
-    assert text.count("tpu_custom_call") == 1
+    assert text.count("tpu_custom_call") == 2
     assert widest(text) == rows and not copy.search(text)
+    assert tile not in text
     xla = lowered(False)
     assert widest(xla) == 32 * 64 and copy.search(xla)
+    assert tile in xla and "tpu_custom_call" not in xla
 
 
 _AOT_SCRIPT = r"""
